@@ -697,6 +697,7 @@ def _retry_after_s(inflight: int) -> float:
 async def _send_healthz(
     channel: Channel, stream_id: int, draining: bool, inflight: int,
     peer_label: str = "", disagg: Optional[Dict[str, object]] = None,
+    device: Optional[Dict[str, object]] = None,
 ) -> None:
     """/healthz: ok|degraded|draining + queue/occupancy from the metrics
     registry (engine gauges; zeros under the plain HTTP backend).  200 only
@@ -735,7 +736,7 @@ async def _send_healthz(
         "inflight_requests": inflight,
         # ISSUE 4 observability: the decode program's launch profile and
         # the warmup compile bill — fused-path regressions show up here
-        # without a chip window (0 = probe unavailable on this host).
+        # on any host (0 = probe unavailable on this host).
         "decode_kernels_per_step": int(
             global_metrics.gauge("engine_decode_kernels_per_step")
         ),
@@ -802,9 +803,16 @@ async def _send_healthz(
         # hero configuration (int4 + kv-int4 + fused + mux + prefix)
         # reports an EMPTY list here; operators verify it fleet-wide via
         # the proxy's federated /healthz view.
+        # ``attention``: which implementation (Pallas kernel or einsum)
+        # each program family that ran took — the gates pick per shape.
         "config": {
             "fences": global_metrics.info("config_fences", []) or [],
+            "attention": global_metrics.info("attention_branches", {}) or {},
         },
+        # What JAX runs on in THIS process and what each local device
+        # holds — the only place a JAX-free parent (chip_smoke.py, a load
+        # generator) learns the device.  null under the HTTP backend.
+        "device": device,
         # ISSUE 20 observability: the disaggregated prefill/decode ledger —
         # this peer's serving role, pages shipped (prefill side) and
         # spliced from the wire (decode side), and the in-flight transfer
@@ -1223,12 +1231,14 @@ async def _serve_dispatch(
                     )
                     return
                 stats = getattr(backend, "disagg_stats", None)
+                device = getattr(backend, "device_section", None)
                 await _send_healthz(
                     channel, req.stream_id,
                     draining=drain is not None and drain.is_set(),
                     inflight=len(request_tasks),
                     peer_label=peer_label,
                     disagg=stats() if stats is not None else None,
+                    device=device() if device is not None else None,
                 )
                 return
             if route is not None and route[0] == "metrics":

@@ -29,11 +29,16 @@ SSE chunk shape matches the conformance fixture tmp/mock_llm.py:36-88.
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 from typing import AsyncIterator, Dict, Tuple
 
-from p2p_llm_tunnel_tpu.engine.engine import DeadlineExceeded, InferenceEngine
+from p2p_llm_tunnel_tpu.engine.engine import (
+    DeadlineExceeded,
+    InferenceEngine,
+    device_section,
+)
 from p2p_llm_tunnel_tpu.engine.scheduler import QueueFull
 from p2p_llm_tunnel_tpu.protocol.frames import (
     ERROR_CODE_HEADER,
@@ -1217,8 +1222,9 @@ def engine_backend(engine: InferenceEngine, model_name: str | None = None):
     unchanged, and http_backend (no engine, no pool) simply has none:
     ``kv_export`` answers a prefill-side page export, ``kv_import``
     splices a transfer into this engine's pool, ``disagg_stats`` feeds
-    the /healthz "disagg" section, and ``engine_role`` is stamped into
-    the AGREE handshake so the proxy's PeerSet routes by role.
+    the /healthz "disagg" section, ``engine_role`` is stamped into the
+    AGREE handshake so the proxy's PeerSet routes by role, and
+    ``device_section`` feeds the /healthz "device" section.
     """
     api = EngineAPI(engine, model_name)
 
@@ -1229,4 +1235,5 @@ def engine_backend(engine: InferenceEngine, model_name: str | None = None):
     backend.kv_import = engine.import_kv_pages
     backend.disagg_stats = engine.disagg_stats
     backend.engine_role = engine.ecfg.role
+    backend.device_section = functools.partial(device_section, [engine])
     return backend

@@ -6,17 +6,18 @@ InferenceEngine (its own slots/KV cache — typically its own chip or
 tp-mesh); the router admits each request to the least-loaded replica, so
 concurrent streams from one or many proxy peers spread across all chips.
 
-Placement of replicas on distinct devices is the caller's job (e.g. one
-process per chip, or `jax.default_device` per engine); the router itself
-is pure dispatch policy.
+Placement of replicas on distinct devices is the caller's job (one process
+per chip, or build each engine under `jax.default_device(d)` and then
+`engine.commit_to(d)` — cli.py); the router itself is pure dispatch policy.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional
 
 from p2p_llm_tunnel_tpu.engine.api import EngineAPI
-from p2p_llm_tunnel_tpu.engine.engine import InferenceEngine
+from p2p_llm_tunnel_tpu.engine.engine import InferenceEngine, device_section
 from p2p_llm_tunnel_tpu.protocol.frames import RequestHeaders
 from p2p_llm_tunnel_tpu.utils.logging import get_logger
 
@@ -67,4 +68,7 @@ def router_backend(router: ReplicaRouter):
     async def backend(req: RequestHeaders, body: bytes):
         return await router.handle(req, body)
 
+    backend.device_section = functools.partial(
+        device_section, router.engines
+    )
     return backend

@@ -258,6 +258,76 @@ def _logits(cfg: ModelConfig, params, x):
 # prefill
 # ---------------------------------------------------------------------------
 
+def prefill_attention_branch(cfg: ModelConfig, mesh, t: int) -> str:
+    """Which attention implementation whole-prompt prefill takes at padded
+    width ``t``: ``"ulysses"`` | ``"ring"`` (sp>1), ``"pallas-flash"``, or
+    ``"einsum"``.  The ONE predicate — :func:`_prefill_attention_fn`
+    selects by it and the engine reports it (/healthz
+    ``config.attention``), so what is printed is what ran."""
+    axes = dict(mesh.shape) if mesh is not None else {}
+    if axes.get("sp", 1) > 1:
+        return cfg.sp_mode
+    if (
+        cfg.flash
+        and (jax.default_backend() == "tpu" or cfg.flash_interpret)
+        and t % 128 == 0
+        and cfg.head_dim % 128 == 0
+    ):
+        return "pallas-flash"
+    return "einsum"
+
+
+def decode_kernel_decline(cfg: ModelConfig, mesh, kv_view: int):
+    """Why the Pallas decode kernels (s-grid, fused decode layer, fused
+    spec verify) cannot serve this (config, mesh, view) — ``None`` when
+    they can.  The ONE gate ``decode_step`` and ``spec_verify_into_cache``
+    share; the engine turns a non-backend reason into a ``config_fences``
+    entry at startup, so an option that asked for a kernel never gives way
+    to the einsum silently.
+
+    - off the TPU backend the kernels run only in interpret mode (CPU
+      tests) or under ``flash_force`` (lowering-only probes);
+    - tp>1 declines: pallas_call is not GSPMD-partitioned, so under a tp
+      mesh XLA would all-gather the sharded q/KV onto every chip (the
+      hazard prefill's flash_tp shard_map wrapper exists for — apply the
+      same wrapper here before enabling);
+    - shapes must tile (view and head_dim % 128) unless interpreting."""
+    backend = jax.default_backend()
+    if not (backend == "tpu" or cfg.flash_interpret or cfg.flash_force):
+        return f"backend {backend!r} is not tpu"
+    tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
+    if tp > 1:
+        return (f"pallas_call is not GSPMD-partitioned: under a tp={tp} "
+                "mesh XLA would all-gather the sharded cache")
+    if kv_view % 128:
+        return f"kv view {kv_view} does not tile (% 128)"
+    if cfg.head_dim % 128 and not cfg.flash_interpret:
+        return f"head_dim {cfg.head_dim} does not tile (% 128)"
+    return None
+
+
+def decode_attention_branch(cfg: ModelConfig, mesh, kv_view: int) -> str:
+    """Which attention implementation ``decode_step`` takes at this view:
+    ``"pallas-fused-decode-layer"`` (supersedes the flash selection),
+    ``"pallas-sgrid"`` (flash_decode / flash_sgrid both route to the
+    s-grid family), or ``"einsum"``."""
+    if decode_kernel_decline(cfg, mesh, kv_view) is None:
+        if cfg.fused_decode_layer:
+            return "pallas-fused-decode-layer"
+        if cfg.flash_decode or cfg.flash_sgrid:
+            return "pallas-sgrid"
+    return "einsum"
+
+
+def spec_attention_branch(cfg: ModelConfig, mesh, kv_view: int) -> str:
+    """``spec_verify_into_cache``'s branch: the fused K-token verify kernel
+    or the chunk-prefill einsum."""
+    if (cfg.fused_decode_layer
+            and decode_kernel_decline(cfg, mesh, kv_view) is None):
+        return "pallas-fused-spec"
+    return "einsum"
+
+
 def _prefill_attention_fn(cfg: ModelConfig, mesh, t: int):
     """Pick the prefill attention implementation for this (config, mesh).
 
@@ -320,13 +390,7 @@ def _prefill_attention_fn(cfg: ModelConfig, mesh, t: int):
 
         return ring_fn
 
-    use_flash = (
-        cfg.flash
-        and (jax.default_backend() == "tpu" or cfg.flash_interpret)
-        and t % 128 == 0
-        and cfg.head_dim % 128 == 0
-    )
-    if use_flash:
+    if prefill_attention_branch(cfg, mesh, t) == "pallas-flash":
         from p2p_llm_tunnel_tpu.ops.pallas_attention import (
             flash_causal_attention,
         )
@@ -760,15 +824,7 @@ def spec_verify_into_cache(
     s = kv_cache["k"].shape[2] * (2 if quant_mode == "int4" else 1)
     if kv_view is None or kv_view > s:
         kv_view = s
-    tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
-    kernel_ok = (
-        (jax.default_backend() == "tpu" or cfg.flash_interpret
-         or cfg.flash_force)
-        and tp == 1
-        and kv_view % 128 == 0
-        and (cfg.head_dim % 128 == 0 or cfg.flash_interpret)
-    )
-    if not (cfg.fused_decode_layer and kernel_ok):
+    if spec_attention_branch(cfg, mesh, kv_view) == "einsum":
         lengths = jnp.full((b,), t, jnp.int32)
         return chunk_prefill_into_cache(
             cfg, params, tokens, lengths, positions, kv_cache,
@@ -968,25 +1024,12 @@ def decode_step(
     layer_idx = jnp.arange(cfg.n_layers)
     slot_ids = jnp.arange(b)
 
-    # Pallas gating beyond the config flags:
-    # - tp>1 falls back to the einsum path: pallas_call is not GSPMD-
-    #   partitioned, so under a tp mesh XLA would all-gather the sharded
-    #   q/KV onto every chip (the hazard prefill's flash_tp shard_map
-    #   wrapper exists for — apply the same wrapper here before enabling);
-    # - shapes must tile (view and head_dim % 128) unless interpreting.
-    tp = dict(mesh.shape).get("tp", 1) if mesh is not None else 1
-    kernel_ok = (
-        (jax.default_backend() == "tpu" or cfg.flash_interpret
-         or cfg.flash_force)
-        and tp == 1
-        and kv_view % 128 == 0
-        and (cfg.head_dim % 128 == 0 or cfg.flash_interpret)
-    )
+    # Pallas gating beyond the config flags: decode_kernel_decline.
+    branch = decode_attention_branch(cfg, mesh, kv_view)
     # The FUSED decode-layer kernel (ISSUE 4): rope + new-row quant +
     # cache append + frontier-clamped attention in one program per layer.
     # Supersedes the flash selection further below when enabled.
-    use_fused = cfg.fused_decode_layer and kernel_ok
-    if use_fused:
+    if branch == "pallas-fused-decode-layer":
         from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
             fused_decode_layer,
         )
@@ -1037,7 +1080,7 @@ def decode_step(
     # legacy plane kernel's whole-view DMA is its docstring'd weakness, so
     # it is no longer reachable from the model layer (it survives as
     # flash_decode_attention_plane for interpret-mode cross-checks).
-    use_sgrid = (cfg.flash_decode or cfg.flash_sgrid) and kernel_ok
+    use_sgrid = branch == "pallas-sgrid"
     if use_sgrid:
         from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
             flash_decode_attention_sgrid,

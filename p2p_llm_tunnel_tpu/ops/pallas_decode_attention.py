@@ -10,14 +10,18 @@ bodies share the online-softmax math:
   view·head_dim at 1M elements ≈ 4 MB of K+V per program).  Kept ONLY as
   an interpret-mode cross-check of the s-grid family; the public
   ``flash_decode_attention`` entry routes to the s-grid kernel (ISSUE 4:
-  the plane kernel's whole-view DMA is a documented weakness).
+  the plane kernel's whole-view DMA is a documented weakness).  The TPU
+  compiler refuses it (tests/test_tpu_compile.py, strict xfail).
 - ``flash_decode_attention_sgrid`` (r5, VERDICT r4 item 2): the sequence
-  axis joins the grid — program (slot, kv-head, s-block) stages ONE
-  [BLOCK_S, D] block.  The slot's position rides scalar prefetch, and the
+  axis joins the grid — program (slot, s-block) stages ONE
+  [BLOCK_S, K, D] block, all kv-heads, and loops over them (a block that
+  squeezes K out of the native layout is refused by the TPU lowering).
+  The slot's position rides scalar prefetch, and the
   K/V index map CLAMPS past-frontier steps to the frontier block: Pallas
   skips the re-fetch of an unchanged block, so blocks past the frontier
   cost neither DMA nor compute (`pl.when`).  VMEM per program is
-  ~2·BLOCK_S·D·4B regardless of view — no view cap, arbitrary max_seq.
+  ~2·BLOCK_S·K·D bytes-per-value regardless of view — no view cap,
+  arbitrary max_seq.
   The s-grid kernel serves THREE KV precisions through one body
   (``kv_quant``): raw bf16/f32, int8 + per-(token, head) scales, and
   packed int4 (two adjacent tokens per byte along the sequence axis) —
@@ -100,6 +104,31 @@ def page_alignment_violations(kv_quant: Optional[str], page_tokens: int,
             f"int4 packing ({INT4_PACK_TOKENS} tokens/byte)"
         )
     return out
+
+
+def _nibbles_i32(p):
+    """Packed int4 bytes -> (low, high) sign-extended nibbles as int32.
+    The shifts run in int32: Mosaic does not legalize ``arith.shli`` on
+    int8 vectors.  Values are identical to the int8 arithmetic shifts of
+    models.quant.unpack_int4."""
+    p32 = p.astype(jnp.int32)
+    return (jnp.right_shift(jnp.left_shift(p32, 28), 28),
+            jnp.right_shift(p32, 4))
+
+
+def _unpack_seq(p):
+    """[N/2, ...] packed bytes -> [N, ...] int32 values in [-8, 7]: token
+    2i from the low nibble, 2i+1 from the high one."""
+    lo, hi = _nibbles_i32(p)
+    return jnp.stack([lo, hi], axis=1).reshape(
+        (2 * p.shape[0],) + p.shape[1:]
+    )
+
+
+def _pack_byte(lo, hi):
+    """int32 nibble values -> one int8 byte (models.quant.pack_int4
+    layout: low nibble = even token)."""
+    return (jnp.left_shift(hi, 4) | (lo & 0x0F)).astype(jnp.int8)
 
 
 def _decode_kernel(
@@ -252,17 +281,19 @@ BLOCK_S = 256
 def _decode_kernel_sgrid(
     pos_sref,  # scalar-prefetch [B] int32: per-slot query position
     win_sref,  # scalar-prefetch [1] int32: sliding window (S+1 = disabled)
-    q_ref,  # [G, D] this (slot, kv-head)'s query group
-    k_ref,  # [BS, D] ONE s-block of keys (bf16/f32 or int8), or [BS/2, D]
-    #         packed int4 bytes (kv_quant="int4": adjacent tokens share a
-    #         byte — low nibble = token 2i, high = 2i+1)
+    q_ref,  # [H, D] this slot's query heads (head = kv_head * G + g)
+    k_ref,  # [BS, K, D] ONE s-block of keys, all kv-heads (bf16/f32 or
+    #         int8), or [BS/2, K, D] packed int4 bytes (kv_quant="int4":
+    #         adjacent tokens share a byte — low nibble = token 2i)
     v_ref,  # same layout as k_ref
-    *rest,  # kv_quant: (ks_ref [BS,1], vs_ref [BS,1], o, m, l, acc)
+    *rest,  # kv_quant: (ks_ref [BS,K,1], vs_ref [BS,K,1], o, m, l, acc)
     #         else:     (o, m, l, acc)
     scale: float,
     softcap: Optional[float],
     block_s: int,
     n_sblocks: int,
+    kh: int,
+    g: int,
     out_dtype,
     kv_quant: Optional[str],
 ):
@@ -275,13 +306,19 @@ def _decode_kernel_sgrid(
     the einsum path for int8 KV).  int4 additionally unpacks two nibbles
     per byte along the SEQUENCE axis (the lane axis stays D-wide, so TPU
     tiling is unaffected) — the weight-quant lesson applied to KV: packed
-    bytes cross HBM, the wide copy exists only in VMEM."""
+    bytes cross HBM, the wide copy exists only in VMEM.
+
+    The staged block spans ALL kv-heads ([BS, K, D], the cache's trailing
+    dims whole) and the body loops over them, exactly like
+    ``_fused_decode_layer_kernel``: a block that squeezes the K axis out of
+    the native [.., S, K, D] layout is refused by the TPU lowering (the
+    last two block dims must tile (8, 128) or equal the array's)."""
     if kv_quant is not None:
         ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc = rest
     else:
         o_ref, m_sc, l_sc, acc_sc = rest
     bi = pl.program_id(0)
-    sj = pl.program_id(2)
+    sj = pl.program_id(1)
     pos = pos_sref[bi]
     window = win_sref[0]
     # Last s-block holding any attendable key for this slot.  Parked rows
@@ -295,51 +332,48 @@ def _decode_kernel_sgrid(
         l_sc[:] = jnp.zeros_like(l_sc[:])
         acc_sc[:] = jnp.zeros_like(acc_sc[:])
 
-    def _unpack_seq(p):
-        # [BS/2, D] bytes -> [BS, D] int8 values in [-8, 7]: token 2i from
-        # the sign-extended low nibble, 2i+1 from the arithmetic high shift.
-        lo = jnp.right_shift(jnp.left_shift(p, 4), 4)
-        hi = jnp.right_shift(p, 4)
-        return jnp.stack([lo, hi], axis=1).reshape(2 * p.shape[0], p.shape[1])
-
     @pl.when(sj <= frontier)
     def _compute():
-        q = q_ref[:].astype(jnp.float32) * scale
         if kv_quant == "int4":
-            k = _unpack_seq(k_ref[:]).astype(jnp.float32)  # [BS, D]
-            v = _unpack_seq(v_ref[:]).astype(jnp.float32)
+            k_blk = _unpack_seq(k_ref[:]).astype(jnp.float32)  # [BS, K, D]
+            v_blk = _unpack_seq(v_ref[:]).astype(jnp.float32)
         else:
-            k = k_ref[:].astype(jnp.float32)  # [BS, D]
-            v = v_ref[:].astype(jnp.float32)
+            k_blk = k_ref[:].astype(jnp.float32)  # [BS, K, D]
+            v_blk = v_ref[:].astype(jnp.float32)
         if kv_quant is not None:
-            k = k * ks_ref[:]
-            v = v * vs_ref[:]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [G, BS]
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
+            k_blk = k_blk * ks_ref[:]
+            v_blk = v_blk * vs_ref[:]
         k_pos = sj * block_s + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_s), 1
         )
         mask = (k_pos <= pos) & ((pos - k_pos) < window)
-        s = jnp.where(mask, s, _NEG_INF)
+        for h in range(kh):
+            rows = slice(h * g, (h + 1) * g)
+            q = q_ref[rows, :].astype(jnp.float32) * scale  # [G, D]
+            s = jax.lax.dot_general(
+                q, k_blk[:, h, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [G, BS]
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_sc[:, :1]  # [G, 1]
-        l_prev = l_sc[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s == _NEG_INF, 0.0, p)
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-        # Lane-replicated stores: scratch tiles are [G, 128]; sub-lane
-        # writes are awkward on TPU, broadcasting the [G, 1] scalars across
-        # the lane axis keeps every store full-tile.
-        m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+            m_prev = m_sc[rows, :1]  # [G, 1]
+            l_prev = l_sc[rows, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            corr = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_new))
+            p = jnp.exp(s - m_new)
+            p = jnp.where(s == _NEG_INF, 0.0, p)
+            acc_sc[rows, :] = acc_sc[rows, :] * corr + jax.lax.dot_general(
+                p, v_blk[:, h, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+            # Lane-replicated stores: scratch tiles are [H, 128]; sub-lane
+            # writes are awkward on TPU, broadcasting the [G, 1] scalars
+            # across the lane axis keeps every store full-width.
+            m_sc[rows, :] = jnp.broadcast_to(m_new, (g, m_sc.shape[-1]))
+            l_sc[rows, :] = jnp.broadcast_to(l_new, (g, l_sc.shape[-1]))
 
     @pl.when(sj == n_sblocks - 1)
     def _emit():
@@ -365,15 +399,15 @@ def flash_decode_attention_sgrid(
     """S-gridded drop-in for ``flash_decode_attention``: per-block DMA,
     frontier-clamped index map, no view-size cap.
 
-    Grid (B, K, S/BLOCK_S) with the s-axis innermost: scratch accumulators
-    carry the online softmax across s-steps of one (slot, head).  Blocks
-    past the slot's frontier resolve to the SAME block index as the
-    frontier (scalar-prefetch clamp), so Pallas elides their fetch; their
-    compute is skipped with `pl.when`.  With ``k_scale``/``v_scale`` the
-    cache is quantized and dequantized in VMEM: ``kv_quant="int8"`` reads
-    [B, S, K, D] int8 planes, ``"int4"`` reads [B, S/2, K, D] bytes with
-    two adjacent tokens packed per byte (pack with
-    models.quant.pack_int4(axis=1)).
+    Grid (B, S/BLOCK_S) with the s-axis innermost: scratch accumulators
+    carry the online softmax across s-steps of one slot, every kv-head in
+    the same program.  Blocks past the slot's frontier resolve to the SAME
+    block index as the frontier (scalar-prefetch clamp), so Pallas elides
+    their fetch; their compute is skipped with `pl.when`.  With
+    ``k_scale``/``v_scale`` the cache is quantized and dequantized in
+    VMEM: ``kv_quant="int8"`` reads [B, S, K, D] int8 planes, ``"int4"``
+    reads [B, S/2, K, D] bytes with two adjacent tokens packed per byte
+    (pack with models.quant.pack_int4(axis=1)).
     """
     b, t, h, d = q.shape
     assert t == 1, "decode step processes exactly one token per slot"
@@ -404,7 +438,6 @@ def flash_decode_attention_sgrid(
         jnp.full((1,), s + 1, jnp.int32) if window is None
         else jnp.reshape(window, (1,)).astype(jnp.int32)
     )
-    q_g = q[:, 0].reshape(b, kh, g, d)
 
     kernel = functools.partial(
         _decode_kernel_sgrid,
@@ -412,31 +445,33 @@ def flash_decode_attention_sgrid(
         softcap=softcap,
         block_s=bs,
         n_sblocks=n_sb,
+        kh=kh,
+        g=g,
         out_dtype=q.dtype,
         kv_quant=kv_quant,
     )
 
-    def kv_index(bi, ki, sj, pos_r, win_r):
+    def slot_index(bi, sj, pos_r, win_r):
+        return (bi, 0, 0)
+
+    def kv_index(bi, sj, pos_r, win_r):
         # Clamp past-frontier steps to the frontier block: same index as
         # the previous step -> Pallas skips the DMA.  Block indices are in
         # block units, so the same map serves the packed int4 axis (block
         # bs/2 of a S/2-length axis) and the full-width layouts.
-        return (bi, jnp.minimum(sj, pos_r[bi] // bs), ki, 0)
+        return (bi, jnp.minimum(sj, pos_r[bi] // bs), 0, 0)
 
     kv_rows = bs // 2 if kv_quant == "int4" else bs
     in_specs = [
-        pl.BlockSpec(
-            (None, None, g, d),
-            lambda bi, ki, sj, pos_r, win_r: (bi, ki, 0, 0),
-        ),
-        pl.BlockSpec((None, kv_rows, None, d), kv_index),
-        pl.BlockSpec((None, kv_rows, None, d), kv_index),
+        pl.BlockSpec((None, h, d), slot_index),
+        pl.BlockSpec((None, kv_rows, kh, d), kv_index),
+        pl.BlockSpec((None, kv_rows, kh, d), kv_index),
     ]
-    operands = [pos, win, q_g, k_cache, v_cache]
+    operands = [pos, win, q[:, 0], k_cache, v_cache]
     if quantized:
         in_specs += [
-            pl.BlockSpec((None, bs, None, 1), kv_index),
-            pl.BlockSpec((None, bs, None, 1), kv_index),
+            pl.BlockSpec((None, bs, kh, 1), kv_index),
+            pl.BlockSpec((None, bs, kh, 1), kv_index),
         ]
         operands += [
             k_scale.astype(jnp.float32)[..., None],  # [B, S, K, 1]
@@ -445,19 +480,16 @@ def flash_decode_attention_sgrid(
 
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, kh, n_sb),
+            grid=(b, n_sb),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (None, None, g, d),
-                lambda bi, ki, sj, pos_r, win_r: (bi, ki, 0, 0),
-            ),
+            out_specs=pl.BlockSpec((None, h, d), slot_index),
             scratch_shapes=[
-                pltpu.VMEM((g, 128), jnp.float32),
-                pltpu.VMEM((g, 128), jnp.float32),
-                pltpu.VMEM((g, d), jnp.float32),
+                pltpu.VMEM((h, 128), jnp.float32),
+                pltpu.VMEM((h, 128), jnp.float32),
+                pltpu.VMEM((h, d), jnp.float32),
             ],
         ),
         interpret=interpret,
@@ -573,8 +605,11 @@ def _fused_decode_layer_kernel(
         # expression of ops.rope.rope_table so interpret mode reproduces
         # the unfused reference to the ulp.
         half = d // 2
-        lane = jax.lax.broadcasted_iota(jnp.float32, (1, d), 1)
-        pair = jnp.where(lane < half, lane, lane - half)
+        # Integer iota converted to f32: Mosaic's tpu.iota yields integer
+        # vectors only, and small ints convert exactly, so the bit identity
+        # with ops.rope.rope_table holds.
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
+        pair = jnp.where(lane < half, lane, lane - half).astype(jnp.float32)
         freqs = 1.0 / (rope_theta ** (2.0 * pair / d))
         ang = pos.astype(jnp.float32) * freqs
         sin = jnp.sin(ang)
@@ -601,13 +636,6 @@ def _fused_decode_layer_kernel(
         else:
             kq_sc[:] = kn
             vq_sc[:] = vn
-
-    def _unpack_seq(p):  # [BS/2, K, D] bytes -> [BS, K, D] int8 in [-8, 7]
-        lo = jnp.right_shift(jnp.left_shift(p, 4), 4)
-        hi = jnp.right_shift(p, 4)
-        return jnp.stack([lo, hi], axis=1).reshape(
-            2 * p.shape[0], p.shape[1], p.shape[2]
-        )
 
     @pl.when(sj <= frontier)
     def _compute():
@@ -669,9 +697,10 @@ def _fused_decode_layer_kernel(
             vq = jnp.round(vq_sc[:]).astype(jnp.int8)[None]
 
             def pack_row(new, old_b):
-                lo = jnp.where(even, new, old_b) & 0x0F
-                hi = jnp.where(even, jnp.right_shift(old_b, 4), new)
-                return (jnp.left_shift(hi, 4) | lo).astype(jnp.int8)
+                old_lo, old_hi = _nibbles_i32(old_b)
+                new = new.astype(jnp.int32)
+                return _pack_byte(jnp.where(even, new, old_lo),
+                                  jnp.where(even, old_hi, new))
 
             ok_ref[:] = jnp.where(parked, old, pack_row(kq, old))
             ov_ref[:] = jnp.where(parked, old_v, pack_row(vq, old_v))
@@ -976,8 +1005,8 @@ def _fused_spec_decode_layer_kernel(
         l_sc[:] = jnp.zeros_like(l_sc[:])
         acc_sc[:] = jnp.zeros_like(acc_sc[:])
         half = d // 2
-        lane = jax.lax.broadcasted_iota(jnp.float32, (1, d), 1)
-        pair = jnp.where(lane < half, lane, lane - half)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
+        pair = jnp.where(lane < half, lane, lane - half).astype(jnp.float32)
         freqs = 1.0 / (rope_theta ** (2.0 * pair / d))
 
         def rope(x, ang):  # x [rows, D] f32
@@ -1011,13 +1040,6 @@ def _fused_spec_decode_layer_kernel(
             else:
                 kq_sc[t * kh:(t + 1) * kh] = kn
                 vq_sc[t * kh:(t + 1) * kh] = vn
-
-    def _unpack_seq(p):  # [BS/2, K, D] bytes -> [BS, K, D] int8 in [-8, 7]
-        lo = jnp.right_shift(jnp.left_shift(p, 4), 4)
-        hi = jnp.right_shift(p, 4)
-        return jnp.stack([lo, hi], axis=1).reshape(
-            2 * p.shape[0], p.shape[1], p.shape[2]
-        )
 
     def _deq_row(sc, ssc, t, h, stored: bool = False):
         # One burst row's head-h DEQUANTIZED value [1, D] — what a later
@@ -1152,9 +1174,10 @@ def _fused_spec_decode_layer_kernel(
                     jnp.int8)[None]
 
                 def pack_row(new, old_b):
-                    lo = jnp.where(even, new, old_b) & 0x0F
-                    hi = jnp.where(even, jnp.right_shift(old_b, 4), new)
-                    return (jnp.left_shift(hi, 4) | lo).astype(jnp.int8)
+                    old_lo, old_hi = _nibbles_i32(old_b)
+                    new = new.astype(jnp.int32)
+                    return _pack_byte(jnp.where(even, new, old_lo),
+                                      jnp.where(even, old_hi, new))
 
                 ok_ref[:] = jnp.where(
                     tok_parked, base_k, pack_row(kq, base_k))

@@ -73,7 +73,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import INT4_PACK_TOKENS
+from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+    INT4_PACK_TOKENS,
+    _pack_byte,
+    _unpack_seq,
+)
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -201,7 +205,11 @@ def _ragged_prefill_kernel(
         # BIT-for-bit on CPU interpret or a near-half value rounds the
         # other way and the appended cache bytes split from the chunk
         # path's (observed: 1-in-~1e3 elements at a different nibble).
-        lane2 = 2.0 * jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
+        # (integer iota converted to f32 — Mosaic's tpu.iota yields
+        # integer vectors only; small ints convert exactly.)
+        lane2 = 2.0 * jax.lax.broadcasted_iota(
+            jnp.int32, (1, half), 1
+        ).astype(jnp.float32)
         freqs = 1.0 / (rope_theta ** (lane2 / d))
         ang = pos_col.astype(jnp.float32) * freqs
         return jnp.sin(ang), jnp.cos(ang)
@@ -231,13 +239,6 @@ def _ragged_prefill_kernel(
         q_sc[:] = rope(
             q_ref[:].astype(jnp.float32), sin, cos
         ).astype(q_ref.dtype).astype(jnp.float32) * scale
-
-    def _unpack_seq(p):  # [BS/2, K, D] bytes -> [BS, K, D] int8 in [-8, 7]
-        lo = jnp.right_shift(jnp.left_shift(p, 4), 4)
-        hi = jnp.right_shift(p, 4)
-        return jnp.stack([lo, hi], axis=1).reshape(
-            2 * p.shape[0], p.shape[1], p.shape[2]
-        )
 
     def _online(k_blk, v_blk, mask):
         """One flash step over a staged [N, K, D] K/V block for every
@@ -352,21 +353,17 @@ def _ragged_prefill_kernel(
         @pl.when(base + tj == qb)
         def _append():
             if kv_quant == "int4":
-                kq_i = kq.astype(jnp.int8).reshape(
+                kq_i = kq.astype(jnp.int32).reshape(
                     block_q // 2, 2, kh, d
                 )
-                vq_i = vq.astype(jnp.int8).reshape(
+                vq_i = vq.astype(jnp.int32).reshape(
                     block_q // 2, 2, kh, d
                 )
                 # Whole-byte pack (models.quant.pack_int4 layout): token
                 # 2i low nibble, 2i+1 high.  start/block_q evenness makes
                 # every write byte-aligned — no nibble RMW on this path.
-                ok_ref[:] = (
-                    jnp.left_shift(kq_i[:, 1], 4) | (kq_i[:, 0] & 0x0F)
-                ).astype(jnp.int8)
-                ov_ref[:] = (
-                    jnp.left_shift(vq_i[:, 1], 4) | (vq_i[:, 0] & 0x0F)
-                ).astype(jnp.int8)
+                ok_ref[:] = _pack_byte(kq_i[:, 0], kq_i[:, 1])
+                ov_ref[:] = _pack_byte(vq_i[:, 0], vq_i[:, 1])
             elif kv_quant == "int8":
                 ok_ref[:] = kq.astype(jnp.int8)
                 ov_ref[:] = vq.astype(jnp.int8)
@@ -560,6 +557,22 @@ def ragged_prefill_attention(
         pltpu.VMEM((block_q, h, d), jnp.float32),    # acc
     ]
 
+    # Scoped VMEM the program needs, stated to the compiler: the f32
+    # scratch (q, m, l, acc) and the double-buffered q/out/tail/history
+    # blocks all grow with block_q and pass Mosaic's 16 MiB default near
+    # block_q=128 (the v5e compiler asked for 18.1 MiB at H=32, D=128).
+    # Twice the buffer bytes leaves room for the step's f32 temporaries;
+    # a v5e core has 128 MiB of VMEM.
+    act, kvb = q.dtype.itemsize, k_cache.dtype.itemsize
+    vmem_buffers = (
+        block_q * h * (2 * d + 2 * 128) * 4
+        + 2 * 2 * block_q * h * d * act
+        + 2 * 2 * block_q * kh * d * act
+        + 2 * 2 * (bs // pack) * kh * d * kvb
+        + 2 * 2 * (block_q // pack) * kh * d * kvb
+    )
+    vmem_limit = min(max(16 << 20, 2 * vmem_buffers), 96 << 20)
+
     outs = pl.pallas_call(
         kernel,
         out_shape=tuple(out_shapes),
@@ -571,6 +584,7 @@ def ragged_prefill_attention(
             scratch_shapes=scratch,
         ),
         input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(*operands)
     if quantized:

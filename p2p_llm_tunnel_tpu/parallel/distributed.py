@@ -72,11 +72,9 @@ def init_distributed(
         )
     except RuntimeError as e:
         # Double-init (e.g. a router constructing several engines) is fine;
-        # anything else is a real join failure.  jax 0.9 phrases this
-        # "distributed.initialize should only be called once."; older
-        # versions say "already initialized" — match both.
-        msg = str(e).lower()
-        if "already" not in msg and "only be called once" not in msg:
+        # anything else is a real join failure.  The installed jax (0.9)
+        # phrases it "distributed.initialize should only be called once."
+        if "only be called once" not in str(e):
             raise
         log.debug("jax.distributed already initialized: %s", e)
 

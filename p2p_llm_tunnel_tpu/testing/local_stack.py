@@ -1,7 +1,7 @@
 """Self-contained serve+proxy stack over loopback, runnable as a process.
 
 The server half of the out-of-process ingress load test (ISSUE 7): one
-process hosts the REAL serving path — tiny-model CPU engine → EngineAPI →
+process hosts the REAL serving path — tiny-model engine → EngineAPI →
 run_serve ⇄ loopback tunnel ⇄ run_proxy → HTTP listener — while
 ``scripts/loadgen.py`` hammers the listener from a separate process, so
 client-side parsing never shares an interpreter (or a GIL) with the stack
@@ -18,8 +18,10 @@ loadgen``):
     JAX_PLATFORMS=cpu python -m p2p_llm_tunnel_tpu.testing.local_stack \
         --port 0 --slots 32 --max-seq 256 --max-waiting 600
 
-Runs until SIGTERM/SIGINT.  TUNNEL_CHAOS wraps the loopback tunnel like
-any other transport, so the ingress herd can run under seeded faults.
+The platform is the caller's to choose (``JAX_PLATFORMS``); the stack logs
+the device it got.  Runs until SIGTERM/SIGINT.  TUNNEL_CHAOS wraps the
+loopback tunnel like any other transport, so the ingress herd can run under
+seeded faults.
 """
 
 from __future__ import annotations
@@ -29,25 +31,18 @@ import asyncio
 import os
 import sys
 
-# CPU by default: this is a load harness, not a chip benchmark.  Mirrors
-# tests/conftest.py — the env var must be set before jax imports, and the
-# config update wins over PJRT plugins that force-register other backends.
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-from p2p_llm_tunnel_tpu.endpoints.proxy import run_proxy  # noqa: E402
-from p2p_llm_tunnel_tpu.endpoints.serve import run_serve  # noqa: E402
-from p2p_llm_tunnel_tpu.engine.api import engine_backend  # noqa: E402
-from p2p_llm_tunnel_tpu.engine.engine import (  # noqa: E402
+from p2p_llm_tunnel_tpu.endpoints.proxy import run_proxy
+from p2p_llm_tunnel_tpu.endpoints.serve import run_serve
+from p2p_llm_tunnel_tpu.engine.api import engine_backend
+from p2p_llm_tunnel_tpu.engine.engine import (
     EngineConfig,
     InferenceEngine,
 )
-from p2p_llm_tunnel_tpu.transport.chaos import maybe_chaos  # noqa: E402
-from p2p_llm_tunnel_tpu.transport.loopback import loopback_pair  # noqa: E402
-from p2p_llm_tunnel_tpu.utils.logging import get_logger, init_logging  # noqa: E402
+from p2p_llm_tunnel_tpu.transport.chaos import maybe_chaos
+from p2p_llm_tunnel_tpu.transport.loopback import loopback_pair
+from p2p_llm_tunnel_tpu.utils.logging import get_logger, init_logging
 
 log = get_logger(__name__)
 
@@ -276,6 +271,13 @@ async def amain(args) -> None:
 def main(argv=None) -> int:
     init_logging()
     args = build_parser().parse_args(argv)
+    # The platform comes from the caller's environment only (`make loadgen`
+    # and the tests pass JAX_PLATFORMS=cpu): name the device, so a load run
+    # can never measure another one than it thinks.
+    dev = jax.local_devices()[0]
+    log.info("stack device: platform=%s kind=%s count=%d (JAX_PLATFORMS=%r)",
+             dev.platform, dev.device_kind, len(jax.local_devices()),
+             os.environ.get("JAX_PLATFORMS"))
     try:
         asyncio.run(amain(args))
     except KeyboardInterrupt:

@@ -1,10 +1,9 @@
 """Kernel/launch counting over lowered StableHLO (ISSUE 4 satellite).
 
 The fused decode-layer kernel exists to collapse the per-step launch storm
-(32 layers × 16 steps ≈ 4k kernel launches per decode dispatch), but the
-win must be measurable OFF-chip: chip windows on the tunneled deployment
-last minutes (PERF.md r5), so a regression that re-splits the layer body
-into many kernels has to be visible from any CPU host.  JAX can lower a
+(32 layers × 16 steps ≈ 4k kernel launches per decode dispatch), and a
+regression that re-splits the layer body into many kernels should be
+visible to a CPU test run, at no chip time.  JAX can lower a
 jitted program for the TPU platform from a CPU-only host
 (``jit(f).trace(*args).lower(lowering_platforms=("tpu",))``) — that module
 is the REAL serving program (Pallas kernels appear as single

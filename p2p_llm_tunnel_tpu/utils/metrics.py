@@ -64,7 +64,7 @@ METRICS_CATALOG: Dict[str, str] = {
     ),
     "engine_warmup_compile_s": (
         "wall seconds warmup spent compiling the serving program set "
-        "(gauge; the number a chip window must fit before serving)"
+        "(gauge; the set-up a start pays before its first request)"
     ),
     # -- engine flight recorder / cold-start profiler (ISSUE 12) ----------
     "engine_warmup_programs": (
@@ -74,7 +74,7 @@ METRICS_CATALOG: Dict[str, str] = {
     ),
     "engine_warmup_compile_max_s": (
         "wall seconds of the single slowest warmup program compile "
-        "(gauge; the indivisible floor a chip window must fit)"
+        "(gauge; the floor no parallel warmup can go below)"
     ),
     "engine_cold_compiles_total": (
         "programs compiled ON the serving path after warmup declared the "

@@ -740,8 +740,10 @@ async def run_load(args) -> dict:
 
 
 def spawn_stack(args) -> Tuple[subprocess.Popen, int]:
+    # One process for each chip: this load generator stays off JAX, and
+    # the stack it spawns takes its platform from the caller's environment
+    # (`make loadgen` passes JAX_PLATFORMS=cpu) and logs the device it got.
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     cmd = [
         sys.executable, "-m", "p2p_llm_tunnel_tpu.testing.local_stack",
         "--port", "0", "--slots", str(args.stack_slots),

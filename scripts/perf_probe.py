@@ -41,13 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.environ.get("JAX_CC_DIR", os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache")),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from p2p_llm_tunnel_tpu.utils.compile_cache import enable as _enable_cache
+
+_enable_cache()
 
 
 def main() -> None:

@@ -102,14 +102,35 @@ def test_row_builder_matches_declared_schema():
     assert tuple(keys) == PINNED_ROW_KEYS
 
 
-def test_finalize_preserves_schema_and_adds_only_driver_keys():
-    """_finalize may ADD driver-facing keys but must never rename or drop
-    a row key — a CPU smoke row keeps the full schema with vs_baseline
-    nulled and no_tpu set."""
+def test_finalize_cpu_row_is_never_a_chip_datapoint():
+    """_finalize may ADD the no_tpu key but must never rename or drop a
+    row key.  A CPU row — an explicit JAX_PLATFORMS=cpu rehearsal — keeps
+    the full schema with the chip-only fields nulled (vs_baseline, mfu)
+    and carries nothing from an earlier chip run."""
     bench = _bench_module()
     row = {k: 0 for k in PINNED_ROW_KEYS}
     row["platform"] = "cpu"
     out = bench._finalize(dict(row))
-    assert set(PINNED_ROW_KEYS) <= set(out)
-    assert out["no_tpu"] is True and out["vs_baseline"] is None
+    assert set(out) == set(PINNED_ROW_KEYS) | {"no_tpu"}
+    assert out["no_tpu"] is True
+    assert out["vs_baseline"] is None and out["mfu"] is None
     assert json.dumps(out)  # the row stays a single serializable JSON line
+
+
+def test_finalize_leaves_a_chip_row_alone():
+    bench = _bench_module()
+    row = {k: 0 for k in PINNED_ROW_KEYS}
+    row.update(platform="tpu", vs_baseline=0.5, mfu=0.1)
+    assert bench._finalize(dict(row)) == row
+
+
+def test_mfu_peak_comes_from_the_device_table():
+    """The bf16 peak is keyed by device_kind with its source; the CPU has
+    no MFU and an unknown TPU kind is an error, not a default."""
+    import pytest
+
+    bench = _bench_module()
+    assert bench._peak_bf16_flops("tpu", "TPU v5 lite") == 197e12
+    assert bench._peak_bf16_flops("cpu", "cpu") is None
+    with pytest.raises(RuntimeError, match="TPU v9"):
+        bench._peak_bf16_flops("tpu", "TPU v9")

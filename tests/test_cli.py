@@ -177,7 +177,7 @@ def test_sigterm_saves_prefix_snapshot(tmp_path):
 
     snap = tmp_path / "snap"
     env = dict(
-        os.environ, TUNNEL_JAX_PLATFORM="cpu",
+        os.environ, JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=1",
     )
     proc = subprocess.Popen(

@@ -45,12 +45,6 @@ def test_init_distributed_swallows_double_init(monkeypatch):
     monkeypatch.setattr(jax.distributed, "initialize", boom)
     init_distributed("host0:8476", 4, 1)  # must not raise
 
-    def boom_old(**kw):
-        raise RuntimeError("jax.distributed is already initialized")
-
-    monkeypatch.setattr(jax.distributed, "initialize", boom_old)
-    init_distributed("host0:8476", 4, 1)  # older phrasing also swallowed
-
 
 def test_init_distributed_propagates_real_failures(monkeypatch):
     def boom(**kw):

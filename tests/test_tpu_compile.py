@@ -1,0 +1,316 @@
+"""What a CPU test run cannot otherwise see about the chip (ISSUE 21).
+
+- Every ``pallas_call`` in ``ops/`` is COMPILED — not interpreted, not merely
+  lowered — for a described TPU v5e at mistral-7b / llama3-8b widths (H=32,
+  K=8, D=128), in each KV form the engine serves.  The TPU compiler is
+  installed here and compiles for a chip that is described and not attached
+  (``jax.experimental.topologies``); interpret mode and StableHLO lowering
+  never reach Mosaic, which refused three of the four kernel families until
+  this file existed.  A kernel left unrepaired is a strict ``xfail`` carrying
+  the compiler's sentence.
+- ``--replicas``: each engine's decode output lives on its own device.
+- The compile-cache helper and ``serve --backend tpu``'s refusal of a
+  backend it was not asked to run on.
+"""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from p2p_llm_tunnel_tpu.ops.pallas_attention import flash_causal_attention
+from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+    flash_decode_attention_plane,
+    flash_decode_attention_sgrid,
+    fused_decode_layer,
+    fused_spec_decode_layer,
+)
+from p2p_llm_tunnel_tpu.ops.pallas_prefill_attention import (
+    ragged_prefill_attention,
+)
+
+# mistral-7b / llama3-8b attention widths, the engine's 32 slots + scratch
+# row, max_seq 1024, mistral's window.
+H, K, D, L = 32, 8, 128, 32
+ROWS, MAX_SEQ, WINDOW = 33, 1024, 4096
+KV_FORMS = [None, "int8", "int4"]
+VIEWS = [256, 512, 1024]
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described (not attached) v5e chip to compile for."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip: the next run would warn and
+    compile again.  Keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(chip, fn, *shapes):
+    """Compile ``fn`` for the described chip from shapes alone and return
+    how many Mosaic kernels the program holds."""
+    args = [
+        None if s is None else jax.ShapeDtypeStruct(s[0], s[1], sharding=chip)
+        for s in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call"
+    )
+
+
+def _cache_shapes(kv, rows_axis, seq):
+    """(k, v, k_scale, v_scale) shapes of a cache in KV form ``kv`` whose
+    leading axes are ``rows_axis``."""
+    dtype = jnp.bfloat16 if kv is None else jnp.int8
+    srows = seq // 2 if kv == "int4" else seq
+    plane = (rows_axis + (srows, K, D), dtype)
+    scale = None if kv is None else (rows_axis + (seq, K), jnp.float32)
+    return plane, plane, scale, scale
+
+
+@pytest.mark.parametrize("t", [128, 1024])
+def test_flash_prefill_compiles_for_v5e(chip, t):
+    """The default whole-prompt prefill kernel — the one kernel that ever
+    ran on a chip — must stay green."""
+    n = _compile(
+        chip,
+        lambda q, k, v, valid: flash_causal_attention(
+            q, k, v, valid, window=WINDOW),
+        ((8, t, H, D), jnp.bfloat16), ((8, t, K, D), jnp.bfloat16),
+        ((8, t, K, D), jnp.bfloat16), ((8, t), jnp.bool_),
+    )
+    assert n == 1
+
+
+@pytest.mark.parametrize("view", VIEWS)
+@pytest.mark.parametrize("kv", KV_FORMS)
+def test_sgrid_decode_compiles_for_v5e(chip, kv, view):
+    k, v, ks, vs = _cache_shapes(kv, (ROWS,), view)
+    n = _compile(
+        chip,
+        lambda q, k_, v_, pos, ks_, vs_: flash_decode_attention_sgrid(
+            q, k_, v_, pos, k_scale=ks_, v_scale=vs_, kv_quant=kv,
+            window=WINDOW),
+        ((ROWS, 1, H, D), jnp.bfloat16), k, v, ((ROWS,), jnp.int32), ks, vs,
+    )
+    assert n == 1
+
+
+@pytest.mark.parametrize("view", VIEWS)
+@pytest.mark.parametrize("kv", KV_FORMS)
+def test_fused_decode_layer_compiles_for_v5e(chip, kv, view):
+    k, v, ks, vs = _cache_shapes(kv, (L, ROWS), MAX_SEQ)
+    n = _compile(
+        chip,
+        lambda q, kn, vn, k_, v_, ks_, vs_, pos, layer: fused_decode_layer(
+            q, kn, vn, k_, v_, ks_, vs_, pos, layer, kv_view=view,
+            rope_theta=1e4, kv_quant=kv, window=WINDOW),
+        ((ROWS, H, D), jnp.bfloat16), ((ROWS, K, D), jnp.bfloat16),
+        ((ROWS, K, D), jnp.bfloat16), k, v, ks, vs,
+        ((ROWS,), jnp.int32), ((), jnp.int32),
+    )
+    assert n == 1
+
+
+@pytest.mark.parametrize("view", VIEWS)
+@pytest.mark.parametrize("kv", KV_FORMS)
+def test_fused_spec_decode_layer_compiles_for_v5e(chip, kv, view):
+    t = 5  # spec_k 4: the carry token + four proposals
+    k, v, ks, vs = _cache_shapes(kv, (L, ROWS), MAX_SEQ)
+    n = _compile(
+        chip,
+        lambda q, kn, vn, k_, v_, ks_, vs_, pos, layer:
+        fused_spec_decode_layer(
+            q, kn, vn, k_, v_, ks_, vs_, pos, layer, kv_view=view,
+            rope_theta=1e4, kv_quant=kv, window=WINDOW),
+        ((ROWS, t, H, D), jnp.bfloat16), ((ROWS, t, K, D), jnp.bfloat16),
+        ((ROWS, t, K, D), jnp.bfloat16), k, v, ks, vs,
+        ((ROWS,), jnp.int32), ((), jnp.int32),
+    )
+    assert n == 1
+
+
+@pytest.mark.parametrize("kv", KV_FORMS)
+def test_ragged_prefill_compiles_for_v5e(chip, kv):
+    """block_q 16 is what the engine derives from its default page size and
+    segment width; 1024 flat tokens = prefill_rows 8 x prefill_chunk 128."""
+    block_q, tot = 16, 1024
+    nqb = tot // block_q
+    k, v, ks, vs = _cache_shapes(kv, (L, ROWS), MAX_SEQ)
+    desc = ((nqb,), jnp.int32)
+    n = _compile(
+        chip,
+        lambda q, kn, vn, k_, v_, ks_, vs_, a, b, c, d, layer:
+        ragged_prefill_attention(
+            q, kn, vn, k_, v_, ks_, vs_, a, b, c, d, layer, block_q=block_q,
+            max_row_blocks=128 // block_q, rope_theta=1e4, kv_quant=kv,
+            window=WINDOW),
+        ((tot, H, D), jnp.bfloat16), ((tot, K, D), jnp.bfloat16),
+        ((tot, K, D), jnp.bfloat16), k, v, ks, vs,
+        desc, desc, desc, desc, ((), jnp.int32),
+    )
+    assert n == 1
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="The Pallas TPU lowering currently requires that the last two "
+           "dimensions of your block shape are divisible by 8 and 128 "
+           "respectively, or be equal to the respective dimensions of the "
+           "overall array. Block spec for args[0] in pallas_call "
+           "_decode_kernel: the (1, 1) position block, and behind it the "
+           "K-squeezed cache planes.  No option reaches this kernel: it is "
+           "the interpret-mode cross-check of the s-grid family "
+           "(ROADMAP Design 2).",
+)
+def test_plane_decode_kernel_is_refused_by_the_tpu_compiler(chip):
+    k, v, _, _ = _cache_shapes(None, (ROWS,), 512)
+    _compile(
+        chip,
+        lambda q, k_, v_, pos: flash_decode_attention_plane(
+            q, k_, v_, pos, window=WINDOW),
+        ((ROWS, 1, H, D), jnp.bfloat16), k, v, ((ROWS,), jnp.int32),
+    )
+
+
+# ---------------------------------------------------------------------------
+# --replicas: one engine per device
+# ---------------------------------------------------------------------------
+
+def test_each_replica_dispatches_on_its_own_device(cpu_devices):
+    """cli.py builds replica i under ``jax.default_device(d[i])`` and then
+    commits it there.  Without the commit the arrays are uncommitted, the
+    engine loop (which runs outside that context) dispatches on device 0,
+    and the donated cache follows — four replicas on one chip."""
+    from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
+
+    def replica(i, commit):
+        with jax.default_device(cpu_devices[i]):
+            eng = InferenceEngine(engine_cfg=EngineConfig(
+                model="tiny", num_slots=2, max_seq=64, dtype="float32",
+                decode_steps=2, seed=i, prefix_cache=True,
+            ))
+        if commit:
+            eng.commit_to(cpu_devices[i])
+        return eng
+
+    for i in range(4):
+        eng = replica(i, commit=True)
+        outs, _ = eng._dispatch_decode(view=64, steps=2)
+        assert {d.id for d in outs[0].devices()} == {cpu_devices[i].id}
+        assert eng.resident_devices() == [cpu_devices[i].id]
+    # The control: what the parent commit did for every replica but the first.
+    eng = replica(3, commit=False)
+    eng._dispatch_decode(view=64, steps=2)
+    assert {d.id for d in eng.kv_cache["k"].devices()} == {cpu_devices[0].id}
+
+
+# ---------------------------------------------------------------------------
+# the compile-cache helper
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def cache_updates(monkeypatch):
+    """Record jax.config.update calls instead of moving this process's cache."""
+    calls = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, value: calls.append((name, value))
+    )
+    return calls
+
+
+def test_compile_cache_set_from_outside_sets_nothing_in_code(
+        monkeypatch, cache_updates, tmp_path):
+    from p2p_llm_tunnel_tpu.utils import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable() == str(tmp_path)
+    assert cache_updates == []  # JAX reads the variable itself
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch, cache_updates):
+    from p2p_llm_tunnel_tpu.utils import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.enable() == want
+    assert cache_updates == [("jax_compilation_cache_dir", want)]
+
+
+# ---------------------------------------------------------------------------
+# serve --backend tpu serves the TPU, or the CPU when asked
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("platform,asked,refused", [
+    ("tpu", None, False),
+    ("tpu", "cpu", False),
+    ("cpu", "cpu", False),     # the documented way to run tests and rehearsals
+    ("cpu", "cpu,tpu", False),
+    ("cpu", None, True),       # JAX fell back: say so, do not serve
+    ("cpu", "", True),
+    ("cpu", "tpu,cpu", True),
+    ("gpu", "cpu", True),
+])
+def test_require_tpu_backend(monkeypatch, platform, asked, refused):
+    from p2p_llm_tunnel_tpu.cli import require_tpu_backend
+
+    if asked is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", asked)
+    if not refused:
+        require_tpu_backend(platform, "serve --backend tpu")
+        return
+    with pytest.raises(SystemExit) as e:
+        require_tpu_backend(platform, "serve --backend tpu")
+    assert repr(platform) in str(e.value)  # names the platform it found
+
+
+def test_serve_backend_tpu_refuses_before_building_an_engine(monkeypatch):
+    """Through the CLI's own start-up path: on this CPU-only test process,
+    with no explicit JAX_PLATFORMS=cpu, ``serve --backend tpu`` exits and
+    no engine is ever constructed."""
+    import asyncio
+
+    import p2p_llm_tunnel_tpu.cli as cli_mod
+    import p2p_llm_tunnel_tpu.engine.engine as eng_mod
+
+    built = []
+    monkeypatch.setattr(
+        eng_mod, "InferenceEngine", lambda **kw: built.append(kw)
+    )
+    monkeypatch.setattr(cli_mod, "_BACKEND", None)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    args = cli_mod.build_parser().parse_args(
+        ["serve", "--room", "r", "--backend", "tpu"]
+    )
+    with pytest.raises(SystemExit, match="'cpu'"):
+        asyncio.run(cli_mod._engine_backend(args))
+    assert built == []

@@ -6,8 +6,7 @@ compilation cache to hand those executables back to the serial execute
 pass (and to live dispatch).  That only works if the AOT-lowered programs
 hash IDENTICALLY to the ones live dispatch builds — any aval drift
 (shape/dtype/static-arg mismatch in _decode_warm_args/_chunk_warm_args)
-silently doubles compile work on the serving path, which on the
-tunneled-TPU deployment costs a whole chip window (PERF.md r5).
+silently doubles compile work before the first request is served.
 
 The hash-identity proof: warm up engine A with the AOT phase ON, snapshot
 the persistent-cache file set, then warm up an identically-configured

@@ -2,8 +2,8 @@
 
 The engine's readiness contract (ISSUE 12/15): after ``warmup()`` declares
 the grid complete, a first-seen program key on the serving path is a
-MID-SERVE COLD COMPILE — tens of seconds of stall inside a live request on
-the tunneled-TPU deployment.  The runtime detector
+MID-SERVE COLD COMPILE — tens of seconds of stall inside a live request
+at 7B widths.  The runtime detector
 (``engine_cold_compiles_total``) catches the hole when traffic hits it;
 this rule is its static counterpart: every ``_program_key`` spelling an
 engine dispatch site can emit (the literal ``kind`` handed to
