@@ -1,0 +1,174 @@
+"""The traffic generator: the same seed gives the same plan, every seed
+offers the same work, and open-loop times run from the due time."""
+
+import json
+import os
+
+import pytest
+
+from benchmarks import traffic
+from benchmarks.stats import Outcome
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+MIXES = ["chat-open", "decode-closed", "docs-open"]
+SEEDS = [0, 7, 2**31 + 11]
+
+#: No cell shares documents yet (PERF.md section 7); the generator's
+#: sharing is tested on a mix of the test's own.
+DOCS_OPEN = {
+    "name": "docs-open", "loop": "open", "rate_rps": 4.0,
+    "arrivals": {"dist": "exponential"}, "lead_s": 5.0, "tail_s": 4.0,
+    "shared_prefix": {"documents": 16,
+                      "tokens": {"dist": "uniform", "min": 512, "max": 832},
+                      "popularity": {"dist": "zipf", "s": 1.0}, "warm": True},
+    "prompt_tokens": {"dist": "uniform", "min": 16, "max": 48},
+    "output_tokens": {"dist": "uniform", "min": 16, "max": 48},
+}
+
+
+def mix(name):
+    if name == "docs-open":
+        return dict(DOCS_OPEN)
+    with open(os.path.join(REPO, "benchmarks", "traffic", name + ".json")) as f:
+        return json.load(f)
+
+
+def all_requests(plan):
+    return plan.requests or [r for c in plan.clients for r in c]
+
+
+@pytest.mark.parametrize("name", MIXES)
+@pytest.mark.parametrize("seed", SEEDS)
+def test_same_seed_same_plan(name, seed):
+    a = traffic.make_plan(mix(name), seed, 48, 32000)
+    b = traffic.make_plan(mix(name), seed, 48, 32000)
+    assert [(r.due, r.prompt, r.max_tokens) for r in all_requests(a)] == \
+        [(r.due, r.prompt, r.max_tokens) for r in all_requests(b)]
+    assert [r.prompt for r in a.warm] == [r.prompt for r in b.warm]
+
+
+def in_window(plan, seconds=48):
+    return [r for r in plan.requests if 0 <= r.due < seconds]
+
+
+def shape(plan, r):
+    """A request without its words: own prompt words, output, document."""
+    own = r.prompt_words - (0 if r.document is None
+                            else plan.warm[r.document].prompt_words)
+    return (own, r.max_tokens, r.document)
+
+
+@pytest.mark.parametrize("name", ["chat-open", "docs-open"])
+def test_the_window_holds_the_same_cycle_from_another_start(name):
+    m = mix(name)
+    plans = [traffic.make_plan(m, s, 48, 32000) for s in SEEDS]
+    cycles = [[shape(p, r) for r in in_window(p)] for p in plans]
+    n = round(m["rate_rps"] * 48)
+    assert all(len(c) == n for c in cycles)
+    # the same requests, in the same cyclic order, from another start
+    doubled = cycles[0] + cycles[0]
+    for c in cycles[1:]:
+        assert sorted(c) == sorted(cycles[0])
+        at = [i for i in range(n) if doubled[i:i + n] == c]
+        assert at and c != cycles[0]
+    # ... with other words
+    assert in_window(plans[0])[0].prompt != in_window(plans[1])[0].prompt
+    # the same gaps between arrivals, cyclically
+    def gaps(p):
+        dues = [r.due for r in in_window(p)]
+        return sorted(round(b - a, 6) for a, b in zip(dues, dues[1:]))
+    assert len(set(gaps(plans[0])) & set(gaps(plans[1]))) > 0.95 * (n - 2)
+
+
+@pytest.mark.parametrize("name", ["chat-open", "docs-open"])
+def test_lead_in_and_tail_replay_the_cycle(name):
+    m = mix(name)
+    plan = traffic.make_plan(m, 3, 48, 32000)
+    dues = [r.due for r in plan.requests]
+    assert min(dues) >= -m["lead_s"] and max(dues) < 48 + m["tail_s"]
+    window = {round(r.due, 6): shape(plan, r) for r in in_window(plan)}
+    for r in plan.requests:
+        if r.due < 0:       # the end of the cycle, one lap early
+            assert window[round(r.due + 48, 6)] == shape(plan, r)
+        elif r.due >= 48:   # the start of the cycle, one lap late
+            assert window[round(r.due - 48, 6)] == shape(plan, r)
+    lead = sum(1 for d in dues if d < 0)
+    assert lead == pytest.approx(m["rate_rps"] * m["lead_s"], abs=8)
+    # every prompt of the run is its own, replayed or not
+    own = [r.prompt.split()[-4:] for r in plan.requests]
+    assert len({tuple(w) for w in own}) == len(own)
+
+
+def test_closed_loop_offers_the_same_sequences_to_other_clients():
+    m = mix("decode-closed")
+    plans = [traffic.make_plan(m, s, 48, 152064) for s in SEEDS]
+    def seqs(p):
+        return sorted([(r.prompt_words, r.max_tokens) for r in c]
+                      for c in p.clients)
+    assert seqs(plans[0]) == seqs(plans[1]) == seqs(plans[2])
+    assert [r.prompt for r in plans[0].clients[0]] != \
+        [r.prompt for r in plans[1].clients[0]]
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_lengths_respect_the_mix(name):
+    m = mix(name)
+    plan = traffic.make_plan(m, 1, 48, 32000)
+    for r in all_requests(plan):
+        assert m["output_tokens"]["min"] <= r.max_tokens <= m["output_tokens"]["max"]
+        assert len(r.prompt.split()) == r.prompt_words
+    assert plan.max_context <= 1024
+
+
+def test_lognormal_multiset_holds_median_and_tail():
+    dist = {"dist": "lognormal", "median": 192, "sigma": 0.7, "min": 32, "max": 768}
+    vals = traffic.int_multiset(dist, 201)
+    assert vals == sorted(vals)
+    assert vals[100] == 192
+    assert vals[0] == 32 and vals[-1] == 768
+
+
+@pytest.mark.parametrize("n", [10, 100, 457])
+def test_zipf_counts_are_exact(n):
+    counts = traffic.zipf_counts(16, 1.0, n)
+    assert sum(counts) == n and counts == sorted(counts, reverse=True)
+    assert counts[0] >= 2 * counts[3] - 1
+
+
+def test_documents_are_shared_and_warmed():
+    plan = traffic.make_plan(mix("docs-open"), 9, 48, 32000)
+    assert len(plan.warm) == 16
+    docs = {r.document: r.prompt for r in plan.warm}
+    for r in plan.requests:
+        assert r.prompt.startswith(docs[r.document] + " ")
+    words = sum(r.prompt_words for r in plan.warm)
+    assert 16 * 512 <= words <= 16 * 832
+
+
+def test_closed_loop_deals_every_client_a_sequence():
+    m = mix("decode-closed")
+    plan = traffic.make_plan(m, 2, 48, 152064)
+    assert len(plan.clients) == m["clients"]
+    assert all(len(c) == m["requests_per_client"] for c in plan.clients)
+    assert len({r.index for c in plan.clients for r in c}) == \
+        m["clients"] * m["requests_per_client"]
+    assert plan.client_starts[0] == -m["lead_s"]
+    assert all(-m["lead_s"] <= s < 0 for s in plan.client_starts)
+    assert len(set(plan.client_starts)) == m["clients"]
+
+
+def test_open_loop_times_run_from_the_due_time():
+    o = Outcome(0, due=100.0, asked=8)
+    o.sent, o.first_token = 100.4, 101.0
+    assert o.ttft_ms() == pytest.approx(1000.0)
+    assert o.late_ms() == pytest.approx(400.0)
+
+
+def test_unknown_loop_and_distribution_are_errors():
+    m = dict(mix("chat-open"), loop="spiral")
+    with pytest.raises(ValueError):
+        traffic.make_plan(m, 1, 10, 32000)
+    with pytest.raises(ValueError):
+        traffic.multiset({"dist": "cauchy"}, 4)
+    with pytest.raises(ValueError):
+        traffic.multiset({"dist": "gamma", "cv": 2.0}, 4)
