@@ -714,11 +714,16 @@ async def _send_healthz(
     # WHY the peer is not-ok (ISSUE 12 satellite): the fabric routes
     # around degraded peers, and without a reason the routing decision is
     # unexplainable from the proxy.  Priority order mirrors the status
-    # computation: a drain beats a watchdog trip beats an SLO burn.
+    # computation: a drain beats an engine verdict beats an SLO burn.  The
+    # engine says which of its two detectors raised engine_degraded: the
+    # decode-stall watchdog ("stall") or the memory-thrash detector
+    # ("memory"); a gauge raised with no reason published reads as a stall.
     if draining:
         reason = "drain"
     elif global_metrics.gauge("engine_degraded") > 0:
-        reason = "watchdog"
+        reason = str(
+            global_metrics.info("engine_degraded_reason", "") or "stall"
+        )
     elif slo_section["alerting"]:
         reason = "slo"
     else:
@@ -805,9 +810,13 @@ async def _send_healthz(
         # the proxy's federated /healthz view.
         # ``attention``: which implementation (Pallas kernel or einsum)
         # each program family that ran took — the gates pick per shape.
+        # ``quant`` / ``kv_quant``: the weight and cache types the engine
+        # was built with (null under backends with no engine).
         "config": {
             "fences": global_metrics.info("config_fences", []) or [],
             "attention": global_metrics.info("attention_branches", {}) or {},
+            "quant": global_metrics.info("config_quant"),
+            "kv_quant": global_metrics.info("config_kv_quant"),
         },
         # What JAX runs on in THIS process and what each local device
         # holds — the only place a JAX-free parent (chip_smoke.py, a load
@@ -1202,7 +1211,7 @@ async def _serve_dispatch(
                     # The span journal as Chrome trace-event JSON — load
                     # in chrome://tracing / Perfetto, or summarize with
                     # scripts/traceview.py.  The engine flight recorder's
-                    # slice/counter tracks ride the same export (ISSUE
+                    # slices ride the same export (ISSUE
                     # 12): one journal, so the fleet stitcher gives every
                     # peer its own engine-flight lane for free.
                     trace = global_tracer.chrome_trace()

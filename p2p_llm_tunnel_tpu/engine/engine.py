@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import contextlib
 import functools
 import os
 import time
@@ -394,6 +395,47 @@ class _ActiveRequest:
     trace_span: Optional[str] = None
     t_parked: Optional[float] = None
     finish: Optional[str] = None
+    # What engine.prefill_exec says of the request's prefill: prompt tokens
+    # reused from the pool, prefill dispatches that carried its rows
+    # (counted as their engine.prefill_part spans close), and the loop
+    # iteration that admitted it.
+    cached_tokens: int = 0
+    parts: int = 0
+    iter_admitted: int = 0
+
+
+class _Dispatch:
+    """One open dispatch record; built only while the span journal is on.
+
+    ``span`` names the engine-scope span it closes as and the profiler
+    annotation around the dispatch call; ``attrs`` carry what the dispatch
+    did (``seq``, ``program``, real and padded work); ``parts`` is, for a
+    prefill dispatch, each row's (request id, tokens, start, final)."""
+
+    __slots__ = ("span", "t0", "attrs", "parts")
+
+    def __init__(self, span: str, attrs: Dict[str, object], parts=None):
+        self.span = span
+        self.t0 = time.monotonic()
+        self.attrs = attrs
+        self.parts = parts
+
+    def annotation(self):
+        """The record on the device trace's host plane: it records nothing
+        unless a profile is running, and then carries ``seq`` (the join
+        key to the journal) and ``mono_us`` (this process's monotonic
+        clock at the dispatch, from which a reader fits the offset between
+        the journal's clock and the trace's)."""
+        work = {k: self.attrs[k] for k in ("steps", "tokens")
+                if k in self.attrs}
+        return jax.profiler.TraceAnnotation(
+            self.span, seq=self.attrs["seq"], mono_us=int(self.t0 * 1e6),
+            **work,
+        )
+
+
+#: What a dispatch call is wrapped in while the span journal is off.
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
 class InferenceEngine:
@@ -549,7 +591,7 @@ class InferenceEngine:
         self._prefix_published: Dict[str, int] = {}
         # Memory-degradation state (ISSUE 16), initialised BEFORE the
         # prefix block below publishes its first gauges: why
-        # engine_degraded is set ("watchdog" | "memory" | "" — the
+        # engine_degraded is set ("stall" | "memory" | "" — the
         # watchdog's progress-clear only touches its own reason), the
         # thrash detector's sliding window of (evict, realloc) deltas,
         # and the in-flight tier-I/O ledger the leak gate reads.
@@ -892,6 +934,10 @@ class InferenceEngine:
         # engine per process is the deployed shape, same contract as the
         # blackbox engine provider).
         global_metrics.set_info("config_fences", list(self.config_fences))
+        # ... and the precision the engine was built with (after fencing):
+        # a client holding the deployment to its stated types reads it here.
+        global_metrics.set_info("config_quant", self.ecfg.quant)
+        global_metrics.set_info("config_kv_quant", self.ecfg.kv_quant)
 
         # Prefill may run a hotter quant mode than decode (prefill_act_quant):
         # a separate static config for the prefill program only.
@@ -991,6 +1037,16 @@ class InferenceEngine:
         # record.  Plain assignments only — no read-modify-write straddles
         # an await (TC13).
         self._last_mux: Dict[str, object] = {}
+        # The dispatch ledger (tracing on only): a per-engine sequence
+        # number over every device dispatch of the loop, and the record the
+        # most recent dispatch opened — stashed by the dispatch method
+        # (executor thread) for the loop to pick up once the executor call
+        # it awaited has returned, as _last_burst is.
+        self._dispatch_seq = 0
+        self._last_dispatch: Optional[_Dispatch] = None
+        # Non-idle loop iterations so far (engine.prefill_exec's
+        # ``iterations`` is a difference of two readings).
+        self._loop_iter = 0
         self._flight_admitted = 0
         self._flight_conv = 0
         self._flight_pageouts = 0
@@ -1209,18 +1265,20 @@ class InferenceEngine:
             # key=None: sampling randomness is the per-request (seed, pos)
             # stream — the burst key no longer feeds it (and the old split
             # per step was dead weight XLA DCE'd anyway).
-            sampled = sampling.sample(logits, samp, None, counts=cnt,
-                                      pos=pos + 1, bias=bias)
-            cnt = jax.lax.cond(
-                any_pen,
-                lambda: cnt.at[jnp.arange(b), sampled].add(1),
-                lambda: cnt,
-            )
-            lp = jax.lax.cond(
-                any_lp,
-                lambda: sampling.logprob_data(logits, sampled),
-                lambda: sampling.empty_logprob_data(b, logits.shape[-1]),
-            )
+            with jax.named_scope("head_sample"):
+                sampled = sampling.sample(logits, samp, None, counts=cnt,
+                                          pos=pos + 1, bias=bias)
+                cnt = jax.lax.cond(
+                    any_pen,
+                    lambda: cnt.at[jnp.arange(b), sampled].add(1),
+                    lambda: cnt,
+                )
+                lp = jax.lax.cond(
+                    any_lp,
+                    lambda: sampling.logprob_data(logits, sampled),
+                    lambda: sampling.empty_logprob_data(
+                        b, logits.shape[-1]),
+                )
             return (sampled, pos + 1, cnt, cache), (sampled, lp)
 
         (tokens, positions, counts, kv_cache), (toks, lps) = jax.lax.scan(
@@ -1279,14 +1337,15 @@ class InferenceEngine:
             self._prefill_mcfg, params, tokens, lengths, starts, kv_cache,
             slots, kv_view=kv_view,
         )
-        first = sampling.sample(last_logits, samp, key, pos=starts + lengths,
-                                bias=bias[slots])
-        lp = jax.lax.cond(
-            jnp.any(samp.logprobs > 0),
-            lambda: sampling.logprob_data(last_logits, first),
-            lambda: sampling.empty_logprob_data(
-                first.shape[0], last_logits.shape[-1]),
-        )
+        with jax.named_scope("head_sample"):
+            first = sampling.sample(last_logits, samp, key,
+                                    pos=starts + lengths, bias=bias[slots])
+            lp = jax.lax.cond(
+                jnp.any(samp.logprobs > 0),
+                lambda: sampling.logprob_data(last_logits, first),
+                lambda: sampling.empty_logprob_data(
+                    first.shape[0], last_logits.shape[-1]),
+            )
         return first, lp, kv_cache
 
     def _ragged_prefill_fn(
@@ -1418,9 +1477,9 @@ class InferenceEngine:
                     )
                     global_metrics.inc("engine_watchdog_stalls_total")
                     self.degraded = True
-                    self.degraded_reason = "watchdog"  # tunnelcheck: disable=TC13  reason ownership protocol: watchdog writes only on the not-degraded -> degraded edge it just took; "memory" trips/clears are owned by the loop's _thrash_tick hysteresis and never race this branch
+                    self.degraded_reason = "stall"  # tunnelcheck: disable=TC13  reason ownership protocol: watchdog writes only on the not-degraded -> degraded edge it just took; "memory" trips/clears are owned by the loop's _thrash_tick hysteresis and never race this branch
                     global_metrics.set_info(
-                        "engine_degraded_reason", "watchdog"
+                        "engine_degraded_reason", "stall"
                     )
                     global_metrics.set_gauge("engine_degraded", 1.0)
                     # Postmortem black box: snapshot the engine AT the
@@ -1429,8 +1488,8 @@ class InferenceEngine:
                     # raises past its own logging).
                     global_blackbox.capture("watchdog", attribution=phase)
             elif (self.degraded and not stalled
-                    and self.degraded_reason == "watchdog"):
-                # Progress only clears a WATCHDOG degradation: a memory
+                    and self.degraded_reason == "stall"):
+                # Progress only clears a STALL degradation: a memory
                 # trip (ISSUE 16) is owned by the thrash detector's own
                 # hysteresis — tokens still flow while the pool thrashes,
                 # so "a token landed" proves nothing about memory health.
@@ -1461,6 +1520,13 @@ class InferenceEngine:
                 except asyncio.CancelledError:
                     pass
                 self._watchdog_task = None
+            if self.degraded:
+                # The verdict in the process-wide registry was this
+                # engine's, and nothing is left running that would clear
+                # it: a later engine of the process, or a bare run_serve,
+                # must not inherit "degraded" from an engine that stopped.
+                global_metrics.set_gauge("engine_degraded", 0.0)
+                global_metrics.set_info("engine_degraded_reason", "")
             if self._task is not None:
                 try:
                     await self._task
@@ -2550,6 +2616,15 @@ class InferenceEngine:
                         "engine.prefill_exec", trace_id=tid,
                         parent_id=state.trace_span, track="engine",
                         t0=state.t_admitted, t1=state.first_token_at,
+                        attrs={
+                            "prompt_tokens": len(run.request.prompt_ids),
+                            "cached_tokens": state.cached_tokens,
+                            "parts": state.parts,
+                            # 1: the iteration that admitted it also
+                            # fetched its first token
+                            "iterations": (self._loop_iter
+                                           - state.iter_admitted + 1),
+                        },
                     )
                 global_tracer.add_event(
                     "engine.first_token", trace_id=tid,
@@ -2590,6 +2665,75 @@ class InferenceEngine:
         while b < n:
             b *= 2
         return min(b, self.ecfg.max_seq)
+
+    def _open_dispatch(self, span: str, program: str, parts=None,
+                       **work) -> _Dispatch:
+        """Open the record of the dispatch about to be made (executor
+        thread).  Callers test ``global_tracer.enabled`` first: with the
+        journal off no record, attrs dict or parts list is built."""
+        self._dispatch_seq += 1
+        return _Dispatch(
+            span, dict(work, seq=self._dispatch_seq, program=program), parts
+        )
+
+    def _open_prefill_dispatch(self, program: str, rows, nb: int,
+                               positions: int, **key) -> _Dispatch:
+        """A prefill dispatch's record: ``rows`` is [(run, start, segment
+        ids, final?)] in row order (``start``: the cache position the
+        segment begins at), ``nb`` the rows dispatched (padding included)
+        and ``positions`` the token positions the program runs over."""
+        return self._open_dispatch(
+            "engine.prefill_segment", program,
+            parts=[(run.request.request_id, len(seg), start, final)
+                   for run, start, seg, final in rows],
+            rows=len(rows), rows_padded=nb,
+            tokens=sum(len(seg) for _r, _s, seg, _f in rows),
+            positions=positions, **key,
+        )
+
+    def _open_pool_copy(self, program: str, entries) -> _Dispatch:
+        """A batched pool copy's record: ``entries`` is the sub-batch's
+        [(slot, pool ids, block numbers)], padded by the copy program to
+        ``prefill_rows`` rows of ``_prefix_max_blocks`` blocks."""
+        pr = self.ecfg.prefill_rows
+        return self._open_dispatch(
+            "engine.pool_copy", program, rows=len(entries), rows_padded=pr,
+            blocks=sum(len(ids) for _slot, ids, _bnos in entries),
+            blocks_padded=pr * self._prefix_max_blocks,
+        )
+
+    def _close_pool_copy(self, rec: Optional[_Dispatch]) -> None:
+        """Nothing of a pool copy is fetched: its record ends where the
+        dispatch call returns."""
+        if rec is not None:
+            global_tracer.add_span(
+                "engine.pool_copy", trace_id=None, track="engine-loop",
+                t0=rec.t0, attrs=rec.attrs,
+            )
+
+    def _close_prefill_dispatch(self, rec: Optional[_Dispatch]) -> None:
+        """The sampled block of a prefill dispatch is on the host: its
+        engine-scope record, and over the same interval one
+        ``engine.prefill_part`` for each traced request with rows in it."""
+        if rec is None:
+            return
+        t1 = time.monotonic()
+        global_tracer.add_span(
+            "engine.prefill_segment", trace_id=None, track="engine-loop",
+            t0=rec.t0, t1=t1, attrs=rec.attrs,
+        )
+        for rid, tokens, start, final in rec.parts:
+            state = self._requests.get(rid)
+            if state is None or state.trace is None:
+                continue
+            state.parts += 1
+            global_tracer.add_span(
+                "engine.prefill_part", trace_id=state.trace.trace_id,
+                parent_id=state.trace_span, track="engine",
+                t0=rec.t0, t1=t1,
+                attrs={"seq": rec.attrs["seq"], "tokens": tokens,
+                       "start": start, "final": final},
+            )
 
     def _dispatch_prefill_batch(
         self, runs: List[RunningSlot], t: int,
@@ -2655,34 +2799,41 @@ class InferenceEngine:
             seed=jnp.asarray(seeds),
             bias_on=jnp.asarray(bias_on),
         )
+        rec = self._last_dispatch = self._open_prefill_dispatch(
+            "prefill_echo" if echo else "prefill",
+            [(run, 0, run.request.prompt_ids, True) for run in runs],
+            nb, nb * t, t=t,
+        ) if global_tracer.enabled else None
         t_jit0 = time.monotonic()
-        if echo:
-            first, lp, plp, self.kv_cache = self._jit_prefill(
-                self.params,
-                self.kv_cache,
-                self._bias,
-                jnp.asarray(tokens),
-                jnp.asarray(lengths),
-                jnp.asarray(slots),
-                samp,
-                self._next_key(),
-                True,
-            )
-        else:
-            plp = None
-            first, lp, self.kv_cache = self._jit_prefill(
-                self.params,
-                self.kv_cache,
-                self._bias,
-                jnp.asarray(tokens),
-                jnp.asarray(lengths),
-                jnp.asarray(slots),
-                samp,
-                self._next_key(),
-            )
+        with rec.annotation() if rec else _NO_ANNOTATION:
+            if echo:
+                first, lp, plp, self.kv_cache = self._jit_prefill(
+                    self.params,
+                    self.kv_cache,
+                    self._bias,
+                    jnp.asarray(tokens),
+                    jnp.asarray(lengths),
+                    jnp.asarray(slots),
+                    samp,
+                    self._next_key(),
+                    True,
+                )
+            else:
+                plp = None
+                first, lp, self.kv_cache = self._jit_prefill(
+                    self.params,
+                    self.kv_cache,
+                    self._bias,
+                    jnp.asarray(tokens),
+                    jnp.asarray(lengths),
+                    jnp.asarray(slots),
+                    samp,
+                    self._next_key(),
+                )
         self._note_program("prefill_echo" if echo else "prefill", (t,),  # tunnelcheck: disable=TC17  echo/scoring prefill is an explicitly-requested eval feature compiled on FIRST USE by design (_prefill_fn docstring) — never on the default serving path, so warming its [t] grid would bill every cold start for a feature most deploys never invoke
                            time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
+        global_metrics.inc("engine_prefill_positions_total", nb * t)
         out = first, (lp if lps.any() else None), plp
         self._start_host_copy(out)
         return out
@@ -2764,21 +2915,26 @@ class InferenceEngine:
         # attention read cost of an admission tracks the live context, not
         # max_seq (VERDICT r4 item 7).
         view = self._chunk_view_bucket(int(starts.max()) + t)
+        rec = self._last_dispatch = self._open_prefill_dispatch(
+            "chunk_prefill", rows, nb, nb * t, t=t, view=view,
+        ) if global_tracer.enabled else None
         t_jit0 = time.monotonic()
-        first, lp, self.kv_cache = self._jit_chunk_prefill(
-            self.params,
-            self.kv_cache,
-            self._bias,
-            jnp.asarray(tokens),
-            jnp.asarray(lengths),
-            jnp.asarray(starts),
-            jnp.asarray(slots),
-            samp,
-            self._next_key(),
-            view,
-        )
+        with rec.annotation() if rec else _NO_ANNOTATION:
+            first, lp, self.kv_cache = self._jit_chunk_prefill(
+                self.params,
+                self.kv_cache,
+                self._bias,
+                jnp.asarray(tokens),
+                jnp.asarray(lengths),
+                jnp.asarray(starts),
+                jnp.asarray(slots),
+                samp,
+                self._next_key(),
+                view,
+            )
         self._note_program("chunk", (t, view), time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
+        global_metrics.inc("engine_prefill_positions_total", nb * t)
         out = first, (lp if lps.any() else None), None
         self._start_host_copy(out)
         return out
@@ -2853,24 +3009,29 @@ class InferenceEngine:
             seed=jnp.asarray(seeds),
             bias_on=jnp.asarray(bias_on),
         )
+        rec = self._last_dispatch = self._open_prefill_dispatch(
+            "ragged_prefill", rows, nb, tot, t=tot,
+        ) if global_tracer.enabled else None
         t_jit0 = time.monotonic()
-        first, lp, self.kv_cache = self._jit_ragged(
-            self.params,
-            self.kv_cache,
-            self._bias,
-            jnp.asarray(tokens),
-            jnp.asarray(slot_of),
-            jnp.asarray(start_of),
-            jnp.asarray(qoff_of),
-            jnp.asarray(base_of),
-            jnp.asarray(sample_idx),
-            jnp.asarray(samp_pos),
-            jnp.asarray(slots),
-            samp,
-            self._next_key(),
-        )
+        with rec.annotation() if rec else _NO_ANNOTATION:
+            first, lp, self.kv_cache = self._jit_ragged(
+                self.params,
+                self.kv_cache,
+                self._bias,
+                jnp.asarray(tokens),
+                jnp.asarray(slot_of),
+                jnp.asarray(start_of),
+                jnp.asarray(qoff_of),
+                jnp.asarray(base_of),
+                jnp.asarray(sample_idx),
+                jnp.asarray(samp_pos),
+                jnp.asarray(slots),
+                samp,
+                self._next_key(),
+            )
         self._note_program("ragged", (tot,), time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
+        global_metrics.inc("engine_prefill_positions_total", tot)
         out = first, (lp if lps.any() else None), None
         self._start_host_copy(out)
         return out
@@ -2987,30 +3148,41 @@ class InferenceEngine:
         ov_pos = np.where(inactive, park, self._positions)
         view = self._kv_view_bucket() if view is None else view
         steps = self._burst_steps() if steps is None else steps
+        slots = self.ecfg.num_slots
+        live = int(np.count_nonzero(active[:slots]))
+        # (warm-up's dummy bursts are no work of the loop's: not counted)
+        rec = self._last_dispatch = self._open_dispatch(
+            "engine.decode_burst", "decode", view=view, steps=steps,
+            live_rows=live, slots=slots,
+        ) if global_tracer.enabled and not self._warming else None
         t_jit0 = time.monotonic()
-        (sampled, lp_out, self._dev_tokens, self._dev_positions,
-         self._dev_counts, self.kv_cache) = self._jit_decode(
-            self.params,
-            self.kv_cache,
-            self._dev_tokens,
-            self._dev_positions,
-            self._dev_counts,
-            self._bias,
-            jnp.array(ov_mask),
-            jnp.array(self._last_token),
-            jnp.array(ov_pos),
-            samp,
-            self._next_key(),
-            view,
-            steps,
-        )
+        with rec.annotation() if rec else _NO_ANNOTATION:
+            (sampled, lp_out, self._dev_tokens, self._dev_positions,
+             self._dev_counts, self.kv_cache) = self._jit_decode(
+                self.params,
+                self.kv_cache,
+                self._dev_tokens,
+                self._dev_positions,
+                self._dev_counts,
+                self._bias,
+                jnp.array(ov_mask),
+                jnp.array(self._last_token),
+                jnp.array(ov_pos),
+                samp,
+                self._next_key(),
+                view,
+                steps,
+            )
         # First hit of a (view, steps) pair = trace+compile inside that
         # call wall; after warmup that is a grid hole (ISSUE 12).
         self._note_program("decode", (view, steps),
                            time.monotonic() - t_jit0)
-        self._last_burst = (
-            steps, int(np.count_nonzero(active[: self.ecfg.num_slots]))
-        )
+        self._last_burst = (steps, live)
+        if not self._warming:
+            global_metrics.inc("engine_decode_steps_total", steps)
+            global_metrics.inc("engine_decode_row_steps_total", live * steps)
+            global_metrics.inc("engine_decode_slot_steps_total",
+                               slots * steps)
         self._ov_mask[:] = False  # patch consumed by this dispatch
         # Rows must ALSO have been active at dispatch time to be accounted:
         # a chunk-prefilling slot holds its request-id long before its
@@ -3429,9 +3601,13 @@ class InferenceEngine:
             slots, pids, bnos = pad_rows(
                 entries, pr, self._prefix_max_blocks, scratch=None
             )
-            self.kv_cache = self._copy_in(  # tunnelcheck: disable=TC07  ONE dispatch per prefill_rows-wide sub-batch: this batching IS the r5 fix
-                self.kv_cache, self._pool, slots, pids, bnos
-            )
+            rec = (self._open_pool_copy("pool_to_cache", entries)
+                   if global_tracer.enabled else None)
+            with rec.annotation() if rec else _NO_ANNOTATION:
+                self.kv_cache = self._copy_in(  # tunnelcheck: disable=TC07  ONE dispatch per prefill_rows-wide sub-batch: this batching IS the r5 fix
+                    self.kv_cache, self._pool, slots, pids, bnos
+                )
+            self._close_pool_copy(rec)
 
     def _prefix_insert(self, runs: List[RunningSlot]) -> None:
         """Save the runs' now-prefilled, not-yet-pooled prompt blocks into
@@ -3455,9 +3631,14 @@ class InferenceEngine:
                 entries[lo : lo + pr], pr, self._prefix_max_blocks,
                 scratch=0,
             )
-            self._pool = self._copy_out(  # tunnelcheck: disable=TC07  ONE dispatch per prefill_rows-wide sub-batch, off the TTFT-critical path
-                self._pool, self.kv_cache, slots, pids, bnos
-            )
+            rec = (self._open_pool_copy("cache_to_pool",
+                                        entries[lo : lo + pr])
+                   if global_tracer.enabled else None)
+            with rec.annotation() if rec else _NO_ANNOTATION:
+                self._pool = self._copy_out(  # tunnelcheck: disable=TC07  ONE dispatch per prefill_rows-wide sub-batch, off the TTFT-critical path
+                    self._pool, self.kv_cache, slots, pids, bnos
+                )
+            self._close_pool_copy(rec)
         if total:
             global_metrics.inc("engine_prefix_saved_blocks_total", total)
 
@@ -3495,9 +3676,17 @@ class InferenceEngine:
             st = self._requests.get(run.request.request_id)
             if st is not None and st.t_admitted is None:
                 st.t_admitted = now
+                st.iter_admitted = self._loop_iter
                 global_metrics.observe(
                     "engine_queue_wait_ms", (now - st.t_submit) * 1000.0
                 )
+
+    def _note_cached_tokens(self, run: RunningSlot, hist: int) -> None:
+        """The request reuses ``hist`` prompt tokens from the pool (what
+        engine.prefill_exec's ``cached_tokens`` reports)."""
+        state = self._requests.get(run.request.request_id)
+        if state is not None:
+            state.cached_tokens = hist
 
     async def _dispatch_plain_waves(
         self, loop, admitted: List[RunningSlot]
@@ -3537,6 +3726,7 @@ class InferenceEngine:
                         global_metrics.inc(
                             "engine_prefix_hit_tokens_total", hist
                         )
+                        self._note_cached_tokens(run, hist)
                     self._segmented[run.slot] = (run, hist)
                     admitted.remove(run)
             if seg_hits:
@@ -3556,6 +3746,7 @@ class InferenceEngine:
                 hist = hist_of[run.slot] = 0
             if hist:
                 global_metrics.inc("engine_prefix_hit_tokens_total", hist)
+                self._note_cached_tokens(run, hist)
             t = self._bucket(len(run.request.prompt_ids) - hist)
             echo = bool(run.request.echo_logprobs)
             groups.setdefault((t, hist > 0, echo), []).append(run)
@@ -3577,14 +3768,15 @@ class InferenceEngine:
                 self._executor, self._dispatch_prefill_batch, runs, t, hists,
                 echo,
             )
-            dispatched.append((runs, first_dev, t0))
+            dispatched.append((runs, first_dev, t0, self._last_dispatch))
         inserts: List[RunningSlot] = []
-        for runs, first_dev, t0 in dispatched:
+        for runs, first_dev, t0, rec in dispatched:
             firsts, lp, plp = await loop.run_in_executor(
                 self._executor,
                 lambda fd=first_dev: jax.tree.map(np.asarray,
                                                   jax.device_get(fd)),  # tunnelcheck: disable=TC07  one FETCH per already-dispatched chunk, in dispatch order: the pipelining that overlaps the RTT with compute
             )
+            self._close_prefill_dispatch(rec)
             # Wall time of this chunk's dispatch → result-on-host span, the
             # per-phase timing SURVEY §5 asks for (overlaps siblings').
             wall_ms = (time.monotonic() - t0) * 1000.0
@@ -3713,6 +3905,8 @@ class InferenceEngine:
             if hist:
                 hits.append((run.slot, pool_ids))
                 global_metrics.inc("engine_prefix_hit_tokens_total", hist)
+                if state is not None:
+                    state.cached_tokens = hist
             self._segmented[run.slot] = (run, hist)
         if hits:
             # Dispatched before any of the wave's segments (same executor,
@@ -3833,9 +4027,10 @@ class InferenceEngine:
         iteration's ``max_rows`` budget under mux, whichever is smaller)
         by ONE segment each, as one chunk-prefill call (executor thread).
 
-        Returns (rows, first_dev, t_dispatch, n_tokens) where rows is
-        [(run, was_final)] in row order and n_tokens counts REAL segment
-        tokens, or None when nothing is pending.  Every segment pads to the
+        Returns (rows, first_dev, t_dispatch, n_tokens, record) where rows
+        is [(run, was_final)] in row order, n_tokens counts REAL segment
+        tokens and record is the dispatch's open ledger record (None with
+        tracing off), or None when nothing is pending.  Every segment pads to the
         same ``prefill_chunk`` bucket — one compiled program; a final
         (short) segment's pad positions write junk KV past the prompt end,
         which decode overwrites before it ever becomes attendable (the
@@ -3875,11 +4070,11 @@ class InferenceEngine:
         t_dispatch = time.monotonic()
         first_lp = self._dispatch_chunk_rows(chunk_rows, chunk)
         global_metrics.inc("engine_prefill_segments_total", len(rows))
-        return rows, first_lp, t_dispatch, n_tokens
+        return rows, first_lp, t_dispatch, n_tokens, self._last_dispatch
 
     async def _finish_segments(self, loop, seg) -> None:
         """Fetch a segment dispatch's sampled block; activate final rows."""
-        rows, first_dev, t_dispatch, n_tokens = seg
+        rows, first_dev, t_dispatch, n_tokens, rec = seg
         firsts, lp, _plp = await loop.run_in_executor(
             self._executor,
             lambda: jax.tree.map(np.asarray, jax.device_get(first_dev)),
@@ -3891,15 +4086,7 @@ class InferenceEngine:
         self._note_prefill_cost(
             n_tokens, (time.monotonic() - t_dispatch) * 1000.0,
         )
-        if global_tracer.enabled:
-            # Engine-scope timeline row (no trace id): one span per
-            # chunked-prefill sub-batch, dispatch -> sampled block on host.
-            global_tracer.add_span(
-                "engine.prefill_segment", trace_id=None, track="engine-loop",
-                t0=t_dispatch,
-                attrs={"rows": len(rows),
-                       "final": sum(1 for _r, f in rows if f)},
-            )
+        self._close_prefill_dispatch(rec)
         inserts: List[RunningSlot] = []
         for i, ((run, final), first) in enumerate(
             zip(rows, firsts[: len(rows)])
@@ -3917,17 +4104,17 @@ class InferenceEngine:
             )
             self._release_pages_for(inserts)
 
-    def _trace_burst(self, t_dispatch: float, assign: List) -> None:
-        """Engine-scope decode-burst span: dispatch -> fetched block
-        processed.  Overlapping by construction (burst n+1 dispatches
-        before burst n is fetched) — the Chrome view shows the pipelining
-        directly.  Pure host bookkeeping, skipped when tracing is off."""
-        if not global_tracer.enabled:
+    def _trace_burst(self, rec: Optional[_Dispatch]) -> None:
+        """Close a decode burst's dispatch record: dispatch -> fetched
+        block processed.  Overlapping by construction (burst n+1
+        dispatches before burst n is fetched) — the Chrome view shows the
+        pipelining directly.  ``rec`` is None when the burst was dispatched
+        with tracing off."""
+        if rec is None:
             return
         global_tracer.add_span(
             "engine.decode_burst", trace_id=None, track="engine-loop",
-            t0=t_dispatch,
-            attrs={"rows": sum(1 for a in assign if a is not None)},
+            t0=rec.t0, attrs=rec.attrs,
         )
 
     def _fence(self, knob: str, off, reason: str) -> None:
@@ -4008,9 +4195,14 @@ class InferenceEngine:
                 entries[lo : lo + pr], pr, self._prefix_max_blocks,
                 scratch=0,
             )
-            self._pool = self._copy_out(  # tunnelcheck: disable=TC07  ONE dispatch per prefill_rows-wide sub-batch, off the TTFT-critical path (end of iteration)
-                self._pool, self.kv_cache, slots, pids, bnos
-            )
+            rec = (self._open_pool_copy("cache_to_pool",
+                                        entries[lo : lo + pr])
+                   if global_tracer.enabled else None)
+            with rec.annotation() if rec else _NO_ANNOTATION:
+                self._pool = self._copy_out(  # tunnelcheck: disable=TC07  ONE dispatch per prefill_rows-wide sub-batch, off the TTFT-critical path (end of iteration)
+                    self._pool, self.kv_cache, slots, pids, bnos
+                )
+            self._close_pool_copy(rec)
         if total:
             global_metrics.inc("engine_conv_saved_pages_total", total)
             global_metrics.inc("engine_prefix_saved_blocks_total", total)
@@ -4716,7 +4908,7 @@ class InferenceEngine:
         # (found the hard way: a shape bug in a new sampler input)
         # strands all generate() callers on a queue nobody will feed.
         try:
-            # (sampled device array, request-id snapshot, dispatch instant)
+            # (sampled device array, request-id snapshot, dispatch record)
             in_flight = None
             while self._running:
                 if self.scheduler.idle and in_flight is None:
@@ -4732,12 +4924,17 @@ class InferenceEngine:
                         await asyncio.wait_for(self._wake.wait(), timeout=0.5)
                     except asyncio.TimeoutError:
                         continue
+                    # Woken by a submission: its budget starts now, not at
+                    # the idle tick up to half a second (or one blocked
+                    # event loop) ago.
+                    self._last_progress = time.monotonic()
                     continue
 
                 # Flight recorder (ISSUE 12): per-iteration scratch reset +
                 # phase markers.  A wedged dispatch leaves the phase at the
                 # stalled step — the watchdog's attribution.
                 it_t0 = time.monotonic()
+                self._loop_iter += 1
                 self._flight_admitted = 0  # tunnelcheck: disable=TC13  single-writer contract: only THIS loop task and the admission helpers it awaits touch the per-iteration flight scratch; the reset-here/accumulate-in-_note_admission/read-at-record sequence cannot interleave with another writer
                 self._flight_conv = 0
                 self._flight_pageouts = 0
@@ -4818,14 +5015,14 @@ class InferenceEngine:
                     # plain burst first (mode switch mid-stream).
                     global_flight.set_phase("decode_fetch")
                     if in_flight is not None:
-                        outs_dev, assign, t_disp = in_flight
+                        outs_dev, assign, burst_rec = in_flight
                         outs = await loop.run_in_executor(
                             self._executor,
                             lambda: jax.tree.map(
                                 np.asarray, jax.device_get(outs_dev)),
                         )
                         await self._process_burst(outs, assign)
-                        self._trace_burst(t_disp, assign)
+                        self._trace_burst(burst_rec)
                         in_flight = None
                     global_flight.set_phase("decode_dispatch")
                     spec_out, spec_assign = await loop.run_in_executor(
@@ -4856,15 +5053,14 @@ class InferenceEngine:
                 current = None
                 global_flight.set_phase("decode_dispatch")
                 if any(self._active_mask):
-                    t_disp0 = time.monotonic()
                     outs_dev0, assign0 = await loop.run_in_executor(
                         self._executor, self._dispatch_decode
                     )
-                    current = (outs_dev0, assign0, t_disp0)
+                    current = (outs_dev0, assign0, self._last_dispatch)
                 t_dispatch = time.monotonic()
                 global_flight.set_phase("decode_fetch")
                 if in_flight is not None:
-                    outs_dev, assign, t_disp = in_flight
+                    outs_dev, assign, burst_rec = in_flight
                     t0 = time.monotonic()
                     outs = await loop.run_in_executor(
                         self._executor,
@@ -4879,7 +5075,7 @@ class InferenceEngine:
                     t_fetch = time.monotonic()
                     global_flight.set_phase("process")
                     await self._process_burst(outs, assign)
-                    self._trace_burst(t_disp, assign)
+                    self._trace_burst(burst_rec)
                 else:
                     t_fetch = t_dispatch
                 global_flight.set_phase("segments")

@@ -980,14 +980,15 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
         row — duplicate scatters write identical bytes, so order cannot
         matter."""
         out = dict(cache)
-        for key, arr in cache.items():
-            unit = block // 2 if key in packed_keys else block
-            pos = _pos(unit, blk_nos)
-            vals = pool[key][:, pool_ids]  # [L, R, Nmax, unit, ...]
-            flat = vals.reshape(
-                (vals.shape[0], rows, pos.shape[1]) + vals.shape[4:]
-            )
-            out[key] = arr.at[:, slots[:, None], pos].set(flat)
+        with jax.named_scope("pool_copy"):
+            for key, arr in cache.items():
+                unit = block // 2 if key in packed_keys else block
+                pos = _pos(unit, blk_nos)
+                vals = pool[key][:, pool_ids]  # [L, R, Nmax, unit, ...]
+                flat = vals.reshape(
+                    (vals.shape[0], rows, pos.shape[1]) + vals.shape[4:]
+                )
+                out[key] = arr.at[:, slots[:, None], pos].set(flat)
         return out
 
     def cache_to_pool(pool, cache, slots, pool_ids, blk_nos):
@@ -998,14 +999,15 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
         different contents to one live block."""
         flat_ids = pool_ids.reshape(-1)
         out = dict(pool)
-        for key, arr in pool.items():
-            unit = block // 2 if key in packed_keys else block
-            pos = _pos(unit, blk_nos)
-            vals = cache[key][:, slots[:, None], pos]  # [L, R, Nmax*unit, ...]
-            vals = vals.reshape(
-                (vals.shape[0], rows * max_blocks, unit) + vals.shape[3:]
-            )
-            out[key] = arr.at[:, flat_ids].set(vals)
+        with jax.named_scope("pool_copy"):
+            for key, arr in pool.items():
+                unit = block // 2 if key in packed_keys else block
+                pos = _pos(unit, blk_nos)
+                vals = cache[key][:, slots[:, None], pos]  # [L, R, Nmax*unit, ...]
+                vals = vals.reshape(
+                    (vals.shape[0], rows * max_blocks, unit) + vals.shape[3:]
+                )
+                out[key] = arr.at[:, flat_ids].set(vals)
         return out
 
     return (

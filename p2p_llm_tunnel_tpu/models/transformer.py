@@ -695,85 +695,91 @@ def chunk_prefill_into_cache(
     def step(carry, xs):
         x, cache = carry
         blk, idx = xs
-        h = _norm(cfg, x, blk["attn_norm"])
-        q, k, v = _qkv(cfg, blk, h, pos)  # rope at global positions
+        with jax.named_scope("attn"):
+            h = _norm(cfg, x, blk["attn_norm"])
+            q, k, v = _qkv(cfg, blk, h, pos)  # rope at global positions
         cache = dict(cache)
-        if quant_mode == "int4":
-            kq, k_s = _quant_kv4(k)
-            vq, v_s = _quant_kv4(v)
-            # Whole-byte writes either way (see the docstring contract):
-            # aligned chunks scatter packed bytes directly, unaligned
-            # spec-verify bursts splice covering bytes; the scale planes
-            # stay per-token full width.
-            if unaligned_int4:
-                cache["k"] = splice_packed_rows(
-                    cache["k"], idx, slots, starts, kq)
-                cache["v"] = splice_packed_rows(
-                    cache["v"], idx, slots, starts, vq)
+        with jax.named_scope("kv_write"):
+            if quant_mode == "int4":
+                kq, k_s = _quant_kv4(k)
+                vq, v_s = _quant_kv4(v)
+                # Whole-byte writes either way (see the docstring contract):
+                # aligned chunks scatter packed bytes directly, unaligned
+                # spec-verify bursts splice covering bytes; the scale planes
+                # stay per-token full width.
+                if unaligned_int4:
+                    cache["k"] = splice_packed_rows(
+                        cache["k"], idx, slots, starts, kq)
+                    cache["v"] = splice_packed_rows(
+                        cache["v"], idx, slots, starts, vq)
+                else:
+                    cache["k"] = write_packed_chunk(
+                        cache["k"], idx, rows, bpos, kq)
+                    cache["v"] = write_packed_chunk(
+                        cache["v"], idx, rows, bpos, vq)
+                cache["k_scale"] = cache["k_scale"].at[idx, rows, pos].set(k_s)
+                cache["v_scale"] = cache["v_scale"].at[idx, rows, pos].set(v_s)
+            elif quant:
+                kq, k_s = _quant_kv(k)
+                vq, v_s = _quant_kv(v)
+                cache["k"] = cache["k"].at[idx, rows, pos].set(kq)
+                cache["v"] = cache["v"].at[idx, rows, pos].set(vq)
+                cache["k_scale"] = cache["k_scale"].at[idx, rows, pos].set(k_s)
+                cache["v_scale"] = cache["v_scale"].at[idx, rows, pos].set(v_s)
             else:
-                cache["k"] = write_packed_chunk(
-                    cache["k"], idx, rows, bpos, kq)
-                cache["v"] = write_packed_chunk(
-                    cache["v"], idx, rows, bpos, vq)
-            cache["k_scale"] = cache["k_scale"].at[idx, rows, pos].set(k_s)
-            cache["v_scale"] = cache["v_scale"].at[idx, rows, pos].set(v_s)
-        elif quant:
-            kq, k_s = _quant_kv(k)
-            vq, v_s = _quant_kv(v)
-            cache["k"] = cache["k"].at[idx, rows, pos].set(kq)
-            cache["v"] = cache["v"].at[idx, rows, pos].set(vq)
-            cache["k_scale"] = cache["k_scale"].at[idx, rows, pos].set(k_s)
-            cache["v_scale"] = cache["v_scale"].at[idx, rows, pos].set(v_s)
-        else:
-            cache["k"] = cache["k"].at[idx, rows, pos].set(k)
-            cache["v"] = cache["v"].at[idx, rows, pos].set(v)
-        # One fused (layer, view) slice, then row gather: [Bp, view, K, D].
-        # (int4: the packed value planes slice kv_view // 2 BYTE rows and
-        # unpack to kv_view tokens in the operand read.)
-        view_rows = kv_view // 2 if quant_mode == "int4" else kv_view
-        zero = jnp.zeros((), idx.dtype)
-        start5 = (idx, zero, zero, zero, zero)
-        lshape = (
-            (1, cache["k"].shape[1], view_rows) + cache["k"].shape[3:]
-        )
-        k_all = jax.lax.dynamic_slice(cache["k"], start5, lshape)[0][slots]
-        v_all = jax.lax.dynamic_slice(cache["v"], start5, lshape)[0][slots]
-        if quant_mode == "int4":
-            k_all = unpack_int4(k_all, axis=1)
-            v_all = unpack_int4(v_all, axis=1)
-        if quant:
-            sshape = (
-                (1, cache["k_scale"].shape[1], kv_view)
-                + cache["k_scale"].shape[3:]
+                cache["k"] = cache["k"].at[idx, rows, pos].set(k)
+                cache["v"] = cache["v"].at[idx, rows, pos].set(v)
+        with jax.named_scope("kv_read"):
+            # One fused (layer, view) slice, then row gather: [Bp, view, K, D].
+            # (int4: the packed value planes slice kv_view // 2 BYTE rows and
+            # unpack to kv_view tokens in the operand read.)
+            view_rows = kv_view // 2 if quant_mode == "int4" else kv_view
+            zero = jnp.zeros((), idx.dtype)
+            start5 = (idx, zero, zero, zero, zero)
+            lshape = (
+                (1, cache["k"].shape[1], view_rows) + cache["k"].shape[3:]
             )
-            k_s_all = jax.lax.dynamic_slice(
-                cache["k_scale"], start5[:4], sshape)[0][slots]
-            v_s_all = jax.lax.dynamic_slice(
-                cache["v_scale"], start5[:4], sshape)[0][slots]
-            k_all = (k_all.astype(jnp.float32) * k_s_all[..., None]).astype(x.dtype)
-            v_all = (v_all.astype(jnp.float32) * v_s_all[..., None]).astype(x.dtype)
-        attn = history_attention(
-            q, k_all, v_all, starts,
-            scale=cfg.query_scale,
-            softcap=cfg.attn_softcap,
-            window=_layer_window(cfg, idx, kv_view),
-        )
-        attn = mm(attn.reshape(b, t, -1), blk["wo"], cfg.act_quant)
-        if cfg.post_norms:
-            attn = _norm(cfg, attn, blk["post_attn_norm"])
-        x = x + attn
-        h = _norm(cfg, x, blk["mlp_norm"])
-        mlp = _mlp(cfg, blk, h)
-        if cfg.post_norms:
-            mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
-        x = x + mlp
+            k_all = jax.lax.dynamic_slice(cache["k"], start5, lshape)[0][slots]
+            v_all = jax.lax.dynamic_slice(cache["v"], start5, lshape)[0][slots]
+            if quant_mode == "int4":
+                k_all = unpack_int4(k_all, axis=1)
+                v_all = unpack_int4(v_all, axis=1)
+            if quant:
+                sshape = (
+                    (1, cache["k_scale"].shape[1], kv_view)
+                    + cache["k_scale"].shape[3:]
+                )
+                k_s_all = jax.lax.dynamic_slice(
+                    cache["k_scale"], start5[:4], sshape)[0][slots]
+                v_s_all = jax.lax.dynamic_slice(
+                    cache["v_scale"], start5[:4], sshape)[0][slots]
+                k_all = (k_all.astype(jnp.float32) * k_s_all[..., None]).astype(x.dtype)
+                v_all = (v_all.astype(jnp.float32) * v_s_all[..., None]).astype(x.dtype)
+        with jax.named_scope("attn"):
+            attn = history_attention(
+                q, k_all, v_all, starts,
+                scale=cfg.query_scale,
+                softcap=cfg.attn_softcap,
+                window=_layer_window(cfg, idx, kv_view),
+            )
+            attn = mm(attn.reshape(b, t, -1), blk["wo"], cfg.act_quant)
+            if cfg.post_norms:
+                attn = _norm(cfg, attn, blk["post_attn_norm"])
+            x = x + attn
+        with jax.named_scope("ffn"):
+            h = _norm(cfg, x, blk["mlp_norm"])
+            mlp = _mlp(cfg, blk, h)
+            if cfg.post_norms:
+                mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
+            x = x + mlp
         return (x, cache), None
 
     (x, new_cache), _ = jax.lax.scan(
         step, (x, dict(kv_cache)), (params["blocks"], layer_idx)
     )
-    x = _norm(cfg, x, params["final_norm"])
-    logits = _logits(cfg, params, x)  # [Bp,T,V]
+    with jax.named_scope("head_sample"):
+        x = _norm(cfg, x, params["final_norm"])
+        logits = _logits(cfg, params, x)  # [Bp,T,V]
     if return_all_logits:
         # Speculative verify (engine spec_ngram): every position's logits
         # decide how many proposed tokens survive.
@@ -1114,86 +1120,90 @@ def decode_step(
     def step(carry, xs):
         x, cache = carry
         blk, idx = xs
-        h = _norm(cfg, x, blk["attn_norm"])
-        q, k, v = _qkv(cfg, blk, h, pos2d)  # q [B,1,H,D], k/v [B,1,K,D]
+        with jax.named_scope("attn"):
+            h = _norm(cfg, x, blk["attn_norm"])
+            q, k, v = _qkv(cfg, blk, h, pos2d)  # q [B,1,H,D], k/v [B,1,K,D]
         cache = dict(cache)
-        if quant_mode == "int4":
-            kq, k_s = _quant_kv4(k[:, 0])
-            vq, v_s = _quant_kv4(v[:, 0])
-            # Packed nibble read-modify-write via quant.append_packed_token
-            # (the TC19 commit point): the new token shares a byte with its
-            # sequence neighbour, whose nibble must survive (for odd
-            # positions it holds the PREVIOUS token's real value).  Parked
-            # rows (pos >= s) rely on the same OOB semantics as the int8
-            # path: the gather clamps (value unused) and the scatter drops
-            # the write.
-            cache["k"] = append_packed_token(
-                cache["k"], idx, slot_ids, positions, kq)
-            cache["v"] = append_packed_token(
-                cache["v"], idx, slot_ids, positions, vq)
-            cache["k_scale"] = (
-                cache["k_scale"].at[idx, slot_ids, positions].set(k_s)
-            )
-            cache["v_scale"] = (
-                cache["v_scale"].at[idx, slot_ids, positions].set(v_s)
-            )
-        elif quant:
-            kq, k_s = _quant_kv(k[:, 0])
-            vq, v_s = _quant_kv(v[:, 0])
-            cache["k"] = cache["k"].at[idx, slot_ids, positions].set(kq)
-            cache["v"] = cache["v"].at[idx, slot_ids, positions].set(vq)
-            cache["k_scale"] = (
-                cache["k_scale"].at[idx, slot_ids, positions].set(k_s)
-            )
-            cache["v_scale"] = (
-                cache["v_scale"].at[idx, slot_ids, positions].set(v_s)
-            )
-        else:
-            cache["k"] = cache["k"].at[idx, slot_ids, positions].set(k[:, 0])
-            cache["v"] = cache["v"].at[idx, slot_ids, positions].set(v[:, 0])
-        # ONE dynamic_slice for (layer, view-prefix): slicing the layer out
-        # first and sub-slicing after makes XLA materialize the full-length
-        # layer before the view cut — the fused form reads only view bytes.
-        view_rows = kv_view // 2 if quant_mode == "int4" else kv_view
-        view_shape = (1, b, view_rows, cfg.n_kv_heads, cfg.head_dim)
-        zero = jnp.zeros((), idx.dtype)
-        start = (idx, zero, zero, zero, zero)
-        k_l = jax.lax.dynamic_slice(cache["k"], start, view_shape)[0]
-        v_l = jax.lax.dynamic_slice(cache["v"], start, view_shape)[0]
-        if quant:
-            # Dequant fuses into the attention einsum's operand read: int8
-            # bytes cross HBM, bf16 never materializes (same fusion the
-            # int8 weights rely on — PERF.md).
-            sc_shape = (1, b, kv_view, cfg.n_kv_heads)
-            k_s = jax.lax.dynamic_slice(
-                cache["k_scale"], start[:4], sc_shape)[0]
-            v_s = jax.lax.dynamic_slice(
-                cache["v_scale"], start[:4], sc_shape)[0]
-            if use_sgrid:
+        with jax.named_scope("kv_write"):
+            if quant_mode == "int4":
+                kq, k_s = _quant_kv4(k[:, 0])
+                vq, v_s = _quant_kv4(v[:, 0])
+                # Packed nibble read-modify-write via quant.append_packed_token
+                # (the TC19 commit point): the new token shares a byte with its
+                # sequence neighbour, whose nibble must survive (for odd
+                # positions it holds the PREVIOUS token's real value).  Parked
+                # rows (pos >= s) rely on the same OOB semantics as the int8
+                # path: the gather clamps (value unused) and the scatter drops
+                # the write.
+                cache["k"] = append_packed_token(
+                    cache["k"], idx, slot_ids, positions, kq)
+                cache["v"] = append_packed_token(
+                    cache["v"], idx, slot_ids, positions, vq)
+                cache["k_scale"] = (
+                    cache["k_scale"].at[idx, slot_ids, positions].set(k_s)
+                )
+                cache["v_scale"] = (
+                    cache["v_scale"].at[idx, slot_ids, positions].set(v_s)
+                )
+            elif quant:
+                kq, k_s = _quant_kv(k[:, 0])
+                vq, v_s = _quant_kv(v[:, 0])
+                cache["k"] = cache["k"].at[idx, slot_ids, positions].set(kq)
+                cache["v"] = cache["v"].at[idx, slot_ids, positions].set(vq)
+                cache["k_scale"] = (
+                    cache["k_scale"].at[idx, slot_ids, positions].set(k_s)
+                )
+                cache["v_scale"] = (
+                    cache["v_scale"].at[idx, slot_ids, positions].set(v_s)
+                )
+            else:
+                cache["k"] = cache["k"].at[idx, slot_ids, positions].set(k[:, 0])
+                cache["v"] = cache["v"].at[idx, slot_ids, positions].set(v[:, 0])
+        with jax.named_scope("kv_read"):
+            # ONE dynamic_slice for (layer, view-prefix): slicing the layer out
+            # first and sub-slicing after makes XLA materialize the full-length
+            # layer before the view cut — the fused form reads only view bytes.
+            view_rows = kv_view // 2 if quant_mode == "int4" else kv_view
+            view_shape = (1, b, view_rows, cfg.n_kv_heads, cfg.head_dim)
+            zero = jnp.zeros((), idx.dtype)
+            start = (idx, zero, zero, zero, zero)
+            k_l = jax.lax.dynamic_slice(cache["k"], start, view_shape)[0]
+            v_l = jax.lax.dynamic_slice(cache["v"], start, view_shape)[0]
+            if quant:
+                # Dequant fuses into the attention einsum's operand read:
+                # int8 bytes cross HBM, bf16 never materializes (same
+                # fusion the int8 weights rely on — PERF.md).
+                sc_shape = (1, b, kv_view, cfg.n_kv_heads)
+                k_s = jax.lax.dynamic_slice(
+                    cache["k_scale"], start[:4], sc_shape)[0]
+                v_s = jax.lax.dynamic_slice(
+                    cache["v_scale"], start[:4], sc_shape)[0]
+                if not use_sgrid:
+                    if quant_mode == "int4":
+                        k_l = unpack_int4(k_l, axis=1)
+                        v_l = unpack_int4(v_l, axis=1)
+                    k_l = (k_l.astype(jnp.float32)
+                           * k_s[..., None]).astype(x.dtype)
+                    v_l = (v_l.astype(jnp.float32)
+                           * v_s[..., None]).astype(x.dtype)
+        with jax.named_scope("attn"):
+            if quant and use_sgrid:
                 # Packed/int8 K/V + scales go straight into the kernel,
                 # which dequantizes in VMEM — the bf16 plane never
                 # materializes in HBM (that was the whole einsum-path cost).
                 attn = attention(q, k_l, v_l, idx, k_s, v_s)
             else:
-                if quant_mode == "int4":
-                    k_l = unpack_int4(k_l, axis=1)
-                    v_l = unpack_int4(v_l, axis=1)
-                k_l = (k_l.astype(jnp.float32)
-                       * k_s[..., None]).astype(x.dtype)
-                v_l = (v_l.astype(jnp.float32)
-                       * v_s[..., None]).astype(x.dtype)
                 attn = attention(q, k_l, v_l, idx)
-        else:
-            attn = attention(q, k_l, v_l, idx)
-        attn = mm(attn.reshape(b, 1, -1), blk["wo"], cfg.act_quant)
-        if cfg.post_norms:
-            attn = _norm(cfg, attn, blk["post_attn_norm"])
-        x = x + attn
-        h = _norm(cfg, x, blk["mlp_norm"])
-        mlp = _mlp(cfg, blk, h)
-        if cfg.post_norms:
-            mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
-        x = x + mlp
+            attn = mm(attn.reshape(b, 1, -1), blk["wo"], cfg.act_quant)
+            if cfg.post_norms:
+                attn = _norm(cfg, attn, blk["post_attn_norm"])
+            x = x + attn
+        with jax.named_scope("ffn"):
+            h = _norm(cfg, x, blk["mlp_norm"])
+            mlp = _mlp(cfg, blk, h)
+            if cfg.post_norms:
+                mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
+            x = x + mlp
         return (x, cache), None
 
     (x, new_cache), _ = jax.lax.scan(
@@ -1201,8 +1211,9 @@ def decode_step(
         (x, dict(kv_cache)),
         (params["blocks"], layer_idx),
     )
-    x = _norm(cfg, x, params["final_norm"])
-    logits = _logits(cfg, params, x)[:, 0]  # [B,V]
+    with jax.named_scope("head_sample"):
+        x = _norm(cfg, x, params["final_norm"])
+        logits = _logits(cfg, params, x)[:, 0]  # [B,V]
     return logits, new_cache
 
 

@@ -10,7 +10,8 @@ decode-stall watchdog trips.  This module is that third leg (ISSUE 12):
   burst width, prefill rows dispatched, slot/tenant occupancy, the host
   wall split).  Cheap enough to never be off: one dict + deque append per
   iteration, no device traffic, no syscalls.  Exported as Chrome-trace
-  slice/counter tracks through the existing ``/healthz?trace=1`` journal
+  slices (the whole record in each slice's args) through the existing
+  ``/healthz?trace=1`` journal
   (so PR 9's fleet stitching yields per-peer engine lanes for free) and
   summarized by ``scripts/traceview.py --flight``.
 - :class:`CompileWatch` — the compile/cold-start journal: every compiled
@@ -216,11 +217,6 @@ class FlightRecorder:
     records, plus the loop's current-phase marker (what the watchdog
     reports as stall attribution)."""
 
-    #: Chrome counter tracks exported per record (the rest of the fields
-    #: ride the per-iteration slice's args).
-    COUNTER_FIELDS = ("queue_depth", "backlog_rows", "budget_tokens",
-                      "active_slots")
-
     def __init__(self, capacity: Optional[int] = None):
         if capacity is None:
             capacity = int(
@@ -289,8 +285,8 @@ class FlightRecorder:
 
     def chrome_events(self) -> List[Dict[str, object]]:
         """The ring as Chrome trace events: one ``ph:"X"`` slice per
-        iteration on an ``engine-flight`` lane (args = the full record)
-        plus ``ph:"C"`` counter tracks for the COUNTER_FIELDS series.
+        iteration on an ``engine-flight`` lane (args = the full record:
+        what ``scripts/traceview.py --flight`` and the benchmark read).
         Merged into the ``/healthz?trace=1`` export by the serve loop, so
         the fleet stitcher gives every peer its own engine-flight lane."""
         recs = self.records()
@@ -312,13 +308,6 @@ class FlightRecorder:
                 "dur": max(1, int(dur_ms * 1000)),
                 "args": dict(rec),
             })
-            for key in self.COUNTER_FIELDS:
-                if key in rec:
-                    events.append({
-                        "name": f"flight.{key}", "cat": "engine-flight",
-                        "ph": "C", "pid": 1, "tid": tid, "ts": ts,
-                        "args": {key: rec[key]},
-                    })
         return events
 
 

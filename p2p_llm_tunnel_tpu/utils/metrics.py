@@ -26,6 +26,19 @@ METRICS_CATALOG: Dict[str, str] = {
     # -- engine ----------------------------------------------------------
     "engine_tokens_total": "decode tokens emitted to streams (counter)",
     "engine_prefill_tokens_total": "prompt tokens prefilled (counter)",
+    "engine_prefill_positions_total": (
+        "positions the prefill programs were dispatched over, padding "
+        "included: rows x width of every prefill dispatch; tokens / "
+        "positions is the prefill fill --prefill-rows is sized by (counter)"
+    ),
+    "engine_decode_steps_total": "decode steps dispatched (counter)",
+    "engine_decode_row_steps_total": (
+        "live rows x steps over every decode burst dispatched (counter)"
+    ),
+    "engine_decode_slot_steps_total": (
+        "slots x steps over every decode burst dispatched; row-steps / "
+        "slot-steps is the decode fill --slots is sized by (counter)"
+    ),
     "engine_prefill_segments_total": "chunked-prefill segments executed (counter)",
     "engine_spec_tokens_total": "tokens emitted via speculative decode (counter)",
     "engine_spec_accepted_tokens_total": "draft tokens accepted by verify (counter)",

@@ -97,7 +97,15 @@ SPAN_CATALOG: Dict[str, str] = {
     ),
     "engine.prefill_exec": (
         "slot admission -> first token, incl. any prefix-dedup park time "
-        "(the execution half of the TTFT decomposition)"
+        "(the execution half of the TTFT decomposition; attrs carry "
+        "prompt_tokens, cached_tokens, parts and iterations — the span "
+        "minus the union of its engine.prefill_part children is the time "
+        "the request held a slot with nothing of its own in flight)"
+    ),
+    "engine.prefill_part": (
+        "one request's rows in one prefill dispatch: same interval as "
+        "that dispatch's engine.prefill_segment record (child of the "
+        "request's engine.request span; attrs seq, tokens, start, final)"
     ),
     "engine.prefix_park": (
         "parked behind an in-flight shared-prefix prefill owned by "
@@ -108,12 +116,21 @@ SPAN_CATALOG: Dict[str, str] = {
         "for its group (owner side; instant, attrs carry the key count)"
     ),
     "engine.prefill_segment": (
-        "one chunked-prefill sub-batch: dispatch -> sampled block on host "
-        "(engine-scope; attrs carry the row count)"
+        "one prefill dispatch (chunk segment sub-batch, prefix tail, "
+        "whole-prompt wave or ragged group): dispatch -> sampled block on "
+        "host (engine-scope dispatch record; attrs seq, program, t, view, "
+        "rows/rows_padded, tokens/positions = real/dispatched work)"
     ),
     "engine.decode_burst": (
         "one multi-step decode burst: dispatch -> fetched block processed "
-        "(engine-scope; overlaps its successor via pipelining)"
+        "(engine-scope dispatch record; overlaps its successor via "
+        "pipelining; attrs seq, program, view, steps, live_rows, slots)"
+    ),
+    "engine.pool_copy": (
+        "one batched prefix-pool copy dispatch, cache_to_pool or "
+        "pool_to_cache: the dispatch call, nothing is fetched "
+        "(engine-scope dispatch record; attrs seq, program, "
+        "rows/rows_padded, blocks/blocks_padded)"
     ),
     "engine.first_token": "first token accounted for the request (instant)",
     "engine.stream_end": "the request's token stream finished (instant)",
@@ -536,9 +553,7 @@ def validate_chrome_trace(obj: object) -> bool:
             if key not in ev:
                 raise ValueError(f"traceEvents[{i}] missing {key!r}")
         ph = ev["ph"]
-        # "C" counter events are the flight recorder's numeric tracks
-        # (ISSUE 12), merged into the same journal export.
-        if ph not in ("X", "i", "M", "C"):
+        if ph not in ("X", "i", "M"):
             raise ValueError(f"traceEvents[{i}] unknown phase {ph!r}")
         if ph == "M":
             continue

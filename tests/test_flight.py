@@ -87,18 +87,18 @@ def test_flight_chrome_events_are_schema_valid_counters_and_slices():
                          budget_tokens=128, active_slots=2,
                          backlog_rows=1, decode_steps=4)
     evs = rec.chrome_events()
-    # Slices + counter tracks, all loadable next to the span journal.
+    # One slice an iteration, loadable next to the span journal; every
+    # number of the record rides the slice's args (no counter tracks).
     trace = global_tracer.chrome_trace()
     trace["traceEvents"] = list(trace["traceEvents"]) + evs
     assert validate_chrome_trace(trace)
-    phases = {e["ph"] for e in evs}
-    assert "X" in phases and "C" in phases
+    assert [e["ph"] for e in evs] == ["M", "X"]
     slice_ev = next(e for e in evs if e["ph"] == "X")
     assert slice_ev["name"] == "engine.flight"
     assert slice_ev["args"]["queue_depth"] == 3
-    counters = {e["name"] for e in evs if e["ph"] == "C"}
-    assert "flight.queue_depth" in counters
-    assert "flight.budget_tokens" in counters
+    for key, value in (("budget_tokens", 128), ("active_slots", 2),
+                       ("backlog_rows", 1), ("decode_steps", 4)):
+        assert slice_ev["args"][key] == value
 
 
 def test_compile_watch_journal_marks_and_cold_counter():
@@ -229,16 +229,23 @@ def test_healthz_postmortem_surface_and_degraded_reason():
             body = json.loads(r.text)
             assert body == {"postmortem": None, "captured": 0, "paths": []}
             # A watchdog-degraded engine answers with the reason AND the
-            # captured bundle.
+            # captured bundle.  The engine's two detectors read apart: the
+            # decode-stall watchdog as "stall" (also the reading of a bare
+            # gauge with no reason published), the thrash detector as
+            # "memory".
             global_metrics.set_gauge("engine_degraded", 1.0)
             global_blackbox.capture("watchdog", attribution="decode_dispatch")
             try:
-                h = await client.wait(
-                    await client.request("GET", "/healthz"), 10.0
-                )
-                payload = json.loads(h.text)
-                assert payload["status"] == "degraded"
-                assert payload["engine_degraded_reason"] == "watchdog"
+                for published, read in (("", "stall"), ("stall", "stall"),
+                                        ("memory", "memory")):
+                    global_metrics.set_info("engine_degraded_reason",
+                                            published)
+                    h = await client.wait(
+                        await client.request("GET", "/healthz"), 10.0
+                    )
+                    payload = json.loads(h.text)
+                    assert payload["status"] == "degraded"
+                    assert payload["engine_degraded_reason"] == read
                 r = await client.wait(
                     await client.request("GET", "/healthz?postmortem=1"),
                     10.0,
@@ -250,6 +257,7 @@ def test_healthz_postmortem_surface_and_degraded_reason():
                 assert set(body["postmortem"]) == set(POSTMORTEM_SCHEMA)
             finally:
                 global_metrics.set_gauge("engine_degraded", 0.0)
+                global_metrics.set_info("engine_degraded_reason", "")
         finally:
             await _teardown(serve_task, ch, client)
 
@@ -273,7 +281,8 @@ def test_healthz_trace_export_carries_flight_tracks():
                        if e.get("name") == "engine.flight"]
             assert len(flights) == 1
             assert flights[0]["args"]["queue_depth"] == 5
-            assert any(e.get("ph") == "C" for e in obj["traceEvents"])
+            assert flights[0]["args"]["budget_tokens"] == 64
+            assert {e["ph"] for e in obj["traceEvents"]} <= {"M", "X", "i"}
         finally:
             await _teardown(serve_task, ch, client)
 
@@ -610,3 +619,22 @@ def test_postmortem_bundle_identity_two_seeded_runs():
         json.dumps(b1, default=str)
 
     asyncio.run(main())
+
+
+def test_a_stopped_engine_takes_its_degraded_verdict_with_it():
+    """The verdict lives in the process-wide registry (``/healthz`` reads it
+    there).  An engine that stops while degraded must clear it: nothing of
+    it is left running to do so, and the next engine of the process — or a
+    bare ``run_serve``, as in tests/test_fleet.py — would read degraded."""
+    async def main():
+        engine = _engine()
+        await engine.start()
+        engine.degraded = True
+        engine.degraded_reason = "memory"
+        global_metrics.set_gauge("engine_degraded", 1.0)
+        global_metrics.set_info("engine_degraded_reason", "memory")
+        await engine.stop()
+
+    asyncio.run(main())
+    assert global_metrics.gauge("engine_degraded") == 0.0
+    assert global_metrics.info("engine_degraded_reason", "") == ""

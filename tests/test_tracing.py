@@ -357,6 +357,21 @@ def test_healthz_trace_export_and_pool_accounting():
                 # The composition-fence registry rides /healthz too: a
                 # list (empty unless an engine auto-disabled something).
                 assert isinstance(payload["config"]["fences"], list)
+                # ... and the precision the engine was built with (what
+                # an engine publishes at construction; null without one).
+                assert set(payload["config"]) == {
+                    "fences", "attention", "quant", "kv_quant"}
+                global_metrics.set_info("config_quant", "int8")
+                global_metrics.set_info("config_kv_quant", "none")
+                try:
+                    h = await client.wait(await client.request(
+                        "GET", "/healthz"), 10.0)
+                    config = json.loads(h.text)["config"]
+                    assert (config["quant"], config["kv_quant"]) == (
+                        "int8", "none")
+                finally:
+                    global_metrics.set_info("config_quant", None)
+                    global_metrics.set_info("config_kv_quant", None)
             finally:
                 await _teardown(serve_task, ch, client)
 
