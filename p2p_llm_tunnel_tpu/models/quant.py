@@ -156,14 +156,14 @@ def write_packed_prefix(plane: jnp.ndarray, slots: jnp.ndarray,
     return plane.at[:, slots, : packed.shape[2]].set(packed)
 
 
-def write_packed_chunk(plane: jnp.ndarray, idx: jnp.ndarray,
-                       rows: jnp.ndarray, bpos: jnp.ndarray,
-                       vals: jnp.ndarray) -> jnp.ndarray:
-    """Page-aligned chunk write: ``vals [Bp, T(even), K, D]`` at EVEN token
-    starts, pre-translated by the caller to byte positions ``bpos
-    [Bp, T//2]``.  Byte i of the write holds exactly tokens
-    ``(start + 2i, start + 2i + 1)`` — whole bytes, no RMW."""
-    return plane.at[idx, rows, bpos].set(pack_int4(vals, axis=1))
+def write_packed_chunk(plane: jnp.ndarray, rows: jnp.ndarray,
+                       bpos: jnp.ndarray, vals: jnp.ndarray) -> jnp.ndarray:
+    """Page-aligned chunk write, all layers at once: ``vals
+    [L, Bp, T(even), K, D]`` at EVEN token starts, pre-translated by the
+    caller to byte positions ``bpos [Bp, T//2]`` of rows ``rows [Bp, 1]``.
+    Byte i of the write holds exactly tokens ``(start + 2i, start + 2i + 1)``
+    — whole bytes, no RMW."""
+    return plane.at[:, rows, bpos].set(pack_int4(vals, axis=2))
 
 
 def append_packed_token(plane: jnp.ndarray, idx: jnp.ndarray,
@@ -184,27 +184,27 @@ def append_packed_token(plane: jnp.ndarray, idx: jnp.ndarray,
     )
 
 
-def splice_packed_rows(plane: jnp.ndarray, idx: jnp.ndarray,
-                       slots: jnp.ndarray, starts: jnp.ndarray,
-                       vals: jnp.ndarray) -> jnp.ndarray:
-    """Arbitrary-start multi-token splice — the write shape of a
-    spec-verify burst (ISSUE 17): ``vals [B, T, K, D]`` int4 values land at
-    token positions ``[starts, starts + T)`` of each row, ``starts [B]`` of
-    ANY parity and T of any parity.  Gather the covering whole-byte range
-    (``T//2 + 1`` bytes spans every parity case), unpack, overlay the burst
-    tokens, repack, scatter the SAME whole bytes back — boundary nibbles
-    outside the burst are preserved from the gathered bytes, and positions
-    past the plane's end drop on the scatter (parked / overflow rows)."""
-    b, t, _, _ = vals.shape
+def splice_packed_rows(plane: jnp.ndarray, slots: jnp.ndarray,
+                       starts: jnp.ndarray, vals: jnp.ndarray) -> jnp.ndarray:
+    """Arbitrary-start multi-token splice, all layers at once — the write
+    shape of a spec-verify burst (ISSUE 17): ``vals [L, B, T, K, D]`` int4
+    values land at token positions ``[starts, starts + T)`` of each row,
+    ``starts [B]`` of ANY parity and T of any parity.  Gather the covering
+    whole-byte range (``T//2 + 1`` bytes spans every parity case), unpack,
+    overlay the burst tokens, repack, scatter the SAME whole bytes back —
+    boundary nibbles outside the burst are preserved from the gathered
+    bytes, and positions past the plane's end drop on the scatter (parked /
+    overflow rows)."""
+    _, b, t, _, _ = vals.shape
     nb = t // 2 + 1
     bpos = starts[:, None] // 2 + jnp.arange(nb)[None, :]  # [B, nb]
-    old = plane[idx, slots[:, None], bpos]  # [B, nb, K, D]
-    old_tok = unpack_int4(old, axis=1)  # [B, 2*nb, K, D]
+    old = plane[:, slots[:, None], bpos]  # [L, B, nb, K, D]
+    old_tok = unpack_int4(old, axis=2)  # [L, B, 2*nb, K, D]
     jrel = jnp.arange(2 * nb)[None, :] - (starts % 2)[:, None]  # [B, 2nb]
     use_new = (jrel >= 0) & (jrel < t)
-    newv = vals[jnp.arange(b)[:, None], jnp.clip(jrel, 0, t - 1)]
+    newv = vals[:, jnp.arange(b)[:, None], jnp.clip(jrel, 0, t - 1)]
     merged = jnp.where(use_new[:, :, None, None], newv, old_tok)
-    return plane.at[idx, slots[:, None], bpos].set(pack_int4(merged, axis=1))
+    return plane.at[:, slots[:, None], bpos].set(pack_int4(merged, axis=2))
 
 
 def _quantize4(w: jnp.ndarray, axis: int, group_size: int = 128) -> QTensor4:

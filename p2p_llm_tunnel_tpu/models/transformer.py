@@ -621,10 +621,29 @@ def chunk_prefill_into_cache(
     the same result as ``prefill_into_cache`` — pinned by
     tests/test_prefix_cache.py against that oracle.
 
-    Like decode_step, the cache is carried through the layer scan so tail
-    writes stay in-place; attention reads the cache row back (one fused
-    (layer, view) dynamic_slice), which covers history and tail in a
-    single read.
+    The cache is NOT a carry of the layer scan (ISSUE 26).  Inside the loop
+    it is only read, as a loop-invariant operand: each layer slices its own
+    (layer, view) rows, lays its fresh tail over positions
+    ``[starts, starts + T)`` of that small ``[Bp, view, K, D]`` array (a
+    position at or past the view is dropped) and attends to the result —
+    the same values at the same positions the cache will hold.  A quantised
+    cache lays the QUANTISED tail and its scales over the stored history and
+    dequantises the whole view, so a chunk attends to what the cache will
+    hold.  The tails leave the scan stacked ``[L, Bp, T, K, D]`` and the
+    cache is written ONCE after it, for all layers, in place on the donated
+    buffer (as ``prefill_into_cache`` writes).  Carried and scattered in
+    every layer, a plane with 4 KV heads was converted between the layout
+    the loop keeps it in and the one the scatter wants, whole, there and
+    back, in every layer: half the device in ``qwen2-7b.decode-closed``
+    (PERF.md section 6, PR 26; tests/test_tpu_compile.py guards it).
+
+    Invariant the carry used to hide: within one dispatch, real rows name
+    DISTINCT slots — a row sees the history and its own tail, never another
+    row's tail of the same dispatch.  The engine keeps it by construction
+    (``_dispatch_segments`` picks one segment a slot from ``_segmented``,
+    which is keyed by slot; a prefix-tail row is one admitted request in its
+    own slot; spec-verify passes ``arange(b)``).  Padding rows may all name
+    the parking row: they write junk there and nothing reads it.
 
     Scope limits (the engine enforces both):
     - No sequence-parallel path: under an sp>1 mesh the engine disables
@@ -674,6 +693,17 @@ def chunk_prefill_into_cache(
     layer_idx = jnp.arange(cfg.n_layers)
     quant = kv_cache_is_quantized(kv_cache)
     rows = slots[:, None]  # [Bp,1] broadcasts against pos [Bp,T]
+    # Which tail position each view position takes, a 0/1 matrix
+    # [Bp, view, T]: one 1 for a position in [starts, starts + T), none
+    # elsewhere, so a tail position at or past the view is dropped.
+    place = (
+        jnp.arange(kv_view)[None, :, None] - starts[:, None, None]
+        == jnp.arange(t)[None, None, :]
+    )
+    fresh = place.any(axis=-1)  # [Bp, view]
+    # (int4: the packed value planes slice kv_view // 2 BYTE rows and
+    # unpack to kv_view tokens.)
+    view_rows = kv_view // 2 if quant_mode == "int4" else kv_view
     if quant_mode == "int4":
         from p2p_llm_tunnel_tpu.models.quant import (
             splice_packed_rows,
@@ -681,78 +711,57 @@ def chunk_prefill_into_cache(
             write_packed_chunk,
         )
 
-        # Byte positions of the page-aligned packed write: starts is even
-        # by the contract above, so byte i of the write holds exactly
-        # tokens (starts + 2i, starts + 2i + 1) — whole bytes, plain
-        # scatter, no nibble RMW on the chunk path.  (Unaligned spec-verify
-        # bursts skip this and splice covering bytes instead.)
-        bpos = None
-        if not unaligned_int4:
-            bpos = starts[:, None] // 2 + jnp.arange(t // 2)[None, :]
-
     from p2p_llm_tunnel_tpu.ops.attention import history_attention
 
-    def step(carry, xs):
-        x, cache = carry
+    def read_view(plane, idx, n):
+        """Positions ``[0, n)`` of the dispatch's own rows of layer ``idx``:
+        one (layer, view) slice of all cache rows, then the row gather."""
+        start = (idx,) + (jnp.zeros((), idx.dtype),) * (plane.ndim - 1)
+        shape = (1, plane.shape[1], n) + plane.shape[3:]
+        return jax.lax.dynamic_slice(plane, start, shape)[0][slots]
+
+    def lay(hist, tail):
+        """``tail [Bp,T,...]`` over positions ``[starts, starts + T)`` of
+        ``hist [Bp,view,...]``.  The 0/1 product puts each tail value in its
+        place exactly (one term a position); on the chip it costs less than
+        a scatter into the gathered rows, which first copies them into one
+        buffer (PERF.md section 6, PR 26)."""
+        # float32 alone has to ask: the chip's default rounds its products
+        # to bfloat16 (asked of bfloat16 tails too, it costs 3 % of a run).
+        exact = jax.lax.Precision.HIGHEST if tail.dtype == jnp.float32 else None
+        moved = jnp.einsum(
+            "bpt,bt...->bp...", place.astype(tail.dtype), tail,
+            precision=exact, preferred_element_type=tail.dtype,
+        )
+        at = fresh.reshape(fresh.shape + (1,) * (hist.ndim - 2))
+        return jnp.where(at, moved, hist)
+
+    def step(x, xs):
         blk, idx = xs
         with jax.named_scope("attn"):
             h = _norm(cfg, x, blk["attn_norm"])
             q, k, v = _qkv(cfg, blk, h, pos)  # rope at global positions
-        cache = dict(cache)
+        k_s = v_s = None
         with jax.named_scope("kv_write"):
+            # The tail in the form the cache will hold it.
             if quant_mode == "int4":
-                kq, k_s = _quant_kv4(k)
-                vq, v_s = _quant_kv4(v)
-                # Whole-byte writes either way (see the docstring contract):
-                # aligned chunks scatter packed bytes directly, unaligned
-                # spec-verify bursts splice covering bytes; the scale planes
-                # stay per-token full width.
-                if unaligned_int4:
-                    cache["k"] = splice_packed_rows(
-                        cache["k"], idx, slots, starts, kq)
-                    cache["v"] = splice_packed_rows(
-                        cache["v"], idx, slots, starts, vq)
-                else:
-                    cache["k"] = write_packed_chunk(
-                        cache["k"], idx, rows, bpos, kq)
-                    cache["v"] = write_packed_chunk(
-                        cache["v"], idx, rows, bpos, vq)
-                cache["k_scale"] = cache["k_scale"].at[idx, rows, pos].set(k_s)
-                cache["v_scale"] = cache["v_scale"].at[idx, rows, pos].set(v_s)
+                k, k_s = _quant_kv4(k)
+                v, v_s = _quant_kv4(v)
             elif quant:
-                kq, k_s = _quant_kv(k)
-                vq, v_s = _quant_kv(v)
-                cache["k"] = cache["k"].at[idx, rows, pos].set(kq)
-                cache["v"] = cache["v"].at[idx, rows, pos].set(vq)
-                cache["k_scale"] = cache["k_scale"].at[idx, rows, pos].set(k_s)
-                cache["v_scale"] = cache["v_scale"].at[idx, rows, pos].set(v_s)
-            else:
-                cache["k"] = cache["k"].at[idx, rows, pos].set(k)
-                cache["v"] = cache["v"].at[idx, rows, pos].set(v)
+                k, k_s = _quant_kv(k)
+                v, v_s = _quant_kv(v)
         with jax.named_scope("kv_read"):
-            # One fused (layer, view) slice, then row gather: [Bp, view, K, D].
-            # (int4: the packed value planes slice kv_view // 2 BYTE rows and
-            # unpack to kv_view tokens in the operand read.)
-            view_rows = kv_view // 2 if quant_mode == "int4" else kv_view
-            zero = jnp.zeros((), idx.dtype)
-            start5 = (idx, zero, zero, zero, zero)
-            lshape = (
-                (1, cache["k"].shape[1], view_rows) + cache["k"].shape[3:]
-            )
-            k_all = jax.lax.dynamic_slice(cache["k"], start5, lshape)[0][slots]
-            v_all = jax.lax.dynamic_slice(cache["v"], start5, lshape)[0][slots]
+            k_all = read_view(kv_cache["k"], idx, view_rows)
+            v_all = read_view(kv_cache["v"], idx, view_rows)
             if quant_mode == "int4":
                 k_all = unpack_int4(k_all, axis=1)
                 v_all = unpack_int4(v_all, axis=1)
+            # The tail over the history: [Bp, view, K, D].
+            k_all = lay(k_all, k)
+            v_all = lay(v_all, v)
             if quant:
-                sshape = (
-                    (1, cache["k_scale"].shape[1], kv_view)
-                    + cache["k_scale"].shape[3:]
-                )
-                k_s_all = jax.lax.dynamic_slice(
-                    cache["k_scale"], start5[:4], sshape)[0][slots]
-                v_s_all = jax.lax.dynamic_slice(
-                    cache["v_scale"], start5[:4], sshape)[0][slots]
+                k_s_all = lay(read_view(kv_cache["k_scale"], idx, kv_view), k_s)
+                v_s_all = lay(read_view(kv_cache["v_scale"], idx, kv_view), v_s)
                 k_all = (k_all.astype(jnp.float32) * k_s_all[..., None]).astype(x.dtype)
                 v_all = (v_all.astype(jnp.float32) * v_s_all[..., None]).astype(x.dtype)
         with jax.named_scope("attn"):
@@ -772,11 +781,35 @@ def chunk_prefill_into_cache(
             if cfg.post_norms:
                 mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
             x = x + mlp
-        return (x, cache), None
+        return x, (k, v, k_s, v_s)
 
-    (x, new_cache), _ = jax.lax.scan(
-        step, (x, dict(kv_cache)), (params["blocks"], layer_idx)
+    x, (ks, vs, k_ss, v_ss) = jax.lax.scan(
+        step, x, (params["blocks"], layer_idx)
     )
+    # One write for all layers, [L,Bp,T,K,D] into the donated cache.
+    new_cache = dict(kv_cache)
+    with jax.named_scope("kv_write"):
+        if quant_mode == "int4":
+            # Whole-byte writes either way (see the docstring contract).
+            if unaligned_int4:
+                new_cache["k"] = splice_packed_rows(
+                    kv_cache["k"], slots, starts, ks)
+                new_cache["v"] = splice_packed_rows(
+                    kv_cache["v"], slots, starts, vs)
+            else:
+                # starts is even, so byte i of the write holds exactly
+                # tokens (starts + 2i, starts + 2i + 1).
+                bpos = starts[:, None] // 2 + jnp.arange(t // 2)[None, :]
+                new_cache["k"] = write_packed_chunk(
+                    kv_cache["k"], rows, bpos, ks)
+                new_cache["v"] = write_packed_chunk(
+                    kv_cache["v"], rows, bpos, vs)
+        else:
+            new_cache["k"] = kv_cache["k"].at[:, rows, pos].set(ks)
+            new_cache["v"] = kv_cache["v"].at[:, rows, pos].set(vs)
+        if quant:
+            new_cache["k_scale"] = kv_cache["k_scale"].at[:, rows, pos].set(k_ss)
+            new_cache["v_scale"] = kv_cache["v_scale"].at[:, rows, pos].set(v_ss)
     with jax.named_scope("head_sample"):
         x = _norm(cfg, x, params["final_norm"])
         logits = _logits(cfg, params, x)  # [Bp,T,V]

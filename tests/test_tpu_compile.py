@@ -15,7 +15,9 @@
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
@@ -197,6 +199,104 @@ def test_plane_decode_kernel_is_refused_by_the_tpu_compiler(chip):
             q, k_, v_, pos, window=WINDOW),
         ((ROWS, 1, H, D), jnp.bfloat16), k, v, ((ROWS,), jnp.int32),
     )
+
+
+# ---------------------------------------------------------------------------
+# chunk prefill: the KV cache is not a value the layer loop writes (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+
+
+def _plane_sized(type_text, plane):
+    """Array types in ``type_text`` with as many elements as a cache plane."""
+    return [
+        m.group(0) for m in _ARRAY.finditer(type_text)
+        if math.prod(int(d) for d in m.group(2).split(",") if d) == plane
+    ]
+
+
+def _plane_work(hlo, plane):
+    """(plane-sized ``copy`` results anywhere, plane-sized values that a
+    ``while`` body computes) in a compiled program's text.  A loop-invariant
+    operand rides the body's tuple too (HLO has no other way to hand it in);
+    what the body may not do is make a plane: parameter,
+    get-tuple-element and the root tuple that passes it on are all it may
+    hold of that size."""
+    instr = re.compile(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", re.M)
+    copies = [
+        (name, typ) for name, typ, op in instr.findall(hlo)
+        if op == "copy" and _plane_sized(typ, plane)
+    ]
+    made = []
+    for body in set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo)):
+        start = re.search(
+            rf"^%?{re.escape(body)} \(.*\{{\s*$", hlo, re.M)
+        text = hlo[start.end():hlo.index("\n}", start.end())]
+        made += [
+            (body, name, op) for name, typ, op in instr.findall(text)
+            if op not in ("parameter", "get-tuple-element", "tuple")
+            and _plane_sized(typ, plane)
+        ]
+    return copies, made
+
+
+@pytest.mark.parametrize("model,kv,view", [
+    ("qwen2-7b", None, 1024), ("qwen2-7b", "int8", 1024),
+    ("qwen2-7b", "int4", 1024), ("mistral-7b", None, 1024),
+    ("mistral-7b", "int8", 1024), ("mistral-7b", "int4", 1024),
+    ("qwen2-7b", None, 256),
+])
+def test_chunk_prefill_makes_no_cache_plane_in_its_layer_loop(
+        chip, model, kv, view):
+    """``chunk_prefill_into_cache`` at the benchmark's shapes (33 rows x
+    1024, tail 8 x 128, cache donated) and each configuration's own
+    attention widths and depth; FFN and vocabulary are cut, they do not
+    touch the cache's layout.  With 4 KV heads (qwen2-7b) the compiler
+    keeps a loop-carried plane heads-outermost and a scatter wants it
+    row-major: carried through the layer scan, the plane was converted
+    there and back in every layer (four ``copy bf16[946176,4,128]``, half
+    the device in ``qwen2-7b.decode-closed``, PERF.md section 6).  The
+    guard for every later configuration with few KV heads: no ``copy``
+    makes a plane, the layer loop makes none, and the written cache is
+    the donated one."""
+    from p2p_llm_tunnel_tpu.models.config import get_config
+    from p2p_llm_tunnel_tpu.models.transformer import (
+        chunk_prefill_into_cache,
+        init_kv_cache,
+        init_params,
+    )
+
+    cfg = get_config(model, ffn_dim=512, vocab_size=1024)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+            tree,
+        )
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, ROWS, MAX_SEQ, quant=kv)))
+    tokens, row = on_chip((
+        jax.ShapeDtypeStruct((8, 128), jnp.int32),
+        jax.ShapeDtypeStruct((8,), jnp.int32),
+    ))
+    hlo = jax.jit(
+        lambda p, c, tok, lengths, starts, slots: chunk_prefill_into_cache(
+            cfg, p, tok, lengths, starts, c, slots, kv_view=view),
+        donate_argnums=(1,),
+    ).lower(params, cache, tokens, row, row, row).compile().as_text()
+
+    copies, made = _plane_work(hlo, math.prod(cache["k"].shape))
+    assert copies == []
+    assert made == []
+    assert "while(" in hlo  # the layer scan is still one loop to look into
+    # Both planes (and both scale planes) are written on the donated input.
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert aliased.count("alias") == len(cache)
 
 
 # ---------------------------------------------------------------------------
