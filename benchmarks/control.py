@@ -15,10 +15,11 @@ own sequences, exactly as a run would.
 (``--kv-quant int8``: an int8 cache where the configuration states bf16;
 ``--quant w8a8``: int8 activations where it states bf16;
 ``--prefill-act-quant``: int8 activations in prefill alone).  ``--weight-bits
-4`` puts the reference itself in the program's place with its weights
-rounded to int4, where the configuration states int8: no stack is started,
-the number is the rounded reference against the plain one.  The last line
-says for each seed whether ``correct`` would hold under the file's limits.
+<n>`` puts the reference itself in the program's place with its weights
+rounded to ``n`` bits, fewer than the configuration states (4 where it
+states int8): no stack is started, the number is the rounded reference
+against the plain one.  The last line says for each seed whether ``correct``
+would hold under the file's limits.
 """
 
 from __future__ import annotations
@@ -61,7 +62,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--serve-args", default="")
-    ap.add_argument("--weight-bits", type=int, default=8)
+    ap.add_argument("--weight-bits", type=int, default=None)
     ap.add_argument("--root", default=REPO, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     seeds = [int(s) for s in args.seeds.split(",")]
@@ -70,8 +71,8 @@ def main(argv=None) -> int:
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     config_file = os.path.join(args.root, entry["file"])
     config = load_json(config_file)
-    mix = load_json(os.path.join(args.root, "benchmarks", "traffic",
-                                 cell["traffic"] + ".json"))
+    data = os.path.join(args.root, "benchmarks")
+    mix = load_json(os.path.join(data, "traffic", cell["traffic"] + ".json"))
     platform = stack.platform_asked()
     vocab = int(config["vocab_size"])
     weight_seed = seeds[0] % 2147483629
@@ -82,17 +83,16 @@ def main(argv=None) -> int:
         plan = traffic.make_plan(mix, seed, bench["run_seconds"], vocab)
         groups.append(correctness.sequences(plan, seed, vocab, max_seq))
     flat = [s for g in groups for s in g]
-    stated = correctness.cache_bytes_stated(config)
-    counted = stated
+    counted = None
 
-    if args.weight_bits < 8:
+    if args.weight_bits is not None:
         # the reference in the program's place, weights rounded: it scores
         # the probes a system would have been asked for, with token 3 as
         # every generated token
         for s in flat:
             pretend(s)
-        rounded = correctness.run_reference(
-            config_file, weight_seed, flat, work, platform,
+        rounded, _ = correctness.run_reference(
+            config_file, data, weight_seed, flat, work, platform,
             weight_bits=args.weight_bits)
         for s, lp in zip(flat, rounded):
             s["system"] = list(lp)
@@ -110,8 +110,10 @@ def main(argv=None) -> int:
             counted = correctness.cache_bytes_counted(st.port, config)
         finally:
             st.stop()
-    plain = correctness.run_reference(config_file, weight_seed, flat, work,
-                                      platform)
+    plain, stated = correctness.run_reference(config_file, data, weight_seed,
+                                              flat, work, platform)
+    if args.weight_bits is not None:
+        counted = stated  # no stack, no pool: only the log-probabilities
     limits = config["correct"]["limits"]
     rows, at = [], 0
     for seed, group in zip(seeds, groups):

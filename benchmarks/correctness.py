@@ -35,19 +35,27 @@ width instead: the bytes a cached token takes, as the program's own
 accounting of its prefix pool gives them on ``/healthz``, are those of the
 stated type exactly.
 
+What a cached token holds, which published keys the reference reads and
+the reference itself belong to the configuration's model family: a module
+the configuration file names (``"reference": "<module>"``, ``reference``
+when absent), found beside the data files (``family``).
+
 Limits and their reasons are in the configuration file.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import os
 import random
+import re
 import subprocess
 import sys
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from benchmarks import stack
 from benchmarks.stack import BenchFailure
@@ -65,7 +73,58 @@ LADDER_FIRST, LADDER_STEP = 32, 8
 ASK_AT_ONCE = 8
 NUMBERS = ("echo_prompt", "echo_decode", "traffic_decode", "traffic_prefill")
 
-_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
+#: Bytes of one value of every type a configuration's ``precision`` or a
+#: control may name.
+TYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1,
+              "int4": 0.5}
+#: What every family's module gives (benchmarks/README.md, "A model family").
+FAMILY_NAMES = ("REQUIRED_KEYS", "shapes_of", "make_weights",
+                "forward_logprobs", "cache_bytes_per_token")
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: str):
+    """A module found by its file's name under a data root (a family's
+    reference, a per-layer reader), loaded once."""
+    spec = importlib.util.spec_from_file_location(
+        "benchmarks_file_" + os.path.basename(path)[:-3], path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def family(config: Dict, data: str):
+    """The module of the configuration's model family, ``<data>/<name>.py``:
+    ``data`` is the benchmark's directory of the root that holds
+    BENCHMARK.json, where the readers are found too.  It imports JAX: a run
+    asks in the reference's process, never in the one that starts it."""
+    name = config.get("reference", "reference")
+    if not isinstance(name, str) or not re.fullmatch(r"[A-Za-z0-9_]+", name):
+        raise BenchFailure(f"a reference is named by a module's name, not "
+                           f"{name!r}")
+    path = os.path.join(os.path.abspath(data), name + ".py")
+    if not os.path.exists(path):
+        raise BenchFailure(f"the configuration names the reference {name!r}; "
+                           f"there is no {path}")
+    module = load_module(path)
+    lacks = [n for n in FAMILY_NAMES if not hasattr(module, n)]
+    if lacks:
+        raise BenchFailure(f"{path} gives no {', '.join(lacks)}")
+    return module
+
+
+def check_published(config: Dict, fam) -> None:
+    """The published keys that the family's reference reads are whole
+    numbers in the file."""
+    for key in ("hidden_size", "num_hidden_layers", "vocab_size",
+                *fam.REQUIRED_KEYS):
+        value = config.get(key)
+        if not isinstance(value, int) or isinstance(value, bool):
+            raise BenchFailure(
+                f"the configuration's {key} is {value!r}: the reference "
+                f"{os.path.basename(fam.__file__)} needs a whole number "
+                f"there; a model of another family names its own "
+                f"`reference`")
 
 
 def _ids(text: str) -> List[int]:
@@ -160,13 +219,19 @@ def ask_engine(port: int, seqs: List[Dict]) -> List[str]:
     return faults
 
 
-def run_reference(config_file: str, weight_seed: int, seqs: List[Dict],
-                  work: str, platform: str, weight_bits: int = 8) -> List:
-    """The reference's log-probability at every probe of every sequence."""
+def run_reference(config_file: str, data: str, weight_seed: int,
+                  seqs: List[Dict], work: str, platform: str,
+                  weight_bits: Optional[int] = None) -> Tuple[List, int]:
+    """The reference's log-probability at every probe of every sequence,
+    and the bytes its family's module says a cached token takes
+    (``cache_bytes_stated``, asked in the reference's process).
+    ``weight_bits`` None: the weights as the configuration states them; a
+    number: the reference's own weights rounded to that many bits (a
+    control)."""
     spec = os.path.join(work, "reference_in.json")
     out = os.path.join(work, "reference_out.json")
     with open(spec, "w") as f:
-        json.dump({"config": config_file, "seed": weight_seed,
+        json.dump({"config": config_file, "data": data, "seed": weight_seed,
                    "platform": platform, "weight_bits": weight_bits,
                    "sequences": [{"tokens": s["tokens"],
                                   "probes": s["probes"]} for s in seqs]}, f)
@@ -190,7 +255,8 @@ def run_reference(config_file: str, weight_seed: int, seqs: List[Dict],
             tail = lf.read().decode("utf-8", "replace")[-2000:]
         raise BenchFailure(f"the reference exited with code {code}:\n{tail}")
     with open(out) as f:
-        return json.load(f)
+        said = json.load(f)
+    return said["logprobs"], said["cache_bytes_per_token"]
 
 
 def compare(seqs: List[Dict], reference: List[List[float]]) -> Dict[str, Dict]:
@@ -204,15 +270,13 @@ def compare(seqs: List[Dict], reference: List[List[float]]) -> Dict[str, Dict]:
             for k, v in diffs.items()}
 
 
-def cache_bytes_stated(config: Dict) -> int:
-    """Bytes of K and V that one cached token takes in every layer, in the
-    type the configuration states for the cache."""
-    heads = int(config["num_attention_heads"])
-    head_dim = int(config.get("head_dim")
-                   or int(config["hidden_size"]) // heads)
-    return (int(config["num_hidden_layers"]) * 2
-            * int(config["num_key_value_heads"]) * head_dim
-            * _BYTES[config["precision"]["kv_cache"]])
+def cache_bytes_stated(config: Dict, data: str) -> int:
+    """Bytes that one cached token takes in every layer, in the type the
+    configuration states for the cache: what its family's module says a
+    token caches (keys and values of the KV heads, a latent, ...)."""
+    fam = family(config, data)
+    check_published(config, fam)
+    return fam.cache_bytes_per_token(config)
 
 
 def cache_bytes_counted(port: int, config: Dict) -> Optional[float]:

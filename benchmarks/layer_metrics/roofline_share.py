@@ -1,7 +1,8 @@
 """A decode step's share (%) of its roofline: the least time the chip
 could take for the step (benchmarks/roofline.py, from the configuration's
 sizes and the live tokens the client had in flight during the traced
-window) over the step's measured device time."""
+window) over the step's measured device time: that of the decode runs the
+program's own dispatch records account for (``dispatch_device``)."""
 
 import importlib.util
 import os
@@ -39,10 +40,10 @@ def live_rows_and_tokens(ctx):
     return rows / samples, tokens / samples
 
 
-def read(ctx, modules):
-    if ctx.trace is None or ctx.peaks is None or ctx.trace_span is None:
+def read(ctx):
+    if ctx.peaks is None or ctx.trace_span is None:
         return None
-    step_ms = _sibling("program_time").read(ctx, modules, "step")
+    step_ms = _sibling("dispatch_device").read(ctx, "step")
     if not step_ms:
         return None
     rows, tokens = live_rows_and_tokens(ctx)

@@ -21,6 +21,9 @@ comparison in ``correctness.py`` fails, which is the point.
 Shapes come from the configuration file's published keys
 (``hidden_size``, ``num_hidden_layers``, ...), never from a preset of the
 program.
+
+This is the dense family's module (benchmarks/README.md, "A model family"):
+a configuration that names no ``reference`` gets this one.
 """
 
 from __future__ import annotations
@@ -32,8 +35,16 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from benchmarks.correctness import TYPE_BYTES
+
 #: What the int8 draw spans; the scale divides by it.
 _QMAX = 127.0
+
+#: The published keys ``shapes_of`` and ``cache_bytes_per_token`` need as
+#: whole numbers, beside the three every family has (``hidden_size``,
+#: ``num_hidden_layers``, ``vocab_size``).
+REQUIRED_KEYS = ("num_attention_heads", "num_key_value_heads",
+                 "intermediate_size")
 
 
 def shapes_of(config: Dict[str, Any]) -> Dict[str, Any]:
@@ -59,6 +70,14 @@ def shapes_of(config: Dict[str, Any]) -> Dict[str, Any]:
         "qkv_bias": bool(config.get("attention_bias",
                                     config.get("model_type") == "qwen2")),
     }
+
+
+def cache_bytes_per_token(config: Dict[str, Any]) -> int:
+    """Bytes of K and V that one cached token takes in every layer, in the
+    type the configuration states for the cache."""
+    s = shapes_of(config)
+    return int(s["layers"] * 2 * s["kv_heads"] * s["head_dim"]
+               * TYPE_BYTES[config["precision"]["kv_cache"]])
 
 
 def _draw(key, shape, fan_in: int, scale_len_axes):
@@ -110,10 +129,11 @@ class _Frozen(dict):
         return hash(tuple(sorted(self.items())))
 
 
-def _requant(qw: Dict[str, Any], bits: int) -> Dict[str, Any]:
+def _requant(qw: Dict[str, Any], bits: Optional[int]) -> Dict[str, Any]:
     """The control's weights: the same matrix rounded onto a coarser
-    symmetric grid of ``bits`` bits (int4: [-7, 7])."""
-    if bits >= 8:
+    symmetric grid of ``bits`` bits (int4: [-7, 7]).  None: the weights as
+    drawn, which are the int8 the configurations state."""
+    if bits is None or bits >= 8:
         return qw
     top = float(2 ** (bits - 1) - 1)
     q = jnp.round(qw["q"].astype(jnp.float32) * (top / _QMAX))
@@ -180,7 +200,7 @@ _LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                "bq", "bk", "bv")
 
 
-def _take_layer(weights, i, bits: int):
+def _take_layer(weights, i, bits: Optional[int]):
     """Layer ``i`` of the stacked weights (``i`` may be traced)."""
     def at(a):
         return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
@@ -199,7 +219,7 @@ def _take_layer(weights, i, bits: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _program(s: "_Frozen", weight_bits: int):
+def _program(s: "_Frozen", weight_bits: Optional[int]):
     """The whole forward as one jitted function of (weights, tokens): a loop
     over the layers, one layer's weights in float32 at a time (a 7B model
     in float32 does not fit one chip).  Compiled once for each
@@ -223,16 +243,18 @@ def _program(s: "_Frozen", weight_bits: int):
 
 
 def forward_logprobs(shapes: Dict[str, Any], weights: Dict[str, Any],
-                     tokens, weight_bits: int = 8) -> jnp.ndarray:
+                     tokens, weight_bits: Optional[int] = None) -> jnp.ndarray:
     """log-softmax of the next-token logits at every position: [T, vocab].
-    ``weight_bits`` below 8 is the control: the same arithmetic on weights
-    rounded to that many bits."""
-    return _program(_Frozen(shapes), int(weight_bits))(
+    ``weight_bits`` None is the model as the configuration states it; a
+    number is the control: the same arithmetic on weights rounded to that
+    many bits."""
+    bits = None if weight_bits is None else int(weight_bits)
+    return _program(_Frozen(shapes), bits)(
         weights, jnp.asarray(tokens, jnp.int32))
 
 
 def token_logprobs(shapes: Dict[str, Any], weights: Dict[str, Any], tokens,
-                   weight_bits: int = 8) -> jnp.ndarray:
+                   weight_bits: Optional[int] = None) -> jnp.ndarray:
     """log P(tokens[t + 1] | tokens[..t]) for every t: [T - 1]."""
     tokens = jnp.asarray(tokens, jnp.int32)
     lp = forward_logprobs(shapes, weights, tokens, weight_bits)

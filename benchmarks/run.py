@@ -23,7 +23,6 @@ _PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -44,7 +43,9 @@ TRACE_S = 3.0
 #: A cold start at 7B: engine build plus every program compiled from
 #: nothing, inside the 1200 s a first run may take.
 READY_DEADLINE_S = 900.0
-#: Counters read at the window's two ends.
+#: Counters read at the window's two ends in every cell: ``correct``'s
+#: compile check and the readers that came with the first cells use them.
+#: A per-layer metric's file names any other it reads (``"counters"``).
 WINDOW_COUNTERS = ["engine_cold_compiles_total",
                    "engine_prefix_hit_tokens_total",
                    "engine_prefill_tokens_total", "engine_tokens_total"]
@@ -86,14 +87,39 @@ class Context:
         self.peaks: Optional[dict] = None
 
 
+def metric_spec(data: str, name: str) -> dict:
+    return load_json(os.path.join(data, "layer_metrics", name + ".json"))
+
+
+def counters_of(data: str, per_layer: List[dict]) -> List[str]:
+    """``WINDOW_COUNTERS`` and, after them, the counters that the cell's
+    per-layer metrics name in their own files."""
+    names = list(WINDOW_COUNTERS)
+    for m in per_layer:
+        for name in metric_spec(data, m["name"]).get("counters", []):
+            if name not in names:
+                names.append(name)
+    return names
+
+
+def read_counters(text: str, names: List[str]) -> Dict[str, float]:
+    """The named counters of a ``/metrics`` exposition.  One that a metric's
+    file names and this program does not publish is left out (its reader
+    then finds nothing to read); ``WINDOW_COUNTERS`` have to be there."""
+    out = {}
+    for name in names:
+        try:
+            out[name] = stack.metric_value(text, name)
+        except BenchFailure:
+            if name in WINDOW_COUNTERS:
+                raise
+    return out
+
+
 def _reader(data: str, name: str):
     """The reader module ``layer_metrics/<name>.py`` of the data root."""
-    path = os.path.join(data, "layer_metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks_reader_" + name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    return correctness.load_module(
+        os.path.join(os.path.abspath(data), "layer_metrics", name + ".py"))
 
 
 def read_layer_metrics(ctx: Context, wanted: List[dict],
@@ -102,8 +128,7 @@ def read_layer_metrics(ctx: Context, wanted: List[dict],
     nothing to read returns None and the metric is left out."""
     out = {}
     for m in wanted:
-        spec = load_json(os.path.join(data, "layer_metrics",
-                                      m["name"] + ".json"))
+        spec = metric_spec(data, m["name"])
         value = _reader(data, spec["reader"]).read(ctx, **spec.get("args", {}))
         if value is None:
             say(f"per-layer {m['name']}: nothing to read")
@@ -112,21 +137,21 @@ def read_layer_metrics(ctx: Context, wanted: List[dict],
     return out
 
 
-async def _window_counters(port: int, t0: float, t1: float,
+async def _window_counters(port: int, names: List[str], t0: float, t1: float,
                            into: Dict[str, float]) -> None:
     async def read() -> Dict[str, float]:
         _, raw = await client.fetch("127.0.0.1", port, "GET", "/metrics")
-        text = raw.decode()
-        return {n: stack.metric_value(text, n) for n in WINDOW_COUNTERS}
+        return read_counters(raw.decode(), names)
 
     await client._sleep_until(t0)
     first = await read()
     await client._sleep_until(t1)
     last = await read()
-    into.update({n: last[n] - first[n] for n in WINDOW_COUNTERS})
+    into.update({n: last[n] - first[n] for n in first if n in last})
 
 
-async def _trace_window(port: int, serve_pid: int, start: float, length: float,
+async def _trace_window(port: int, names: List[str], serve_pid: int,
+                        start: float, length: float,
                         polls: List[tuple]) -> None:
     """Signal the serve process to record its device trace, and poll the
     counters a few times a second around it so that their values at the
@@ -139,10 +164,7 @@ async def _trace_window(port: int, serve_pid: int, start: float, length: float,
             os.kill(serve_pid, signal.SIGUSR1)
             signalled = True
         _, raw = await client.fetch("127.0.0.1", port, "GET", "/metrics")
-        text = raw.decode()
-        polls.append((time.monotonic(),
-                      {n: stack.metric_value(text, n)
-                       for n in WINDOW_COUNTERS}))
+        polls.append((time.monotonic(), read_counters(raw.decode(), names)))
         await asyncio.sleep(0.2)
 
 
@@ -201,6 +223,7 @@ def run(argv=None) -> int:
     definitions = {m["name"]: load_json(os.path.join(
         data, "end_to_end", m["name"] + ".json")) for m in e2e}
     limits = config["correct"]["limits"]
+    counter_names = counters_of(data, per_layer)
 
     platform = stack.platform_asked()
     weight_seed = args.seed % 2147483629
@@ -253,11 +276,12 @@ def run(argv=None) -> int:
         setup_s = t0 - _PROCESS_START
 
         async def on_window(w0: float, w1: float) -> None:
-            jobs = [_window_counters(port, w0, w1, ctx.counters)]
+            jobs = [_window_counters(port, counter_names, w0, w1,
+                                     ctx.counters)]
             if args.trace:
                 jobs.append(_trace_window(
-                    port, st.serve.pid, w0 + (args.seconds - trace_s) / 2.0,
-                    trace_s, ctx.polls))
+                    port, counter_names, st.serve.pid,
+                    w0 + (args.seconds - trace_s) / 2.0, trace_s, ctx.polls))
             await asyncio.gather(*jobs)
 
         load = asyncio.run(client.offer(plan, "127.0.0.1", port,
@@ -293,8 +317,8 @@ def run(argv=None) -> int:
 
     # The serve process has let the chip go: the reference takes it.
     say("stack stopped; starting the reference")
-    reference = correctness.run_reference(config_file, weight_seed, seqs,
-                                          work, platform)
+    reference, stated = correctness.run_reference(
+        config_file, data, weight_seed, seqs, work, platform)
     say("reference done")
     numbers = correctness.compare(seqs, reference)
     if args.trace:
@@ -305,8 +329,7 @@ def run(argv=None) -> int:
     bad = [(o.index, o.failed()) for o in load.outcomes if o.failed()]
     for index, why in bad[:10]:
         say(f"request {index} failed: {why}")
-    correct = correctness.judge(numbers, limits, counted,
-                                correctness.cache_bytes_stated(config), say)
+    correct = correctness.judge(numbers, limits, counted, stated, say)
     for fault in faults:
         say(f"correct: {fault}")
     correct = correct and not faults and not bad and cold == 0

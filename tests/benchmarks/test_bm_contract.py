@@ -101,37 +101,235 @@ def test_per_layer_metrics(metric):
         assert metric["unit"] == "%"
 
 
-@pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
-def test_configurations(cfg):
+def is_a_width(key):
+    """A key ``reduced`` may never name (the vocabulary may be sliced)."""
+    return (key.endswith(("_dim", "_rank")) or key == "num_experts_per_tok"
+            or (key.endswith("_size") and key != "vocab_size"))
+
+
+def check_configuration(cfg, body, data_root):
+    """What the benchmark asks of every configuration, whatever its model
+    family: ``cfg`` is its entry in BENCHMARK.json, ``body`` its file,
+    ``data_root`` the directory where its family's module is found."""
+    from benchmarks import correctness
+
     assert set(cfg) == {"name", "source", "file", "reduced", "why"}
     assert cfg["source"].startswith("https://huggingface.co/")
     assert PATH.match(cfg["file"]) and cfg["file"].startswith("benchmarks/")
-    assert any(w["config"] == cfg["name"] for w in BENCH["workloads"])
-    body = load(os.path.join(REPO, cfg["file"]))
     assert body["source"] == cfg["source"] and body["reduced"] == cfg["reduced"]
+    assert len(cfg["reduced"]) <= 16
+    assert not any(is_a_width(k) for k in cfg["reduced"]), cfg["reduced"]
     for key in ("assumed", "precision", "serve", "deployment", "correct"):
         assert key in body, key
-    for key in ("hidden_size", "intermediate_size", "num_hidden_layers",
-                "num_attention_heads", "num_key_value_heads", "vocab_size"):
-        assert isinstance(body[key], int)
-    from benchmarks import correctness
+    # the published keys its own reference reads are whole numbers
+    fam = correctness.family(body, data_root)
+    correctness.check_published(body, fam)
+    kv_block = body["serve"]["kv_block_tokens"]
+    assert isinstance(kv_block, int) and kv_block > 0
+    # every stated precision is of a type the harness knows the bytes of
+    precision = body["precision"]
+    assert set(precision) == {"weights", "activations", "kv_cache"}
+    assert set(precision.values()) <= set(correctness.TYPE_BYTES)
 
     limits = body["correct"]["limits"]
     assert set(limits) == set(correctness.NUMBERS)
-    # every limit stands between its two readings, with room on both sides
     sound, controls = body["correct"]["sound"], body["correct"]["controls"]
     assert sound["seeds"] >= 12
+    stated = correctness.cache_bytes_stated(body, data_root)
+    assert sound["cache_bytes_per_token"] == stated
+    # every limit stands over the sound runs' largest, with room
     for name, limit in limits.items():
         assert 1.2 * sound[name][1] <= limit, name
-        assert limit <= controls["w8a8"][name][0] / 1.2, name
-        if name in controls["int4_weights"]:
-            assert limit <= controls["int4_weights"][name][0] / 5, name
-    assert sound["cache_bytes_per_token"] == correctness.cache_bytes_stated(
-        body)
-    assert body["serve"]["kv_block_tokens"] == 16
-    assert body["precision"] == {"weights": "int8", "activations": "bfloat16",
-                                 "kv_cache": "bfloat16"}
-    assert not any(k.endswith(("_dim", "_rank")) for k in cfg["reduced"])
+    # ... and under the smallest reading of each control that fails on it
+    lowered = set()
+    for name, control in controls.items():
+        to, was = control["to"], precision[control["lowers"]]
+        assert correctness.TYPE_BYTES[to] < correctness.TYPE_BYTES[was], name
+        assert control["fails"] and control["fails_on"], \
+            f"the control {name} does not fail"
+        # an integer grid of half the bits is 16 times coarser: it has to
+        # fail by far
+        room = 5 if (control["lowers"] == "weights"
+                     and to.startswith("int") and was.startswith("int")
+                     and 2 * correctness.TYPE_BYTES[to]
+                     == correctness.TYPE_BYTES[was]) else 1.2
+        for number in control["fails_on"]:
+            if number == "cache_bytes_per_token":
+                assert control[number] != stated, \
+                    f"the control {name} does not fail on {number}"
+            else:
+                assert limits[number] <= control[number][0] / room, \
+                    f"the control {name} does not fail on {number}"
+        lowered.add(control["lowers"])
+    assert lowered == set(precision), \
+        f"no control lowers {sorted(set(precision) - lowered)}"
+
+
+@pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
+def test_configurations(cfg):
+    assert any(w["config"] == cfg["name"] for w in BENCH["workloads"])
+    check_configuration(cfg, load(os.path.join(REPO, cfg["file"])),
+                        data())
+
+
+# ---- a configuration of another family, in a root of its own ----------------
+
+#: The ``config`` of the catalog's ``sarvam-105b`` row, verbatim (source:
+#: https://huggingface.co/sarvamai/sarvam-105b/blob/main/config.json), with
+#: the KV-head count as the row's top level gives it, null: latent attention
+#: has no KV heads to count.
+MLA_PUBLISHED = load(os.path.join(REPO, "tests", "benchmarks", "data",
+                                  "sarvam-105b.published.json"))
+
+#: A family's module that states what a token caches and which keys it
+#: reads, and no forward: enough for the contract, not for a run.
+MLA_STUB = '''"""Latent attention and routed experts: the statement, no equations."""
+from benchmarks.correctness import TYPE_BYTES
+
+REQUIRED_KEYS = ("num_attention_heads", "kv_lora_rank", "qk_rope_head_dim",
+                 "qk_nope_head_dim", "v_head_dim", "intermediate_size",
+                 "moe_intermediate_size", "num_experts",
+                 "num_experts_per_tok", "num_shared_experts",
+                 "first_k_dense_replace")
+
+
+def cache_bytes_per_token(config):
+    """The normed latent and the one roped key all heads share."""
+    return int(config["num_hidden_layers"]
+               * (config["kv_lora_rank"] + config["qk_rope_head_dim"])
+               * TYPE_BYTES[config["precision"]["kv_cache"]])
+
+
+def shapes_of(config):
+    raise NotImplementedError
+
+
+make_weights = forward_logprobs = shapes_of
+'''
+
+
+def mla_configuration(**over):
+    numbers = dict.fromkeys(("echo_prompt", "echo_decode", "traffic_decode",
+                             "traffic_prefill"), [0.004, 0.005])
+    failing = dict.fromkeys(numbers, [0.010, 0.012])
+    body = dict(
+        MLA_PUBLISHED, name="mla", reference="mla_reference",
+        source="https://huggingface.co/sarvamai/sarvam-105b/blob/main/"
+               "config.json",
+        reduced=["num_hidden_layers", "num_experts", "vocab_size"],
+        assumed={}, deployment="a test",
+        precision={"weights": "bfloat16", "activations": "bfloat16",
+                   "kv_cache": "bfloat16"},
+        serve={"model": "none", "max_seq": 4096, "kv_block_tokens": 16,
+               "args": []},
+        correct={
+            "limits": dict.fromkeys(numbers, 0.0075),
+            "sound": dict(numbers, seeds=12,
+                          cache_bytes_per_token=32 * 576 * 2),
+            "controls": {
+                "int8_weights": dict(failing, lowers="weights", to="int8",
+                                     fails=True, fails_on=list(numbers)),
+                "int8_activations": dict(failing, lowers="activations",
+                                         to="int8", fails=True,
+                                         fails_on=list(numbers)),
+                "int8_cache": dict(lowers="kv_cache", to="int8", fails=True,
+                                   fails_on=["cache_bytes_per_token"],
+                                   cache_bytes_per_token=32 * 592)}})
+    body.update(over)
+    cfg = {"name": "mla", "source": body["source"],
+           "file": "benchmarks/configs/mla.json",
+           "reduced": body["reduced"], "why": "a test"}
+    return cfg, body
+
+
+def quiet_control(body, **change):
+    """The configuration with its activations control changed."""
+    controls = dict(body["correct"]["controls"])
+    controls["int8_activations"] = dict(controls["int8_activations"],
+                                        **change)
+    return dict(body["correct"], controls=controls)
+
+
+@pytest.fixture()
+def family_root(tmp_path):
+    """A data root with the dense family's module, as the repository has
+    it, and the stub's beside it."""
+    import shutil
+
+    shutil.copy(data("reference.py"), tmp_path)
+    (tmp_path / "mla_reference.py").write_text(MLA_STUB)
+    return str(tmp_path)
+
+
+def dense_keys_left_null():
+    """What the dense family's reference needs and the published row does
+    not give."""
+    from benchmarks import reference
+
+    return [k for k in reference.REQUIRED_KEYS if MLA_PUBLISHED[k] is None]
+
+
+def test_a_configuration_without_kv_heads_is_accepted_with_its_own_family(
+        family_root):
+    cfg, body = mla_configuration()
+    assert len(dense_keys_left_null()) == 1
+    check_configuration(cfg, body, family_root)
+
+
+REFUSED = {
+    "no-family-of-its-own": (
+        lambda body: dict(body, reference="reference"),
+        lambda: dense_keys_left_null()[0] + " is None"),
+    "a-family-that-is-no-file": (
+        lambda body: dict(body, reference="no_such_family"),
+        "there is no "),
+    "a-family-named-by-a-path": (
+        lambda body: dict(body, reference="../reference"),
+        "named by a module's name"),
+    "a-required-key-is-no-whole-number": (
+        lambda body: dict(body, kv_lora_rank=512.5), "kv_lora_rank is 512.5"),
+    "a-control-that-does-not-fail": (
+        lambda body: dict(body, correct=quiet_control(body, fails=False)),
+        "int8_activations does not fail"),
+    "a-control-that-reads-inside-a-limit": (
+        lambda body: dict(body, correct=quiet_control(
+            body, echo_decode=[0.0080, 0.012])),
+        "does not fail on echo_decode"),
+    "a-precision-no-control-lowers": (
+        lambda body: dict(body, correct=dict(
+            body["correct"], controls={
+                k: v for k, v in body["correct"]["controls"].items()
+                if k != "int8_cache"})),
+        "no control lowers ['kv_cache']"),
+    "a-cache-control-of-the-stated-width": (
+        lambda body: dict(body, correct=dict(
+            body["correct"], controls=dict(
+                body["correct"]["controls"], int8_cache=dict(
+                    body["correct"]["controls"]["int8_cache"],
+                    cache_bytes_per_token=32 * 576 * 2)))),
+        "does not fail on cache_bytes_per_token"),
+    "a-type-of-unknown-width": (
+        lambda body: dict(body, precision=dict(body["precision"],
+                                               kv_cache="fp6")), "fp6"),
+    "a-width-in-reduced": (
+        lambda body: dict(body, reduced=["kv_lora_rank"]), "kv_lora_rank"),
+    "an-expert-width-in-reduced": (
+        lambda body: dict(body, reduced=["moe_intermediate_size"]),
+        "moe_intermediate_size"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSED))
+def test_a_configuration_is_refused(case, family_root):
+    change, message = REFUSED[case]
+    cfg, body = mla_configuration()
+    body = change(body)
+    cfg = dict(cfg, reduced=body["reduced"])
+    from benchmarks.stack import BenchFailure
+
+    with pytest.raises((AssertionError, BenchFailure, KeyError)) as e:
+        check_configuration(cfg, body, family_root)
+    assert (message() if callable(message) else message) in str(e.value)
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
@@ -178,3 +376,63 @@ def test_every_percentile_reported_names_its_percentile():
         if found:
             assert spec["percentile"] == int(found.group(1))
             assert spec["percentile"] <= 90  # what 100+ requests support
+
+
+# ---- window counters are data ------------------------------------------------
+
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
+def test_an_accepted_cell_reads_the_four_counters_it_always_read(cell):
+    from benchmarks import run
+
+    per_layer = run.metrics_of(BENCH, "per_layer", cell["name"])
+    assert per_layer
+    assert run.counters_of(data(), per_layer) == run.WINDOW_COUNTERS
+    assert len(run.WINDOW_COUNTERS) == 4
+
+
+METRICS_TEXT = """# HELP engine_tokens_total tokens
+engine_cold_compiles_total 0
+engine_prefix_hit_tokens_total 12
+engine_prefill_tokens_total 3400
+engine_tokens_total 560
+engine_decode_row_steps_total 1700
+"""
+
+
+@pytest.mark.parametrize("named,read", [
+    ([], {}),
+    (["engine_decode_row_steps_total"],
+     {"engine_decode_row_steps_total": 1700.0}),
+    # one the program does not publish is left out, not an error
+    (["engine_decode_row_steps_total", "engine_no_such_total"],
+     {"engine_decode_row_steps_total": 1700.0}),
+    # one of the four named again is read once
+    (["engine_tokens_total"], {}),
+], ids=["none", "one", "unpublished", "one-of-the-four"])
+def test_a_metrics_file_names_the_counters_it_reads(tmp_path, named, read):
+    from benchmarks import run
+
+    metrics = tmp_path / "layer_metrics"
+    metrics.mkdir()
+    (metrics / "plain.json").write_text(json.dumps({"reader": "x"}))
+    (metrics / "counting.json").write_text(
+        json.dumps({"reader": "x", "counters": named}))
+    names = run.counters_of(str(tmp_path),
+                            [{"name": "plain"}, {"name": "counting"}])
+    assert names[:4] == run.WINDOW_COUNTERS
+    assert len(names) == len(set(names))
+    assert set(names) == set(run.WINDOW_COUNTERS) | set(named)
+    got = run.read_counters(METRICS_TEXT, names)
+    assert got == dict({"engine_cold_compiles_total": 0.0,
+                        "engine_prefix_hit_tokens_total": 12.0,
+                        "engine_prefill_tokens_total": 3400.0,
+                        "engine_tokens_total": 560.0}, **read)
+
+
+def test_one_of_the_four_counters_missing_fails_the_run():
+    from benchmarks import run
+    from benchmarks.stack import BenchFailure
+
+    text = METRICS_TEXT.replace("engine_cold_compiles_total 0\n", "")
+    with pytest.raises(BenchFailure, match="engine_cold_compiles_total"):
+        run.read_counters(text, run.WINDOW_COUNTERS)

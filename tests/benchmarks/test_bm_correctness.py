@@ -10,6 +10,7 @@ import pytest
 from benchmarks import correctness, traffic
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+DATA = os.path.join(REPO, "benchmarks")
 
 
 def load(*parts):
@@ -25,14 +26,41 @@ def load(*parts):
 def test_cache_bytes_of_a_token_as_the_configuration_states_them(name, want):
     config = load("configs", name + ".json")
     assert config["precision"]["kv_cache"] == "bfloat16"
-    assert correctness.cache_bytes_stated(config) == want
+    assert correctness.cache_bytes_stated(config, DATA) == want
     # the pool the file sizes is those bytes, blocks and tokens
     blocks = int(config["serve"]["args"][
         config["serve"]["args"].index("--prefix-pool-blocks") + 1])
     gib = blocks * config["serve"]["kv_block_tokens"] * want / 2**30
     assert gib == {"mistral-7b": 2.0, "qwen2-7b": 0.875}[name]
     half = dict(config, precision=dict(config["precision"], kv_cache="int8"))
-    assert correctness.cache_bytes_stated(half) == want // 2
+    assert correctness.cache_bytes_stated(half, DATA) == want // 2
+
+
+@pytest.mark.parametrize("kv_cache,want", [("bfloat16", 6 * 576 * 2),
+                                           ("int8", 6 * 576)])
+def test_the_cache_statement_is_the_familys(tmp_path, kv_cache, want):
+    """A configuration that names its reference is held to what that module
+    says a token caches: here a latent of 512 and one shared roped key of
+    64 a layer, and no KV head in it."""
+    from test_bm_contract import MLA_PUBLISHED, MLA_STUB
+
+    (tmp_path / "latent.py").write_text(MLA_STUB)
+    config = dict(MLA_PUBLISHED, reference="latent", num_hidden_layers=6,
+                  precision={"kv_cache": kv_cache})
+    assert config["num_key_value_heads"] is None
+    assert correctness.cache_bytes_stated(config, str(tmp_path)) == want
+    # without a module of its own it is the dense family's, which needs
+    # KV heads: refused by the key's name
+    del config["reference"]
+    with pytest.raises(correctness.BenchFailure,
+                       match="num_key_value_heads is None"):
+        correctness.cache_bytes_stated(config, DATA)
+
+
+def test_a_family_module_has_to_give_all_five_names(tmp_path):
+    (tmp_path / "half.py").write_text("REQUIRED_KEYS = ()\n")
+    with pytest.raises(correctness.BenchFailure, match="gives no shapes_of"):
+        correctness.family({"reference": "half"}, str(tmp_path))
 
 
 @pytest.fixture(scope="module")

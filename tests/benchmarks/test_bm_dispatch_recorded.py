@@ -109,3 +109,43 @@ def test_recorded_scopes_and_the_span_readers(recorded, summary):
     wait = reader("prefill_wait")
     assert len(wait.waits_ms(ctx)) == 23
     assert wait.read(ctx, 50) == pytest.approx(190.385)
+
+
+def test_the_roofline_share_reads_the_ledgers_step(recorded, summary):
+    """``decode_roofline``: the least step time for what the client had in
+    flight over the step the dispatch records give (18.77 ms here), and
+    nothing without the chip's peaks or a traced window."""
+    from types import SimpleNamespace
+
+    from benchmarks import roofline
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(DATA)))
+    with open(os.path.join(repo, "benchmarks", "configs",
+                           "mistral-7b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(repo, "benchmarks", "peaks.json")) as f:
+        peaks = json.load(f)["devices"]["TPU v5 lite"]
+    share = reader("roofline_share")
+    ctx = ctx_of(recorded, summary)
+    # 17 requests decoding all through the traced window, 200 prompt words
+    # and 10 tokens each so far
+    ctx.config, ctx.peaks, ctx.trace_span = config, peaks, (100.0, 103.0)
+    ctx.plan = SimpleNamespace(all_requests=lambda: [
+        SimpleNamespace(index=i, prompt_words=200) for i in range(17)])
+    ctx.load = SimpleNamespace(outcomes=[
+        SimpleNamespace(index=i, first_token=90.0, last_token=110.0,
+                        tokens_seen=40, asked=64, token_times=[(95.0, 10)])
+        for i in range(17)])
+    least = roofline.least_step_seconds(config, peaks, 17.0, 17 * 210.0)
+    got = share.read(ctx)
+    assert got == pytest.approx(100.0 * least["seconds"] * 1000.0
+                                / 18.76504465)
+    assert 45.0 < got < 55.0 and least["bound"] == "memory"
+    ctx.peaks = None          # a CPU rehearsal has no peaks
+    assert share.read(ctx) is None
+    ctx.peaks, ctx.trace_span = peaks, None
+    assert share.read(ctx) is None
+    # a run whose trace paired no decode burst has no step to hold it against
+    bare = Ctx([], dict(summary, pairs={}))
+    bare.peaks, bare.trace_span = peaks, (100.0, 103.0)
+    assert share.read(bare) is None

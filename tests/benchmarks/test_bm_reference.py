@@ -187,3 +187,20 @@ def test_int4_weights_fail_the_tolerance(name, over):
     picked = [abs(ctl[t, tokens[t + 1]] - ref[t, tokens[t + 1]])
               for t in range(len(tokens) - 1)]
     assert np.mean(picked) > 10 * TOLERANCE
+
+
+@pytest.mark.parametrize("name,over", CASES[:2], ids=["tiny", "tiny-qwen"])
+def test_as_stated_is_the_int8_the_dense_family_draws(name, over):
+    """``weight_bits=None`` (the weights as the configuration states them)
+    and ``8`` are the same arithmetic for the dense module, bit for bit."""
+    from p2p_llm_tunnel_tpu.models.config import get_config
+
+    cfg = get_config(name, **over)
+    shapes = reference.shapes_of(published(cfg))
+    weights = reference.make_weights(shapes, 2**31 + 3)
+    tokens = list(np.random.RandomState(4).randint(3, cfg.vocab_size, size=32))
+    stated = np.asarray(reference.forward_logprobs(shapes, weights, tokens))
+    eight = np.asarray(reference.forward_logprobs(shapes, weights, tokens,
+                                                  weight_bits=8))
+    assert stated.tobytes() == eight.tobytes()
+    assert reference._requant(weights["wq"], None) is weights["wq"]

@@ -2,8 +2,10 @@
 configuration, tiny mixes, an added end-to-end metric and an added
 per-layer metric, none of which edits a file the benchmark has.  Then the
 controls of ``correct`` through the same stack at tiny size: what
-``benchmarks/control.py`` reads on the chip at 7B when a limit is set.  (One
-file, so that one worker runs these stacks one after another.)"""
+``benchmarks/control.py`` reads on the chip at 7B when a limit is set.  Last,
+a cell of a second model family (``tiny-moe``, unquantised) whose reference,
+cache statement and window counters all come from files the test's root
+adds.  (One file, so that one worker runs these stacks one after another.)"""
 
 import json
 import os
@@ -77,7 +79,7 @@ def test_an_added_closed_cell_traced_reports_per_layer_metrics_only(root):
     assert set(result) == RESULT_KEYS
     assert result["correct"] is True
     # device metrics are absent on the CPU, never zero
-    assert "decode_step_dev_ms.closed" not in result["metrics"]
+    assert "decode_step_ctr_dev_ms.closed" not in result["metrics"]
     assert "decode_roofline.closed" not in result["metrics"]
     assert "out_tok_per_s" not in result["metrics"]
     assert children_left() == []
@@ -120,12 +122,12 @@ NUMBERS = ("echo_prompt", "echo_decode", "traffic_decode", "traffic_prefill")
 TINY_CACHE_BYTES = 2 * 2 * 2 * 16 * 2
 
 
-def read_control(root, *args):
+def read_control(root, *args, cell="tiny.tiny-open"):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
-        [sys.executable, CONTROL, "--root", root, "--workload",
-         "tiny.tiny-open", "--seeds", "11,12,13", *args],
+        [sys.executable, CONTROL, "--root", root, "--workload", cell,
+         "--seeds", "11,12,13", *args],
         cwd=tinycell.REPO, env=env, stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, timeout=300)
     assert proc.returncode == 0, proc.stderr.decode()[-2000:]
@@ -183,3 +185,58 @@ def test_int4_weights_in_the_references_place_fail(root):
     rows = read_control(root, "--weight-bits", "4")
     assert not any(r["correct"] for r in rows)
     assert all(r["echo_prompt"] > 0.05 for r in rows)
+
+
+# ---- a second model family, from added files alone ---------------------------
+
+def test_the_second_familys_cell_is_made_of_added_files_only(root):
+    """Nothing under ``benchmarks/`` is shadowed: what the root holds by a
+    name the repository has is a byte-for-byte copy, and the family's own
+    files have names the repository has not."""
+    theirs = os.path.join(REPO, "benchmarks")
+    added = []
+    for dirpath, _dirs, files in os.walk(os.path.join(root, "benchmarks")):
+        for name in files:
+            mine = os.path.join(dirpath, name)
+            rel = os.path.relpath(mine, os.path.join(root, "benchmarks"))
+            if not os.path.exists(os.path.join(theirs, rel)):
+                added.append(rel)
+                continue
+            with open(mine, "rb") as a, open(os.path.join(theirs, rel),
+                                             "rb") as b:
+                assert a.read() == b.read(), rel
+    assert {"moe_reference.py", "configs/tiny-moe.json",
+            "layer_metrics/counter_ratio.py",
+            "layer_metrics/decode_fill_ctr_pct.json"} <= set(added)
+
+
+def test_the_second_family_is_correct_as_stated_and_reads_its_own_counters(
+        root):
+    """One traced run: the reference is the configuration's own module, the
+    cache statement is that module's, and the per-layer value comes from
+    counters that only the metric's own file names."""
+    result, lines = last_line(run_cell(root, tinycell.MOE_CELL, 1))
+    assert result["correct"] is True and result["failed"] == 0
+    assert set(result["metrics"]) == {"decode_fill_ctr_pct"}
+    assert 0.0 < result["metrics"]["decode_fill_ctr_pct"]["value"] <= 100.0
+    text = "\n".join(lines)
+    assert text.count("limit 0.05: holds") == 4
+    assert f"cache_bytes_per_token {TINY_CACHE_BYTES} by" in text
+    assert children_left() == []
+
+
+def test_the_second_family_fails_with_4_bit_weights_in_the_references_place(
+        root):
+    """The control of bfloat16 weights here: the family's own reference
+    with its weights rounded to 4 bits."""
+    rows = read_control(root, "--weight-bits", "4", cell=tinycell.MOE_CELL)
+    assert not any(r["correct"] for r in rows)
+    assert all(r[n] > 0.05 for r in rows for n in NUMBERS)
+
+
+def test_the_second_family_fails_by_width_alone_for_an_int8_cache(root):
+    rows = read_control(root, "--serve-args=--kv-quant int8",
+                        cell=tinycell.MOE_CELL)
+    assert not any(r["correct"] for r in rows)
+    assert all(r["cache_bytes_per_token"] < TINY_CACHE_BYTES for r in rows)
+    assert all(r[n] < 0.05 for r in rows for n in NUMBERS)

@@ -152,7 +152,7 @@ def test_recorded_chip_trace_busy_share_and_names():
     assert all(g[1] < 1e-5 for g in out["idle_gaps"])
 
 
-def test_the_decode_step_reader_on_the_recorded_trace():
+def test_the_program_time_reader_on_the_recorded_trace():
     import importlib.util
 
     path = os.path.join(os.path.dirname(DATA), "..", "..", "benchmarks",
@@ -163,32 +163,18 @@ def test_the_decode_step_reader_on_the_recorded_trace():
 
     class Ctx:
         trace = tr.reduce(recorded())
-        config = {"num_hidden_layers": 32}
         polls, trace_span = [], None
 
-    step = reader.read(Ctx, ["_decode_fn"], "step")
-    assert step == pytest.approx(165.49131 / 8)
-    assert reader.read(Ctx, ["_no_such_program"], "step") is None
+    assert reader.read(Ctx, ["_no_such_program"], "ktok") is None
     assert reader.read(Ctx, ["_chunk_prefill_fn"], "ktok") is None  # no polls
-    Ctx.config = {"num_hidden_layers": 5}
-    with pytest.raises(ValueError, match="not whole steps"):
-        reader.read(Ctx, ["_decode_fn"], "step")
-    # the 8 steps counted, held against the tokens the program counted
-    Ctx.config = {"num_hidden_layers": 32,
-                  "serve": {"args": ["--quant", "int8", "--slots", "32"]}}
+    # 87.9 ms of chunk prefill over the 250 prompt tokens the polls give
     Ctx.trace_span = (10.0, 13.0)
-
-    def polls(tokens):
-        return [(9.9, {"engine_tokens_total": 0.0}),
-                (13.1, {"engine_tokens_total": tokens * 3.2 / 3.0})]
-
-    Ctx.polls = polls(8 * 32)      # every slot live in every step
-    assert reader.read(Ctx, ["_decode_fn"], "step") == step
-    Ctx.polls = polls(8 * 12)      # an open loop: fewer rows live
-    assert reader.read(Ctx, ["_decode_fn"], "step") == step
-    Ctx.polls = polls(16 * 32)     # as if the layer loop were unrolled by 2
-    with pytest.raises(ValueError, match="step count is wrong"):
-        reader.read(Ctx, ["_decode_fn"], "step")
+    Ctx.polls = [(9.0, {"engine_prefill_tokens_total": 1000.0}),
+                 (14.0, {"engine_prefill_tokens_total": 1000.0 + 250 * 5 / 3})]
+    assert reader.read(Ctx, ["_chunk_prefill_fn"], "ktok") == \
+        pytest.approx(87.88923 / 0.25)
+    with pytest.raises(ValueError, match="unknown divisor"):
+        reader.read(Ctx, ["_decode_fn"], "step")   # retired with its metrics
 
 
 def test_prompt_tokens_between_the_trace_edges_are_interpolated():
@@ -208,6 +194,8 @@ def test_prompt_tokens_between_the_trace_edges_are_interpolated():
     assert reader.counter_between(Ctx, "c") == pytest.approx(350.0 - 150.0)
     Ctx.trace_span = (9.0, 12.0)   # no poll before the edge
     assert reader.counter_between(Ctx, "c") is None
+    Ctx.trace_span = (10.5, 12.5)  # a counter the program does not publish
+    assert reader.counter_between(Ctx, "no_such_counter") is None
 
 
 def test_reading_a_recorded_xplane_file(tmp_path):

@@ -1,7 +1,12 @@
 """Builds, in a directory of its own, a benchmark root whose cells are made
 only of added files: the repository's data files copied as they are, plus a
 tiny configuration, tiny mixes, and a BENCHMARK.json that names them.
-``benchmarks/run.py --root <dir>`` then runs those cells on the CPU."""
+``benchmarks/run.py --root <dir>`` then runs those cells on the CPU.
+
+One cell is of a second model family, also from added files alone: the
+program's ``tiny-moe`` preset served unquantised, whose configuration names
+its own reference (``moe_reference.py``, copied from beside this file) and
+whose per-layer metric reads counters that only its own file names."""
 
 import json
 import os
@@ -27,6 +32,27 @@ TINY_CONFIG = {
                            "traffic_decode": 0.05, "traffic_prefill": 0.05},
                 "why": "tiny on the CPU reads about 0.004"},
 }
+
+#: The published mixtral names for what the tiny-moe preset holds.
+TINY_MOE_CONFIG = {
+    "model_type": "mixtral", "hidden_size": 64, "intermediate_size": 128,
+    "num_hidden_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 512,
+    "num_local_experts": 4, "num_experts_per_tok": 2,
+    "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
+    "name": "tiny-moe",
+    "source": "p2p_llm_tunnel_tpu/models/config.py tiny-moe",
+    "reduced": [], "reference": "moe_reference",
+    "precision": {"weights": "bfloat16", "activations": "bfloat16",
+                  "kv_cache": "bfloat16"},
+    "serve": {"model": "tiny-moe", "max_seq": 256, "kv_block_tokens": 16,
+              "args": ["--slots", "4"],
+              "env": {"TUNNEL_WARMUP_VIEW_CAP": "256"}},
+    "correct": {"limits": {"echo_prompt": 0.05, "echo_decode": 0.05,
+                           "traffic_decode": 0.05, "traffic_prefill": 0.05},
+                "why": "tiny-moe on the CPU reads about 0.005"},
+}
+MOE_CELL = "tiny-moe.tiny-closed"
 
 TINY_OPEN = {
     "name": "tiny-open", "loop": "open", "rate_rps": 10.0,
@@ -67,13 +93,44 @@ def read(ctx, counter: str):
 '''
 
 
+COUNTER_RATIO = '''"""One counter's growth over the window as a share (%) of another's."""
+
+
+def read(ctx, over: str, under: str):
+    if not ctx.counters.get(under) or over not in ctx.counters:
+        return None
+    return 100.0 * ctx.counters[over] / ctx.counters[under]
+'''
+
+
 def build(root: str) -> str:
     data = os.path.join(root, "benchmarks")
     for sub in ("configs", "traffic", "layer_metrics", "end_to_end"):
         shutil.copytree(os.path.join(REPO, "benchmarks", sub),
                         os.path.join(data, sub))
-    with open(os.path.join(data, "configs", "tiny.json"), "w") as f:
-        json.dump(TINY_CONFIG, f)
+    # the dense family's module, where a configuration that names no
+    # reference finds it: a copy, as the data directories are
+    shutil.copy(os.path.join(REPO, "benchmarks", "reference.py"), data)
+    # a second family: its reference, its configuration, a reader and a
+    # metric whose file names the counters it reads
+    shutil.copy(os.path.join(HERE, "moe_reference.py"), data)
+    for config in (TINY_CONFIG, TINY_MOE_CONFIG):
+        with open(os.path.join(data, "configs", config["name"] + ".json"),
+                  "w") as f:
+            json.dump(config, f)
+    with open(os.path.join(data, "layer_metrics", "counter_ratio.py"),
+              "w") as f:
+        f.write(COUNTER_RATIO)
+    with open(os.path.join(data, "layer_metrics", "decode_fill_ctr_pct.json"),
+              "w") as f:
+        json.dump({"reader": "counter_ratio",
+                   "args": {"over": "engine_decode_row_steps_total",
+                            "under": "engine_decode_slot_steps_total"},
+                   # the last is one the program does not publish: it
+                   # is left out of what the reader sees, and fails nothing
+                   "counters": ["engine_decode_row_steps_total",
+                                "engine_decode_slot_steps_total",
+                                "engine_no_such_counter_total"]}, f)
     for mix in (TINY_OPEN, TINY_CLOSED):
         with open(os.path.join(data, "traffic", mix["name"] + ".json"),
                   "w") as f:
@@ -95,21 +152,25 @@ def build(root: str) -> str:
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = ["tiny.tiny-open", "tiny.tiny-closed"]
-    bench["configs"] = [{"name": "tiny", "source": TINY_CONFIG["source"],
-                         "file": "benchmarks/configs/tiny.json",
-                         "reduced": [], "why": "a test"}]
+    bench["configs"] = [
+        {"name": c["name"], "source": c["source"],
+         "file": f"benchmarks/configs/{c['name']}.json", "reduced": [],
+         "why": "a test"} for c in (TINY_CONFIG, TINY_MOE_CONFIG)]
     bench["workloads"] = [
         {"name": cells[0], "config": "tiny", "traffic": "tiny-open",
          "chips": 1, "why": "a test"},
         {"name": cells[1], "config": "tiny", "traffic": "tiny-closed",
-         "chips": 1, "why": "a test"}]
+         "chips": 1, "why": "a test"},
+        {"name": MOE_CELL, "config": "tiny-moe", "traffic": "tiny-closed",
+         "chips": 1, "why": "a test: a second family, from files alone"}]
     bench["end_to_end"] = [
         {"name": "ttft_p50_ms", "unit": "ms", "better": "lower",
          "bound": 0.05, "source": "host_clock", "workloads": [cells[0]]},
         {"name": "ttft_p60_ms", "unit": "ms", "better": "lower",
          "bound": 0.05, "source": "host_clock", "workloads": [cells[0]]},
         {"name": "out_tok_per_s", "unit": "tokens/s", "better": "higher",
-         "bound": 0.05, "source": "host_clock", "workloads": [cells[1]]},
+         "bound": 0.05, "source": "host_clock",
+         "workloads": [cells[1], MOE_CELL]},
         {"name": "setup_s", "unit": "s", "better": "lower", "bound": 0.1,
          "source": "host_clock"}]
     for m in bench["per_layer"]:
@@ -121,6 +182,10 @@ def build(root: str) -> str:
         {"name": "prefix_hit_share", "unit": "%", "better": "higher",
          "source": "program_counter", "layer": "KV stores",
          "moves": "ttft_p50_ms", "workloads": [cells[0]]})
+    bench["per_layer"].append(
+        {"name": "decode_fill_ctr_pct", "unit": "%", "better": "higher",
+         "source": "program_counter", "layer": "scheduler + loop",
+         "moves": "out_tok_per_s", "workloads": [MOE_CELL]})
     bench["per_layer"].append(
         {"name": "late_p50_ms", "unit": "ms", "better": "lower",
          "source": "host_clock", "layer": "load generator (benchmark's own)",
