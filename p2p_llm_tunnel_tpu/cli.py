@@ -205,13 +205,16 @@ def build_parser() -> argparse.ArgumentParser:
                        help="tensor-parallel degree over the device mesh")
     serve.add_argument("--ckpt", default=_env("TUNNEL_CKPT"),
                        help="orbax checkpoint path (default: random init)")
-    serve.add_argument("--quant", choices=("none", "int8", "w8a8", "int4"),
+    serve.add_argument("--quant",
+                       choices=("none", "int8", "w8a8", "int4", "a8"),
                        default=_env("TUNNEL_QUANT", "none"),
                        help="weight quantization: int8 halves decode HBM "
                             "traffic; w8a8 also quantizes activations "
                             "(int8 MXU dots); int4 packs two weights per "
                             "byte with per-group scales, halving the "
-                            "weight stream again")
+                            "weight stream again; a8 rounds activations "
+                            "to int8 over the weights as they are (a "
+                            "precision control, not a serving mode)")
     serve.add_argument("--quant-group-size", type=int,
                        default=int(_env("TUNNEL_QUANT_GROUP_SIZE", "128")),
                        help="int4 scale group size (contracted positions "

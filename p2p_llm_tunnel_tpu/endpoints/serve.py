@@ -817,6 +817,10 @@ async def _send_healthz(
             "attention": global_metrics.info("attention_branches", {}) or {},
             "quant": global_metrics.info("config_quant"),
             "kv_quant": global_metrics.info("config_kv_quant"),
+            # the cache's form (KV heads or one latent row a token) and the
+            # share of the published model held: layers, experts, rows of
+            # the vocabulary
+            "model": global_metrics.info("config_model"),
         },
         # What JAX runs on in THIS process and what each local device
         # holds — the only place a JAX-free parent (chip_smoke.py, a load

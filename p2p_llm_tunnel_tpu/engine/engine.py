@@ -438,6 +438,24 @@ class _Dispatch:
 _NO_ANNOTATION = contextlib.nullcontext()
 
 
+class _CountsFirst:
+    """A jitted serving program of a model with routed layers: its FIRST
+    output is what those layers counted (models/moe.py STATS), handed to
+    ``take`` as the device array it is; callers get the rest, the tuple the
+    program returns for any other model."""
+
+    def __init__(self, jitted, take):
+        self._jitted, self._take = jitted, take
+
+    def __call__(self, *args):
+        out = self._jitted(*args)
+        self._take(out[0])
+        return out[1:]
+
+    def lower(self, *args):
+        return self._jitted.lower(*args)
+
+
 class InferenceEngine:
     """Slot-batched continuous-decode engine over one model."""
 
@@ -455,6 +473,7 @@ class InferenceEngine:
         self.mcfg = model_cfg or get_config(
             self.ecfg.model, vocab_size=self.tokenizer.vocab_size
         )
+        self._refuse_unsupported()
         # flash_sgrid IMPLIES flash_decode (it selects the kernel variant):
         # the bench applies the same implication, so the benched and served
         # configs agree for a lone --flash-sgrid / TUNNEL_FLASH_SGRID=1.
@@ -537,6 +556,12 @@ class InferenceEngine:
                     actual, self.ecfg.quant_group_size,
                 )
                 self.ecfg = dc_replace(self.ecfg, quant_group_size=actual)
+        elif self.ecfg.quant == "a8":
+            # Int8 activations over the weights as they are: the
+            # benchmark's activations control for a family served
+            # unquantised (models/quant.py round_act).
+            if not self.mcfg.act_quant:
+                self.mcfg = dc_replace(self.mcfg, act_quant=True)
         elif self.ecfg.quant not in ("none", ""):
             raise ValueError(f"unknown quant mode {self.ecfg.quant!r}")
         # Cross-host SPMD serving (PARITY A8): in a multi-process run rank 0
@@ -569,6 +594,10 @@ class InferenceEngine:
         self._scratch_slot = b
         if self.ecfg.kv_quant not in ("none", "", "int8", "int4"):
             raise ValueError(f"unknown kv_quant mode {self.ecfg.kv_quant!r}")
+        # Routed layers count their assignments on the device; the counts
+        # ride each serving program's outputs (_CountsFirst) and reach the
+        # host with the tokens: (dispatch record or None, device array).
+        self._moe_pending: Deque[Tuple[Optional[_Dispatch], object]] = deque()
         if self.ecfg.prefix_evict not in ("cost", "lru"):
             raise ValueError(
                 f"unknown prefix_evict mode {self.ecfg.prefix_evict!r}"
@@ -895,6 +924,8 @@ class InferenceEngine:
                 # predicate init_pool sizes pages with, so the page unit
                 # and the copy unit cannot split.
                 packed_keys=pool_packed_keys(self.kv_cache),
+                layerwise_keys=frozenset(
+                    self.kv_cache if self.mcfg.kv_lora_rank else ()),
             )
             if self._spmd is not None:
                 self._copy_in = self._spmd.wrap("copy_in", self._copy_in, 2)
@@ -938,6 +969,7 @@ class InferenceEngine:
         # a client holding the deployment to its stated types reads it here.
         global_metrics.set_info("config_quant", self.ecfg.quant)
         global_metrics.set_info("config_kv_quant", self.ecfg.kv_quant)
+        global_metrics.set_info("config_model", self._model_section())
 
         # Prefill may run a hotter quant mode than decode (prefill_act_quant):
         # a separate static config for the prefill program only.
@@ -1086,6 +1118,14 @@ class InferenceEngine:
         self._jit_chunk_prefill = jax.jit(
             self._chunk_prefill_fn, donate_argnums=(1,), static_argnums=(9,)
         )
+        # (multi-process SPMD replays dispatches rank by rank and takes the
+        # cache as each program's last output: it keeps the plain tuples)
+        self._moe_counts = bool(self.mcfg.n_experts) and self._spmd is None
+        if self._moe_counts:
+            self._jit_decode = _CountsFirst(self._jit_decode, self._take_moe)
+            self._jit_prefill = _CountsFirst(self._jit_prefill, self._take_moe)
+            self._jit_chunk_prefill = _CountsFirst(
+                self._jit_chunk_prefill, self._take_moe)
 
         self._jit_spec = jax.jit(
             self._spec_verify_fn, donate_argnums=(1,), static_argnums=(6,)
@@ -1258,9 +1298,9 @@ class InferenceEngine:
 
         def one(carry, _xs):
             toks, pos, cnt, cache = carry
-            logits, cache = decode_step(
+            logits, cache, *moe = decode_step(
                 self.mcfg, params, cache, toks, pos, kv_view=kv_view,
-                mesh=self.mesh,
+                mesh=self.mesh, with_stats=self._moe_counts,
             )
             # key=None: sampling randomness is the per-request (seed, pos)
             # stream — the burst key no longer feeds it (and the old split
@@ -1279,9 +1319,9 @@ class InferenceEngine:
                     lambda: sampling.empty_logprob_data(
                         b, logits.shape[-1]),
                 )
-            return (sampled, pos + 1, cnt, cache), (sampled, lp)
+            return (sampled, pos + 1, cnt, cache), (sampled, lp, moe)
 
-        (tokens, positions, counts, kv_cache), (toks, lps) = jax.lax.scan(
+        (tokens, positions, counts, kv_cache), (toks, lps, moe) = jax.lax.scan(
             one, (tokens, positions, counts, kv_cache), None, length=steps
         )
         # [k, ...] scan stacking -> [B, k, ...] row-major for the host.
@@ -1290,7 +1330,9 @@ class InferenceEngine:
             jnp.swapaxes(lps[1], 0, 1),   # top ids [B, k, CAP]
             jnp.swapaxes(lps[2], 0, 1),   # top logprobs [B, k, CAP]
         )
-        return toks.T, lp_out, tokens, positions, counts, kv_cache  # [B, k]
+        # ([steps, STATS] counts of the routed layers first, where counted)
+        head = tuple(m.sum(axis=0) for m in moe)
+        return head + (toks.T, lp_out, tokens, positions, counts, kv_cache)
 
     def _prefill_fn(self, params, kv_cache, bias, tokens, lengths, slots,
                     samp, key, echo=False):
@@ -1300,15 +1342,18 @@ class InferenceEngine:
         the serving default).  One body serves both compiled variants so
         the sampling/logprob handling cannot drift between them."""
         prompt_lps = None
+        # padding rows sit on the scratch slot and count for nothing
+        stat_rows = slots != self._scratch_slot if self._moe_counts else None
         if echo:
-            last_logits, kv_cache, prompt_lps = prefill_into_cache(
+            last_logits, kv_cache, prompt_lps, *moe = prefill_into_cache(
                 self._prefill_mcfg, params, tokens, lengths, kv_cache, slots,
                 mesh=self.mesh, return_prompt_logprobs=True,
+                stat_rows=stat_rows,
             )
         else:
-            last_logits, kv_cache = prefill_into_cache(
+            last_logits, kv_cache, *moe = prefill_into_cache(
                 self._prefill_mcfg, params, tokens, lengths, kv_cache, slots,
-                mesh=self.mesh,
+                mesh=self.mesh, stat_rows=stat_rows,
             )
         # Prefill rows are packed; gather each row's SLOT bias plane.
         first = sampling.sample(last_logits, samp, key, pos=lengths,
@@ -1320,8 +1365,8 @@ class InferenceEngine:
                 first.shape[0], last_logits.shape[-1]),
         )
         if echo:
-            return first, lp, prompt_lps, kv_cache
-        return first, lp, kv_cache
+            return (*moe, first, lp, prompt_lps, kv_cache)
+        return (*moe, first, lp, kv_cache)
 
     def _chunk_prefill_fn(
         self, params, kv_cache, bias, tokens, lengths, starts, slots, samp,
@@ -1333,9 +1378,11 @@ class InferenceEngine:
             chunk_prefill_into_cache,
         )
 
-        last_logits, kv_cache = chunk_prefill_into_cache(
+        last_logits, kv_cache, *moe = chunk_prefill_into_cache(
             self._prefill_mcfg, params, tokens, lengths, starts, kv_cache,
             slots, kv_view=kv_view,
+            stat_rows=slots != self._scratch_slot if self._moe_counts
+            else None,
         )
         with jax.named_scope("head_sample"):
             first = sampling.sample(last_logits, samp, key,
@@ -1346,7 +1393,7 @@ class InferenceEngine:
                 lambda: sampling.empty_logprob_data(
                     first.shape[0], last_logits.shape[-1]),
             )
-        return first, lp, kv_cache
+        return (*moe, first, lp, kv_cache)
 
     def _ragged_prefill_fn(
         self, params, kv_cache, bias, tokens, slot_of, start_of, qoff_of,
@@ -1789,6 +1836,62 @@ class InferenceEngine:
         if kind == "ragged":
             return "pallas-ragged"
         return "einsum"  # chunk: ops.attention.history_attention
+
+    def _refuse_unsupported(self) -> None:
+        """What a latent-attention model does not have yet is refused at
+        start-up, by name, instead of served wrongly: its weights have no
+        quantiser (experts: models/quant.py), its layers no mesh rules
+        (parallel/), and the Pallas kernels, the ragged prefill and the
+        speculative verify read a cache of KV heads."""
+        if not self.mcfg.kv_lora_rank:
+            return
+        e = self.ecfg
+        asked = [
+            (e.quant not in ("none", "", "a8"), f"--quant {e.quant}"),
+            (e.kv_quant == "int4", "--kv-quant int4"),
+            (e.tp > 1, f"--tp {e.tp}"), (e.sp > 1, f"--sp {e.sp}"),
+            (e.ep > 1, f"--ep {e.ep}"),
+            (e.flash_decode or e.flash_sgrid or self.mcfg.flash_decode,
+             "--flash-decode"),
+            (e.fused_decode_layer or self.mcfg.fused_decode_layer,
+             "--fused-decode-layer"),
+            (e.ragged_prefill, "--ragged-prefill"),
+            (e.spec_ngram > 0, "--spec-ngram"),
+            (bool(e.ckpt_path), "--ckpt (no converter for this family)"),
+        ]
+        refused = [name for on, name in asked if on]
+        if refused:
+            raise ValueError(
+                f"model {self.mcfg.name!r} (latent attention, routed "
+                f"experts) cannot be served with {', '.join(refused)}: "
+                "serve it with --quant none on one chip, without the Pallas "
+                "decode kernels, the ragged prefill or speculative decoding"
+            )
+
+    def _model_section(self) -> Dict[str, object]:
+        """/healthz ``config.model``: the cache's form and the share of the
+        published model this process holds."""
+        m = self.mcfg
+        rows, s = self.ecfg.num_slots + 1, self.ecfg.max_seq
+        per_token = sum(
+            int(arr.size) * arr.dtype.itemsize
+            for arr in self.kv_cache.values()) // (rows * s)
+        first, held = m.experts_held
+        return {
+            "name": m.name,
+            "cache": {
+                "form": "latent" if m.kv_lora_rank else "kv_heads",
+                "values_per_token_layer": (
+                    m.head_dim if m.kv_lora_rank
+                    else 2 * m.n_kv_heads * m.head_dim),
+                "bytes_per_token": per_token,
+            },
+            "layers": {"held": m.n_layers,
+                       "of": m.published_layers or m.n_layers},
+            "experts": {"held": held, "first": first, "of": m.n_experts},
+            "vocab_rows": {"held": m.vocab_size,
+                           "of": m.vocab_size * m.layer_chips},
+        }
 
     def _fence_declined_decode_kernels(self) -> None:
         """An option that asked for a Pallas decode kernel (flash_decode /
@@ -2666,6 +2769,32 @@ class InferenceEngine:
             b *= 2
         return min(b, self.ecfg.max_seq)
 
+    def _take_moe(self, counts) -> None:
+        """A serving program's routed-layer counts, still on the device
+        (executor thread, as the dispatch call returns): queued beside the
+        dispatch's record until the host has its tokens."""
+        if self._warming:
+            return
+        counts.copy_to_host_async()
+        self._moe_pending.append(
+            (self._last_dispatch if global_tracer.enabled else None, counts))
+
+    def _drain_moe(self) -> None:
+        """Publish the counts of every dispatch whose outputs are on the
+        host (called where a dispatch's tokens have just been fetched: its
+        counts came with them, so nothing here waits for the device)."""
+        while self._moe_pending and self._moe_pending[0][1].is_ready():
+            rec, counts = self._moe_pending.popleft()
+            made, held, fullest, touched = (int(n) for n in np.asarray(counts))
+            global_metrics.inc("engine_moe_assignments_total", made)
+            global_metrics.inc("engine_moe_assignments_held_total", held)
+            global_metrics.inc("engine_moe_expert_tokens_max_total", fullest)
+            global_metrics.inc("engine_moe_experts_touched_total", touched)
+            if rec is not None:
+                rec.attrs.update(
+                    moe_assignments=made, moe_held=held,
+                    moe_expert_tokens_max=fullest, moe_experts_touched=touched)
+
     def _open_dispatch(self, span: str, program: str, parts=None,
                        **work) -> _Dispatch:
         """Open the record of the dispatch about to be made (executor
@@ -2715,6 +2844,7 @@ class InferenceEngine:
         """The sampled block of a prefill dispatch is on the host: its
         engine-scope record, and over the same interval one
         ``engine.prefill_part`` for each traced request with rows in it."""
+        self._drain_moe()
         if rec is None:
             return
         t1 = time.monotonic()
@@ -3217,6 +3347,10 @@ class InferenceEngine:
             "ckpt_path": self.ecfg.ckpt_path,
             "block": self._prefix_block,
             "capacity": self.ecfg.prefix_pool_blocks,
+            # The cache's form: each leaf's per-token shape and type (keys
+            # and values of the KV heads, or one latent row; their scales).
+            "page": [[key, list(arr.shape[3:]), str(arr.dtype)]
+                     for key, arr in sorted(self.kv_cache.items())],
         }
 
     def save_prefix_snapshot(self) -> None:
@@ -4110,6 +4244,7 @@ class InferenceEngine:
         dispatches before burst n is fetched) — the Chrome view shows the
         pipelining directly.  ``rec`` is None when the burst was dispatched
         with tracing off."""
+        self._drain_moe()
         if rec is None:
             return
         global_tracer.add_span(

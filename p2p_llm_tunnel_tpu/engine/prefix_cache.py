@@ -950,7 +950,8 @@ def plan_inserts(
 
 
 def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
-                        packed_keys: frozenset = frozenset()):
+                        packed_keys: frozenset = frozenset(),
+                        layerwise_keys: frozenset = frozenset()):
     """Row-batched copy programs: ONE dispatch serves up to ``rows``
     requests' block copies.
 
@@ -967,6 +968,12 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
     ``packed_keys`` leaves (the int4 value planes) move in
     ``block // 2``-byte page units — pages stay whole-byte by the ISSUE 14
     alignment guarantee, so packed copies are plain scatters too.
+    ``layerwise_keys`` leaves are read from and written to the CACHE one
+    layer at a time: a plane of one row of values a token and no head axis
+    (the latent cache, models/mla.py) with the layer axis in a gather's or
+    scatter's window is turned layers-innermost for it by the chip's
+    compiler, whole, and back.  The pool side keeps whole pages in its
+    window either way.
     """
 
     def _pos(unit, blk_nos):
@@ -988,7 +995,12 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
                 flat = vals.reshape(
                     (vals.shape[0], rows, pos.shape[1]) + vals.shape[4:]
                 )
-                out[key] = arr.at[:, slots[:, None], pos].set(flat)
+                if key in layerwise_keys:
+                    for i in range(arr.shape[0]):
+                        arr = arr.at[i, slots[:, None], pos].set(flat[i])
+                    out[key] = arr
+                else:
+                    out[key] = arr.at[:, slots[:, None], pos].set(flat)
         return out
 
     def cache_to_pool(pool, cache, slots, pool_ids, blk_nos):
@@ -1003,7 +1015,12 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
             for key, arr in pool.items():
                 unit = block // 2 if key in packed_keys else block
                 pos = _pos(unit, blk_nos)
-                vals = cache[key][:, slots[:, None], pos]  # [L, R, Nmax*unit, ...]
+                if key in layerwise_keys:
+                    vals = jnp.stack([
+                        cache[key][i, slots[:, None], pos]
+                        for i in range(arr.shape[0])])
+                else:
+                    vals = cache[key][:, slots[:, None], pos]  # [L, R, Nmax*unit, ...]
                 vals = vals.reshape(
                     (vals.shape[0], rows * max_blocks, unit) + vals.shape[3:]
                 )

@@ -11,8 +11,19 @@ tensor-parallel v5e-8.  Architectural knobs cover both families:
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Optional
+from dataclasses import dataclass, replace
+from typing import Optional, Tuple
+
+
+@dataclass(frozen=True)
+class YarnRope:
+    """``deepseek_yarn`` rope scaling as a published config states it."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -92,10 +103,66 @@ class ModelConfig:
     # ``ep`` mesh axis (expert parallelism — models/moe.py).
     n_experts: int = 0
     n_experts_per_tok: int = 2
+    # The routed layer beyond mixtral's (models/moe.py).  ``n_experts`` is
+    # always the PUBLISHED count, the router's width.
+    moe_ffn_dim: int = 0  # an expert's width; 0 = ffn_dim
+    n_shared_experts: int = 0  # SwiGLUs of moe_ffn_dim every token takes
+    first_dense_layers: int = 0  # leading layers with a plain ffn_dim MLP
+    # "softmax" (mixtral: softmax over all experts, top-k, renormalised) |
+    # "sigmoid" (aux-free-bias family: sigmoid scores, the top-k of score +
+    # selection bias, weights the unbiased scores over their sum).
+    router_score: str = "softmax"
+    router_bias: bool = False  # the selection bias ``router_bias`` [L, E]
+    routed_scale: float = 1.0  # routed_scaling_factor
+    # The share one process holds of a layer that ``layer_chips`` chips
+    # divide among them: experts [i * E/n, (i + 1) * E/n) and as many rows
+    # of the vocabulary.  ``vocab_size`` counts the rows HELD (the engine
+    # sets it from the tokenizer); the published table has layer_chips
+    # times as many.  One chip runs its layer without the exchange: tokens
+    # routed to absent experts add nothing.
+    layer_chips: int = 1
+    chip_index: int = 0
+    # Layers of the published model when ``n_layers`` is one pipeline
+    # stage's (0: the whole model is here).
+    published_layers: int = 0
+    # Latent attention (MLA): kv_lora_rank > 0 replaces the K/V heads by
+    # one cached row of kv_lora_rank + qk_rope_head_dim values a token
+    # (models/mla.py).  ``head_dim`` is then that row's width and
+    # ``n_kv_heads`` 1.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    qk_norm: bool = False  # RMSNorm(w) over each head's query before rope
+    yarn: Optional[YarnRope] = None
 
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's feed-forward: leading dense ones, then experts."""
+        if not self.n_experts:
+            return ("dense",) * self.n_layers
+        d = min(self.first_dense_layers, self.n_layers)
+        return ("dense",) * d + ("moe",) * (self.n_layers - d)
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(first, count) of the experts this process holds."""
+        n = self.n_experts // self.layer_chips
+        return self.chip_index * n, n
+
+    @property
+    def expert_dim(self) -> int:
+        return self.moe_ffn_dim or self.ffn_dim
+
+    @property
+    def q_head_dim(self) -> int:
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.head_dim
 
 
 def tiny(vocab_size: int = 512) -> ModelConfig:
@@ -308,8 +375,95 @@ def mixtral_8x7b() -> ModelConfig:
     )
 
 
+def sarvam_105b() -> ModelConfig:
+    """sarvam-105b as published (huggingface.co/sarvamai/sarvam-105b
+    config.json): latent attention, one dense layer, then 128 routed
+    experts top-8 with a selection bias and one shared expert."""
+    return ModelConfig(
+        name="sarvam-105b",
+        vocab_size=262144,
+        dim=4096,
+        n_layers=32,
+        n_heads=64,
+        n_kv_heads=1,
+        head_dim=576,
+        ffn_dim=16384,
+        rope_theta=10000.0,
+        norm_eps=1e-6,
+        n_experts=128,
+        n_experts_per_tok=8,
+        moe_ffn_dim=2048,
+        n_shared_experts=1,
+        first_dense_layers=1,
+        router_score="sigmoid",
+        router_bias=True,
+        routed_scale=2.5,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        qk_norm=True,
+        yarn=YarnRope(factor=40.0, original_max=4096, beta_fast=32.0,
+                      beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0),
+    )
+
+
+def sarvam_105b_ep4s() -> ModelConfig:
+    """One chip's share of sarvam-105b: one of 4 chips that share each
+    layer (experts 0-31, vocabulary rows 0-65,535) and the first pipeline
+    stage (the dense layer and 5 expert layers).  Every width, the router's
+    128 outputs, top-8, the bias and 2.5 are as published."""
+    return replace(sarvam_105b(), name="sarvam-105b-ep4s", n_layers=6,
+                   published_layers=32, vocab_size=65536, layer_chips=4,
+                   chip_index=0)
+
+
+def tiny_mla_moe(vocab_size: int = 512) -> ModelConfig:
+    """CPU-testable sarvam-style config: latent 32 + rope 8, yarn on, one
+    dense layer then three of 8 experts top-2 + 1 shared (an even depth, as
+    the published model's and its share's: two layers share a row of the
+    rope-key plane, models/mla.py)."""
+    return ModelConfig(
+        name="tiny-mla-moe",
+        vocab_size=vocab_size,
+        dim=64,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=1,
+        head_dim=40,
+        ffn_dim=128,
+        norm_eps=1e-6,
+        n_experts=8,
+        n_experts_per_tok=2,
+        moe_ffn_dim=32,
+        n_shared_experts=1,
+        first_dense_layers=1,
+        router_score="sigmoid",
+        router_bias=True,
+        routed_scale=2.5,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        qk_norm=True,
+        yarn=YarnRope(factor=40.0, original_max=16, beta_fast=32.0,
+                      beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0),
+    )
+
+
+def tiny_mla_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
+    """tiny-mla-moe as one of 2 chips that share each layer: experts 0-3
+    and ``vocab_size`` rows of a table twice as long."""
+    return replace(tiny_mla_moe(vocab_size), name="tiny-mla-moe-ep2s",
+                   layer_chips=2, chip_index=0)
+
+
 PRESETS = {
     "tiny": tiny,
+    "tiny-mla-moe": tiny_mla_moe,
+    "tiny-mla-moe-ep2s": tiny_mla_moe_ep2s,
+    "sarvam-105b": sarvam_105b,
+    "sarvam-105b-ep4s": sarvam_105b_ep4s,
     "tiny-qwen": tiny_qwen,
     "tiny-moe": tiny_moe,
     "mixtral-8x7b": mixtral_8x7b,
@@ -329,7 +483,5 @@ def get_config(name: str, **overrides) -> ModelConfig:
         raise KeyError(f"unknown model preset {name!r}; have {sorted(PRESETS)}")
     cfg = PRESETS[name]()
     if overrides:
-        from dataclasses import replace
-
         cfg = replace(cfg, **overrides)
     return cfg

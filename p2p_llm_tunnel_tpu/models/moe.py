@@ -1,40 +1,64 @@
-"""Mixture-of-experts MLP block + expert-parallel sharding (P5).
+"""The routed-expert feed-forward: a layer that is told which experts it holds.
 
-SURVEY.md §2 marks expert parallelism "out of scope unless MoE models
-added" — this adds them: mixtral-style blocks where each layer's MLP is a
-router over ``n_experts`` per-expert SwiGLUs, top-k routed with
-renormalized gate weights.
+One layer serves every routed family the repo has: mixtral-style blocks
+(``tiny-moe``, ``mixtral-8x7b``: softmax router, top-k renormalised, all
+experts held) and the aux-free-bias family (``sarvam-105b``: sigmoid scores,
+a selection bias, a scaling factor, a shared expert, and possibly only one
+chip's share of the experts).
 
-Compute strategy: DENSE-DROPLESS — every expert computes every token and
-the router weights (zero for unrouted experts) scale the combine.  This
-keeps shapes static (XLA-friendly, no capacity dropping, exactly
-reproduces the routed math) at the cost of E/k× the FLOPs of a routed
-gather; a Megablocks-style grouped matmul is the future optimization for
-serving large MoEs at scale.
+What it does, for ``N`` tokens, ``E`` published experts, top-``k``:
 
-Expert parallelism falls out of sharding: expert weights carry the expert
-axis on an ``ep`` mesh axis (pspecs below), so each device computes ONLY
-its resident experts' contributions and the final expert-contraction
-einsum becomes a psum over ``ep`` — GSPMD inserts the collective.  With
-dense-dropless compute this is exact EP: per-device FLOPs and weight
-memory both scale down by the ep degree.
+- ``moe_route``: scores all ``E`` experts in float32 (the router keeps its
+  published width whatever is held) and picks ``k`` a token.
+- ``moe_experts``: the ``N * k`` assignments are sorted by expert, those to
+  experts this process does not hold last; the held experts' SwiGLUs run as
+  three grouped matrix products over the sorted rows
+  (``jax.lax.ragged_dot``: on the TPU one Mosaic grouped-matmul kernel each,
+  which visits only the row tiles its groups cover), and each row's result
+  goes back to its token, weighted.  Shapes are static and nothing is
+  dropped under any imbalance: the sorted buffer has room for every
+  assignment, so one expert may take them all.  Rows past the held groups
+  are never multiplied.
+- ``moe_shared``: the shared experts, one SwiGLU over every token.
+
+The held experts are ``cfg.experts_held``: with ``layer_chips`` chips sharing
+a layer, chip ``i`` holds experts ``[i * E/n, (i + 1) * E/n)``.  A token
+routed to an absent expert gets nothing for that assignment, here as in the
+reference (tests/mla_moe_plain.py); no code stands in for the absent
+chips or their exchange.  The parts all shares give, the shared expert
+counted once, add up to the uncut layer (tests/test_mla_moe.py).
+
+Expert parallelism under a mesh: expert leaves carry their expert axis on
+``ep`` (pspecs below) and GSPMD partitions the products.  The exchange of a
+routed layer over several chips (all-to-all by expert) is not written; see
+ROADMAP.md.
 """
 
 from __future__ import annotations
+
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from p2p_llm_tunnel_tpu.models.quant import mm, round_act
+
+#: What a routed layer counts of one call, int32: assignments of counted
+#: tokens, those to held experts, the fullest held expert's tokens, and the
+#: held experts that got a token.
+STATS = 4
+
 
 def init_moe_blocks(cfg, keys, dense_fn) -> dict:
-    """MoE leaves for the stacked block tree.
+    """Mixtral-style leaves for the stacked block tree (every layer routed,
+    all experts held).
 
     ``dense_fn(key, shape, fan_in)`` is init_params' dense initializer so
     MoE weights follow the same distribution.  Layout:
     router [L, Dm, E]; experts [L, E, Dm, F] (gate/up) and [L, E, F, Dm]
     (down)."""
-    l, dm, f, e = cfg.n_layers, cfg.dim, cfg.ffn_dim, cfg.n_experts
+    l, dm, f, e = cfg.n_layers, cfg.dim, cfg.expert_dim, cfg.n_experts
     return {
         "router": dense_fn(keys[0], (l, dm, e), dm),
         "moe_gate": dense_fn(keys[1], (l, e, dm, f), dm),
@@ -54,35 +78,103 @@ def moe_pspecs() -> dict:
     }
 
 
-def moe_mlp(cfg, blk, h, act_fn) -> jnp.ndarray:
-    """Routed MLP for one layer: h [B, T, Dm] → [B, T, Dm].
-
-    ``blk`` holds this layer's slice (router [Dm, E], experts [E, ...]).
-    Router math in fp32 (softmax over experts, top-k, renormalize) exactly
-    as mixtral; combine contracts the expert axis LAST so an ep-sharded
-    expert dimension turns into one psum.
-    """
+def route(cfg, blk, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x [N, Dm] -> (experts [N, k] int32 over all E, weights [N, k] f32)."""
     k = cfg.n_experts_per_tok
-    e = cfg.n_experts
+    # float32 for real: the chip's default rounds a float32 product's
+    # operands to bfloat16, and a score off in the third digit routes a
+    # token to another expert than the reference's
+    logits = jnp.dot(x.astype(jnp.float32), blk["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif cfg.router_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router_score {cfg.router_score!r}")
+    chosen_by = scores
+    if cfg.router_bias:
+        # the bias moves the choice, never the weight
+        chosen_by = scores + blk["router_bias"].astype(jnp.float32)
+    _, top_i = jax.lax.top_k(chosen_by, k)
+    top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    return top_i, top_w * cfg.routed_scale
 
-    logits = (
-        h.astype(jnp.float32) @ blk["router"].astype(jnp.float32)
-    )  # [B, T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)  # [B, T, k]
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-    # Scatter the renormalized top-k back to a dense [B, T, E] weight map
-    # (zeros for unrouted experts — they compute but contribute nothing).
-    weights = (
-        jax.nn.one_hot(top_i, e, dtype=jnp.float32) * top_p[..., None]
-    ).sum(axis=-2)  # [B, T, E]
 
-    # Dense-dropless expert compute, expert axis kept free until the end.
-    gate = jnp.einsum("btd,edf->btef", h, blk["moe_gate"])
-    up = jnp.einsum("btd,edf->btef", h, blk["moe_up"])
-    inner = act_fn(gate) * up  # [B, T, E, F]
-    down = jnp.einsum("btef,efd->bted", inner, blk["moe_down"])
-    out = jnp.einsum(
-        "bted,bte->btd", down.astype(jnp.float32), weights
-    )
-    return out.astype(h.dtype)
+def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
+            counted: Optional[jnp.ndarray] = None, stacked=None, layer=None,
+            router_in: Optional[jnp.ndarray] = None):
+    """Routed feed-forward of one layer: h [B, T, Dm] -> ([B, T, Dm], stats).
+
+    ``blk`` holds this layer's slice: router [Dm, E], the held experts
+    [He, ...], and where the config has them ``router_bias`` [E] and the
+    shared experts' ``shared_gate/up`` [Dm, Fs], ``shared_down`` [Fs, Dm].
+    ``counted`` [B, T] bool marks the tokens that ``stats`` (int32
+    [STATS]) counts: padding is computed like any token and counts for
+    nothing.
+
+    ``stacked`` + ``layer``: the experts of ALL expert layers as three
+    arrays [layers * He, ...] and this layer's index among them, in place of
+    ``blk``'s expert leaves.  The grouped product then names its experts by
+    the groups' sizes (every other layer's are empty) and reads them where
+    they lie.  A layer's slice of the stack handed to the kernel is a copy
+    first: 1.6 GB of experts copied in every layer of every step were 36 %
+    of the device in ``sarvam-105b.context-closed`` (PERF.md section 6, PR
+    28).
+
+    ``router_in`` [B, T, Dm]: what the router scores, where the caller has
+    ``h`` in more digits than the experts take (float32 before its rounding
+    to bfloat16): a score is compared with its neighbours, and every digit
+    lost routes some token elsewhere.
+    """
+    b, t, dm = h.shape
+    n, k = b * t, cfg.n_experts_per_tok
+    lo, held = cfg.experts_held
+    x = h.reshape(n, dm)
+    aq = cfg.act_quant
+    with jax.named_scope("moe_route"):
+        top_i, top_w = route(
+            cfg, blk, x if router_in is None else router_in.reshape(n, dm))
+        local = top_i - lo
+        here = (local >= 0) & (local < held)
+        # an absent expert's assignments sort after every held one's
+        group = jnp.where(here, local, held).reshape(-1)  # [N*k]
+        order = jnp.argsort(group, stable=True)
+        token_of = order // k
+        in_group = jax.nn.one_hot(group, held + 1, dtype=jnp.int32)
+        sizes = jnp.sum(in_group, axis=0)[:held]
+        weight = jnp.where(here, top_w, 0.0).reshape(-1)[order]
+        if counted is None:
+            counted = jnp.ones((b, t), bool)
+        real = jnp.repeat(counted.reshape(-1), k)  # [N*k], unsorted order
+        real_sizes = jnp.sum(
+            in_group * real[:, None].astype(jnp.int32), axis=0)[:held]
+        stats = jnp.stack([
+            jnp.sum(real.astype(jnp.int32)),
+            jnp.sum(real_sizes),
+            jnp.max(real_sizes),
+            jnp.sum((real_sizes > 0).astype(jnp.int32)),
+        ])
+    with jax.named_scope("moe_experts"):
+        experts = blk
+        if stacked is not None:
+            experts = stacked
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((stacked["moe_gate"].shape[0],), jnp.int32), sizes,
+                (layer * held,))
+        rows = round_act(x, aq)[token_of]  # [N*k, Dm], sorted by expert
+        gate = jax.lax.ragged_dot(rows, experts["moe_gate"], sizes)
+        up = jax.lax.ragged_dot(rows, experts["moe_up"], sizes)
+        inner = round_act(act_fn(gate) * up, aq)
+        down = jax.lax.ragged_dot(inner, experts["moe_down"], sizes)
+        # (rows past the held groups hold nothing defined: their weight is 0)
+        part = jnp.where(weight[:, None] > 0,
+                         down.astype(jnp.float32) * weight[:, None], 0.0)
+        out = jnp.zeros((n, dm), jnp.float32).at[token_of].add(part)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            inner = act_fn(mm(x, blk["shared_gate"], aq)) * mm(
+                x, blk["shared_up"], aq)
+            out = out + mm(inner, blk["shared_down"], aq).astype(jnp.float32)
+    return out.astype(h.dtype).reshape(b, t, dm), stats

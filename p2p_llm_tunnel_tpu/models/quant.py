@@ -302,7 +302,8 @@ def mm(x: jnp.ndarray, w, act_quant: bool = False) -> jnp.ndarray:
     (``act_quant=True``: dynamic int8 activations, int8 MXU dot).
     QTensor4 is always weight-only (the per-group scale varies along the
     contracted axis, so dequant feeds the operand instead — fused by XLA;
-    ``act_quant`` is ignored)."""
+    ``act_quant`` is ignored).  A plain array under ``act_quant`` takes
+    :func:`round_act`'s activations."""
     if isinstance(w, QTensor4):
         return x @ _dequant4(w, x.dtype)
     if isinstance(w, QTensor):
@@ -311,7 +312,18 @@ def mm(x: jnp.ndarray, w, act_quant: bool = False) -> jnp.ndarray:
             return (y * w.scale.astype(jnp.float32)).astype(x.dtype)
         y = x @ w.q.astype(x.dtype)
         return y * w.scale.astype(x.dtype)
-    return x @ w
+    return round_act(x, act_quant) @ w
+
+
+def round_act(x: jnp.ndarray, act_quant: bool) -> jnp.ndarray:
+    """The activations a product over UNQUANTISED weights takes: ``x``, or
+    under ``act_quant`` ``x`` rounded to per-token int8 and back (``--quant
+    a8``: the benchmark's activations control for a family served in
+    bfloat16, not a serving mode: the dot itself stays in x's type)."""
+    if not act_quant:
+        return x
+    xq, xs = _quantize_act(x)
+    return (xq.astype(jnp.float32) * xs).astype(x.dtype)
 
 
 def embed_lookup(embed, tokens: jnp.ndarray, dtype) -> jnp.ndarray:

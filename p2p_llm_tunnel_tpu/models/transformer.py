@@ -41,6 +41,10 @@ def init_params(
     cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16
 ) -> Params:
     """Random init (truncated-normal fan-in); layout matches checkpoint loader."""
+    if cfg.kv_lora_rank:
+        from p2p_llm_tunnel_tpu.models import mla
+
+        return mla.init_params(cfg, key, dtype)
     l, dm, h, kh, hd, f, v = (
         cfg.n_layers, cfg.dim, cfg.n_heads, cfg.n_kv_heads,
         cfg.head_dim, cfg.ffn_dim, cfg.vocab_size,
@@ -102,7 +106,14 @@ def init_kv_cache(
     byte along the sequence axis (token 2i low nibble, 2i+1 high — the
     models.quant.pack_int4(axis) layout), quartering the KV stream; the
     scale planes stay per-token full resolution.  ``max_seq`` must be even
-    for int4 (every serving bucket is)."""
+    for int4 (every serving bucket is).
+
+    A latent-attention config (``cfg.kv_lora_rank``) gets its own form, one
+    plane ``{"c": [L, rows, S, C + Dr]}`` (models/mla.py)."""
+    if cfg.kv_lora_rank:
+        from p2p_llm_tunnel_tpu.models import mla
+
+        return mla.init_kv_cache(cfg, num_slots, max_seq, dtype, quant)
     shape = (cfg.n_layers, num_slots, max_seq, cfg.n_kv_heads, cfg.head_dim)
     _modes = {False: None, True: "int8", None: None, "none": None, "": None,
               "int8": "int8", "int4": "int4"}
@@ -182,14 +193,26 @@ def _act(cfg: ModelConfig, x):
     return jax.nn.silu(x)
 
 
-def _mlp(cfg: ModelConfig, blk, h):
+def _mlp(cfg: ModelConfig, blk, h, counted=None):
+    """The block's feed-forward -> (out, what a routed layer counted of the
+    ``counted`` tokens (models/moe.py) or None for a dense one)."""
     if cfg.n_experts:
         from p2p_llm_tunnel_tpu.models.moe import moe_mlp
 
-        return moe_mlp(cfg, blk, h, lambda x: _act(cfg, x))
+        return moe_mlp(cfg, blk, h, lambda x: _act(cfg, x), counted)
     aq = cfg.act_quant
     gate = _act(cfg, mm(h, blk["w_gate"], aq)) * mm(h, blk["w_up"], aq)
-    return mm(gate, blk["w_down"], aq)
+    return mm(gate, blk["w_down"], aq), None
+
+
+def _moe_total(stats):
+    """Per-layer counts [L, STATS] of a layer scan -> their sum; zeros for a
+    dense model."""
+    from p2p_llm_tunnel_tpu.models.moe import STATS
+
+    if stats is None:
+        return jnp.zeros((STATS,), jnp.int32)
+    return stats.sum(axis=0)
 
 
 def _qkv_proj(cfg: ModelConfig, blk, h):
@@ -443,19 +466,31 @@ def prefill(
     tokens: jnp.ndarray,  # [B, T] right-padded
     valid: jnp.ndarray,  # [B, T] bool
     mesh=None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Full-prompt forward. Returns (logits [B,T,V], k, v [L,B,T,K,D]).
+    counted=None,
+):
+    """Full-prompt forward. Returns (logits [B,T,V], k, v [L,B,T,K,D]); a
+    latent-attention config returns its cached rows [L,B,T,C+Dr] for ``k``
+    and None for ``v``.  With ``counted`` ([B,T] bool) a fourth value: the
+    routed layers' counts of those tokens (see ``apply_blocks``).
 
     ``mesh`` (optional jax.sharding.Mesh) selects sharded attention paths:
     tp shard_map's the flash kernel over head shards; sp>1 runs ring
     attention over the sequence axis (see _prefill_attention_fn).
     """
     b, t = tokens.shape
+    if cfg.kv_lora_rank:
+        from p2p_llm_tunnel_tpu.models import mla
+
+        logits, rows, stats = mla.prefill(cfg, params, tokens, valid, counted)
+        if counted is not None:
+            return logits, rows, None, stats
+        return logits, rows, None
     x = _embed(cfg, params, tokens)
     attention = _prefill_attention_fn(cfg, mesh, t)
-    x, ks, vs = apply_blocks(cfg, params["blocks"], x, valid, attention)
-    x = _norm(cfg, x, params["final_norm"])
-    return _logits(cfg, params, x), ks, vs
+    out = apply_blocks(cfg, params["blocks"], x, valid, attention,
+                       counted=counted)
+    x = _norm(cfg, out[0], params["final_norm"])
+    return (_logits(cfg, params, x),) + tuple(out[1:])
 
 
 def encode_pooled(
@@ -488,8 +523,11 @@ def apply_blocks(
     valid: jnp.ndarray,  # [B, T] bool
     attention,  # fn(q, k, v, valid, window) -> [B,T,H,D]
     layer_offset=0,  # global index of blocks[0] (pp stages pass stage*L/S)
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Run a stacked block chunk over activations; returns (x', ks, vs).
+    counted=None,  # [B, T] bool: with it, also returns the routed layers' counts
+):
+    """Run a stacked block chunk over activations; returns (x', ks, vs), and
+    with ``counted`` a fourth value: what the routed layers counted of those
+    tokens (models/moe.py STATS, summed over layers; zeros for a dense model).
 
     Factored out of ``prefill`` so the pipeline-parallel stage executor
     (parallel/pipeline.py) runs exactly the same per-layer computation on
@@ -509,13 +547,15 @@ def apply_blocks(
             attn = _norm(cfg, attn, blk["post_attn_norm"])
         x = x + attn
         h = _norm(cfg, x, blk["mlp_norm"])
-        mlp = _mlp(cfg, blk, h)
+        mlp, stats = _mlp(cfg, blk, h, counted)
         if cfg.post_norms:
             mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
         x = x + mlp
-        return x, (k, v)
+        return x, (k, v, stats)
 
-    x, (ks, vs) = jax.lax.scan(step, x, (blocks, layer_idx))
+    x, (ks, vs, stats) = jax.lax.scan(step, x, (blocks, layer_idx))
+    if counted is not None:
+        return x, ks, vs, _moe_total(stats)
     return x, ks, vs
 
 
@@ -528,6 +568,7 @@ def prefill_into_cache(
     slots: jnp.ndarray,  # [Bp] cache slot per prompt
     mesh=None,
     return_prompt_logprobs: bool = False,
+    stat_rows=None,  # [Bp] bool: with it, the routed layers' counts come last
 ):
     """Prefill prompts and scatter their KV into cache slots.
 
@@ -541,10 +582,28 @@ def prefill_into_cache(
     ``tokens[:, t]`` under the logits at position ``t-1``; entry 0 is 0.0
     (no context) and entries past a prompt's length are junk the caller
     masks by ``lengths``.
+
+    With ``stat_rows`` the last value returned is what the routed layers
+    counted (models/moe.py STATS) of the real tokens of those rows; padding
+    rows, which the engine parks on its scratch slot, count for nothing.
     """
     b, t = tokens.shape
+    if cfg.kv_lora_rank:
+        from p2p_llm_tunnel_tpu.models import mla
+
+        out = mla.prefill_into_cache(
+            cfg, params, tokens, lengths, kv_cache, slots,
+            return_prompt_logprobs=return_prompt_logprobs,
+            stat_rows=stat_rows)
+        return out if stat_rows is not None else out[:-1]
     valid = jnp.arange(t)[None, :] < lengths[:, None]
-    logits, ks, vs = prefill(cfg, params, tokens, valid, mesh=mesh)
+    stats = None
+    if stat_rows is not None:
+        logits, ks, vs, stats = prefill(
+            cfg, params, tokens, valid, mesh=mesh,
+            counted=valid & stat_rows[:, None])
+    else:
+        logits, ks, vs = prefill(cfg, params, tokens, valid, mesh=mesh)
     last = jnp.take_along_axis(
         logits, (lengths - 1)[:, None, None], axis=1
     )[:, 0]  # [Bp, V]
@@ -593,9 +652,47 @@ def prefill_into_cache(
     else:
         out["k"] = kv_cache["k"].at[:, slots, :t_w].set(ks)
         out["v"] = kv_cache["v"].at[:, slots, :t_w].set(vs)
-    if return_prompt_logprobs:
-        return last, out, prompt_lps
-    return last, out
+    ret = (last, out, prompt_lps) if return_prompt_logprobs else (last, out)
+    return ret + (stats,) if stat_rows is not None else ret
+
+
+def tail_placement(kv_view: int, starts: jnp.ndarray, t: int):
+    """Which tail position each view position takes, a 0/1 matrix
+    ``place [Bp, view, T]``: one 1 for a position in [starts, starts + T),
+    none elsewhere, so a tail position at or past the view is dropped; and
+    ``fresh [Bp, view]``, the positions that take one."""
+    place = (
+        jnp.arange(kv_view)[None, :, None] - starts[:, None, None]
+        == jnp.arange(t)[None, None, :]
+    )
+    return place, place.any(axis=-1)
+
+
+def read_cache_view(plane, idx, n: int, slots):
+    """Positions ``[0, n)`` of the rows ``slots`` of layer ``idx`` of a
+    cache plane ``[L, rows, S, ...]``: one (layer, view) slice of all cache
+    rows, then the row gather."""
+    start = (idx,) + (jnp.zeros((), idx.dtype),) * (plane.ndim - 1)
+    shape = (1, plane.shape[1], n) + plane.shape[3:]
+    return jax.lax.dynamic_slice(plane, start, shape)[0][slots]
+
+
+def lay_tail(hist, tail, place, fresh):
+    """``tail [Bp,T,...]`` over positions ``[starts, starts + T)`` of
+    ``hist [Bp,view,...]`` (``place``, ``fresh``: :func:`tail_placement`).
+    The 0/1 product puts each tail value in its place exactly (one term a
+    position); on the chip it costs less than a scatter into the gathered
+    rows, which first copies them into one buffer (PERF.md section 6, PR
+    26)."""
+    # float32 alone has to ask: the chip's default rounds its products
+    # to bfloat16 (asked of bfloat16 tails too, it costs 3 % of a run).
+    exact = jax.lax.Precision.HIGHEST if tail.dtype == jnp.float32 else None
+    moved = jnp.einsum(
+        "bpt,bt...->bp...", place.astype(tail.dtype), tail,
+        precision=exact, preferred_element_type=tail.dtype,
+    )
+    at = fresh.reshape(fresh.shape + (1,) * (hist.ndim - 2))
+    return jnp.where(at, moved, hist)
 
 
 def chunk_prefill_into_cache(
@@ -609,7 +706,8 @@ def chunk_prefill_into_cache(
     kv_view: Optional[int] = None,  # static: attend only to cache[:kv_view]
     return_all_logits: bool = False,  # static: [Bp,T,V] instead of last
     unaligned_int4: bool = False,  # static: arbitrary-parity int4 starts
-) -> Tuple[jnp.ndarray, KVCache]:
+    stat_rows=None,  # [Bp] bool: with it, the routed layers' counts come last
+):
     """Prefill only the TAIL of each prompt against reused history KV.
 
     The prefix-cache admission path (engine/prefix_cache.py): positions
@@ -675,9 +773,23 @@ def chunk_prefill_into_cache(
     registers from gathered covering bytes, so HBM stores stay whole-byte
     and the last ``config_fences`` entry stays dead (ISSUE 17).
 
-    Returns last-real-tail-token logits [Bp, V] and the updated cache.
+    Returns last-real-tail-token logits [Bp, V] and the updated cache, and
+    with ``stat_rows`` the routed layers' counts of the real tokens of those
+    rows (as ``prefill_into_cache``).
     """
     b, t = tokens.shape
+    if cfg.kv_lora_rank:
+        from p2p_llm_tunnel_tpu.models import mla
+
+        out = mla.chunk_prefill_into_cache(
+            cfg, params, tokens, lengths, starts, kv_cache, slots,
+            kv_view=kv_view, return_all_logits=return_all_logits,
+            stat_rows=stat_rows)
+        return out if stat_rows is not None else out[:-1]
+    counted = None
+    if stat_rows is not None:
+        counted = ((jnp.arange(t)[None, :] < lengths[:, None])
+                   & stat_rows[:, None])
     quant_mode = kv_cache_quant_mode(kv_cache)
     if quant_mode == "int4" and t % 2 and not unaligned_int4:
         raise ValueError(
@@ -693,14 +805,7 @@ def chunk_prefill_into_cache(
     layer_idx = jnp.arange(cfg.n_layers)
     quant = kv_cache_is_quantized(kv_cache)
     rows = slots[:, None]  # [Bp,1] broadcasts against pos [Bp,T]
-    # Which tail position each view position takes, a 0/1 matrix
-    # [Bp, view, T]: one 1 for a position in [starts, starts + T), none
-    # elsewhere, so a tail position at or past the view is dropped.
-    place = (
-        jnp.arange(kv_view)[None, :, None] - starts[:, None, None]
-        == jnp.arange(t)[None, None, :]
-    )
-    fresh = place.any(axis=-1)  # [Bp, view]
+    place, fresh = tail_placement(kv_view, starts, t)
     # (int4: the packed value planes slice kv_view // 2 BYTE rows and
     # unpack to kv_view tokens.)
     view_rows = kv_view // 2 if quant_mode == "int4" else kv_view
@@ -714,27 +819,10 @@ def chunk_prefill_into_cache(
     from p2p_llm_tunnel_tpu.ops.attention import history_attention
 
     def read_view(plane, idx, n):
-        """Positions ``[0, n)`` of the dispatch's own rows of layer ``idx``:
-        one (layer, view) slice of all cache rows, then the row gather."""
-        start = (idx,) + (jnp.zeros((), idx.dtype),) * (plane.ndim - 1)
-        shape = (1, plane.shape[1], n) + plane.shape[3:]
-        return jax.lax.dynamic_slice(plane, start, shape)[0][slots]
+        return read_cache_view(plane, idx, n, slots)
 
     def lay(hist, tail):
-        """``tail [Bp,T,...]`` over positions ``[starts, starts + T)`` of
-        ``hist [Bp,view,...]``.  The 0/1 product puts each tail value in its
-        place exactly (one term a position); on the chip it costs less than
-        a scatter into the gathered rows, which first copies them into one
-        buffer (PERF.md section 6, PR 26)."""
-        # float32 alone has to ask: the chip's default rounds its products
-        # to bfloat16 (asked of bfloat16 tails too, it costs 3 % of a run).
-        exact = jax.lax.Precision.HIGHEST if tail.dtype == jnp.float32 else None
-        moved = jnp.einsum(
-            "bpt,bt...->bp...", place.astype(tail.dtype), tail,
-            precision=exact, preferred_element_type=tail.dtype,
-        )
-        at = fresh.reshape(fresh.shape + (1,) * (hist.ndim - 2))
-        return jnp.where(at, moved, hist)
+        return lay_tail(hist, tail, place, fresh)
 
     def step(x, xs):
         blk, idx = xs
@@ -777,13 +865,13 @@ def chunk_prefill_into_cache(
             x = x + attn
         with jax.named_scope("ffn"):
             h = _norm(cfg, x, blk["mlp_norm"])
-            mlp = _mlp(cfg, blk, h)
+            mlp, stats = _mlp(cfg, blk, h, counted)
             if cfg.post_norms:
                 mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
             x = x + mlp
-        return x, (k, v, k_s, v_s)
+        return x, (k, v, k_s, v_s, stats)
 
-    x, (ks, vs, k_ss, v_ss) = jax.lax.scan(
+    x, (ks, vs, k_ss, v_ss, stats) = jax.lax.scan(
         step, x, (params["blocks"], layer_idx)
     )
     # One write for all layers, [L,Bp,T,K,D] into the donated cache.
@@ -813,14 +901,15 @@ def chunk_prefill_into_cache(
     with jax.named_scope("head_sample"):
         x = _norm(cfg, x, params["final_norm"])
         logits = _logits(cfg, params, x)  # [Bp,T,V]
+    tail = (_moe_total(stats),) if stat_rows is not None else ()
     if return_all_logits:
         # Speculative verify (engine spec_ngram): every position's logits
         # decide how many proposed tokens survive.
-        return logits, new_cache
+        return (logits, new_cache) + tail
     last = jnp.take_along_axis(
         logits, (lengths - 1)[:, None, None], axis=1
     )[:, 0]
-    return last, new_cache
+    return (last, new_cache) + tail
 
 
 def spec_verify_into_cache(
@@ -905,7 +994,7 @@ def spec_verify_into_cache(
             attn = _norm(cfg, attn, blk["post_attn_norm"])
         x = x + attn
         h = _norm(cfg, x, blk["mlp_norm"])
-        mlp = _mlp(cfg, blk, h)
+        mlp, _ = _mlp(cfg, blk, h)
         if cfg.post_norms:
             mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
         x = x + mlp
@@ -1001,7 +1090,7 @@ def ragged_prefill_into_cache(
             attn = _norm(cfg, attn, blk["post_attn_norm"])
         x = x + attn
         h = _norm(cfg, x, blk["mlp_norm"])
-        mlp = _mlp(cfg, blk, h)
+        mlp, _ = _mlp(cfg, blk, h)
         if cfg.post_norms:
             mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
         x = x + mlp
@@ -1032,8 +1121,12 @@ def decode_step(
     positions: jnp.ndarray,  # [B] where this token goes in the cache
     kv_view: Optional[int] = None,  # static: attend only to cache[:kv_view]
     mesh=None,  # Mesh when params/cache are sharded (gates the flash path)
-) -> Tuple[jnp.ndarray, KVCache]:
-    """One decode step over every slot. Returns (logits [B,V], new cache).
+    with_stats: bool = False,  # static: the routed layers' counts come last
+):
+    """One decode step over every slot. Returns (logits [B,V], new cache),
+    and under ``with_stats`` what the routed layers counted (models/moe.py
+    STATS) of the rows that are not parked at ``positions >= S``; the Pallas
+    decode paths count nothing.
 
     Static shapes throughout: inactive slots still compute (masked out by the
     engine when sampling) — the XLA-friendly cost of continuous batching.
@@ -1052,6 +1145,12 @@ def decode_step(
     later reads exactly what was written.
     """
     b = tokens.shape[0]
+    if cfg.kv_lora_rank:
+        from p2p_llm_tunnel_tpu.models import mla
+
+        out = mla.decode_step(cfg, params, kv_cache, tokens, positions,
+                              kv_view=kv_view)
+        return out if with_stats else out[:-1]
     quant_mode = kv_cache_quant_mode(kv_cache)
     quant = quant_mode is not None
     # Logical sequence length: the int4 cache's sequence axis is byte-packed.
@@ -1060,6 +1159,7 @@ def decode_step(
         kv_view = s
     x = _embed(cfg, params, tokens[:, None])  # [B,1,Dm]
     pos2d = positions[:, None]  # [B,1]
+    counted = pos2d < s if with_stats else None
     layer_idx = jnp.arange(cfg.n_layers)
     slot_ids = jnp.arange(b)
 
@@ -1100,7 +1200,7 @@ def decode_step(
                 attn = _norm(cfg, attn, blk["post_attn_norm"])
             x = x + attn
             h = _norm(cfg, x, blk["mlp_norm"])
-            mlp = _mlp(cfg, blk, h)
+            mlp, _ = _mlp(cfg, blk, h)
             if cfg.post_norms:
                 mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
             x = x + mlp
@@ -1113,6 +1213,8 @@ def decode_step(
         )
         x = _norm(cfg, x, params["final_norm"])
         logits = _logits(cfg, params, x)[:, 0]  # [B,V]
+        if with_stats:
+            return logits, new_cache, _moe_total(None)
         return logits, new_cache
 
     # flash_decode / flash_sgrid both route to the S-GRID family now: the
@@ -1233,13 +1335,13 @@ def decode_step(
             x = x + attn
         with jax.named_scope("ffn"):
             h = _norm(cfg, x, blk["mlp_norm"])
-            mlp = _mlp(cfg, blk, h)
+            mlp, stats = _mlp(cfg, blk, h, counted)
             if cfg.post_norms:
                 mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
             x = x + mlp
-        return (x, cache), None
+        return (x, cache), stats
 
-    (x, new_cache), _ = jax.lax.scan(
+    (x, new_cache), stats = jax.lax.scan(
         step,
         (x, dict(kv_cache)),
         (params["blocks"], layer_idx),
@@ -1247,6 +1349,8 @@ def decode_step(
     with jax.named_scope("head_sample"):
         x = _norm(cfg, x, params["final_norm"])
         logits = _logits(cfg, params, x)[:, 0]  # [B,V]
+    if with_stats:
+        return logits, new_cache, _moe_total(stats)
     return logits, new_cache
 
 

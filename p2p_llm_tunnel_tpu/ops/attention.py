@@ -167,3 +167,63 @@ def cached_attention(
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = _gqa_out(probs, v_cache)
     return out.reshape(b, 1, h, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA): one cached row [c | k_r] a token, shared by all heads
+# ---------------------------------------------------------------------------
+
+def _masked_softmax(scores: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    scores = jnp.where(mask, scores, _NEG_INF)
+    probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+    return probs / probs.sum(axis=-1, keepdims=True)
+
+
+def latent_attention_decompressed(
+    q_n: jnp.ndarray,   # [B,T,H,Dn] no-rope part of the query
+    q_r: jnp.ndarray,   # [B,T,H,Dr] roped part
+    k_n: jnp.ndarray,   # [B,S,H,Dn] keys decompressed from the latent
+    k_r: jnp.ndarray,   # [B,S,Dr] the one roped key all heads share
+    v: jnp.ndarray,     # [B,S,H,Dv] values decompressed from the latent
+    mask: jnp.ndarray,  # [B,T,S] bool
+    scale: float,
+) -> jnp.ndarray:
+    """The prefill form: keys of width Dn + Dr, values of width Dv, per
+    head.  Returns [B,T,H,Dv]."""
+    scores = (
+        jnp.einsum("bthd,bshd->bhts", q_n, k_n,
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("bthd,bsd->bhts", q_r, k_r,
+                     preferred_element_type=jnp.float32)
+    ) * scale
+    probs = _masked_softmax(scores, mask[:, None])
+    out = jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q_n.dtype)
+
+
+def latent_attention_absorbed(
+    q_c: jnp.ndarray,    # [B,T,H,C] no-rope query folded through W_kn
+    q_r: jnp.ndarray,    # [B,T,H,Dr]
+    c: jnp.ndarray,      # [B,S,C] cached latents
+    k_r: jnp.ndarray,    # [B,S,Dr] cached roped keys
+    mask: jnp.ndarray,   # [B,T,S] bool
+    scale: float,
+) -> jnp.ndarray:
+    """The decode form: every head scores against the one cached row
+    ``[c | k_r]`` and the weighted sum is taken of the latent itself.
+    Returns [B,T,H,C]; the caller applies W_kvb's value half.  Same
+    mathematics as the decompressed form (tests/test_mla_moe.py holds them
+    equal)."""
+    scores = (
+        jnp.einsum("bthc,bsc->bhts", q_c, c,
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("bthd,bsd->bhts", q_r, k_r,
+                     preferred_element_type=jnp.float32)
+    ) * scale
+    probs = _masked_softmax(scores, mask[:, None])
+    # (summed head-major: the CPU backend has no bfloat16 product into
+    # float32 for the token-major result; the swap is of a [B,H,T,C] array)
+    out = jnp.einsum("bhts,bsc->bhtc", probs.astype(c.dtype), c,
+                     preferred_element_type=jnp.float32)
+    return jnp.swapaxes(out, 1, 2).astype(q_c.dtype)

@@ -35,6 +35,24 @@ METRICS_CATALOG: Dict[str, str] = {
     "engine_decode_row_steps_total": (
         "live rows x steps over every decode burst dispatched (counter)"
     ),
+    "engine_moe_assignments_total": (
+        "token-to-expert assignments the routed layers made of real tokens, "
+        "over every expert layer of every dispatch (counter)"
+    ),
+    "engine_moe_assignments_held_total": (
+        "of those, the assignments to experts this process holds; over "
+        "engine_moe_assignments_total it is the share of the routed work "
+        "done here (counter)"
+    ),
+    "engine_moe_expert_tokens_max_total": (
+        "tokens of the fullest held expert, summed over expert layers, decode "
+        "steps and prefill dispatches; times the experts held over "
+        "engine_moe_assignments_held_total it is the imbalance (counter)"
+    ),
+    "engine_moe_experts_touched_total": (
+        "held experts that got a token, summed likewise: the expert weights "
+        "a step has to read (counter)"
+    ),
     "engine_decode_slot_steps_total": (
         "slots x steps over every decode burst dispatched; row-steps / "
         "slot-steps is the decode fill --slots is sized by (counter)"

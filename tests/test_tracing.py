@@ -360,7 +360,7 @@ def test_healthz_trace_export_and_pool_accounting():
                 # ... and the precision the engine was built with (what
                 # an engine publishes at construction; null without one).
                 assert set(payload["config"]) == {
-                    "fences", "attention", "quant", "kv_quant"}
+                    "fences", "attention", "quant", "kv_quant", "model"}
                 global_metrics.set_info("config_quant", "int8")
                 global_metrics.set_info("config_kv_quant", "none")
                 try:
