@@ -118,9 +118,11 @@ def build_parser() -> argparse.ArgumentParser:
                             "a full burst (0 = no adaptation)")
     serve.add_argument("--prefill-rows", type=int,
                        default=int(_env("TUNNEL_PREFILL_ROWS", "8")),
-                       help="rows per batched-prefill program: admissions "
-                            "are chunked and padded to exactly this many "
-                            "rows per dispatch")
+                       help="at most this many rows per batched-prefill "
+                            "dispatch: admissions are chunked to it; a "
+                            "prompt's first segment that arrives alone or "
+                            "beside one other runs as one or two rows, not "
+                            "padded to this many")
     serve.add_argument("--dtype", default=_env("TUNNEL_DTYPE", "bfloat16"),
                        help="activation/weight dtype for the in-process "
                             "engine (bfloat16|float32)")

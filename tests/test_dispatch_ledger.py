@@ -136,9 +136,12 @@ def test_prefill_records_and_counters_agree_exactly(traced):
         grown["engine_prefill_positions_total"]
     for r in segs:
         a = r.attrs
-        assert a["positions"] == a["rows_padded"] * a["t"] == ROWS * CHUNK
-        assert 0 < a["rows"] <= a["rows_padded"]
-        assert 0 < a["tokens"] <= a["rows"] * CHUNK
+        # the rung the dispatch was padded to (ISSUE 30): the smallest of
+        # chunk_row_ladder(ROWS) = (1, 2) that holds its rows
+        assert a["rows_padded"] == a["rows"] <= ROWS
+        assert a["positions"] == a["rows_padded"] * a["t"]
+        assert a["t"] == CHUNK and 0 < a["tokens"] <= a["rows"] * CHUNK
+    assert {r.attrs["rows_padded"] for r in segs} == {1, 2}  # both rungs ran
     # the pool spared the sharers their prefix: fewer tokens than prompts
     assert grown["engine_prefill_tokens_total"] < \
         sum(len(p) for p in prompts())

@@ -242,16 +242,21 @@ def _plane_work(hlo, plane):
     return copies, made
 
 
-@pytest.mark.parametrize("model,kv,view", [
-    ("qwen2-7b", None, 1024), ("qwen2-7b", "int8", 1024),
-    ("qwen2-7b", "int4", 1024), ("mistral-7b", None, 1024),
-    ("mistral-7b", "int8", 1024), ("mistral-7b", "int4", 1024),
-    ("qwen2-7b", None, 256),
+@pytest.mark.parametrize("model,kv,view,rung", [
+    ("qwen2-7b", None, 1024, 8), ("qwen2-7b", "int8", 1024, 8),
+    ("qwen2-7b", "int4", 1024, 8), ("mistral-7b", None, 1024, 8),
+    ("mistral-7b", "int8", 1024, 8), ("mistral-7b", "int4", 1024, 8),
+    ("qwen2-7b", None, 256, 8),
+    # the low rungs are programs of their own (ISSUE 30); the cells run
+    # them at view 128, the guard also holds at the largest
+    ("qwen2-7b", None, 1024, 1), ("qwen2-7b", "int8", 1024, 1),
+    ("qwen2-7b", "int4", 1024, 1), ("mistral-7b", None, 1024, 1),
+    ("qwen2-7b", None, 128, 1), ("qwen2-7b", None, 128, 2),
 ])
 def test_chunk_prefill_makes_no_cache_plane_in_its_layer_loop(
-        chip, model, kv, view):
+        chip, model, kv, view, rung):
     """``chunk_prefill_into_cache`` at the benchmark's shapes (33 rows x
-    1024, tail 8 x 128, cache donated) and each configuration's own
+    1024, tail ``rung`` x 128, cache donated) and each configuration's own
     attention widths and depth; FFN and vocabulary are cut, they do not
     touch the cache's layout.  With 4 KV heads (qwen2-7b) the compiler
     keeps a loop-carried plane heads-outermost and a scatter wants it
@@ -281,8 +286,8 @@ def test_chunk_prefill_makes_no_cache_plane_in_its_layer_loop(
     cache = on_chip(jax.eval_shape(
         lambda: init_kv_cache(cfg, ROWS, MAX_SEQ, quant=kv)))
     tokens, row = on_chip((
-        jax.ShapeDtypeStruct((8, 128), jnp.int32),
-        jax.ShapeDtypeStruct((8,), jnp.int32),
+        jax.ShapeDtypeStruct((rung, 128), jnp.int32),
+        jax.ShapeDtypeStruct((rung,), jnp.int32),
     ))
     hlo = jax.jit(
         lambda p, c, tok, lengths, starts, slots: chunk_prefill_into_cache(
@@ -317,8 +322,13 @@ def _share_shapes(chip, cfg, rows, max_seq, kv=None):
     return params, cache
 
 
-@pytest.mark.parametrize("kv", [None, "int8"])
-def test_chunk_prefill_makes_no_latent_plane_in_its_layer_loops(chip, kv):
+@pytest.mark.parametrize("kv,rung,t,view", [
+    (None, 8, 128, 1024), ("int8", 8, 128, 1024),
+    # the cell's own dispatches: the ladder under --prefill-rows 2
+    (None, 1, 512, 2048), (None, 2, 512, 2048),
+])
+def test_chunk_prefill_makes_no_latent_plane_in_its_layer_loops(
+        chip, kv, rung, t, view):
     """The same guard on the latent planes (sarvam-105b's share: 33 rows x
     4096 of 512 latent values a layer and of 128 rope-key values a pair of
     layers; two layer scans): no plane-sized ``copy``, neither loop makes a
@@ -333,11 +343,11 @@ def test_chunk_prefill_makes_no_latent_plane_in_its_layer_loops(chip, kv):
     cfg = get_config("sarvam-105b-ep4s", ffn_dim=512, moe_ffn_dim=128,
                      vocab_size=1024)
     params, cache = _share_shapes(chip, cfg, 33, 4096, kv)
-    tokens, row = _on(chip, (jax.ShapeDtypeStruct((8, 128), jnp.int32),
-                             jax.ShapeDtypeStruct((8,), jnp.int32)))
+    tokens, row = _on(chip, (jax.ShapeDtypeStruct((rung, t), jnp.int32),
+                             jax.ShapeDtypeStruct((rung,), jnp.int32)))
     hlo = jax.jit(
         lambda p, c, tok, lengths, starts, slots: chunk_prefill_into_cache(
-            cfg, p, tok, lengths, starts, c, slots, kv_view=1024),
+            cfg, p, tok, lengths, starts, c, slots, kv_view=view),
         donate_argnums=(1,),
     ).lower(params, cache, tokens, row, row, row).compile().as_text()
     for plane in ("c", "kr"):
@@ -347,6 +357,47 @@ def test_chunk_prefill_makes_no_latent_plane_in_its_layer_loops(chip, kv):
     assert "while(" in hlo  # the expert layers are still one loop to look into
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
     assert aliased.count("alias") == len(cache)
+
+
+@pytest.mark.parametrize("model,max_seq,t,view,prefill_rows,top_gb", [
+    # the eight-row program's temporaries as the chip's own
+    # memory_analysis() read them (PERF.md section 4, PR 26): 1.24 GB
+    ("qwen2-7b", 1024, 128, 1024, 8, 1.25),
+    ("mistral-7b", 1024, 128, 1024, 8, 0.5),
+    ("sarvam-105b-ep4s", 4096, 512, 2048, 2, 1.25),
+])
+def test_chunk_program_temporaries_shrink_with_the_rung(
+        chip, model, max_seq, t, view, prefill_rows, top_gb):
+    """The whole chunk-prefill program of each configuration at the
+    benchmark's shapes (33 cache rows; the dense 7B models with int8 weights
+    as served), compiled at the two rungs a segment dispatch pads to: the
+    one-row program needs under a third of what the ``prefill_rows`` one
+    needs beside the weights and the cache (mostly the ``[rows, t,
+    vocabulary]`` logits), so it cannot fail to load where that one loads."""
+    from p2p_llm_tunnel_tpu.models import transformer as T
+    from p2p_llm_tunnel_tpu.models.config import get_config
+    from p2p_llm_tunnel_tpu.models.quant import init_params_quantized
+
+    cfg = get_config(model)
+    init = T.init_params if cfg.kv_lora_rank else init_params_quantized
+    params = _on(chip, jax.eval_shape(
+        lambda: init(cfg, jax.random.PRNGKey(0))))
+    cache = _on(chip, jax.eval_shape(
+        lambda: T.init_kv_cache(cfg, ROWS, max_seq)))
+    temps = []
+    for rung in (1, prefill_rows):
+        tokens, row = _on(chip, (
+            jax.ShapeDtypeStruct((rung, t), jnp.int32),
+            jax.ShapeDtypeStruct((rung,), jnp.int32)))
+        compiled = jax.jit(
+            lambda p, c, tok, lengths, starts, slots:
+            T.chunk_prefill_into_cache(
+                cfg, p, tok, lengths, starts, c, slots, kv_view=view),
+            donate_argnums=(1,),
+        ).lower(params, cache, tokens, row, row, row).compile()
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+    assert temps[0] < temps[1] / 3, temps
+    assert temps[1] < top_gb * 1e9, temps
 
 
 SHARE_PROGRAMS = {

@@ -2531,7 +2531,7 @@ def test_tc17_plan_tuple_and_warm_helper_kinds_are_reachable(tmp_path):
         """
         class Eng:
             def warmup_plan(self):
-                return [("decode", (128, 8)), ("chunk", (16, 128))]
+                return [("decode", (128, 8)), ("chunk", (8, 16, 128))]
 
             def _warm_ragged_program(self, tot):
                 self._note_program("ragged", (tot,), 0.0)
@@ -2540,7 +2540,7 @@ def test_tc17_plan_tuple_and_warm_helper_kinds_are_reachable(tmp_path):
                 self._note_program("decode", (128, 8), 0.1)
 
             def _dispatch_chunk_rows(self, rows, t):
-                self._note_program("chunk", (t, 128), 0.1)
+                self._note_program("chunk", (len(rows), t, 128), 0.1)
 
             def _dispatch_ragged_rows(self, rows):
                 self._note_program("ragged", (64,), 0.1)
@@ -2548,6 +2548,51 @@ def test_tc17_plan_tuple_and_warm_helper_kinds_are_reachable(tmp_path):
         rules=["TC17"],
     )
     assert active == []
+
+
+_TC17_ROW_KEY = """
+class Eng:
+    def warmup_plan(self):
+        return [("decode", (128, 8))] + [
+            ("chunk", {plan}) for rows in (1, 8)]
+
+    def _warm_chunk_program(self, nb, t, view):
+        self._note_program("chunk", {warm}, 0.0)
+
+    def _dispatch_decode(self):
+        self._note_program("decode", (128, 8), 0.1)
+
+    def _dispatch_chunk_rows(self, rows, t):
+        self._note_program("chunk", {dispatch}, 0.1)
+"""
+
+
+@pytest.mark.parametrize("plan,warm,dispatch,flagged", [
+    ("(rows, 16, 128)", "(nb, t, view)", "(nb, t, 128)", False),
+    # the key before the row ladder, left behind at the dispatch site
+    ("(rows, 16, 128)", "(nb, t, view)", "(t, 128)", True),
+    # ... or left behind in the plan and the warmer: one rung of many warmed
+    ("(16, 128)", "(t, view)", "(nb, t, 128)", True),
+    # a shape that is no tuple literal says nothing about its dimensions
+    ("(rows, 16, 128)", "(nb, t, view)", "shape", False),
+    ("shape", "shape", "(nb, t, 128)", False),
+])
+def test_tc17_knows_a_key_by_its_dimensions_too(tmp_path, plan, warm,
+                                                dispatch, flagged):
+    """ISSUE 30: a chunk program is (rows, t, view).  A dispatch site that
+    notes another number of dimensions than warm-up readies mints keys
+    that never match, whatever the kind says."""
+    active, _ = check(
+        tmp_path,
+        _TC17_ROW_KEY.format(plan=plan, warm=warm, dispatch=dispatch),
+        rules=["TC17"],
+    )
+    if not flagged:
+        assert active == []
+        return
+    assert rules_of(active) == ["TC17"]
+    assert "'chunk'" in active[0].message
+    assert "dimension" in active[0].message
 
 
 def test_tc17_program_key_spelling_is_a_dispatch_site_too(tmp_path):

@@ -234,9 +234,11 @@ def test_mux_herd_hits_zero_cold_compiles(persistent_cache, monkeypatch):
     program the scheduler can reach — both burst sizes x every view
     bucket, the chunk program at the (defaulted) segment width x every
     view a padded tail can bucket to (the cap + prefill_chunk term of
-    _warmup_views), the prefix copy ops, and the single batched-segment
-    row shape (rows always pad to prefill_rows, so the budget controller
-    cannot mint new shapes) — is compiled by warmup(); a multiplexed
+    _warmup_views), the prefix copy ops, and every row rung a dispatch
+    pads to (chunk_row_ladder: the budget controller picks how many rows a
+    dispatch carries, the ladder which warmed shape holds them; the herd
+    over all rungs of prefill_rows=8 is tests/test_prefill_row_ladder.py)
+    — is compiled by warmup(); a multiplexed
     shared-prefix herd with multi-segment, short-tail, and mid-decode
     admissions then adds ZERO fresh compiles."""
     monkeypatch.setenv("TUNNEL_WARMUP_VIEW_CAP", "100")

@@ -33,13 +33,21 @@ Mechanics: per file, literal kinds are collected from two sides —
 
 A dispatch kind absent from the file's warm kinds flags at the dispatch
 site.  Files that never call ``_note_program`` are out of scope.
+
+A key is its kind AND its shape (ISSUE 30: a chunk-prefill program is
+``(rows, t, view)`` since a dispatch picks its row count): where a dispatch
+site and the warm side both spell a kind's shape as a tuple literal, the
+dispatch site's must have as many dimensions as one the warm side spells —
+a ``chunk[t,view]`` noted at dispatch can never equal a warmed
+``chunk[rows,t,view]``, so every first dispatch would count as a cold
+compile (and a plan that forgot the new axis warms one rung of many).
 """
 
 from __future__ import annotations
 
 import ast
 import re
-from typing import Iterator, List, Optional, Set
+from typing import Dict, Iterator, List, Optional, Set
 
 from tools.tunnelcheck.core import ProjectContext, SourceFile, Violation
 
@@ -49,6 +57,14 @@ _KIND_FNS = ("_note_program", "_program_key")
 #: Functions whose bodies ARE the warmup/AOT plan: the serial pass, the
 #: plan enumeration, and the per-kind warm helpers.
 _WARM_NAME_RE = re.compile(r"^(warmup|_warm)")
+
+_MSG_DIMS = (
+    "program kind {kind!r} is keyed by {got} dimension(s) here but the "
+    "warmup/AOT plan generators key it by {want}: the two spellings of the "
+    "key can never be equal, so warm-up readies programs this site never "
+    "finds and every first dispatch counts as a mid-serve cold compile — "
+    "give the dispatch site and warmup_plan()/_warm_* the same shape"
+)
 
 _MSG = (
     "program kind {kind!r} is dispatched here but unreachable from the "
@@ -85,9 +101,18 @@ def _arg0_kinds(node: ast.Call) -> List[str]:
     return []
 
 
+def _dims(node: Optional[ast.AST]) -> Optional[int]:
+    """Dimensions of a shape spelled as a tuple literal, else None."""
+    if isinstance(node, ast.Tuple) and not any(
+            isinstance(e, ast.Starred) for e in node.elts):
+        return len(node.elts)
+    return None
+
+
 def check_tc17(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
     warm_kinds: Set[str] = set()
-    dispatch_sites: List = []  # (node, kinds)
+    warm_dims: Dict[str, Set[int]] = {}
+    dispatch_sites: List = []  # (node, kinds, dimensions of the shape)
     saw_note = [False]
 
     def visit_fn(fn, enclosing_warm: Optional[bool]) -> None:
@@ -110,6 +135,7 @@ def check_tc17(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
                 name = _call_name(node)
                 if name in _KIND_FNS:
                     kinds = _arg0_kinds(node)
+                    dims = _dims(node.args[1]) if len(node.args) > 1 else None
                     if not is_warm:
                         # BOTH spellings are dispatch sites: a program
                         # key minted via _program_key directly (ad-hoc
@@ -117,9 +143,12 @@ def check_tc17(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
                         # as a _note_program call.
                         saw_note[0] = True
                         if kinds:
-                            dispatch_sites.append((node, kinds))
+                            dispatch_sites.append((node, kinds, dims))
                     else:
                         warm_kinds.update(kinds)
+                        if dims is not None:
+                            for kind in kinds:
+                                warm_dims.setdefault(kind, set()).add(dims)
             elif is_warm and isinstance(node, ast.Tuple) and node.elts:
                 # The plan enumeration's ("kind", shape) tuples and the
                 # AOT jobs list's leading-label tuples.
@@ -127,6 +156,9 @@ def check_tc17(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
                 if (isinstance(first, ast.Constant)
                         and isinstance(first.value, str)):
                     warm_kinds.add(first.value)
+                    dims = _dims(node.elts[1]) if len(node.elts) == 2 else None
+                    if dims is not None:
+                        warm_dims.setdefault(first.value, set()).add(dims)
             stack.extend(ast.iter_child_nodes(node))
 
     def visit_scope(body) -> None:
@@ -140,11 +172,21 @@ def check_tc17(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
     if not saw_note[0]:
         return iter(())
     out: List[Violation] = []
-    for node, kinds in dispatch_sites:
+    for node, kinds, dims in dispatch_sites:
         for kind in sorted(set(kinds) - warm_kinds):
             out.append(Violation(
                 "TC17", sf.path, node.lineno,
                 _MSG.format(kind=kind),
                 end_line=node.end_lineno,
             ))
+        for kind in sorted(set(kinds) & warm_kinds):
+            want = warm_dims.get(kind)
+            if dims is not None and want and dims not in want:
+                out.append(Violation(
+                    "TC17", sf.path, node.lineno,
+                    _MSG_DIMS.format(
+                        kind=kind, got=dims,
+                        want=" or ".join(str(d) for d in sorted(want))),
+                    end_line=node.end_lineno,
+                ))
     return iter(out)
