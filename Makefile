@@ -1,7 +1,7 @@
 # Dev commands — the reference uses a Justfile (Justfile:9-61); make is the
 # equivalent available in this toolchain.
 
-.PHONY: native native-san lint test test-unit test-fast test-local test-race chaos bench bench-smoke chip-smoke loadgen serve proxy signal multichip
+.PHONY: native native-san lint test test-unit test-fast test-local test-race chaos chip-smoke loadgen serve proxy signal multichip
 
 native:            ## build the C++ frame codec + ARQ core (git-ignored: built, not shipped)
 	scripts/build-native.sh
@@ -24,7 +24,7 @@ lint:              ## tunnelcheck static invariants + test-collection guard
 	@# warnings (never exit-code-affecting) so dead waivers cannot rot in
 	@# place masking future regressions on the same line.
 	@mkdir -p artifacts
-	python -m tools.tunnelcheck p2p_llm_tunnel_tpu scripts tests bench.py chip_smoke.py __graft_entry__.py --jobs auto --sarif artifacts/lint.sarif --cache artifacts/tunnelcheck-cache --waiver-audit --budget-s $(LINT_BUDGET_S)
+	python -m tools.tunnelcheck p2p_llm_tunnel_tpu scripts tests chip_smoke.py __graft_entry__.py --jobs auto --sarif artifacts/lint.sarif --cache artifacts/tunnelcheck-cache --waiver-audit --budget-s $(LINT_BUDGET_S)
 	@# Collection guard (ISSUE 4): collect ALL of tests/ — slow marks
 	@# included — so a slow-tier test file that stops importing fails HERE
 	@# instead of rotting uncollected (tier-1 deselects slow and ignores
@@ -161,19 +161,6 @@ loadgen:           ## out-of-process SSE ingress herd against a spawned loopback
 	JAX_PLATFORMS=cpu python scripts/loadgen.py --spawn \
 		--tenant herd:$${LOADGEN_CLIENTS:-500} \
 		--max-tokens $${LOADGEN_MAX_TOKENS:-16} --json
-
-bench:             ## end-to-end tok/s + TTFT through the tunnel (needs the chip)
-	python bench.py
-
-# The explicit CPU rehearsal of bench.py: tiny model, 4 clients, tight
-# caps.  The row's JSON schema is pinned by RESULT_ROW_KEYS in bench.py
-# and tests/test_bench_smoke.py; a CPU row names its platform and carries
-# no_tpu=true with vs_baseline and mfu null (never comparable to a chip).
-bench-smoke:       ## fast CPU-only bench row (pinned schema)
-	JAX_PLATFORMS=cpu BENCH_MODEL=tiny BENCH_CLIENTS=4 BENCH_MAX_TOKENS=8 \
-	BENCH_SLOTS=4 BENCH_MAX_SEQ=128 BENCH_DECODE_STEPS=4 \
-	BENCH_PROMPT_TOKENS=16 \
-	BENCH_BUDGET_S=$${BENCH_BUDGET_S:-600} python bench.py
 
 # On a machine with a TPU v5e chip (through the chip tool: `chiprun --
 # python chip_smoke.py`).  Fails without a chip.  `--four-chip` runs the

@@ -278,7 +278,7 @@ def build_parser() -> argparse.ArgumentParser:
                        action=argparse.BooleanOptionalAction,
                        default=_env("TUNNEL_MUX", "1") == "1",
                        help="iteration-level prefill/decode multiplexing "
-                            "(default ON, matching bench.py): each engine "
+                            "(default ON): each engine "
                             "step runs one decode burst plus a budgeted "
                             "slice of chunked-prefill segments, with "
                             "prefix-grouped admission deduping shared "
@@ -325,8 +325,8 @@ def build_parser() -> argparse.ArgumentParser:
     serve.add_argument("--prefix-cache",
                        action=argparse.BooleanOptionalAction,
                        default=_env("TUNNEL_PREFIX_CACHE", "1") == "1",
-                       help="automatic prefix caching (default ON, matching "
-                            "bench.py): reuse prompt-prefix KV across "
+                       help="automatic prefix caching (default ON): "
+                            "reuse prompt-prefix KV across "
                             "requests (shared system prompts, resent "
                             "conversations); pure latency optimization, "
                             "outputs unchanged; disable with "
@@ -586,7 +586,7 @@ async def _serve_once(args, drain: "Optional[asyncio.Event]" = None) -> None:
 
 
 def require_tpu_backend(platform: str, who: str) -> None:
-    """A chip-serving entry point (``serve --backend tpu``, bench.py) means
+    """A chip-serving entry point (``serve --backend tpu``, chip_smoke.py) means
     the TPU: refuse to start on whatever else JAX fell back to, naming it.
     The CPU is served only when the caller asked for it explicitly with
     ``JAX_PLATFORMS=cpu`` (tests and rehearsals).  SystemExit, not an

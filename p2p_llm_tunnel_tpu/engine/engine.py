@@ -215,10 +215,8 @@ class EngineConfig:
     # measurement; correctness is oracle-pinned (tests/test_pallas_decode).
     flash_decode: bool = False
     # S-gridded flash decode (models/config.py flash_sgrid): per-block DMA
-    # with frontier-clamped fetches; the variant to measure when the plane
-    # kernel's whole-view DMA loses on chip (VERDICT r4 item 2).  As of
-    # ISSUE 4, flash_decode and flash_sgrid both select the s-grid family
-    # (the plane kernel is an interpret-mode cross-check only).
+    # with frontier-clamped fetches (VERDICT r4 item 2).  As of ISSUE 4,
+    # flash_decode and flash_sgrid both select the s-grid family.
     flash_sgrid: bool = False
     # Fused decode-layer Pallas kernel (ISSUE 4): one program per layer
     # fuses rope + new-row KV quantization + the cache append + the
@@ -1700,9 +1698,8 @@ class InferenceEngine:
         )
         # Cold-start breakdown (ISSUE 12): the per-program grid this
         # warmup compiled/loaded — count + slowest single program next to
-        # the wall total, published as gauges (and recorded in the
-        # bench-smoke row).  From here on a first-seen program key on the
-        # serving path is a mid-serve cold compile.
+        # the wall total, published as gauges.  From here on a first-seen
+        # program key on the serving path is a mid-serve cold compile.
         warm_events = global_compile_watch.since(compile_mark)
         global_metrics.set_gauge(
             "engine_warmup_programs",
@@ -1726,10 +1723,9 @@ class InferenceEngine:
         flash_force on) so the counted program is the one a TPU backend
         would run even when this process serves the CPU/interpret path;
         callers are single-threaded by construction (warmup before
-        serving; perf_probe before its measurement loop).  The ONE home of
-        the jit-signature + warm-args recipe, shared with
-        scripts/perf_probe.py — a second hand-rolled copy there is the
-        TC02 stale-signature incident class.
+        serving).  The ONE home of the jit-signature + warm-args recipe:
+        a second hand-rolled copy is the TC02 stale-signature incident
+        class.
         """
         self._ensure_decode_carry()
         old = self.mcfg
@@ -1983,8 +1979,8 @@ class InferenceEngine:
         selects from the FULL bucket list, so an out-of-hint request
         on-demand-compiles instead of breaking; the hint only trades warmup
         time against that risk.  Each program of a 32-layer model takes
-        tens of seconds to compile cold, which is why bench.py and
-        chip_smoke.py set it."""
+        tens of seconds to compile cold, which is why chip_smoke.py sets
+        it."""
         views = self._view_buckets()
         cap = int(os.environ.get("TUNNEL_WARMUP_VIEW_CAP", "0") or 0)
         if cap <= 0:

@@ -83,42 +83,6 @@ class Latin1Tokenizer(ByteTokenizer):
         return bytes([token_id]).decode("latin-1")
 
 
-class NumericTokenizer:
-    """Renders EVERY id as visible text (``"<id> "``), unlike ByteTokenizer
-    where ids ≥ 256 decode to "".
-
-    Exists for end-to-end benchmarks with random weights: each decoded token
-    becomes a non-empty SSE delta, so every token crosses the tunnel as a
-    RES_BODY frame and client-side counts equal engine counts — making frame
-    mux, flow control, and SSE emission part of the measurement (the loop
-    replaced is reference serve.rs:263-277; VERDICT r3 item 3).  Encoding is
-    byte-level like ByteTokenizer so prompts stay well-formed.
-    """
-
-    PAD = 256
-    BOS = 257
-    EOS = 258
-
-    bos_id = BOS
-    eos_id = EOS
-
-    def __init__(self, vocab_size: int = 259):
-        self._vocab_size = max(int(vocab_size), 259)
-
-    @property
-    def vocab_size(self) -> int:
-        return self._vocab_size
-
-    def encode(self, text: str) -> List[int]:
-        return list(text.encode("utf-8"))
-
-    def decode(self, ids: List[int]) -> str:
-        return "".join(f"{i} " for i in ids)
-
-    def decode_token(self, token_id: int) -> str:
-        return f"{token_id} "
-
-
 class StreamDecoder:
     """Incremental detokenizer that never emits broken UTF-8 mid-codepoint.
 

@@ -76,11 +76,8 @@ class ModelConfig:
     # head_dim % 128 == 0).  Off by default: the einsum path is the oracle;
     # flip on once measured faster for the target config.
     flash_decode: bool = False
-    # With flash_decode: use the S-gridded variant (per-block DMA, frontier
-    # skips the fetch too, no view-size cap) instead of the full-plane one.
-    # As of ISSUE 4 both flags route to the s-grid family — the plane
-    # kernel's whole-view DMA is its documented weakness and it is kept
-    # only as an interpret-mode cross-check.
+    # The S-gridded variant (per-block DMA, frontier skips the fetch too,
+    # no view-size cap).  As of ISSUE 4 both flags route to it.
     flash_sgrid: bool = False
     # Fused decode-layer Pallas kernel (ISSUE 4): one program per layer
     # performs rope + new-row KV quantization + the cache append (in-place

@@ -21,8 +21,7 @@ the scale varies ALONG the contracted axis, dequant cannot run after the
 dot — instead unpack (two arithmetic shifts) + group-scale multiply feed
 the dot's operand directly, and XLA fuses them into the operand load the
 same way it fuses the int8 convert: the packed bytes are what crosses HBM,
-a bf16 copy never materializes (verify with scripts/perf_probe.py
-PP_QUANT=int4 — the int8 lesson, PERF.md r3/r4).
+a bf16 copy never materializes (the int8 lesson).
 
 Net-new vs the reference (no ML code there at all, SURVEY.md §2); sized by
 BASELINE.md's "Llama-3 8B on v5e-1" config.

@@ -1217,10 +1217,7 @@ def decode_step(
             return logits, new_cache, _moe_total(None)
         return logits, new_cache
 
-    # flash_decode / flash_sgrid both route to the S-GRID family now: the
-    # legacy plane kernel's whole-view DMA is its docstring'd weakness, so
-    # it is no longer reachable from the model layer (it survives as
-    # flash_decode_attention_plane for interpret-mode cross-checks).
+    # flash_decode / flash_sgrid both route to the S-GRID family.
     use_sgrid = branch == "pallas-sgrid"
     if use_sgrid:
         from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (

@@ -1,17 +1,8 @@
 """Pallas decode-attention kernels: one token per slot vs the KV cache.
 
-The decode analog of ops/pallas_attention.py (VERDICT r3 item 4).  THREE
+The decode analog of ops/pallas_attention.py (VERDICT r3 item 4).  TWO
 bodies share the online-softmax math:
 
-- ``flash_decode_attention_plane`` (legacy): each grid program owns one
-  (slot, kv-head) pair and stages that head's full [view, D] K/V planes,
-  skipping COMPUTE for K blocks past the slot's frontier but not their
-  HBM→VMEM DMA — callers must bound view (the model layer caps
-  view·head_dim at 1M elements ≈ 4 MB of K+V per program).  Kept ONLY as
-  an interpret-mode cross-check of the s-grid family; the public
-  ``flash_decode_attention`` entry routes to the s-grid kernel (ISSUE 4:
-  the plane kernel's whole-view DMA is a documented weakness).  The TPU
-  compiler refuses it (tests/test_tpu_compile.py, strict xfail).
 - ``flash_decode_attention_sgrid`` (r5, VERDICT r4 item 2): the sequence
   axis joins the grid — program (slot, s-block) stages ONE
   [BLOCK_S, K, D] block, all kv-heads, and loops over them (a block that
@@ -49,8 +40,8 @@ kernel launches per decode step matters at 32 layers × 16 steps per burst
 Reads the cache in its native [.., S, K, D] layout — no per-step
 transpose of a GB-scale cache.  The fused kernel's blocks span all
 kv-heads ([BLOCK_S, K, D]) so the trailing block dims match the array
-and the kernel cross-lowers for TPU from any host (the launch-count
-probe in scripts/perf_probe.py depends on that).
+and the kernel cross-lowers for TPU from any host
+(tests/test_tpu_compile.py compiles it for a described v5e).
 
 The einsum path remains the numerics oracle (tests/test_pallas_decode.py,
 tests/test_fused_decode_layer.py validate against it) and the fallback
@@ -68,8 +59,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-BLOCK_K = 128
 
 #: Tokens per byte along the packed int4 sequence axis — THE packing
 #: constant the page-alignment contract (ISSUE 14) is a multiple of.
@@ -131,60 +120,6 @@ def _pack_byte(lo, hi):
     return (jnp.left_shift(hi, 4) | (lo & 0x0F)).astype(jnp.int8)
 
 
-def _decode_kernel(
-    pos_ref,  # SMEM (1, 1) int32: this slot's query position
-    win_ref,  # SMEM (1, 1) int32: sliding window (S+1 = disabled)
-    q_ref,  # [G, D] this (slot, kv-head)'s query group
-    k_ref,  # [S, D] this (slot, kv-head)'s keys
-    v_ref,  # [S, D]
-    o_ref,  # [G, D]
-    *,
-    scale: float,
-    softcap: Optional[float],
-    seq_len: int,
-    out_dtype,
-):
-    g, d = q_ref.shape
-    pos = pos_ref[0, 0]
-    window = win_ref[0, 0]
-    q = q_ref[:].astype(jnp.float32) * scale
-
-    m0 = jnp.full((g, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    acc0 = jnp.zeros((g, d), jnp.float32)
-
-    def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[pl.ds(j * BLOCK_K, BLOCK_K), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * BLOCK_K, BLOCK_K), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [G, BK]
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = j * BLOCK_K + jax.lax.broadcasted_iota(
-            jnp.int32, (1, BLOCK_K), 1
-        )
-        mask = (k_pos <= pos) & ((pos - k_pos) < window)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        corr = jnp.where(m == _NEG_INF, 0.0, jnp.exp(m - m_new))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s == _NEG_INF, 0.0, p)
-        acc = acc * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        l = l * corr + p.sum(axis=-1, keepdims=True)
-        return m_new, l, acc
-
-    # Per-slot frontier: blocks wholly past this slot's position are skipped
-    # (inactive slots sit at pos 0 and read one block).
-    n_blocks = jnp.minimum(pos // BLOCK_K + 1, pl.cdiv(seq_len, BLOCK_K))
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(out_dtype)
-
-
 def flash_decode_attention(
     q: jnp.ndarray,  # [B, 1, H, D]
     k_cache: jnp.ndarray,  # [B, S, K, D]
@@ -192,82 +127,11 @@ def flash_decode_attention(
     q_positions: jnp.ndarray,  # [B] int32
     **kwargs,
 ) -> jnp.ndarray:
-    """Drop-in for ops.attention.cached_attention on TPU-tileable shapes.
-
-    Routed to the S-GRIDDED kernel (ISSUE 4 satellite): the legacy plane
-    body stages the slot's whole [view, D] K/V planes per program — a
-    docstring'd VMEM/DMA weakness — while the s-grid variant fetches one
-    block, skips past-frontier DMA, and has no view cap.  The plane body
-    survives as ``flash_decode_attention_plane`` strictly for
-    interpret-mode cross-checks of the shared online-softmax math.
-    """
+    """Drop-in for ops.attention.cached_attention on TPU-tileable shapes:
+    the S-GRIDDED kernel, which fetches one block a program, skips
+    past-frontier DMA, and has no view cap."""
     return flash_decode_attention_sgrid(q, k_cache, v_cache, q_positions,
                                         **kwargs)
-
-
-def flash_decode_attention_plane(
-    q: jnp.ndarray,  # [B, 1, H, D]
-    k_cache: jnp.ndarray,  # [B, S, K, D]
-    v_cache: jnp.ndarray,  # [B, S, K, D]
-    q_positions: jnp.ndarray,  # [B] int32
-    *,
-    scale: Optional[float] = None,
-    softcap: Optional[float] = None,
-    window=None,  # None | int | traced int scalar
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Legacy whole-plane variant — interpret-mode cross-check ONLY.
-
-    Requires S % 128 == 0 (the engine's kv-view buckets guarantee this).
-    ``window`` may be a traced scalar (gemma-2 alternates windows across
-    scanned layers), delivered through SMEM like the prefill kernel.
-    """
-    b, t, h, d = q.shape
-    assert t == 1, "decode step processes exactly one token per slot"
-    s = k_cache.shape[1]
-    kh = k_cache.shape[2]
-    g = h // kh
-    if scale is None:
-        scale = d**-0.5
-    if s % BLOCK_K != 0:
-        raise ValueError(f"decode kernel needs S % {BLOCK_K} == 0, got {s}")
-
-    pos = q_positions.astype(jnp.int32).reshape(b, 1)
-    win = jnp.asarray(s + 1 if window is None else window, jnp.int32).reshape(1, 1)
-    # [B, K, G, D]: program (b, k) takes the [G, D] query group of kv-head k.
-    q_g = q[:, 0].reshape(b, kh, g, d)
-
-    kernel = functools.partial(
-        _decode_kernel,
-        scale=scale,
-        softcap=softcap,
-        seq_len=s,
-        out_dtype=q.dtype,
-    )
-    grid = (b, kh)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
-        grid_spec=pl.GridSpec(
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1), lambda bi, ki: (bi, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1), lambda bi, ki: (0, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((None, None, g, d), lambda bi, ki: (bi, ki, 0, 0)),
-                # cache stays [B, S, K, D]: squeeze the kv-head axis in the
-                # block so each program streams a strided [S, D] plane.
-                pl.BlockSpec((None, s, None, d), lambda bi, ki: (bi, 0, ki, 0)),
-                pl.BlockSpec((None, s, None, d), lambda bi, ki: (bi, 0, ki, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (None, None, g, d), lambda bi, ki: (bi, ki, 0, 0)
-            ),
-        ),
-        interpret=interpret,
-    )(pos, win, q_g, k_cache, v_cache)
-    return out.reshape(b, 1, h, d)
 
 
 # ---------------------------------------------------------------------------

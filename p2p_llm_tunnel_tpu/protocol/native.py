@@ -12,27 +12,30 @@ import ctypes
 import os
 from typing import List, Optional, Tuple
 
-_LIB: Optional[ctypes.CDLL] = None
-_TRIED = False
-
-_LIB_PATH = os.path.join(
+_BUILD_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native", "build", "libtunnelframes.so",
+    "native", "build",
 )
 
 TF_OK = 0
 
 
-def _load() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
-    if _TRIED:
-        return _LIB
-    _TRIED = True
-    if not os.path.exists(_LIB_PATH):
+def open_library(name: str) -> Optional[ctypes.CDLL]:
+    """``native/build/<name>`` opened, or None where it is not built or does
+    not load (scripts/build-native.sh builds it).  The one way this package
+    finds a native library: transport/arq.py opens its core through it too."""
+    path = os.path.join(_BUILD_DIR, name)
+    if not os.path.exists(path):
         return None
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
+        return ctypes.CDLL(path)
     except OSError:
+        return None
+
+
+def _load() -> Optional[ctypes.CDLL]:
+    lib = open_library("libtunnelframes.so")
+    if lib is None:
         return None
     u8p = ctypes.POINTER(ctypes.c_uint8)
     lib.tf_encode_frame.restype = ctypes.c_int32
@@ -56,12 +59,15 @@ def _load() -> Optional[ctypes.CDLL]:
         ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint32),
         ctypes.c_uint32, ctypes.POINTER(ctypes.c_uint32),
     ]
-    _LIB = lib
     return lib
 
 
+# Loaded once, at import, as transport/arq.py loads its core.
+_LIB = _load()
+
+
 def available() -> bool:
-    return _load() is not None
+    return _LIB is not None
 
 
 def _buf(data: bytes):
@@ -71,7 +77,7 @@ def _buf(data: bytes):
 
 def encode_frame(msg_type: int, stream_id: int, payload: bytes) -> Optional[bytes]:
     """Native frame encode; None when the library is absent."""
-    lib = _load()
+    lib = _LIB
     if lib is None:
         return None
     cap = 5 + len(payload)
@@ -88,7 +94,7 @@ def decode_frame(data: bytes) -> Optional[Tuple[int, int, bytes]]:
 
     Raises ValueError with the native status code on malformed frames.
     """
-    lib = _load()
+    lib = _LIB
     if lib is None:
         return None
     mt = ctypes.c_uint8()
@@ -109,7 +115,7 @@ def chunk_body(
     Returns the list of raw frame bytes (no length prefix, ready for
     Channel.send), or None when the lib is absent.
     """
-    lib = _load()
+    lib = _LIB
     if lib is None:
         return None
     n_chunks = (len(body) + chunk_size - 1) // chunk_size if body else 0

@@ -5,12 +5,11 @@ process hosts the REAL serving path — tiny-model engine → EngineAPI →
 run_serve ⇄ loopback tunnel ⇄ run_proxy → HTTP listener — while
 ``scripts/loadgen.py`` hammers the listener from a separate process, so
 client-side parsing never shares an interpreter (or a GIL) with the stack
-under test.  This is the same topology bench.py builds in-process, minus
-the bench harness and plus a parseable readiness line:
+under test.  A parseable readiness line,
 
     LOADGEN_STACK_PORT=<port>
 
-printed on stdout once the engine is warm and the listener is accepting.
+is printed on stdout once the engine is warm and the listener is accepting.
 
 Usage (normally spawned by ``scripts/loadgen.py --spawn`` / ``make
 loadgen``):

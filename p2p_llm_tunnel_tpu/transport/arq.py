@@ -22,9 +22,10 @@ schedules must produce identical decisions from both implementations.
 from __future__ import annotations
 
 import ctypes
-import os
 from collections import deque
 from typing import Deque, List, Optional
+
+from p2p_llm_tunnel_tpu.protocol.native import open_library
 
 #: Shared constants (mirrored in native/tunnel_arq.cc; the oracle test
 #: would catch drift).
@@ -155,18 +156,9 @@ class PyArq:
         self._cwnd = self._ssthresh
 
 
-_LIB_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native", "build", "libtunnelarq.so",
-)
-
-
 def _load_lib():
-    if not os.path.exists(_LIB_PATH):
-        return None
-    try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    lib = open_library("libtunnelarq.so")
+    if lib is None:
         return None
     lib.arq_new.restype = ctypes.c_void_p
     lib.arq_new.argtypes = [ctypes.c_double]

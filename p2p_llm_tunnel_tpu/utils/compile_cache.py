@@ -1,10 +1,9 @@
 """The one place a process picks its persistent XLA compilation cache.
 
 Every process that compiles engine programs (``tunnel serve --backend tpu``,
-bench.py's serving child, scripts/perf_probe.py, chip_smoke.py's children)
-calls :func:`enable` before its first compile, so they all share one cache
-and a second start of the same configuration loads programs instead of
-compiling them.
+chip_smoke.py's children) calls :func:`enable` before its first compile, so
+they all share one cache and a second start of the same configuration loads
+programs instead of compiling them.
 
 Placement rule: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
 itself and no directory is set in code — the caller (a chip tool, a

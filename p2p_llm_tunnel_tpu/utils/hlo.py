@@ -21,8 +21,8 @@ Both are reported; the decode scans appear ONCE in the module (lax.scan
 lowers to ``stablehlo.while``), so per-layer-step numbers come from the
 innermost while body that contains a dot — the layer scan.
 
-Used by scripts/perf_probe.py (report), the engine's
-``engine_decode_kernels_per_step`` gauge, and the ISSUE 4 acceptance test
+Used by the engine's ``engine_decode_kernels_per_step`` gauge and the
+ISSUE 4 acceptance test
 (fused path ≥40% fewer major kernels per decode layer-step).
 """
 

@@ -2,8 +2,8 @@
 
 The reference has logging only — no counters, no /metrics (SURVEY.md §5).
 This framework exposes the BASELINE-graded quantities (tok/s, TTFT, queue
-depth, batch occupancy) as a tiny in-process registry that endpoints, the
-engine, and ``bench.py`` all share.
+depth, batch occupancy) as a tiny in-process registry that endpoints and
+the engine share.
 """
 
 from __future__ import annotations
@@ -17,7 +17,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 
 #: The one catalogue of legal metric names.  Every literal string handed to
 #: ``Metrics.inc``/``set_gauge``/``observe`` (and the read-side ``counter``/
-#: ``gauge``/``percentile``/``rate``, which /healthz and bench.py use) must
+#: ``gauge``/``percentile``/``rate``, which /healthz uses) must
 #: appear here — enforced statically by tunnelcheck rule TC06, so a typo'd
 #: name can't silently split a time series.  ``snapshot()`` derives
 #: ``<hist>_p50``/``_p95``/``_p99``/``_p999``/``_count`` suffixes from
@@ -101,7 +101,7 @@ METRICS_CATALOG: Dict[str, str] = {
     "engine_warmup_programs": (
         "distinct programs the warmup grid compiled/loaded before serving "
         "(gauge; the per-program breakdown lives in the CompileWatch "
-        "journal and the bench-smoke row)"
+        "journal)"
     ),
     "engine_warmup_compile_max_s": (
         "wall seconds of the single slowest warmup program compile "

@@ -5,18 +5,17 @@ Drives hundreds-to-1000 concurrent ``/v1/chat/completions`` SSE streams
 against a tunnel proxy from a SEPARATE process — client-side HTTP parsing
 must never share an interpreter with the server under test (the same
 reason the reference drives load from curl, scripts/test-tunnel.sh:88-96).
-Unlike scripts/bench_clients.py (bench.py's helper, which imports the
-package), this speaks raw HTTP/1.1 + chunked transfer over asyncio
-sockets, so it also runs against a deployed proxy with nothing installed.
+It speaks raw HTTP/1.1 + chunked transfer over asyncio sockets and
+imports nothing of the package, so it also runs against a deployed proxy
+with nothing installed.
 
 Per-tenant mixes model the hot-tenant-aggressor-vs-victim-herd scenario:
 each ``--tenant name:clients[:requests]`` spec contributes ``clients``
 concurrent clients issuing ``requests`` sequential generations tagged with
 ``x-tunnel-tenant: name`` (the explicit label, so server-side series and
 ``--tenant-weights`` match the spec names; ``x-api-key`` identities are
-fingerprinted server-side); the report aggregates the same p50/p99/p999
-TTFT/TTFB rows bench.py records, per tenant, plus ok/shed/error/stuck
-counts.
+fingerprinted server-side); the report aggregates p50/p99/p999 TTFT/TTFB
+rows per tenant, plus ok/shed/error/stuck counts.
 
 Usage:
     # against a running proxy

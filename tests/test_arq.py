@@ -11,22 +11,21 @@ import pytest
 from p2p_llm_tunnel_tpu.transport.arq import (
     CWND_INIT,
     CWND_MIN,
+    NativeArq,
     PyArq,
     RTO_MAX,
     RTO_MIN,
-    native_available,
 )
 
-if native_available():
-    from p2p_llm_tunnel_tpu.transport.arq import NativeArq
-
-    IMPLS = [PyArq, NativeArq]
-else:  # pragma: no cover - native lib always built in CI
-    IMPLS = [PyArq]
+# tests/conftest.py builds the native core before collection; where it could
+# not, ``native_libs`` fails these tests with the build's output.
+IMPLS = [PyArq, NativeArq]
 
 
 @pytest.fixture(params=IMPLS, ids=lambda c: c.__name__)
 def arq(request):
+    if request.param is NativeArq:
+        request.getfixturevalue("native_libs")
     return request.param(cwnd_cap=512.0)
 
 
@@ -104,9 +103,8 @@ def test_cwnd_floor_after_repeated_loss(arq):
 # the oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.skipif(not native_available(), reason="native ARQ not built")
 @pytest.mark.parametrize("seed", range(8))
-def test_native_matches_python_on_random_schedules(seed):
+def test_native_matches_python_on_random_schedules(seed, native_libs):
     rng = random.Random(seed)
     py, nat = PyArq(512.0), NativeArq(512.0)
     if rng.random() < 0.5:

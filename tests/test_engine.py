@@ -276,21 +276,6 @@ def test_stream_decoder_multibyte():
     assert out == text
 
 
-def test_numeric_tokenizer_renders_every_id():
-    from p2p_llm_tunnel_tpu.engine.tokenizer import NumericTokenizer, StreamDecoder
-
-    tok = NumericTokenizer(vocab_size=128256)
-    assert tok.vocab_size == 128256
-    assert tok.decode_token(0) == "0 "
-    assert tok.decode_token(128255) == "128255 "
-    # StreamDecoder must flush every push immediately (no pending buffering)
-    dec = StreamDecoder(tok)
-    assert dec.push(42) == "42 "
-    assert dec.push(99999) == "99999 "
-    # encoding stays byte-level so prompts are valid ids
-    assert all(i < 256 for i in tok.encode("hello"))
-
-
 def test_engine_crash_surfaces_instead_of_hanging():
     """A dispatch exception must fail in-flight consumers with an error and
     reject later submissions — never a silent 200 or a hung queue."""

@@ -1,29 +1,16 @@
 """Native C++ codec vs Python codec: byte-identical behavior.
 
-Builds the library on demand (g++ is in the image); the Python codec in
-protocol/frames.py is the oracle.
+tests/conftest.py builds the library before collection (g++ is in the image);
+where it could not, ``native_libs`` fails these tests with the build's output.
+The Python codec in protocol/frames.py is the oracle.
 """
-
-import subprocess
-from pathlib import Path
 
 import pytest
 
 from p2p_llm_tunnel_tpu.protocol import frames
 from p2p_llm_tunnel_tpu.protocol import native
 
-REPO = Path(__file__).resolve().parent.parent
-
-
-@pytest.fixture(scope="module", autouse=True)
-def built_lib():
-    lib = REPO / "native" / "build" / "libtunnelframes.so"
-    if not lib.exists():
-        subprocess.run([str(REPO / "scripts" / "build-native.sh")], check=True)
-    # force a (re)load attempt after build
-    native._TRIED = False
-    native._LIB = None
-    assert native.available(), "native library failed to load"
+pytestmark = pytest.mark.usefixtures("native_libs")
 
 
 @pytest.mark.parametrize("mtype,stream_id,payload", [

@@ -13,7 +13,6 @@ import pytest
 from p2p_llm_tunnel_tpu.ops.attention import cached_attention
 from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
     flash_decode_attention,
-    flash_decode_attention_plane,
     flash_decode_attention_sgrid,
 )
 
@@ -41,42 +40,6 @@ def test_matches_einsum_oracle(h, kh):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_positions_gate_attendable_prefix():
-    """Cache entries past a slot's position must not influence its output:
-    corrupt the tail of the cache and assert identical results."""
-    b, s, h, kh, d = 2, 256, 4, 2, 16
-    q, k, v = _mk(b, s, h, kh, d, seed=1)
-    pos = jnp.array([50, 130], jnp.int32)
-    base = flash_decode_attention_plane(q, k, v, pos, interpret=True)
-    k2 = k.at[:, 200:].set(1e6)
-    v2 = v.at[:, 200:].set(-1e6)
-    poisoned = flash_decode_attention_plane(q, k2, v2, pos, interpret=True)
-    np.testing.assert_allclose(np.asarray(base), np.asarray(poisoned))
-
-
-def test_sliding_window_matches_oracle():
-    b, s, h, kh, d = 2, 256, 4, 2, 16
-    q, k, v = _mk(b, s, h, kh, d, seed=2)
-    pos = jnp.array([180, 255], jnp.int32)
-    for window in (16, 64):
-        want = cached_attention(q, k, v, pos, window=window)
-        got = flash_decode_attention_plane(q, k, v, pos, window=window,
-                                     interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-
-
-def test_softcap_and_scale_match_oracle():
-    b, s, h, kh, d = 2, 128, 4, 2, 16
-    q, k, v = _mk(b, s, h, kh, d, seed=3)
-    pos = jnp.array([64, 127], jnp.int32)
-    want = cached_attention(q, k, v, pos, scale=0.25, softcap=30.0)
-    got = flash_decode_attention_plane(q, k, v, pos, scale=0.25, softcap=30.0,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
 def test_traced_window_scalar():
     """gemma-2 passes the window as a traced scalar from inside lax.scan."""
     b, s, h, kh, d = 1, 128, 2, 1, 16
@@ -84,7 +47,7 @@ def test_traced_window_scalar():
     pos = jnp.array([100], jnp.int32)
 
     def f(win):
-        return flash_decode_attention_plane(q, k, v, pos, window=win,
+        return flash_decode_attention(q, k, v, pos, window=win,
                                       interpret=True)
 
     got = jax.jit(f)(jnp.asarray(32))
@@ -110,29 +73,38 @@ def test_sgrid_matches_einsum_oracle(h, kh):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_sgrid_window_softcap_and_small_view():
-    b, s, h, kh, d = 2, 128, 4, 2, 16  # s < BLOCK_S: single-block grid
-    q, k, v = _mk(b, s, h, kh, d, seed=2)
-    pos = jnp.array([5, 127], jnp.int32)
-    for kw in (dict(window=32), dict(softcap=20.0), dict()):
+@pytest.mark.parametrize("s,seed,pos,kws", [
+    # s < BLOCK_S: single-block grid
+    (128, 2, [5, 127], (dict(window=32), dict(softcap=20.0), dict())),
+    (256, 2, [180, 255], (dict(window=16), dict(window=64))),
+    (128, 3, [64, 127], (dict(scale=0.25, softcap=30.0),)),
+], ids=["small_view", "sliding_window", "softcap_and_scale"])
+def test_sgrid_window_softcap_and_small_view(s, seed, pos, kws):
+    b, h, kh, d = 2, 4, 2, 16
+    q, k, v = _mk(b, s, h, kh, d, seed=seed)
+    pos = jnp.array(pos, jnp.int32)
+    for kw in kws:
         want = cached_attention(q, k, v, pos, **kw)
-        got = flash_decode_attention_sgrid(q, k, v, pos, interpret=True,
-                                           **kw)
+        got = flash_decode_attention(q, k, v, pos, interpret=True, **kw)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5, err_msg=str(kw))
 
 
-def test_sgrid_positions_gate_attendable_prefix():
+@pytest.mark.parametrize("s,seed,pos,poison_from", [
+    (256, 1, [50, 130], 200),
+    (512, 3, [50, 300], 301),
+], ids=["one_block", "two_blocks"])
+def test_sgrid_positions_gate_attendable_prefix(s, seed, pos, poison_from):
     """Frontier pruning must not change results: poison the cache past
     every slot's position (incl. blocks the index-map clamp never fetches)
     and assert identical output."""
-    b, s, h, kh, d = 2, 512, 4, 2, 16
-    q, k, v = _mk(b, s, h, kh, d, seed=3)
-    pos = jnp.array([50, 300], jnp.int32)
-    base = flash_decode_attention_sgrid(q, k, v, pos, interpret=True)
-    k2 = k.at[:, 301:].set(1e6)
-    v2 = v.at[:, 301:].set(-1e6)
-    poisoned = flash_decode_attention_sgrid(q, k2, v2, pos, interpret=True)
+    b, h, kh, d = 2, 4, 2, 16
+    q, k, v = _mk(b, s, h, kh, d, seed=seed)
+    pos = jnp.array(pos, jnp.int32)
+    base = flash_decode_attention(q, k, v, pos, interpret=True)
+    k2 = k.at[:, poison_from:].set(1e6)
+    v2 = v.at[:, poison_from:].set(-1e6)
+    poisoned = flash_decode_attention(q, k2, v2, pos, interpret=True)
     np.testing.assert_allclose(np.asarray(base), np.asarray(poisoned))
 
 
@@ -229,17 +201,11 @@ def test_full_model_decode_flash_parity():
 
 
 def test_public_entry_routes_to_sgrid():
-    """ISSUE 4 satellite: ``flash_decode_attention`` is the s-grid kernel
-    now — bit-identical output to calling the s-grid entry directly, and
-    the plane body (whole-view DMA, the docstring'd weakness) survives
-    only as ``flash_decode_attention_plane`` for cross-checks."""
+    """ISSUE 4 satellite: ``flash_decode_attention`` is the s-grid kernel:
+    bit-identical output to calling the s-grid entry directly."""
     b, s, h, kh, d = 2, 256, 4, 2, 16
     q, k, v = _mk(b, s, h, kh, d, seed=9)
     pos = jnp.array([7, 200], jnp.int32)
     routed = flash_decode_attention(q, k, v, pos, interpret=True)
     sgrid = flash_decode_attention_sgrid(q, k, v, pos, interpret=True)
     np.testing.assert_array_equal(np.asarray(routed), np.asarray(sgrid))
-    # ...and the plane cross-check still agrees with the shared math.
-    plane = flash_decode_attention_plane(q, k, v, pos, interpret=True)
-    np.testing.assert_allclose(np.asarray(plane), np.asarray(sgrid),
-                               rtol=2e-5, atol=2e-5)

@@ -28,7 +28,6 @@ from jax.sharding import SingleDeviceSharding
 
 from p2p_llm_tunnel_tpu.ops.pallas_attention import flash_causal_attention
 from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
-    flash_decode_attention_plane,
     flash_decode_attention_sgrid,
     fused_decode_layer,
     fused_spec_decode_layer,
@@ -178,27 +177,6 @@ def test_ragged_prefill_compiles_for_v5e(chip, kv):
         desc, desc, desc, desc, ((), jnp.int32),
     )
     assert n == 1
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="The Pallas TPU lowering currently requires that the last two "
-           "dimensions of your block shape are divisible by 8 and 128 "
-           "respectively, or be equal to the respective dimensions of the "
-           "overall array. Block spec for args[0] in pallas_call "
-           "_decode_kernel: the (1, 1) position block, and behind it the "
-           "K-squeezed cache planes.  No option reaches this kernel: it is "
-           "the interpret-mode cross-check of the s-grid family "
-           "(ROADMAP Design 2).",
-)
-def test_plane_decode_kernel_is_refused_by_the_tpu_compiler(chip):
-    k, v, _, _ = _cache_shapes(None, (ROWS,), 512)
-    _compile(
-        chip,
-        lambda q, k_, v_, pos: flash_decode_attention_plane(
-            q, k_, v_, pos, window=WINDOW),
-        ((ROWS, 1, H, D), jnp.bfloat16), k, v, ((ROWS,), jnp.int32),
-    )
 
 
 # ---------------------------------------------------------------------------

@@ -875,14 +875,14 @@ def test_file_waiver_and_disable_all(tmp_path):
 
 def test_self_run_shipped_tree_is_clean():
     """The repo must always pass its own checker (the `make lint` gate) —
-    including the repo-root entry points bench.py and __graft_entry__.py,
-    which read catalogued metrics and jit model functions respectively."""
+    including the repo-root entry points chip_smoke.py and
+    __graft_entry__.py, which jit the kernels and the model functions."""
     active, _ = run_paths(
         [
             REPO_ROOT / "p2p_llm_tunnel_tpu",
             REPO_ROOT / "scripts",
             REPO_ROOT / "tests",
-            REPO_ROOT / "bench.py",
+            REPO_ROOT / "chip_smoke.py",
             REPO_ROOT / "__graft_entry__.py",
         ]
     )
@@ -3876,7 +3876,7 @@ def test_waiver_audit_shipped_tree_has_no_stale_waivers():
     audit: list = []
     run_paths(
         [REPO_ROOT / "p2p_llm_tunnel_tpu", REPO_ROOT / "scripts",
-         REPO_ROOT / "tests", REPO_ROOT / "bench.py",
+         REPO_ROOT / "tests", REPO_ROOT / "chip_smoke.py",
          REPO_ROOT / "__graft_entry__.py"],
         waiver_audit=audit,
     )

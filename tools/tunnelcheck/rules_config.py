@@ -2,10 +2,10 @@
 
 The config-rot counterpart to TC06 (ISSUE 5): EngineConfig grows a field
 per feature, but a field nobody plumbs through the serve CLI is reachable
-only by programmatic embedders and the bench's env knobs — operators of
-the deployed binary simply cannot turn it on, and nothing fails.  That is
-exactly how ``decode_steps_eager`` and ``prefill_rows`` sat env/bench-only
-for four PRs while README documented them as serving levers.
+only by programmatic embedders — operators of the deployed binary simply
+cannot turn it on, and nothing fails.  That is exactly how
+``decode_steps_eager`` and ``prefill_rows`` sat env-only for four PRs
+while README documented them as serving levers.
 
 The rule fires on every dataclass field of a class named ``EngineConfig``
 that never appears as a KEYWORD in an ``EngineConfig(...)`` construction
