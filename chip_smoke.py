@@ -689,7 +689,14 @@ def kernels_child(rehearse: bool) -> None:
     for kv in (None, "int8", "int4"):
         cache = random_cache(kv)
         ulps = 16 if kv else 8
+        assert decode_attention_branch(ref_cfg, None, view, kv) == "einsum"
         ref_logits, _ = run_decode(ref_cfg, params, cache, toks, pos, kv_view=view)
+        if kv is None:
+            # the default read of the plain cache: no option selects it
+            assert decode_attention_branch(base, None, view) == "pallas-rows"
+            logits, _ = run_decode(base, params, cache, toks, pos, kv_view=view)
+            report(f"decode_attention_rows kv=bf16 view={view}",
+                   logits[:live], ref_logits[:live], ulps)
         for label, knobs in (
             ("flash_decode_attention_sgrid",
              dict(flash_decode=True, flash_sgrid=True)),

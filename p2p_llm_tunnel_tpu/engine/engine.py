@@ -17,7 +17,9 @@ import asyncio
 import concurrent.futures
 import contextlib
 import functools
+import importlib
 import os
+import threading
 import time
 from dataclasses import dataclass, replace as dc_replace
 from collections import deque
@@ -522,6 +524,15 @@ class InferenceEngine:
                 tp=self.ecfg.tp, dp=1, sp=self.ecfg.sp, ep=self.ecfg.ep
             )
         self.mesh = mesh
+        if self._decode_reads_rows():
+            # Tracing decode's kernel imports Pallas: 1.3 s of Python that
+            # the first decode program of the warm-up would wait for.
+            # Import it beside the parameters' initialisation instead.
+            threading.Thread(
+                target=importlib.import_module,
+                args=("p2p_llm_tunnel_tpu.ops.pallas_decode_attention",),
+                name="import-pallas", daemon=True,
+            ).start()
         placed = False  # params already carry their mesh shardings
         if params is None:
             if self.ecfg.ckpt_path:
@@ -1248,9 +1259,14 @@ class InferenceEngine:
     def resident_devices(self) -> List[int]:
         """Ids of the devices holding this engine's weights and KV cache.
         The cache is donated through every dispatch, so it sits where the
-        LAST program ran: one id per replica, the whole mesh under tp."""
+        LAST program ran: one id per replica, the whole mesh under tp.
+        Read from each array's sharding, which outlives a donation:
+        /healthz asks from the serve loop's thread while the engine's
+        thread is inside a dispatch, when ``kv_cache`` still names the
+        arrays just donated, and ``devices()`` of those raises."""
         leaves = jax.tree.leaves((self.params, self.kv_cache))
-        return sorted({d.id for leaf in leaves for d in leaf.devices()})
+        return sorted({d.id for leaf in leaves
+                       for d in leaf.sharding.device_set})
 
     def commit_to(self, device) -> None:
         """Commit every resident device array to ``device`` (data-parallel
@@ -1838,7 +1854,9 @@ class InferenceEngine:
         """The attention implementation program ``(kind, shape)`` traces —
         answered by the model layer's own gate predicates."""
         if kind == "decode":
-            return decode_attention_branch(self.mcfg, self.mesh, shape[0])
+            return decode_attention_branch(
+                self.mcfg, self.mesh, shape[0], self._kv_quant_mode(),
+                self.ecfg.max_seq)
         if kind == "spec":
             return spec_attention_branch(self.mcfg, self.mesh, shape[0])
         if kind in ("prefill", "prefill_echo"):
@@ -2020,8 +2038,12 @@ class InferenceEngine:
         steps = {self.ecfg.decode_steps}
         if 0 < self.ecfg.decode_steps_eager < self.ecfg.decode_steps:
             steps.add(self.ecfg.decode_steps_eager)
+        # Where decode's attention bounds its own reads (the rows kernel)
+        # the view axis is gone: one program a step count, at max_seq.
+        decode_views = ([self.ecfg.max_seq] if self._decode_reads_rows()
+                        else views)
         plan: List[Tuple[str, Tuple[int, ...]]] = [
-            ("decode", (v, k)) for v in views for k in sorted(steps)
+            ("decode", (v, k)) for v in decode_views for k in sorted(steps)
         ]
         if self.ecfg.spec_ngram > 0:
             # One fused verify program per (view, burst width): adaptive K
@@ -3198,6 +3220,21 @@ class InferenceEngine:
         self._start_host_copy(out)
         return out
 
+    def _kv_quant_mode(self) -> Optional[str]:
+        """The cache's precision as the model layer names it."""
+        mode = self.ecfg.kv_quant
+        return mode if mode in ("int8", "int4") else None
+
+    def _decode_reads_rows(self) -> bool:
+        """Whether decode's attention is the kernel that reads each row of
+        the stacked cache up to the row's own position
+        (decode_attention_branch's answer at max_seq): it has no use for a
+        view, so decode's view ladder is one entry and _dispatch_decode
+        passes that one.  Chunk and spec programs read by einsum and keep
+        their views."""
+        return self._attention_branch(
+            "decode", (self.ecfg.max_seq,)) == "pallas-rows"
+
     def _view_buckets(self) -> List[int]:
         """The full set of kv-view buckets this engine can ever dispatch:
         powers of two from 128 up, clamped to max_seq.  The ONLY bucket
@@ -3308,14 +3345,19 @@ class InferenceEngine:
         ov_mask = self._ov_mask | inactive
         park = self.ecfg.max_seq
         ov_pos = np.where(inactive, park, self._positions)
-        view = self._kv_view_bucket() if view is None else view
+        if view is None:
+            # The rows kernel reads the device-side positions: no bucket,
+            # and no pad for the carry's lead on the host's accounting.
+            view = (self.ecfg.max_seq if self._decode_reads_rows()
+                    else self._kv_view_bucket())
         steps = self._burst_steps() if steps is None else steps
         slots = self.ecfg.num_slots
         live = int(np.count_nonzero(active[:slots]))
+        attn = self._attention_branch("decode", (view, steps))
         # (warm-up's dummy bursts are no work of the loop's: not counted)
         rec = self._last_dispatch = self._open_dispatch(
             "engine.decode_burst", "decode", view=view, steps=steps,
-            live_rows=live, slots=slots,
+            live_rows=live, slots=slots, attn=attn,
         ) if global_tracer.enabled and not self._warming else None
         t_jit0 = time.monotonic()
         with rec.annotation() if rec else _NO_ANNOTATION:
@@ -3342,6 +3384,8 @@ class InferenceEngine:
         self._last_burst = (steps, live)
         if not self._warming:
             global_metrics.inc("engine_decode_steps_total", steps)
+            if attn != "einsum":
+                global_metrics.inc("engine_decode_kernel_steps_total", steps)
             global_metrics.inc("engine_decode_row_steps_total", live * steps)
             global_metrics.inc("engine_decode_slot_steps_total",
                                slots * steps)

@@ -57,7 +57,9 @@ class ModelConfig:
     # Use the Pallas flash kernel for prefill attention when the backend is
     # TPU and shapes tile (T%128==0, head_dim%128==0).  Under a tp mesh the
     # kernel runs per head-shard via shard_map (GSPMD does not
-    # auto-partition pallas_call).
+    # auto-partition pallas_call).  Likewise decode's default read of the
+    # plain bf16 cache, the rows kernel (transformer.decode_attention_branch).
+    # Off: the einsum everywhere, the reference the kernels are held against.
     flash: bool = True
     # Run the flash kernel in Pallas interpret mode even off-TPU — CPU-mesh
     # tests of the shard_map'd kernel path set this.

@@ -1,7 +1,10 @@
 """Pallas decode-attention kernels: one token per slot vs the KV cache.
 
-The decode analog of ops/pallas_attention.py (VERDICT r3 item 4).  TWO
-bodies share the online-softmax math:
+The decode analog of ops/pallas_attention.py (VERDICT r3 item 4).  The
+default read of the plain bf16 cache on the TPU is ``decode_attention_rows``
+(ISSUE 33, at the end of this file: one invocation a layer over the stacked
+cache, a software pipeline over each live row's blocks, no view).  Behind
+options, TWO older bodies share the online-softmax math:
 
 - ``flash_decode_attention_sgrid`` (r5, VERDICT r4 item 2): the sequence
   axis joins the grid — program (slot, s-block) stages ONE
@@ -1268,3 +1271,272 @@ def fused_spec_decode_layer(
                 ks5[..., 0], vs5[..., 0])
     attn, kc, vc = outs
     return attn.reshape(b, t_burst, h, d), kc, vc, None, None
+
+
+# ---------------------------------------------------------------------------
+# Rows over the stacked cache (ISSUE 33): the default decode read
+# ---------------------------------------------------------------------------
+
+#: The smallest block of cache positions the rows kernel reads at a time:
+#: the decline gate's ``max_seq % 128``.
+ROWS_BLOCK = 128
+#: Cache rows (positions x kv-heads) a block holds where the sequence
+#: allows: under about a thousand the kernel's cost an item shows (4 KV
+#: heads at 128 positions ran at 0.40 us an item where the DMA needs 0.32,
+#: at 256 positions at the DMA's 0.64; PERF.md section 6, PR 33), above it
+#: a row reads further past its own position.
+ROWS_COLS = 1024
+#: Blocks in flight: the ring holds this many key and value blocks.
+ROWS_DEPTH = 4
+#: A row's last block is fetched as far as its position, in this many
+#: parts (on the chip, a layer of mistral's cell mix: 64.7 us whole, 59.6 in
+#: halves, 59.3 in quarters, 67.9 in eighths; PERF.md section 6, PR 33).
+ROWS_PARTS = 2
+
+#: The kernel's name in compiled programs and device traces.
+ROWS_KERNEL = "decode_attn_rows"
+
+
+def rows_block(seq: int, kv_heads: int) -> int:
+    """Cache positions one item of the rows kernel covers, from the cache's
+    own shape: ``ROWS_COLS`` rows of ``[K, D]``, in whole ``ROWS_BLOCK``s
+    that divide the sequence."""
+    bs = max(ROWS_COLS // kv_heads // ROWS_BLOCK, 1) * ROWS_BLOCK
+    while seq % bs:
+        bs -= ROWS_BLOCK
+    return bs
+
+
+def decode_rows_worklist(positions: jnp.ndarray, seq: int,
+                         block: int) -> jnp.ndarray:
+    """The (row, block) pairs one decode step's attention reads, in row
+    order: ``[1 + N + B] int32`` with ``N = B * seq // block``: the count
+    first, then ``row << 16 | block index`` for every block of ``block``
+    positions that holds a position ``<=`` the row's own (a row parked at
+    ``positions >= seq`` has none), then the positions themselves — all the
+    kernel's scalars that a step's layers share, in the one array that one
+    copy brings in.  ``decode_step`` makes it once, outside the layer
+    scan."""
+    b = positions.shape[0]
+    n_sb = seq // block
+    pos = positions.astype(jnp.int32)
+    nblk = jnp.where(pos < seq, pos // block + 1, 0)
+    ends = jnp.cumsum(nblk)
+    w = jnp.arange(b * n_sb, dtype=jnp.int32)
+    # (compared against every row's end at once: the default search is a
+    # loop of its own on the device, once a decode step)
+    row = jnp.minimum(
+        jnp.searchsorted(ends, w, side="right", method="compare_all")
+        .astype(jnp.int32), b - 1)
+    blk = w - (ends - nblk)[row]
+    return jnp.concatenate([ends[-1:], row << 16 | blk, pos])
+
+
+def _decode_rows_kernel(
+    layer_sref,  # scalar-prefetch [2] int32: layer index into the [L,...]
+    #              cache, sliding window (S+1 = disabled)
+    work_sref,   # scalar-prefetch [1 + N + B] int32: decode_rows_worklist
+    q_ref,      # [B, H, D] every row's query heads (head = kv_head * G + g)
+    k_hbm,      # [L, B, S*K, D] the stacked cache where it lies (HBM)
+    v_hbm,
+    o_ref,      # [B, H, D]
+    kbuf,       # [DEPTH, BS*K, D] ring of key blocks
+    vbuf,
+    sem,        # DMA semaphores [2, DEPTH]
+    *,
+    scale: float,
+    softcap: Optional[float],
+    block_s: int,
+    depth: int,
+    parts: int,
+    kv_heads: int,
+):
+    """One invocation a layer: a software pipeline over the step's work
+    list.  Each item is one ``[BS*K, D]`` block of one row — positions
+    outermost, kv-heads inside, as the cache lies — and is scored against
+    ALL of the row's query heads in one product; the mask takes the other
+    kv-heads' columns out again, so the value product over the same flat
+    block sums only a head's own.  The MXU's cost is set by the block
+    (each key and value tile passes through once), not by the 4 or 7 query
+    rows a kv-head has, so the wasted columns cost nothing and the block
+    needs no de-interleaving."""
+    layer = layer_sref[0]
+    window = layer_sref[1]
+    n_work = work_sref[0]
+    rows_per_blk = kbuf.shape[1]
+    b, h, d = q_ref.shape
+    pos_at = work_sref.shape[0] - b
+
+    def whole_div(x, n):
+        # x // n of a small non-negative int32 vector, by way of float32
+        # (exact: x + 0.5 is never within 2^-12 of a multiple of n here)
+        return ((x.astype(jnp.float32) + 0.5) * (1.0 / n)).astype(jnp.int32)
+
+    # A block column's position inside the block and its kv-head; a query
+    # head's kv-head.
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, rows_per_blk), 1)
+    col_off = whole_div(col, kv_heads)
+    col_head = col - col_off * kv_heads
+    row_head = whole_div(
+        jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0), h // kv_heads)
+
+    def item(w):
+        """Work item ``w``: its row, its block's index, the row's position."""
+        packed = work_sref[1 + w]
+        row = packed >> 16
+        return row, packed & 0xFFFF, work_sref[pos_at + row]
+
+    def copies(w, slot, then):
+        """``then`` each of item ``w``'s two copies into ``slot``.  A row's
+        last block is brought in only as far as the part (one of ``parts``)
+        that holds the row's position: the rest of the buffer keeps what an
+        earlier item left there, which the mask takes out (the value ring
+        is zeroed once below, so what it takes out is finite)."""
+        row, blk, pos = item(w)
+        start = pl.multiple_of(blk * rows_per_blk, rows_per_blk)
+        part = rows_per_blk // parts
+        needed = jnp.where(blk == pos // block_s,
+                           (pos % block_s) * kv_heads // part + 1, parts)
+        for n in range(1, parts + 1):
+            @pl.when(needed == n)
+            def _(n=n):
+                for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                    then(pltpu.make_async_copy(
+                        hbm.at[layer, row, pl.ds(start, n * part)],
+                        buf.at[slot, pl.ds(0, n * part)],
+                        sem.at[which, slot]))
+
+    def start(w):
+        @pl.when(w < n_work)
+        def _():
+            copies(w, w % depth, lambda c: c.start())
+
+    # A parked row is in no item: its output is zeros, not what the buffer
+    # held.
+    o_ref[...] = jnp.zeros_like(o_ref)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    for w0 in range(depth - 1):
+        start(w0)
+
+    def body(w, carry):
+        m_prev, l_prev, acc = carry
+        slot = w % depth
+        row, blk, pos = item(w)
+        copies(w, slot, lambda c: c.wait())
+        # The slot the previous item has just left takes the item DEPTH - 1
+        # ahead.
+        start(w + depth - 1)
+
+        first = blk == 0
+        m_prev = jnp.where(first, _NEG_INF, m_prev)
+        l_prev = jnp.where(first, 0.0, l_prev)
+        acc = jnp.where(first, 0.0, acc)
+
+        q = q_ref[row]  # [H, D]
+        k = kbuf[slot]  # [BS*K, D]
+        v = vbuf[slot]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [H, BS*K]
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        k_pos = blk * block_s + col_off  # [1, BS*K]
+        live = (k_pos <= pos) & ((pos - k_pos) < window)
+        s = jnp.where(live & (col_head == row_head), s, _NEG_INF)
+
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        corr = jnp.where(m_prev <= _NEG_INF, 0.0, jnp.exp(m_prev - m_new))
+        p = jnp.where(s <= _NEG_INF, 0.0, jnp.exp(s - m_new))
+        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+        if v.dtype == jnp.float32:
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            # The weights in two bf16 halves, stacked on the rows: 2H rows
+            # cost the MXU what H do, and the sum is the float32 weight's
+            # product to 2^-16.
+            hi = p.astype(v.dtype)
+            lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+            both = jax.lax.dot_general(
+                jnp.concatenate([hi, lo], axis=0), v,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            pv = both[:h] + both[h:]
+        acc = acc * corr + pv
+
+        @pl.when(blk == pos // block_s)
+        def _emit():
+            o_ref[row] = (acc / jnp.maximum(l_new, 1e-30)).astype(o_ref.dtype)
+
+        return m_new, l_new, acc
+
+    jax.lax.fori_loop(0, n_work, body, (
+        jnp.full((h, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((h, 1), jnp.float32),
+        jnp.zeros((h, d), jnp.float32),
+    ))
+
+
+def decode_attention_rows(
+    q: jnp.ndarray,        # [B, H, D]
+    k_cache: jnp.ndarray,  # [L, B, S, K, D] the stacked cache, row written
+    v_cache: jnp.ndarray,
+    layer_idx,               # int32 scalar (traced: the scan's layer index)
+    work: jnp.ndarray,       # decode_rows_worklist(positions, S, block)
+    *,
+    block: int,  # static: rows_block(S, K), the work list's
+    scale: Optional[float] = None,
+    softcap: Optional[float] = None,
+    window=None,  # None | int | traced int scalar
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``cached_attention`` over layer ``layer_idx`` of the stacked cache,
+    read where it lies: no plane is sliced out, a row's blocks past its
+    position are neither fetched nor computed, and a row parked at a
+    position ``>= S`` does no work (its output is zeros).  The cache
+    already holds this step's own row at the positions the work list was
+    made from.  Same mathematics as the einsum: the cache's own operands
+    into float32 scores, float32 softmax and accumulation.  A window masks;
+    it does not yet bound the blocks fetched from below.  Returns
+    ``[B, H, D]``."""
+    l, b, s, kh, d = k_cache.shape
+    h = q.shape[1]
+    if s % ROWS_BLOCK or s % block:
+        raise ValueError(f"rows decode kernel needs S % {ROWS_BLOCK} == 0 "
+                         f"and whole blocks of {block}, got {s}")
+    if scale is None:
+        scale = d**-0.5
+    bs = block
+    cols = bs * kh
+    layer = jnp.stack([
+        jnp.asarray(layer_idx, jnp.int32),
+        jnp.asarray(s + 1 if window is None else window, jnp.int32),
+    ])
+
+    kernel = functools.partial(
+        _decode_rows_kernel,
+        scale=scale, softcap=softcap, block_s=bs, depth=ROWS_DEPTH,
+        parts=ROWS_PARTS, kv_heads=kh,
+    )
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[vmem, hbm, hbm],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((ROWS_DEPTH, cols, d), k_cache.dtype),
+                pltpu.VMEM((ROWS_DEPTH, cols, d), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, ROWS_DEPTH)),
+            ],
+        ),
+        interpret=interpret,
+        name=ROWS_KERNEL,
+    )(layer, work, q,
+      k_cache.reshape(l, b, s * kh, d), v_cache.reshape(l, b, s * kh, d))

@@ -103,6 +103,11 @@ class UdpChannel(Channel):
         self._unacked: Dict[int, bytes] = {}  # seq → sealed packet
         self._window_free = asyncio.Event()
         self._window_free.set()
+        # One message's fragments take consecutive sequence numbers: a
+        # sender that waits for the window mid-message must not be passed
+        # by another task's send (the receiver joins fragments up to the
+        # next fin, so a passing message is spliced into the waiting one).
+        self._send_lock = asyncio.Lock()
 
         # receiver state
         self._recv_next = 0
@@ -304,24 +309,25 @@ class UdpChannel(Channel):
     async def _send_impl(self, data: bytes) -> None:
         if not self._established.is_set():
             await self._established.wait()
-        if self.is_closed:
-            raise ChannelClosed("udp channel closed")
         # fragment into MTU payloads; fin marks the message boundary
         offsets = range(0, len(data), MTU_PAYLOAD) if data else [0]
         frags = [data[o : o + MTU_PAYLOAD] for o in offsets]
-        for i, frag in enumerate(frags):
-            while not self._arq.can_send():
-                self._window_free.clear()
-                await self._window_free.wait()
-                if self.is_closed:
-                    raise ChannelClosed("udp channel closed")
-            seq = self._next_seq
-            self._next_seq = (self._next_seq + 1) & 0xFFFFFFFF
-            fin = 1 if i == len(frags) - 1 else 0
-            pkt = _DATA_HDR.pack(PT_DATA, seq, fin) + frag
-            self._unacked[seq] = pkt
-            self._arq.on_send(seq, time.monotonic())
-            self._send_raw(pkt, self._peer_addr)
+        async with self._send_lock:
+            if self.is_closed:  # closed while this send waited its turn
+                raise ChannelClosed("udp channel closed")
+            for i, frag in enumerate(frags):
+                while not self._arq.can_send():
+                    self._window_free.clear()
+                    await self._window_free.wait()
+                    if self.is_closed:
+                        raise ChannelClosed("udp channel closed")
+                seq = self._next_seq
+                self._next_seq = (self._next_seq + 1) & 0xFFFFFFFF
+                fin = 1 if i == len(frags) - 1 else 0
+                pkt = _DATA_HDR.pack(PT_DATA, seq, fin) + frag
+                self._unacked[seq] = pkt
+                self._arq.on_send(seq, time.monotonic())
+                self._send_raw(pkt, self._peer_addr)
 
     # -- receiving ---------------------------------------------------------
 

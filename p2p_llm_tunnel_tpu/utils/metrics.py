@@ -32,6 +32,11 @@ METRICS_CATALOG: Dict[str, str] = {
         "positions is the prefill fill --prefill-rows is sized by (counter)"
     ),
     "engine_decode_steps_total": "decode steps dispatched (counter)",
+    "engine_decode_kernel_steps_total": (
+        "of those, the steps whose attention ran as a Pallas kernel (the "
+        "record's attn is not einsum); over engine_decode_steps_total it is "
+        "the share of decode the kernel engages in (counter)"
+    ),
     "engine_decode_row_steps_total": (
         "live rows x steps over every decode burst dispatched (counter)"
     ),

@@ -124,7 +124,9 @@ SPAN_CATALOG: Dict[str, str] = {
     "engine.decode_burst": (
         "one multi-step decode burst: dispatch -> fetched block processed "
         "(engine-scope dispatch record; overlaps its successor via "
-        "pipelining; attrs seq, program, view, steps, live_rows, slots)"
+        "pipelining; attrs seq, program, view, steps, live_rows, slots, "
+        "attn = the attention branch the program ran: einsum or a kernel's "
+        "name; under pallas-rows view is max_seq in every record)"
     ),
     "engine.pool_copy": (
         "one batched prefix-pool copy dispatch, cache_to_pool or "

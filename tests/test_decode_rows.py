@@ -1,0 +1,379 @@
+"""The rows decode kernel (ISSUE 33) against the einsum oracle, and the plan
+that follows it.
+
+``decode_attention_rows`` reads layer ``idx`` of the STACKED cache where it
+lies, each row up to its own position; ``cached_attention`` on that layer's
+plane is the ground truth.  Interpret mode on the CPU (the kernel compiled
+for a described v5e: tests/test_tpu_compile.py).  Where it runs, decode's
+view ladder is one entry.
+"""
+
+import asyncio
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from p2p_llm_tunnel_tpu.engine.engine import (
+    EngineConfig,
+    InferenceEngine,
+    _program_key,
+)
+from p2p_llm_tunnel_tpu.engine.tokenizer import ByteTokenizer
+from p2p_llm_tunnel_tpu.models.config import get_config
+from p2p_llm_tunnel_tpu.models.transformer import (
+    decode_attention_branch,
+    decode_step,
+    init_kv_cache,
+    init_params,
+)
+from p2p_llm_tunnel_tpu.ops.attention import cached_attention
+from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+    ROWS_BLOCK,
+    decode_attention_rows,
+    decode_rows_worklist,
+    rows_block,
+)
+from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
+
+S = 512
+#: position 0, a block's last and the next block's first, S - 1, a row
+#: parked at S and one beyond, and ragged ones between
+POSITIONS = [0, ROWS_BLOCK - 1, ROWS_BLOCK, S - 1, S, S + 7, 300, 41]
+
+
+def _mk(h, kh, d, dtype, layers=3, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    b = len(POSITIONS)
+    q = jax.random.normal(ks[0], (b, h, d), dtype)
+    k = jax.random.normal(ks[1], (layers, b, S, kh, d), dtype)
+    v = jax.random.normal(ks[2], (layers, b, S, kh, d), dtype)
+    return q, k, v, jnp.asarray(POSITIONS, jnp.int32)
+
+
+def _both(q, k, v, pos, layer, block=ROWS_BLOCK, **kw):
+    got = decode_attention_rows(
+        q, k, v, jnp.int32(layer), decode_rows_worklist(pos, S, block),
+        block=block, interpret=True, **kw)
+    want = cached_attention(q[:, None], k[layer], v[layer], pos, **kw)[:, 0]
+    return np.asarray(got, np.float32), np.asarray(want, np.float32)
+
+
+def test_worklist_is_each_live_rows_blocks_in_row_order():
+    for block in (ROWS_BLOCK, 2 * ROWS_BLOCK):
+        work = np.asarray(decode_rows_worklist(
+            jnp.asarray(POSITIONS, jnp.int32), S, block))
+        assert work.shape == (1 + len(POSITIONS) * S // block
+                              + len(POSITIONS),)
+        assert list(work[-len(POSITIONS):]) == POSITIONS
+        items = [(int(w) >> 16, int(w) & 0xFFFF)
+                 for w in work[1:1 + work[0]]]
+        want = [(row, blk) for row, p in enumerate(POSITIONS) if p < S
+                for blk in range(p // block + 1)]
+        assert items == want
+        assert all(row not in (4, 5) for row, _ in items)  # parked: no work
+
+
+@pytest.mark.parametrize("seq,kv_heads,want", [
+    (1024, 8, 128), (1024, 4, 256), (1024, 16, 128), (1024, 2, 512),
+    (1024, 1, 1024), (384, 4, 128), (768, 4, 256), (4096, 8, 128),
+])
+def test_a_block_is_about_a_thousand_cache_rows_in_whole_blocks(
+        seq, kv_heads, want):
+    assert rows_block(seq, kv_heads) == want
+
+
+@pytest.mark.parametrize("h,kh", [(32, 8), (28, 4), (4, 4), (8, 1)])
+def test_rows_match_the_einsum_on_the_stacked_cache(h, kh):
+    """Both GQA ratios of the cells (32:8, 28:4), plain MHA and MQA, over
+    ragged positions with every edge; the layer read is the one asked."""
+    q, k, v, pos = _mk(h, kh, 32, jnp.float32)
+    live = np.asarray(POSITIONS) < S
+    for layer, block in ((0, ROWS_BLOCK), (2, rows_block(S, kh))):
+        got, want = _both(q, k, v, pos, layer, block)
+        np.testing.assert_allclose(got[live], want[live],
+                                   rtol=2e-5, atol=2e-5)
+        assert not got[~live].any()  # a parked row does no work
+
+
+@pytest.mark.parametrize("h,kh", [(32, 8), (28, 4)])
+def test_rows_match_the_einsum_in_bfloat16(h, kh):
+    """The cells' own precision: bf16 operands, float32 scores and sums."""
+    q, k, v, pos = _mk(h, kh, 128, jnp.bfloat16, layers=2)
+    live = np.asarray(POSITIONS) < S
+    got, want = _both(q, k, v, pos, 1)
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(window=64), dict(window=200), dict(window=S + 1),
+    dict(softcap=20.0), dict(scale=0.25, softcap=30.0, window=130),
+])
+def test_rows_take_window_scale_and_softcap(kw):
+    q, k, v, pos = _mk(8, 2, 32, jnp.float32, seed=3)
+    live = np.asarray(POSITIONS) < S
+    got, want = _both(q, k, v, pos, 1, **kw)
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+
+
+def test_rows_take_a_traced_window_and_layer_inside_a_scan():
+    """gemma-2's alternating layers hand the window, and the layer scan the
+    index, as traced scalars."""
+    q, k, v, pos = _mk(8, 2, 32, jnp.float32, seed=5)
+    work = decode_rows_worklist(pos, S, ROWS_BLOCK)
+    wins = jnp.asarray([64, S + 1, 64], jnp.int32)
+
+    def body(_, xs):
+        idx, win = xs
+        return None, decode_attention_rows(
+            q, k, v, idx, work, block=ROWS_BLOCK, window=win,
+            interpret=True)
+
+    _, got = jax.lax.scan(body, None, (jnp.arange(3), wins))
+    live = np.asarray(POSITIONS) < S
+    for layer in range(3):
+        want = cached_attention(q[:, None], k[layer], v[layer], pos,
+                                window=int(wins[layer]))[:, 0]
+        np.testing.assert_allclose(np.asarray(got[layer])[live],
+                                   np.asarray(want)[live],
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_rows_refuse_a_sequence_that_does_not_tile():
+    q = jnp.zeros((1, 2, 16))
+    k = jnp.zeros((1, 1, 100, 1, 16))
+    with pytest.raises(ValueError, match="S %"):
+        decode_attention_rows(q, k, k, jnp.int32(0),
+                              jnp.zeros((3,), jnp.int32),
+                              block=ROWS_BLOCK, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# the branch: the default where it can run, by what the code can observe
+# ---------------------------------------------------------------------------
+
+TINY = get_config("tiny", vocab_size=ByteTokenizer().vocab_size)
+INTERP = replace(TINY, flash_interpret=True)
+INTERP_F32 = replace(get_config("tiny"), flash_interpret=True)
+
+
+@pytest.mark.parametrize("cfg,view,kv,want", [
+    (INTERP, 256, None, "pallas-rows"),
+    (INTERP, 256, "int8", "einsum"),       # a quantised cache keeps the einsum
+    (INTERP, 256, "int4", "einsum"),
+    (INTERP, 192, None, "einsum"),         # does not tile by 128
+    (TINY, 256, None, "einsum"),           # a CPU backend, not interpreting
+    (replace(INTERP, flash=False), 256, None, "einsum"),  # the reference
+    (replace(INTERP, flash_sgrid=True), 256, None, "pallas-sgrid"),
+    (replace(INTERP, fused_decode_layer=True), 256, None,
+     "pallas-fused-decode-layer"),
+    (replace(get_config("tiny-mla-moe"), flash_interpret=True), 256, None,
+     "einsum"),                            # the latent family
+])
+def test_the_branch_is_decided_by_what_the_code_observes(cfg, view, kv, want):
+    assert decode_attention_branch(cfg, None, view, kv) == want
+
+
+@pytest.mark.parametrize("view,max_seq,want", [
+    (128, 512, "pallas-rows"),  # the kernel reads the cache, not the view
+    (128, 320, "einsum"),       # a rung tiles, the cache does not
+    (256, 320, "einsum"),
+    (320, 320, "einsum"),
+])
+def test_the_rows_branch_is_decided_on_the_cache_not_the_view(
+        view, max_seq, want):
+    assert decode_attention_branch(INTERP, None, view, None, max_seq) == want
+
+
+def test_a_cache_that_does_not_tile_keeps_the_einsum_at_every_rung():
+    """An engine whose ``max_seq`` is no multiple of 128 still dispatches
+    the rungs 128 and 256 of its view ladder: ``decode_step`` there is the
+    einsum's, to the bit, whatever the backend would let a kernel do."""
+    cfg = get_config("tiny")
+    params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    toks = jnp.asarray([3, 5, 7, 11], jnp.int32)
+    pos = jnp.asarray([0, 100, 126, 320], jnp.int32)
+    got, want = (
+        decode_step(c, params, init_kv_cache(cfg, 4, 320, jnp.float32),
+                    toks, pos, kv_view=128)[0]
+        for c in (INTERP_F32, cfg))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_tp_mesh_declines_the_rows_kernel(cpu_devices):
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.asarray(cpu_devices[:2]).reshape(1, 2), ("dp", "tp"))
+    assert decode_attention_branch(INTERP, mesh, 256) == "einsum"
+    mesh = Mesh(np.asarray(cpu_devices[:2]).reshape(2, 1), ("dp", "tp"))
+    assert decode_attention_branch(INTERP, mesh, 256) == "pallas-rows"
+
+
+def test_decode_step_on_the_rows_kernel_agrees_with_the_einsum():
+    """Whole ``decode_step``, float32, over steps that cross a block edge;
+    gemma's alternating windows and soft cap ride the same kernel."""
+    for name in ("tiny", "tiny-gemma"):
+        cfg = get_config(name)
+        params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+        toks = jnp.asarray([3, 5, 7, 11], jnp.int32)
+        pos = jnp.asarray([0, 126, 255, 256], jnp.int32)  # the last: parked
+        outs = {}
+        for label, c in (("einsum", cfg),
+                         ("rows", replace(cfg, flash_interpret=True))):
+            cache = init_kv_cache(cfg, 4, 256, jnp.float32)
+            t, p, seq = toks, pos, []
+            for _ in range(3):
+                logits, cache = decode_step(c, params, cache, t, p,
+                                            kv_view=256)
+                seq.append(np.asarray(logits))
+                t = jnp.argmax(logits, -1).astype(jnp.int32)
+                p = p + 1
+            outs[label] = (np.stack(seq), np.asarray(cache["k"]))
+        np.testing.assert_allclose(outs["rows"][0][:, :2],
+                                   outs["einsum"][0][:, :2],
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(outs["rows"][1][:, :2],
+                                   outs["einsum"][1][:, :2],
+                                   rtol=2e-4, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the plan: one decode program a step count where the kernel runs
+# ---------------------------------------------------------------------------
+
+ECFG = dict(
+    model="tiny", num_slots=4, max_seq=512, dtype="float32", seed=0,
+    decode_steps=4, decode_steps_eager=2, prefill_rows=2, prefill_chunk=16,
+    prefix_cache=True, mux=True,
+)
+
+
+def _engine(mcfg=None, **over):
+    return InferenceEngine(model_cfg=mcfg,
+                           engine_cfg=EngineConfig(**{**ECFG, **over}),
+                           tokenizer=ByteTokenizer())
+
+
+def _decode_entries(eng):
+    return [shape for kind, shape in eng.warmup_plan() if kind == "decode"]
+
+
+def test_the_plan_holds_one_decode_entry_a_step_count_on_the_kernel_path():
+    eng = _engine(INTERP)
+    assert eng._decode_reads_rows()
+    assert _decode_entries(eng) == [(512, 2), (512, 4)]
+    # chunk programs read by einsum and keep their views
+    views = {shape[2] for kind, shape in eng.warmup_plan() if kind == "chunk"}
+    assert views == {128, 256, 512}
+
+
+def test_the_einsum_paths_plan_is_unchanged():
+    want = [(v, k) for v in (128, 256, 512) for k in (2, 4)]
+    assert _decode_entries(_engine()) == want  # a CPU backend
+    assert _decode_entries(_engine(INTERP, kv_quant="int8")) == want
+    eng = _engine(INTERP, max_seq=320)  # 320 does not tile by 128
+    assert not eng._decode_reads_rows()
+    assert _decode_entries(eng) == [(v, k) for v in (128, 256, 320)
+                                    for k in (2, 4)]
+    rest = [e for e in _engine(INTERP).warmup_plan() if e[0] != "decode"]
+    assert rest == [e for e in _engine().warmup_plan() if e[0] != "decode"]
+
+
+def test_spec_programs_keep_their_views_beside_the_rows_kernel():
+    eng = _engine(INTERP, spec_ngram=3, spec_k=2)
+    assert _decode_entries(eng) == [(512, 2), (512, 4)]
+    assert {s[0] for kind, s in eng.warmup_plan() if kind == "spec"} == \
+        {128, 256, 512}
+
+
+@pytest.fixture(scope="module")
+def kernel_run():
+    """A run on the kernel path whose rows cross the old bucket edges at 128
+    and 256: what was dispatched, the records, the counters' growth, and the
+    same prompts' tokens on the einsum path."""
+    from p2p_llm_tunnel_tpu.utils.tracing import global_tracer
+
+    names = ("engine_cold_compiles_total", "engine_decode_steps_total",
+             "engine_decode_kernel_steps_total")
+    prompts = [list(range(1, 101)), list(range(5, 125)),
+               [7 + i % 50 for i in range(230)], [9, 8, 7]]
+
+    async def collect(eng, ids):
+        return [ev.token_id async for ev in eng.generate(
+            ids, max_new_tokens=40, stop_ids=())]
+
+    async def run(mcfg, trace):
+        eng = _engine(mcfg)
+        await eng.start()
+        await eng.warmup()
+        planned = {_program_key(kind, shape)
+                   for kind, shape in eng.warmup_plan()}
+        global_tracer.clear()
+        global_tracer.configure(enabled=trace, sample=1.0, capacity=65536)
+        before = {n: global_metrics.counter(n) for n in names}
+        try:
+            toks = await asyncio.gather(*(collect(eng, p) for p in prompts))
+            await asyncio.sleep(0.3)
+            grown = {n: global_metrics.counter(n) - before[n] for n in names}
+            records = [r for r in global_tracer.records()
+                       if r.name == "engine.decode_burst"]
+        finally:
+            global_tracer.configure(enabled=False)
+            global_tracer.clear()
+            ready = set(eng._programs_ready)
+            branches = dict(eng.attention_branches)
+            await eng.stop()
+        return toks, grown, records, ready, planned, branches
+
+    kernel = asyncio.run(run(INTERP, True))
+    einsum = asyncio.run(run(None, True))
+    return kernel, einsum
+
+
+def test_no_program_outside_the_plan_runs_across_the_old_bucket_edges(
+        kernel_run):
+    (toks, grown, records, ready, planned, branches), _ = kernel_run
+    assert all(len(t) == 40 for t in toks)
+    assert grown["engine_cold_compiles_total"] == 0
+    assert {k for k in ready if k.startswith("decode")} <= planned
+    assert len({k for k in ready if k.startswith("decode")}) == 2
+    assert branches["decode"] == ["pallas-rows"]
+    # rows passed 128 and 256 while decoding: the old ladder's edges
+    assert {r.attrs["view"] for r in records} == {512}
+
+
+def test_the_kernel_path_emits_the_einsum_paths_tokens(kernel_run):
+    (toks, *_), (want, *_) = kernel_run
+    assert toks == want
+
+
+def test_records_carry_the_branch_and_the_counter_is_held_to_them(kernel_run):
+    for (_t, grown, records, *_), branch in zip(
+            kernel_run, ("pallas-rows", "einsum")):
+        assert records and {r.attrs["attn"] for r in records} == {branch}
+        steps = sum(r.attrs["steps"] for r in records)
+        assert steps == grown["engine_decode_steps_total"] > 0
+        assert grown["engine_decode_kernel_steps_total"] == sum(
+            r.attrs["steps"] for r in records if r.attrs["attn"] != "einsum")
+    assert kernel_run[0][1]["engine_decode_kernel_steps_total"] == \
+        kernel_run[0][1]["engine_decode_steps_total"]
+    assert kernel_run[1][1]["engine_decode_kernel_steps_total"] == 0
+
+
+def test_healthz_device_section_survives_a_dispatch_in_flight():
+    """/healthz is answered from the serve loop's thread.  While the
+    engine's thread is inside a dispatch ``kv_cache`` still names the arrays
+    that dispatch has just donated; reading the devices off them raised
+    ``Array has been deleted`` out of ``run_serve`` and the proxy lost the
+    peer with every stream in flight (my chip runs, PR 33: 3 runs of 40
+    polled beside their load)."""
+    from p2p_llm_tunnel_tpu.engine.engine import device_section
+
+    eng = _engine()
+    want = eng.resident_devices()
+    for leaf in eng.kv_cache.values():
+        leaf.delete()  # what a donation leaves behind until the call returns
+    assert eng.resident_devices() == want
+    assert device_section([eng])["engines"] == [want]

@@ -25,6 +25,7 @@ COUNTERS = (
     "engine_prefill_tokens_total", "engine_prefill_positions_total",
     "engine_decode_steps_total", "engine_decode_row_steps_total",
     "engine_decode_slot_steps_total", "engine_tokens_total",
+    "engine_decode_kernel_steps_total",
 )
 CHUNK = 16
 SLOTS = 4
@@ -159,6 +160,13 @@ def test_decode_records_and_counters_agree_exactly(traced):
         grown["engine_decode_slot_steps_total"]
     assert all(r.attrs["slots"] == SLOTS and
                0 <= r.attrs["live_rows"] <= SLOTS for r in bursts)
+    # the branch each burst's program ran, and the steps a kernel took of
+    # them (none here: a CPU backend keeps the einsum; the kernel path is
+    # held the same way in tests/test_decode_rows.py)
+    assert {r.attrs["attn"] for r in bursts} == {"einsum"}
+    assert sum(r.attrs["steps"] for r in bursts
+               if r.attrs["attn"] != "einsum") == \
+        grown["engine_decode_kernel_steps_total"] == 0
     # every emitted token but a request's first came out of a live row-step
     assert grown["engine_tokens_total"] == sum(emitted)
     assert row_steps >= grown["engine_tokens_total"] - len(emitted)

@@ -28,7 +28,11 @@ from jax.sharding import SingleDeviceSharding
 
 from p2p_llm_tunnel_tpu.ops.pallas_attention import flash_causal_attention
 from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+    ROWS_KERNEL,
+    decode_attention_rows,
+    decode_rows_worklist,
     flash_decode_attention_sgrid,
+    rows_block,
     fused_decode_layer,
     fused_spec_decode_layer,
 )
@@ -298,6 +302,75 @@ def _share_shapes(chip, cfg, rows, max_seq, kv=None):
     cache = _on(chip, jax.eval_shape(
         lambda: init_kv_cache(cfg, rows, max_seq, quant=kv)))
     return params, cache
+
+
+# ---------------------------------------------------------------------------
+# decode reads a row's live keys where they lie (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (28, 4)])
+def test_rows_decode_compiles_for_v5e(chip, heads, kv_heads):
+    """The default decode read (ISSUE 33) at both cells' GQA ratios, over
+    the stacked cache: one Mosaic kernel, and the flat ``[S*K, D]`` form it
+    reads is the cache's own bytes (a bitcast, no plane-sized copy)."""
+    cache = ((L, ROWS, MAX_SEQ, kv_heads, D), jnp.bfloat16)
+
+    block = rows_block(MAX_SEQ, kv_heads)
+
+    def fn(q, k, v, pos, layer):
+        return decode_attention_rows(
+            q, k, v, layer, decode_rows_worklist(pos, MAX_SEQ, block),
+            block=block, window=WINDOW)
+
+    args = [
+        jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+            ((ROWS, heads, D), jnp.bfloat16), cache, cache,
+            ((ROWS,), jnp.int32), ((), jnp.int32))
+    ]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    copies, _ = _plane_work(hlo, math.prod(cache[0]))
+    assert copies == []
+
+
+@pytest.mark.parametrize("model", ["mistral-7b", "qwen2-7b"])
+def test_the_shipped_decode_program_slices_no_plane(chip, model):
+    """``decode_step`` as a TPU backend runs it at the cells' shapes (33
+    rows x 1024 of bf16 cache, 8 and 4 KV heads; FFN and vocabulary cut,
+    they do not touch the cache): the rows kernel once in the layer scan,
+    no ``dynamic-slice`` of a layer's ``[1,33,S,K,D]`` plane left (the two
+    copies that were 16.5 % of mistral's decode-closed window, PERF.md
+    section 6), no plane-sized copy, and the cache written is the donated
+    one."""
+    from dataclasses import replace
+
+    from p2p_llm_tunnel_tpu.models.config import get_config
+    from p2p_llm_tunnel_tpu.models.transformer import (
+        decode_attention_branch,
+        decode_step,
+    )
+
+    cfg = replace(get_config(model, ffn_dim=512, vocab_size=1024),
+                  flash_force=True)  # the branch a TPU backend takes
+    assert decode_attention_branch(cfg, None, MAX_SEQ) == "pallas-rows"
+    params, cache = _share_shapes(chip, cfg, ROWS, MAX_SEQ)
+    row = _on(chip, jax.ShapeDtypeStruct((ROWS,), jnp.int32))
+    hlo = jax.jit(
+        lambda p, c, tok, pos: decode_step(cfg, p, c, tok, pos,
+                                           kv_view=MAX_SEQ),
+        donate_argnums=(1,),
+    ).lower(params, cache, row, row).compile().as_text()
+
+    assert hlo.count("tpu_custom_call") == 1 and ROWS_KERNEL in hlo
+    plane = f"[1,{ROWS},{MAX_SEQ},{cfg.n_kv_heads},{D}]"
+    assert plane not in hlo
+    assert "dynamic-slice" not in "".join(
+        line for line in hlo.splitlines()
+        if f"{ROWS},{MAX_SEQ},{cfg.n_kv_heads},{D}]" in line)
+    copies, _ = _plane_work(hlo, math.prod(cache["k"].shape))
+    assert copies == []
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert aliased.count("alias") == len(cache)
 
 
 @pytest.mark.parametrize("kv,rung,t,view", [

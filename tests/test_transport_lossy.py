@@ -161,3 +161,59 @@ def test_throughput_degrades_sublinearly():
         f"5% loss degraded throughput {t_lossy / t_clean:.1f}x "
         f"({t_clean:.2f}s → {t_lossy:.2f}s)"
     )
+
+
+def test_concurrent_senders_never_splice_a_message_into_another():
+    """Many tasks send on one channel, as a serve peer's 32 streams do.  A
+    message of several fragments that waits for the window mid-message must
+    not be passed by another task's send: the receiver joins fragments up to
+    the next fin, so a passing frame would be spliced into the waiting one
+    (PERF.md section 7's SSE fault: a control character inside a delta's
+    text, then ``unknown message type``).  The small senders are woken from
+    a thread, as token events are: their steps can be queued ahead of the
+    waiting sender's wake-up."""
+    import threading
+
+    big, small, per, size = 4, 16, 40, 100_000
+
+    async def main():
+        a, b, _ = await _lossy_pair(0.0)
+        loop = asyncio.get_running_loop()
+        queues = [asyncio.Queue() for _ in range(small)]
+
+        def feed():
+            for j in range(per):
+                for q in queues:
+                    loop.call_soon_threadsafe(q.put_nowait, j)
+                time.sleep(0.003)
+
+        async def big_sender(i):
+            for _ in range(per):
+                await a.send(bytes([i]) * size)  # 84 fragments
+
+        async def small_sender(i):
+            for _ in range(per):
+                await queues[i].get()
+                await a.send(bytes([100 + i]) * 300)
+
+        async def recv_all():
+            return [await b.recv() for _ in range((big + small) * per)]
+
+        feeder = threading.Thread(target=feed, daemon=True)
+        feeder.start()
+        try:
+            got, *_ = await asyncio.gather(
+                recv_all(),
+                *(big_sender(i) for i in range(big)),
+                *(small_sender(i) for i in range(small)))
+        finally:
+            a.close()
+            b.close()
+        feeder.join()
+        spliced = [m for m in got
+                   if len(set(m)) != 1 or len(m) not in (300, size)]
+        assert not spliced, f"{len(spliced)} of {len(got)} messages spliced"
+        for i in range(big):  # each sender's own messages all arrived
+            assert sum(m[0] == i for m in got) == per
+
+    run(main())

@@ -324,3 +324,63 @@ def test_mux_spec_herd_hits_zero_cold_compiles(persistent_cache,
     assert not live_new, (
         f"mux+spec herd compiled {len(live_new)} programs warmup missed"
     )
+
+
+def test_rows_kernel_herd_warms_one_decode_program_a_step_count(
+        persistent_cache, monkeypatch):
+    """ISSUE 33: where decode's attention is the rows kernel (here in
+    interpret mode) the plan holds ONE decode entry a step count, at
+    max_seq — the view axis is gone — and is still complete: a multiplexed
+    herd whose rows decode across the old ladder's edge at 128 asks
+    _dispatch_decode for no program outside it (no fresh compile, no cache
+    file, ``engine_cold_compiles_total`` unmoved).  Chunk programs keep
+    their views, and the einsum path's plan is what it was."""
+    from dataclasses import replace
+
+    from p2p_llm_tunnel_tpu.models.config import get_config
+    from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
+
+    monkeypatch.setenv("TUNNEL_WARMUP_PAR", "2")
+    tok = ByteTokenizer()
+    mcfg = replace(
+        get_config("tiny", vocab_size=tok.vocab_size), flash_interpret=True
+    )
+    cfg = {**ECFG, "mux": True}
+
+    def decode_entries(eng):
+        return [s for kind, s in eng.warmup_plan() if kind == "decode"]
+
+    plain = InferenceEngine(engine_cfg=EngineConfig(**cfg), tokenizer=tok)
+    assert decode_entries(plain) == [(128, 2), (128, 4), (256, 2), (256, 4)]
+
+    async def run():
+        eng = InferenceEngine(
+            model_cfg=mcfg, engine_cfg=EngineConfig(**cfg), tokenizer=tok)
+        assert decode_entries(eng) == [(256, 2), (256, 4)]
+        assert [e for e in eng.warmup_plan() if e[0] != "decode"] == \
+            [e for e in plain.warmup_plan() if e[0] != "decode"]
+        await eng.start()
+        await eng.warmup()
+        warmed = _cache_files(persistent_cache)
+        cold0 = global_metrics.counter("engine_cold_compiles_total")
+        herd = [list(range(1, 101)), list(range(1, 91)), [5, 6, 7]]
+        outs = await asyncio.gather(
+            *(_collect(eng, p, max_new=48) for p in herd))
+        outs.append(await _collect(eng, list(range(1, 121)), max_new=24))
+        cold = global_metrics.counter("engine_cold_compiles_total") - cold0
+        ready = {k for k in eng._programs_ready if k.startswith("decode")}
+        branches = eng.attention_branches["decode"]
+        await eng.stop()
+        return outs, warmed, cold, ready, branches
+
+    outs, warmed, cold, ready, branches = asyncio.run(run())
+    assert warmed, "warmup wrote nothing to the persistent cache"
+    assert [len(o) for o in outs] == [48, 48, 48, 24]
+    assert branches == ["pallas-rows"]
+    assert ready == {"decode[256,2]", "decode[256,4]"}
+    assert cold == 0, f"{cold} mid-serve cold compiles on the kernel path"
+    live_new = _cache_files(persistent_cache) - warmed
+    assert not live_new, (
+        f"the kernel-path herd compiled {len(live_new)} programs warmup "
+        f"missed"
+    )
