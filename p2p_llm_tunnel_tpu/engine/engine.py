@@ -667,6 +667,20 @@ class InferenceEngine:
         # evenness fences — runs AFTER the mux default below has
         # picked the effective prefill_chunk, so a defaulted odd
         # width cannot dodge it.)
+        # A model with window layers as rings (models/swa.py) gets its ring
+        # sized here, by the widest chunk-prefill segment this engine will
+        # dispatch: the effective chunk is settled further down, by this
+        # rule.  0 for every other model.
+        self._ring = 0
+        if self.mcfg.attn_pattern is not None:
+            chunk = self.ecfg.prefill_chunk
+            if chunk <= 0 and self.ecfg.mux and self.ecfg.sp <= 1:
+                chunk = self._mux_default_chunk()
+            self._ring = self.mcfg.ring_default(s, max(chunk, 0))
+            self.mcfg = dc_replace(self.mcfg, ring_positions=self._ring)
+        self._attn_kinds = tuple(
+            k == "window" for k in self.mcfg.attn_kinds)
+
         def make_cache():
             return init_kv_cache(
                 self.mcfg, rows, s, dtype, quant=self.ecfg.kv_quant
@@ -717,15 +731,8 @@ class InferenceEngine:
         # back to budgeted whole-prompt admission waves.
         if self.ecfg.mux and self.ecfg.prefill_chunk <= 0:
             if self.ecfg.sp <= 1:
-                # 128 measured best on the 32-client herd (PERF.md r8):
-                # wide enough that a shared-prefix owner drains in a few
-                # sub-batches, narrow enough that one segment's compute
-                # stays comparable to a decode burst.
                 self.ecfg = dc_replace(
-                    self.ecfg,
-                    prefill_chunk=max(self.ecfg.min_prefill_bucket,
-                                      min(128, s)),
-                )
+                    self.ecfg, prefill_chunk=self._mux_default_chunk())
         if self.ecfg.kv_quant == "int4":
             # Page-alignment pass (ISSUE 14), AFTER the mux default above
             # so the EFFECTIVE chunk width is what gets rounded: packed
@@ -949,8 +956,11 @@ class InferenceEngine:
                 # predicate init_pool sizes pages with, so the page unit
                 # and the copy unit cannot split.
                 packed_keys=pool_packed_keys(self.kv_cache),
+                # (no head axis in a row of either family's planes)
                 layerwise_keys=frozenset(
-                    self.kv_cache if self.mcfg.kv_lora_rank else ()),
+                    self.kv_cache if self.mcfg.kv_lora_rank or self._ring
+                    else ()),
+                ring_keys=self._ring_keys(),
             )
             if self._spmd is not None:
                 self._copy_in = self._spmd.wrap("copy_in", self._copy_in, 2)
@@ -1868,12 +1878,18 @@ class InferenceEngine:
         return "einsum"  # chunk: ops.attention.history_attention
 
     def _refuse_unsupported(self) -> None:
-        """What a latent-attention model does not have yet is refused at
-        start-up, by name, instead of served wrongly: its weights have no
-        quantiser (experts: models/quant.py), its layers no mesh rules
-        (parallel/), and the Pallas kernels, the ragged prefill and the
-        speculative verify read a cache of KV heads."""
-        if not self.mcfg.kv_lora_rank:
+        """What a family served by a module of its own (latent attention:
+        models/mla.py; window rings beside full planes: models/swa.py) does
+        not have yet is refused at start-up, by name, instead of served
+        wrongly: its weights have no quantiser (experts: models/quant.py),
+        its layers no mesh rules (parallel/), and the Pallas kernels, the
+        ragged prefill and the speculative verify read one plane of KV
+        heads whose keys and values are equally wide."""
+        if self.mcfg.kv_lora_rank:
+            what = "latent attention, routed experts"
+        elif self.mcfg.attn_pattern is not None:
+            what = "window rings beside full planes, routed experts"
+        else:
             return
         e = self.ecfg
         asked = [
@@ -1892,30 +1908,55 @@ class InferenceEngine:
         refused = [name for on, name in asked if on]
         if refused:
             raise ValueError(
-                f"model {self.mcfg.name!r} (latent attention, routed "
-                f"experts) cannot be served with {', '.join(refused)}: "
+                f"model {self.mcfg.name!r} ({what}) cannot be served with "
+                f"{', '.join(refused)}: "
                 "serve it with --quant none on one chip, without the Pallas "
                 "decode kernels, the ragged prefill or speculative decoding"
             )
+
+    def _mux_default_chunk(self) -> int:
+        """The segment width multiplexing picks when none was configured.
+        128 measured best on the 32-client herd (PERF.md r8): wide enough
+        that a shared-prefix owner drains in a few sub-batches, narrow
+        enough that one segment's compute stays comparable to a decode
+        burst."""
+        return max(self.ecfg.min_prefill_bucket,
+                   min(128, self.ecfg.max_seq))
+
+    def _ring_keys(self) -> frozenset:
+        """The cache leaves that are rings (none but a window-ring
+        model's)."""
+        if not self._ring:
+            return frozenset()
+        from p2p_llm_tunnel_tpu.models.swa import RING_KEYS
+
+        return RING_KEYS & frozenset(self.kv_cache)
 
     def _model_section(self) -> Dict[str, object]:
         """/healthz ``config.model``: the cache's form and the share of the
         published model this process holds."""
         m = self.mcfg
         rows, s = self.ecfg.num_slots + 1, self.ecfg.max_seq
-        per_token = sum(
-            int(arr.size) * arr.dtype.itemsize
-            for arr in self.kv_cache.values()) // (rows * s)
-        first, held = m.experts_held
-        return {
-            "name": m.name,
-            "cache": {
+        if self._ring:
+            from p2p_llm_tunnel_tpu.models.swa import cache_section
+
+            # two kinds of plane: what a pooled token and what a slot holds
+            # are two statements
+            cache = cache_section(m, self.kv_cache)
+        else:
+            cache = {
                 "form": "latent" if m.kv_lora_rank else "kv_heads",
                 "values_per_token_layer": (
                     m.head_dim if m.kv_lora_rank
                     else 2 * m.n_kv_heads * m.head_dim),
-                "bytes_per_token": per_token,
-            },
+                "bytes_per_token": sum(
+                    int(arr.size) * arr.dtype.itemsize
+                    for arr in self.kv_cache.values()) // (rows * s),
+            }
+        first, held = m.experts_held
+        return {
+            "name": m.name,
+            "cache": cache,
             "layers": {"held": m.n_layers,
                        "of": m.published_layers or m.n_layers},
             "experts": {"held": held, "first": first, "of": m.n_experts},
@@ -2818,6 +2859,29 @@ class InferenceEngine:
             b *= 2
         return min(b, self.ecfg.max_seq)
 
+    def _count_kv_rows(self, first, n, rec: Optional[_Dispatch]) -> None:
+        """What a dispatch's attention has to read of the cache, by layer
+        kind: for each row, queries at positions ``first[i] .. first[i] +
+        n[i] - 1`` (as the host accounts them when it dispatches: no fetch)
+        see ``p + 1`` positions in a full layer and ``min(p + 1, window)``
+        in a window layer; summed over rows and layers.  Counted in
+        ``engine_kv_rows_{full,window}_total`` and, under tracing, on the
+        dispatch's record, so the two always agree."""
+        first = np.asarray(first, np.int64)
+        n = np.broadcast_to(np.asarray(n, np.int64), first.shape)
+        lw = sum(self._attn_kinds)
+        lf = len(self._attn_kinds) - lw
+        full = int((n * first + n * (n + 1) // 2).sum()) * lf
+        window = 0
+        if lw:
+            w = int(self.mcfg.sliding_window)
+            m = np.clip(w - 1 - first, 0, n)  # queries that see < w positions
+            window = int((m * first + m * (m + 1) // 2 + (n - m) * w).sum()) * lw
+        global_metrics.inc("engine_kv_rows_full_total", full)
+        global_metrics.inc("engine_kv_rows_window_total", window)
+        if rec is not None:
+            rec.attrs.update(kv_rows_full=full, kv_rows_window=window)
+
     def _take_moe(self, counts) -> None:
         """A serving program's routed-layer counts, still on the device
         (executor thread, as the dispatch call returns): queued beside the
@@ -3013,6 +3077,8 @@ class InferenceEngine:
                            time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
         global_metrics.inc("engine_prefill_positions_total", nb * t)
+        self._count_kv_rows(
+            [0] * len(runs), [len(r.request.prompt_ids) for r in runs], rec)
         out = first, (lp if lps.any() else None), plp
         self._start_host_copy(out)
         return out
@@ -3119,6 +3185,8 @@ class InferenceEngine:
                            time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
         global_metrics.inc("engine_prefill_positions_total", nb * t)
+        self._count_kv_rows([start for _r, start, _g, _s in rows],
+                            [len(seg) for _r, _s, seg, _f in rows], rec)
         out = first, (lp if lps.any() else None), None
         self._start_host_copy(out)
         return out
@@ -3216,6 +3284,8 @@ class InferenceEngine:
         self._note_program("ragged", (tot,), time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
         global_metrics.inc("engine_prefill_positions_total", tot)
+        self._count_kv_rows([start for _r, start, _g, _s in rows],
+                            [len(seg) for _r, _s, seg, _f in rows], rec)
         out = first, (lp if lps.any() else None), None
         self._start_host_copy(out)
         return out
@@ -3389,6 +3459,10 @@ class InferenceEngine:
             global_metrics.inc("engine_decode_row_steps_total", live * steps)
             global_metrics.inc("engine_decode_slot_steps_total",
                                slots * steps)
+            # (the device's carry may lead these positions by the bursts
+            # in flight)
+            self._count_kv_rows(
+                self._positions[:slots][active[:slots]], steps, rec)
         self._ov_mask[:] = False  # patch consumed by this dispatch
         # Rows must ALSO have been active at dispatch time to be accounted:
         # a chunk-prefilling slot holds its request-id long before its
@@ -3819,19 +3893,51 @@ class InferenceEngine:
                 )
             self._close_pool_copy(rec)
 
-    def _prefix_insert(self, runs: List[RunningSlot]) -> None:
+    def _ring_live(self, wave: List[Tuple[int, List[int]]],
+                   lead: int = 0) -> List[Tuple[int, List[int]]]:
+        """Of ``[(slot, token ids the slot holds)]``, the entries whose
+        unsaved blocks the slot's rings still hold whole (all of them where
+        no layer is a ring).  A ring of ``R`` holds the last ``R`` positions
+        written; ``lead``: positions the device may have written past the
+        ids (decode steps dispatched before the host saw the stream end).
+        An entry whose first unsaved block has left the ring saves nothing:
+        a chain with a hole matches no further than the hole."""
+        if not self._ring:
+            return wave
+        live = []
+        for slot, ids in wave:
+            missing = self._prefix.missing(ids)
+            if missing and (missing[0][0] * self._prefix_block
+                            < len(ids) + lead - self._ring):
+                continue
+            live.append((slot, ids))
+        return live
+
+    def _prefix_insert(self, runs: List[RunningSlot],
+                       held: Optional[List[int]] = None) -> None:
         """Save the runs' now-prefilled, not-yet-pooled prompt blocks into
         the pool (executor thread), one batched dispatch per prefill_rows.
         Same-wave eviction hazards are handled by
-        :func:`prefix_cache.plan_inserts` (see its docstring)."""
+        :func:`prefix_cache.plan_inserts` (see its docstring).
+
+        ``held[i]``: the tokens of run ``i``'s prompt that its slot holds so
+        far (all of it when not given).  Where window layers are rings, a
+        block can be saved only while the ring still holds it, so a
+        segmented prompt is saved segment by segment
+        (:meth:`_dispatch_segments`), and a run whose first unsaved block
+        has already left the ring (a whole-prompt prefill longer than the
+        ring) saves nothing (:meth:`_ring_live`)."""
         from p2p_llm_tunnel_tpu.engine.prefix_cache import (
             pad_rows,
             plan_inserts,
         )
 
+        wave = self._ring_live([
+            (run.slot, run.request.prompt_ids if held is None
+             else run.request.prompt_ids[: held[i]])
+            for i, run in enumerate(runs)])
         entries = plan_inserts(
-            self._prefix,
-            [(run.slot, run.request.prompt_ids) for run in runs],
+            self._prefix, wave,
             ms_per_token=self._prefill_ms_per_token or 1.0,
         )
         total = sum(len(ids) for _, ids, _ in entries)
@@ -4281,6 +4387,15 @@ class InferenceEngine:
         t_dispatch = time.monotonic()
         first_lp = self._dispatch_chunk_rows(chunk_rows, chunk)
         global_metrics.inc("engine_prefill_segments_total", len(rows))
+        if self._ring and self._prefix is not None:
+            # a ring holds this segment's blocks now and not after the next
+            # one: save them here, in device order behind the segment (the
+            # final segment's are saved where the run finishes)
+            mid = [(run, start + len(seg))
+                   for run, start, seg, final in chunk_rows if not final]
+            if mid:
+                self._prefix_insert([run for run, _n in mid],
+                                    [n for _run, n in mid])
         return rows, first_lp, t_dispatch, n_tokens, self._last_dispatch
 
     async def _finish_segments(self, loop, seg) -> None:
@@ -4396,6 +4511,9 @@ class InferenceEngine:
             plan_inserts,
         )
 
+        # (the carry runs at most two bursts ahead of the host, and the
+        # stream's last token was never fed back)
+        pending = self._ring_live(pending, lead=2 * self.ecfg.decode_steps)
         entries = plan_inserts(
             self._prefix, pending, conv=True,
             ms_per_token=self._prefill_ms_per_token or 1.0,

@@ -951,7 +951,8 @@ def plan_inserts(
 
 def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
                         packed_keys: frozenset = frozenset(),
-                        layerwise_keys: frozenset = frozenset()):
+                        layerwise_keys: frozenset = frozenset(),
+                        ring_keys: frozenset = frozenset()):
     """Row-batched copy programs: ONE dispatch serves up to ``rows``
     requests' block copies.
 
@@ -974,11 +975,27 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
     scatter's window is turned layers-innermost for it by the chip's
     compiler, whole, and back.  The pool side keeps whole pages in its
     window either way.
+    ``ring_keys`` leaves are rings in the CACHE (a window layer's planes,
+    models/swa.py: ``R`` positions a slot, position ``p`` at ``p % R``) and
+    whole pages in the pool, like any leaf: a pooled token holds its window
+    layers' keys and values too.  A restore writes only the last ``R``
+    positions of the row's prefix (its blocks are numbered from 0, so its
+    length is its largest block number's end): earlier ones would land on
+    the same ring slots, and no window reaches them.  A save reads each
+    block at its positions' ring slots, so a block is saved while the ring
+    still holds it: the engine saves a prompt's blocks segment by segment.
     """
 
     def _pos(unit, blk_nos):
         offs = jnp.arange(unit)[None, None, :]
         return (blk_nos[:, :, None] * unit + offs).reshape(rows, -1)
+
+    def _ring_slots(pos, blk_nos, ring):
+        """Ring slots of ``pos [R, n]`` for a restore of the blocks
+        ``blk_nos``; ``ring`` itself (dropped by the scatter) for positions
+        more than a ring before the prefix's end."""
+        end = (blk_nos.max(axis=1, keepdims=True) + 1) * block
+        return jnp.where(pos >= end - ring, pos % ring, ring)
 
     def blocks_to_cache(cache, pool, slots, pool_ids, blk_nos):
         """cache[slots[r]] positions [blk_nos[r,i]*B, +B) <- pool[pool_ids[r,i]].
@@ -995,6 +1012,8 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
                 flat = vals.reshape(
                     (vals.shape[0], rows, pos.shape[1]) + vals.shape[4:]
                 )
+                if key in ring_keys:
+                    pos = _ring_slots(pos, blk_nos, arr.shape[2])
                 if key in layerwise_keys:
                     for i in range(arr.shape[0]):
                         arr = arr.at[i, slots[:, None], pos].set(flat[i])
@@ -1015,6 +1034,8 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
             for key, arr in pool.items():
                 unit = block // 2 if key in packed_keys else block
                 pos = _pos(unit, blk_nos)
+                if key in ring_keys:
+                    pos = pos % cache[key].shape[2]
                 if key in layerwise_keys:
                     vals = jnp.stack([
                         cache[key][i, slots[:, None], pos]

@@ -134,6 +134,25 @@ class ModelConfig:
     v_head_dim: int = 0
     qk_norm: bool = False  # RMSNorm(w) over each head's query before rope
     yarn: Optional[YarnRope] = None
+    # Window and full attention layers mixed by a pattern given as data
+    # (models/swa.py): ``attn_pattern[l]`` is 1 where layer ``l`` attends to
+    # the last ``sliding_window`` positions and 0 where it attends to all.
+    # The two kinds differ in more than the mask: ``n_kv_heads`` /
+    # ``rope_theta`` are the full layers', ``window_kv_heads`` /
+    # ``window_rope_theta`` the window layers'.  Keys and queries are
+    # ``head_dim`` wide, values ``v_head_dim``; the leading ``rotary_dim``
+    # columns of a head are roped (0: all); values are scaled by
+    # ``value_scale`` as they are projected; under ``window_sink`` a learned
+    # scalar a head joins the window layers' softmax denominator.  A window
+    # layer caches a ring of ``ring_positions`` positions a slot, not
+    # ``max_seq`` (0: the engine sizes it, ``ring_default``).
+    attn_pattern: Optional[Tuple[int, ...]] = None
+    window_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    rotary_dim: int = 0
+    value_scale: float = 1.0
+    window_sink: bool = False
+    ring_positions: int = 0
 
     @property
     def q_per_kv(self) -> int:
@@ -162,6 +181,36 @@ class ModelConfig:
         if self.kv_lora_rank:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim
+
+    @property
+    def attn_kinds(self) -> Tuple[str, ...]:
+        """Each layer's attention, ``"full"`` or ``"window"``: the pattern
+        where one is given, else what ``sliding_window`` and
+        ``window_pattern`` say of a uniform cache."""
+        if self.attn_pattern is not None:
+            return tuple("window" if k else "full"
+                         for k in self.attn_pattern[: self.n_layers])
+        if self.sliding_window is None:
+            return ("full",) * self.n_layers
+        if self.window_pattern == "all":
+            return ("window",) * self.n_layers
+        return tuple("window" if l % 2 == 0 else "full"
+                     for l in range(self.n_layers))
+
+    def kv_heads_of(self, kind: str) -> int:
+        if kind == "window" and self.window_kv_heads:
+            return self.window_kv_heads
+        return self.n_kv_heads
+
+    def ring_default(self, max_seq: int, chunk: int = 0) -> int:
+        """Positions a window layer's ring holds a slot: the window and the
+        widest chunk-prefill segment, in whole 128s (a segment's padded tail
+        must not reach round the ring into the window the next query reads),
+        never more than ``max_seq``."""
+        if self.ring_positions:
+            return min(self.ring_positions, max_seq)
+        need = (self.sliding_window or 0) + max(chunk, 128)
+        return min(-(-need // 128) * 128, max_seq)
 
 
 def tiny(vocab_size: int = 512) -> ModelConfig:
@@ -457,8 +506,105 @@ def tiny_mla_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
                    layer_chips=2, chip_index=0)
 
 
+#: hybrid_layer_pattern of MiMo-V2-Flash: layer 0 full, four window layers,
+#: then periods of one full layer and five window layers, a full layer last.
+_MIMO_PATTERN = (0, 1, 1, 1, 1) + (0, 1, 1, 1, 1, 1) * 7 + (0,)
+
+
+def mimo_v2_flash() -> ModelConfig:
+    """MiMo-V2-Flash as published (huggingface.co/XiaomiMiMo/MiMo-V2-Flash
+    config.json): 9 full and 39 window attention layers (window 128, a sink
+    in their softmax, 8 KV heads against 4), keys 192 and values 128 wide,
+    64 rotary columns, one dense layer then 256 routed experts top-8 with a
+    selection bias and no shared expert.  For shapes and tests of the
+    config: no chip holds it."""
+    return ModelConfig(
+        name="mimo-v2-flash",
+        vocab_size=152576,
+        dim=4096,
+        n_layers=48,
+        n_heads=64,
+        n_kv_heads=4,
+        head_dim=192,
+        ffn_dim=16384,
+        rope_theta=5000000.0,
+        norm_eps=1e-5,
+        sliding_window=128,
+        n_experts=256,
+        n_experts_per_tok=8,
+        moe_ffn_dim=2048,
+        first_dense_layers=1,
+        router_score="sigmoid",
+        router_bias=True,
+        routed_scale=1.0,
+        v_head_dim=128,
+        attn_pattern=_MIMO_PATTERN,
+        window_kv_heads=8,
+        window_rope_theta=10000.0,
+        rotary_dim=64,
+        value_scale=0.707,
+        window_sink=True,
+    )
+
+
+def mimo_v2_flash_ep16s() -> ModelConfig:
+    """One chip's share of MiMo-V2-Flash: one of 16 chips that share each
+    layer (experts 0-15, vocabulary rows 0-19,071) and the first pipeline
+    stage: the dense layer and six routed layers, kinds F SSSS F S (one
+    whole period of the pattern).  Every width, the router's 256 outputs,
+    top-8 and the bias are as published."""
+    return replace(mimo_v2_flash(), name="mimo-v2-flash-ep16s", n_layers=7,
+                   published_layers=48, vocab_size=19072, layer_chips=16,
+                   chip_index=0)
+
+
+def tiny_swa_moe(vocab_size: int = 512) -> ModelConfig:
+    """CPU-testable MiMo-style config: seven layers F SSSS F S, window 8,
+    keys 24 and values 16 wide with 8 rotary columns, 1 (full) and 2
+    (window) KV heads, a sink, one dense layer then 8 experts top-2; rings
+    of 16 positions, so a prompt of some tens of tokens wraps them."""
+    return ModelConfig(
+        name="tiny-swa-moe",
+        vocab_size=vocab_size,
+        dim=64,
+        n_layers=7,
+        n_heads=4,
+        n_kv_heads=1,
+        head_dim=24,
+        ffn_dim=128,
+        rope_theta=5000000.0,
+        norm_eps=1e-5,
+        sliding_window=8,
+        n_experts=8,
+        n_experts_per_tok=2,
+        moe_ffn_dim=32,
+        first_dense_layers=1,
+        router_score="sigmoid",
+        router_bias=True,
+        v_head_dim=16,
+        attn_pattern=(0, 1, 1, 1, 1, 0, 1),
+        window_kv_heads=2,
+        window_rope_theta=10000.0,
+        rotary_dim=8,
+        value_scale=0.707,
+        window_sink=True,
+        ring_positions=16,
+    )
+
+
+def tiny_swa_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
+    """tiny-swa-moe as one of 2 chips that share each layer: experts 0-3
+    and ``vocab_size`` rows of a table twice as long."""
+    return replace(tiny_swa_moe(vocab_size), name="tiny-swa-moe-ep2s",
+                   layer_chips=2, chip_index=0)
+
+
 PRESETS = {
     "tiny": tiny,
+    "tiny-swa-moe": tiny_swa_moe,
+    "tiny-swa-moe-ep2s": tiny_swa_moe_ep2s,
+    "mimo-v2-flash": mimo_v2_flash,
+    "mimo-v2-flash-ep16s": mimo_v2_flash_ep16s,
     "tiny-mla-moe": tiny_mla_moe,
     "tiny-mla-moe-ep2s": tiny_mla_moe_ep2s,
     "sarvam-105b": sarvam_105b,

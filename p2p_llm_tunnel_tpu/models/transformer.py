@@ -41,10 +41,9 @@ def init_params(
     cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16
 ) -> Params:
     """Random init (truncated-normal fan-in); layout matches checkpoint loader."""
-    if cfg.kv_lora_rank:
-        from p2p_llm_tunnel_tpu.models import mla
-
-        return mla.init_params(cfg, key, dtype)
+    family = _family_module(cfg)
+    if family is not None:
+        return family.init_params(cfg, key, dtype)
     l, dm, h, kh, hd, f, v = (
         cfg.n_layers, cfg.dim, cfg.n_heads, cfg.n_kv_heads,
         cfg.head_dim, cfg.ffn_dim, cfg.vocab_size,
@@ -108,12 +107,12 @@ def init_kv_cache(
     scale planes stay per-token full resolution.  ``max_seq`` must be even
     for int4 (every serving bucket is).
 
-    A latent-attention config (``cfg.kv_lora_rank``) gets its own form, one
-    plane ``{"c": [L, rows, S, C + Dr]}`` (models/mla.py)."""
-    if cfg.kv_lora_rank:
-        from p2p_llm_tunnel_tpu.models import mla
-
-        return mla.init_kv_cache(cfg, num_slots, max_seq, dtype, quant)
+    A latent-attention config (``cfg.kv_lora_rank``) gets its own form, the
+    latent planes (models/mla.py); one with an ``attn_pattern`` gets window
+    layers as rings beside full-length planes (models/swa.py)."""
+    family = _family_module(cfg)
+    if family is not None:
+        return family.init_kv_cache(cfg, num_slots, max_seq, dtype, quant)
     shape = (cfg.n_layers, num_slots, max_seq, cfg.n_kv_heads, cfg.head_dim)
     _modes = {False: None, True: "int8", None: None, "none": None, "": None,
               "int8": "int8", "int4": "int4"}
@@ -215,6 +214,21 @@ def _moe_total(stats):
     return stats.sum(axis=0)
 
 
+def _family_module(cfg: ModelConfig):
+    """The module that serves a family whose cache is not one ``[L, rows, S,
+    K, D]`` pair of planes (latent rows: models/mla.py; window rings beside
+    full planes: models/swa.py), or None."""
+    if cfg.kv_lora_rank:
+        from p2p_llm_tunnel_tpu.models import mla
+
+        return mla
+    if cfg.attn_pattern is not None:
+        from p2p_llm_tunnel_tpu.models import swa
+
+        return swa
+    return None
+
+
 def _qkv_proj(cfg: ModelConfig, blk, h):
     """QKV projections + bias + head split, NO rope — the fused decode
     kernel applies rope in VMEM at each slot's position, so the decode
@@ -295,6 +309,7 @@ def prefill_attention_branch(cfg: ModelConfig, mesh, t: int) -> str:
         and (jax.default_backend() == "tpu" or cfg.flash_interpret)
         and t % 128 == 0
         and cfg.head_dim % 128 == 0
+        and cfg.attn_pattern is None
     ):
         return "pallas-flash"
     return "einsum"
@@ -349,6 +364,7 @@ def decode_attention_branch(cfg: ModelConfig, mesh, kv_view: int,
         if cfg.flash_decode or cfg.flash_sgrid:
             return "pallas-sgrid"
     if (cfg.flash and kv_quant is None and not cfg.kv_lora_rank
+            and cfg.attn_pattern is None
             and decode_kernel_decline(cfg, mesh, max_seq or kv_view) is None):
         return "pallas-rows"
     return "einsum"
@@ -482,7 +498,7 @@ def prefill(
 ):
     """Full-prompt forward. Returns (logits [B,T,V], k, v [L,B,T,K,D]); a
     latent-attention config returns its cached rows [L,B,T,C+Dr] for ``k``
-    and None for ``v``.  With ``counted`` ([B,T] bool) a fourth value: the
+    and None for ``v``, a window-and-full one its rows by kind.  With ``counted`` ([B,T] bool) a fourth value: the
     routed layers' counts of those tokens (see ``apply_blocks``).
 
     ``mesh`` (optional jax.sharding.Mesh) selects sharded attention paths:
@@ -490,10 +506,10 @@ def prefill(
     attention over the sequence axis (see _prefill_attention_fn).
     """
     b, t = tokens.shape
-    if cfg.kv_lora_rank:
-        from p2p_llm_tunnel_tpu.models import mla
-
-        logits, rows, stats = mla.prefill(cfg, params, tokens, valid, counted)
+    family = _family_module(cfg)
+    if family is not None:
+        logits, rows, stats = family.prefill(cfg, params, tokens, valid,
+                                             counted)
         if counted is not None:
             return logits, rows, None, stats
         return logits, rows, None
@@ -600,10 +616,9 @@ def prefill_into_cache(
     rows, which the engine parks on its scratch slot, count for nothing.
     """
     b, t = tokens.shape
-    if cfg.kv_lora_rank:
-        from p2p_llm_tunnel_tpu.models import mla
-
-        out = mla.prefill_into_cache(
+    family = _family_module(cfg)
+    if family is not None:
+        out = family.prefill_into_cache(
             cfg, params, tokens, lengths, kv_cache, slots,
             return_prompt_logprobs=return_prompt_logprobs,
             stat_rows=stat_rows)
@@ -790,10 +805,9 @@ def chunk_prefill_into_cache(
     rows (as ``prefill_into_cache``).
     """
     b, t = tokens.shape
-    if cfg.kv_lora_rank:
-        from p2p_llm_tunnel_tpu.models import mla
-
-        out = mla.chunk_prefill_into_cache(
+    family = _family_module(cfg)
+    if family is not None:
+        out = family.chunk_prefill_into_cache(
             cfg, params, tokens, lengths, starts, kv_cache, slots,
             kv_view=kv_view, return_all_logits=return_all_logits,
             stat_rows=stat_rows)
@@ -1162,11 +1176,10 @@ def decode_step(
     ``kv_view`` bounds nothing, and the engine compiles one view.
     """
     b = tokens.shape[0]
-    if cfg.kv_lora_rank:
-        from p2p_llm_tunnel_tpu.models import mla
-
-        out = mla.decode_step(cfg, params, kv_cache, tokens, positions,
-                              kv_view=kv_view)
+    family = _family_module(cfg)
+    if family is not None:
+        out = family.decode_step(cfg, params, kv_cache, tokens, positions,
+                                 kv_view=kv_view)
         return out if with_stats else out[:-1]
     quant_mode = kv_cache_quant_mode(kv_cache)
     quant = quant_mode is not None

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -227,3 +228,88 @@ def latent_attention_absorbed(
     out = jnp.einsum("bhts,bsc->bhtc", probs.astype(c.dtype), c,
                      preferred_element_type=jnp.float32)
     return jnp.swapaxes(out, 1, 2).astype(q_c.dtype)
+
+
+# ---------------------------------------------------------------------------
+# attention under a mask by position, values narrower than keys, a sink
+# ---------------------------------------------------------------------------
+
+#: Bytes of float32 scores one block of queries may take in
+#: :func:`masked_attention`: a chunk-prefill segment of 2 x 512 queries
+#: against 8192 cached positions and 64 heads would score 2 GB at once.
+_SCORE_BLOCK_BYTES = 1 << 28
+
+
+def window_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray,
+                window: Optional[int] = None) -> jnp.ndarray:
+    """Which key a query sees, by position: ``q_pos [B,T]``, ``k_pos [B,S]``
+    (a key slot that holds nothing yet carries a negative position) ->
+    ``[B,T,S]``.  Causal; under ``window`` also ``q - k < window``.  The
+    keys may lie in any order, so a ring's slots are named by the positions
+    they hold (:func:`ring_positions`)."""
+    q, k = q_pos[:, :, None], k_pos[:, None, :]
+    mask = (k >= 0) & (k <= q)
+    if window is not None:
+        mask &= (q - k) < window
+    return mask
+
+
+def ring_positions(last: jnp.ndarray, ring: int) -> jnp.ndarray:
+    """The position each slot of a ring of ``ring`` slots holds once
+    position ``last [B]`` has been written at ``last % ring``: slot ``r``
+    holds the newest ``p <= last`` with ``p % ring == r``; negative where
+    the ring has not come round to it yet.  -> ``[B, ring]``."""
+    r = jnp.arange(ring)[None, :]
+    return last[:, None] - jnp.mod(last[:, None] - r, ring)
+
+
+def masked_attention(
+    q: jnp.ndarray,      # [B,T,H,Dk]
+    k: jnp.ndarray,      # [B,S,K,Dk]
+    v: jnp.ndarray,      # [B,S,K,Dv]: Dv need not be Dk
+    mask: jnp.ndarray,   # [B,T,S] bool
+    scale: float,
+    sink: Optional[jnp.ndarray] = None,  # [H] float32 logits
+) -> jnp.ndarray:
+    """GQA attention over whatever keys ``mask`` admits -> ``[B,T,H,Dv]``.
+
+    ``sink``: one learned logit a head that joins the softmax's denominator
+    and carries no value, ``p_ij = exp(s_ij) / (exp(b_h) + sum_j' exp(s_ij'))``:
+    a head may then give its keys less than all of its weight.
+
+    Scores are float32; the weighted sum takes the probabilities rounded to
+    the values' type.  Queries are scored a block at a time where all at
+    once would take more than ``_SCORE_BLOCK_BYTES``."""
+    b, t, h, _ = q.shape
+    s, kh, dv = k.shape[1], k.shape[2], v.shape[-1]
+    g = h // kh
+    sink_kg = None if sink is None else sink.astype(jnp.float32).reshape(kh, g)
+
+    def attend(q_blk, mask_blk):
+        q5 = q_blk.reshape(b, -1, kh, g, q_blk.shape[-1])
+        scores = _gqa_scores(q5, k, scale)  # [B,K,G,t,S]
+        scores = jnp.where(mask_blk[:, None, None], scores, _NEG_INF)
+        top = scores.max(axis=-1, keepdims=True)
+        if sink_kg is not None:
+            top = jnp.maximum(top, sink_kg[None, :, :, None, None])
+        probs = jnp.exp(scores - top)
+        denom = probs.sum(axis=-1, keepdims=True)
+        if sink_kg is not None:
+            denom = denom + jnp.exp(sink_kg[None, :, :, None, None] - top)
+        probs = (probs / denom).astype(v.dtype)
+        out = jnp.einsum("bkgts,bskd->btkgd", probs, v,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(b, -1, h, dv).astype(q.dtype)
+
+    per_query = b * h * s * 4
+    block = t
+    while block > 16 and block * per_query > _SCORE_BLOCK_BYTES \
+            and block % 2 == 0:
+        block //= 2
+    if block == t:
+        return attend(q, mask)
+    n = t // block
+    q_blocks = jnp.moveaxis(q.reshape(b, n, block, h, -1), 1, 0)
+    m_blocks = jnp.moveaxis(mask.reshape(b, n, block, s), 1, 0)
+    out = jax.lax.map(lambda xs: attend(*xs), (q_blocks, m_blocks))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, dv)

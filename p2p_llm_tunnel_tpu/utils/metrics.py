@@ -40,6 +40,16 @@ METRICS_CATALOG: Dict[str, str] = {
     "engine_decode_row_steps_total": (
         "live rows x steps over every decode burst dispatched (counter)"
     ),
+    "engine_kv_rows_full_total": (
+        "cache positions x full-attention layers that the attention of every "
+        "dispatch had to read, from the rows' positions on the host: a "
+        "query at position p sees p + 1 (counter)"
+    ),
+    "engine_kv_rows_window_total": (
+        "the same for window layers, where a query sees min(p + 1, window); "
+        "over the sum of the two it is the share of attention's reads that "
+        "the windows bound (counter)"
+    ),
     "engine_moe_assignments_total": (
         "token-to-expert assignments the routed layers made of real tokens, "
         "over every expert layer of every dispatch (counter)"

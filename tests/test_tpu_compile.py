@@ -497,6 +497,120 @@ def test_the_share_presets_programs_fit_one_chip(chip, program):
 
 
 # ---------------------------------------------------------------------------
+# window rings beside full planes (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+#: mimo-v2-flash-ep16s at the cell's size: 48 slots + the scratch row x 8192,
+#: rings of 640 (window 128 + segments of 512).
+SWA_ROWS, SWA_SEQ, SWA_RING = 49, 8192, 640
+SWA_PLANES = {"k": (2, SWA_SEQ, 4 * 192), "v": (2, SWA_SEQ, 4 * 128),
+              "wk": (5, SWA_RING, 8 * 192), "wv": (5, SWA_RING, 8 * 128)}
+
+
+def _swa(chip, **small):
+    from p2p_llm_tunnel_tpu.models.config import get_config
+
+    cfg = get_config("mimo-v2-flash-ep16s", ring_positions=SWA_RING, **small)
+    params, cache = _share_shapes(chip, cfg, SWA_ROWS, SWA_SEQ)
+    assert {k: (v.shape[0],) + v.shape[2:] for k, v in cache.items()} \
+        == SWA_PLANES
+    return cfg, params, cache
+
+
+def _swa_batch(chip):
+    return _on(chip, {
+        "row49": jax.ShapeDtypeStruct((SWA_ROWS,), jnp.int32),
+        "row8": jax.ShapeDtypeStruct((8,), jnp.int32),
+        "row2": jax.ShapeDtypeStruct((2,), jnp.int32),
+        "row1": jax.ShapeDtypeStruct((1,), jnp.int32),
+        "tok128": jax.ShapeDtypeStruct((8, 128), jnp.int32),
+        "tok512": jax.ShapeDtypeStruct((2, 512), jnp.int32),
+        "tok512x1": jax.ShapeDtypeStruct((1, 512), jnp.int32)})
+
+
+SWA_PROGRAMS = {
+    "decode-8192": lambda T, cfg, p, c, b: T.decode_step(
+        cfg, p, c, b["row49"], b["row49"], kv_view=8192, with_stats=True),
+    "decode-1024": lambda T, cfg, p, c, b: T.decode_step(
+        cfg, p, c, b["row49"], b["row49"], kv_view=1024, with_stats=True),
+    "chunk-512-at-8192": lambda T, cfg, p, c, b: T.chunk_prefill_into_cache(
+        cfg, p, b["tok512"], b["row2"], b["row2"], c, b["row2"],
+        kv_view=8192, stat_rows=b["row2"] != 48),
+    "chunk-512-at-512-one-row":
+        lambda T, cfg, p, c, b: T.chunk_prefill_into_cache(
+            cfg, p, b["tok512x1"], b["row1"], b["row1"], c, b["row1"],
+            kv_view=512, stat_rows=b["row1"] != 48),
+    "prefill-128": lambda T, cfg, p, c, b: T.prefill_into_cache(
+        cfg, p, b["tok128"], b["row8"], c, b["row8"],
+        return_prompt_logprobs=True, stat_rows=b["row8"] != 48),
+}
+
+
+def _swa_compiled(chip, program, **small):
+    from p2p_llm_tunnel_tpu.models import transformer as T
+
+    cfg, params, cache = _swa(chip, **small)
+    return cfg, cache, jax.jit(
+        lambda p, c, b: SWA_PROGRAMS[program](T, cfg, p, c, b),
+        donate_argnums=(1,)).lower(params, cache, _swa_batch(chip)).compile()
+
+
+@pytest.mark.parametrize("program", sorted(SWA_PROGRAMS))
+def test_the_four_planes_are_written_where_they_lie(chip, program):
+    """Every serving program of ``mimo-v2-flash-ep16s`` at the cell's shapes
+    (the feed-forwards narrowed: they touch no plane): no plane-sized
+    ``copy`` around a write of any of the four planes (keys 192 wide are one
+    and a half lane tiles a head: a row is the KV heads side by side, 768 or
+    1,536 values, whole tiles), every plane written is the donated one, and
+    in chunk prefill, where the planes are no carry of the layer loops, no
+    loop body makes one."""
+    _, cache, compiled = _swa_compiled(
+        chip, program, ffn_dim=512, moe_ffn_dim=128, vocab_size=1024)
+    hlo = compiled.as_text()
+    for name, plane in cache.items():
+        copies, made = _plane_work(hlo, math.prod(plane.shape))
+        assert copies == [], name
+        if program.startswith("chunk"):
+            assert made == [], name
+    assert "while(" in hlo  # the four window layers are one loop to look into
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert aliased.count("alias") == len(cache)
+
+
+@pytest.mark.parametrize("program", ["decode-8192", "chunk-512-at-8192",
+                                     "prefill-128"])
+def test_the_mimo_share_fits_one_chip_at_its_stated_bytes(chip, program):
+    """``mimo-v2-flash-ep16s`` at the cell's size: the compiler holds the
+    four planes at their stated bytes to the byte (no width of 192 padded to
+    256, no head axis padded to a sublane tile: 2.66 GB, where a uniform
+    cache read under a mask would be 49 x 8192 x 30,720 B = 12.3 GB), and
+    weights, planes, the prefix pool of 2048 blocks and the program's own
+    temporaries are inside a v5e's 16 GB.  The routed products are Mosaic
+    kernels."""
+    cfg, cache, compiled = _swa_compiled(chip, program)
+    m = compiled.memory_analysis()
+    planes = sum(math.prod(v.shape) * 2 for v in cache.values())
+    assert planes == SWA_ROWS * (2 * 2560 * SWA_SEQ + 5 * 5120 * SWA_RING)
+    from p2p_llm_tunnel_tpu.models.transformer import init_params
+
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize for x in
+                  jax.tree.leaves(jax.eval_shape(
+                      lambda: init_params(cfg, jax.random.PRNGKey(0)))))
+    assert 6.85e9 < weights < 6.87e9
+    # (the batch's few integers are the rest of the arguments)
+    assert 0 <= m.argument_size_in_bytes - weights - planes < 2 ** 20
+    tiled = set(re.findall(
+        r"bf16\[[25],49,(?:8192|640),\d+\]\{3,2,1,0:T\(8,128\)\(2,1\)\}",
+        compiled.as_text()))
+    assert len(tiled) == 4, tiled
+    pool = 2048 * 16 * 30720
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes + pool)
+    assert held < 13.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
+    assert compiled.as_text().count("ragged-dot") >= 3
+
+
+# ---------------------------------------------------------------------------
 # --replicas: one engine per device
 # ---------------------------------------------------------------------------
 
