@@ -728,6 +728,24 @@ def kernels_child(rehearse: bool) -> None:
         )
         report(f"ragged_prefill_attention kv={kv or 'bf16'} block_q={block_q}",
                got, want, ulps)
+    # -- the rows kernel over planes of KV heads side by side (ISSUE 36): a
+    # decode step of the window + full family, its full layers on the kernel
+    swa = (get_config("tiny-swa-moe", flash_interpret=True) if rehearse else
+           get_config("mimo-v2-flash-ep16s", n_experts=16, layer_chips=1,
+                      moe_ffn_dim=256, ffn_dim=1024, vocab_size=1024))
+    swa_ref = dataclasses.replace(swa, flash=False)
+    assert decode_attention_branch(swa, None, view, None, seq) == "pallas-rows"
+    assert decode_attention_branch(swa_ref, None, view, None, seq) == "einsum"
+    swa_params = init_params(swa, jax.random.PRNGKey(SEED), jnp.bfloat16)
+    cache = {name: jnp.asarray(rng.standard_normal(leaf.shape, np.float32),
+                               leaf.dtype)
+             for name, leaf in init_kv_cache(swa, rows, seq).items()}
+    swa_toks = jnp.asarray(rng.integers(3, swa.vocab_size, (rows,)), jnp.int32)
+    logits, _ = run_decode(swa, swa_params, cache, swa_toks, pos, kv_view=seq)
+    want, _ = run_decode(swa_ref, swa_params, cache, swa_toks, pos, kv_view=seq)
+    report(f"decode_attention_rows planes of {swa.kv_heads_of('full')} x "
+           f"{swa.head_dim}/{swa.v_head_dim} seq={seq}", logits[:live],
+           want[:live])
     if failures:
         raise SystemExit(f"kernels outside tolerance: {failures}")
 

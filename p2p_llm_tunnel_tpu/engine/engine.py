@@ -42,6 +42,7 @@ from p2p_llm_tunnel_tpu.engine.tokenizer import ByteTokenizer, StreamDecoder, To
 from p2p_llm_tunnel_tpu.models.config import ModelConfig, get_config
 from p2p_llm_tunnel_tpu.models.transformer import (
     decode_attention_branch,
+    decode_branch_coverage,
     decode_kernel_decline,
     decode_step,
     init_kv_cache,
@@ -1836,6 +1837,8 @@ class InferenceEngine:
             return
         self._programs_ready.add(key)
         branch = self._attention_branch(kind, shape)
+        if kind == "decode":
+            branch = decode_branch_coverage(self.mcfg, branch)
         if branch not in self.attention_branches.setdefault(kind, []):
             self.attention_branches[kind].append(branch)
             global_metrics.set_info(
@@ -1882,9 +1885,9 @@ class InferenceEngine:
         models/mla.py; window rings beside full planes: models/swa.py) does
         not have yet is refused at start-up, by name, instead of served
         wrongly: its weights have no quantiser (experts: models/quant.py),
-        its layers no mesh rules (parallel/), and the Pallas kernels, the
-        ragged prefill and the speculative verify read one plane of KV
-        heads whose keys and values are equally wide."""
+        its layers no mesh rules (parallel/), and the Pallas kernels behind
+        options, the ragged prefill and the speculative verify read one
+        plane of KV heads whose keys and values are equally wide."""
         if self.mcfg.kv_lora_rank:
             what = "latent attention, routed experts"
         elif self.mcfg.attn_pattern is not None:

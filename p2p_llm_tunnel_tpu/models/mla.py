@@ -516,8 +516,9 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
 
 
 def decode_step(cfg, params, kv_cache, tokens, positions,
-                kv_view: Optional[int] = None):
-    """``transformer.decode_step`` for the latent planes, absorbed form; the
+                kv_view: Optional[int] = None, mesh=None):
+    """``transformer.decode_step`` for the latent planes, absorbed form
+    (``mesh`` is the families' signature: nothing here is gated by it); the
     planes are the carry of both layer scans and take one in-place row write
     a layer each (the ``kr`` row read first: the layer's pair-mate owns its
     other half).  Rows parked at ``positions >= S`` write nothing (the

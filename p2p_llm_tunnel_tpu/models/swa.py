@@ -53,6 +53,14 @@ parameters' type; the router scores the normed stream before that rounding
 (``models/mla.py``'s reasons hold here: a routed layer compares scores).
 Every program also returns what its routed layers counted (``moe.STATS``).
 
+Decode reads a full layer by what ``transformer.decode_attention_branch``
+answers (ISSUE 36): on the TPU, over plain bf16 planes whose rows are whole
+lane tiles, ``ops.pallas_decode_attention.decode_attention_rows`` takes the
+stacked planes where they lie and each row's blocks up to its own position
+(no layer sliced out, no view); elsewhere the einsum over the layer's
+``kv_view`` positions.  A window layer reads its whole ring by einsum under
+either, and chunk prefill keeps the einsum and its views.
+
 Int8 planes (``--kv-quant int8``) keep int8 values with one float32 scale a
 token, layer and KV head beside each plane (``"k_scale" [Lf, rows, S, Kf]``
 and so on): the benchmark's cache control, which no cell serves.
@@ -578,13 +586,19 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
 
 
 def decode_step(cfg, params, kv_cache, tokens, positions,
-                kv_view: Optional[int] = None):
+                kv_view: Optional[int] = None, mesh=None):
     """``transformer.decode_step`` for the two kinds of plane; the planes
     are the carry of the runs' scans and take one in-place row write a layer
-    each.  Only the full layers follow ``kv_view``: a window layer reads
-    its whole ring under the mask by position.  Rows parked at ``positions
-    >= S`` write nothing and count for nothing.  Returns (logits [B,V],
-    cache, stats)."""
+    each.  A window layer reads its whole ring under the mask by position.
+    A full layer reads by what ``decode_attention_branch`` answers:
+    ``"pallas-rows"`` (the TPU, plain bf16 planes) is one kernel over the
+    stacked planes where they lie that stops at each row's own position, so
+    no layer is sliced out and ``kv_view`` bounds nothing; the einsum reads
+    the layer's ``kv_view`` positions under the causal mask.  Rows parked at
+    ``positions >= S`` write nothing and count for nothing.  Returns (logits
+    [B,V], cache, stats)."""
+    from p2p_llm_tunnel_tpu.models.transformer import decode_attention_branch
+
     b = tokens.shape[0]
     s = kv_cache["k"].shape[2]
     ring = kv_cache["wk"].shape[2]
@@ -596,12 +610,23 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
     slot_ids = jnp.arange(b)
     live = positions < s
     counted = live[:, None]
-    masks = {
-        "full": window_mask(
-            pos2d, jnp.broadcast_to(jnp.arange(kv_view), (b, kv_view))),
-        "window": window_mask(pos2d, ring_positions(positions, ring),
-                              cfg.sliding_window),
-    }
+    use_rows = decode_attention_branch(
+        cfg, mesh, kv_view, "int8" if quant else None, s) == "pallas-rows"
+    masks = {"window": window_mask(pos2d, ring_positions(positions, ring),
+                                   cfg.sliding_window)}
+    if use_rows:
+        from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+            decode_attention_rows,
+            decode_rows_worklist,
+            rows_block,
+        )
+
+        # One work list a step, shared by the full layers.
+        block = rows_block(s, cfg.kv_heads_of("full"))
+        work = decode_rows_worklist(positions, s, block)
+    else:
+        masks["full"] = window_mask(
+            pos2d, jnp.broadcast_to(jnp.arange(kv_view), (b, kv_view)))
     at = {"full": positions,
           "window": _ring_slots(positions, live, ring)}
     extent = {"full": kv_view, "window": ring}
@@ -609,6 +634,7 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
     def layer(run, carry, blk, ai, ffn):
         x, cache = carry
         kind = run.attn
+        on_rows = use_rows and kind == "full"
         with jax.named_scope("attn"):
             q, k, v = _attn_inputs(cfg, kind, blk,
                                    _normed(cfg, x, blk, dtype), pos2d)
@@ -629,10 +655,19 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
                 if quant:
                     cache[name + "_scale"] = cache[
                         name + "_scale"].at[where].set(scale)
-            with jax.named_scope("kv_read"):
-                rows.append(_unpack(seen(name), seen(name + "_scale"), dtype)
-                            if quant else seen(name))
-        a = _attend(cfg, kind, blk, q, rows[0], rows[1], masks[kind])
+            if not on_rows:
+                with jax.named_scope("kv_read"):
+                    rows.append(
+                        _unpack(seen(name), seen(name + "_scale"), dtype)
+                        if quant else seen(name))
+        if on_rows:
+            with jax.named_scope("attn"), jax.named_scope("attn_full"):
+                a = decode_attention_rows(
+                    q[:, 0], cache["k"], cache["v"], ai, work, block=block,
+                    scale=cfg.query_scale or cfg.head_dim ** -0.5,
+                    interpret=cfg.flash_interpret).reshape(b, 1, -1)
+        else:
+            a = _attend(cfg, kind, blk, q, rows[0], rows[1], masks[kind])
         with jax.named_scope("attn"):
             x = x + mm(a, blk["wo"], cfg.act_quant)
         with jax.named_scope("ffn"):

@@ -329,7 +329,10 @@ def decode_kernel_decline(cfg: ModelConfig, mesh, kv_view: int):
       mesh XLA would all-gather the sharded q/KV onto every chip (the
       hazard prefill's flash_tp shard_map wrapper exists for — apply the
       same wrapper here before enabling);
-    - shapes must tile (view and head_dim % 128) unless interpreting."""
+    - shapes must tile (view and head_dim % 128) unless interpreting; in a
+      model with an ``attn_pattern`` the lane width is asked of a full
+      layer's ROWS (its KV heads side by side, models/swa.py), not of a
+      head."""
     backend = jax.default_backend()
     if not (backend == "tpu" or cfg.flash_interpret or cfg.flash_force):
         return f"backend {backend!r} is not tpu"
@@ -339,7 +342,19 @@ def decode_kernel_decline(cfg: ModelConfig, mesh, kv_view: int):
                 "mesh XLA would all-gather the sharded cache")
     if kv_view % 128:
         return f"kv view {kv_view} does not tile (% 128)"
-    if cfg.head_dim % 128 and not cfg.flash_interpret:
+    if cfg.flash_interpret:
+        return None
+    if cfg.attn_pattern is not None:
+        # Planes whose rows are a position's KV heads side by side
+        # (models/swa.py): a row has to be whole lane tiles; a head's own
+        # width need not be.
+        kv = cfg.kv_heads_of("full")
+        for what, width in (("key", kv * cfg.head_dim),
+                            ("value", kv * cfg.v_head_dim)):
+            if width % 128:
+                return (f"a full layer's {what} row of {width} does not "
+                        "tile (% 128)")
+    elif cfg.head_dim % 128:
         return f"head_dim {cfg.head_dim} does not tile (% 128)"
     return None
 
@@ -354,20 +369,34 @@ def decode_attention_branch(cfg: ModelConfig, mesh, kv_view: int,
     both route to the s-grid family), else ``"pallas-rows"`` wherever it
     can run — by what the code can observe, no option: the gate below
     passes for the whole cache, which is what that kernel reads (the view
-    bounds nothing there), and the cache is the plain plane of KV heads
-    (the int8 and int4 caches and the latent family keep the einsum) —
-    else ``"einsum"``.  ``cfg.flash`` off is the einsum everywhere, as in
-    prefill (the reference a kernel is held against)."""
-    if decode_kernel_decline(cfg, mesh, kv_view) is None:
+    bounds nothing there), and the cache is plain planes of KV heads (the
+    int8 and int4 caches and the latent family keep the einsum) — else
+    ``"einsum"``.  ``cfg.flash`` off is the einsum everywhere, as in
+    prefill (the reference a kernel is held against).
+
+    In a model with an ``attn_pattern`` (models/swa.py) the answer is the
+    FULL layers': their planes are what follows a view.  A window layer
+    reads its whole ring by einsum under either answer
+    (:func:`decode_branch_coverage`)."""
+    if (cfg.attn_pattern is None
+            and decode_kernel_decline(cfg, mesh, kv_view) is None):
         if cfg.fused_decode_layer:
             return "pallas-fused-decode-layer"
         if cfg.flash_decode or cfg.flash_sgrid:
             return "pallas-sgrid"
     if (cfg.flash and kv_quant is None and not cfg.kv_lora_rank
-            and cfg.attn_pattern is None
             and decode_kernel_decline(cfg, mesh, max_seq or kv_view) is None):
         return "pallas-rows"
     return "einsum"
+
+
+def decode_branch_coverage(cfg: ModelConfig, branch: str) -> str:
+    """``branch`` as /healthz ``config.attention.decode`` prints it: with
+    the layers it covers where it does not cover all (a model with window
+    layers: the branch is its full layers')."""
+    if cfg.attn_pattern is None or branch == "einsum":
+        return branch
+    return f"{branch} (full layers; window layers: einsum over the ring)"
 
 
 def spec_attention_branch(cfg: ModelConfig, mesh, kv_view: int) -> str:
@@ -1179,7 +1208,7 @@ def decode_step(
     family = _family_module(cfg)
     if family is not None:
         out = family.decode_step(cfg, params, kv_cache, tokens, positions,
-                                 kv_view=kv_view)
+                                 kv_view=kv_view, mesh=mesh)
         return out if with_stats else out[:-1]
     quant_mode = kv_cache_quant_mode(kv_cache)
     quant = quant_mode is not None

@@ -3,8 +3,10 @@
 The decode analog of ops/pallas_attention.py (VERDICT r3 item 4).  The
 default read of the plain bf16 cache on the TPU is ``decode_attention_rows``
 (ISSUE 33, at the end of this file: one invocation a layer over the stacked
-cache, a software pipeline over each live row's blocks, no view).  Behind
-options, TWO older bodies share the online-softmax math:
+cache, a software pipeline over each live row's blocks, no view; since ISSUE
+36 also over planes whose rows hold a position's KV heads side by side, keys
+and values not equally wide: models/swa.py's full layers).  Behind options,
+TWO older bodies share the online-softmax math:
 
 - ``flash_decode_attention_sgrid`` (r5, VERDICT r4 item 2): the sequence
   axis joins the grid — program (slot, s-block) stages ONE
@@ -1350,6 +1352,7 @@ def _decode_rows_kernel(
     depth: int,
     parts: int,
     kv_heads: int,
+    side_by_side: bool = False,
 ):
     """One invocation a layer: a software pipeline over the step's work
     list.  Each item is one ``[BS*K, D]`` block of one row — positions
@@ -1359,12 +1362,25 @@ def _decode_rows_kernel(
     block sums only a head's own.  The MXU's cost is set by the block
     (each key and value tile passes through once), not by the 4 or 7 query
     rows a kv-head has, so the wasted columns cost nothing and the block
-    needs no de-interleaving."""
+    needs no de-interleaving.
+
+    ``side_by_side`` is the other way a cache lies (ISSUE 36: planes
+    ``[L, B, S, K*Dk]`` and ``[L, B, S, K*Dv]``, a position's KV heads in
+    its columns): the same work list, ring and softmax over another item.
+    A block is ``[BS, K*Dk]`` keys and ``[BS, K*Dv]`` values; ``q_ref`` is
+    ``[B, H, K*Dk]`` with a head's query in its own KV head's columns and
+    zeros elsewhere, so ONE product scores every head against the block
+    with no head mask (the other heads' keys meet zeros), and a KV head's
+    value columns — a static slice of whole lane tiles — take only its own
+    query heads' weights.  ``o_ref`` is ``[B, H, Dv]``."""
     layer = layer_sref[0]
     window = layer_sref[1]
     n_work = work_sref[0]
     rows_per_blk = kbuf.shape[1]
-    b, h, d = q_ref.shape
+    b, h = q_ref.shape[:2]
+    d_out = o_ref.shape[2]
+    # buffer rows a cache position takes
+    per_pos = rows_per_blk // block_s
     pos_at = work_sref.shape[0] - b
 
     def whole_div(x, n):
@@ -1375,10 +1391,13 @@ def _decode_rows_kernel(
     # A block column's position inside the block and its kv-head; a query
     # head's kv-head.
     col = jax.lax.broadcasted_iota(jnp.int32, (1, rows_per_blk), 1)
-    col_off = whole_div(col, kv_heads)
-    col_head = col - col_off * kv_heads
-    row_head = whole_div(
-        jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0), h // kv_heads)
+    if side_by_side:
+        col_off = col
+    else:
+        col_off = whole_div(col, kv_heads)
+        col_head = col - col_off * kv_heads
+        row_head = whole_div(
+            jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0), h // kv_heads)
 
     def item(w):
         """Work item ``w``: its row, its block's index, the row's position."""
@@ -1396,7 +1415,7 @@ def _decode_rows_kernel(
         start = pl.multiple_of(blk * rows_per_blk, rows_per_blk)
         part = rows_per_blk // parts
         needed = jnp.where(blk == pos // block_s,
-                           (pos % block_s) * kv_heads // part + 1, parts)
+                           (pos % block_s) * per_pos // part + 1, parts)
         for n in range(1, parts + 1):
             @pl.when(needed == n)
             def _(n=n):
@@ -1410,6 +1429,23 @@ def _decode_rows_kernel(
         @pl.when(w < n_work)
         def _():
             copies(w, w % depth, lambda c: c.start())
+
+    def weighted(p, v):
+        """Float32 weights ``[R, N]`` over values ``[N, W]`` -> float32."""
+        if v.dtype == jnp.float32:
+            return jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        # The weights in two bf16 halves, stacked on the rows: 2R rows cost
+        # the MXU what R do, and the sum is the float32 weight's product to
+        # 2^-16.
+        hi = p.astype(v.dtype)
+        lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+        both = jax.lax.dot_general(
+            jnp.concatenate([hi, lo], axis=0), v,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return both[:p.shape[0]] + both[p.shape[0]:]
 
     # A parked row is in no item: its output is zeros, not what the buffer
     # held.
@@ -1443,27 +1479,22 @@ def _decode_rows_kernel(
             s = softcap * jnp.tanh(s / softcap)
         k_pos = blk * block_s + col_off  # [1, BS*K]
         live = (k_pos <= pos) & ((pos - k_pos) < window)
-        s = jnp.where(live & (col_head == row_head), s, _NEG_INF)
+        if not side_by_side:
+            live = live & (col_head == row_head)
+        s = jnp.where(live, s, _NEG_INF)
 
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         corr = jnp.where(m_prev <= _NEG_INF, 0.0, jnp.exp(m_prev - m_new))
         p = jnp.where(s <= _NEG_INF, 0.0, jnp.exp(s - m_new))
         l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-        if v.dtype == jnp.float32:
-            pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        if side_by_side:
+            g = h // kv_heads
+            pv = jnp.concatenate([
+                weighted(p[n * g:(n + 1) * g],
+                         v[:, n * d_out:(n + 1) * d_out])
+                for n in range(kv_heads)], axis=0)
         else:
-            # The weights in two bf16 halves, stacked on the rows: 2H rows
-            # cost the MXU what H do, and the sum is the float32 weight's
-            # product to 2^-16.
-            hi = p.astype(v.dtype)
-            lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
-            both = jax.lax.dot_general(
-                jnp.concatenate([hi, lo], axis=0), v,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            pv = both[:h] + both[h:]
+            pv = weighted(p, v)
         acc = acc * corr + pv
 
         @pl.when(blk == pos // block_s)
@@ -1475,14 +1506,14 @@ def _decode_rows_kernel(
     jax.lax.fori_loop(0, n_work, body, (
         jnp.full((h, 1), _NEG_INF, jnp.float32),
         jnp.zeros((h, 1), jnp.float32),
-        jnp.zeros((h, d), jnp.float32),
+        jnp.zeros((h, d_out), jnp.float32),
     ))
 
 
 def decode_attention_rows(
     q: jnp.ndarray,        # [B, H, D]
-    k_cache: jnp.ndarray,  # [L, B, S, K, D] the stacked cache, row written
-    v_cache: jnp.ndarray,
+    k_cache: jnp.ndarray,  # [L, B, S, K, D] the stacked cache, row written,
+    v_cache: jnp.ndarray,  # or planes [L, B, S, K*D] and [L, B, S, K*Dv]
     layer_idx,               # int32 scalar (traced: the scan's layer index)
     work: jnp.ndarray,       # decode_rows_worklist(positions, S, block)
     *,
@@ -1499,17 +1530,32 @@ def decode_attention_rows(
     already holds this step's own row at the positions the work list was
     made from.  Same mathematics as the einsum: the cache's own operands
     into float32 scores, float32 softmax and accumulation.  A window masks;
-    it does not yet bound the blocks fetched from below.  Returns
-    ``[B, H, D]``."""
-    l, b, s, kh, d = k_cache.shape
-    h = q.shape[1]
+    it does not yet bound the blocks fetched from below.
+
+    The layout is the cache's own and read off its shape: five axes are
+    ``[L, B, S, K, D]``; four are planes whose rows hold a position's KV
+    heads side by side, keys ``K * D`` wide (``D`` the query's) and values
+    ``K * Dv``, which need not be as wide.  Returns ``[B, H, D]``, of the
+    planes ``[B, H, Dv]``."""
+    side_by_side = k_cache.ndim == 4
+    h, d = q.shape[1:]
+    if side_by_side:
+        l, b, s, width = k_cache.shape
+        kh = width // d
+        dv = v_cache.shape[-1] // kh
+        per_pos = 1
+        # A head's query over its own KV head's columns, zeros elsewhere.
+        own = jnp.eye(kh, dtype=q.dtype)[None, :, None, :, None]
+        q = (q.reshape(b, kh, h // kh, 1, d) * own).reshape(b, h, width)
+    else:
+        l, b, s, kh, d = k_cache.shape
+        dv, per_pos = d, kh
     if s % ROWS_BLOCK or s % block:
         raise ValueError(f"rows decode kernel needs S % {ROWS_BLOCK} == 0 "
                          f"and whole blocks of {block}, got {s}")
     if scale is None:
         scale = d**-0.5
-    bs = block
-    cols = bs * kh
+    rows = block * per_pos
     layer = jnp.stack([
         jnp.asarray(layer_idx, jnp.int32),
         jnp.asarray(s + 1 if window is None else window, jnp.int32),
@@ -1517,26 +1563,31 @@ def decode_attention_rows(
 
     kernel = functools.partial(
         _decode_rows_kernel,
-        scale=scale, softcap=softcap, block_s=bs, depth=ROWS_DEPTH,
-        parts=ROWS_PARTS, kv_heads=kh,
+        scale=scale, softcap=softcap, block_s=block, depth=ROWS_DEPTH,
+        parts=ROWS_PARTS, kv_heads=kh, side_by_side=side_by_side,
     )
+    if not side_by_side:
+        # (a bitcast: positions outermost, KV heads inside, as they lie)
+        k_cache = k_cache.reshape(l, b, s * kh, d)
+        v_cache = v_cache.reshape(l, b, s * kh, d)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
             in_specs=[vmem, hbm, hbm],
             out_specs=vmem,
             scratch_shapes=[
-                pltpu.VMEM((ROWS_DEPTH, cols, d), k_cache.dtype),
-                pltpu.VMEM((ROWS_DEPTH, cols, d), v_cache.dtype),
+                pltpu.VMEM((ROWS_DEPTH, rows, k_cache.shape[-1]),
+                           k_cache.dtype),
+                pltpu.VMEM((ROWS_DEPTH, rows, v_cache.shape[-1]),
+                           v_cache.dtype),
                 pltpu.SemaphoreType.DMA((2, ROWS_DEPTH)),
             ],
         ),
         interpret=interpret,
         name=ROWS_KERNEL,
-    )(layer, work, q,
-      k_cache.reshape(l, b, s * kh, d), v_cache.reshape(l, b, s * kh, d))
+    )(layer, work, q, k_cache, v_cache)

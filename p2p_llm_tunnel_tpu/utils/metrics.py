@@ -34,8 +34,9 @@ METRICS_CATALOG: Dict[str, str] = {
     "engine_decode_steps_total": "decode steps dispatched (counter)",
     "engine_decode_kernel_steps_total": (
         "of those, the steps whose attention ran as a Pallas kernel (the "
-        "record's attn is not einsum); over engine_decode_steps_total it is "
-        "the share of decode the kernel engages in (counter)"
+        "record's attn is not einsum; in a model with window layers: its "
+        "full layers' attention); over engine_decode_steps_total it is the "
+        "share of decode the kernel engages in (counter)"
     ),
     "engine_decode_row_steps_total": (
         "live rows x steps over every decode burst dispatched (counter)"

@@ -126,7 +126,10 @@ SPAN_CATALOG: Dict[str, str] = {
         "(engine-scope dispatch record; overlaps its successor via "
         "pipelining; attrs seq, program, view, steps, live_rows, slots, "
         "attn = the attention branch the program ran: einsum or a kernel's "
-        "name; under pallas-rows view is max_seq in every record)"
+        "name, in a model with window layers the branch of its full layers "
+        "(a window layer reads its ring by einsum under either); under "
+        "pallas-rows view is max_seq in every record, under einsum the "
+        "bucket sliced)"
     ),
     "engine.pool_copy": (
         "one batched prefix-pool copy dispatch, cache_to_pool or "

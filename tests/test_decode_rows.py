@@ -29,7 +29,11 @@ from p2p_llm_tunnel_tpu.models.transformer import (
     init_kv_cache,
     init_params,
 )
-from p2p_llm_tunnel_tpu.ops.attention import cached_attention
+from p2p_llm_tunnel_tpu.ops.attention import (
+    cached_attention,
+    masked_attention,
+    window_mask,
+)
 from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
     ROWS_BLOCK,
     decode_attention_rows,
@@ -139,6 +143,94 @@ def test_rows_take_a_traced_window_and_layer_inside_a_scan():
         np.testing.assert_allclose(np.asarray(got[layer])[live],
                                    np.asarray(want)[live],
                                    rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the other layout: planes whose rows hold the KV heads side by side
+# (ISSUE 36; models/swa.py's full layers, keys and values not equally wide)
+# ---------------------------------------------------------------------------
+
+def _planes(h, kh, dk, dv, dtype, layers=2, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    b = len(POSITIONS)
+    q = jax.random.normal(ks[0], (b, h, dk), dtype)
+    k = jax.random.normal(ks[1], (layers, b, S, kh * dk), dtype)
+    v = jax.random.normal(ks[2], (layers, b, S, kh * dv), dtype)
+    return q, k, v, jnp.asarray(POSITIONS, jnp.int32)
+
+
+def _plane_oracle(q, k, v, pos, layer, **kw):
+    """``masked_attention`` over the layer's plane, causal."""
+    b, h, dk = q.shape
+    kh = k.shape[-1] // dk
+    mask = window_mask(pos[:, None], jnp.broadcast_to(jnp.arange(S), (b, S)),
+                       kw.get("window"))
+    return masked_attention(
+        q[:, None], k[layer].reshape(b, S, kh, dk),
+        v[layer].reshape(b, S, kh, -1), mask,
+        kw.get("scale") or dk ** -0.5)[:, 0]
+
+
+@pytest.mark.parametrize("h,kh,dk,dv,dtype,tol", [
+    (64, 4, 192, 128, jnp.float32, 2e-5),   # the cell's heads and widths
+    (64, 4, 192, 128, jnp.bfloat16, 2e-2),  # and its precision
+    (4, 1, 24, 16, jnp.float32, 2e-5),      # tiny-swa-moe's full layers
+    (8, 2, 24, 16, jnp.float32, 2e-5),
+    (8, 8, 16, 32, jnp.bfloat16, 2e-2),     # values wider than keys, MHA
+])
+def test_rows_match_the_einsum_on_planes_of_heads_side_by_side(
+        h, kh, dk, dv, dtype, tol):
+    """Keys ``[L,B,S,K*Dk]`` and values ``[L,B,S,K*Dv]`` with ``Dk != Dv``,
+    read off the planes' own shapes: positions 0, a block's last and the next
+    block's first, S - 1, parked rows (no work: zeros), ragged ones; the
+    layer read is the one asked; the answer is a head's ``Dv`` columns."""
+    q, k, v, pos = _planes(h, kh, dk, dv, dtype)
+    live = np.asarray(POSITIONS) < S
+    for layer, block in ((0, ROWS_BLOCK), (1, rows_block(S, kh))):
+        got = decode_attention_rows(
+            q, k, v, jnp.int32(layer), decode_rows_worklist(pos, S, block),
+            block=block, interpret=True)
+        assert got.shape == (len(POSITIONS), h, dv) and got.dtype == dtype
+        got = np.asarray(got, np.float32)
+        want = np.asarray(_plane_oracle(q, k, v, pos, layer), np.float32)
+        np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+        assert not got[~live].any()
+
+
+def test_rows_on_planes_take_a_scale_a_window_and_a_traced_layer_in_a_scan():
+    q, k, v, pos = _planes(8, 2, 24, 16, jnp.float32, layers=3, seed=5)
+    block = rows_block(S, 2)
+    work = decode_rows_worklist(pos, S, block)
+
+    def body(_, idx):
+        return None, decode_attention_rows(
+            q, k, v, idx, work, block=block, scale=0.25, window=130,
+            interpret=True)
+
+    _, got = jax.lax.scan(body, None, jnp.arange(3))
+    live = np.asarray(POSITIONS) < S
+    for layer in range(3):
+        want = _plane_oracle(q, k, v, pos, layer, scale=0.25, window=130)
+        np.testing.assert_allclose(np.asarray(got[layer])[live],
+                                   np.asarray(want)[live],
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_a_row_of_the_planes_is_read_no_further_than_its_position():
+    """What lies past a row's own position is in no item: poison there (and
+    in every other layer) changes nothing, to the bit."""
+    q, k, v, pos = _planes(8, 2, 24, 16, jnp.float32, layers=2, seed=9)
+    block = rows_block(S, 2)
+    work = decode_rows_worklist(pos, S, block)
+    past = (jnp.arange(S)[None, :] > pos[:, None])[None, :, :, None]
+    other = (jnp.arange(2) != 1)[:, None, None, None]
+    bad = past | other
+    got, poisoned = (
+        decode_attention_rows(q, a, b_, jnp.int32(1), work, block=block,
+                              interpret=True)
+        for a, b_ in ((k, v), (jnp.where(bad, jnp.nan, k),
+                               jnp.where(bad, 3e38, v))))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(poisoned))
 
 
 def test_rows_refuse_a_sequence_that_does_not_tile():
@@ -288,8 +380,7 @@ def test_spec_programs_keep_their_views_beside_the_rows_kernel():
         {128, 256, 512}
 
 
-@pytest.fixture(scope="module")
-def kernel_run():
+def _run_both(kernel_cfg, **over):
     """A run on the kernel path whose rows cross the old bucket edges at 128
     and 256: what was dispatched, the records, the counters' growth, and the
     same prompts' tokens on the einsum path."""
@@ -305,7 +396,7 @@ def kernel_run():
             ids, max_new_tokens=40, stop_ids=())]
 
     async def run(mcfg, trace):
-        eng = _engine(mcfg)
+        eng = _engine(mcfg, **over)
         await eng.start()
         await eng.warmup()
         planned = {_program_key(kind, shape)
@@ -327,9 +418,14 @@ def kernel_run():
             await eng.stop()
         return toks, grown, records, ready, planned, branches
 
-    kernel = asyncio.run(run(INTERP, True))
+    kernel = asyncio.run(run(kernel_cfg, True))
     einsum = asyncio.run(run(None, True))
     return kernel, einsum
+
+
+@pytest.fixture(scope="module")
+def kernel_run():
+    return _run_both(INTERP)
 
 
 def test_no_program_outside_the_plan_runs_across_the_old_bucket_edges(
@@ -360,6 +456,96 @@ def test_records_carry_the_branch_and_the_counter_is_held_to_them(kernel_run):
     assert kernel_run[0][1]["engine_decode_kernel_steps_total"] == \
         kernel_run[0][1]["engine_decode_steps_total"]
     assert kernel_run[1][1]["engine_decode_kernel_steps_total"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the family with window rings beside full planes (ISSUE 36): its full
+# layers on the kernel, its plan of one decode entry a step count
+# ---------------------------------------------------------------------------
+
+SWA_INTERP = get_config("tiny-swa-moe", flash_interpret=True,
+                        vocab_size=ByteTokenizer().vocab_size)
+
+
+@pytest.fixture(scope="module")
+def swa_run():
+    return _run_both(SWA_INTERP, model="tiny-swa-moe")
+
+
+def test_the_window_and_full_familys_plan_is_one_decode_entry_a_step_count():
+    eng = _engine(SWA_INTERP, model="tiny-swa-moe")
+    assert eng._decode_reads_rows()
+    assert _decode_entries(eng) == [(512, 2), (512, 4)]
+    views = {shape[2] for kind, shape in eng.warmup_plan() if kind == "chunk"}
+    assert views == {128, 256, 512}  # chunk prefill reads by einsum
+    rest = [e for e in eng.warmup_plan() if e[0] != "decode"]
+    einsum = _engine(model="tiny-swa-moe")
+    assert rest == [e for e in einsum.warmup_plan() if e[0] != "decode"]
+    assert _decode_entries(einsum) == [
+        (v, k) for v in (128, 256, 512) for k in (2, 4)]
+
+
+def test_the_familys_kernel_path_emits_the_einsum_paths_tokens(swa_run):
+    (toks, *_), (want, *_) = swa_run
+    assert all(len(t) == 40 for t in toks)
+    assert toks == want
+
+
+def test_the_family_runs_no_program_outside_its_plan(swa_run):
+    (_, grown, records, ready, planned, branches), (
+        _, _, einsum_records, _, _, einsum_branches) = swa_run
+    assert grown["engine_cold_compiles_total"] == 0
+    assert {k for k in ready if k.startswith("decode")} <= planned
+    assert len({k for k in ready if k.startswith("decode")}) == 2
+    # rows passed 128 and 256 while decoding: the old ladder's edges
+    assert {r.attrs["view"] for r in records} == {512}
+    assert len({r.attrs["view"] for r in einsum_records}) > 1  # the ladder
+    # /healthz says which layers the kernel covers
+    assert branches["decode"] == [
+        "pallas-rows (full layers; window layers: einsum over the ring)"]
+    assert einsum_branches["decode"] == ["einsum"]
+
+
+def test_the_familys_records_and_counter_say_the_kernel_engaged(swa_run):
+    for (_t, grown, records, *_), branch in zip(
+            swa_run, ("pallas-rows", "einsum")):
+        assert records and {r.attrs["attn"] for r in records} == {branch}
+        assert sum(r.attrs["steps"] for r in records) == \
+            grown["engine_decode_steps_total"] > 0
+    kernel, einsum = (run[1] for run in swa_run)
+    assert kernel["engine_decode_kernel_steps_total"] == \
+        kernel["engine_decode_steps_total"]
+    assert einsum["engine_decode_kernel_steps_total"] == 0
+
+
+def test_swa_decode_step_on_the_kernel_agrees_with_the_einsum():
+    """Whole ``swa.decode_step``, float32, over steps that cross a block
+    edge, a row at a block's last position and a parked one: logits, both
+    kinds of plane and the routed layers' counts."""
+    cfg = replace(SWA_INTERP, flash_interpret=False)
+    params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    toks = jnp.asarray([3, 5, 7, 11], jnp.int32)
+    pos = jnp.asarray([0, 126, 255, 256], jnp.int32)  # the last: parked
+    outs = {}
+    for label, c in (("einsum", cfg), ("rows", SWA_INTERP)):
+        cache = init_kv_cache(cfg, 4, 256, jnp.float32)
+        t, p, seq = toks, pos, []
+        for _ in range(3):
+            logits, cache, stats = decode_step(c, params, cache, t, p,
+                                               kv_view=256, with_stats=True)
+            seq.append((np.asarray(logits), np.asarray(stats)))
+            t = jnp.argmax(logits, -1).astype(jnp.int32)
+            p = p + 1
+        outs[label] = seq, {k: np.asarray(v) for k, v in cache.items()}
+    for step, ((got, got_stats), (want, want_stats)) in enumerate(zip(
+            outs["rows"][0], outs["einsum"][0])):
+        rows = 3 if step == 0 else 2  # the third row parks itself at 256
+        np.testing.assert_allclose(got[:rows], want[:rows],
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_array_equal(got_stats, want_stats)
+    for name, plane in outs["rows"][1].items():
+        np.testing.assert_allclose(plane[:, :2], outs["einsum"][1][name][:, :2],
+                                   rtol=2e-4, atol=2e-5)
 
 
 def test_healthz_device_section_survives_a_dispatch_in_flight():

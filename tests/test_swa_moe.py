@@ -535,18 +535,85 @@ def test_what_the_family_lacks_is_refused_at_start_up(case):
         _engine("tiny-swa-moe", **REFUSED[case])
 
 
-def test_decode_keeps_the_einsum_and_its_views():
+def _two_chips(cpu_devices):
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(cpu_devices[:2]).reshape(1, 2), ("dp", "tp"))
+
+
+#: (what the code can observe) -> the full layers' decode read (ISSUE 36).
+#: model, config fields, KV quant, a tp mesh?, the answer.
+BRANCHES = {
+    "bf16-planes-interpreting":
+        ("tiny-swa-moe", dict(flash_interpret=True), None, False,
+         "pallas-rows"),
+    "the-cells-planes-on-a-tpu-backend":  # rows of 768 and 512: whole tiles
+        ("mimo-v2-flash-ep16s", dict(flash_force=True), None, False,
+         "pallas-rows"),
+    "int8-planes":
+        ("tiny-swa-moe", dict(flash_interpret=True), "int8", False, "einsum"),
+    "a-cpu-backend":
+        ("mimo-v2-flash-ep16s", {}, None, False, "einsum"),
+    "a-tp-mesh":
+        ("tiny-swa-moe", dict(flash_interpret=True), None, True, "einsum"),
+    "a-key-row-that-is-no-whole-lane-tile":  # 1 x 192; the values' 128 is
+        ("mimo-v2-flash-ep16s", dict(flash_force=True, n_kv_heads=1), None,
+         False, "einsum"),
+    "a-value-row-that-is-no-whole-lane-tile":  # 4 x 192 = 768, 4 x 80 = 320
+        ("mimo-v2-flash-ep16s", dict(flash_force=True, v_head_dim=80), None,
+         False, "einsum"),
+    "the-tiny-presets-rows-on-a-tpu-backend":  # 24 and 16 wide
+        ("tiny-swa-moe", dict(flash_force=True), None, False, "einsum"),
+    "the-reference":
+        ("tiny-swa-moe", dict(flash_interpret=True, flash=False), None,
+         False, "einsum"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BRANCHES))
+def test_the_branch_is_decided_by_what_the_code_observes(case, cpu_devices):
+    """No flag and no model name: the backend, the mesh, the planes' type
+    and a row's width decide whether a full layer's decode read is the rows
+    kernel; the plan follows (one decode entry a step count at ``max_seq``,
+    or the view ladder), and so does what ``decode_step`` traces.  Whole
+    prompts and chunks keep the einsum either way."""
+    from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
     from p2p_llm_tunnel_tpu.models.transformer import (
         decode_attention_branch,
+        decode_branch_coverage,
         prefill_attention_branch,
     )
+    from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import ROWS_KERNEL
 
-    cfg = replace(get_config("mimo-v2-flash-ep16s"), flash_force=True)
-    assert decode_attention_branch(cfg, None, 8192, None, 8192) == "einsum"
+    name, fields, kv, tp, want = BRANCHES[case]
+    cfg = get_config(name, **fields)
+    mesh = _two_chips(cpu_devices) if tp else None
+    seq = 8192 if name.startswith("mimo") else 512
+    assert decode_attention_branch(cfg, mesh, 128, kv, seq) == want
     assert prefill_attention_branch(cfg, None, 512) == "einsum"
-    eng = _engine()
-    assert not eng._decode_reads_rows()
-    assert eng._attention_branch("decode", (128, 2)) == "einsum"
+    covers = decode_branch_coverage(cfg, want)
+    assert covers.startswith(want) and ("window layers" in covers) == (
+        want != "einsum")
+    if name.startswith("mimo"):
+        return  # the share at its size is tests/test_tpu_compile.py's
+    # what decode_step traces
+    params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    cache = init_kv_cache(cfg, 2, seq, jnp.float32, quant=kv)
+    row = jnp.zeros((2,), jnp.int32)
+    text = str(jax.make_jaxpr(lambda p, c: decode_step(
+        cfg, p, c, row, row, kv_view=128, mesh=mesh))(params, cache))
+    assert (ROWS_KERNEL in text) == (want == "pallas-rows")
+    if tp:
+        return  # the engine refuses --tp for this family at start-up
+    # the plan
+    eng = InferenceEngine(model_cfg=cfg, engine_cfg=EngineConfig(
+        model=name, num_slots=2, max_seq=seq, dtype="float32", decode_steps=4,
+        decode_steps_eager=2, kv_quant=kv or "none"))
+    entries = [shape for kind, shape in eng.warmup_plan() if kind == "decode"]
+    views = [seq] if want == "pallas-rows" else [128, 256, 512]
+    assert eng._decode_reads_rows() == (want == "pallas-rows")
+    assert entries == [(v, k) for v in views for k in (2, 4)]
+    assert eng._attention_branch("decode", (128, 2)) == want
 
 
 def test_healthz_names_both_kinds_of_plane_and_a_slots_bytes():

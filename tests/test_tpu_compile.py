@@ -18,6 +18,7 @@ from __future__ import annotations
 import math
 import os
 import re
+from dataclasses import replace
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
@@ -333,7 +334,38 @@ def test_rows_decode_compiles_for_v5e(chip, heads, kv_heads):
     assert copies == []
 
 
-@pytest.mark.parametrize("model", ["mistral-7b", "qwen2-7b"])
+def test_rows_decode_compiles_for_planes_of_heads_side_by_side(chip):
+    """The same kernel over the other layout (ISSUE 36): mimo-v2-flash's
+    full planes at the cell's size, ``k [2,49,8192,768]`` and ``v
+    [2,49,8192,512]`` (4 KV heads of 192 / 128 side by side, 64 query
+    heads): one Mosaic kernel that takes both planes as they lie, and the
+    answer is a head's 128 value columns."""
+    k = ((2, SWA_ROWS, SWA_SEQ, 4 * 192), jnp.bfloat16)
+    v = ((2, SWA_ROWS, SWA_SEQ, 4 * 128), jnp.bfloat16)
+    block = rows_block(SWA_SEQ, 4)
+    assert block == 256
+
+    def fn(q, k, v, pos, layer):
+        return decode_attention_rows(
+            q, k, v, layer, decode_rows_worklist(pos, SWA_SEQ, block),
+            block=block)
+
+    args = [
+        jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+            ((SWA_ROWS, 64, 192), jnp.bfloat16), k, v,
+            ((SWA_ROWS,), jnp.int32), ((), jnp.int32))
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert f"bf16[{SWA_ROWS},64,128]" in hlo
+    for plane in (k, v):
+        copies, made = _plane_work(hlo, math.prod(plane[0]))
+        assert copies == [] and made == []
+
+
+@pytest.mark.parametrize("model", ["mistral-7b", "qwen2-7b",
+                                   "mimo-v2-flash-ep16s"])
 def test_the_shipped_decode_program_slices_no_plane(chip, model):
     """``decode_step`` as a TPU backend runs it at the cells' shapes (33
     rows x 1024 of bf16 cache, 8 and 4 KV heads; FFN and vocabulary cut,
@@ -341,15 +373,16 @@ def test_the_shipped_decode_program_slices_no_plane(chip, model):
     no ``dynamic-slice`` of a layer's ``[1,33,S,K,D]`` plane left (the two
     copies that were 16.5 % of mistral's decode-closed window, PERF.md
     section 6), no plane-sized copy, and the cache written is the donated
-    one."""
-    from dataclasses import replace
-
+    one.  The mimo share (ISSUE 36) has two kinds of plane and sizes of its
+    own: :func:`_mimo_decode_slices_no_full_plane`."""
     from p2p_llm_tunnel_tpu.models.config import get_config
     from p2p_llm_tunnel_tpu.models.transformer import (
         decode_attention_branch,
         decode_step,
     )
 
+    if get_config(model).attn_pattern is not None:
+        return _mimo_decode_slices_no_full_plane(chip)
     cfg = replace(get_config(model, ffn_dim=512, vocab_size=1024),
                   flash_force=True)  # the branch a TPU backend takes
     assert decode_attention_branch(cfg, None, MAX_SEQ) == "pallas-rows"
@@ -533,6 +566,10 @@ SWA_PROGRAMS = {
         cfg, p, c, b["row49"], b["row49"], kv_view=8192, with_stats=True),
     "decode-1024": lambda T, cfg, p, c, b: T.decode_step(
         cfg, p, c, b["row49"], b["row49"], kv_view=1024, with_stats=True),
+    # as a TPU backend runs it (ISSUE 36): the full layers on the rows kernel
+    "decode-on-the-chip": lambda T, cfg, p, c, b: T.decode_step(
+        replace(cfg, flash_force=True), p, c, b["row49"], b["row49"],
+        kv_view=8192, with_stats=True),
     "chunk-512-at-8192": lambda T, cfg, p, c, b: T.chunk_prefill_into_cache(
         cfg, p, b["tok512"], b["row2"], b["row2"], c, b["row2"],
         kv_view=8192, stat_rows=b["row2"] != 48),
@@ -577,8 +614,40 @@ def test_the_four_planes_are_written_where_they_lie(chip, program):
     assert aliased.count("alias") == len(cache)
 
 
-@pytest.mark.parametrize("program", ["decode-8192", "chunk-512-at-8192",
-                                     "prefill-128"])
+def _mimo_decode_slices_no_full_plane(chip):
+    """``decode_step`` as a TPU backend runs it at the cell's shapes (ISSUE
+    36; the feed-forwards narrowed): the rows kernel once in each of the two
+    runs that hold a full layer, and no ``[1,49,8192,768]`` or ``[..,512]``
+    left, which as a ``dynamic-slice`` and a ``copy`` of each were the eight
+    largest operations of the cell's window (PERF.md section 6, PR 36).
+    The window layers' ring is still sliced: ``[1,49,640,*]``."""
+    from p2p_llm_tunnel_tpu.models.transformer import decode_attention_branch
+
+    cfg, cache, compiled = _swa_compiled(
+        chip, "decode-on-the-chip", ffn_dim=512, moe_ffn_dim=128,
+        vocab_size=1024)
+    assert decode_attention_branch(
+        replace(cfg, flash_force=True), None, 1024, None, SWA_SEQ) \
+        == "pallas-rows"
+    hlo = compiled.as_text()
+    assert hlo.count(ROWS_KERNEL) >= 2
+    for width in (768, 512):
+        assert f"[1,{SWA_ROWS},{SWA_SEQ},{width}]" not in hlo
+        assert f"[{SWA_ROWS},{SWA_SEQ},{width}]" not in hlo
+        assert "dynamic-slice" not in "".join(
+            line for line in hlo.splitlines()
+            if f"{SWA_ROWS},{SWA_SEQ},{width}]" in line)
+    assert f"[1,{SWA_ROWS},{SWA_RING}," in hlo
+    # and the einsum path, which a CPU backend and the int8 planes keep,
+    # still has them
+    _, _, einsum = _swa_compiled(
+        chip, "decode-8192", ffn_dim=512, moe_ffn_dim=128, vocab_size=1024)
+    assert f"[1,{SWA_ROWS},{SWA_SEQ},768]" in einsum.as_text()
+    assert ROWS_KERNEL not in einsum.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode-8192", "decode-on-the-chip",
+                                     "chunk-512-at-8192", "prefill-128"])
 def test_the_mimo_share_fits_one_chip_at_its_stated_bytes(chip, program):
     """``mimo-v2-flash-ep16s`` at the cell's size: the compiler holds the
     four planes at their stated bytes to the byte (no width of 192 padded to
