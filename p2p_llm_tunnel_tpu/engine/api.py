@@ -24,6 +24,20 @@ Surfaces (BASELINE.md configs):
   OpenAI /v1/embeddings)
 - GET /health
 
+A model that generates by blocks (``ModelConfig.block_length``: positions
+are filled a block at a time by masked denoising, engine/block_engine.py)
+answers the same surfaces: tokens stream in position order, and
+``usage.completion_tokens`` is what was asked whether or not it ends a
+block.  ``token_logprobs`` there is, for the token at position ``q`` (offset
+``r`` of its block, group ``g = r // k``), the log-softmax at that token of
+the logits AT ``q`` in the pass where ``q``'s block holds its true tokens at
+offsets ``< g * k`` and the mask token from there on, over clean earlier
+blocks: for a generated token the probability it was sampled with, for a
+prompt token under ``echo`` the same quantity teacher-forced (an echoed
+prompt runs through the decode passes as forced outcomes).  A function of
+the sequence's tokens alone: not of where the prompt ended, of
+``max_tokens`` or of what follows ``q``.
+
 SSE chunk shape matches the conformance fixture tmp/mock_llm.py:36-88.
 """
 

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from p2p_llm_tunnel_tpu.engine import sampling
+from p2p_llm_tunnel_tpu.engine.block_engine import BlockDecodeMixin
 from p2p_llm_tunnel_tpu.engine.scheduler import (
     GenRequest,
     MuxController,
@@ -473,7 +474,7 @@ class _CountsFirst:
         return self._jitted.lower(*args)
 
 
-class InferenceEngine:
+class InferenceEngine(BlockDecodeMixin):
     """Slot-batched continuous-decode engine over one model."""
 
     def __init__(
@@ -490,6 +491,12 @@ class InferenceEngine:
         self.mcfg = model_cfg or get_config(
             self.ecfg.model, vocab_size=self.tokenizer.vocab_size
         )
+        # Generation by blocks (engine/block_engine.py); 0: a token a step.
+        self._block = self.mcfg.block_length
+        if self._block and self.mcfg.mask_token_id >= self.mcfg.vocab_size:
+            # a preset's mask id under a smaller tokenizer (tiny-*)
+            self.mcfg = dc_replace(
+                self.mcfg, mask_token_id=self.mcfg.vocab_size - 1)
         self._refuse_unsupported()
         # flash_sgrid IMPLIES flash_decode (it selects the kernel variant):
         # the bench applies the same implication, so the benched and served
@@ -734,6 +741,13 @@ class InferenceEngine:
             if self.ecfg.sp <= 1:
                 self.ecfg = dc_replace(
                     self.ecfg, prefill_chunk=self._mux_default_chunk())
+        if self._block:
+            # Every prompt's whole blocks go through chunk prefill (the one
+            # prefill program of this family), in segments of whole blocks.
+            chunk = self.ecfg.prefill_chunk or self._mux_default_chunk()
+            chunk = -(-chunk // self._block) * self._block
+            if chunk != self.ecfg.prefill_chunk:
+                self.ecfg = dc_replace(self.ecfg, prefill_chunk=chunk)
         if self.ecfg.kv_quant == "int4":
             # Page-alignment pass (ISSUE 14), AFTER the mux default above
             # so the EFFECTIVE chunk width is what gets rounded: packed
@@ -1026,6 +1040,8 @@ class InferenceEngine:
         self._logprobs = np.zeros((rows,), np.int32)
         self._sample_seed = np.zeros((rows,), np.uint32)
         self._slot_bias_on = np.zeros((rows,), bool)
+        if self._block:
+            self._init_block(rows)
         self._spec_hist: Dict[int, tuple] = {}
         # Adaptive verify width (ISSUE 17): per-slot windowed acceptance
         # EMA driving _spec_pick_k, the last-64-burst (proposed, accepted)
@@ -1144,9 +1160,14 @@ class InferenceEngine:
         # keeps attention HBM reads tracking actual context length instead
         # of max_seq; the two burst sizes trade throughput (big) against
         # admission latency (small, used while requests wait).
+        # (the decode program, the carry it donates, its static view and
+        # steps: decode_launch_report lowers the same)
+        self._decode_program = (
+            (self._block_decode_fn, (1, 2, 3, 4, 5), (14, 15))
+            if self._block else (self._decode_fn, (1, 2, 3, 4), (11, 12)))
         self._jit_decode = jax.jit(
-            self._decode_fn, donate_argnums=(1, 2, 3, 4),
-            static_argnums=(11, 12),
+            self._decode_program[0], donate_argnums=self._decode_program[1],
+            static_argnums=self._decode_program[2],
         )
         self._jit_prefill = jax.jit(
             self._prefill_fn, donate_argnums=(1,), static_argnums=(8,)
@@ -1425,6 +1446,12 @@ class InferenceEngine:
             stat_rows=slots != self._scratch_slot if self._moe_counts
             else None,
         )
+        if self._block:
+            # a clean prompt block's logits decide nothing: the head is
+            # dead code here, and the first tokens come from a decode pass
+            return (*moe, jnp.zeros(lengths.shape, jnp.int32),
+                    sampling.empty_logprob_data(
+                        lengths.shape[0], last_logits.shape[-1]), kv_cache)
         with jax.named_scope("head_sample"):
             first = sampling.sample(last_logits, samp, key,
                                     pos=starts + lengths, bias=bias[slots])
@@ -1765,7 +1792,8 @@ class InferenceEngine:
             )
 
             return _report(
-                jax.jit(self._decode_fn, static_argnums=(11, 12)),
+                jax.jit(self._decode_program[0],
+                        static_argnums=self._decode_program[2]),
                 *self._decode_warm_args(
                     self._warmup_views()[0] if view is None else view,
                     self.ecfg.decode_steps if steps is None else steps,
@@ -1892,6 +1920,8 @@ class InferenceEngine:
             what = "latent attention, routed experts"
         elif self.mcfg.attn_pattern is not None:
             what = "window rings beside full planes, routed experts"
+        elif self.mcfg.block_length:
+            what = "generation by blocks, routed experts"
         else:
             return
         e = self.ecfg
@@ -1957,8 +1987,17 @@ class InferenceEngine:
                     for arr in self.kv_cache.values()) // (rows * s),
             }
         first, held = m.experts_held
+        generation = {}
+        if m.block_length:
+            generation = {"generation": {
+                "block_length": m.block_length,
+                "denoise_steps": m.denoise_steps,
+                "remasking": "sequential",
+                "mask_token_id": m.mask_token_id,
+            }}
         return {
             "name": m.name,
+            **generation,
             "cache": cache,
             "layers": {"held": m.n_layers,
                        "of": m.published_layers or m.n_layers},
@@ -2152,6 +2191,8 @@ class InferenceEngine:
     def _decode_warm_args(self, view: int, steps: int):
         """Positional args for a decode-burst program, aval-identical to
         _dispatch_decode's live call (same shapes/dtypes, zero values)."""
+        if self._block:
+            return self._block_warm_args(view, steps)
         rows = self.ecfg.num_slots + 1
         return (
             self.params, self.kv_cache, self._dev_tokens,
@@ -3333,7 +3374,11 @@ class InferenceEngine:
         need = 1
         if active.any():
             need = int(self._positions[:n][active].max()) + 1
-        need += 2 * self.ecfg.decode_steps + 1
+        # (a pass of a model that generates by blocks moves a row by up to a
+        # group of tokens, and its base by a block)
+        need += 2 * self.ecfg.decode_steps * (
+            self.mcfg.block_length // self.mcfg.denoise_steps
+            if self._block else 1) + 1 + self._block
         if self.ecfg.spec_ngram > 0:
             # Spec verify writes (and must be able to ATTEND) proposal KV
             # at positions up to pos + K; a view that excludes them would
@@ -3374,6 +3419,31 @@ class InferenceEngine:
                 return eager  # slot finishing within one full burst
         return full
 
+    def _burst_samp(self) -> sampling.SamplingParams:
+        """The sampling plane of a decode burst, from the host's per-slot
+        state (copies: see _dispatch_decode on aliasing); penalties,
+        logprobs and bias count for active rows only."""
+        active = self._active_mask
+        return sampling.SamplingParams(
+            temperature=jnp.array(self._temp),
+            top_k=jnp.array(self._top_k),
+            top_p=jnp.array(self._top_p),
+            freq_pen=jnp.array(np.where(active, self._freq_pen, 0.0)),
+            pres_pen=jnp.array(np.where(active, self._pres_pen, 0.0)),
+            logprobs=jnp.array(np.where(active, self._logprobs, 0)),
+            seed=jnp.array(self._sample_seed),
+            bias_on=jnp.array(self._slot_bias_on & active),
+        )
+
+    def _burst_assign(self) -> List[Optional[int]]:
+        """Which request holds each row of the burst being dispatched (None:
+        free, or not yet active), the scratch row last."""
+        return [
+            run.request.request_id
+            if run is not None and self._active_mask[i] else None
+            for i, run in enumerate(self.scheduler.slots)
+        ] + [None]
+
     def _dispatch_decode(self, *, view: Optional[int] = None,
                          steps: Optional[int] = None):
         """Non-blocking: dispatch one k-step burst; returns (sampled_device,
@@ -3384,6 +3454,8 @@ class InferenceEngine:
         in flight to the host — the pipelining that hides the device_get
         round trip.
         """
+        if self._block:
+            return self._dispatch_block_decode(view, steps)
         self._ensure_decode_carry()
         # jnp.array (copy=True) — NOT jnp.asarray — for every persistent host
         # array at the dispatch boundary: on the CPU backend asarray zero-copy
@@ -3396,16 +3468,7 @@ class InferenceEngine:
         # from a finished request can't keep the [B,V] penalty path enabled
         # for later all-greedy batches.
         active = self._active_mask
-        samp = sampling.SamplingParams(
-            temperature=jnp.array(self._temp),
-            top_k=jnp.array(self._top_k),
-            top_p=jnp.array(self._top_p),
-            freq_pen=jnp.array(np.where(active, self._freq_pen, 0.0)),
-            pres_pen=jnp.array(np.where(active, self._pres_pen, 0.0)),
-            logprobs=jnp.array(np.where(active, self._logprobs, 0)),
-            seed=jnp.array(self._sample_seed),
-            bias_on=jnp.array(self._slot_bias_on & active),
-        )
+        samp = self._burst_samp()
         # INACTIVE rows are parked at position >= max_seq every dispatch:
         # decode_step writes KV at every row's carry position, and a stale
         # carry pointing into a slot that a chunk-prefill segment has
@@ -3471,11 +3534,7 @@ class InferenceEngine:
         # a chunk-prefilling slot holds its request-id long before its
         # device carry is real, so the burst in flight when its final
         # segment lands would otherwise be credited as its tokens.
-        assign = [
-            run.request.request_id
-            if run is not None and self._active_mask[i] else None
-            for i, run in enumerate(self.scheduler.slots)
-        ] + [None]  # scratch row
+        assign = self._burst_assign()
         # Skip the lp arrays in the host fetch when nobody asked: the
         # ~17 KB/burst of zeros would otherwise ride every device_get on a
         # link where transfer time is the bottleneck.
@@ -3532,7 +3591,10 @@ class InferenceEngine:
         rows = self.ecfg.num_slots + 1
         glob = (self._spmd.globalize if self._spmd is not None
                 else (lambda x: x))
-        self._dev_tokens = glob(jnp.zeros((rows,), jnp.int32))
+        self._dev_tokens = glob(jnp.zeros(
+            (rows, self._block) if self._block else (rows,), jnp.int32))
+        if self._block:
+            self._dev_decided = jnp.zeros((rows,), jnp.int32)
         self._dev_positions = glob(jnp.zeros((rows,), jnp.int32))
         self._dev_counts = glob(
             jnp.zeros((rows, self.mcfg.vocab_size), jnp.int32)
@@ -3636,6 +3698,8 @@ class InferenceEngine:
         self._pres_pen[i] = req.pres_pen
         self._logprobs[i] = req.logprobs
         self._sample_seed[i] = req.seed
+        if self._block:
+            self._admit_block_row(run)
         # The device-side carry knows nothing about this slot yet; patch it
         # in at the next dispatch.
         self._ov_mask[i] = True
@@ -3864,6 +3928,11 @@ class InferenceEngine:
                 # the end-of-iteration batched insert; a turn-N+1 prompt
                 # that resends this conversation matches through it.
                 seq = out.request.prompt_ids + out.generated[:-1]
+                if self._block:
+                    # generation by blocks: the blocks before the last
+                    # token's are committed (a pass on a block follows the
+                    # commit of the one before it); that block may not be
+                    seq = seq[: len(seq) // self._block * self._block]
                 if len(seq) >= self._prefix_block:
                     self._conv_pending.append((slot, seq))
         else:
@@ -4033,13 +4102,22 @@ class InferenceEngine:
         # (Routed BEFORE the tail-bucket cap below: segments use the
         # prefill_chunk-wide program, so a long tail composes with any
         # history length.)
+        if self._block:
+            # Generation by blocks has no whole-prompt prefill: an echoed
+            # prompt runs through the decode passes as forced outcomes
+            # (engine/block_engine.py), every other prompt's whole blocks
+            # through the segments below, whatever its length.
+            for run in [r for r in admitted if r.request.echo_logprobs]:
+                self._admit_one(run)
+                admitted.remove(run)
         if self.ecfg.prefill_chunk > 0:
             seg_hits: List[Tuple[int, List[int]]] = []
             for run in list(admitted):
                 if run.request.echo_logprobs:
                     continue  # echo: whole-prompt prefill only (see above)
                 hist = hist_of[run.slot]
-                if len(run.request.prompt_ids) - hist > self.ecfg.prefill_chunk:
+                if (self._block or len(run.request.prompt_ids) - hist
+                        > self.ecfg.prefill_chunk):
                     if hist:
                         seg_hits.append((run.slot, pool_ids_of[run.slot]))
                         global_metrics.inc(
@@ -4377,7 +4455,7 @@ class InferenceEngine:
         rows: List[Tuple[RunningSlot, bool]] = []
         n_tokens = 0
         for run, start in picked:
-            ids = run.request.prompt_ids
+            ids = self._prefill_ids(run)
             seg = ids[start : start + chunk]
             final = start + len(seg) >= len(ids)
             if final:
@@ -4423,8 +4501,10 @@ class InferenceEngine:
             if not final or self.scheduler.slots[run.slot] is not run:
                 continue
             self._admit_one(run)
-            lp_row = None if lp is None else (lp[0][i], lp[1][i], lp[2][i])
-            self._account_token(run.slot, int(first), lp_row)
+            if not self._block:  # (its first tokens: the first decode pass)
+                lp_row = (None if lp is None
+                          else (lp[0][i], lp[1][i], lp[2][i]))
+                self._account_token(run.slot, int(first), lp_row)
             if self._prefix is not None:
                 inserts.append(run)
         if inserts:
@@ -4442,6 +4522,8 @@ class InferenceEngine:
         self._drain_moe()
         if rec is None:
             return
+        if self._block:
+            rec.attrs.update(self._blk_burst_attrs)
         global_tracer.add_span(
             "engine.decode_burst", trace_id=None, track="engine-loop",
             t0=rec.t0, attrs=rec.attrs,
@@ -5136,6 +5218,8 @@ class InferenceEngine:
         rows that were freed or re-admitted since (pipelining lag) carry
         junk tokens for the *old* occupant and are skipped.
         """
+        if self._block:
+            return await self._process_block_burst(outs, assign)
         sampled, lp_out = outs
         for col in range(sampled.shape[1]):
             for i in np.nonzero(self._active_mask)[0]:

@@ -132,7 +132,10 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    qk_norm: bool = False  # RMSNorm(w) over each head's query before rope
+    # RMSNorm(w) over each head's query before rope (latent attention:
+    # models/mla.py); in the KV-head family over each query AND key head's
+    # columns, weights ``q_norm`` / ``k_norm`` [L, head_dim] (Qwen3's).
+    qk_norm: bool = False
     yarn: Optional[YarnRope] = None
     # Window and full attention layers mixed by a pattern given as data
     # (models/swa.py): ``attn_pattern[l]`` is 1 where layer ``l`` attends to
@@ -153,6 +156,29 @@ class ModelConfig:
     value_scale: float = 1.0
     window_sink: bool = False
     ring_positions: int = 0
+    # Generation by masked denoising over blocks (models/block_decode.py):
+    # positions are filled ``block_length`` at a time (0: one token a
+    # sequence and step).  A block takes ``denoise_steps`` passes that each
+    # decide ``block_length / denoise_steps`` offsets in order (sequential
+    # remasking; the offsets not yet decided hold ``mask_token_id``), then a
+    # commit pass that writes the clean block's K/V.  Attention is
+    # block-causal: position ``i`` sees ``j`` where ``j // block_length <=
+    # i // block_length``.
+    block_length: int = 0
+    denoise_steps: int = 0
+    mask_token_id: int = 0
+    # What lies between the products stays float32 and is rounded to the
+    # activations' type once, where the next product takes it: the residual
+    # stream, the normed stream a router scores, a head's query and key
+    # through their norm and rope, the attention output's projection, a
+    # routed expert's gate and up values, and the experts' results until
+    # their weighted sum.  Every product's operands and the K/V a token
+    # caches stay in the activations' type.  With every expert of a layer
+    # held, each rounding moves some token's 8th and 9th score past each
+    # other (models/swa.py and models/mla.py keep their stream and router
+    # so for the same reason); read on the CPU at 7 layers of 64 experts it
+    # takes 18 % off the distance to the float32 reference.
+    residual_f32: bool = False
 
     @property
     def q_per_kv(self) -> int:
@@ -592,6 +618,68 @@ def tiny_swa_moe(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+def sdar_30b_a3b() -> ModelConfig:
+    """SDAR-30B-A3B-Chat (JetLM; ``model_type`` ``sdar_moe``): a Qwen3-MoE
+    body (QK norm, 128 routed experts of width 768, top-8 of a softmax,
+    renormalised, no shared expert; ``intermediate_size`` 6144 is read by
+    nothing: every layer is routed) that generates by masked denoising
+    over blocks of 4 (the family's ``generate.py``: block length 4; 2
+    sequential steps of 2 stand for its confidence threshold)."""
+    return ModelConfig(
+        name="sdar-30b-a3b",
+        vocab_size=151936,
+        dim=2048,
+        n_layers=48,
+        n_heads=32,
+        n_kv_heads=4,
+        head_dim=128,
+        ffn_dim=6144,
+        rope_theta=1000000.0,
+        norm_eps=1e-6,
+        n_experts=128,
+        n_experts_per_tok=8,
+        moe_ffn_dim=768,
+        qk_norm=True,
+        block_length=4,
+        denoise_steps=2,
+        mask_token_id=151669,
+        residual_f32=True,
+    )
+
+
+def sdar_30b_a3b_pp7s() -> ModelConfig:
+    """The first of seven pipeline stages of SDAR-30B-A3B-Chat: 7 of 48
+    layers, each whole (all 128 experts), with the embedding and the whole
+    head; every width as published."""
+    return replace(sdar_30b_a3b(), name="sdar-30b-a3b-pp7s", n_layers=7,
+                   published_layers=48)
+
+
+def tiny_sdar_moe(vocab_size: int = 512) -> ModelConfig:
+    """CPU-testable SDAR-style config: blocks of 4 in 2 steps, 8 experts
+    top-2, QK norm, 4 query heads on 2 KV heads."""
+    return ModelConfig(
+        name="tiny-sdar-moe",
+        vocab_size=vocab_size,
+        dim=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=16,
+        ffn_dim=128,
+        rope_theta=1000000.0,
+        norm_eps=1e-6,
+        n_experts=8,
+        n_experts_per_tok=2,
+        moe_ffn_dim=32,
+        qk_norm=True,
+        block_length=4,
+        denoise_steps=2,
+        mask_token_id=vocab_size - 1,
+        residual_f32=True,
+    )
+
+
 def tiny_swa_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
     """tiny-swa-moe as one of 2 chips that share each layer: experts 0-3
     and ``vocab_size`` rows of a table twice as long."""
@@ -601,6 +689,9 @@ def tiny_swa_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
 
 PRESETS = {
     "tiny": tiny,
+    "tiny-sdar-moe": tiny_sdar_moe,
+    "sdar-30b-a3b": sdar_30b_a3b,
+    "sdar-30b-a3b-pp7s": sdar_30b_a3b_pp7s,
     "tiny-swa-moe": tiny_swa_moe,
     "tiny-swa-moe-ep2s": tiny_swa_moe_ep2s,
     "mimo-v2-flash": mimo_v2_flash,

@@ -48,22 +48,38 @@ from p2p_llm_tunnel_tpu.models.quant import mm, round_act
 #: tokens, those to held experts, the fullest held expert's tokens, and the
 #: held experts that got a token.
 STATS = 4
+#: The leaves that hold experts, [L, E, ...] in a stacked block tree.
+EXPERT_LEAVES = ("moe_gate", "moe_up", "moe_down")
 
 
-def init_moe_blocks(cfg, keys, dense_fn) -> dict:
+def init_moe_blocks(cfg, keys, dense_fn, per_expert: bool = False) -> dict:
     """Mixtral-style leaves for the stacked block tree (every layer routed,
     all experts held).
 
     ``dense_fn(key, shape, fan_in)`` is init_params' dense initializer so
     MoE weights follow the same distribution.  Layout:
     router [L, Dm, E]; experts [L, E, Dm, F] (gate/up) and [L, E, F, Dm]
-    (down)."""
+    (down).  ``per_expert``: expert ``e`` of layer ``i`` is drawn from
+    ``fold_in(fold_in(key, i), e)``, one at a time (models/swa.py's
+    scheme), where a leaf drawn whole in float32 would not fit the chip."""
     l, dm, f, e = cfg.n_layers, cfg.dim, cfg.expert_dim, cfg.n_experts
+
+    def experts(k, shape, fan_in):
+        if not per_expert:
+            return dense_fn(k, (l, e) + shape, fan_in)
+
+        def one(i):
+            return dense_fn(
+                jax.random.fold_in(jax.random.fold_in(k, i // e), i % e),
+                shape, fan_in)
+
+        return jax.lax.map(one, jnp.arange(l * e)).reshape((l, e) + shape)
+
     return {
         "router": dense_fn(keys[0], (l, dm, e), dm),
-        "moe_gate": dense_fn(keys[1], (l, e, dm, f), dm),
-        "moe_up": dense_fn(keys[2], (l, e, dm, f), dm),
-        "moe_down": dense_fn(keys[3], (l, e, f, dm), f),
+        "moe_gate": experts(keys[1], (dm, f), dm),
+        "moe_up": experts(keys[2], (dm, f), dm),
+        "moe_down": experts(keys[3], (f, dm), f),
     }
 
 
@@ -164,10 +180,15 @@ def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
                 jnp.zeros((stacked["moe_gate"].shape[0],), jnp.int32), sizes,
                 (layer * held,))
         rows = round_act(x, aq)[token_of]  # [N*k, Dm], sorted by expert
-        gate = jax.lax.ragged_dot(rows, experts["moe_gate"], sizes)
-        up = jax.lax.ragged_dot(rows, experts["moe_up"], sizes)
-        inner = round_act(act_fn(gate) * up, aq)
-        down = jax.lax.ragged_dot(inner, experts["moe_down"], sizes)
+        # under cfg.residual_f32 the values between the three products stay
+        # float32 and are rounded to the activations' type once, where the
+        # last product takes them; its results reach the weighted sum whole
+        wide = ({"preferred_element_type": jnp.float32}
+                if cfg.residual_f32 else {})
+        gate = jax.lax.ragged_dot(rows, experts["moe_gate"], sizes, **wide)
+        up = jax.lax.ragged_dot(rows, experts["moe_up"], sizes, **wide)
+        inner = round_act((act_fn(gate) * up).astype(rows.dtype), aq)
+        down = jax.lax.ragged_dot(inner, experts["moe_down"], sizes, **wide)
         # (rows past the held groups hold nothing defined: their weight is 0)
         part = jnp.where(weight[:, None] > 0,
                          down.astype(jnp.float32) * weight[:, None], 0.0)
@@ -177,4 +198,6 @@ def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
             inner = act_fn(mm(x, blk["shared_gate"], aq)) * mm(
                 x, blk["shared_up"], aq)
             out = out + mm(inner, blk["shared_down"], aq).astype(jnp.float32)
-    return out.astype(h.dtype).reshape(b, t, dm), stats
+    # (under cfg.residual_f32 the stream takes the sum as it is)
+    return out.astype(jnp.float32 if cfg.residual_f32 else h.dtype).reshape(
+        b, t, dm), stats

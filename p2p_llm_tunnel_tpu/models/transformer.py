@@ -24,7 +24,12 @@ import jax
 import jax.numpy as jnp
 
 from p2p_llm_tunnel_tpu.models.config import ModelConfig
-from p2p_llm_tunnel_tpu.models.quant import embed_lookup, head_matmul, mm
+from p2p_llm_tunnel_tpu.models.quant import (
+    embed_lookup,
+    head_matmul,
+    mm,
+    round_act,
+)
 from p2p_llm_tunnel_tpu.ops.attention import cached_attention, causal_attention
 from p2p_llm_tunnel_tpu.ops.norms import rms_norm
 from p2p_llm_tunnel_tpu.ops.rope import apply_rope
@@ -65,7 +70,10 @@ def init_params(
     if cfg.n_experts:
         from p2p_llm_tunnel_tpu.models.moe import init_moe_blocks
 
-        blocks.update(init_moe_blocks(cfg, keys[8:12], dense))
+        # (a block-generation model is drawn an expert at a time: its
+        # 128 experts a layer do not fit the chip as one float32 draw)
+        blocks.update(init_moe_blocks(cfg, keys[8:12], dense,
+                                      per_expert=bool(cfg.block_length)))
     else:
         blocks.update({
             "w_gate": dense(keys[4], (l, dm, f), dm),
@@ -75,6 +83,14 @@ def init_params(
     if cfg.post_norms:
         blocks["post_attn_norm"] = jnp.zeros((l, dm), dtype)
         blocks["post_mlp_norm"] = jnp.zeros((l, dm), dtype)
+    if cfg.qk_norm:
+        # Qwen3's: one learned weight a column of a head, shared by the
+        # heads.  Drawn about 1 (not ones) so tests exercise a weight that
+        # changes the output.
+        for name, part in (("q_norm", 60), ("k_norm", 61)):
+            blocks[name] = (1.0 + 0.1 * jax.random.normal(
+                jax.random.fold_in(key, part), (l, hd), jnp.float32)
+            ).astype(dtype)
     if cfg.attn_bias:
         # qwen2: bias on Q/K/V projections only.  Random init (not zeros)
         # so tests exercise a bias that actually changes the output.
@@ -186,22 +202,61 @@ def _norm(cfg: ModelConfig, x, w):
     return rms_norm(x, w, cfg.norm_eps, plus_one=cfg.post_norms)
 
 
+def stream_in(cfg: ModelConfig, x):
+    """The embedded activations as the residual stream carries them, and
+    the type the layers' products take (``x``'s own): float32 under
+    ``cfg.residual_f32``, else ``x`` as it is (no operation)."""
+    return (x.astype(jnp.float32) if cfg.residual_f32 else x), x.dtype
+
+
+def normed(cfg: ModelConfig, x, w, act):
+    """RMSNorm of the stream -> (in the products' type ``act``, as the
+    router scores it: the float32 norm under ``cfg.residual_f32``, else
+    None = the same)."""
+    h = _norm(cfg, x, w)
+    if not cfg.residual_f32:
+        return h, None
+    return h.astype(act), h
+
+
 def _act(cfg: ModelConfig, x):
     if cfg.act == "gelu":
         return jax.nn.gelu(x, approximate=True)
     return jax.nn.silu(x)
 
 
-def _mlp(cfg: ModelConfig, blk, h, counted=None):
+def _mlp(cfg: ModelConfig, blk, h, counted=None, stacked=None, layer=None,
+         router_in=None):
     """The block's feed-forward -> (out, what a routed layer counted of the
-    ``counted`` tokens (models/moe.py) or None for a dense one)."""
+    ``counted`` tokens (models/moe.py) or None for a dense one).
+    ``stacked`` + ``layer``: :func:`split_experts`; ``router_in``:
+    :func:`normed`."""
     if cfg.n_experts:
         from p2p_llm_tunnel_tpu.models.moe import moe_mlp
 
-        return moe_mlp(cfg, blk, h, lambda x: _act(cfg, x), counted)
+        return moe_mlp(cfg, blk, h, lambda x: _act(cfg, x), counted,
+                       stacked=stacked, layer=layer, router_in=router_in)
     aq = cfg.act_quant
     gate = _act(cfg, mm(h, blk["w_gate"], aq)) * mm(h, blk["w_up"], aq)
     return mm(gate, blk["w_down"], aq), None
+
+
+def split_experts(cfg: ModelConfig, blocks):
+    """``blocks`` as (the leaves a layer scan slices, the experts of all
+    layers as three arrays [L * E, ...] that a routed layer reads where
+    they lie, or None).  A layer's slice of the expert stack handed to the
+    grouped product is a copy first (models/moe.py ``stacked``): 1.2 GB a
+    layer at 128 experts of 2048 x 768.  Taken by the block-generation
+    family, which holds every expert of a layer; the other routed presets
+    of this module keep their slices (``--ep`` shards their expert axis)."""
+    if not (cfg.n_experts and cfg.block_length):
+        return blocks, None
+    from p2p_llm_tunnel_tpu.models.moe import EXPERT_LEAVES
+
+    stacked = {k: blocks[k].reshape((-1,) + blocks[k].shape[2:])
+               for k in EXPERT_LEAVES}
+    return ({k: v for k, v in blocks.items() if k not in EXPERT_LEAVES},
+            stacked)
 
 
 def _moe_total(stats):
@@ -229,15 +284,23 @@ def _family_module(cfg: ModelConfig):
     return None
 
 
+def _proj(cfg: ModelConfig, x, w):
+    """``x @ w`` (models/quant.py ``mm``); under ``cfg.residual_f32`` the
+    result stays float32: the operands are ``x``'s type either way."""
+    if cfg.residual_f32:
+        return jnp.dot(round_act(x, cfg.act_quant), w,
+                       preferred_element_type=jnp.float32)
+    return mm(x, w, cfg.act_quant)
+
+
 def _qkv_proj(cfg: ModelConfig, blk, h):
     """QKV projections + bias + head split, NO rope — the fused decode
     kernel applies rope in VMEM at each slot's position, so the decode
     fused path consumes these directly."""
     b, t, _ = h.shape
-    aq = cfg.act_quant
-    q = mm(h, blk["wq"], aq)
-    k = mm(h, blk["wk"], aq)
-    v = mm(h, blk["wv"], aq)
+    q = _proj(cfg, h, blk["wq"])
+    k = _proj(cfg, h, blk["wk"])
+    v = _proj(cfg, h, blk["wv"])
     if cfg.attn_bias:  # qwen2: additive bias on the Q/K/V projections
         q = q + blk["bq"].astype(q.dtype)
         k = k + blk["bk"].astype(k.dtype)
@@ -245,6 +308,9 @@ def _qkv_proj(cfg: ModelConfig, blk, h):
     q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:  # over each head's columns, before rope
+        q = rms_norm(q, blk["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, blk["k_norm"], cfg.norm_eps)
     return q, k, v
 
 
@@ -252,6 +318,10 @@ def _qkv(cfg: ModelConfig, blk, h, positions):
     q, k, v = _qkv_proj(cfg, blk, h)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.residual_f32:
+        # projected, normed and roped in float32, rounded once: to what the
+        # scores take and the cache holds
+        q, k, v = (a.astype(h.dtype) for a in (q, k, v))
     return q, k, v
 
 
@@ -310,6 +380,7 @@ def prefill_attention_branch(cfg: ModelConfig, mesh, t: int) -> str:
         and t % 128 == 0
         and cfg.head_dim % 128 == 0
         and cfg.attn_pattern is None
+        and not cfg.block_length  # the flash kernel's mask is causal
     ):
         return "pallas-flash"
     return "einsum"
@@ -385,6 +456,7 @@ def decode_attention_branch(cfg: ModelConfig, mesh, kv_view: int,
         if cfg.flash_decode or cfg.flash_sgrid:
             return "pallas-sgrid"
     if (cfg.flash and kv_quant is None and not cfg.kv_lora_rank
+            and not cfg.block_length  # models/block_decode.py: an einsum
             and decode_kernel_decline(cfg, mesh, max_seq or kv_view) is None):
         return "pallas-rows"
     return "einsum"
@@ -514,6 +586,7 @@ def _prefill_attention_fn(cfg: ModelConfig, mesh, t: int):
         scale=cfg.query_scale,
         softcap=cfg.attn_softcap,
         window=window,
+        block=cfg.block_length,
     )
 
 
@@ -546,7 +619,7 @@ def prefill(
     attention = _prefill_attention_fn(cfg, mesh, t)
     out = apply_blocks(cfg, params["blocks"], x, valid, attention,
                        counted=counted)
-    x = _norm(cfg, out[0], params["final_norm"])
+    x = _norm(cfg, out[0], params["final_norm"]).astype(x.dtype)
     return (_logits(cfg, params, x),) + tuple(out[1:])
 
 
@@ -594,17 +667,19 @@ def apply_blocks(
     n_chunk = jax.tree_util.tree_leaves(blocks)[0].shape[0]
     layer_idx = layer_offset + jnp.arange(n_chunk)
 
+    x, act = stream_in(cfg, x)
+
     def step(x, xs):
         blk, idx = xs
-        h = _norm(cfg, x, blk["attn_norm"])
+        h, _ = normed(cfg, x, blk["attn_norm"], act)
         q, k, v = _qkv(cfg, blk, h, positions)
         attn = attention(q, k, v, valid, _layer_window(cfg, idx, t))
-        attn = mm(attn.reshape(b, t, -1), blk["wo"], cfg.act_quant)
+        attn = _proj(cfg, attn.reshape(b, t, -1), blk["wo"])
         if cfg.post_norms:
             attn = _norm(cfg, attn, blk["post_attn_norm"])
         x = x + attn
-        h = _norm(cfg, x, blk["mlp_norm"])
-        mlp, stats = _mlp(cfg, blk, h, counted)
+        h, h32 = normed(cfg, x, blk["mlp_norm"], act)
+        mlp, stats = _mlp(cfg, blk, h, counted, router_in=h32)
         if cfg.post_norms:
             mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
         x = x + mlp
@@ -855,7 +930,7 @@ def chunk_prefill_into_cache(
     s = kv_cache["k"].shape[2] * (2 if quant_mode == "int4" else 1)
     if kv_view is None or kv_view > s:
         kv_view = s
-    x = _embed(cfg, params, tokens)
+    x, act = stream_in(cfg, _embed(cfg, params, tokens))
     pos = starts[:, None] + jnp.arange(t)[None, :]  # [Bp,T] global positions
     layer_idx = jnp.arange(cfg.n_layers)
     quant = kv_cache_is_quantized(kv_cache)
@@ -873,6 +948,8 @@ def chunk_prefill_into_cache(
 
     from p2p_llm_tunnel_tpu.ops.attention import history_attention
 
+    scanned, stacked = split_experts(cfg, params["blocks"])
+
     def read_view(plane, idx, n):
         return read_cache_view(plane, idx, n, slots)
 
@@ -882,7 +959,7 @@ def chunk_prefill_into_cache(
     def step(x, xs):
         blk, idx = xs
         with jax.named_scope("attn"):
-            h = _norm(cfg, x, blk["attn_norm"])
+            h, _ = normed(cfg, x, blk["attn_norm"], act)
             q, k, v = _qkv(cfg, blk, h, pos)  # rope at global positions
         k_s = v_s = None
         with jax.named_scope("kv_write"):
@@ -905,29 +982,30 @@ def chunk_prefill_into_cache(
             if quant:
                 k_s_all = lay(read_view(kv_cache["k_scale"], idx, kv_view), k_s)
                 v_s_all = lay(read_view(kv_cache["v_scale"], idx, kv_view), v_s)
-                k_all = (k_all.astype(jnp.float32) * k_s_all[..., None]).astype(x.dtype)
-                v_all = (v_all.astype(jnp.float32) * v_s_all[..., None]).astype(x.dtype)
+                k_all = (k_all.astype(jnp.float32) * k_s_all[..., None]).astype(act)
+                v_all = (v_all.astype(jnp.float32) * v_s_all[..., None]).astype(act)
         with jax.named_scope("attn"):
             attn = history_attention(
                 q, k_all, v_all, starts,
                 scale=cfg.query_scale,
                 softcap=cfg.attn_softcap,
                 window=_layer_window(cfg, idx, kv_view),
+                block=cfg.block_length,
             )
-            attn = mm(attn.reshape(b, t, -1), blk["wo"], cfg.act_quant)
+            attn = _proj(cfg, attn.reshape(b, t, -1), blk["wo"])
             if cfg.post_norms:
                 attn = _norm(cfg, attn, blk["post_attn_norm"])
             x = x + attn
         with jax.named_scope("ffn"):
-            h = _norm(cfg, x, blk["mlp_norm"])
-            mlp, stats = _mlp(cfg, blk, h, counted)
+            h, h32 = normed(cfg, x, blk["mlp_norm"], act)
+            mlp, stats = _mlp(cfg, blk, h, counted, stacked, idx, h32)
             if cfg.post_norms:
                 mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
             x = x + mlp
         return x, (k, v, k_s, v_s, stats)
 
     x, (ks, vs, k_ss, v_ss, stats) = jax.lax.scan(
-        step, x, (params["blocks"], layer_idx)
+        step, x, (scanned, layer_idx)
     )
     # One write for all layers, [L,Bp,T,K,D] into the donated cache.
     new_cache = dict(kv_cache)
@@ -954,7 +1032,7 @@ def chunk_prefill_into_cache(
             new_cache["k_scale"] = kv_cache["k_scale"].at[:, rows, pos].set(k_ss)
             new_cache["v_scale"] = kv_cache["v_scale"].at[:, rows, pos].set(v_ss)
     with jax.named_scope("head_sample"):
-        x = _norm(cfg, x, params["final_norm"])
+        x = _norm(cfg, x, params["final_norm"]).astype(act)
         logits = _logits(cfg, params, x)  # [Bp,T,V]
     tail = (_moe_total(stats),) if stat_rows is not None else ()
     if return_all_logits:

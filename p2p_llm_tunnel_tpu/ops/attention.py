@@ -47,10 +47,13 @@ def causal_attention(
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
     window: Optional[int] = None,
+    block: int = 0,
 ) -> jnp.ndarray:
     """Self-attention over one padded prompt batch (prefill).
 
     q [B,T,H,D], k/v [B,T,K,D], valid [B,T] bool marks real (non-pad) tokens.
+    ``block``: block-causal, position ``i`` sees ``j`` where ``j // block
+    <= i // block`` (its own block whole); 0 is causal.
     """
     b, t, h, d = q.shape
     kh = k.shape[2]
@@ -64,7 +67,7 @@ def causal_attention(
 
     i = jnp.arange(t)[:, None]
     j = jnp.arange(t)[None, :]
-    mask = j <= i  # causal
+    mask = j // block <= i // block if block else j <= i
     if window is not None:
         mask &= (i - j) < window
     mask = mask[None, None, None, :, :] & valid[:, None, None, None, :]
@@ -85,6 +88,7 @@ def history_attention(
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
     window: Optional[int] = None,
+    block: int = 0,
 ) -> jnp.ndarray:
     """Chunk attention for prefill-with-history (prefix caching).
 
@@ -101,6 +105,11 @@ def history_attention(
     the junk KV they wrote is never attended by real queries — the same
     overwrite-before-read argument as ``prefill_into_cache``.
 
+    ``block``: block-causal instead (``j // block <= g // block``: a
+    query sees its own block whole).  Callers keep ``starts`` and every
+    real tail length a multiple of ``block``, so a real query's block holds
+    no pad position.
+
     q [B,T,H,D]; k/v_cache [B,S,K,D]; starts [B] int32.
     """
     b, t, h, d = q.shape
@@ -116,7 +125,10 @@ def history_attention(
     s = k_cache.shape[1]
     g = starts[:, None] + jnp.arange(t)[None, :]  # [B,T] global query pos
     j = jnp.arange(s)[None, None, :]  # [1,1,S]
-    mask = j <= g[:, :, None]  # [B,T,S]
+    if block:
+        mask = j // block <= g[:, :, None] // block
+    else:
+        mask = j <= g[:, :, None]  # [B,T,S]
     if window is not None:
         mask &= (g[:, :, None] - j) < window
     scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
@@ -168,6 +180,47 @@ def cached_attention(
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = _gqa_out(probs, v_cache)
     return out.reshape(b, 1, h, d).astype(q.dtype)
+
+
+def block_attention(
+    q: jnp.ndarray,
+    k_cache: jnp.ndarray,
+    v_cache: jnp.ndarray,
+    k_own: jnp.ndarray,
+    v_own: jnp.ndarray,
+    base: jnp.ndarray,
+    *,
+    scale: Optional[float] = None,
+) -> jnp.ndarray:
+    """A block of ``T`` query positions a row against the cache prefix
+    ``[0, base)`` whole and the block's own ``T`` keys and values whole (no
+    causal mask inside the block), which need not be in the cache: the
+    decode pass of a model that fills positions a block at a time
+    (models/block_decode.py).  One softmax over both parts.
+
+    q [B,T,H,D]; k/v_cache [B,S,K,D]; k/v_own [B,T,K,D]; base [B] int32.
+    """
+    b, t, h, d = q.shape
+    kh = k_cache.shape[2]
+    if scale is None:
+        scale = d**-0.5
+    q5 = q.reshape(b, t, kh, h // kh, d)
+    hist = _gqa_scores(q5, k_cache, scale)  # [B,K,G,T,S]
+    seen = jnp.arange(k_cache.shape[1])[None, :] < base[:, None]  # [B,S]
+    hist = jnp.where(seen[:, None, None, None, :], hist, _NEG_INF)
+    with jax.named_scope("attn_block"):
+        own = _gqa_scores(q5, k_own, scale)  # [B,K,G,T,T]
+        top = jnp.maximum(hist.max(axis=-1, keepdims=True),
+                          own.max(axis=-1, keepdims=True))
+    p_hist = jnp.exp(hist - top)
+    o_hist = _gqa_out(p_hist, v_cache)
+    with jax.named_scope("attn_block"):
+        p_own = jnp.exp(own - top)
+        denom = (p_hist.sum(axis=-1, keepdims=True)
+                 + p_own.sum(axis=-1, keepdims=True))
+        out = o_hist + _gqa_out(p_own, v_own)
+        out = out / jnp.moveaxis(denom, 3, 1)  # [B,T,K,G,1]
+    return out.reshape(b, t, h, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,21 @@ METRICS_CATALOG: Dict[str, str] = {
         "over the sum of the two it is the share of attention's reads that "
         "the windows bound (counter)"
     ),
+    "engine_block_row_passes_total": (
+        "passes of real rows through the block decode program of a model "
+        "that generates by blocks, denoise and commit passes alike "
+        "(counter)"
+    ),
+    "engine_block_commit_row_passes_total": (
+        "of those, the commit passes: they write a block's K/V and decide "
+        "nothing (counter)"
+    ),
+    "engine_block_tokens_decided_total": (
+        "tokens those passes decided that were delivered to a request "
+        "(never a forced prompt token, never one past a request's end); "
+        "over engine_block_row_passes_total it is what a row's pass yields "
+        "(counter)"
+    ),
     "engine_moe_assignments_total": (
         "token-to-expert assignments the routed layers made of real tokens, "
         "over every expert layer of every dispatch (counter)"

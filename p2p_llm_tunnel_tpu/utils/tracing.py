@@ -129,7 +129,9 @@ SPAN_CATALOG: Dict[str, str] = {
         "name, in a model with window layers the branch of its full layers "
         "(a window layer reads its ring by einsum under either); under "
         "pallas-rows view is max_seq in every record, under einsum the "
-        "bucket sliced)"
+        "bucket sliced; a model that generates by blocks adds block = its "
+        "block length and, from the fetched burst, row_passes_denoise, "
+        "row_passes_commit and tokens_decided of real rows)"
     ),
     "engine.pool_copy": (
         "one batched prefix-pool copy dispatch, cache_to_pool or "

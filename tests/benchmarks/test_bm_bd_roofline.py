@@ -1,0 +1,211 @@
+"""The block-generation family's byte and FLOP counts against the
+configuration's sizes worked out by hand, its readers on recorded numbers,
+and never over 100 % of the peak for a pass that takes what the chip must."""
+
+import json
+import os
+import types
+
+import pytest
+
+from benchmarks import block_diffusion_roofline as roofline
+from benchmarks.correctness import load_module
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+LM = os.path.join(REPO, "benchmarks", "layer_metrics")
+CELL = "sdar-30b-a3b.blockgen-closed"
+
+
+@pytest.fixture(scope="module")
+def config():
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "sdar-30b-a3b.json")) as f:
+        return json.load(f)
+
+
+def peaks():
+    with open(os.path.join(REPO, "benchmarks", "peaks.json")) as f:
+        return json.load(f)["devices"]["TPU v5 lite"]
+
+
+# W_q 2048 x 32 x 128, W_k + W_v 2 x 2048 x 4 x 128, W_o 4096 x 2048
+ATTENTION = 2048 * 4096 + 2 * 2048 * 512 + 4096 * 2048
+ROUTER = 2048 * 128
+EXPERT = 3 * 2048 * 768
+HEAD = 2048 * 151936
+REST = 7 * (ATTENTION + ROUTER) + HEAD
+
+
+def test_the_sizes_are_the_issues_arithmetic(config):
+    s = roofline.sizes(config)
+    assert ATTENTION == 18_874_368 and EXPERT == 4_718_592
+    assert (s["attention"], s["router"], s["expert"], s["head"]) == (
+        ATTENTION, ROUTER, EXPERT, HEAD)
+    assert (s["layers"], s["experts"], s["block"], s["group"]) == (
+        7, 128, 4, 2)
+    # a cached position: 4 KV heads x (128 + 128) values = 2,048 B
+    assert s["kv_row"] == 1024 and 7 * s["kv_row"] * 2 == 14336
+    # the cut: 4,984 M parameters with the embedding and every expert
+    assert 7 * (ATTENTION + ROUTER + 128 * EXPERT) + 2 * HEAD \
+        == pytest.approx(4.984e9, rel=1e-3)
+
+
+def test_a_pass_reads_the_prefix_once_a_block_and_the_experts_it_touched(
+        config):
+    none = roofline.pass_bytes(config, 0, 0, 0)
+    assert none == 2 * REST
+    every = roofline.pass_bytes(config, 0, 7 * 128, 0)
+    assert every - none == 2 * EXPERT * 896
+    assert roofline.pass_bytes(config, 0, 10**6, 0) == every  # no more than are
+    # the program counts positions x layers SEEN by 4 queries a block: the
+    # prefix is read once for the four, 2,048 B a position and layer
+    assert roofline.pass_bytes(config, 4000, 0, 0) - none == 1000 * 2048
+    # a committing row writes 4 rows in each of 7 layers
+    assert roofline.pass_bytes(config, 0, 0, 3) - none == 3 * 4 * 7 * 2048
+    # 48 rows at a base of 600: each pass's 4 queries see 604 positions in 7
+    # layers; every expert touched; a third of the rows commit: 9.76 GB
+    # (0.89 GB of attention, routers and head, 8.46 GB of experts, 0.42 GB
+    # of cache)
+    seen = 48 * 4 * 604 * 7
+    one = roofline.pass_bytes(config, seen, 896, 16)
+    assert one == pytest.approx(2 * REST + 2 * EXPERT * 896
+                                + 48 * 604 * 7 * 2048 + 16 * 28 * 2048)
+    assert one == pytest.approx(9.76e9, rel=1e-2)
+
+
+def test_the_least_pass_is_bound_by_memory_at_48_rows(config):
+    seen = 48 * 4 * 604 * 7
+    least = roofline.least_pass_seconds(
+        config, peaks(), 48, seen, 896, 48 * 4 * 8 * 7, 16)
+    assert least["bound"] == "memory"
+    assert least["seconds"] == pytest.approx(
+        roofline.pass_bytes(config, seen, 896, 16) / 819e9)
+    assert 0.0115 < least["seconds"] < 0.0125
+    flops = roofline.pass_flops(config, 48, seen, 48 * 4 * 8 * 7)
+    # 192 positions through 7 layers' attention and router, 96 through the
+    # head, 10,752 assignments, and the scores
+    assert flops == pytest.approx(
+        2 * 7 * (ATTENTION + ROUTER) * 192 + 2 * HEAD * 96
+        + 2 * EXPERT * 10752 + 2 * 2 * 32 * 128 * seen)
+    assert least["by_flops_s"] < 0.15 * least["by_bytes_s"]
+
+
+def test_the_expert_products_are_bound_by_the_weights_they_read(config):
+    one = roofline.experts_least_seconds(config, peaks(), 896, 10752)
+    assert one["seconds"] == one["by_bytes_s"] > 5 * one["by_flops_s"]
+    assert one["by_flops_s"] == pytest.approx(
+        2 * EXPERT * 10752 / peaks()["bf16_flops_per_s"])
+
+
+def ledger_ctx(config, records):
+    ctx = types.SimpleNamespace()
+    ctx.config = config
+    ctx.load = types.SimpleNamespace(t0=10.0, t1=20.0)
+    ctx.spans = [{"name": name, "ph": "X", "ts": ts * 1e6, "dur": 1000,
+                  "args": dict(args, seq=i)}
+                 for i, (name, ts, args) in enumerate(records)]
+    return ctx
+
+
+def test_the_block_ledger_sums_the_windows_decode_records(config):
+    reader = load_module(os.path.join(LM, "block_ledger.py"))
+    ctx = ledger_ctx(config, [
+        ("engine.decode_burst", 11.0, {"row_passes_denoise": 200,
+                                       "row_passes_commit": 100,
+                                       "tokens_decided": 390}),
+        ("engine.decode_burst", 12.0, {"row_passes_denoise": 60,
+                                       "row_passes_commit": 40,
+                                       "tokens_decided": 110}),
+        # one before the window, one of another span, one without counts
+        ("engine.decode_burst", 9.0, {"row_passes_denoise": 10**6,
+                                      "row_passes_commit": 0,
+                                      "tokens_decided": 1}),
+        ("engine.prefill_segment", 12.5, {"row_passes_denoise": 10**6,
+                                          "row_passes_commit": 0,
+                                          "tokens_decided": 1}),
+        ("engine.decode_burst", 13.0, {"steps": 8}),
+    ])
+    assert reader.read(ctx, "tokens_per_row_pass") == pytest.approx(1.25)
+    assert reader.read(ctx, "commit_share") == pytest.approx(35.0)
+    assert reader.read(ledger_ctx(config, []), "commit_share") is None
+    with pytest.raises(ValueError):
+        reader.read(ctx, "no-such")
+
+
+def test_a_blocks_scope_is_the_innermost_named_one():
+    reader = load_module(os.path.join(LM, "block_scope_share.py"))
+    kinds = load_module(os.path.join(LM, "kind_scope_share.py"))
+    known = load_module(os.path.join(LM, "scope_share.py")).SCOPES \
+        + reader.OWN
+    path = "jit(_block_decode_fn)/while/body/attn/attn_block/dot_general:"
+    assert kinds.scope_of(path, known) == "attn_block"
+    assert kinds.scope_of(
+        "jit(f)/head_sample/denoise_select/select_n:", known) \
+        == "denoise_select"
+    assert kinds.scope_of("jit(f)/attn/dot_general:", known) == "attn"
+    # the readers with the fixed lists take the same operations for attn's
+    assert load_module(os.path.join(LM, "scope_share.py")).scope_of(path) \
+        == "attn"
+
+
+NEW = ["tokens_per_row_pass.blockgen", "commit_pass_share_pct.blockgen",
+       "block_attn_dev_pct.blockgen", "decode_roofline.blockgen",
+       "moe_experts_roofline.blockgen"]
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_the_new_readers_find_nothing_on_a_run_without_them(config, name):
+    """What the parent's traced run gives them, and for the device metrics a
+    CPU rehearsal: no device planes, no counts on the records: nothing to
+    read, nothing raised."""
+    with open(os.path.join(LM, name + ".json")) as f:
+        spec = json.load(f)
+    reader = load_module(os.path.join(LM, spec["reader"] + ".py"))
+    ctx = ledger_ctx(config, [("engine.decode_burst", 11.0, {"steps": 8})])
+    ctx.cell, ctx.peaks, ctx.trace_span = "no-such-cell.rehearsal", None, None
+    assert reader.read(ctx, **spec.get("args", {})) is None
+    ctx.peaks, ctx.trace_span = peaks(), (0.0, 1.0)
+    assert reader.read(ctx, **spec.get("args", {})) is None
+
+
+def test_a_share_cannot_exceed_100_where_the_pass_takes_the_least_time(
+        config):
+    """The reader's own arithmetic on one recorded burst whose measured pass
+    is exactly the least time: 100 %, by construction, and under it for any
+    slower pass."""
+    rec = {"steps": 8, "live_rows": 48, "kv_rows_full": 8 * 48 * 4 * 604 * 7,
+           "moe_experts_touched": 8 * 896, "moe_held": 8 * 10752,
+           "row_passes_commit": 8 * 16}
+    per = {k: v / 8 for k, v in rec.items()}
+    least = roofline.least_pass_seconds(
+        config, peaks(), 48, per["kv_rows_full"],
+        per["moe_experts_touched"], per["moe_held"],
+        per["row_passes_commit"])["seconds"]
+    for measured, share in ((least, 100.0), (2 * least, 50.0)):
+        assert 100.0 * least / measured == pytest.approx(share)
+
+
+def test_the_cells_metrics_are_the_ones_the_issue_names():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    mine = sorted(m["name"] for m in bench["per_layer"]
+                  if CELL in m.get("workloads", []))
+    assert mine == sorted(NEW + [
+        "decode_fill_pct.closed", "decode_step_ctr_dev_ms.closed",
+        "kv_move_dev_pct.closed", "moe_dev_pct.context",
+        "moe_held_share_pct.context", "moe_imbalance.context"])
+    assert [m["workloads"] for m in bench["per_layer"]
+            if m["name"] in NEW] == [[CELL]] * 5
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "sdar-30b-a3b", "blockgen-closed", 1)
+    with open(os.path.join(REPO, "benchmarks", "traffic",
+                           "blockgen-closed.json")) as f:
+        mix = json.load(f)
+    assert (mix["loop"], mix["clients"], mix["requests_per_client"],
+            mix["lead_s"], mix["request_timeout_s"]) == (
+        "closed", 48, 16, 5.0, 90.0)
+    assert mix["prompt_tokens"] == {"dist": "lognormal", "median": 256,
+                                    "sigma": 0.8, "min": 32, "max": 1024}
+    assert mix["output_tokens"] == {"dist": "uniform", "min": 256,
+                                    "max": 512}
