@@ -746,6 +746,29 @@ def kernels_child(rehearse: bool) -> None:
     report(f"decode_attention_rows planes of {swa.kv_heads_of('full')} x "
            f"{swa.head_dim}/{swa.v_head_dim} seq={seq}", logits[:live],
            want[:live])
+    # -- the grouped expert product (ISSUE 39) against ragged_dot: the middle
+    # layer's groups of a stack of three, an empty group and a long one
+    from p2p_llm_tunnel_tpu.ops.pallas_grouped_matmul import (
+        grouped_matmul,
+        visit_list,
+    )
+
+    held, k, n = (4, 128, 128) if rehearse else (16, 2048, 768)
+    sizes = np.asarray(([3, 0, 150, 7] * (held // 4)), np.int32)
+    m = int(sizes.sum()) + 24  # rows past the held groups
+    lhs = jnp.asarray(rng.standard_normal((m, k), np.float32), jnp.bfloat16)
+    experts = jnp.asarray(
+        rng.standard_normal((3 * held, k, n), np.float32) * k ** -0.5,
+        jnp.bfloat16)
+    stacked = np.zeros(3 * held, np.int32)
+    stacked[held:2 * held] = sizes
+    for out in (jnp.float32, jnp.bfloat16):
+        got = grouped_matmul(lhs, experts, visit_list(jnp.asarray(sizes), held),
+                             out_dtype=out, interpret=rehearse)
+        want = jax.lax.ragged_dot(lhs, experts, jnp.asarray(stacked),
+                                  preferred_element_type=out)
+        report(f"moe_grouped_rows [{m},{k}]x[{3 * held},{k},{n}] "
+               f"-> {jnp.dtype(out).name}", got[:m - 24], want[:m - 24])
     if failures:
         raise SystemExit(f"kernels outside tolerance: {failures}")
 

@@ -236,6 +236,7 @@ class BlockDecodeMixin:
             global_metrics.inc("engine_decode_row_steps_total", live * steps)
             global_metrics.inc("engine_decode_slot_steps_total",
                                slots * steps)
+            self._note_moe("decode", (slots + 1) * self._block, rec)
             # every pass's `block` queries see the prefix below the block
             # and the block itself (the host's positions: the device's
             # carry may lead them by the bursts in flight)

@@ -41,6 +41,8 @@ from p2p_llm_tunnel_tpu.engine.scheduler import (
 )
 from p2p_llm_tunnel_tpu.engine.tokenizer import ByteTokenizer, StreamDecoder, Tokenizer
 from p2p_llm_tunnel_tpu.models.config import ModelConfig, get_config
+from p2p_llm_tunnel_tpu.models.moe import RAGGED as MOE_RAGGED
+from p2p_llm_tunnel_tpu.models.moe import grouped_product_branch
 from p2p_llm_tunnel_tpu.models.transformer import (
     decode_attention_branch,
     decode_branch_coverage,
@@ -50,6 +52,7 @@ from p2p_llm_tunnel_tpu.models.transformer import (
     init_params,
     prefill_attention_branch,
     prefill_into_cache,
+    reads_expert_stack,
     spec_attention_branch,
 )
 from p2p_llm_tunnel_tpu.utils.flight import (
@@ -2002,9 +2005,26 @@ class InferenceEngine(BlockDecodeMixin):
             "layers": {"held": m.n_layers,
                        "of": m.published_layers or m.n_layers},
             "experts": {"held": held, "first": first, "of": m.n_experts},
+            **self._moe_section(),
             "vocab_rows": {"held": m.vocab_size,
                            "of": m.vocab_size * m.layer_chips},
         }
+
+    def _moe_section(self) -> Dict[str, object]:
+        """/healthz ``config.model.expert_products``: the implementation
+        the grouped products take in a decode dispatch and in a chunk
+        prefill dispatch of ``prefill_rows`` rows (a smaller rung of the
+        row ladder may take another: its record says)."""
+        if not self.mcfg.n_experts:
+            return {}
+        e = self.ecfg
+        return {"expert_products": {
+            "decode": self._moe_branch(
+                "decode", (e.num_slots + 1) * (self.mcfg.block_length or 1)),
+            "chunk_prefill": self._moe_branch(
+                "chunk_prefill", e.prefill_rows * e.prefill_chunk)
+            if e.prefill_chunk > 0 else None,
+        }}
 
     def _fence_declined_decode_kernels(self) -> None:
         """An option that asked for a Pallas decode kernel (flash_decode /
@@ -2952,6 +2972,31 @@ class InferenceEngine(BlockDecodeMixin):
                     moe_assignments=made, moe_held=held,
                     moe_expert_tokens_max=fullest, moe_experts_touched=touched)
 
+    def _moe_branch(self, program: str, tokens: int) -> Optional[str]:
+        """The grouped expert products' implementation in ``program`` over
+        ``tokens`` token positions a layer, by the model layer's own rule
+        (models/moe.py ``grouped_product_branch``); None for a model
+        without routed layers."""
+        m = self.mcfg
+        if not m.n_experts:
+            return None
+        return grouped_product_branch(
+            m, self.mesh, tokens, reads_expert_stack(m, program),
+            getattr(self.params["blocks"]["moe_gate"], "dtype", jnp.bfloat16))
+
+    def _note_moe(self, program: str, tokens: int,
+                  rec: Optional[_Dispatch]) -> None:
+        """A dispatch of a routed model: its record says which
+        implementation the grouped products ran, and /metrics counts the
+        dispatches that took the kernel."""
+        branch = self._moe_branch(program, tokens)
+        if branch is None:
+            return
+        if branch != MOE_RAGGED:
+            global_metrics.inc("engine_moe_kernel_dispatches_total")
+        if rec is not None:
+            rec.attrs["moe"] = branch
+
     def _open_dispatch(self, span: str, program: str, parts=None,
                        **work) -> _Dispatch:
         """Open the record of the dispatch about to be made (executor
@@ -3121,6 +3166,7 @@ class InferenceEngine(BlockDecodeMixin):
                            time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
         global_metrics.inc("engine_prefill_positions_total", nb * t)
+        self._note_moe("prefill", nb * t, rec)
         self._count_kv_rows(
             [0] * len(runs), [len(r.request.prompt_ids) for r in runs], rec)
         out = first, (lp if lps.any() else None), plp
@@ -3229,6 +3275,7 @@ class InferenceEngine(BlockDecodeMixin):
                            time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
         global_metrics.inc("engine_prefill_positions_total", nb * t)
+        self._note_moe("chunk_prefill", nb * t, rec)
         self._count_kv_rows([start for _r, start, _g, _s in rows],
                             [len(seg) for _r, _s, seg, _f in rows], rec)
         out = first, (lp if lps.any() else None), None
@@ -3328,6 +3375,7 @@ class InferenceEngine(BlockDecodeMixin):
         self._note_program("ragged", (tot,), time.monotonic() - t_jit0)
         global_metrics.inc("engine_prefill_tokens_total", total)
         global_metrics.inc("engine_prefill_positions_total", tot)
+        self._note_moe("ragged_prefill", tot, rec)
         self._count_kv_rows([start for _r, start, _g, _s in rows],
                             [len(seg) for _r, _s, seg, _f in rows], rec)
         out = first, (lp if lps.any() else None), None
@@ -3525,6 +3573,7 @@ class InferenceEngine(BlockDecodeMixin):
             global_metrics.inc("engine_decode_row_steps_total", live * steps)
             global_metrics.inc("engine_decode_slot_steps_total",
                                slots * steps)
+            self._note_moe("decode", slots + 1, rec)
             # (the device's carry may lead these positions by the bursts
             # in flight)
             self._count_kv_rows(

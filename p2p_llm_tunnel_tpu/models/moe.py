@@ -12,13 +12,20 @@ What it does, for ``N`` tokens, ``E`` published experts, top-``k``:
   published width whatever is held) and picks ``k`` a token.
 - ``moe_experts``: the ``N * k`` assignments are sorted by expert, those to
   experts this process does not hold last; the held experts' SwiGLUs run as
-  three grouped matrix products over the sorted rows
-  (``jax.lax.ragged_dot``: on the TPU one Mosaic grouped-matmul kernel each,
-  which visits only the row tiles its groups cover), and each row's result
+  three grouped matrix products over the sorted rows, and each row's result
   goes back to its token, weighted.  Shapes are static and nothing is
   dropped under any imbalance: the sorted buffer has room for every
   assignment, so one expert may take them all.  Rows past the held groups
-  are never multiplied.
+  are never multiplied.  Which implementation a product takes is
+  ``grouped_product_branch``'s answer.  ``jax.lax.ragged_dot`` is on the TPU
+  the chip compiler's grouped kernel, whose visit of a group costs a row
+  tile of hundreds of rows whatever the group holds: at the cells' 1.5 to 64
+  rows an expert it ran at 188-528 GB/s of expert weights (PERF.md section
+  6, PR 39: 9.0 us for a 3.1 MB expert that streams in 3.8).
+  ``ops/pallas_grouped_matmul.py`` streams each touched expert once under a
+  row window that follows the group, 540-720 GB/s at the same shapes (4.4
+  us for that expert), and is taken where the experts are read in the stack
+  of all layers, on one chip, up to the rows an expert that were measured.
 - ``moe_shared``: the shared experts, one SwiGLU over every token.
 
 The held experts are ``cfg.experts_held``: with ``layer_chips`` chips sharing
@@ -43,6 +50,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from p2p_llm_tunnel_tpu.models.quant import mm, round_act
+from p2p_llm_tunnel_tpu.ops.pallas_grouped_matmul import (
+    GROUPED_KERNEL,
+    grouped_matmul,
+    visit_list,
+    vmem_bytes,
+)
 
 #: What a routed layer counts of one call, int32: assignments of counted
 #: tokens, those to held experts, the fullest held expert's tokens, and the
@@ -50,6 +63,13 @@ from p2p_llm_tunnel_tpu.models.quant import mm, round_act
 STATS = 4
 #: The leaves that hold experts, [L, E, ...] in a stacked block tree.
 EXPERT_LEAVES = ("moe_gate", "moe_up", "moe_down")
+#: A dispatch record's ``moe`` where the products are the compiler's.
+RAGGED = "ragged-dot"
+#: VMEM the grouped kernel may ask for (a v5e core has 128 MiB).
+KERNEL_VMEM = 96 * 2**20
+#: Sorted rows an expert (of all the published ones: what a held expert
+#: expects) the kernel is taken up to: the most the contest measured.
+KERNEL_ROWS_AN_EXPERT = 64
 
 
 def init_moe_blocks(cfg, keys, dense_fn, per_expert: bool = False) -> dict:
@@ -118,6 +138,47 @@ def route(cfg, blk, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return top_i, top_w * cfg.routed_scale
 
 
+def grouped_product_branch(cfg, mesh, tokens: int, stacked: bool = True,
+                           dtype=jnp.bfloat16) -> str:
+    """Which implementation the three grouped products of a routed layer
+    take in a program that runs ``tokens`` token positions a layer:
+    ``"ragged-dot"`` (``jax.lax.ragged_dot``: the chip compiler's grouped
+    kernel) or ``ops/pallas_grouped_matmul.py``'s kernel by its name
+    (``GROUPED_KERNEL``).  Answered from what the code observes, no option
+    (ISSUE 39):
+
+    - the backend: the kernel is the TPU's (on the CPU only under
+      ``cfg.flash_interpret``, the tests' interpret mode, or
+      ``cfg.flash_force``, their lowering-only probes); ``cfg.flash`` off
+      keeps the reference everywhere, as in attention;
+    - the mesh: a ``pallas_call`` is not GSPMD-partitioned, ``ragged_dot``
+      is (``--ep`` / ``--tp`` shard the expert leaves);
+    - ``stacked``: whether the layer reads its experts where they lie in
+      the stack of all layers (``moe_mlp``'s ``stacked``): a layer's slice
+      handed to a kernel is a copy first, and the programs that slice are
+      the ones a mesh may shard;
+    - the static shapes: the kernel wins where an expert gets few of the
+      ``tokens * k`` sorted rows (``KERNEL_ROWS_AN_EXPERT`` of the
+      published experts: the contest of PERF.md section 6, PR 39), and it
+      keeps a block of rows, its result and a ring of an expert's tiles in
+      VMEM (``KERNEL_VMEM``); ``dtype`` is the rows' and the experts'.
+    """
+    backend = jax.default_backend()
+    if not (stacked and cfg.flash
+            and (backend == "tpu" or cfg.flash_interpret
+                 or cfg.flash_force)):
+        return RAGGED
+    if mesh is not None and any(n > 1 for n in dict(mesh.shape).values()):
+        return RAGGED
+    rows = tokens * cfg.n_experts_per_tok
+    out = jnp.float32 if cfg.residual_f32 else dtype
+    need = max(vmem_bytes(rows, cfg.dim, cfg.expert_dim, dtype, out),
+               vmem_bytes(rows, cfg.expert_dim, cfg.dim, dtype, out))
+    if rows > KERNEL_ROWS_AN_EXPERT * cfg.n_experts or need > KERNEL_VMEM:
+        return RAGGED
+    return GROUPED_KERNEL
+
+
 def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
             counted: Optional[jnp.ndarray] = None, stacked=None, layer=None,
             router_in: Optional[jnp.ndarray] = None):
@@ -173,9 +234,13 @@ def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
             jnp.sum((real_sizes > 0).astype(jnp.int32)),
         ])
     with jax.named_scope("moe_experts"):
-        experts = blk
-        if stacked is not None:
-            experts = stacked
+        experts = blk if stacked is None else stacked
+        # (the families that hand over the stack refuse a mesh at start-up)
+        kernel = grouped_product_branch(
+            cfg, None, n, stacked is not None, x.dtype) != RAGGED
+        if kernel:
+            visits = visit_list(sizes, layer * held)
+        elif stacked is not None:
             sizes = jax.lax.dynamic_update_slice(
                 jnp.zeros((stacked["moe_gate"].shape[0],), jnp.int32), sizes,
                 (layer * held,))
@@ -185,10 +250,19 @@ def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
         # last product takes them; its results reach the weighted sum whole
         wide = ({"preferred_element_type": jnp.float32}
                 if cfg.residual_f32 else {})
-        gate = jax.lax.ragged_dot(rows, experts["moe_gate"], sizes, **wide)
-        up = jax.lax.ragged_dot(rows, experts["moe_up"], sizes, **wide)
+
+        def product(lhs, name):
+            if not kernel:
+                return jax.lax.ragged_dot(lhs, experts[name], sizes, **wide)
+            return grouped_matmul(
+                lhs, experts[name], visits,
+                out_dtype=wide.get("preferred_element_type", lhs.dtype),
+                interpret=cfg.flash_interpret)
+
+        gate = product(rows, "moe_gate")
+        up = product(rows, "moe_up")
         inner = round_act((act_fn(gate) * up).astype(rows.dtype), aq)
-        down = jax.lax.ragged_dot(inner, experts["moe_down"], sizes, **wide)
+        down = product(inner, "moe_down")
         # (rows past the held groups hold nothing defined: their weight is 0)
         part = jnp.where(weight[:, None] > 0,
                          down.astype(jnp.float32) * weight[:, None], 0.0)

@@ -259,6 +259,17 @@ def split_experts(cfg: ModelConfig, blocks):
             stacked)
 
 
+def reads_expert_stack(cfg: ModelConfig, program: str) -> bool:
+    """Whether ``program`` (the engine's name for it) hands a routed layer
+    the experts of all layers to read where they lie (``moe_mlp``'s
+    ``stacked``): every program of a family module, and the two that take
+    :func:`split_experts` here, the decode passes and chunk prefill of a
+    model that generates by blocks."""
+    return _family_module(cfg) is not None or (
+        bool(cfg.n_experts and cfg.block_length)
+        and program in ("decode", "chunk_prefill"))
+
+
 def _moe_total(stats):
     """Per-layer counts [L, STATS] of a layer scan -> their sum; zeros for a
     dense model."""

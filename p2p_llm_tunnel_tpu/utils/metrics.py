@@ -66,6 +66,11 @@ METRICS_CATALOG: Dict[str, str] = {
         "over engine_block_row_passes_total it is what a row's pass yields "
         "(counter)"
     ),
+    "engine_moe_kernel_dispatches_total": (
+        "decode bursts and prefill dispatches of a routed model whose "
+        "grouped expert products ran as the repo's Pallas kernel (the "
+        "record's moe is not ragged-dot) (counter)"
+    ),
     "engine_moe_assignments_total": (
         "token-to-expert assignments the routed layers made of real tokens, "
         "over every expert layer of every dispatch (counter)"

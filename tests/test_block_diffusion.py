@@ -151,11 +151,11 @@ def test_a_token_equal_to_the_mask_id_is_a_token(model):
 
 # ---- the engine -----------------------------------------------------------------
 
-def _engine(**kw):
+def _engine(model_cfg=None, **kw):
     from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
 
     kw = {"num_slots": 3, "decode_steps": 2, "prefill_chunk": 16, **kw}
-    return InferenceEngine(engine_cfg=EngineConfig(
+    return InferenceEngine(model_cfg=model_cfg, engine_cfg=EngineConfig(
         model="tiny-sdar-moe", max_seq=MAX_SEQ, dtype="float32", mux=True,
         prefix_cache=True, prefix_pool_blocks=32, **kw))
 
@@ -423,6 +423,32 @@ def test_counters_are_the_sums_of_the_records():
     section = eng._model_section()["generation"]
     assert section == {"block_length": 4, "denoise_steps": 2,
                        "remasking": "sequential", "mask_token_id": 258}
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged-dot", "kernel"])
+def test_the_records_moe_and_the_kernel_counter_are_held_to_each_other(
+        kernel):
+    """(ISSUE 39) Every pass burst and chunk-prefill record says which
+    grouped product its program ran, the counter grows by the records that
+    say the kernel, and the kernel's passes (interpreted here) give the
+    reference's log-probabilities as ``ragged_dot``'s do."""
+    from tests import moe_records
+
+    cfg = get_config("tiny-sdar-moe", flash_interpret=kernel,
+                     vocab_size=259)
+    eng = _engine(cfg)
+    prompt = _prompt(73, 22)
+    with moe_records.tracing():
+        before = moe_records.global_metrics.counter(moe_records.COUNTER)
+        (events,) = _generate(eng, [(prompt, 7, False)])
+        grew = moe_records.global_metrics.counter(
+            moe_records.COUNTER) - before
+        records = [r for r in moe_records.global_tracer.records()
+                   if r.name in ("engine.decode_burst",
+                                 "engine.prefill_segment")]
+    _check_against_reference(eng, prompt, events)
+    moe_records.check(eng, grew, records, kernel)
 
 
 @pytest.mark.parametrize("option,named", [

@@ -266,10 +266,10 @@ def test_a_latent_page_survives_pool_slot_pool(model):
 
 # ---- the engine -----------------------------------------------------------------
 
-def _engine(model_name="tiny-mla-moe-ep2s", **kw):
+def _engine(model_name="tiny-mla-moe-ep2s", model_cfg=None, **kw):
     from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
 
-    return InferenceEngine(engine_cfg=EngineConfig(
+    return InferenceEngine(model_cfg=model_cfg, engine_cfg=EngineConfig(
         model=model_name, num_slots=2, max_seq=128, dtype="float32",
         decode_steps=2, **kw))
 
@@ -393,6 +393,29 @@ def test_the_counters_and_the_ledger_carry_the_counts():
     assert 0 < grew[1] < grew[0]  # a share holds some of them, not all
 
 
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged-dot", "kernel"])
+def test_the_records_moe_and_the_kernel_counter_are_held_to_each_other(
+        kernel):
+    """(ISSUE 39) Every decode and prefill record of a share says which
+    grouped product its program ran; the counter grows by the records that
+    say the kernel; the kernel (interpreted here) emits ``ragged_dot``'s
+    tokens."""
+    from tests import moe_records
+
+    def run(interpret):
+        eng = _engine(
+            model_cfg=get_config("tiny-mla-moe-ep2s", flash_interpret=interpret,
+                                 vocab_size=259),
+            mux=True, prefix_cache=True, prefix_pool_blocks=16)
+        return (eng,) + moe_records.run_traced(eng, _prompt(9, 37), 5)
+
+    eng, toks, grew, records = run(kernel)
+    moe_records.check(eng, grew, records, kernel)
+    if kernel:
+        assert toks == run(False)[1]
+
+
 REFUSED = {
     "quant-int8": dict(quant="int8"),
     "quant-int4": dict(quant="int4"),
@@ -421,6 +444,9 @@ def test_healthz_names_the_cache_form_and_the_share():
         "bytes_per_token": cfg.n_layers * 40 * 4}
     assert section["layers"] == {"held": 4, "of": 4}
     assert section["experts"] == {"held": 4, "first": 0, "of": 8}
+    # (a CPU backend: the grouped products are ragged_dot's)
+    assert set(section["expert_products"]) == {"decode", "chunk_prefill"}
+    assert section["expert_products"]["decode"] == moe.RAGGED
     assert section["vocab_rows"] == {"held": cfg.vocab_size,
                                      "of": 2 * cfg.vocab_size}
     assert eng._prefix_block_bytes == 16 * cfg.n_layers * 40 * 4

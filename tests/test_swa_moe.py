@@ -338,10 +338,10 @@ def test_a_page_of_two_kinds_is_restored_into_a_ring(model):
 
 # ---- the engine -----------------------------------------------------------------
 
-def _engine(model_name="tiny-swa-moe-ep2s", **kw):
+def _engine(model_name="tiny-swa-moe-ep2s", model_cfg=None, **kw):
     from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
 
-    return InferenceEngine(engine_cfg=EngineConfig(
+    return InferenceEngine(model_cfg=model_cfg, engine_cfg=EngineConfig(
         model=model_name, num_slots=2, max_seq=128, dtype="float32",
         decode_steps=2, **kw))
 
@@ -514,6 +514,30 @@ def test_the_records_and_the_counters_carry_the_rows_read_by_kind():
     assert dense._attn_kinds == (False, False) and dense._ring == 0
 
 
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged-dot", "kernel"])
+def test_the_records_moe_and_the_kernel_counter_are_held_to_each_other(
+        kernel):
+    """(ISSUE 39) Every decode and prefill record of a share says which
+    grouped product its program ran; the counter grows by the records that
+    say the kernel; the kernel (interpreted here) emits ``ragged_dot``'s
+    tokens."""
+    from tests import moe_records
+
+    def run(interpret):
+        eng = _engine(
+            model_cfg=get_config("tiny-swa-moe-ep2s", flash_interpret=interpret,
+                                 vocab_size=259),
+            mux=True, prefix_cache=True, prefix_pool_blocks=16,
+            prefill_chunk=16)
+        return (eng,) + moe_records.run_traced(eng, _prompt(9, 37), 5)
+
+    eng, toks, grew, records = run(kernel)
+    moe_records.check(eng, grew, records, kernel)
+    if kernel:
+        assert toks == run(False)[1]
+
+
 REFUSED = {
     "quant-int8": dict(quant="int8"),
     "quant-int4": dict(quant="int4"),
@@ -638,6 +662,9 @@ def test_healthz_names_both_kinds_of_plane_and_a_slots_bytes():
     assert eng._prefix_block_bytes == 16 * cache["bytes_per_token"]
     assert section["layers"] == {"held": 7, "of": 7}
     assert section["experts"] == {"held": 4, "first": 0, "of": 8}
+    # (a CPU backend: the grouped products are ragged_dot's)
+    assert set(section["expert_products"]) == {"decode", "chunk_prefill"}
+    assert section["expert_products"]["decode"] == moe.RAGGED
     assert section["vocab_rows"] == {"held": eng.mcfg.vocab_size,
                                      "of": 2 * eng.mcfg.vocab_size}
     assert eng._prefix_snapshot_meta()["page"] == [

@@ -287,6 +287,20 @@ def test_chunk_prefill_makes_no_cache_plane_in_its_layer_loop(
     assert aliased.count("alias") == len(cache)
 
 
+def _grouped_products(hlo, kernel):
+    """The routed layers' grouped products in a compiled program: the
+    repo's kernel where a TPU backend's branch was traced (ISSUE 39), else
+    the compiler's ``ragged-dot``; never both."""
+    from p2p_llm_tunnel_tpu.ops.pallas_grouped_matmul import GROUPED_KERNEL
+
+    found = {
+        True: len([line for line in hlo.splitlines()
+                   if "tpu_custom_call" in line and GROUPED_KERNEL in line]),
+        False: hlo.count("ragged-dot")}
+    assert not found[not kernel]
+    return found[kernel]
+
+
 def _on(chip, tree):
     return jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
@@ -496,6 +510,15 @@ SHARE_PROGRAMS = {
     "prefill-128": lambda T, cfg, p, c, b: T.prefill_into_cache(
         cfg, p, b["tok128"], b["row8"], c, b["row8"],
         return_prompt_logprobs=True),
+    # as a TPU backend runs them (ISSUE 39): the grouped products as the
+    # repo's kernel, a chunk's 8,192 sorted rows in blocks
+    "decode-on-the-chip": lambda T, cfg, p, c, b: T.decode_step(
+        replace(cfg, flash_force=True), p, c, b["row33"], b["row33"],
+        kv_view=4096),
+    "chunk-512-on-the-chip":
+        lambda T, cfg, p, c, b: T.chunk_prefill_into_cache(
+            replace(cfg, flash_force=True), p, b["tok512"], b["row2"],
+            b["row2"], c, b["row2"], kv_view=4096),
 }
 
 
@@ -526,7 +549,8 @@ def test_the_share_presets_programs_fit_one_chip(chip, program):
             + m.output_size_in_bytes - m.alias_size_in_bytes + pool)
     assert held < 15.75 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
     assert m.argument_size_in_bytes > 10 * 2 ** 30  # the share is all there
-    assert compiled.as_text().count("ragged-dot") >= 3
+    assert _grouped_products(
+        compiled.as_text(), kernel=program.endswith("on-the-chip")) >= 3
 
 
 # ---------------------------------------------------------------------------
@@ -676,7 +700,8 @@ def test_the_mimo_share_fits_one_chip_at_its_stated_bytes(chip, program):
     held = (m.argument_size_in_bytes + m.temp_size_in_bytes
             + m.output_size_in_bytes - m.alias_size_in_bytes + pool)
     assert held < 13.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
-    assert compiled.as_text().count("ragged-dot") >= 3
+    assert _grouped_products(
+        compiled.as_text(), kernel=program == "decode-on-the-chip") >= 3
 
 
 # ---------------------------------------------------------------------------
@@ -689,6 +714,12 @@ BD_PROGRAMS = {
     "block-decode-2048": lambda T, B, cfg, p, c, b: B.block_decode_step(
         cfg, p, c, b["blk"], b["row49"], b["row49"], kv_view=2048,
         with_stats=True),
+    # (the branch a TPU backend takes: the grouped products as the repo's
+    # kernel, ISSUE 39)
+    "block-decode-on-the-chip": lambda T, B, cfg, p, c, b:
+        B.block_decode_step(
+            replace(cfg, flash_force=True), p, c, b["blk"], b["row49"],
+            b["row49"], kv_view=2048, with_stats=True),
     "block-decode-256": lambda T, B, cfg, p, c, b: B.block_decode_step(
         cfg, p, c, b["blk"], b["row49"], b["row49"], kv_view=256,
         with_stats=True),
@@ -741,7 +772,7 @@ def test_the_block_programs_hold_the_cache_as_stated_and_fit_one_chip(
              if re.search(r"= \w+\[(?:896|128),(?:2048,768|768,2048)\]", line)
              and re.search(r" (?:copy|convert|dynamic-slice)\(", line)]
     assert moved == []
-    assert hlo.count("ragged-dot") >= 3
+    assert _grouped_products(hlo, kernel=program.endswith("on-the-chip")) >= 3
     weights = sum(math.prod(x.shape) * x.dtype.itemsize
                   for x in jax.tree.leaves(params))
     assert 9.96e9 < weights < 9.98e9  # 4,984 M parameters
