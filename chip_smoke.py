@@ -449,14 +449,15 @@ def serve_phase(size: dict) -> dict:
     # After warmup, before any request: the set-up facts.
     healthz = get_json(port, "/healthz")
     dev = device_of(healthz, platform)
-    metrics = _request(port, "GET", "/metrics")[1].decode()
     warm_s = healthz["warmup_compile_s"]
+    started = healthz["startup"]
+    slowest = started["slowest_program"] or {"key": "-", "seconds": 0.0}
     say(f"device: platform={dev['platform']} kind={dev['device_kind']!r} "
         f"count={dev['count']}")
     say(f"set-up: process start to ready {stack.ready_wall_s:.1f}s, of which "
-        f"warmup {warm_s:.1f}s for "
-        f"{int(metric_value(metrics, 'engine_warmup_programs'))} programs "
-        f"(slowest {metric_value(metrics, 'engine_warmup_compile_max_s'):.1f}s)"
+        f"warmup {warm_s:.1f}s for {started['programs']} programs "
+        f"(slowest {slowest['key']} {slowest['seconds']:.1f}s; "
+        f"{started['persistent_misses']} not in the compile cache)"
         f"; imports + engine init {stack.ready_wall_s - warm_s:.1f}s")
 
     # Two short prompts through the non-streamed Ollama route.  Shorter

@@ -23,6 +23,7 @@ import asyncio
 import os
 import random
 import sys
+import time
 from typing import Optional
 
 from p2p_llm_tunnel_tpu.utils.logging import get_logger, init_logging
@@ -559,6 +560,9 @@ async def _serve_once(args, drain: "Optional[asyncio.Event]" = None) -> None:
             # Multi-host follower rank: the replay loop above ran to
             # completion (leader stopped); nothing to serve here.
             return
+    global _TUNNEL_TIMED
+    tunnel_t0 = None if _TUNNEL_TIMED else time.monotonic()
+    _TUNNEL_TIMED = True
     channel, signaling = await connect(
         args.signal, args.room, args.transport,
         stun_server=args.stun, relay=args.relay,
@@ -573,6 +577,7 @@ async def _serve_once(args, drain: "Optional[asyncio.Event]" = None) -> None:
             drain_timeout=getattr(args, "drain_timeout", 0.0),
             stream_grace_s=getattr(args, "stream_grace_s", -1.0),
             stream_journal_bytes=getattr(args, "stream_journal_bytes", 0),
+            tunnel_t0=tunnel_t0,
         )
         if backend is not None:
             await run_serve(channel, backend=backend, **kwargs)
@@ -604,6 +609,8 @@ def require_tpu_backend(platform: str, who: str) -> None:
 
 
 _BACKEND = None
+#: The first session's signaling connect has been timed (startup.tunnel).
+_TUNNEL_TIMED = False
 #: Engines constructed by this process — the Ctrl+C path snapshots their
 #: prefix pools (asyncio.run tears down before any engine.stop() runs).
 _ENGINES: list = []
@@ -619,15 +626,21 @@ async def _engine_backend(args):
     if _BACKEND is not None:
         return _BACKEND
     from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
+    from p2p_llm_tunnel_tpu.utils.flight import global_compile_watch as watch
 
-    tokenizer = None
-    if args.tokenizer:
-        from p2p_llm_tunnel_tpu.engine.tokenizer import HFTokenizer
-
-        tokenizer = HFTokenizer(args.tokenizer)
-
+    # The start-up journal (ISSUE 40): imports, tokenizer, backend,
+    # engine_build and warmup tile startup.process in this order.
     import jax
 
+    if args.tokenizer:
+        from p2p_llm_tunnel_tpu.engine.tokenizer import HFTokenizer
+    watch.add_span("startup.imports", t0=watch.process_began()[0])
+    tokenizer = None
+    if args.tokenizer:
+        with watch.startup_phase("startup.tokenizer") as attrs:
+            tokenizer = HFTokenizer(args.tokenizer)
+            attrs["entries"] = tokenizer.vocab_size
+    t_backend = time.monotonic()
     mesh = None
     if args.coordinator:
         # Multi-host: join the runtime FIRST (jax.devices() becomes global),
@@ -659,6 +672,10 @@ async def _engine_backend(args):
         "engine device: platform=%s kind=%s local_devices=%d; compile "
         "cache at %s", devices[0].platform, devices[0].device_kind,
         len(devices), jax.config.jax_compilation_cache_dir,
+    )
+    watch.add_span(
+        "startup.backend", t0=t_backend, platform=devices[0].platform,
+        device_kind=devices[0].device_kind, devices=len(devices),
     )
 
     def make_engine(seed: int) -> InferenceEngine:
@@ -733,11 +750,12 @@ async def _engine_backend(args):
             )
         log.info("starting %d engine replicas: model=%s slots=%d",
                  args.replicas, args.model, args.slots)
-        router = ReplicaRouter(
-            [make_engine(i) for i in range(args.replicas)], args.model
-        )
-        _ENGINES.extend(router.engines)
-        await router.start()
+        with watch.startup_phase("startup.engine_build"):
+            router = ReplicaRouter(
+                [make_engine(i) for i in range(args.replicas)], args.model
+            )
+            _ENGINES.extend(router.engines)
+            await router.start()
         # Pre-compile every decode variant BEFORE serving: a first-hit
         # compile inside the live loop would stall the event loop past the
         # transport's 15 s dead-peer timeout and kill the tunnel.
@@ -748,6 +766,7 @@ async def _engine_backend(args):
         from p2p_llm_tunnel_tpu.engine.api import engine_backend
 
         log.info("starting TPU engine: model=%s slots=%d", args.model, args.slots)
+        t_build = time.monotonic()
         engine = make_engine(0)
         _ENGINES.append(engine)
         spmd = getattr(engine, "_spmd", None)  # tests inject fake engines
@@ -761,9 +780,11 @@ async def _engine_backend(args):
             await asyncio.to_thread(engine.spmd_follower_loop)
             return None
         await engine.start()
+        watch.add_span("startup.engine_build", t0=t_build)
         # See replica branch: compile all decode variants before traffic.
         await engine.warmup()
         _BACKEND = engine_backend(engine, args.model)
+    watch.mark_ready()
     return _BACKEND
 
 
@@ -937,6 +958,10 @@ async def _amain(args) -> None:
 
 
 def main(argv: Optional[list] = None) -> None:
+    t_main = time.monotonic()  # startup.process falls back to this line
+    from p2p_llm_tunnel_tpu.utils.flight import global_compile_watch
+
+    global_compile_watch.process_began(t_main)
     init_logging()
     import signal as _signal
 

@@ -49,7 +49,11 @@ from p2p_llm_tunnel_tpu.protocol.frames import (
     parse_deadline_ms,
 )
 from p2p_llm_tunnel_tpu.transport.base import Channel, ChannelClosed
-from p2p_llm_tunnel_tpu.utils.flight import global_blackbox, global_flight
+from p2p_llm_tunnel_tpu.utils.flight import (
+    global_blackbox,
+    global_compile_watch,
+    global_flight,
+)
 from p2p_llm_tunnel_tpu.utils.logging import get_logger
 from p2p_llm_tunnel_tpu.utils.metrics import (
     Metrics,
@@ -748,6 +752,11 @@ async def _send_healthz(
         "warmup_compile_s": round(
             global_metrics.gauge("engine_warmup_compile_s"), 1
         ),
+        # ISSUE 40: where the start went, from the kernel's process start
+        # to ready: seconds by phase, the warmed programs, how many of
+        # them the compile cache on disk held, the slowest one.  Always
+        # there, tracing on or off (empty phases under the HTTP backend).
+        "startup": global_compile_watch.startup_section(),
         # ISSUE 5 observability: the TTFT decomposition (queue wait vs
         # prefill execution), the multiplexing controller's current prefill
         # budget, and shared-prefix admission dedup — the numbers that say
@@ -937,6 +946,7 @@ async def run_serve(
     drain_timeout: float = 0.0,
     stream_grace_s: float = -1.0,
     stream_journal_bytes: int = 0,
+    tunnel_t0: Optional[float] = None,
 ) -> None:
     """Run the provider side until the tunnel dies; raises to trigger retry.
 
@@ -966,6 +976,10 @@ async def run_serve(
     cancelled (today's typed ``peer_lost`` outcome, strictly narrowed).
     Defaults: resume.DEFAULT_GRACE_S / DEFAULT_JOURNAL_BYTES;
     ``stream_grace_s=0`` disables resume entirely (legacy wire).
+
+    ``tunnel_t0`` (a process's first session only): the monotonic instant
+    its signaling connect began; the start-up journal's ``startup.tunnel``
+    span runs from it to AGREE sent.
     """
     if backend is None:
         backend = http_backend(upstream_url, advertise_prefix)
@@ -1010,6 +1024,8 @@ async def run_serve(
              "on" if flow.enabled else "off",
              f", role {agree.role}" if agree.role != "both" else "",
              f", fabric peer id {peer_label!r}" if peer_label else "")
+    if tunnel_t0 is not None:
+        global_compile_watch.add_span("startup.tunnel", t0=tunnel_t0)
 
     pending: Dict[int, Tuple[RequestHeaders, bytearray]] = {}
     kv_pending: Dict[int, Tuple[KvPagesManifest, bytearray]] = {}
@@ -1217,11 +1233,14 @@ async def _serve_dispatch(
                     # scripts/traceview.py.  The engine flight recorder's
                     # slices ride the same export (ISSUE
                     # 12): one journal, so the fleet stitcher gives every
-                    # peer its own engine-flight lane for free.
+                    # peer its own engine-flight lane for free.  So does
+                    # the start-up journal (ISSUE 40), whatever the span
+                    # rings have turned over since.
                     trace = global_tracer.chrome_trace()
                     trace["traceEvents"] = (
                         list(trace["traceEvents"])
                         + global_flight.chrome_events()
+                        + global_compile_watch.chrome_events()
                     )
                     await _send_simple(
                         channel, req.stream_id, 200,

@@ -105,6 +105,12 @@ def _program_key(kind: str, shape: Tuple[int, ...]) -> str:
     return f"{kind}[{','.join(str(s) for s in shape)}]"
 
 
+def _tree_bytes(tree) -> int:
+    """Bytes of a pytree's arrays, from shapes and types (no fetch)."""
+    return sum(int(x.size) * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(tree))
+
+
 def chunk_row_ladder(prefill_rows: int) -> Tuple[int, ...]:
     """Row counts a chunk-prefill dispatch can be padded to: the powers
     of two below ``prefill_rows``, then ``prefill_rows`` itself (8 -> 1, 2,
@@ -545,7 +551,10 @@ class InferenceEngine(BlockDecodeMixin):
                 name="import-pallas", daemon=True,
             ).start()
         placed = False  # params already carry their mesh shardings
+        t_params = time.monotonic()
+        params_source = "injected"
         if params is None:
+            params_source = "checkpoint" if self.ecfg.ckpt_path else "random"
             if self.ecfg.ckpt_path:
                 from p2p_llm_tunnel_tpu.models.checkpoint import load_checkpoint
 
@@ -622,6 +631,13 @@ class InferenceEngine(BlockDecodeMixin):
             param_shardings = _pshard(self.mcfg, mesh, params)
         self.params = params
         self.param_shardings = param_shardings
+        # Start-up journal (ISSUE 40).  The host's view: the device may
+        # still be filling the arrays, and whoever reads them first waits.
+        global_compile_watch.add_span(
+            "startup.params", t0=t_params, source=params_source,
+            quant=self.ecfg.quant or "none", bytes=_tree_bytes(params),
+        )
+        t_cache = time.monotonic()
 
         b, s = self.ecfg.num_slots, self.ecfg.max_seq
         # One extra cache row: the scratch slot that padded prefill rows
@@ -1013,6 +1029,11 @@ class InferenceEngine(BlockDecodeMixin):
             # leak-gate contract tests/test_paged_pool.py pins.
             self.scheduler.page_reserve = self._reserve_pages
 
+        global_compile_watch.add_span(
+            "startup.cache_alloc", t0=t_cache,
+            bytes=_tree_bytes(self.kv_cache) + (
+                _tree_bytes(self._pool) if self._prefix is not None else 0),
+        )
         # Publish the fence registry where /healthz can read it without
         # holding an engine reference (latest engine wins — one serving
         # engine per process is the deployed shape, same contract as the
@@ -1695,7 +1716,9 @@ class InferenceEngine(BlockDecodeMixin):
         there)."""
         loop = asyncio.get_running_loop()
         t_warm0 = time.monotonic()
-        compile_mark = global_compile_watch.mark()
+        # JAX's compile events, kept by thread for warm-up's length: each
+        # program's record (CompileWatch.note) finds its own among them
+        global_compile_watch.listen(True)
         await self._warm_aot_parallel(loop)
         # Serial execute pass DRIVEN BY warmup_plan() — the same
         # enumeration the AOT phase lowered and TC17 checks dispatch
@@ -1742,32 +1765,31 @@ class InferenceEngine(BlockDecodeMixin):
             )
         finally:
             self._warming = False
+        global_compile_watch.add_span("startup.execute", t0=t0)
         if self._prefix is not None:
             # Copy-op programs sit outside the bucket-grid plan (no
             # _program_key kind); warmed here so pool hits never compile
             # on the serving path.
-            await loop.run_in_executor(self._executor, self._warm_prefix)
+            with global_compile_watch.startup_phase("startup.prefix_warm"):
+                await loop.run_in_executor(self._executor, self._warm_prefix)
         # Observability (ISSUE 4): total warmup compile wall time — the
-        # set-up a start pays before serving its first request — and the
-        # launch-count gauge, both surfaced by serve's /healthz.
+        # set-up a start pays before serving its first request — surfaced
+        # by serve's /healthz.  The per-program breakdown (count, slowest,
+        # what the compile cache on disk held) is the start-up journal's,
+        # in /healthz's ``startup`` section.  From here on a first-seen
+        # program key on the serving path is a mid-serve cold compile.
         global_metrics.set_gauge(
             "engine_warmup_compile_s", time.monotonic() - t_warm0
         )
-        # Cold-start breakdown (ISSUE 12): the per-program grid this
-        # warmup compiled/loaded — count + slowest single program next to
-        # the wall total, published as gauges.  From here on a first-seen
-        # program key on the serving path is a mid-serve cold compile.
-        warm_events = global_compile_watch.since(compile_mark)
-        global_metrics.set_gauge(
-            "engine_warmup_programs",
-            len({e["key"] for e in warm_events}),
-        )
-        global_metrics.set_gauge(
-            "engine_warmup_compile_max_s",
-            max((e["seconds"] for e in warm_events), default=0.0),
-        )
         self._warmup_done = True
-        await loop.run_in_executor(self._executor, self._set_kernel_gauge)
+        with global_compile_watch.startup_phase(
+                "startup.launch_probe") as attrs:
+            report = await loop.run_in_executor(
+                self._executor, self._set_kernel_gauge)
+            if report is not None:
+                attrs["pallas_calls"] = report["layer_body_pallas"]
+        global_compile_watch.listen(False)
+        global_compile_watch.add_span("startup.warmup", t0=t_warm0)
 
     def decode_launch_report(self, view: Optional[int] = None,
                              steps: Optional[int] = None):
@@ -1832,14 +1854,14 @@ class InferenceEngine(BlockDecodeMixin):
         finally:
             self.mcfg = old
 
-    def _set_kernel_gauge(self) -> None:
+    def _set_kernel_gauge(self) -> Optional[Dict[str, int]]:
         """Publish ``engine_decode_kernels_per_step``: launch-proxy major
         kernels in the layer-scan body of the decode burst
-        (:meth:`decode_launch_report`)."""
+        (:meth:`decode_launch_report`, which this returns)."""
         report = self.decode_launch_report()
         if report is None or not report["layer_body_major"]:
             log.info("decode launch-count probe unavailable on this host")
-            return
+            return None
         global_metrics.set_gauge(
             "engine_decode_kernels_per_step", report["layer_body_major"]
         )
@@ -1849,6 +1871,7 @@ class InferenceEngine(BlockDecodeMixin):
             report["layer_body_major"], report["layer_body_ops"],
             report["layer_body_pallas"],
         )
+        return report
 
     def _note_program(self, kind: str, shape: Tuple[int, ...],
                       seconds: float) -> None:
@@ -1856,8 +1879,10 @@ class InferenceEngine(BlockDecodeMixin):
         FIRST execution of program ``(kind, shape)`` in this process.
 
         During warmup the event lands in the journal as the per-program
-        cold-start breakdown (``cache_hit`` when the parallel AOT phase
-        already compiled the key, so the serial pass only loaded it).
+        cold-start breakdown (``aot_hit`` when the parallel AOT phase
+        already compiled the key, so the serial pass only loaded it; JAX's
+        own compile events of the dispatching thread where warm-up opened
+        a scope for them).
         After :meth:`warmup` declared the grid complete, a first-seen key
         is a MID-SERVE COLD COMPILE — a hole in the warmup bucket grid
         (the ``test_warmup_aot`` bug class) — counted, journaled cold, and
@@ -1880,7 +1905,7 @@ class InferenceEngine(BlockDecodeMixin):
         global_compile_watch.note(
             program=kind, key=key, shape=list(shape), seconds=seconds,
             phase="serve" if cold else "warmup",
-            cache_hit=key in self._aot_keys, cold=cold,
+            aot_hit=key in self._aot_keys, cold=cold,
         )
         if cold:
             global_metrics.inc("engine_cold_compiles_total")
@@ -2443,6 +2468,13 @@ class InferenceEngine(BlockDecodeMixin):
                  lambda: self._copy_out.lower(*out_args))
             )
 
+        # _one is, statement for statement, what it was before the journal
+        # (ISSUE 40): every form of it that did more, however little and
+        # wherever in it, read the phase 1.1-2.5 s longer in a warm 7B
+        # start, and nobody knows why (PERF.md, PR 40).  The journal splits
+        # a record into Python's part and XLA's inside note(), from what
+        # JAX itself reported in this thread.  The copy programs' records
+        # are the serial pass's (_warm_prefix).
         def _one(label, kind, shape, thunk):
             t1 = time.monotonic()
             try:
@@ -2452,7 +2484,7 @@ class InferenceEngine(BlockDecodeMixin):
                 if kind is not None:
                     # The per-program cold-start breakdown (ISSUE 12): the
                     # AOT compile carries the real compile seconds; the
-                    # serial pass then records a cache_hit load of the
+                    # serial pass then records an aot_hit load of the
                     # same key (it finds it in _aot_keys).
                     key = _program_key(kind, shape)
                     self._aot_keys.add(key)
@@ -2478,6 +2510,8 @@ class InferenceEngine(BlockDecodeMixin):
                 "warmup aot: %d programs in %.1fs (%d threads)",
                 len(jobs), time.monotonic() - t1, par,
             )
+            global_compile_watch.add_span(
+                "startup.aot", t0=t1, threads=par)
 
         await loop.run_in_executor(self._executor, _all)
 
@@ -2544,8 +2578,15 @@ class InferenceEngine(BlockDecodeMixin):
         t0 = time.monotonic()
         in_args, _ = self._copy_warm_args()
         self.kv_cache = self._copy_in(*in_args)
+        t1 = time.monotonic()
+        global_compile_watch.note(
+            program="copy", key="copy_in", shape=[], seconds=t1 - t0,
+            phase="warmup")
         _, out_args = self._copy_warm_args()
         self._pool = self._copy_out(*out_args)
+        global_compile_watch.note(
+            program="copy", key="copy_out", shape=[],
+            seconds=time.monotonic() - t1, phase="warmup")
         if self._page_out_op is not None:
             # Spill-tier I/O programs (ISSUE 16): one round trip through
             # the scratch page compiles both — idx is traced, so these are

@@ -15,11 +15,18 @@ decode-stall watchdog trips.  This module is that third leg (ISSUE 12):
   (so PR 9's fleet stitching yields per-peer engine lanes for free) and
   summarized by ``scripts/traceview.py --flight``.
 - :class:`CompileWatch` — the compile/cold-start journal: every compiled
-  program emits one ``(program, key, shape, seconds, phase, cache_hit,
+  program emits one ``(program, key, shape, seconds, phase, aot_hit,
   cold)`` event.  A compile event AFTER warmup completed is a hole in the
   warmup bucket grid (the ``test_warmup_aot`` bug class) surfaced at
   runtime as ``engine_cold_compiles_total`` + a timeline event instead of
-  only in tests.
+  only in tests.  Since ISSUE 40 it is also the START-UP journal: the
+  phases of a serve process from the kernel's process start to ``ready``
+  (``startup.*`` spans) and one ``startup.program`` record for each warmed
+  program with Python's part (tracing, lowering) and XLA's (the compile or
+  the persistent cache's load) apart.  Always on, a few dozen records a
+  process, in a bounded list of its own that no request traffic can evict;
+  exported on a ``startup`` lane of ``/healthz?trace=1`` and summed in the
+  ``/healthz`` ``startup`` section.
 - :class:`BlackBox` — postmortem capture: on a watchdog trip, SLO breach,
   drain timeout, or fatal engine error, atomically snapshot {flight tail,
   scheduler/slot/tenant state, recent spans, metrics, EngineConfig} into
@@ -43,12 +50,13 @@ the explicitly-waived wall-clock fields (``WALLCLOCK_WAIVED`` + the
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, Iterator, List, Optional, Tuple
 
 from p2p_llm_tunnel_tpu.utils.logging import get_logger
 from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
@@ -142,6 +150,194 @@ FLIGHT_SCHEMA: Dict[str, str] = {
     "process_ms": "token accounting + segment finish wall (waived)",
 }
 
+#: The one catalogue of legal start-up journal field names (tunnelcheck
+#: TC16): what a compile event (``CompileWatch.note``) and the attrs of a
+#: ``startup.*`` span may carry.  Span NAMES are tracing.SPAN_CATALOG's.
+STARTUP_SCHEMA: Dict[str, str] = {
+    "seq": "compile-event sequence number (monotone per journal)",
+    "program": "program kind: decode|spec|prefill|chunk|ragged|copy|...",
+    "key": "program key, kind[shape] (copy_in / copy_out for the copies)",
+    "shape": "the program's bucket shape",
+    "seconds": (
+        "wall seconds of the record: lower + compile in the AOT phase, the "
+        "first dispatch in the serial pass or on the serving path (waived)"
+    ),
+    "phase": "aot | warmup (the serial pass) | serve (a cold compile)",
+    "aot_hit": (
+        "the AOT phase of THIS process already compiled the key, so the "
+        "serial pass only loaded it (was cache_hit before ISSUE 40)"
+    ),
+    "cold": "compiled on the serving path after warm-up declared done",
+    "thread": "name of the thread that compiled (process-scoped, waived)",
+    "trace_lower_s": (
+        "Python's part: tracing and lowering to StableHLO, as a WALL inside "
+        "its thread, so with what the thread waited for the interpreter "
+        "lock the phase's threads share (AOT: the record's wall less "
+        "compile_s; serial pass: JAX's own trace + MLIR duration events in "
+        "the dispatching thread)"
+    ),
+    "compile_s": (
+        "XLA's part: the backend compile or the persistent cache's load "
+        "(JAX's backend-compile duration event in the compiling thread)"
+    ),
+    "persistent_hit": (
+        "true: the executable came from the compile cache on disk (JAX's "
+        "cache_hits event in the compiling thread); false: it was compiled "
+        "and written there (cache_misses); null: neither event, so no "
+        "cache, a JAX without the events, or a program that compiles "
+        "faster than JAX's threshold for caching and is compiled again at "
+        "every start (a fact of the machine's disk, waived)"
+    ),
+    "pallas_calls": (
+        "custom calls in the decode layer body, on startup.launch_probe "
+        "(the one place utils/hlo.py's count is already paid for)"
+    ),
+    "clock": (
+        "where startup.process got its start: proc (/proc/self/stat "
+        "starttime) or cli.main (its first line; /proc absent or unusable)"
+    ),
+    "entries": "tokenizer vocabulary entries",
+    "platform": "JAX's default backend",
+    "device_kind": "device kind of the first local device",
+    "devices": "local device count",
+    "source": "where the parameters came from: random|checkpoint|injected",
+    "quant": "weight quantisation mode the engine was built with",
+    "bytes": "bytes of the arrays the phase made, from shapes (no fetch)",
+    "threads": "AOT compile threads (TUNNEL_WARMUP_PAR)",
+}
+
+#: The ``startup.*`` spans that tile ``startup.process``, in order.
+STARTUP_PHASES = ("startup.imports", "startup.tokenizer", "startup.backend",
+                  "startup.engine_build", "startup.warmup")
+#: Start-up records kept: a few dozen a process; beyond the cap (many
+#: engines in one test process) later ones are dropped, never the first.
+STARTUP_CAPACITY = 2048
+
+#: JAX's monitoring events the journal attributes to the compiling thread.
+_EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_EV_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EV_MLIR = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_EV_COMPILE = "/jax/core/compile/backend_compile_duration"
+_EV_WATCHED = frozenset(
+    {_EV_CACHE_HIT, _EV_CACHE_MISS, _EV_TRACE, _EV_MLIR, _EV_COMPILE}
+)
+
+
+#: What JAX reported while a warm-up listened, by the name of the thread
+#: that compiled: (instant, event, seconds or 1).  ``CompileWatch.note``
+#: takes a thread's entries when it records that thread's program.
+_HEARD: Dict[str, List[Tuple[float, str, float]]] = {}
+_listeners_lock = threading.Lock()
+_listeners_on = False
+#: Warm-ups running (replicas may overlap): above 0 the listeners keep
+#: what each thread compiles.  At 0 (before and after warm-up) they
+#: return at once, so the serving path keeps nothing.
+_listening = 0
+
+
+def _on_jax_event(event: str, *args: float, **_kw: object) -> None:
+    """Both of jax.monitoring's listener shapes: ``(event)`` counts one,
+    ``(event, seconds)`` gives the seconds.  JAX calls listeners in the
+    thread that compiles, so entries kept by thread attribute them: no
+    scope is opened around a compile and nothing is added to the code that
+    times one."""
+    if _listening and event in _EV_WATCHED:
+        _HEARD.setdefault(threading.current_thread().name, []).append(
+            (time.monotonic(), event, args[0] if args else 1.0))
+
+
+def _heard(thread: str, t0: float) -> Dict[str, object]:
+    """The STARTUP_SCHEMA fields of what ``thread`` compiled since ``t0``
+    (its entries are taken: the next program starts empty); nothing where
+    nothing was heard."""
+    entries = [e for e in _HEARD.pop(thread, ()) if e[0] >= t0]
+    if not entries:
+        return {}
+    sums: Dict[str, float] = {}
+    for _t, event, value in entries:
+        sums[event] = sums.get(event, 0.0) + value
+    # JAX's own two events: a hit loaded the executable from the cache on
+    # disk; a miss compiled it and WROTE it there.  Neither: no cache, or a
+    # program under JAX's thresholds (a compile shorter than
+    # jax_persistent_cache_min_compile_time_secs, 1 s, is never written, so
+    # it is compiled again at every start and is no miss).  The program's
+    # own compile is the window's last: the helpers that make its arguments
+    # (a jnp.zeros) compile before it.
+    asked = [event for _t, event, _v in entries
+             if event in (_EV_CACHE_HIT, _EV_CACHE_MISS)]
+    out: Dict[str, object] = {
+        "persistent_hit": asked[-1] == _EV_CACHE_HIT if asked else None,
+    }
+    if _EV_TRACE in sums or _EV_MLIR in sums:
+        out["trace_lower_s"] = round(
+            sums.get(_EV_TRACE, 0.0) + sums.get(_EV_MLIR, 0.0), 4)
+    if _EV_COMPILE in sums:
+        out["compile_s"] = round(sums[_EV_COMPILE], 4)
+    return out
+
+
+def _install_listeners() -> None:
+    global _listeners_on
+    with _listeners_lock:
+        if _listeners_on:
+            return
+        _listeners_on = True
+        try:
+            from jax import monitoring
+
+            monitoring.register_event_listener(_on_jax_event)
+            monitoring.register_event_duration_secs_listener(_on_jax_event)
+        except Exception as e:  # no such API: every record says null
+            log.info("jax.monitoring listeners unavailable: %s", e)
+
+
+def program_rollup(programs: List[Dict[str, object]],
+                   slowest: int = 5) -> Dict[str, object]:
+    """What /healthz's ``startup`` section and ``traceview.py --startup``
+    both say of a start's ``startup.program`` records (each its attrs plus
+    ``seconds``, the record's duration).  The AOT phase's records carry
+    the compile; a start without one (no TUNNEL_WARMUP_PAR) compiled in
+    its serial pass, and those are read instead."""
+    # (the copy programs' records are the serial pass's in every start)
+    compiled = [p for p in programs if p.get("phase") == "aot"] or programs
+    lower = [p["trace_lower_s"] for p in compiled
+             if p.get("trace_lower_s") is not None]
+    return {
+        "programs": len({p.get("key") for p in programs}),
+        "trace_lower_s_per_program": (
+            sum(lower) / len(lower) if lower else None),
+        "persistent_hits": sum(
+            p.get("persistent_hit") is True for p in compiled),
+        "persistent_misses": sum(
+            p.get("persistent_hit") is False for p in compiled),
+        "slowest": sorted(
+            compiled, key=lambda p: -p["seconds"])[:slowest],
+    }
+
+
+def process_start(fallback: float) -> Tuple[float, str]:
+    """The kernel's start of this process on ``time.monotonic()``'s clock,
+    and where it came from.  ``/proc/self/stat`` field 22 counts clock
+    ticks since boot; CLOCK_BOOTTIME read beside CLOCK_MONOTONIC converts
+    it (they differ by time spent suspended).  ``fallback`` (the first
+    line of ``cli.main``) where /proc is absent or the answer is not a
+    past instant of this boot."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # the command may hold spaces and parentheses: split after it
+            fields = f.read().rsplit(b")", 1)[1].split()
+        ticks = int(fields[19])  # field 22; fields[0] is field 3
+        since_boot = ticks / os.sysconf("SC_CLK_TCK")
+        now_mono = time.monotonic()
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - since_boot
+        if 0.0 <= age < 86400.0 and now_mono - age <= fallback:
+            return now_mono - age, "proc"
+    except (OSError, ValueError, IndexError, AttributeError):
+        pass
+    return fallback, "cli.main"
+
+
 #: The one catalogue of legal postmortem-bundle top-level fields
 #: (tunnelcheck TC16).  ``BlackBox.capture`` builds EXACTLY this key set —
 #: a runtime lockstep guard backs the static rule.
@@ -182,6 +378,9 @@ POSTMORTEM_TRIGGERS = ("watchdog", "slo", "drain", "crash", "manual",
 WALLCLOCK_WAIVED = frozenset({
     "captured_unix_s", "t", "ts", "dur", "seconds", "min_slack_s",
     "span_id", "parent_id", "trace_id",
+    # a compile event's thread name and whether the machine's disk held
+    # the executable (STARTUP_SCHEMA): facts of the process, not the run
+    "thread", "persistent_hit",
 })
 #: Field-name suffixes waived as wall-clock derived (``engine_ttft_ms``,
 #: ``engine_warmup_compile_s``, ``tenant_tokens_per_s``, ...); the
@@ -312,39 +511,83 @@ class FlightRecorder:
 
 
 class CompileWatch:
-    """Bounded, thread-safe journal of program-compile events.
+    """Bounded, thread-safe journal of program compiles and of start-up.
 
-    One event per (program kind, bucket shape) the FIRST time a process
-    compiles/loads it: warmup's AOT phase, warmup's serial execute pass
-    (``cache_hit`` when the AOT phase already compiled the key), and —
-    the alarm case — ``cold=True`` mid-serve compiles after warmup
-    declared the grid complete."""
+    **Compile events**: one per (program kind, bucket shape) the FIRST time
+    a process compiles/loads it: warmup's AOT phase, warmup's serial
+    execute pass (``aot_hit`` when the AOT phase already compiled the
+    key), and — the alarm case — ``cold=True`` mid-serve compiles after
+    warmup declared the grid complete.
+
+    **Start-up records** (ISSUE 40): ``startup.*`` spans and instants on
+    ``time.monotonic()``'s clock, one ``startup.program`` span for every
+    compile event that is not cold, in a list of their own that is filled
+    once and never evicted (request traffic writes elsewhere; past
+    ``STARTUP_CAPACITY`` later records are dropped, never the first).
+    Recorded whether request tracing is on or not."""
 
     def __init__(self, capacity: int = 512):
         self._lock = threading.Lock()
         self._events: Deque[Dict[str, object]] = deque(maxlen=max(1, capacity))
         self._seq = 0
         self._cold = 0
+        self._startup: List[Dict[str, object]] = []
+        self._process: Optional[Tuple[float, str]] = None
 
     def reset(self) -> None:
         with self._lock:
             self._events.clear()
             self._seq = 0
             self._cold = 0
+            self._startup.clear()
+            self._process = None
+
+    @staticmethod
+    def _check(fields: Dict[str, object]) -> None:
+        unknown = set(fields) - set(STARTUP_SCHEMA)
+        if unknown:
+            raise ValueError(
+                f"start-up journal field(s) not in STARTUP_SCHEMA: "
+                f"{sorted(unknown)}"
+            )
+
+    # -- compile events ----------------------------------------------------
 
     def note(self, *, program: str, key: str, shape: List[int],
-             seconds: float, phase: str, cache_hit: bool = False,
-             cold: bool = False) -> None:
+             seconds: float, phase: str, aot_hit: bool = False,
+             cold: bool = False, **fields: object) -> None:
+        """One compile event of the calling thread, ended now.  While a
+        warm-up listens, ``trace_lower_s``, ``compile_s`` and
+        ``persistent_hit`` come from what JAX reported in this thread over
+        the record's ``seconds``; ``fields`` (STARTUP_SCHEMA) override."""
+        self._check(fields)
+        t1 = time.monotonic()
+        thread = threading.current_thread().name
+        event = {
+            "program": program, "key": key, "shape": list(shape),
+            "seconds": round(seconds, 4), "phase": phase,
+            "aot_hit": bool(aot_hit), "cold": bool(cold), "thread": thread,
+            "trace_lower_s": None, "compile_s": None,
+            "persistent_hit": None,
+        }
+        if _listening:
+            event.update(_heard(thread, t1 - seconds - 0.05))
+            if phase == "aot" and event["compile_s"] is not None:
+                # the record is a whole lowering and compile: XLA's part
+                # is what JAX timed, Python's the rest of the wall
+                xla = event["compile_s"] = min(event["compile_s"], seconds)
+                event["trace_lower_s"] = round(seconds - xla, 4)
+        event.update(fields)
         with self._lock:
             self._seq += 1
-            self._events.append({
-                "seq": self._seq, "program": program, "key": key,
-                "shape": list(shape), "seconds": round(seconds, 4),
-                "phase": phase, "cache_hit": bool(cache_hit),
-                "cold": bool(cold),
-            })
+            event["seq"] = self._seq
+            self._events.append(event)
             if cold:
                 self._cold += 1
+        if not cold:
+            # the event itself, not a copy: one dict, in the ring a bundle
+            # reads and in the list no later event can push it out of
+            self._keep("startup.program", t1 - seconds, seconds, event)
 
     def mark(self) -> int:
         """Current sequence number — pass to :meth:`since` to read only
@@ -363,6 +606,137 @@ class CompileWatch:
     @property
     def cold_total(self) -> int:
         return self._cold
+
+    # -- JAX's own compile events ------------------------------------------
+
+    @staticmethod
+    def listen(on: bool) -> None:
+        """Warm-up switches JAX's compile events on for its length (the
+        listeners are installed once, on the first call); :meth:`note`
+        then finds each program's in what its thread reported."""
+        global _listening
+        if on:
+            _install_listeners()
+        with _listeners_lock:
+            _listening = max(0, _listening + (1 if on else -1))
+            if not _listening:
+                _HEARD.clear()
+
+    # -- start-up spans ----------------------------------------------------
+
+    def process_began(self, fallback: Optional[float] = None,
+                      ) -> Tuple[float, str]:
+        """(start of this process on the monotonic clock, ``proc`` or
+        ``cli.main``): settled on the first call, which ``cli.main`` makes
+        on its first line."""
+        with self._lock:
+            if self._process is None:
+                self._process = process_start(
+                    time.monotonic() if fallback is None else fallback)
+            return self._process
+
+    def _keep(self, name: str, ts: float, dur: Optional[float],
+              attrs: Dict[str, object]) -> None:
+        with self._lock:
+            if len(self._startup) < STARTUP_CAPACITY:
+                self._startup.append(
+                    {"name": name, "ts": ts, "dur": dur, "attrs": attrs})
+
+    def add_span(self, name: str, *, t0: float, t1: Optional[float] = None,
+                 **attrs: object) -> None:
+        """One completed start-up span (``name`` from SPAN_CATALOG, attrs
+        from STARTUP_SCHEMA); ``t1`` defaults to now."""
+        self._check(attrs)
+        end = time.monotonic() if t1 is None else t1
+        self._keep(name, t0, max(0.0, end - t0), attrs)
+
+    def add_event(self, name: str, *, t: Optional[float] = None,
+                  **attrs: object) -> None:
+        """One start-up instant."""
+        self._check(attrs)
+        self._keep(name, time.monotonic() if t is None else t, None, attrs)
+
+    @contextlib.contextmanager
+    def startup_phase(self, name: str,
+                      **attrs: object) -> Iterator[Dict[str, object]]:
+        """Time the block as the span ``name``; the yielded dict takes
+        attrs learned inside it.  A block that raises records nothing."""
+        t0 = time.monotonic()
+        yield attrs
+        self.add_span(name, t0=t0, **attrs)
+
+    def mark_ready(self) -> None:
+        """The engine is warm and its backend installed: the instant
+        ``startup.ready``, and the root span ``startup.process`` from the
+        kernel's start of the process to it."""
+        t0, clock = self.process_began()
+        now = time.monotonic()
+        self.add_span("startup.process", t0=t0, t1=now, clock=clock)
+        self.add_event("startup.ready", t=now)
+
+    def startup_records(self) -> List[Dict[str, object]]:
+        with self._lock:
+            return [dict(r, attrs=dict(r["attrs"])) for r in self._startup]
+
+    def chrome_events(self) -> List[Dict[str, object]]:
+        """The start-up records as Chrome trace events: the phases on a
+        ``startup`` lane, each compiling thread's ``startup.program``
+        slices on a lane of its own beside it (they overlap in the AOT
+        phase).  ``ts`` / ``dur`` in µs of ``time.monotonic()``, attrs
+        under ``args``: the form the span journal exports."""
+        recs = self.startup_records()
+        if not recs:
+            return []
+        lanes: Dict[str, int] = {"startup": 1002}
+        events: List[Dict[str, object]] = []
+        for rec in recs:
+            lane = "startup"
+            if rec["name"] == "startup.program":
+                lane = f"startup {rec['attrs'].get('thread', '')}"
+            tid = lanes.setdefault(lane, 1002 + len(lanes))
+            ev: Dict[str, object] = {
+                "name": rec["name"], "cat": "startup", "pid": 1, "tid": tid,
+                "ts": int(rec["ts"] * 1e6), "args": rec["attrs"],
+            }
+            if rec["dur"] is None:
+                ev.update(ph="i", s="t")
+            else:
+                ev.update(ph="X", dur=max(1, int(rec["dur"] * 1e6)))
+            events.append(ev)
+        return [
+            {"ph": "M", "name": "thread_name", "pid": 1, "tid": tid,
+             "args": {"name": lane}}
+            for lane, tid in lanes.items()
+        ] + events
+
+    def startup_section(self) -> Dict[str, object]:
+        """The ``/healthz`` ``startup`` payload: seconds by phase (summed
+        over engines where a process built several), the warmed programs
+        and how many of them the compile cache on disk held, the slowest
+        one.  An operator sizes a rolling restart's drain window from
+        ``to_ready_s`` and tells a cold cache (``persistent_misses``) from
+        a slow runtime start (``phases_s['startup.backend']``)."""
+        recs = self.startup_records()
+        phases: Dict[str, float] = {}
+        for rec in recs:
+            if rec["dur"] is not None and rec["name"] != "startup.program":
+                phases[rec["name"]] = round(
+                    phases.get(rec["name"], 0.0) + rec["dur"], 3)
+        rollup = program_rollup(
+            [dict(r["attrs"], seconds=r["dur"]) for r in recs
+             if r["name"] == "startup.program"], slowest=1)
+        slowest = rollup.pop("slowest")
+        del rollup["trace_lower_s_per_program"]
+        return {
+            "ready": any(r["name"] == "startup.ready" for r in recs),
+            "to_ready_s": phases.get("startup.process"),
+            "phases_s": phases,
+            **rollup,
+            "slowest_program": (
+                {"key": slowest[0]["key"],
+                 "seconds": round(slowest[0]["seconds"], 3)}
+                if slowest else None),
+        }
 
 
 class BlackBox:

@@ -131,18 +131,11 @@ METRICS_CATALOG: Dict[str, str] = {
     ),
     "engine_warmup_compile_s": (
         "wall seconds warmup spent compiling the serving program set "
-        "(gauge; the set-up a start pays before its first request)"
+        "(gauge; the set-up a start pays before its first request; the "
+        "count, the slowest program and the phases around it are "
+        "/healthz's startup section, from the start-up journal)"
     ),
     # -- engine flight recorder / cold-start profiler (ISSUE 12) ----------
-    "engine_warmup_programs": (
-        "distinct programs the warmup grid compiled/loaded before serving "
-        "(gauge; the per-program breakdown lives in the CompileWatch "
-        "journal)"
-    ),
-    "engine_warmup_compile_max_s": (
-        "wall seconds of the single slowest warmup program compile "
-        "(gauge; the floor no parallel warmup can go below)"
-    ),
     "engine_cold_compiles_total": (
         "programs compiled ON the serving path after warmup declared the "
         "bucket grid complete (counter; every increment is a hole in the "

@@ -150,6 +150,68 @@ SPAN_CATALOG: Dict[str, str] = {
         "a hole in the warmup bucket grid; attrs carry the program key "
         "(instant; ISSUE 12 cold-start profiler)"
     ),
+    # -- start-up (ISSUE 40) ---------------------------------------------
+    # Written to the start-up journal (utils/flight.py CompileWatch), not
+    # to this recorder's rings: always on, never evicted, exported on the
+    # ``startup`` lane of /healthz?trace=1.  Attrs: flight.STARTUP_SCHEMA.
+    "startup.process": (
+        "the kernel's start of the serve process (/proc/self/stat "
+        "starttime on the monotonic clock; attr clock says proc or "
+        "cli.main) -> startup.ready; imports, tokenizer, backend, "
+        "engine_build and warmup tile it in that order"
+    ),
+    "startup.ready": (
+        "engine warm, backend installed: _engine_backend returns (instant)"
+    ),
+    "startup.imports": (
+        "process start -> the tokenizer's load (or the first backend "
+        "touch): the interpreter, the package's and JAX's imports, a "
+        "wrapper's patching, argument parsing"
+    ),
+    "startup.tokenizer": "HFTokenizer(...) where one is given (entries)",
+    "startup.backend": (
+        "the first backend touch, jax.default_backend() / local_devices(), "
+        "after a multi-host join where one is asked: the TPU runtime's "
+        "start (platform, device_kind, devices)"
+    ),
+    "startup.engine_build": (
+        "InferenceEngine(...) and engine.start() (all replicas'); "
+        "parent of startup.params and startup.cache_alloc"
+    ),
+    "startup.params": (
+        "the parameters as the host sees them made: random init, a "
+        "checkpoint's load or an injected tree, quantised and sharded "
+        "(source, quant, bytes; the device may still be filling them)"
+    ),
+    "startup.cache_alloc": (
+        "the KV planes and the prefix pool allocated (bytes)"
+    ),
+    "startup.warmup": (
+        "engine.warmup(): parent of aot, execute, prefix_warm, launch_probe"
+    ),
+    "startup.aot": (
+        "the threaded AOT phase: every planned program and the copy "
+        "programs lowered and compiled (threads); parent of the "
+        "phase's startup.program records"
+    ),
+    "startup.execute": (
+        "the serial pass: every planned program dispatched once"
+    ),
+    "startup.prefix_warm": "the prefix pool's copy programs run once",
+    "startup.launch_probe": (
+        "_set_kernel_gauge: the decode program lowered once more for "
+        "engine_decode_kernels_per_step (pallas_calls)"
+    ),
+    "startup.program": (
+        "one warmed program: a planned program's lower + compile in the "
+        "AOT phase and its first dispatch in the serial pass, a copy "
+        "program's run under prefix_warm (program, key, shape, thread, "
+        "phase, trace_lower_s, compile_s, persistent_hit, aot_hit)"
+    ),
+    "startup.tunnel": (
+        "the first session only: signaling connect -> AGREE sent; outside "
+        "startup.process (the serve peer dials once its engine is warm)"
+    ),
 }
 
 #: Optional trace-context request header: ``<trace_id>/<parent_span_id>``,

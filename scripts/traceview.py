@@ -12,6 +12,8 @@ Usage:
     curl -s 'http://127.0.0.1:8000/healthz?trace=1' > trace.json   # via proxy
     python scripts/traceview.py trace.json
     python scripts/traceview.py trace.json --json     # machine-readable
+    python scripts/traceview.py trace.json --flight   # the engine loop
+    python scripts/traceview.py trace.json --startup  # process start -> ready
 """
 
 from __future__ import annotations
@@ -54,7 +56,8 @@ def summarize(trace: dict) -> dict:
         args = ev.get("args", {})
         tid = args.get("trace_id")
         if tid is None:
-            if ev.get("ph") == "X":
+            # (the start-up journal's lane has a view of its own)
+            if ev.get("ph") == "X" and ev.get("cat") != "startup":
                 engine_scope.setdefault(ev["name"], []).append(
                     ev["dur"] / 1000.0
                 )
@@ -274,6 +277,57 @@ def _print_flight(out: dict) -> None:
         print("  ".join(f"{rec.get(c, '-')!s:>13}" for c in cols))
 
 
+def summarize_startup(trace: dict, slowest: int = 5) -> dict:
+    """Rollup of the start-up journal's lane in a capture (ISSUE 40): the
+    ``startup.*`` spans of ``/healthz?trace=1``.  Seconds by phase in the
+    order they began (``startup.process`` is the whole, from the kernel's
+    start of the process to ``startup.ready``), the warmed programs with
+    Python's part and XLA's apart, how many the compile cache on disk
+    held, and the ``slowest`` programs."""
+    from p2p_llm_tunnel_tpu.utils.tracing import validate_chrome_trace
+
+    validate_chrome_trace(trace)
+    evs = sorted(
+        (ev for ev in trace["traceEvents"]
+         if ev.get("ph") == "X" and ev.get("cat") == "startup"),
+        key=lambda e: e["ts"],
+    )
+    phases = [
+        {"span": ev["name"], "seconds": ev["dur"] / 1e6,
+         "attrs": ev.get("args", {})}
+        for ev in evs if ev["name"] != "startup.program"
+    ]
+    from p2p_llm_tunnel_tpu.utils.flight import program_rollup
+
+    programs = [dict(ev.get("args", {}), seconds=ev["dur"] / 1e6)
+                for ev in evs if ev["name"] == "startup.program"]
+    return {"phases": phases, **program_rollup(programs, slowest)}
+
+
+def _print_startup(out: dict) -> None:
+    if not out["phases"]:
+        print("startup: no startup.* spans in this capture")
+        return
+    whole = next((p["seconds"] for p in out["phases"]
+                  if p["span"] == "startup.process"), None)
+    print(f"{'span':24} {'seconds':>9} {'share':>6}  attrs")
+    for p in out["phases"]:
+        share = (f"{100.0 * p['seconds'] / whole:5.1f}%"
+                 if whole and p["span"] != "startup.tunnel" else "     -")
+        attrs = " ".join(f"{k}={v}" for k, v in p["attrs"].items())
+        print(f"{p['span']:24} {p['seconds']:9.3f} {share}  {attrs}")
+    per = out["trace_lower_s_per_program"]
+    print(f"-- {out['programs']} program(s); compile cache on disk: "
+          f"{out['persistent_hits']} hit(s), {out['persistent_misses']} "
+          f"miss(es); tracing + lowering "
+          f"{'-' if per is None else f'{per:.3f}'} s a program")
+    for p in out["slowest"]:
+        print(f"-- {p.get('key', '?'):24} {p['seconds']:7.3f}s = lower "
+              f"{p.get('trace_lower_s')} + compile {p.get('compile_s')} "
+              f"(persistent_hit {p.get('persistent_hit')}, thread "
+              f"{p.get('thread')})")
+
+
 def _fmt(v: Optional[float]) -> str:
     return f"{v:8.1f}" if v is not None else "       -"
 
@@ -290,16 +344,23 @@ def main(argv=None) -> int:
                     help="summarize the engine flight-recorder tracks "
                          "(per-iteration scheduler decisions) instead of "
                          "the per-request view")
+    ap.add_argument("--startup", action="store_true",
+                    help="summarize the start-up journal's lane: seconds "
+                         "by phase from process start to ready, and the "
+                         "five slowest warmed programs")
     args = ap.parse_args(argv)
     raw = (sys.stdin.read() if args.path == "-"
            else open(args.path).read())
-    if args.flight:
-        out = summarize_flight(json.loads(raw))
-        if args.json:
-            print(json.dumps(out, indent=2))
-        else:
-            _print_flight(out)
-        return 0
+    for wanted, rollup, show in (
+            (args.flight, summarize_flight, _print_flight),
+            (args.startup, summarize_startup, _print_startup)):
+        if wanted:
+            out = rollup(json.loads(raw))
+            if args.json:
+                print(json.dumps(out, indent=2))
+            else:
+                show(out)
+            return 0
     out = summarize(json.loads(raw))
     if args.json:
         print(json.dumps(out, indent=2))

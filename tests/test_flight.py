@@ -29,6 +29,8 @@ from p2p_llm_tunnel_tpu.transport import loopback_pair
 from p2p_llm_tunnel_tpu.utils.flight import (
     FLIGHT_SCHEMA,
     POSTMORTEM_SCHEMA,
+    STARTUP_PHASES,
+    STARTUP_SCHEMA,
     BlackBox,
     CompileWatch,
     FlightRecorder,
@@ -110,7 +112,211 @@ def test_compile_watch_journal_marks_and_cold_counter():
             seconds=0.5, phase="serve", cold=True)
     assert [e["key"] for e in cw.since(mark)] == ["chunk[64,128]"]
     assert cw.cold_total == 1
-    assert cw.events()[0]["cache_hit"] is False
+    assert cw.events()[0]["aot_hit"] is False
+
+
+# -- the start-up journal (ISSUE 40) -----------------------------------------
+
+
+def _a_start(cw, t=100.0):
+    """A start written by hand: the five phases that tile the process, two
+    programs of an AOT phase and the serial pass's run of one of them."""
+    cw._process = (t, "proc")
+    cw.add_span("startup.imports", t0=t, t1=t + 20.0)
+    cw.add_span("startup.tokenizer", t0=t + 20.0, t1=t + 21.0, entries=32000)
+    cw.add_span("startup.backend", t0=t + 21.0, t1=t + 27.0,
+                platform="tpu", device_kind="TPU v5 lite", devices=1)
+    cw.add_span("startup.params", t0=t + 27.5, t1=t + 30.0, source="random",
+                quant="int8", bytes=7 << 30)
+    cw.add_span("startup.engine_build", t0=t + 27.0, t1=t + 33.0)
+    cw.add_span("startup.aot", t0=t + 33.0, t1=t + 45.0, threads=4)
+    for key, lower, comp, hit in (("decode[1024,8]", 0.7, 9.3, False),
+                                  ("chunk[8,128,1024]", 0.9, 0.1, True)):
+        cw.note(program=key.split("[")[0], key=key, shape=[1], phase="aot",
+                seconds=lower + comp, trace_lower_s=lower, compile_s=comp,
+                persistent_hit=hit)
+    cw.note(program="decode", key="decode[1024,8]", shape=[1], seconds=0.2,
+            phase="warmup", aot_hit=True)
+    cw.add_span("startup.warmup", t0=t + 33.0, t1=t + 50.0)
+    cw.add_span("startup.process", t0=t, t1=t + 50.0, clock="proc")
+    cw.add_event("startup.ready", t=t + 50.0)
+    cw.add_span("startup.tunnel", t0=t + 50.1, t1=t + 53.0)
+
+
+def test_startup_journal_section_sums_phases_and_programs():
+    cw = CompileWatch()
+    assert cw.startup_section()["ready"] is False
+    assert cw.startup_section()["programs"] == 0
+    assert cw.startup_section()["slowest_program"] is None
+    _a_start(cw)
+    section = cw.startup_section()
+    assert section["ready"] is True and section["to_ready_s"] == 50.0
+    assert section["phases_s"]["startup.backend"] == 6.0
+    assert section["phases_s"]["startup.tunnel"] == 2.9
+    # the AOT phase's records are the programs; the serial pass's run of
+    # the same key adds none
+    assert section["programs"] == 2
+    assert section["persistent_hits"] == 1
+    assert section["persistent_misses"] == 1
+    assert section["slowest_program"] == {"key": "decode[1024,8]",
+                                          "seconds": 10.0}
+    # the phases that tile the process do: no overlap, nothing left over
+    tiles = [r for r in cw.startup_records() if r["name"] in STARTUP_PHASES]
+    assert [r["name"] for r in tiles] == list(STARTUP_PHASES)
+    assert sum(r["dur"] for r in tiles) == pytest.approx(50.0)
+    # compile events keep their journal; what was cache_hit is aot_hit
+    assert [e["aot_hit"] for e in cw.events()] == [False, False, True]
+    assert "cache_hit" not in cw.events()[0]
+
+
+def test_startup_journal_rejects_a_field_outside_its_schema():
+    cw = CompileWatch()
+    with pytest.raises(ValueError, match="STARTUP_SCHEMA"):
+        cw.add_span("startup.backend", t0=1.0, platfrom="tpu")  # tunnelcheck: disable=TC16  deliberate drift: pins the runtime guard
+    with pytest.raises(ValueError, match="STARTUP_SCHEMA"):
+        cw.note(program="decode", key="k", shape=[], seconds=0.1,
+                phase="aot", compile_secs=0.1)
+    assert cw.startup_records() == [] and cw.events() == []
+    # every field a compile event carries is a declared one
+    cw.note(program="decode", key="k", shape=[], seconds=0.1, phase="aot")
+    assert set(cw.events()[0]) <= set(STARTUP_SCHEMA)
+
+
+def test_startup_journal_chrome_events_ride_a_lane_of_their_own():
+    cw = CompileWatch()
+    assert cw.chrome_events() == []
+    _a_start(cw)
+    evs = cw.chrome_events()
+    assert validate_chrome_trace({"traceEvents": evs})
+    lanes = {e["args"]["name"]: e["tid"] for e in evs if e["ph"] == "M"}
+    assert lanes["startup"] == 1002 and len(lanes) == 2  # + one thread's
+    by_name = {e["name"]: e for e in evs if e["ph"] != "M"}
+    process = by_name["startup.process"]
+    assert process["ph"] == "X" and process["ts"] == 100_000_000
+    assert process["dur"] == 50_000_000
+    assert process["args"] == {"clock": "proc"}
+    assert by_name["startup.ready"]["ph"] == "i"
+    assert by_name["startup.ready"]["ts"] == 150_000_000
+    programs = [e for e in evs if e["name"] == "startup.program"]
+    assert {e["tid"] for e in programs} == {1003}
+    assert programs[0]["args"]["trace_lower_s"] == 0.7
+    assert programs[0]["args"]["persistent_hit"] is False
+    # every name is a catalogued span, every attr a declared field
+    from p2p_llm_tunnel_tpu.utils.tracing import SPAN_CATALOG
+    for e in evs:
+        if e["ph"] != "M":
+            assert e["name"] in SPAN_CATALOG
+            assert set(e["args"]) <= set(STARTUP_SCHEMA)
+
+
+def test_startup_records_survive_300000_request_ring_events():
+    """The request ring of a traced benchmark run (262,144 records) turns
+    over; the journal's list is its own and is never evicted."""
+    _a_start(global_compile_watch)
+    before = global_compile_watch.chrome_events()
+    global_tracer.configure(enabled=True, capacity=262144)
+    try:
+        for i in range(300_000):
+            global_tracer.add_event("engine.first_token", trace_id="ab" * 8)
+        assert len(global_tracer.records()) == 262144
+        assert global_compile_watch.chrome_events() == before
+        assert global_compile_watch.startup_section()["programs"] == 2
+    finally:
+        global_tracer.configure(enabled=False, capacity=4096)
+        global_tracer.clear()
+
+
+def test_startup_journal_is_bounded_by_dropping_the_latest():
+    from p2p_llm_tunnel_tpu.utils.flight import STARTUP_CAPACITY
+
+    cw = CompileWatch()
+    cw.add_span("startup.process", t0=1.0, t1=2.0, clock="proc")
+    for i in range(STARTUP_CAPACITY + 10):
+        cw.add_event("startup.ready", t=float(i))
+    recs = cw.startup_records()
+    assert len(recs) == STARTUP_CAPACITY
+    assert recs[0]["name"] == "startup.process"
+
+
+def test_process_start_is_the_kernels_and_falls_back_to_the_given_line(
+        monkeypatch):
+    import time
+
+    from p2p_llm_tunnel_tpu.utils import flight
+
+    now = time.monotonic()
+    t, clock = flight.process_start(now)
+    if os.path.exists("/proc/self/stat"):
+        # this test process began before this line and after the machine
+        assert clock == "proc" and 0.0 < now - t < 86400.0
+    else:
+        assert (t, clock) == (now, "cli.main")
+    # /proc absent (or an answer that is no past instant): the given line
+    def no_sysconf(_name):
+        raise ValueError("unrecognized configuration name")
+
+    monkeypatch.setattr(flight.os, "sysconf", no_sysconf)
+    assert flight.process_start(now) == (now, "cli.main")
+    cw = CompileWatch()
+    assert cw.process_began(now) == (now, "cli.main")
+    assert cw.process_began(now + 5.0) == (now, "cli.main")  # settled once
+    cw.mark_ready()
+    root = cw.startup_records()[0]
+    assert root["name"] == "startup.process" and root["ts"] == now
+    assert root["attrs"] == {"clock": "cli.main"}
+
+
+def test_attribution_is_by_thread_and_read_once():
+    """jax.monitoring calls its listeners in the compiling thread: what it
+    reports is kept by thread, so a program's record finds nothing another
+    thread compiled, nothing older than itself, and only while a warm-up
+    listens."""
+    import time
+
+    from p2p_llm_tunnel_tpu.utils import flight
+
+    def noted(cw, phase, seconds):
+        cw.note(program="decode", key="decode[1]", shape=[1], phase=phase,
+                seconds=seconds)
+        return cw.events()[-1]
+
+    cw = CompileWatch()
+    flight._on_jax_event(flight._EV_COMPILE, 1.0)   # nobody listens yet
+    assert noted(cw, "aot", 1.0)["compile_s"] is None
+    seen = {}
+
+    def other():
+        flight._on_jax_event(flight._EV_CACHE_MISS)
+        flight._on_jax_event(flight._EV_CACHE_HIT)   # the program's: last
+        seen["other"] = noted(cw, "warmup", 1.0)
+
+    cw.listen(True)
+    cw.listen(True)                                  # a second replica
+    try:
+        flight._on_jax_event(flight._EV_COMPILE, 7.0)  # before the record
+        time.sleep(0.12)
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+        assert seen["other"]["persistent_hit"] is True
+        assert seen["other"]["compile_s"] is None    # not that thread's
+        flight._on_jax_event(flight._EV_TRACE, 0.25)
+        flight._on_jax_event(flight._EV_MLIR, 0.5)
+        flight._on_jax_event(flight._EV_COMPILE, 2.0)
+        flight._on_jax_event("/jax/some/other/event", 9.0)
+        cw.listen(False)                             # one is still warming
+        rec = noted(cw, "warmup", 0.05)              # the serial pass: JAX's
+        assert rec["trace_lower_s"] == 0.75 and rec["compile_s"] == 2.0
+        assert rec["persistent_hit"] is None         # no cache was asked
+        assert noted(cw, "warmup", 0.05)["compile_s"] is None  # read once
+        flight._on_jax_event(flight._EV_COMPILE, 0.5)
+        rec = noted(cw, "aot", 0.75)     # AOT: the wall less XLA's part
+        assert rec["compile_s"] == 0.5 and rec["trace_lower_s"] == 0.25
+        flight._on_jax_event(flight._EV_TRACE, 0.125)  # the launch probe's
+    finally:
+        cw.listen(False)
+    assert flight._listening == 0 and flight._HEARD == {}
+    assert noted(cw, "serve", 1.0)["trace_lower_s"] is None
 
 
 def test_postmortem_canonical_strips_waived_wallclock_fields():
@@ -408,6 +614,94 @@ def test_traceview_flight_summary(tmp_path, capsys):
     assert json.loads(capsys.readouterr().out)["iterations"] == 3
 
 
+def test_traceview_startup_phase_table_and_slowest_programs(tmp_path,
+                                                            capsys):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "traceview_under_test",
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "scripts", "traceview.py"),
+    )
+    traceview = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(traceview)
+
+    _a_start(global_compile_watch)
+    global_flight.record_iteration(t=1.0, dur_ms=1.0, queue_depth=1)
+    trace = global_tracer.chrome_trace()
+    trace["traceEvents"] = (
+        list(trace["traceEvents"]) + global_flight.chrome_events()
+        + global_compile_watch.chrome_events()
+    )
+    out = traceview.summarize_startup(trace)
+    assert [p["span"] for p in out["phases"]][:3] == [
+        "startup.imports", "startup.process", "startup.tokenizer"]
+    seconds = {p["span"]: p["seconds"] for p in out["phases"]}
+    assert seconds["startup.process"] == 50.0
+    assert seconds["startup.backend"] == 6.0
+    assert out["programs"] == 2
+    assert out["persistent_hits"] == 1 and out["persistent_misses"] == 1
+    assert out["trace_lower_s_per_program"] == pytest.approx(0.8)
+    assert [p["key"] for p in out["slowest"]] == [
+        "decode[1024,8]", "chunk[8,128,1024]"]
+    # the per-request view leaves the journal's lane to its own view
+    assert not any(name.startswith("startup.")
+                   for name in traceview.summarize(trace)["engine_scope"])
+
+    path = tmp_path / "trace.json"
+    path.write_text(json.dumps(trace))
+    assert traceview.main([str(path), "--startup"]) == 0
+    printed = capsys.readouterr().out
+    assert "startup.backend" in printed and "6.000" in printed
+    assert "2 program(s); compile cache on disk: 1 hit(s), 1 miss(es)" \
+        in printed
+    assert "decode[1024,8]" in printed and "lower 0.7 + compile 9.3" in printed
+    assert traceview.main([str(path), "--startup", "--json"]) == 0
+    assert json.loads(capsys.readouterr().out)["programs"] == 2
+    # a capture of a process with no journal says so
+    path.write_text(json.dumps(global_tracer.chrome_trace()))
+    assert traceview.main([str(path), "--startup"]) == 0
+    assert "no startup.* spans" in capsys.readouterr().out
+
+
+def test_healthz_surfaces_carry_the_startup_journal_under_any_backend():
+    """The serve loop answers both itself: the section without --trace,
+    the lane beside the flight tracks, under the plain HTTP backend too
+    (an empty journal: no phases, not ready)."""
+    async def main():
+        serve_task, ch, client = await _stack(_echo_backend())
+        try:
+            h = await client.wait(
+                await client.request("GET", "/healthz"), 10.0)
+            assert json.loads(h.text)["startup"] == {
+                "ready": False, "to_ready_s": None, "phases_s": {},
+                "programs": 0, "persistent_hits": 0,
+                "persistent_misses": 0, "slowest_program": None}
+            _a_start(global_compile_watch)
+            global_tracer.configure(enabled=True, capacity=64)
+            for _ in range(300):     # the small ring turns over five times
+                global_tracer.add_event("engine.first_token",
+                                        trace_id="cd" * 8)
+            h = await client.wait(
+                await client.request("GET", "/healthz"), 10.0)
+            section = json.loads(h.text)["startup"]
+            assert section["ready"] and section["to_ready_s"] == 50.0
+            r = await client.wait(
+                await client.request("GET", "/healthz?trace=1"), 10.0)
+            obj = json.loads(r.text)
+            assert validate_chrome_trace(obj)
+            names = [e["name"] for e in obj["traceEvents"]
+                     if e.get("cat") == "startup"]
+            assert names.count("startup.program") == 3
+            assert "startup.process" in names and "startup.ready" in names
+        finally:
+            global_tracer.configure(enabled=False, capacity=4096)
+            global_tracer.clear()
+            await _teardown(serve_task, ch, client)
+
+    asyncio.run(main())
+
+
 # ---------------------------------------------------------------------------
 # engine-backed behavior (tiny model, CPU)
 # ---------------------------------------------------------------------------
@@ -475,12 +769,17 @@ def test_warmup_compile_journal_covers_grid_and_gauges():
                 assert f"decode[{view},{engine.ecfg.decode_steps}]" in keys
             assert all(e["phase"] in ("warmup", "aot") for e in events)
             assert not any(e["cold"] for e in events)
-            # total/count/max published as catalogued gauges.
+            # the total as a catalogued gauge; count and slowest program
+            # in the start-up journal's section (ISSUE 40: the two gauges
+            # that carried them are gone from the catalog)
             assert global_metrics.gauge("engine_warmup_compile_s") > 0
-            n = global_metrics.gauge("engine_warmup_programs")
-            assert n == len(keys) >= 1
-            mx = global_metrics.gauge("engine_warmup_compile_max_s")
+            section = global_compile_watch.startup_section()
+            assert section["programs"] == len(keys) >= 1
+            mx = section["slowest_program"]["seconds"]
             assert 0 < mx <= global_metrics.gauge("engine_warmup_compile_s")
+            from p2p_llm_tunnel_tpu.utils.metrics import METRICS_CATALOG
+            assert "engine_warmup_programs" not in METRICS_CATALOG
+            assert "engine_warmup_compile_max_s" not in METRICS_CATALOG
             assert engine._warmup_done
             assert global_metrics.counter("engine_cold_compiles_total") == 0
         finally:

@@ -2474,6 +2474,87 @@ def test_tc16_http11_is_the_one_legal_matcher_and_waiver_works(tmp_path):
     assert active == [] and rules_of(waived) == ["TC16"]
 
 
+def test_tc16_flags_unknown_startup_span_attr_and_compile_event_field(
+        tmp_path):
+    """ISSUE 40: the start-up journal's vocabulary is STARTUP_SCHEMA's —
+    the attrs of a ``startup.*`` span (the clock's t0/t1/t are no attrs)
+    and the keywords of a compile event."""
+    active, _ = check(
+        tmp_path,
+        """
+        from p2p_llm_tunnel_tpu.utils.flight import global_compile_watch
+
+        def start(t0):
+            global_compile_watch.add_span(
+                "startup.backend", t0=t0, platform="tpu", platfrom="tpu")
+            global_compile_watch.add_event("startup.ready", t=t0, redy=1)
+            with global_compile_watch.startup_phase(
+                    "startup.tokenizer", entrys=3):
+                pass
+            global_compile_watch.note(
+                program="decode", key="k", shape=[], seconds=0.1,
+                phase="aot", compile_secs=0.1)
+        """,
+        rules=["TC16"],
+    )
+    assert rules_of(active) == ["TC16"] * 4
+    for violation, typo in zip(active, ("platfrom", "redy", "entrys",
+                                        "compile_secs")):
+        assert typo in violation.message
+        assert "STARTUP_SCHEMA" in violation.message
+
+
+def test_tc16_declared_startup_fields_and_other_recorders_are_clean(
+        tmp_path):
+    active, _ = check(
+        tmp_path,
+        """
+        from p2p_llm_tunnel_tpu.utils.flight import global_compile_watch
+        from p2p_llm_tunnel_tpu.utils.tracing import global_tracer
+
+        def start(t0, journal, notes):
+            global_compile_watch.add_span(
+                "startup.backend", t0=t0, t1=t0 + 1.0, platform="tpu",
+                device_kind="TPU v5 lite", devices=1)
+            with global_compile_watch.startup_phase(
+                    "startup.tokenizer") as attrs:
+                attrs["entries"] = 3
+            global_compile_watch.note(
+                program="decode", key="k", shape=[], seconds=0.1,
+                phase="aot", aot_hit=False, trace_lower_s=0.05,
+                compile_s=0.05, persistent_hit=None)
+            # the span recorder's own keywords are not journal fields ...
+            global_tracer.add_span("engine.request", trace_id="ab",
+                                   t0=t0, track="engine", attrs={"x": 1})
+            # ... nor is some other object's note()
+            notes.note(anything="goes")
+        """,
+        rules=["TC16"],
+    )
+    assert active == []
+
+
+def test_tc09_checks_the_startup_journals_span_names(tmp_path):
+    """The journal writes through its own add_span / add_event /
+    startup_phase: their literal names are SPAN_CATALOG's too."""
+    active, _ = check(
+        tmp_path,
+        """
+        from p2p_llm_tunnel_tpu.utils.flight import global_compile_watch
+
+        def start(t0):
+            global_compile_watch.add_span("startup.backend", t0=t0)
+            global_compile_watch.add_span("startup.bakend", t0=t0)
+            with global_compile_watch.startup_phase("startup.tokeniser"):
+                pass
+        """,
+        rules=["TC09"],
+    )
+    assert rules_of(active) == ["TC09", "TC09"]
+    assert "startup.bakend" in active[0].message
+    assert "startup.tokeniser" in active[1].message
+
+
 def test_tc16_runtime_registry_agrees_with_static_rule():
     """The runtime guard TC16 statically mirrors: record_iteration
     rejects undeclared fields, capture builds exactly the declared
@@ -2491,6 +2572,8 @@ def test_tc16_runtime_registry_agrees_with_static_rule():
     rec.record_iteration(**{k: 0 for k in FLIGHT_SCHEMA if k != "iter"})
     bundle = BlackBox(directory="").capture("manual")
     assert set(bundle) == set(POSTMORTEM_SCHEMA)
+    # the start-up journal's twin (ISSUE 40): tests/test_flight.py
+    # test_startup_journal_rejects_a_field_outside_its_schema
 
 
 # ---------------------------------------------------------------------------

@@ -347,6 +347,9 @@ class ProjectContext:
         self.postmortem_fields: Set[str] = (
             _str_collection(flight, "POSTMORTEM_SCHEMA") if flight else set()
         )
+        self.startup_fields: Set[str] = (
+            _str_collection(flight, "STARTUP_SCHEMA") if flight else set()
+        )
 
     @property
     def callgraph(self):
@@ -480,7 +483,7 @@ RULE_SUMMARIES = {
     "TC13": "read-modify-write of shared state straddles an await/yield without a lock",
     "TC14": "client-controlled header/body bytes reach a trusted sink unsanitized",
     "TC15": "span/slot/in-flight registration not released on every exit path (incl. generator aclose)",
-    "TC16": "flight/postmortem field not in the flight.py registries / ops path matched outside http11.ops_route",
+    "TC16": "flight/postmortem/start-up field not in the flight.py registries / ops path matched outside http11.ops_route",
     "TC17": "dispatch-site program kind unreachable from the warmup/AOT plan generators (mid-serve cold-compile hole)",
     "TC18": "KV page bytes spliced into a device pool without the registered tier-boundary pin check (verify_page_pin)",
     "TC19": "packed-KV write outside the byte-aligned helpers (pack_int4 -> buffer write, or hand-rolled nibble merge)",

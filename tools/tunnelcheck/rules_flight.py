@@ -11,6 +11,11 @@ trustworthy if its vocabulary and its transport are single-sourced:
    schema members.  A typo'd field doesn't fail anything — it silently
    splits the black-box vocabulary between the writer and every reader
    (traceview --flight, the bundle-identity chaos test, dashboards).
+   The start-up journal (ISSUE 40) keeps to the same rule: the keywords
+   of a compile event (``<...>compile_watch.note(...)``) and the attrs of
+   a ``startup.*`` span (``add_span`` / ``add_event`` / ``startup_phase``
+   with a literal ``"startup."`` name; ``t0`` / ``t1`` / ``t`` are the
+   clock's) must be declared in ``STARTUP_SCHEMA``.
 
 2. **Ops routing**: the serve loop, proxy, and any future debug surface
    must classify ``/healthz`` / ``/metrics`` requests through
@@ -30,13 +35,25 @@ import ast
 import re
 from typing import Iterator, List
 
-from tools.tunnelcheck.core import ProjectContext, SourceFile, Violation
+from tools.tunnelcheck.core import (
+    ProjectContext,
+    SourceFile,
+    Violation,
+    dotted_name,
+)
 
 #: The write entry point whose keyword arguments are flight-record fields.
 FLIGHT_WRITE = "record_iteration"
 #: The capture entry point; a literal dict bound to these keywords carries
 #: postmortem top-level fields.
 CAPTURE_FN = "capture"
+#: The start-up journal's span writers (name first, attrs as keywords) and
+#: the keywords that are instants of the clock, not attrs.
+STARTUP_WRITES = {"add_span", "add_event", "startup_phase"}
+STARTUP_CLOCK_KW = {"t0", "t1", "t"}
+#: Its compile-event writer, told from any other ``note`` by its receiver.
+COMPILE_NOTE = "note"
+COMPILE_NOTE_RECEIVER = "compile_watch"
 
 #: Registry module (the schemas live here); its own internals are exempt
 #: from the ops/record checks the way utils/metrics.py is for TC12.
@@ -82,6 +99,7 @@ def check_tc16(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
     # -- half 1: schema-registry field names ------------------------------
     flight_fields = ctx.flight_fields
     postmortem_fields = ctx.postmortem_fields
+    startup_fields = ctx.startup_fields
     for node in ast.walk(sf.tree):
         if not isinstance(node, ast.Call):
             continue
@@ -98,6 +116,29 @@ def check_tc16(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
                 out.append(Violation(
                     "TC16", sf.path, node.lineno,
                     _SCHEMA_MSG.format(names=bad, registry="FLIGHT_SCHEMA"),
+                    end_line=node.end_lineno,
+                ))
+        startup_kw = None
+        if startup_fields and isinstance(fn, ast.Attribute):
+            first = node.args[0] if node.args else None
+            if (name in STARTUP_WRITES and isinstance(first, ast.Constant)
+                    and isinstance(first.value, str)
+                    and first.value.startswith("startup.")):
+                startup_kw = STARTUP_CLOCK_KW
+            elif name == COMPILE_NOTE and (
+                    dotted_name(fn.value) or "").endswith(
+                        COMPILE_NOTE_RECEIVER):
+                startup_kw = set()
+        if startup_kw is not None:
+            bad = sorted(
+                kw.arg for kw in node.keywords
+                if kw.arg is not None and kw.arg not in startup_kw
+                and kw.arg not in startup_fields
+            )
+            if bad:
+                out.append(Violation(
+                    "TC16", sf.path, node.lineno,
+                    _SCHEMA_MSG.format(names=bad, registry="STARTUP_SCHEMA"),
                     end_line=node.end_lineno,
                 ))
         if name == CAPTURE_FN and postmortem_fields:

@@ -24,8 +24,10 @@ from typing import Iterator, List
 from tools.tunnelcheck.core import ProjectContext, SourceFile, Violation
 from tools.tunnelcheck.rules_jax import _traced_functions
 
-#: The recorder's emit surface (utils.tracing.TraceRecorder).
-SPAN_EMIT_METHODS = {"add_span", "add_event"}
+#: The recorder's emit surface (utils.tracing.TraceRecorder) and the
+#: start-up journal's (utils.flight.CompileWatch: the same two names plus
+#: the block timer), whose ``startup.*`` names live in the same catalogue.
+SPAN_EMIT_METHODS = {"add_span", "add_event", "startup_phase"}
 
 
 def check_tc09(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
