@@ -361,8 +361,19 @@ def build_parser() -> argparse.ArgumentParser:
     serve.add_argument("--ep", type=int, default=int(_env("TUNNEL_EP", "1")),
                        help="expert-parallel degree for MoE models")
     serve.add_argument("--tokenizer", default=_env("TUNNEL_TOKENIZER"),
-                       help="HF tokenizer path for real checkpoints "
-                            "(default: byte-level)")
+                       help="a checkpoint's tokenizer (default: byte-level). "
+                            "A local directory is read with the tokenizers "
+                            "library alone: tokenizer.json, "
+                            "tokenizer_config.json, and chat_template.jinja, "
+                            "additional_chat_templates/*.jinja or an older "
+                            "layout's special_tokens_map.json where present. "
+                            "transformers is imported (18-25 s) only for a "
+                            "hub name, a directory without tokenizer.json, a "
+                            "tokenizer class other than "
+                            "PreTrainedTokenizerFast / Llama / Qwen2, an "
+                            "auto_map, or added or special tokens that "
+                            "tokenizer.json does not hold; the start-up log "
+                            "names the loader and the reason")
     serve.add_argument("--replicas", type=int,
                        default=int(_env("TUNNEL_REPLICAS", "1")),
                        help="data-parallel engine replicas behind a router, "
@@ -640,6 +651,11 @@ async def _engine_backend(args):
         with watch.startup_phase("startup.tokenizer") as attrs:
             tokenizer = HFTokenizer(args.tokenizer)
             attrs["entries"] = tokenizer.vocab_size
+            attrs["loader"] = tokenizer.loader
+        log.info("tokenizer: %s, %d entries, loaded by %s%s", args.tokenizer,
+                 tokenizer.vocab_size, tokenizer.loader,
+                 f" ({tokenizer.fallback_reason})"
+                 if tokenizer.fallback_reason else "")
     t_backend = time.monotonic()
     mesh = None
     if args.coordinator:

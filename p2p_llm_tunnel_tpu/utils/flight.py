@@ -197,6 +197,11 @@ STARTUP_SCHEMA: Dict[str, str] = {
         "starttime) or cli.main (its first line; /proc absent or unusable)"
     ),
     "entries": "tokenizer vocabulary entries",
+    "loader": (
+        "what loaded the tokenizer: tokenizers (tokenizer.json read with "
+        "that library alone) or transformers (AutoTokenizer: the 18-25 s "
+        "import, for what the plain loader refuses)"
+    ),
     "platform": "JAX's default backend",
     "device_kind": "device kind of the first local device",
     "devices": "local device count",

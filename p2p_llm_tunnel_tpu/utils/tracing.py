@@ -168,7 +168,10 @@ SPAN_CATALOG: Dict[str, str] = {
         "touch): the interpreter, the package's and JAX's imports, a "
         "wrapper's patching, argument parsing"
     ),
-    "startup.tokenizer": "HFTokenizer(...) where one is given (entries)",
+    "startup.tokenizer": (
+        "HFTokenizer(...) where one is given (entries; loader: tokenizers, "
+        "or transformers where the import could not be left out)"
+    ),
     "startup.backend": (
         "the first backend touch, jax.default_backend() / local_devices(), "
         "after a multi-host join where one is asked: the TPU runtime's "

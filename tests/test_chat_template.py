@@ -6,8 +6,8 @@ was instruction-tuned on — and fall back to the generic role-prefixed
 flattening otherwise.  A real `transformers` fast tokenizer is BUILT
 locally (no network): a WordLevel vocab + a jinja chat template, saved
 to disk and loaded through the same HFTokenizer path a real checkpoint
-uses, so `apply_chat_template` runs transformers' genuine template
-engine.
+uses; `apply_chat_template` renders it as transformers' template engine
+does (tests/test_tokenizer_loaders.py holds the two to each other).
 
 Capability parity: the reference serves real Ollama models transparently
 (tunnel/src/serve.rs:219) and Ollama applies the model's Modelfile
@@ -66,6 +66,9 @@ def test_hf_tokenizer_applies_template(hf_dir):
     from p2p_llm_tunnel_tpu.engine.tokenizer import HFTokenizer
 
     tok = HFTokenizer(hf_dir)
+    # what save_pretrained writes is loaded without transformers (ISSUE 41):
+    # tok._t is the package's own reading of the directory
+    assert tok.loader == "tokenizers"
     ids = tok.apply_chat_template(MESSAGES)
     assert ids is not None
     # The template's own rendering, tokenized by the same tokenizer: role

@@ -35,7 +35,22 @@ ARGS = ["serve", "--room", "r", "--backend", "tpu", "--model", "tiny",
         "--slots", "4", "--max-seq", "256"]
 
 
-async def _one_start(out: dict) -> None:
+def _write_tokenizer(path: str) -> str:
+    """A word-level file of ByteTokenizer's 259 entries, so both starts
+    build the same programs (benchmarks/stack.py ``write_tokenizer``'s
+    layout: what every benchmark cell hands ``--tokenizer``)."""
+    from tokenizers import Tokenizer, models, pre_tokenizers
+
+    tok = Tokenizer(models.WordLevel({f"w{i}": i for i in range(259)},
+                                     unk_token="w0"))
+    tok.pre_tokenizer = pre_tokenizers.Whitespace()
+    tok.save(os.path.join(path, "tokenizer.json"))
+    with open(os.path.join(path, "tokenizer_config.json"), "w") as f:
+        json.dump({"tokenizer_class": "PreTrainedTokenizerFast"}, f)
+    return path
+
+
+async def _one_start(out: dict, extra_args=()) -> None:
     """What ``cli.main`` -> ``_serve_once`` does up to the tunnel, then
     the serve loop over a loopback channel with the first session's
     ``tunnel_t0``."""
@@ -43,7 +58,7 @@ async def _one_start(out: dict) -> None:
     cli_mod._BACKEND = None
     cli_mod._ENGINES.clear()
     global_compile_watch.process_began(time.monotonic())
-    args = cli_mod.build_parser().parse_args(ARGS)
+    args = cli_mod.build_parser().parse_args(ARGS + list(extra_args))
     backend = await cli_mod._engine_backend(args)
     engine = cli_mod._ENGINES[0]
     out["plan"] = [cli_mod_key(kind, shape)
@@ -90,7 +105,8 @@ def cli_mod_key(kind, shape) -> str:
 @pytest.fixture(scope="module")
 def starts(tmp_path_factory):
     """{"first": ..., "second": ...}: two starts of one configuration in
-    one process against one compile-cache directory that begins empty."""
+    one process against one compile-cache directory that begins empty; the
+    second with a ``--tokenizer`` directory of the byte tokenizer's size."""
     import jax
     from jax._src import compilation_cache
 
@@ -109,7 +125,8 @@ def starts(tmp_path_factory):
     try:
         asyncio.run(_one_start(out["first"]))
         out["cache_files"] = len(os.listdir(cache))
-        asyncio.run(_one_start(out["second"]))
+        tok_dir = _write_tokenizer(str(tmp_path_factory.mktemp("tokenizer")))
+        asyncio.run(_one_start(out["second"], ["--tokenizer", tok_dir]))
     finally:
         patch.undo()
         jax.config.update("jax_compilation_cache_dir", old_dir)
@@ -141,10 +158,13 @@ def test_the_phases_tile_the_process(starts, which):
     assert process["attrs"]["clock"] in ("proc", "cli.main")
     assert ready["dur"] is None
     assert ready["ts"] == pytest.approx(process["ts"] + process["dur"])
-    # no tokenizer was given: that phase is absent, not zero
-    tiles = [one(start, n) for n in STARTUP_PHASES
-             if n != "startup.tokenizer"]
-    assert spans(start, "startup.tokenizer") == []
+    if which == "first":
+        # no tokenizer was given: that phase is absent, not zero
+        tiles = [one(start, n) for n in STARTUP_PHASES
+                 if n != "startup.tokenizer"]
+        assert spans(start, "startup.tokenizer") == []
+    else:
+        tiles = [one(start, n) for n in STARTUP_PHASES]
     assert tiles[0]["ts"] == process["ts"]
     gaps = 0.0
     for before, after in zip(tiles, tiles[1:]):
@@ -188,6 +208,17 @@ def test_the_phases_carry_their_attrs(starts):
     for rec in start["records"]:
         assert rec["name"] in SPAN_CATALOG
         assert set(rec["attrs"]) <= set(STARTUP_SCHEMA)
+
+
+def test_the_tokenizer_span_names_the_loader_that_engaged(starts):
+    """ISSUE 41: a directory with tokenizer.json is read with the tokenizers
+    library alone, and the span says so beside the entries."""
+    rec = one(starts["second"], "startup.tokenizer")
+    assert rec["attrs"] == {"entries": 259, "loader": "tokenizers"}
+    assert rec["dur"] > 0.0
+    section = starts["second"]["healthz"]["startup"]
+    assert section["phases_s"]["startup.tokenizer"] == pytest.approx(
+        rec["dur"], abs=1e-3)
 
 
 @pytest.mark.parametrize("phase,parent", [("aot", "startup.aot"),

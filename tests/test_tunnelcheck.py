@@ -2517,7 +2517,7 @@ def test_tc16_declared_startup_fields_and_other_recorders_are_clean(
                 "startup.backend", t0=t0, t1=t0 + 1.0, platform="tpu",
                 device_kind="TPU v5 lite", devices=1)
             with global_compile_watch.startup_phase(
-                    "startup.tokenizer") as attrs:
+                    "startup.tokenizer", loader="tokenizers") as attrs:
                 attrs["entries"] = 3
             global_compile_watch.note(
                 program="decode", key="k", shape=[], seconds=0.1,
