@@ -188,14 +188,17 @@ def test_a_share_cannot_exceed_100_where_the_pass_takes_the_least_time(
 def test_the_cells_metrics_are_the_ones_the_issue_names():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    # another layer's metrics (start-up's list every cell) are not this
+    # issue's to name: told by the entry's layer, not by their names
     mine = sorted(m["name"] for m in bench["per_layer"]
-                  if CELL in m.get("workloads", []))
+                  if CELL in m.get("workloads", [])
+                  and m["layer"] != "start-up")
     assert mine == sorted(NEW + [
         "decode_fill_pct.closed", "decode_step_ctr_dev_ms.closed",
         "kv_move_dev_pct.closed", "moe_dev_pct.context",
         "moe_held_share_pct.context", "moe_imbalance.context"])
     assert [m["workloads"] for m in bench["per_layer"]
-            if m["name"] in NEW] == [[CELL]] * 5
+            if m["name"] in NEW] == [[CELL]] * len(NEW)
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "sdar-30b-a3b", "blockgen-closed", 1)

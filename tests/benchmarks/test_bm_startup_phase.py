@@ -31,14 +31,10 @@ NINE = {
     "setup_lower_s_per_program": "s", "setup_programs": "programs",
     "setup_cache_misses": "programs", "setup_after_ready_s": "s",
 }
-#: The cells whose lists carry the nine.  Every cell's serve process writes
-#: the journal, but ``test_bm_bd_roofline.py`` and ``test_bm_swa_roofline.py``
-#: (files the benchmark already had, so not this PR's to edit) hold the sdar
-#: and mimo cells to exactly the per-layer metrics their own issues named:
-#: a ``benchmark`` PR has to let those two tests admit another layer's
-#: metrics before the two cells can be appended here (PERF.md section 7).
-PINNED = {"sdar-30b-a3b.blockgen-closed", "mimo-v2-flash.longmix-closed"}
-CELLS = [w["name"] for w in BENCH["workloads"] if w["name"] not in PINNED]
+#: Every cell's serve process writes the journal, so every cell's list
+#: carries the nine, in ``workloads``' order: a PR that adds a cell appends
+#: its name to the nine lists (benchmarks/README.md, "Adding a cell").
+CELLS = [w["name"] for w in BENCH["workloads"]]
 
 
 def reader():
@@ -159,23 +155,26 @@ def test_an_unknown_quantity_is_an_error():
 # ---- the nine entries against the contract -----------------------------------
 
 @pytest.mark.parametrize("name", sorted(NINE))
-def test_each_of_the_nine_is_declared_for_every_cell_not_pinned(name):
+def test_each_of_the_nine_is_declared_for_every_cell(name):
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
     assert entry == {"name": name, "unit": NINE[name], "better": "lower",
                      "source": "program_span", "layer": "start-up",
                      "moves": "setup_s", "workloads": CELLS}
-    assert len(CELLS) == 5 and len(BENCH["workloads"]) == 7
     spec = run.metric_spec(DATA, name)
     assert set(spec) == {"reader", "args", "layer", "source", "unit",
                          "moves"}
     assert "counters" not in spec    # WINDOW_COUNTERS stay what a cell reads
 
 
-def test_the_nine_stand_at_the_end_and_are_all_that_moves_setup_s():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert set(names[-9:]) == set(NINE)
-    assert {m["name"] for m in BENCH["per_layer"]
-            if m["moves"] == "setup_s"} == set(NINE)
+def test_the_nine_are_of_what_moves_setup_s_and_every_cell_reads_them():
+    """What moves ``setup_s`` is the start-up layer's and nothing else is,
+    wherever the entries stand in ``per_layer`` and whatever a later PR
+    appends after them; the nine are among them, in every cell's list."""
+    moving = {m["name"] for m in BENCH["per_layer"]
+              if m["moves"] == "setup_s"}
+    assert moving == {m["name"] for m in BENCH["per_layer"]
+                      if m["layer"] == "start-up"}
+    assert set(NINE) <= moving
     for cell in CELLS:
         mine = {m["name"] for m in run.metrics_of(BENCH, "per_layer", cell)}
         assert set(NINE) <= mine

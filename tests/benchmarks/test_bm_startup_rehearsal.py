@@ -29,7 +29,7 @@ CELL = "tiny.tiny-open"
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     """tinycell's root, its serve process given the threaded AOT phase the
-    seven cells' configurations ask for."""
+    cells' configurations ask for."""
     root = tinycell.build(str(tmp_path_factory.mktemp("startuproot")))
     path = os.path.join(root, "benchmarks", "configs", "tiny.json")
     with open(path) as f:
