@@ -851,6 +851,15 @@ async def _send_healthz(
             "kv_bytes": int(
                 global_metrics.gauge("engine_prefix_pool_kv_bytes")
             ),
+            # snapshots of recurrent state kept beside the pages (a family
+            # with state-space layers; 0 elsewhere), counted apart:
+            # kv_bytes stays the rows'
+            "state_snapshots": int(
+                global_metrics.gauge("engine_state_snapshots")
+            ),
+            "state_bytes": int(
+                global_metrics.gauge("engine_state_snapshot_bytes")
+            ),
             # ISSUE 14: admission-time page reservations (nonzero at rest
             # is a leak), cost-aware eviction volume, and the
             # conversation cache's reuse accounting — the multi-turn

@@ -707,6 +707,21 @@ class InferenceEngine(BlockDecodeMixin):
             self.mcfg = dc_replace(self.mcfg, ring_positions=self._ring)
         self._attn_kinds = tuple(
             k == "window" for k in self.mcfg.attn_kinds)
+        # A family whose cache is rows a token AND a recurrent state a slot
+        # (models/ssm_moe.py): the leaves that are state, and what a live
+        # row's state takes in all its layers (read and written once a
+        # decode step, once a prefill segment).
+        self._state_keys: Tuple[str, ...] = ()
+        self._state_row_bytes = 0
+        self._snapshots = None
+        if self.mcfg.mixer_pattern is not None:
+            from p2p_llm_tunnel_tpu.models.ssm_moe import (
+                STATE_KEYS,
+                state_bytes_per_slot,
+            )
+
+            self._state_keys = STATE_KEYS
+            self._state_row_bytes = state_bytes_per_slot(self.mcfg, dtype)
 
         def make_cache():
             return init_kv_cache(
@@ -948,8 +963,11 @@ class InferenceEngine(BlockDecodeMixin):
                 evict=self.ecfg.prefix_evict,
                 spill_pages=self.ecfg.spill_pages,
             )
+            # (a state a slot is no rows a token: snapshots, below)
             self._pool = init_pool(
-                self.kv_cache, blk, self.ecfg.prefix_pool_blocks
+                {k: a for k, a in self.kv_cache.items()
+                 if k not in self._state_keys},
+                blk, self.ecfg.prefix_pool_blocks
             )
             if self.ecfg.prefix_cache_dir:
                 from p2p_llm_tunnel_tpu.engine.prefix_cache import (
@@ -992,10 +1010,33 @@ class InferenceEngine(BlockDecodeMixin):
                 packed_keys=pool_packed_keys(self.kv_cache),
                 # (no head axis in a row of either family's planes)
                 layerwise_keys=frozenset(
-                    self.kv_cache if self.mcfg.kv_lora_rank or self._ring
-                    else ()),
+                    self._pool if self.mcfg.kv_lora_rank or self._ring
+                    or self._state_keys else ()),
                 ring_keys=self._ring_keys(),
             )
+            if self._state_keys:
+                from p2p_llm_tunnel_tpu.engine.prefix_cache import (
+                    StateSnapshots,
+                    make_state_copy_ops,
+                )
+
+                # As many snapshots as the pool's pages can use (+ slot 0,
+                # scratch): pages match no further than a boundary that
+                # has one, and one is taken every ``prefill_chunk`` tokens
+                # of a segmented prompt (_state_snapshot), so the pool's
+                # tokens over that spacing; plain LRU.
+                n_snap = 1 + max(1, self.ecfg.prefix_pool_blocks * blk // (
+                    self.ecfg.prefill_chunk or self.ecfg.min_prefill_bucket))
+                self._snapshots = StateSnapshots(n_snap)
+                self._prefix.snapshots = self._snapshots
+                self._snap_pool = {
+                    k: jnp.zeros((self.kv_cache[k].shape[0], n_snap)
+                                 + self.kv_cache[k].shape[2:],
+                                 self.kv_cache[k].dtype)
+                    for k in self._state_keys}
+                self._state_restore_op, self._state_save_op = (
+                    make_state_copy_ops(self._state_keys,
+                                        self.ecfg.prefill_rows))
             if self._spmd is not None:
                 self._copy_in = self._spmd.wrap("copy_in", self._copy_in, 2)
                 self._copy_out = self._spmd.wrap(
@@ -1032,7 +1073,9 @@ class InferenceEngine(BlockDecodeMixin):
         global_compile_watch.add_span(
             "startup.cache_alloc", t0=t_cache,
             bytes=_tree_bytes(self.kv_cache) + (
-                _tree_bytes(self._pool) if self._prefix is not None else 0),
+                _tree_bytes(self._pool) if self._prefix is not None else 0)
+            + (_tree_bytes(self._snap_pool) if self._snapshots is not None
+               else 0),
         )
         # Publish the fence registry where /healthz can read it without
         # holding an engine reference (latest engine wins — one serving
@@ -1944,7 +1987,9 @@ class InferenceEngine(BlockDecodeMixin):
         its layers no mesh rules (parallel/), and the Pallas kernels behind
         options, the ragged prefill and the speculative verify read one
         plane of KV heads whose keys and values are equally wide."""
-        if self.mcfg.kv_lora_rank:
+        if self.mcfg.mixer_pattern is not None:
+            what = "a recurrent state beside the KV planes, routed experts"
+        elif self.mcfg.kv_lora_rank:
             what = "latent attention, routed experts"
         elif self.mcfg.attn_pattern is not None:
             what = "window rings beside full planes, routed experts"
@@ -1966,6 +2011,16 @@ class InferenceEngine(BlockDecodeMixin):
             (e.spec_ngram > 0, "--spec-ngram"),
             (bool(e.ckpt_path), "--ckpt (no converter for this family)"),
         ]
+        if self.mcfg.mixer_pattern is not None:
+            # (a verify that rejects a draft has to roll the state back; the
+            # host tiers, the wire and the snapshot files hold pages only)
+            asked += [
+                (e.spill_pages > 0,
+                 "--spill-pages (no host spill of state snapshots)"),
+                (e.role != "both", f"--role {e.role}"),
+                (bool(e.prefix_cache_dir),
+                 "--prefix-cache-dir (state snapshots are not saved)"),
+            ]
         refused = [name for on, name in asked if on]
         if refused:
             raise ValueError(
@@ -1998,11 +2053,14 @@ class InferenceEngine(BlockDecodeMixin):
         published model this process holds."""
         m = self.mcfg
         rows, s = self.ecfg.num_slots + 1, self.ecfg.max_seq
-        if self._ring:
-            from p2p_llm_tunnel_tpu.models.swa import cache_section
+        if self._ring or self._state_keys:
+            if self._ring:
+                from p2p_llm_tunnel_tpu.models.swa import cache_section
+            else:
+                from p2p_llm_tunnel_tpu.models.ssm_moe import cache_section
 
-            # two kinds of plane: what a pooled token and what a slot holds
-            # are two statements
+            # two kinds of plane (or planes and a state): what a pooled
+            # token and what a slot holds are two statements
             cache = cache_section(m, self.kv_cache)
         else:
             cache = {
@@ -2587,6 +2645,17 @@ class InferenceEngine(BlockDecodeMixin):
         global_compile_watch.note(
             program="copy", key="copy_out", shape=[],
             seconds=time.monotonic() - t1, phase="warmup")
+        if self._snapshots is not None:
+            # the state's two copy programs, scratch slot to scratch
+            # snapshot and back
+            t2 = time.monotonic()
+            self._snap_pool = self._state_save_op(
+                self._snap_pool, self.kv_cache, *self._state_rows([], []))
+            self.kv_cache = self._state_restore_op(
+                self.kv_cache, self._snap_pool, *self._state_rows([], []))
+            global_compile_watch.note(
+                program="copy", key="state_copy", shape=[],
+                seconds=time.monotonic() - t2, phase="warmup")
         if self._page_out_op is not None:
             # Spill-tier I/O programs (ISSUE 16): one round trip through
             # the scratch page compiles both — idx is traced, so these are
@@ -2987,6 +3056,20 @@ class InferenceEngine(BlockDecodeMixin):
         if rec is not None:
             rec.attrs.update(kv_rows_full=full, kv_rows_window=window)
 
+    def _count_state(self, rows: int, rec: Optional[_Dispatch]) -> None:
+        """What a dispatch reads and writes of the recurrent state (a
+        family that has one): ``rows`` row-steps (live rows x steps of a
+        decode burst, the real rows of a prefill dispatch), each the state
+        of every state-space layer once in and once out, from the host's
+        own counts.  Counted in ``engine_state_bytes_total`` and, under
+        tracing, on the dispatch's record, so the two always agree."""
+        if not self._state_row_bytes:
+            return
+        nbytes = 2 * rows * self._state_row_bytes
+        global_metrics.inc("engine_state_bytes_total", nbytes)
+        if rec is not None:
+            rec.attrs.update(state_rows=rows, state_bytes=nbytes)
+
     def _take_moe(self, counts) -> None:
         """A serving program's routed-layer counts, still on the device
         (executor thread, as the dispatch call returns): queued beside the
@@ -3023,7 +3106,7 @@ class InferenceEngine(BlockDecodeMixin):
             return None
         return grouped_product_branch(
             m, self.mesh, tokens, reads_expert_stack(m, program),
-            getattr(self.params["blocks"]["moe_gate"], "dtype", jnp.bfloat16))
+            getattr(self.params["blocks"]["moe_up"], "dtype", jnp.bfloat16))
 
     def _note_moe(self, program: str, tokens: int,
                   rec: Optional[_Dispatch]) -> None:
@@ -3210,6 +3293,7 @@ class InferenceEngine(BlockDecodeMixin):
         self._note_moe("prefill", nb * t, rec)
         self._count_kv_rows(
             [0] * len(runs), [len(r.request.prompt_ids) for r in runs], rec)
+        self._count_state(len(runs), rec)
         out = first, (lp if lps.any() else None), plp
         self._start_host_copy(out)
         return out
@@ -3319,6 +3403,7 @@ class InferenceEngine(BlockDecodeMixin):
         self._note_moe("chunk_prefill", nb * t, rec)
         self._count_kv_rows([start for _r, start, _g, _s in rows],
                             [len(seg) for _r, _s, seg, _f in rows], rec)
+        self._count_state(len(rows), rec)
         out = first, (lp if lps.any() else None), None
         self._start_host_copy(out)
         return out
@@ -3419,6 +3504,7 @@ class InferenceEngine(BlockDecodeMixin):
         self._note_moe("ragged_prefill", tot, rec)
         self._count_kv_rows([start for _r, start, _g, _s in rows],
                             [len(seg) for _r, _s, seg, _f in rows], rec)
+        self._count_state(len(rows), rec)
         out = first, (lp if lps.any() else None), None
         self._start_host_copy(out)
         return out
@@ -3619,6 +3705,7 @@ class InferenceEngine(BlockDecodeMixin):
             # in flight)
             self._count_kv_rows(
                 self._positions[:slots][active[:slots]], steps, rec)
+            self._count_state(live * steps, rec)
         self._ov_mask[:] = False  # patch consumed by this dispatch
         # Rows must ALSO have been active at dispatch time to be accounted:
         # a chunk-prefilling slot holds its request-id long before its
@@ -4054,6 +4141,83 @@ class InferenceEngine(BlockDecodeMixin):
                     self.kv_cache, self._pool, slots, pids, bnos
                 )
             self._close_pool_copy(rec)
+            if self._snapshots is not None:
+                self._state_restore(hits[lo : lo + pr])  # tunnelcheck: disable=TC07  ONE dispatch per prefill_rows-wide sub-batch, behind its rows' copy-in
+
+    def _state_restore(self, hits: List[Tuple[int, List[int]]]) -> None:
+        """The hit slots' recurrent state from the snapshots at their
+        matches' ends (executor thread, behind the rows' copy-in: one
+        dispatch a ``prefill_rows``-wide sub-batch).  A match ends at a
+        snapshot by :meth:`PrefixIndex.match`'s rule; its key is the chain
+        key of the match's last block."""
+        slots, ids = [], []
+        for slot, pool_ids in hits:
+            prompt = self.scheduler.slots[slot].request.prompt_ids
+            key = self._prefix.block_keys(prompt)[len(pool_ids) - 1]
+            idx = self._snapshots.lookup(key)
+            if idx is None:
+                raise RuntimeError(
+                    f"slot {slot}: a prefix hit of {len(pool_ids)} blocks "
+                    "ends at no state snapshot")
+            slots.append(slot)
+            ids.append(idx)
+            global_metrics.inc("engine_state_restores_total")
+            global_metrics.inc("engine_state_bytes_total",
+                               self._state_row_bytes)
+            if global_tracer.enabled:
+                global_tracer.add_event(
+                    "engine.state_restore", trace_id=None,
+                    track="engine-loop",
+                    attrs={"slot": slot, "snapshot": idx,
+                           "tokens_skipped":
+                           len(pool_ids) * self._prefix_block,
+                           "bytes": self._state_row_bytes},
+                )
+        self.kv_cache = self._state_restore_op(
+            self.kv_cache, self._snap_pool, *self._state_rows(slots, ids))
+
+    def _state_rows(self, slots: List[int], ids: List[int]):
+        """(slots, snapshot ids) of one state copy dispatch, padded to
+        ``prefill_rows`` with the scratch slot and snapshot 0."""
+        pad = self.ecfg.prefill_rows - len(slots)
+        return (jnp.asarray(slots + [self._scratch_slot] * pad, jnp.int32),
+                jnp.asarray(ids + [0] * pad, jnp.int32))
+
+    def _state_snapshot(self, wave: List[Tuple[int, List[int]]]) -> None:
+        """Snapshots of the state of the slots of ``[(slot, token ids the
+        slot holds)]`` whose ids end on a block boundary that has none yet
+        (executor thread, behind the dispatch that brought the slot there:
+        the state is then the state after exactly those ids).  The policy:
+        a snapshot wherever a prefill dispatch ends on a block boundary,
+        that is every ``prefill_chunk`` tokens of a segmented prompt and at
+        a prompt's end where that is whole blocks."""
+        pr = self.ecfg.prefill_rows
+        todo = []
+        for slot, ids in wave:
+            if not ids or len(ids) % self._prefix_block:
+                continue
+            key = self._prefix.block_keys(ids)[-1]
+            if key in self._snapshots:
+                self._snapshots.lookup(key)  # touched
+                continue
+            todo.append((slot, self._snapshots.allocate(key), len(ids)))
+        for lo in range(0, len(todo), pr):
+            part = todo[lo : lo + pr]
+            for slot, idx, n in part:
+                global_metrics.inc("engine_state_snapshots_total")
+                global_metrics.inc("engine_state_bytes_total",
+                                   self._state_row_bytes)
+                if global_tracer.enabled:
+                    global_tracer.add_event(
+                        "engine.state_snapshot", trace_id=None,
+                        track="engine-loop",
+                        attrs={"slot": slot, "snapshot": idx, "boundary": n,
+                               "bytes": self._state_row_bytes},
+                    )
+            self._snap_pool = self._state_save_op(
+                self._snap_pool, self.kv_cache, *self._state_rows(
+                    [slot for slot, _i, _n in part],
+                    [idx for _s, idx, _n in part]))
 
     def _ring_live(self, wave: List[Tuple[int, List[int]]],
                    lead: int = 0) -> List[Tuple[int, List[int]]]:
@@ -4098,6 +4262,8 @@ class InferenceEngine(BlockDecodeMixin):
             (run.slot, run.request.prompt_ids if held is None
              else run.request.prompt_ids[: held[i]])
             for i, run in enumerate(runs)])
+        if self._snapshots is not None:
+            self._state_snapshot(wave)
         entries = plan_inserts(
             self._prefix, wave,
             ms_per_token=self._prefill_ms_per_token or 1.0,
@@ -4558,10 +4724,13 @@ class InferenceEngine(BlockDecodeMixin):
         t_dispatch = time.monotonic()
         first_lp = self._dispatch_chunk_rows(chunk_rows, chunk)
         global_metrics.inc("engine_prefill_segments_total", len(rows))
-        if self._ring and self._prefix is not None:
+        if (self._ring or self._snapshots is not None) \
+                and self._prefix is not None:
             # a ring holds this segment's blocks now and not after the next
-            # one: save them here, in device order behind the segment (the
-            # final segment's are saved where the run finishes)
+            # one, and a slot's recurrent state is the state at this
+            # segment's end only until the next: save them here, in device
+            # order behind the segment (the final segment's are saved where
+            # the run finishes)
             mid = [(run, start + len(seg))
                    for run, start, seg, final in chunk_rows if not final]
             if mid:
@@ -5232,6 +5401,12 @@ class InferenceEngine(BlockDecodeMixin):
         global_metrics.set_gauge(
             "engine_prefix_pool_pages_reserved", self._prefix.reserved_pages
         )
+        if self._snapshots is not None:
+            global_metrics.set_gauge(
+                "engine_state_snapshots", len(self._snapshots))
+            global_metrics.set_gauge(
+                "engine_state_snapshot_bytes",
+                len(self._snapshots) * self._state_row_bytes)
         for metric, attr in (
             ("engine_prefix_evictions_total", "evictions"),
             ("engine_conv_hits_total", "conv_hits"),

@@ -75,6 +75,50 @@ class _Entry:
         self.prio = prio
 
 
+class StateSnapshots:
+    """Host index of the recurrent-state snapshots kept beside the pages
+    (a family whose cache is rows a token AND a state a slot:
+    models/ssm_moe.py).  A snapshot is the state after a whole number of
+    blocks, keyed by the chain key of the last of them (which commits to
+    the whole prefix); slot 0 of the snapshot arrays is the scratch target
+    of padding.  Plain LRU, pure host state; pages and snapshots are
+    evicted apart, and a match takes the longest pooled prefix that ends at
+    a snapshot (:meth:`PrefixIndex.match`)."""
+
+    def __init__(self, capacity: int):
+        assert capacity >= 2, "need at least scratch + one snapshot"
+        self.capacity = capacity
+        self._free: List[int] = list(range(1, capacity))
+        self._lru: "OrderedDict[bytes, int]" = OrderedDict()
+        self.evictions = 0
+
+    def __len__(self) -> int:
+        return len(self._lru)
+
+    def __contains__(self, key: bytes) -> bool:
+        return key in self._lru
+
+    def lookup(self, key: bytes) -> Optional[int]:
+        """The snapshot taken at ``key``'s boundary (touched), or None."""
+        idx = self._lru.get(key)
+        if idx is not None:
+            self._lru.move_to_end(key)
+        return idx
+
+    def allocate(self, key: bytes) -> int:
+        """Where ``key``'s snapshot goes: its own slot if it has one, a
+        free one, else the least recently used one's."""
+        idx = self.lookup(key)
+        if idx is None:
+            if self._free:
+                idx = self._free.pop()
+            else:
+                _old, idx = self._lru.popitem(last=False)
+                self.evictions += 1
+            self._lru[key] = idx
+        return idx
+
+
 class PagePinError(ValueError):
     """A KV page's compatibility pins don't match the engine's (quant mode,
     group size, kv_quant, dtype, block geometry): splicing its bytes would
@@ -208,6 +252,10 @@ class PrefixIndex:
         # them signals reuse-distance > capacity (the detector's input).
         self._recent_evicted: "OrderedDict[bytes, float]" = OrderedDict()
         self.thrash_reallocs = 0
+        # Where a cached token is not all a sequence carries (a recurrent
+        # state a slot): the engine's StateSnapshots.  A match then ends at
+        # the longest pooled boundary that has one.
+        self.snapshots: Optional[StateSnapshots] = None
 
     @property
     def used_blocks(self) -> int:
@@ -313,12 +361,23 @@ class PrefixIndex:
 
         Capped at ``(len(prompt)-1) // block`` blocks so at least one real
         token remains for the tail prefill (the first sampled token comes
-        from the tail's last logits)."""
+        from the tail's last logits).  With :attr:`snapshots` the match
+        ends at the longest pooled boundary that has a snapshot: rows
+        without the state at their end restore nothing."""
         self.lookups += 1
         max_blocks = (len(prompt_ids) - 1) // self.block
         ids: List[int] = []
         conv_blocks = 0
-        for key in self._keys_of(prompt_ids)[:max_blocks]:
+        keys = self._keys_of(prompt_ids)[:max_blocks]
+        if self.snapshots is not None:
+            n = 0
+            for i, key in enumerate(keys):
+                if key not in self._lru:
+                    break
+                if key in self.snapshots:
+                    n = i + 1
+            keys = keys[:n]
+        for key in keys:
             entry = self._lru.get(key)
             if entry is None:
                 break
@@ -563,6 +622,12 @@ class PrefixIndex:
         under prefix-only protection)."""
         max_blocks = (len(prompt_ids) - 1) // self.block
         return list(self._keys_of(prompt_ids)[:max_blocks])
+
+    def block_keys(self, ids) -> List[bytes]:
+        """The chain keys of every whole block of ``ids`` (no LRU touch, no
+        cap: the last one names the boundary at ``len(ids)`` where that is
+        whole blocks)."""
+        return list(self._keys_of(ids))
 
     def touch_resident(self, keys) -> None:
         """MRU-touch the resident members of a page-in wave's protection
@@ -1005,7 +1070,10 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
         matter."""
         out = dict(cache)
         with jax.named_scope("pool_copy"):
-            for key, arr in cache.items():
+            # (the pool's leaves: a cache may hold more than rows a token,
+            # a recurrent state a slot, which passes as it is)
+            for key in pool:
+                arr = cache[key]
                 unit = block // 2 if key in packed_keys else block
                 pos = _pos(unit, blk_nos)
                 vals = pool[key][:, pool_ids]  # [L, R, Nmax, unit, ...]
@@ -1052,6 +1120,38 @@ def make_batch_copy_ops(block: int, max_blocks: int, rows: int,
         jax.jit(blocks_to_cache, donate_argnums=(0,)),
         jax.jit(cache_to_pool, donate_argnums=(0,)),
     )
+
+
+def make_state_copy_ops(keys: Tuple[str, ...], rows: int):
+    """The two copy programs of a recurrent state's snapshots, batched like
+    the pages': ``restore(cache, snaps, slots [R], ids [R])`` lays snapshot
+    ``ids[r]`` of every leaf in ``keys`` over cache row ``slots[r]``
+    (``[L, rows, ...]`` <- ``[L, capacity, ...]``), ``save(snaps, cache,
+    slots, ids)`` the other way.  Padding rows name the scratch slot and
+    snapshot 0 on both sides: duplicates write identical bytes.  A row at a
+    time, by slices at traced indices: a gather of two rows out of a leaf
+    of gigabytes read the leaf (9 % of the device in the first chip run)."""
+
+    def _move(dst, src, to, frm):
+        for r in range(rows):
+            row = jax.lax.dynamic_slice_in_dim(src, frm[r], 1, axis=1)
+            dst = jax.lax.dynamic_update_slice_in_dim(dst, row, to[r], axis=1)
+        return dst
+
+    def restore(cache, snaps, slots, ids):
+        out = dict(cache)
+        with jax.named_scope("state_write"):
+            for key in keys:
+                out[key] = _move(cache[key], snaps[key], slots, ids)
+        return out
+
+    def save(snaps, cache, slots, ids):
+        with jax.named_scope("state_read"):
+            return {key: _move(snaps[key], cache[key], ids, slots)
+                    for key in keys}
+
+    return (jax.jit(restore, donate_argnums=(0,)),
+            jax.jit(save, donate_argnums=(0,)))
 
 
 def make_spill_ops():

@@ -179,6 +179,32 @@ class ModelConfig:
     # so for the same reason); read on the CPU at 7 layers of 64 experts it
     # takes 18 % off the distance to the float32 reference.
     residual_f32: bool = False
+    # One mixer a layer by a pattern given as data (models/ssm_moe.py):
+    # ``mixer_pattern[l]`` is ``M`` (a Mamba-2 state-space mixer), ``E``
+    # (routed experts) or ``*`` (attention), each alone under one norm and
+    # one residual; ``n_layers`` counts them all and no position is
+    # encoded.  An ``M`` layer has ``ssm_heads`` heads of ``ssm_head_dim``
+    # (its inner width their product), ``ssm_groups`` groups that share B
+    # and C of ``ssm_state`` values, a causal depthwise convolution over
+    # ``ssm_conv`` positions, and a chunked scan of ``ssm_chunk`` positions
+    # in prefill; ``ssm_dt_*`` bound the time step its bias is drawn for.
+    # Its recurrent state, one ``[heads, head_dim, state]`` array a slot and
+    # layer, is held in float32 beside the KV planes (ssm_moe.STATE_DTYPE).
+    mixer_pattern: Optional[str] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    # The routed layer's experts: gated (three products, ``act(gate) * up``
+    # then down) or not (two: ``act(up)`` then down); the shared experts'
+    # width where it is not the routed experts' (0: ``moe_ffn_dim``).
+    expert_gated: bool = True
+    shared_expert_dim: int = 0
 
     @property
     def q_per_kv(self) -> int:
@@ -203,6 +229,17 @@ class ModelConfig:
         return self.moe_ffn_dim or self.ffn_dim
 
     @property
+    def expert_dim_held(self) -> int:
+        """An expert's width as its matrices are held: ``expert_dim``, but
+        whole lane tiles of 128 where it is wider than one and is not (1856
+        is held as 1920: the columns added to the first matrix and the rows
+        added to the last are zeros, which add nothing to any result, and a
+        lane-tiled array takes those bytes on the chip either way).  The
+        grouped kernel's DMA takes whole tiles (ops/pallas_grouped_matmul)."""
+        f = self.expert_dim
+        return f if f < 128 else -(-f // 128) * 128
+
+    @property
     def q_head_dim(self) -> int:
         if self.kv_lora_rank:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -216,12 +253,29 @@ class ModelConfig:
         if self.attn_pattern is not None:
             return tuple("window" if k else "full"
                          for k in self.attn_pattern[: self.n_layers])
+        if self.mixer_pattern is not None:
+            return ("full",) * self.mixer_kinds.count("*")
         if self.sliding_window is None:
             return ("full",) * self.n_layers
         if self.window_pattern == "all":
             return ("window",) * self.n_layers
         return tuple("window" if l % 2 == 0 else "full"
                      for l in range(self.n_layers))
+
+    @property
+    def mixer_kinds(self) -> str:
+        """The held layers' letters (``mixer_pattern``'s first
+        ``n_layers``)."""
+        return (self.mixer_pattern or "")[: self.n_layers]
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """What the convolution runs over: x, B and C side by side."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     def kv_heads_of(self, kind: str) -> int:
         if kind == "window" and self.window_kv_heads:
@@ -680,6 +734,107 @@ def tiny_sdar_moe(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+#: hybrid_override_pattern of NVIDIA-Nemotron-3-Nano-30B-A3B.
+_NEMOTRON_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def nemotron_3_nano_30b_a3b() -> ModelConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B as published (huggingface.co/nvidia/
+    NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 config.json, ``model_type``
+    ``nemotron_h``): 52 layers, each one mixer alone: 23 Mamba-2 (64 heads
+    of 64, 8 groups, state 128, conv 4), 23 of 128 routed experts of 1856
+    top-6 (relu squared, no gate; sigmoid scores, a selection bias, 2.5)
+    with a shared expert of 3712, and 6 of attention (32 query / 2 KV heads
+    of 128, no rotary).  For shapes and tests of the config: no chip holds
+    it."""
+    return ModelConfig(
+        name="nemotron-3-nano-30b-a3b",
+        vocab_size=131072,
+        dim=2688,
+        n_layers=52,
+        n_heads=32,
+        n_kv_heads=2,
+        head_dim=128,
+        ffn_dim=1856,
+        norm_eps=1e-5,
+        act="relu2",
+        n_experts=128,
+        n_experts_per_tok=6,
+        moe_ffn_dim=1856,
+        n_shared_experts=1,
+        shared_expert_dim=3712,
+        expert_gated=False,
+        router_score="sigmoid",
+        router_bias=True,
+        routed_scale=2.5,
+        v_head_dim=128,
+        mixer_pattern=_NEMOTRON_PATTERN,
+        ssm_heads=64,
+        ssm_head_dim=64,
+        ssm_groups=8,
+        ssm_state=128,
+        ssm_conv=4,
+        ssm_chunk=128,
+        residual_f32=True,
+    )
+
+
+def nemotron_3_nano_30b_a3b_ep2s() -> ModelConfig:
+    """One chip's share of NVIDIA-Nemotron-3-Nano-30B-A3B: one of 2 chips
+    that share each layer (experts 0-63, vocabulary rows 0-65,535; the
+    mixers whole on each) and the first of 4 pipeline stages of 13 layers
+    (``MEMEM*EMEMEM*``: 6 Mamba-2, 5 routed, 2 attention).  Every width,
+    the router's 128 outputs and top-6 are as published."""
+    return replace(nemotron_3_nano_30b_a3b(),
+                   name="nemotron-3-nano-30b-a3b-ep2s", n_layers=13,
+                   published_layers=52, vocab_size=65536, layer_chips=2,
+                   chip_index=0)
+
+
+def tiny_ssm_moe(vocab_size: int = 512) -> ModelConfig:
+    """CPU-testable Nemotron-H-style config: ``MEM*EM*`` (3 Mamba-2 of 4
+    heads of 8, 2 groups, state 16, scan chunks of 8; 2 layers of 8 experts
+    of 24 top-2 with a shared expert of 48; 2 of attention, 4 query heads
+    on 2 KV heads of 16)."""
+    return ModelConfig(
+        name="tiny-ssm-moe",
+        vocab_size=vocab_size,
+        dim=64,
+        n_layers=7,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=16,
+        ffn_dim=24,
+        norm_eps=1e-5,
+        act="relu2",
+        n_experts=8,
+        n_experts_per_tok=2,
+        moe_ffn_dim=24,
+        n_shared_experts=1,
+        shared_expert_dim=48,
+        expert_gated=False,
+        router_score="sigmoid",
+        router_bias=True,
+        routed_scale=2.5,
+        v_head_dim=16,
+        mixer_pattern="MEM*EM*",
+        ssm_heads=4,
+        ssm_head_dim=8,
+        ssm_groups=2,
+        ssm_state=16,
+        ssm_conv=4,
+        ssm_chunk=8,
+        residual_f32=True,
+    )
+
+
+def tiny_ssm_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
+    """tiny-ssm-moe as one of 2 chips that share each layer: experts 0-3
+    and ``vocab_size`` rows of a table twice as long."""
+    return replace(tiny_ssm_moe(vocab_size), name="tiny-ssm-moe-ep2s",
+                   layer_chips=2, chip_index=0)
+
+
 def tiny_swa_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
     """tiny-swa-moe as one of 2 chips that share each layer: experts 0-3
     and ``vocab_size`` rows of a table twice as long."""
@@ -689,6 +844,10 @@ def tiny_swa_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
 
 PRESETS = {
     "tiny": tiny,
+    "tiny-ssm-moe": tiny_ssm_moe,
+    "tiny-ssm-moe-ep2s": tiny_ssm_moe_ep2s,
+    "nemotron-3-nano-30b-a3b": nemotron_3_nano_30b_a3b,
+    "nemotron-3-nano-30b-a3b-ep2s": nemotron_3_nano_30b_a3b_ep2s,
     "tiny-sdar-moe": tiny_sdar_moe,
     "sdar-30b-a3b": sdar_30b_a3b,
     "sdar-30b-a3b-pp7s": sdar_30b_a3b_pp7s,

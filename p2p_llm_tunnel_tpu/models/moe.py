@@ -4,15 +4,19 @@ One layer serves every routed family the repo has: mixtral-style blocks
 (``tiny-moe``, ``mixtral-8x7b``: softmax router, top-k renormalised, all
 experts held) and the aux-free-bias family (``sarvam-105b``: sigmoid scores,
 a selection bias, a scaling factor, a shared expert, and possibly only one
-chip's share of the experts).
+chip's share of the experts).  An expert is three products (gated:
+``down(act(gate x) * up x)``, the SwiGLU of most families) or two
+(``cfg.expert_gated`` off: ``down(act(up x))``, Nemotron-H's relu squared);
+a shared expert is of the same form and of its own width
+(``cfg.shared_expert_dim``; the routed experts' where that is 0).
 
 What it does, for ``N`` tokens, ``E`` published experts, top-``k``:
 
 - ``moe_route``: scores all ``E`` experts in float32 (the router keeps its
   published width whatever is held) and picks ``k`` a token.
 - ``moe_experts``: the ``N * k`` assignments are sorted by expert, those to
-  experts this process does not hold last; the held experts' SwiGLUs run as
-  three grouped matrix products over the sorted rows, and each row's result
+  experts this process does not hold last; the held experts run as three
+  (or two) grouped matrix products over the sorted rows, and each row's result
   goes back to its token, weighted.  Shapes are static and nothing is
   dropped under any imbalance: the sorted buffer has room for every
   assignment, so one expert may take them all.  Rows past the held groups
@@ -26,7 +30,7 @@ What it does, for ``N`` tokens, ``E`` published experts, top-``k``:
   row window that follows the group, 540-720 GB/s at the same shapes (4.4
   us for that expert), and is taken where the experts are read in the stack
   of all layers, on one chip, up to the rows an expert that were measured.
-- ``moe_shared``: the shared experts, one SwiGLU over every token.
+- ``moe_shared``: the shared experts, one feed-forward over every token.
 
 The held experts are ``cfg.experts_held``: with ``layer_chips`` chips sharing
 a layer, chip ``i`` holds experts ``[i * E/n, (i + 1) * E/n)``.  A token
@@ -61,7 +65,9 @@ from p2p_llm_tunnel_tpu.ops.pallas_grouped_matmul import (
 #: tokens, those to held experts, the fullest held expert's tokens, and the
 #: held experts that got a token.
 STATS = 4
-#: The leaves that hold experts, [L, E, ...] in a stacked block tree.
+#: The leaves that hold experts, [L, E, ...] in a stacked block tree: a
+#: gated expert's three; an expert of two products has no ``moe_gate``
+#: (:func:`expert_leaves`).
 EXPERT_LEAVES = ("moe_gate", "moe_up", "moe_down")
 #: A dispatch record's ``moe`` where the products are the compiler's.
 RAGGED = "ragged-dot"
@@ -70,6 +76,11 @@ KERNEL_VMEM = 96 * 2**20
 #: Sorted rows an expert (of all the published ones: what a held expert
 #: expects) the kernel is taken up to: the most the contest measured.
 KERNEL_ROWS_AN_EXPERT = 64
+
+
+def expert_leaves(cfg) -> Tuple[str, ...]:
+    """The expert leaves a model of ``cfg`` has."""
+    return EXPERT_LEAVES if cfg.expert_gated else EXPERT_LEAVES[1:]
 
 
 def init_moe_blocks(cfg, keys, dense_fn, per_expert: bool = False) -> dict:
@@ -140,7 +151,7 @@ def route(cfg, blk, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 def grouped_product_branch(cfg, mesh, tokens: int, stacked: bool = True,
                            dtype=jnp.bfloat16) -> str:
-    """Which implementation the three grouped products of a routed layer
+    """Which implementation the grouped products of a routed layer
     take in a program that runs ``tokens`` token positions a layer:
     ``"ragged-dot"`` (``jax.lax.ragged_dot``: the chip compiler's grouped
     kernel) or ``ops/pallas_grouped_matmul.py``'s kernel by its name
@@ -157,7 +168,9 @@ def grouped_product_branch(cfg, mesh, tokens: int, stacked: bool = True,
       the stack of all layers (``moe_mlp``'s ``stacked``): a layer's slice
       handed to a kernel is a copy first, and the programs that slice are
       the ones a mesh may shard;
-    - the static shapes: the kernel wins where an expert gets few of the
+    - the static shapes: an expert's two widths as held
+      (``cfg.expert_dim_held``) are whole lane tiles of 128 (asked of the
+      chip's compiler, not of the interpreter); the kernel wins where an expert gets few of the
       ``tokens * k`` sorted rows (``KERNEL_ROWS_AN_EXPERT`` of the
       published experts: the contest of PERF.md section 6, PR 39), and it
       keeps a block of rows, its result and a ring of an expert's tiles in
@@ -170,10 +183,17 @@ def grouped_product_branch(cfg, mesh, tokens: int, stacked: bool = True,
         return RAGGED
     if mesh is not None and any(n > 1 for n in dict(mesh.shape).values()):
         return RAGGED
+    width = cfg.expert_dim_held
+    if not cfg.flash_interpret and (cfg.dim % 128 or width % 128):
+        # the kernel's DMA takes an expert's matrix in whole lane tiles
+        # (the chip's compiler refuses a slice of 1856 columns, 14.5 tiles:
+        # a family whose experts are so wide holds them padded,
+        # ``cfg.expert_dim_held``)
+        return RAGGED
     rows = tokens * cfg.n_experts_per_tok
     out = jnp.float32 if cfg.residual_f32 else dtype
-    need = max(vmem_bytes(rows, cfg.dim, cfg.expert_dim, dtype, out),
-               vmem_bytes(rows, cfg.expert_dim, cfg.dim, dtype, out))
+    need = max(vmem_bytes(rows, cfg.dim, width, dtype, out),
+               vmem_bytes(rows, width, cfg.dim, dtype, out))
     if rows > KERNEL_ROWS_AN_EXPERT * cfg.n_experts or need > KERNEL_VMEM:
         return RAGGED
     return GROUPED_KERNEL
@@ -186,12 +206,13 @@ def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
 
     ``blk`` holds this layer's slice: router [Dm, E], the held experts
     [He, ...], and where the config has them ``router_bias`` [E] and the
-    shared experts' ``shared_gate/up`` [Dm, Fs], ``shared_down`` [Fs, Dm].
+    shared experts' ``shared_gate/up`` [Dm, Fs], ``shared_down`` [Fs, Dm]
+    (no ``moe_gate`` / ``shared_gate`` where ``cfg.expert_gated`` is off).
     ``counted`` [B, T] bool marks the tokens that ``stats`` (int32
     [STATS]) counts: padding is computed like any token and counts for
     nothing.
 
-    ``stacked`` + ``layer``: the experts of ALL expert layers as three
+    ``stacked`` + ``layer``: the experts of ALL expert layers as three (two)
     arrays [layers * He, ...] and this layer's index among them, in place of
     ``blk``'s expert leaves.  The grouped product then names its experts by
     the groups' sizes (every other layer's are empty) and reads them where
@@ -242,7 +263,7 @@ def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
             visits = visit_list(sizes, layer * held)
         elif stacked is not None:
             sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((stacked["moe_gate"].shape[0],), jnp.int32), sizes,
+                jnp.zeros((stacked["moe_up"].shape[0],), jnp.int32), sizes,
                 (layer * held,))
         rows = round_act(x, aq)[token_of]  # [N*k, Dm], sorted by expert
         # under cfg.residual_f32 the values between the three products stay
@@ -259,9 +280,13 @@ def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
                 out_dtype=wide.get("preferred_element_type", lhs.dtype),
                 interpret=cfg.flash_interpret)
 
-        gate = product(rows, "moe_gate")
-        up = product(rows, "moe_up")
-        inner = round_act((act_fn(gate) * up).astype(rows.dtype), aq)
+        if cfg.expert_gated:
+            gate = product(rows, "moe_gate")
+            up = product(rows, "moe_up")
+            inner = act_fn(gate) * up
+        else:
+            inner = act_fn(product(rows, "moe_up"))
+        inner = round_act(inner.astype(rows.dtype), aq)
         down = product(inner, "moe_down")
         # (rows past the held groups hold nothing defined: their weight is 0)
         part = jnp.where(weight[:, None] > 0,
@@ -269,8 +294,11 @@ def moe_mlp(cfg, blk, h: jnp.ndarray, act_fn,
         out = jnp.zeros((n, dm), jnp.float32).at[token_of].add(part)
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
-            inner = act_fn(mm(x, blk["shared_gate"], aq)) * mm(
-                x, blk["shared_up"], aq)
+            if cfg.expert_gated:
+                inner = act_fn(mm(x, blk["shared_gate"], aq)) * mm(
+                    x, blk["shared_up"], aq)
+            else:
+                inner = act_fn(mm(x, blk["shared_up"], aq))
             out = out + mm(inner, blk["shared_down"], aq).astype(jnp.float32)
     # (under cfg.residual_f32 the stream takes the sum as it is)
     return out.astype(jnp.float32 if cfg.residual_f32 else h.dtype).reshape(

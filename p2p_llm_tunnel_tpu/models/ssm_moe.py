@@ -1,0 +1,657 @@
+"""One mixer a layer by a pattern: Mamba-2 state-space layers, routed experts
+and attention, each alone under one norm and one residual
+(``NVIDIA-Nemotron-3-Nano-30B-A3B``, ``model_type`` ``nemotron_h``).
+
+``models/transformer.py`` hands its entry points here when
+``cfg.mixer_pattern`` is set, so the engine, the prefix pool and the tunnel
+run this family through the calls they make for every other.
+
+Layers.  ``x <- x + mixer(RMSNorm(x))``, the mixer by the layer's letter in
+``cfg.mixer_kinds``: ``M`` a Mamba-2 mixer (models/ssm.py), ``E`` routed
+experts of two products (``down(relu(up u)^2)``) with a shared expert of its
+own width (models/moe.py), ``*`` attention (``cfg.n_heads`` query heads on
+``cfg.n_kv_heads`` KV heads, causal, no rotary: no position is encoded
+anywhere).  The kinds differ in shape, so each kind's weights are stacked by
+themselves (``mamba``, ``attn``, ``blocks`` = the routed layers) and the
+layers are written out in order, each taking its static slice; where the
+grouped products are the kernel's, the routed layers read the stacked
+experts where they lie (``moe_mlp(stacked=...)``).
+
+**The cache is KV planes and a state a slot**, one dict under one allocator:
+
+- ``"k"``, ``"v"`` ``[La, rows, S, K * D]``: the attention layers' planes, a
+  row a position's KV heads side by side (models/swa.py's full planes:
+  whole lane tiles, read by decode where they lie);
+- ``"ssm"`` ``[Lm, rows, H, P, N]`` (:data:`STATE_DTYPE`, float32): each
+  Mamba-2 layer's recurrent state, and ``"conv"`` ``[Lm, rows, K - 1, C]``
+  (the activations' type): the convolution's last ``K - 1`` inputs
+  (:data:`STATE_KEYS`; positions on the sublanes, the ``C`` channels on the
+  lanes: three values on the lanes would be padded to a tile).
+
+A token caches rows in the attention layers only (the prefix pool's pages);
+the state is no function of one token, so the pool holds **snapshots** of it
+at block boundaries beside the pages (engine/prefix_cache.py).  A sequence
+starts from zeros: a prefill that begins at position 0 reads no state (a
+slot's last tenant leaves nothing behind), one that continues
+(``starts > 0``: a later segment, or the tail behind a restored snapshot)
+reads the slot's.  Padding leaves state and tail as they are: a padded
+position's ``dt`` is 0, padded rows lie on the scratch slot, a decode row
+parked at ``positions >= S`` updates nothing.
+
+The residual stream is float32 and every product takes it rounded to the
+parameters' type; the router scores the normed stream before that rounding
+(``models/mla.py``'s reasons), and what lies between a layer's products
+stays float32 where float32 arithmetic follows (``_mm32``: a Mamba-2
+layer's ``z``, ``dt`` and output, an attention layer's output; the experts'
+results under ``cfg.residual_f32``, which the presets set): with half of a
+layer's experts held, every rounding moves some token to another expert
+than the float32 reference's.  Every program also returns what its routed
+layers counted (``moe.STATS``).
+
+Decode reads an attention layer by what
+``transformer.decode_attention_branch`` answers: on the TPU, over plain
+bf16 planes, ``decode_attention_rows`` over the stacked planes where they
+lie; elsewhere the einsum over the layer's ``kv_view`` positions.  A Mamba-2
+layer's state is read and written where it lies: the layer's ``[rows, H, P,
+N]`` slice of the donated leaf, updated elementwise (scope ``ssm_step``).
+Int8 planes (``--kv-quant int8``) are models/swa.py's: the benchmark's cache
+control; the state has no quantised form.
+
+Scopes: ``ssm_proj`` (the two projections, the gate and its norm),
+``ssm_conv``, ``ssm_scan`` (prefill), ``ssm_step`` (decode), ``state_read``
+/ ``state_write`` (a slice or copy of a state leaf that is not the update
+itself), beside ``attn``, ``kv_read``, ``kv_write``, ``moe_route``,
+``moe_experts``, ``moe_shared``, ``head_sample``.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from p2p_llm_tunnel_tpu.models.config import ModelConfig
+from p2p_llm_tunnel_tpu.models.mla import (
+    ROUTER_BIAS_STD,
+    _counted,
+    _embed,
+    _head,
+)
+from p2p_llm_tunnel_tpu.models.moe import (
+    RAGGED,
+    STATS,
+    expert_leaves,
+    grouped_product_branch,
+    moe_mlp,
+)
+from p2p_llm_tunnel_tpu.models.quant import mm, round_act
+from p2p_llm_tunnel_tpu.models.ssm import (
+    causal_conv,
+    gated_group_norm,
+    ssm_scan,
+    ssm_step,
+)
+from p2p_llm_tunnel_tpu.models.swa import _as_held, _attend, _pack, _unpack, _write
+from p2p_llm_tunnel_tpu.ops.attention import window_mask
+from p2p_llm_tunnel_tpu.ops.norms import rms_norm
+
+#: The cache leaves that are a state a slot, not rows a token: the prefix
+#: pool keeps snapshots of them, never pages.
+STATE_KEYS = ("ssm", "conv")
+#: The recurrent state's type: float32, as the model's card asks of its
+#: servers.  A constant, not an option: ``correct`` cannot tell a bfloat16
+#: state apart (PERF.md section 2), so a narrower state has to come as a
+#: path of its own with a number that judges it.
+STATE_DTYPE = jnp.float32
+#: The weights' group of each letter.
+GROUP = {"M": "mamba", "E": "blocks", "*": "attn"}
+
+
+def kind_counts(cfg: ModelConfig) -> dict:
+    kinds = cfg.mixer_kinds
+    return {k: kinds.count(k) for k in GROUP}
+
+
+def _places(cfg: ModelConfig):
+    """(letter, index in its kind's stack) of every layer, in order."""
+    seen = dict.fromkeys(GROUP, 0)
+    out = []
+    for kind in cfg.mixer_kinds:
+        out.append((kind, seen[kind]))
+        seen[kind] += 1
+    return out
+
+
+def state_bytes_per_slot(cfg: ModelConfig, dtype=jnp.bfloat16) -> int:
+    """What a slot's recurrent state takes in all Mamba-2 layers."""
+    lm = kind_counts(cfg)["M"]
+    ssm = (cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
+           * jnp.dtype(STATE_DTYPE).itemsize)
+    conv = (cfg.ssm_conv - 1) * cfg.ssm_conv_dim * jnp.dtype(dtype).itemsize
+    return lm * (ssm + conv)
+
+
+# ---------------------------------------------------------------------------
+# init
+# ---------------------------------------------------------------------------
+
+def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
+    """Random init (``benchmarks/ssm_moe_reference.py`` states it again for
+    the benchmark); an expert is drawn from its own key by its PUBLISHED
+    index, one at a time (``models/mla.init_params``'s scheme).  ``A_log``,
+    ``dt_bias`` and ``D`` are drawn as the family's own initialiser draws
+    them (``A`` uniform in [1, 16], the time step log-uniform between
+    ``ssm_dt_min`` and ``ssm_dt_max`` through the inverse softplus, ``D``
+    ones), so that random weights decay as trained ones do."""
+    dm, h, kv, hd, v = (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
+                        cfg.vocab_size)
+    n = kind_counts(cfg)
+    lm, le, la = n["M"], n["E"], n["*"]
+    keys = jax.random.split(key, 16)
+
+    def dense(k, shape, fan_in):
+        return (jax.random.truncated_normal(k, -2, 2, shape, jnp.float32)
+                * (fan_in ** -0.5)).astype(dtype)
+
+    params = {
+        "embed": dense(keys[7], (v, dm), dm),
+        "final_norm": jnp.ones((dm,), dtype),
+        "lm_head": dense(jax.random.fold_in(key, 99), (dm, v), dm),
+    }
+    if la:
+        ks = jax.random.split(keys[0], 4)
+        params["attn"] = {
+            "norm": jnp.ones((la, dm), dtype),
+            "wq": dense(ks[0], (la, dm, h * hd), dm),
+            "wk": dense(ks[1], (la, dm, kv * hd), dm),
+            "wv": dense(ks[2], (la, dm, kv * hd), dm),
+            "wo": dense(ks[3], (la, h * hd, dm), h * hd),
+        }
+    if lm:
+        ks = jax.random.split(keys[1], 6)
+        inner, conv_dim, heads = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads
+        dt = jnp.exp(jax.random.uniform(
+            ks[4], (lm, heads), jnp.float32, jnp.log(cfg.ssm_dt_min),
+            jnp.log(cfg.ssm_dt_max)))
+        dt = jnp.maximum(dt, cfg.ssm_dt_floor)
+        params["mamba"] = {
+            "norm": jnp.ones((lm, dm), dtype),
+            "w_in": dense(ks[0], (lm, dm, inner + conv_dim + heads), dm),
+            "conv_w": dense(ks[1], (lm, cfg.ssm_conv, conv_dim), cfg.ssm_conv),
+            "conv_b": dense(ks[2], (lm, conv_dim), cfg.ssm_conv),
+            "w_out": dense(ks[3], (lm, inner, dm), inner),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "a_log": jnp.log(jax.random.uniform(
+                ks[5], (lm, heads), jnp.float32, 1.0, 16.0)),
+            "d_skip": jnp.ones((lm, heads), jnp.float32),
+            "gate_norm": jnp.ones((lm, inner), dtype),
+        }
+    if le:
+        e, fe = cfg.n_experts, cfg.expert_dim
+        fs = cfg.n_shared_experts * (cfg.shared_expert_dim or fe)
+        lo, held = cfg.experts_held
+
+        pad = cfg.expert_dim_held - fe  # zeros: cfg.expert_dim_held
+
+        def experts(k, shape, fan_in):
+            def one(i):
+                ke = jax.random.fold_in(
+                    jax.random.fold_in(k, i // held), lo + i % held)
+                w = dense(ke, shape, fan_in)
+                return jnp.pad(w, ((0, pad), (0, 0)) if shape[0] == fe
+                               else ((0, 0), (0, pad)))
+
+            flat = jax.lax.map(one, jnp.arange(le * held))
+            return flat.reshape((le, held) + flat.shape[1:])
+
+        blocks = {
+            "norm": jnp.ones((le, dm), dtype),
+            "router": dense(keys[8], (le, dm, e), dm),
+            "moe_up": experts(keys[10], (dm, fe), dm),
+            "moe_down": experts(keys[11], (fe, dm), fe),
+        }
+        if cfg.expert_gated:
+            blocks["moe_gate"] = experts(keys[9], (dm, fe), dm)
+        if cfg.router_bias:
+            blocks["router_bias"] = ROUTER_BIAS_STD * jax.random.normal(
+                keys[12], (le, e), jnp.float32)
+        if fs:
+            blocks["shared_up"] = dense(keys[14], (le, dm, fs), dm)
+            blocks["shared_down"] = dense(keys[15], (le, fs, dm), fs)
+            if cfg.expert_gated:
+                blocks["shared_gate"] = dense(keys[13], (le, dm, fs), dm)
+        params["blocks"] = blocks
+    return params
+
+
+def init_kv_cache(cfg: ModelConfig, num_slots: int, max_seq: int,
+                  dtype=jnp.bfloat16, quant=False):
+    """The attention layers' planes ``"k"``, ``"v"`` and the Mamba-2 layers'
+    ``"ssm"`` and ``"conv"`` (zeros: a sequence's start)."""
+    plane = dtype
+    if quant in (True, "int8"):
+        plane = jnp.int8
+    elif quant not in (False, None, "none", ""):
+        raise ValueError(f"the KV planes beside a recurrent state have no KV "
+                         f"quant mode {quant!r} (none | int8)")
+    n = kind_counts(cfg)
+    kv = cfg.n_kv_heads
+    out = {}
+    for name, width in (("k", cfg.head_dim), ("v", cfg.v_head_dim)):
+        out[name] = jnp.zeros((n["*"], num_slots, max_seq, kv * width), plane)
+        if plane == jnp.int8:
+            out[name + "_scale"] = jnp.zeros(
+                (n["*"], num_slots, max_seq, kv), jnp.float32)
+    out["ssm"] = jnp.zeros(
+        (n["M"], num_slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+        STATE_DTYPE)
+    out["conv"] = jnp.zeros(
+        (n["M"], num_slots, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype)
+    return out
+
+
+def cache_section(cfg: ModelConfig, kv_cache) -> dict:
+    """What /healthz ``config.model.cache`` says: the attention layers'
+    planes (what a pooled token takes) and the state a slot."""
+    la, positions = kv_cache["k"].shape[0], kv_cache["k"].shape[2]
+    per_token = sum(a.shape[3] * a.dtype.itemsize
+                    for name, a in kv_cache.items() if name not in STATE_KEYS)
+    ssm, conv = kv_cache["ssm"], kv_cache["conv"]
+    per_slot = sum(int(a[0, 0].size) * a.dtype.itemsize for a in (ssm, conv))
+    return {
+        "form": "kv_heads+state",
+        "kinds": {
+            "attention": {
+                "layers": la, "kv_heads": cfg.n_kv_heads,
+                "key_width": cfg.head_dim, "value_width": cfg.v_head_dim,
+                "positions_per_slot": positions,
+                "bytes_per_token_layer": per_token,
+            },
+            "state": {
+                "layers": ssm.shape[0], "heads": cfg.ssm_heads,
+                "head_width": cfg.ssm_head_dim, "state_width": cfg.ssm_state,
+                "type": str(ssm.dtype),
+                "conv_positions": conv.shape[2], "conv_width": conv.shape[3],
+                "conv_type": str(conv.dtype),
+                "bytes_per_slot": ssm.shape[0] * per_slot,
+            },
+        },
+        "bytes_per_token": la * per_token,
+        "bytes_per_slot": la * per_token * positions + ssm.shape[0] * per_slot,
+    }
+
+
+# ---------------------------------------------------------------------------
+# the layers
+# ---------------------------------------------------------------------------
+
+def _mm32(x, w, act_quant: bool):
+    """``x @ w`` with the product's float32 sums kept: the operands as
+    ``models.quant.mm`` takes them (the activations' type; int8 and back
+    under ``--quant a8``), the result not rounded to them.  For the
+    projections whose result meets float32 arithmetic next (the residual
+    stream's sum, the convolution, the gate): a rounding saved a layer."""
+    return jnp.dot(round_act(x, act_quant), w,
+                   preferred_element_type=jnp.float32)
+
+
+def _layer(stack, i: int, skip=()):
+    return {k: a[i] for k, a in stack.items() if k not in skip}
+
+
+def _mamba(cfg: ModelConfig, blk, h, tail, state, real, decode: bool):
+    """One Mamba-2 mixer over ``h [B,T,Dm]`` (normed, the weights' type):
+    ``tail [B,K-1,C]`` and ``state [B,H,P,N]`` before the segment, ``real
+    [B,T]`` the positions that are no padding -> (out ``[B,T,Dm]``, new
+    tail, new state).  ``decode``: ``T`` is 1 and the update is
+    :func:`ssm.ssm_step`."""
+    b, t, _ = h.shape
+    inner, heads, p = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_head_dim
+    g, n = cfg.ssm_groups, cfg.ssm_state
+    conv_dim = cfg.ssm_conv_dim
+    with jax.named_scope("ssm_proj"):
+        zxd = _mm32(h, blk["w_in"], cfg.act_quant)
+        z = zxd[..., :inner]
+        # (what the convolution carries from a dispatch to the next is the
+        # activations' type: the segment's inputs are rounded as the tail is)
+        xbc = zxd[..., inner:inner + conv_dim].astype(h.dtype)
+        dt = jax.nn.softplus(zxd[..., inner + conv_dim:] + blk["dt_bias"])
+        dt = jnp.where(real[..., None], dt, 0.0)
+    with jax.named_scope("ssm_conv"):
+        xbc, tail = causal_conv(blk["conv_w"], blk["conv_b"], tail, xbc,
+                                real.sum(axis=1).astype(jnp.int32))
+    x = xbc[..., :inner].reshape(b, t, heads, p)
+    bm = xbc[..., inner:inner + g * n].reshape(b, t, g, n)
+    cm = xbc[..., inner + g * n:].reshape(b, t, g, n)
+    a = -jnp.exp(blk["a_log"])
+    if decode:
+        with jax.named_scope("ssm_step"):
+            y, state = ssm_step(x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
+                                state)
+            y = y[:, None]
+    else:
+        with jax.named_scope("ssm_scan"):
+            y, new = ssm_scan(x, dt, a, bm, cm, state, cfg.ssm_chunk)
+            state = new.astype(state.dtype)
+    with jax.named_scope("ssm_proj"):
+        y = y + blk["d_skip"][:, None] * x.astype(jnp.float32)
+        y = gated_group_norm(y.reshape(b, t, inner), z, blk["gate_norm"], g,
+                             cfg.norm_eps)
+        return _mm32(y.astype(h.dtype), blk["w_out"], cfg.act_quant), \
+            tail, state
+
+
+def _qkv(cfg: ModelConfig, blk, h):
+    """h [B,T,Dm] -> q [B,T,H,D] and what the token caches: keys and values
+    ``[B,T,K*D]``, heads side by side.  No rotary."""
+    b, t, _ = h.shape
+    aq = cfg.act_quant
+    q = mm(h, blk["wq"], aq).reshape(b, t, cfg.n_heads, cfg.head_dim)
+    return q, mm(h, blk["wk"], aq), mm(h, blk["wv"], aq)
+
+
+def _stacked_experts(cfg: ModelConfig, params):
+    if "blocks" not in params:
+        return None
+    return {k: params["blocks"][k].reshape(
+        (-1,) + params["blocks"][k].shape[2:]) for k in expert_leaves(cfg)}
+
+
+def _run_layers(cfg: ModelConfig, params, x, counted, mamba, attend):
+    """The layers in order over the float32 stream ``x``: ``mamba(i, blk,
+    h)`` and ``attend(i, blk, h)`` give a Mamba-2 and an attention layer's
+    output from its normed input (``i``: the layer's index in its kind's
+    stack, and so in its kind's cache leaves).  Returns (x, stats)."""
+    from p2p_llm_tunnel_tpu.models.transformer import _act
+
+    dtype = params["embed"].dtype
+    # The kernel reads the experts of all layers where they lie; the
+    # compiler's own grouped product takes the layer's static slice (a
+    # stack's group sizes padded out from a static layer index is a pad
+    # the chip's compiler fails on).
+    stacked, leaves = None, ()
+    if grouped_product_branch(cfg, None, x.shape[0] * x.shape[1], True,
+                              dtype) != RAGGED:
+        stacked, leaves = _stacked_experts(cfg, params), expert_leaves(cfg)
+    total = jnp.zeros((STATS,), jnp.int32)
+    for kind, i in _places(cfg):
+        blk = _layer(params[GROUP[kind]], i, leaves)
+        h32 = rms_norm(x, blk["norm"], cfg.norm_eps)
+        h = h32.astype(dtype)
+        if kind == "M":
+            out = mamba(i, blk, h)
+        elif kind == "*":
+            out = attend(i, blk, h)
+        else:
+            with jax.named_scope("ffn"):
+                out, stats = moe_mlp(
+                    cfg, blk, h, lambda v: _act(cfg, v), counted,
+                    stacked=stacked, layer=i, router_in=h32)
+                total = total + stats
+        x = x + out.astype(jnp.float32)
+    return x, total
+
+
+# ---------------------------------------------------------------------------
+# the three serving programs (+ the whole-prompt forward)
+# ---------------------------------------------------------------------------
+
+def prefill(cfg: ModelConfig, params, tokens, valid, counted=None):
+    """Whole-prompt forward from a sequence's start: (logits [B,T,V],
+    {"full": (keys [La,B,T,K*D], values), "state": (ssm [Lm,B,H,P,N], conv
+    [Lm,B,K-1,C])}, stats of the ``counted`` tokens)."""
+    b, t = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(t), (b, t))
+    mask = window_mask(positions, jnp.where(valid, positions, -1))
+    dtype = params["embed"].dtype
+    if counted is None:
+        counted = valid
+    kv, states = [], []
+    zero_tail = jnp.zeros((b, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype)
+    zero_state = jnp.zeros(
+        (b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+
+    def mamba(i, blk, h):
+        out, tail, state = _mamba(cfg, blk, h, zero_tail, zero_state, valid,
+                                  False)
+        states.append((state, tail))
+        return out
+
+    def attend(i, blk, h):
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(cfg, blk, h)
+        kv.append((k, v))
+        a = _attend(cfg, "full", blk, q, k, v, mask)
+        with jax.named_scope("attn"):
+            return _mm32(a, blk["wo"], cfg.act_quant)
+
+    x, stats = _run_layers(cfg, params, _embed(cfg, params, tokens), counted,
+                           mamba, attend)
+    rows = {}
+    if kv:
+        rows["full"] = tuple(jnp.stack(a) for a in zip(*kv))
+    if states:
+        rows["state"] = tuple(jnp.stack(a) for a in zip(*states))
+    return _head(cfg, params, x), rows, stats
+
+
+def _rows_of(leaf, i: int, slots):
+    """Rows ``slots [Bp]`` of layer ``i`` of a state leaf ``[L, rows, ...]``
+    -> ``[Bp, ...]``, a row at a time by a slice at a traced index: the
+    layer's slice first and a gather of two rows out of it was a copy of the
+    layer's 270 MB in every Mamba-2 layer of every chunk-prefill dispatch (8
+    % of the device in the first traced runs)."""
+    zeros = (0,) * (leaf.ndim - 2)
+    return jnp.stack([
+        jax.lax.dynamic_slice(
+            leaf, (i, slots[r]) + zeros, (1, 1) + leaf.shape[2:])[0, 0]
+        for r in range(slots.shape[0])])
+
+
+def _write_state(kv_cache, states, slots):
+    """The dispatch's rows' new state into their slots, a layer at a time
+    (``states``: [(ssm [Bp,H,P,N], conv [Bp,K-1,C])] in stack order)."""
+    out = dict(kv_cache)
+    with jax.named_scope("state_write"):
+        for name, vals in zip(STATE_KEYS, zip(*states)):
+            leaf = out[name]
+            for i, v in enumerate(vals):
+                leaf = leaf.at[i, slots].set(v.astype(leaf.dtype))
+            out[name] = leaf
+    return out
+
+
+def prefill_into_cache(cfg, params, tokens, lengths, kv_cache, slots,
+                       return_prompt_logprobs=False, stat_rows=None):
+    """``transformer.prefill_into_cache`` for this family: the prompt's rows
+    into the attention planes, the state after its last real token into the
+    slot.  Returns (last logits, cache[, prompt log-probs], stats)."""
+    b, t = tokens.shape
+    valid = jnp.arange(t)[None, :] < lengths[:, None]
+    logits, rows, stats = prefill(cfg, params, tokens, valid,
+                                  _counted(valid, stat_rows))
+    last = jnp.take_along_axis(
+        logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    out = kv_cache
+    if "full" in rows:
+        s = kv_cache["k"].shape[2]
+        k, v = rows["full"]
+        positions = jnp.broadcast_to(jnp.arange(t), (b, t))
+        out = _write(cfg, out, "full", k[:, :, :s], v[:, :, :s], slots,
+                     positions[:, :s], None)
+    if "state" in rows:
+        out = _write_state(out, list(zip(*rows["state"])), slots)
+    if not return_prompt_logprobs:
+        return last, out, stats
+    lsm = jax.nn.log_softmax(logits[:, :-1], axis=-1)
+    scored = jnp.take_along_axis(lsm, tokens[:, 1:, None], axis=-1)[..., 0]
+    prompt_lps = jnp.concatenate(
+        [jnp.zeros((b, 1), jnp.float32), scored.astype(jnp.float32)], axis=1)
+    return last, out, prompt_lps, stats
+
+
+def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
+                             slots, kv_view: Optional[int] = None,
+                             return_all_logits: bool = False,
+                             stat_rows=None):
+    """``transformer.chunk_prefill_into_cache`` for this family: the tail of
+    each prompt against what its slot already holds.  An attention layer
+    reads its (layer, view) rows and lays the fresh tail over them; a
+    Mamba-2 layer starts from the slot's state and tail, from zeros where
+    ``starts`` is 0.  Neither the planes nor the state are a carry of the
+    layers: all tails and states are written once after them.  Returns
+    (logits, cache, stats)."""
+    from p2p_llm_tunnel_tpu.models.transformer import (
+        lay_tail,
+        read_cache_view,
+        tail_placement,
+    )
+
+    b, t = tokens.shape
+    s = kv_cache["k"].shape[2]
+    if kv_view is None or kv_view > s:
+        kv_view = s
+    quant = "k_scale" in kv_cache
+    dtype = params["embed"].dtype
+    pos = starts[:, None] + jnp.arange(t)[None, :]
+    valid = jnp.arange(t)[None, :] < lengths[:, None]
+    counted = _counted(valid, stat_rows)
+    place, fresh = tail_placement(kv_view, starts, t)
+    mask = window_mask(
+        pos, jnp.broadcast_to(jnp.arange(kv_view), (b, kv_view)))
+    carried = starts > 0
+    kv, states = [], []
+
+    def view_rows(name, i):
+        rows = read_cache_view(kv_cache[name], jnp.int32(i), kv_view, slots)
+        if not quant:
+            return rows
+        scale = read_cache_view(kv_cache[name + "_scale"], jnp.int32(i),
+                                kv_view, slots)
+        return _unpack(rows, scale, dtype)
+
+    def mamba(i, blk, h):
+        with jax.named_scope("state_read"):
+            state = jnp.where(carried[:, None, None, None],
+                              _rows_of(kv_cache["ssm"], i, slots), 0)
+            tail = jnp.where(carried[:, None, None],
+                             _rows_of(kv_cache["conv"], i, slots), 0)
+        out, tail, state = _mamba(cfg, blk, h, tail, state, valid, False)
+        states.append((state, tail))
+        return out
+
+    def attend(i, blk, h):
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(cfg, blk, h)
+        kv.append((k, v))
+        with jax.named_scope("kv_read"):
+            k_all = lay_tail(view_rows("k", i),
+                             _as_held(cfg, "full", k, quant), place, fresh)
+            v_all = lay_tail(view_rows("v", i),
+                             _as_held(cfg, "full", v, quant), place, fresh)
+        a = _attend(cfg, "full", blk, q, k_all, v_all, mask)
+        with jax.named_scope("attn"):
+            return _mm32(a, blk["wo"], cfg.act_quant)
+
+    x, stats = _run_layers(cfg, params, _embed(cfg, params, tokens), counted,
+                           mamba, attend)
+    new_cache = kv_cache
+    if kv:
+        k, v = (jnp.stack(a) for a in zip(*kv))
+        new_cache = _write(cfg, new_cache, "full", k, v, slots, pos, None)
+    if states:
+        new_cache = _write_state(new_cache, states, slots)
+    logits = _head(cfg, params, x)
+    if return_all_logits:
+        return logits, new_cache, stats
+    last = jnp.take_along_axis(
+        logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    return last, new_cache, stats
+
+
+def decode_step(cfg, params, kv_cache, tokens, positions,
+                kv_view: Optional[int] = None, mesh=None):
+    """``transformer.decode_step`` for this family: one token a row.  The
+    cache is threaded through the layers; an attention layer takes one
+    in-place row write and reads by what ``decode_attention_branch``
+    answers, a Mamba-2 layer updates its ``[rows, H, P, N]`` slice of the
+    state where it lies.  Rows parked at ``positions >= S`` write nothing,
+    leave their state as it is and count for nothing.  Returns (logits
+    [B,V], cache, stats)."""
+    from p2p_llm_tunnel_tpu.models.transformer import decode_attention_branch
+
+    b = tokens.shape[0]
+    s = kv_cache["k"].shape[2]
+    if kv_view is None or kv_view > s:
+        kv_view = s
+    quant = "k_scale" in kv_cache
+    dtype = params["embed"].dtype
+    pos2d = positions[:, None]
+    slot_ids = jnp.arange(b)
+    live = positions < s
+    kv = cfg.n_kv_heads
+    use_rows = decode_attention_branch(
+        cfg, mesh, kv_view, "int8" if quant else None, s) == "pallas-rows"
+    if use_rows:
+        from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+            decode_attention_rows,
+            decode_rows_worklist,
+            rows_block,
+        )
+
+        # One work list a step, shared by the attention layers.
+        block = rows_block(s, kv)
+        work = decode_rows_worklist(positions, s, block)
+    else:
+        mask = window_mask(
+            pos2d, jnp.broadcast_to(jnp.arange(kv_view), (b, kv_view)))
+    cache = dict(kv_cache)
+
+    def mamba(i, blk, h):
+        # (the layer's slice of each leaf in and out is the update itself:
+        # under its scopes, so that its time is the update's)
+        with jax.named_scope("ssm_conv"):
+            tail = cache["conv"][i]
+        with jax.named_scope("ssm_step"):
+            state = cache["ssm"][i]
+        out, tail, state = _mamba(cfg, blk, h, tail, state, live[:, None],
+                                  True)
+        with jax.named_scope("ssm_step"):
+            cache["ssm"] = cache["ssm"].at[i].set(state)
+        with jax.named_scope("ssm_conv"):
+            cache["conv"] = cache["conv"].at[i].set(tail)
+        return out
+
+    def attend(i, blk, h):
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(cfg, blk, h)
+        rows = []
+        for name, row in (("k", k), ("v", v)):
+            with jax.named_scope("kv_write"):
+                row, scale = _pack(row[:, 0], kv, quant)
+                where = (i, slot_ids, positions)
+                cache[name] = cache[name].at[where].set(row)
+                if quant:
+                    cache[name + "_scale"] = cache[
+                        name + "_scale"].at[where].set(scale)
+            if not use_rows:
+                with jax.named_scope("kv_read"):
+                    seen = cache[name][i, :, :kv_view]
+                    rows.append(
+                        _unpack(seen, cache[name + "_scale"][i, :, :kv_view],
+                                dtype) if quant else seen)
+        if use_rows:
+            with jax.named_scope("attn"):
+                a = decode_attention_rows(
+                    q[:, 0], cache["k"], cache["v"], jnp.int32(i), work,
+                    block=block, scale=cfg.query_scale or cfg.head_dim ** -0.5,
+                    interpret=cfg.flash_interpret).reshape(b, 1, -1)
+        else:
+            a = _attend(cfg, "full", blk, q, rows[0], rows[1], mask)
+        with jax.named_scope("attn"):
+            return _mm32(a, blk["wo"], cfg.act_quant)
+
+    x, stats = _run_layers(cfg, params, _embed(cfg, params, tokens[:, None]),
+                           live[:, None], mamba, attend)
+    return _head(cfg, params, x)[:, 0], cache, stats

@@ -222,6 +222,8 @@ def normed(cfg: ModelConfig, x, w, act):
 def _act(cfg: ModelConfig, x):
     if cfg.act == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if cfg.act == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
 
 
@@ -283,7 +285,12 @@ def _moe_total(stats):
 def _family_module(cfg: ModelConfig):
     """The module that serves a family whose cache is not one ``[L, rows, S,
     K, D]`` pair of planes (latent rows: models/mla.py; window rings beside
-    full planes: models/swa.py), or None."""
+    full planes: models/swa.py; a recurrent state a slot beside KV planes,
+    one mixer a layer: models/ssm_moe.py), or None."""
+    if cfg.mixer_pattern is not None:
+        from p2p_llm_tunnel_tpu.models import ssm_moe
+
+        return ssm_moe
     if cfg.kv_lora_rank:
         from p2p_llm_tunnel_tpu.models import mla
 

@@ -71,6 +71,27 @@ METRICS_CATALOG: Dict[str, str] = {
         "grouped expert products ran as the repo's Pallas kernel (the "
         "record's moe is not ragged-dot) (counter)"
     ),
+    "engine_state_bytes_total": (
+        "bytes of recurrent state (a family with state-space layers) read "
+        "and written: every live row's state of every such layer once in "
+        "and once out a decode step and a prefill dispatch, and each "
+        "snapshot and restore once; from the host's own counts (counter)"
+    ),
+    "engine_state_snapshots_total": (
+        "snapshots of a slot's recurrent state saved beside the prefix "
+        "pool's pages, at block boundaries (counter)"
+    ),
+    "engine_state_restores_total": (
+        "prefix hits whose recurrent state was restored from a snapshot "
+        "(counter)"
+    ),
+    "engine_state_snapshots": (
+        "snapshots of recurrent state the pool holds now (gauge)"
+    ),
+    "engine_state_snapshot_bytes": (
+        "bytes of the snapshots the pool holds now; the pages' are "
+        "engine_prefix_pool_kv_bytes (gauge)"
+    ),
     "engine_moe_assignments_total": (
         "token-to-expert assignments the routed layers made of real tokens, "
         "over every expert layer of every dispatch (counter)"

@@ -139,6 +139,15 @@ SPAN_CATALOG: Dict[str, str] = {
         "(engine-scope dispatch record; attrs seq, program, "
         "rows/rows_padded, blocks/blocks_padded)"
     ),
+    "engine.state_snapshot": (
+        "a slot's recurrent state saved beside the prefix pool's pages, at "
+        "a block boundary (instant; a family with state-space layers; attrs "
+        "slot, snapshot, boundary = the tokens it stands behind, bytes)"
+    ),
+    "engine.state_restore": (
+        "a prefix hit's recurrent state restored from a snapshot (instant; "
+        "attrs slot, snapshot, tokens_skipped, bytes)"
+    ),
     "engine.first_token": "first token accounted for the request (instant)",
     "engine.stream_end": "the request's token stream finished (instant)",
     "engine.deadline_evict": (

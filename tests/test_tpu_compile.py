@@ -705,6 +705,69 @@ def test_the_mimo_share_fits_one_chip_at_its_stated_bytes(chip, program):
 
 
 # ---------------------------------------------------------------------------
+# a recurrent state a slot beside the KV planes (ISSUE 44)
+# ---------------------------------------------------------------------------
+
+#: nemotron-3-nano-30b-a3b-ep2s at the cell's size: 128 slots + the scratch
+#: row x 4096.
+SSM_ROWS, SSM_SEQ = 129, 4096
+SSM_LEAVES = {"k": (2, SSM_ROWS, SSM_SEQ, 256), "v": (2, SSM_ROWS, SSM_SEQ, 256),
+              "ssm": (6, SSM_ROWS, 64, 64, 128),
+              "conv": (6, SSM_ROWS, 3, 6144)}
+SSM_PROGRAMS = {
+    # (the branch a TPU backend takes: the rows kernel over the attention
+    # planes, the grouped kernel over experts held 1920 wide)
+    "decode-on-the-chip": lambda T, cfg, p, c, b: T.decode_step(
+        replace(cfg, flash_force=True), p, c, b["rows"], b["rows"],
+        kv_view=SSM_SEQ, with_stats=True),
+}
+
+
+@pytest.mark.parametrize("program", sorted(SSM_PROGRAMS))
+def test_the_state_is_updated_where_it_lies_and_the_share_fits(chip, program):
+    """``nemotron-3-nano-30b-a3b-ep2s`` at the cell's shapes, as a TPU
+    backend runs it: the four leaves are the donated ones, no program makes
+    a copy of the 1.6 GB state leaf around a layer's update (decode writes
+    the layer's slice in place), the grouped products are Mosaic kernels over experts held in
+    whole lane tiles (the chip's compiler refuses a DMA of 1856 columns),
+    and weights, cache, 96 snapshots, the pool and the program's own
+    temporaries are inside a v5e's 16 GB."""
+    from p2p_llm_tunnel_tpu.models import transformer as T
+    from p2p_llm_tunnel_tpu.models.config import get_config
+    from p2p_llm_tunnel_tpu.models.ssm_moe import state_bytes_per_slot
+
+    cfg = get_config("nemotron-3-nano-30b-a3b-ep2s")
+    params, cache = _share_shapes(chip, cfg, SSM_ROWS, SSM_SEQ)
+    assert {k: v.shape for k, v in cache.items()} == SSM_LEAVES
+    batch = _on(chip, {
+        "rows": jax.ShapeDtypeStruct((SSM_ROWS,), jnp.int32)})
+    compiled = jax.jit(
+        lambda p, c, b: SSM_PROGRAMS[program](T, cfg, p, c, b),
+        donate_argnums=(1,)).lower(params, cache, batch).compile()
+    hlo = compiled.as_text()
+    dims = ",".join(str(d) for d in SSM_LEAVES["ssm"])
+    assert [ln for ln in hlo.splitlines()
+            if " copy(" in ln and f"[{dims}]" in ln] == []
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert aliased.count("alias") == len(cache)
+    assert _grouped_products(hlo, kernel=True) >= 2
+    m = compiled.memory_analysis()
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    # 3,926 M published parameters, the experts held 1920 wide for 1856
+    assert 8.06e9 < weights < 8.08e9
+    leaves = sum(math.prod(v.shape) * v.dtype.itemsize for v in cache.values())
+    assert leaves == SSM_ROWS * (2 * 1024 * SSM_SEQ
+                                 + state_bytes_per_slot(cfg))
+    snapshots = 97 * state_bytes_per_slot(cfg)
+    pool = 4096 * 16 * 2048
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes + snapshots
+            + pool)
+    assert held < 14.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
+
+
+# ---------------------------------------------------------------------------
 # generation by blocks (ISSUE 38)
 # ---------------------------------------------------------------------------
 

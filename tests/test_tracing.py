@@ -346,6 +346,9 @@ def test_healthz_trace_export_and_pool_accounting():
                     # ISSUE 16: host-RAM spill tier + the memory
                     # degradation contract's live reason.
                     "spill", "degraded_reason",
+                    # ISSUE 44: snapshots of recurrent state, counted apart
+                    # from the rows
+                    "state_snapshots", "state_bytes",
                 }
                 assert set(payload["prefix_pool"]["conversation"]) == {
                     "saved_pages_total", "hits_total", "hit_tokens_total",
