@@ -55,6 +55,7 @@ from p2p_llm_tunnel_tpu.models.transformer import (
     reads_expert_stack,
     spec_attention_branch,
 )
+from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import ELEMENTWISE
 from p2p_llm_tunnel_tpu.utils.flight import (
     global_blackbox,
     global_compile_watch,
@@ -714,14 +715,20 @@ class InferenceEngine(BlockDecodeMixin):
         self._state_keys: Tuple[str, ...] = ()
         self._state_row_bytes = 0
         self._snapshots = None
+        # Decode's update of that state in this engine's decode programs,
+        # by the model layer's own rule (``state_update_branch``); None for
+        # a model without such a state.
+        self._state_update: Optional[str] = None
         if self.mcfg.mixer_pattern is not None:
             from p2p_llm_tunnel_tpu.models.ssm_moe import (
                 STATE_KEYS,
                 state_bytes_per_slot,
+                state_update_branch,
             )
 
             self._state_keys = STATE_KEYS
             self._state_row_bytes = state_bytes_per_slot(self.mcfg, dtype)
+            self._state_update = state_update_branch(self.mcfg, self.mesh)
 
         def make_cache():
             return init_kv_cache(
@@ -2062,6 +2069,8 @@ class InferenceEngine(BlockDecodeMixin):
             # two kinds of plane (or planes and a state): what a pooled
             # token and what a slot holds are two statements
             cache = cache_section(m, self.kv_cache)
+            if self._state_keys:
+                cache["kinds"]["state"]["update"] = self._state_update
         else:
             cache = {
                 "form": "latent" if m.kv_lora_rank else "kv_heads",
@@ -3706,6 +3715,12 @@ class InferenceEngine(BlockDecodeMixin):
             self._count_kv_rows(
                 self._positions[:slots][active[:slots]], steps, rec)
             self._count_state(live * steps, rec)
+            if self._state_update is not None:
+                if self._state_update != ELEMENTWISE:
+                    global_metrics.inc(
+                        "engine_decode_state_kernel_steps_total", steps)
+                if rec is not None:
+                    rec.attrs.update(state_update=self._state_update)
         self._ov_mask[:] = False  # patch consumed by this dispatch
         # Rows must ALSO have been active at dispatch time to be accounted:
         # a chunk-prefilling slot holds its request-id long before its

@@ -25,9 +25,13 @@ state is carried by a short scan) and equals the token-by-token recurrence
 at any chunking (tests/test_ssm_moe.py); :func:`ssm_step` is one position of
 it, elementwise in float32.  A position whose ``dt`` is 0 leaves the state
 as it is, to the bit (``exp(0) S + 0``): padding and parked rows are masked
-so, by the caller.  The scan's products are asked in full float32: they are
-a hundredth of the layer's projections, and the state they make is what
-every later token reads.
+so, by the caller.  On the TPU decode's update does not run here: it is
+``ops/pallas_ssm_step.py``'s kernel over the step's live rows of the stacked
+leaf (``ssm_moe.state_update_branch``), and :func:`ssm_step` is the form
+every other backend runs and the reference that kernel is held to
+(tests/test_ssm_step_kernel.py).  The scan's products are asked in full
+float32: they are a hundredth of the layer's projections, and the state
+they make is what every later token reads.
 """
 
 from __future__ import annotations

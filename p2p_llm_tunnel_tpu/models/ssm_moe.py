@@ -52,8 +52,14 @@ Decode reads an attention layer by what
 ``transformer.decode_attention_branch`` answers: on the TPU, over plain
 bf16 planes, ``decode_attention_rows`` over the stacked planes where they
 lie; elsewhere the einsum over the layer's ``kv_view`` positions.  A Mamba-2
-layer's state is read and written where it lies: the layer's ``[rows, H, P,
-N]`` slice of the donated leaf, updated elementwise (scope ``ssm_step``).
+layer's state is updated where it lies by what :func:`state_update_branch`
+answers (scope ``ssm_step`` either way): on the TPU one kernel a layer
+(``ops/pallas_ssm_step.py``) over the step's LIVE rows of the donated leaf,
+each row's state read once and written once with the sum with ``C`` in the
+same pass, parked rows and the other layers never named; elsewhere (a CPU
+backend, a mesh, ``cfg.flash`` off, a state that is no whole tiles)
+``ssm.ssm_step`` elementwise over the layer's ``[rows, H, P, N]`` slice,
+the reference the kernel is held to.
 Int8 planes (``--kv-quant int8``) are models/swa.py's: the benchmark's cache
 control; the state has no quantised form.
 
@@ -95,6 +101,13 @@ from p2p_llm_tunnel_tpu.models.ssm import (
 from p2p_llm_tunnel_tpu.models.swa import _as_held, _attend, _pack, _unpack, _write
 from p2p_llm_tunnel_tpu.ops.attention import window_mask
 from p2p_llm_tunnel_tpu.ops.norms import rms_norm
+from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import (
+    ELEMENTWISE,
+    SSM_STEP_KERNEL,
+    live_rows_worklist,
+    shapes_decline,
+    ssm_step_rows,
+)
 
 #: The cache leaves that are a state a slot, not rows a token: the prefix
 #: pool keeps snapshots of them, never pages.
@@ -121,6 +134,41 @@ def _places(cfg: ModelConfig):
         out.append((kind, seen[kind]))
         seen[kind] += 1
     return out
+
+
+def state_update_branch(cfg: ModelConfig, mesh) -> str:
+    """Which implementation decode's one-token update of the recurrent
+    state takes: ``ops/pallas_ssm_step.py``'s kernel by its name
+    (``SSM_STEP_KERNEL``: the step's live rows of the leaf where they lie,
+    read once and written once, the sum with ``C`` in the same pass) or
+    ``"elementwise"`` (:func:`ssm.ssm_step` in XLA over the layer's slice,
+    every row of it: the reference the kernel is held to).  Answered from
+    what the code observes, no option (ISSUE 45), as
+    ``transformer.decode_attention_branch`` and
+    ``moe.grouped_product_branch`` answer:
+
+    - the backend: the kernel is the TPU's (on the CPU only under
+      ``cfg.flash_interpret``, the tests' interpret mode, or
+      ``cfg.flash_force``, their lowering-only probes); ``cfg.flash`` off
+      keeps the reference everywhere;
+    - the mesh: a ``pallas_call`` is not GSPMD-partitioned (and state
+      under ``--tp`` is refused at start-up);
+    - the static shapes: a head's ``[P, N]`` is whole registers of 8
+      sublanes, in the powers of two the kernel's butterflies take
+      (``pallas_ssm_step.shapes_decline``), and whole lane tiles of 128
+      (asked of the chip's compiler, not of the interpreter)."""
+    backend = jax.default_backend()
+    if not (cfg.flash and (backend == "tpu" or cfg.flash_interpret
+                           or cfg.flash_force)):
+        return ELEMENTWISE
+    if mesh is not None and any(n > 1 for n in dict(mesh.shape).values()):
+        return ELEMENTWISE
+    h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    if shapes_decline(h, p, n, cfg.ssm_groups) is not None:
+        return ELEMENTWISE
+    if not cfg.flash_interpret and n % 128:
+        return ELEMENTWISE
+    return SSM_STEP_KERNEL
 
 
 def state_bytes_per_slot(cfg: ModelConfig, dtype=jnp.bfloat16) -> int:
@@ -300,12 +348,13 @@ def _layer(stack, i: int, skip=()):
     return {k: a[i] for k, a in stack.items() if k not in skip}
 
 
-def _mamba(cfg: ModelConfig, blk, h, tail, state, real, decode: bool):
+def _mamba(cfg: ModelConfig, blk, h, tail, state, real, step=None):
     """One Mamba-2 mixer over ``h [B,T,Dm]`` (normed, the weights' type):
     ``tail [B,K-1,C]`` and ``state [B,H,P,N]`` before the segment, ``real
     [B,T]`` the positions that are no padding -> (out ``[B,T,Dm]``, new
-    tail, new state).  ``decode``: ``T`` is 1 and the update is
-    :func:`ssm.ssm_step`."""
+    tail, new state).  ``step``: decode's, ``T`` is 1 and the update is
+    ``step(x, dt, a, bm, cm) -> y`` of :func:`ssm.ssm_step`'s operands,
+    which holds the state itself (``state`` is None, in and out)."""
     b, t, _ = h.shape
     inner, heads, p = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_head_dim
     g, n = cfg.ssm_groups, cfg.ssm_state
@@ -325,11 +374,9 @@ def _mamba(cfg: ModelConfig, blk, h, tail, state, real, decode: bool):
     bm = xbc[..., inner:inner + g * n].reshape(b, t, g, n)
     cm = xbc[..., inner + g * n:].reshape(b, t, g, n)
     a = -jnp.exp(blk["a_log"])
-    if decode:
+    if step is not None:
         with jax.named_scope("ssm_step"):
-            y, state = ssm_step(x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
-                                state)
-            y = y[:, None]
+            y = step(x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])[:, None]
     else:
         with jax.named_scope("ssm_scan"):
             y, new = ssm_scan(x, dt, a, bm, cm, state, cfg.ssm_chunk)
@@ -413,8 +460,7 @@ def prefill(cfg: ModelConfig, params, tokens, valid, counted=None):
         (b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
 
     def mamba(i, blk, h):
-        out, tail, state = _mamba(cfg, blk, h, zero_tail, zero_state, valid,
-                                  False)
+        out, tail, state = _mamba(cfg, blk, h, zero_tail, zero_state, valid)
         states.append((state, tail))
         return out
 
@@ -537,7 +583,7 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
                               _rows_of(kv_cache["ssm"], i, slots), 0)
             tail = jnp.where(carried[:, None, None],
                              _rows_of(kv_cache["conv"], i, slots), 0)
-        out, tail, state = _mamba(cfg, blk, h, tail, state, valid, False)
+        out, tail, state = _mamba(cfg, blk, h, tail, state, valid)
         states.append((state, tail))
         return out
 
@@ -575,10 +621,12 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
     """``transformer.decode_step`` for this family: one token a row.  The
     cache is threaded through the layers; an attention layer takes one
     in-place row write and reads by what ``decode_attention_branch``
-    answers, a Mamba-2 layer updates its ``[rows, H, P, N]`` slice of the
-    state where it lies.  Rows parked at ``positions >= S`` write nothing,
-    leave their state as it is and count for nothing.  Returns (logits
-    [B,V], cache, stats)."""
+    answers, a Mamba-2 layer updates its part of the state leaf where it
+    lies by what :func:`state_update_branch` answers (the kernel over the
+    live rows, or ``ssm.ssm_step`` over the layer's slice).  Rows parked at
+    ``positions >= S`` write nothing, leave their state as it is (to the
+    bit: ``dt`` 0, or never visited) and count for nothing.  Returns
+    (logits [B,V], cache, stats)."""
     from p2p_llm_tunnel_tpu.models.transformer import decode_attention_branch
 
     b = tokens.shape[0]
@@ -606,19 +654,29 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
     else:
         mask = window_mask(
             pos2d, jnp.broadcast_to(jnp.arange(kv_view), (b, kv_view)))
+    state_rows = None
+    if state_update_branch(cfg, mesh) == SSM_STEP_KERNEL:
+        # One list of the step's live rows, shared by the Mamba-2 layers.
+        with jax.named_scope("ssm_step"):
+            state_rows = live_rows_worklist(positions, s)
     cache = dict(kv_cache)
 
     def mamba(i, blk, h):
-        # (the layer's slice of each leaf in and out is the update itself:
+        # (the layer's part of each leaf in and out is the update itself:
         # under its scopes, so that its time is the update's)
+        def step(*operands):
+            if state_rows is not None:
+                y, cache["ssm"] = ssm_step_rows(
+                    cache["ssm"], i, state_rows, *operands,
+                    interpret=cfg.flash_interpret)
+                return y
+            y, state = ssm_step(*operands, cache["ssm"][i])
+            cache["ssm"] = cache["ssm"].at[i].set(state)
+            return y
+
         with jax.named_scope("ssm_conv"):
             tail = cache["conv"][i]
-        with jax.named_scope("ssm_step"):
-            state = cache["ssm"][i]
-        out, tail, state = _mamba(cfg, blk, h, tail, state, live[:, None],
-                                  True)
-        with jax.named_scope("ssm_step"):
-            cache["ssm"] = cache["ssm"].at[i].set(state)
+        out, tail, _ = _mamba(cfg, blk, h, tail, None, live[:, None], step)
         with jax.named_scope("ssm_conv"):
             cache["conv"] = cache["conv"].at[i].set(tail)
         return out

@@ -38,6 +38,13 @@ METRICS_CATALOG: Dict[str, str] = {
         "full layers' attention); over engine_decode_steps_total it is the "
         "share of decode the kernel engages in (counter)"
     ),
+    "engine_decode_state_kernel_steps_total": (
+        "of the decode steps of a model with a recurrent state a slot, "
+        "those whose state updates ran as the Pallas kernel over the "
+        "step's live rows (the record's state_update is not elementwise); "
+        "over engine_decode_steps_total it is the share of decode that "
+        "kernel engages in (counter)"
+    ),
     "engine_decode_row_steps_total": (
         "live rows x steps over every decode burst dispatched (counter)"
     ),

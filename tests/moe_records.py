@@ -7,6 +7,7 @@ held to ``attn`` in tests/test_decode_rows.py."""
 
 import asyncio
 import contextlib
+import time
 
 from p2p_llm_tunnel_tpu.models.moe import RAGGED
 from p2p_llm_tunnel_tpu.ops.pallas_grouped_matmul import GROUPED_KERNEL
@@ -14,6 +15,42 @@ from p2p_llm_tunnel_tpu.utils.metrics import METRICS_CATALOG, global_metrics
 from p2p_llm_tunnel_tpu.utils.tracing import global_tracer
 
 COUNTER = "engine_moe_kernel_dispatches_total"
+#: The engine-scope records a dispatch closes as; each carries its ``seq``.
+DISPATCHES = ("engine.decode_burst", "engine.prefill_segment",
+              "engine.pool_copy")
+
+
+def _counters():
+    return {k: v for k, v in global_metrics.snapshot().items()
+            if k.startswith("engine_") and k.endswith("_total")}
+
+
+async def dispatches_closed(eng, timeout: float = 10.0) -> None:
+    """Wait until ``eng``'s last dispatches are closed: the last decode
+    burst's record closes, and its routed layers' counts reach the
+    counters, an iteration of the engine's loop AFTER its tokens were handed
+    out.  (A fixed ``sleep(0.3)`` stood here and lost that race under six
+    test workers: ISSUE 45.)  With the journal on: until it holds a closed
+    record for every dispatch opened since the first it holds, up to the
+    last one ``eng`` opened.  With it off: until the engine's counters have
+    stood still for three looks.  ``timeout`` seconds at most."""
+    deadline = time.monotonic() + timeout
+    before, still = None, 0
+    while time.monotonic() < deadline:
+        last = eng._last_dispatch if global_tracer.enabled else None
+        if last is not None:
+            closed = {r.attrs.get("seq") for r in global_tracer.records()
+                      if r.name in DISPATCHES}
+            if closed and closed >= set(
+                    range(min(closed), last.attrs["seq"] + 1)):
+                return
+        else:
+            now = _counters()
+            still = still + 1 if now == before else 0
+            if still == 3:
+                return
+            before = now
+        await asyncio.sleep(0.02)
 
 
 @contextlib.contextmanager
@@ -36,7 +73,7 @@ def run_traced(eng, prompt, new):
             before = global_metrics.counter(COUNTER)
             toks = [ev.token_id async for ev in eng.generate(
                 prompt, max_new_tokens=new, stop_ids=())]
-            await asyncio.sleep(0.3)  # the last burst's record closes
+            await dispatches_closed(eng)
             return toks, global_metrics.counter(COUNTER) - before
         finally:
             await eng.stop()

@@ -30,6 +30,7 @@ from p2p_llm_tunnel_tpu.models.transformer import (
     prefill_attention_branch,
 )
 from tests import block_diffusion_plain as plain
+from tests.moe_records import dispatches_closed
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BLOCK, GROUP = 4, 2
@@ -177,7 +178,7 @@ def _generate(eng, jobs, together=False, tops=3):
                     one(*job, wait=0.05 * i) for i, job in enumerate(jobs)))
             else:
                 out = [await one(*job) for job in jobs]
-            await asyncio.sleep(0.3)  # the last burst's record closes
+            await dispatches_closed(eng)
             return out
         finally:
             await eng.stop()

@@ -26,6 +26,7 @@ COUNTERS = (
     "engine_decode_steps_total", "engine_decode_row_steps_total",
     "engine_decode_slot_steps_total", "engine_tokens_total",
     "engine_decode_kernel_steps_total",
+    "engine_decode_state_kernel_steps_total",
 )
 CHUNK = 16
 SLOTS = 4
@@ -167,6 +168,9 @@ def test_decode_records_and_counters_agree_exactly(traced):
     assert sum(r.attrs["steps"] for r in bursts
                if r.attrs["attn"] != "einsum") == \
         grown["engine_decode_kernel_steps_total"] == 0
+    # (a model without a recurrent state names no branch of its update)
+    assert all("state_update" not in r.attrs for r in bursts)
+    assert grown["engine_decode_state_kernel_steps_total"] == 0
     # every emitted token but a request's first came out of a live row-step
     assert grown["engine_tokens_total"] == sum(emitted)
     assert row_steps >= grown["engine_tokens_total"] - len(emitted)

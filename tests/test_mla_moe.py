@@ -30,6 +30,7 @@ from p2p_llm_tunnel_tpu.models.transformer import (
     prefill_into_cache,
 )
 from tests import mla_moe_plain as plain
+from tests.moe_records import dispatches_closed
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROWS, MAX_SEQ = 4, 64
@@ -360,7 +361,7 @@ def test_the_counters_and_the_ledger_carry_the_counts():
             before = [global_metrics.counter(n) for n in names]
             toks = [ev.token_id async for ev in eng.generate(
                 prompt, max_new_tokens=5, stop_ids=())]
-            await asyncio.sleep(0.3)  # the last burst's record closes
+            await dispatches_closed(eng)
             grew = [global_metrics.counter(n) - b
                     for n, b in zip(names, before)]
         finally:

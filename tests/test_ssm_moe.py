@@ -14,6 +14,7 @@ from __future__ import annotations
 import asyncio
 import json
 import os
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +31,9 @@ from p2p_llm_tunnel_tpu.models.transformer import (
     prefill,
     prefill_into_cache,
 )
+from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import ELEMENTWISE, SSM_STEP_KERNEL
 from tests import ssm_moe_plain as plain
+from tests.moe_records import dispatches_closed
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROWS, MAX_SEQ = 4, 128
@@ -222,12 +225,28 @@ def test_whole_prompt_prefill_matches_the_reference(model):
     assert rows["state"][0].shape == (3, 1, 4, 8, 16)
 
 
+#: Decode's state update as ``ssm.ssm_step`` in XLA, and as the kernel over
+#: the live rows (interpreted; the decode program's attention and grouped
+#: products are their kernels then too): ISSUE 45.
+UPDATES = {"elementwise": {}, "kernel": {"flash_interpret": True}}
+
+
+def _decoding(cfg, update):
+    cfg = replace(cfg, **UPDATES[update])
+    assert ssm_moe.state_update_branch(cfg, None) == (
+        SSM_STEP_KERNEL if update == "kernel" else ELEMENTWISE)
+    return cfg
+
+
+@pytest.mark.parametrize("update", sorted(UPDATES))
 @pytest.mark.parametrize("cuts", [[(0, 43)], [(0, 16), (16, 27), (27, 43)]],
                          ids=["whole", "uneven-segments"])
-def test_prefill_then_64_decode_steps_through_state_and_cache(model, cuts):
+def test_prefill_then_64_decode_steps_through_state_and_cache(model, cuts,
+                                                              update):
     """The prompt whole (``prefill_into_cache``) or as chunk-prefill
-    segments of uneven lengths beside a padding row, then 64 decode steps,
-    against ONE full forward of the plain reference."""
+    segments of uneven lengths beside a padding row, then 64 decode steps
+    (the state updated by either branch), against ONE full forward of the
+    plain reference."""
     cfg, params = model
     full = _prompt(3, 43) + _prompt(4, 64)
     want = np.asarray(plain.forward_logprobs(cfg, params, full))
@@ -244,11 +263,13 @@ def test_prefill_then_64_decode_steps_through_state_and_cache(model, cuts):
         np.testing.assert_allclose(_logprobs(logits[0, :b - a]), want[a:b],
                                    atol=ATOL)
     for p in range(43, 107):
-        logits, cache = _decode(cfg, params, cache, 1, full[p], p)
+        logits, cache = _decode(_decoding(cfg, update), params, cache, 1,
+                                full[p], p)
         np.testing.assert_allclose(_logprobs(logits), want[p], atol=ATOL)
 
 
-def test_two_rows_of_different_lengths_equal_each_alone(model):
+@pytest.mark.parametrize("update", sorted(UPDATES))
+def test_two_rows_of_different_lengths_equal_each_alone(model, update):
     """One dispatch carries a row of 16 and a row of 5 (padded to 16): each
     reads as it does alone, and the padded positions leave state and
     convolution tail untouched: both rows then continue from them."""
@@ -268,7 +289,8 @@ def test_two_rows_of_different_lengths_equal_each_alone(model):
     np.testing.assert_allclose(_logprobs(logits[1]), want_s[5:21], atol=ATOL)
     # a decode step of one row leaves the parked rows' state as it is
     before = {k: np.asarray(cache[k][:, 2]) for k in ssm_moe.STATE_KEYS}
-    _, cache = _decode(cfg, params, cache, 0, long[32], 32)
+    _, cache = _decode(_decoding(cfg, update), params, cache, 0, long[32],
+                       32)
     for k in ssm_moe.STATE_KEYS:
         np.testing.assert_array_equal(np.asarray(cache[k][:, 2]), before[k])
 
@@ -388,7 +410,7 @@ def _generate(eng, prompts, new=10, between=None):
                             [ev.logprob for ev in events]))
                 if between is not None:
                     between()
-            await asyncio.sleep(0.3)  # the last burst's record closes
+            await dispatches_closed(eng)
             return out
         finally:
             await eng.stop()
@@ -575,6 +597,43 @@ def test_the_records_moe_and_the_kernel_counter_are_held_to_each_other(
     assert toks == plain_toks
 
 
+@pytest.mark.parametrize("update", sorted(UPDATES))
+def test_the_state_kernels_counter_the_records_and_healthz_name_one_branch(
+        update):
+    """``engine_decode_state_kernel_steps_total`` grows by the steps of the
+    bursts whose program took the state kernel and by none under the
+    elementwise branch; every burst's record and /healthz name that branch;
+    a parked slot's state is the same to the bit after the bursts."""
+    from p2p_llm_tunnel_tpu.utils.metrics import METRICS_CATALOG, global_metrics
+    from tests.moe_records import tracing
+
+    name = "engine_decode_state_kernel_steps_total"
+    assert name in METRICS_CATALOG
+    want = SSM_STEP_KERNEL if update == "kernel" else ELEMENTWISE
+    with tracing() as tracer:
+        eng = _engine(model_cfg=get_config(
+            "tiny-ssm-moe-ep2s", vocab_size=259, **UPDATES[update]))
+        # slot 1 is never admitted: what lies there is a parked row's
+        eng.kv_cache["ssm"] = eng.kv_cache["ssm"].at[:, 1].set(0.25)
+        before = global_metrics.counter(name)
+        steps = global_metrics.counter("engine_decode_steps_total")
+        _generate(eng, [_prompt(9, 21)], new=7)
+        grew = global_metrics.counter(name) - before
+        steps = global_metrics.counter("engine_decode_steps_total") - steps
+        bursts = [r for r in tracer.records()
+                  if r.name == "engine.decode_burst"]
+    assert bursts and steps == sum(r.attrs["steps"] for r in bursts)
+    assert {r.attrs["state_update"] for r in bursts} == {want}
+    assert grew == (steps if update == "kernel" else 0)
+    state = eng._model_section()["cache"]["kinds"]["state"]
+    assert state["update"] == want == eng._state_update
+    np.testing.assert_array_equal(np.asarray(eng.kv_cache["ssm"][:, 1]), 0.25)
+    assert float(jnp.abs(eng.kv_cache["ssm"][:, 0]).max()) > 0
+    # a model without such a state has no branch, no attr and no count
+    dense = _engine("tiny")
+    assert dense._state_update is None
+
+
 REFUSED = {
     "quant-int8": dict(quant="int8"),
     "quant-int4": dict(quant="int4"),
@@ -612,7 +671,8 @@ def test_healthz_names_the_planes_the_state_and_a_slots_bytes():
     assert cache["kinds"]["state"] == {
         "layers": 3, "heads": 4, "head_width": 8, "state_width": 16,
         "type": "float32", "conv_positions": 3, "conv_width": 96,
-        "conv_type": "float32", "bytes_per_slot": per_slot}
+        "conv_type": "float32", "bytes_per_slot": per_slot,
+        "update": ELEMENTWISE}
     # two statements: what the pool holds for a token, what a slot holds
     assert cache["bytes_per_token"] == 2 * 64 * 4
     assert cache["bytes_per_slot"] == 2 * 64 * 4 * 128 + per_slot

@@ -36,6 +36,7 @@ from p2p_llm_tunnel_tpu.ops.attention import (
     window_mask,
 )
 from tests import swa_moe_plain as plain
+from tests.moe_records import dispatches_closed
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROWS, MAX_SEQ, RING, WINDOW = 4, 96, 16, 8
@@ -356,7 +357,7 @@ def _generate(eng, prompts, new=10):
                     prompt, max_new_tokens=new, logprobs=1, stop_ids=())]
                 out.append(([ev.token_id for ev in events],
                             [ev.logprob for ev in events]))
-            await asyncio.sleep(0.3)  # the last burst's record closes
+            await dispatches_closed(eng)
             return out
         finally:
             await eng.stop()

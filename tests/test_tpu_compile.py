@@ -720,16 +720,35 @@ SSM_PROGRAMS = {
     "decode-on-the-chip": lambda T, cfg, p, c, b: T.decode_step(
         replace(cfg, flash_force=True), p, c, b["rows"], b["rows"],
         kv_view=SSM_SEQ, with_stats=True),
+    # (the same as the engine's burst holds it: the cache a carry of a scan
+    # over the steps, the state kernel's aliased leaf inside the loop)
+    "burst-on-the-chip": lambda T, cfg, p, c, b: _ssm_burst(
+        T, replace(cfg, flash_force=True), p, c, b["rows"], b["rows"]),
 }
+
+
+def _ssm_burst(T, cfg, params, cache, tokens, positions, steps=4):
+    def one(carry, _):
+        tok, pos, cache = carry
+        logits, cache, stats = T.decode_step(
+            cfg, params, cache, tok, pos, kv_view=SSM_SEQ, with_stats=True)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (tok, pos + 1, cache), (tok, stats)
+
+    (_, _, cache), (toks, stats) = jax.lax.scan(
+        one, (tokens, positions, cache), None, length=steps)
+    return toks, cache, stats.sum(axis=0)
 
 
 @pytest.mark.parametrize("program", sorted(SSM_PROGRAMS))
 def test_the_state_is_updated_where_it_lies_and_the_share_fits(chip, program):
     """``nemotron-3-nano-30b-a3b-ep2s`` at the cell's shapes, as a TPU
     backend runs it: the four leaves are the donated ones, no program makes
-    a copy of the 1.6 GB state leaf around a layer's update (decode writes
-    the layer's slice in place), the grouped products are Mosaic kernels over experts held in
-    whole lane tiles (the chip's compiler refuses a DMA of 1856 columns),
+    a copy of the 1.6 GB state leaf around a layer's update (ISSUE 45: the
+    update is the kernel ``ssm_step_rows`` over the live rows of the leaf,
+    aliased in and out, six calls a step, in a scan over the steps too),
+    the grouped products are Mosaic kernels over experts held in whole lane
+    tiles (the chip's compiler refuses a DMA of 1856 columns),
     and weights, cache, 96 snapshots, the pool and the program's own
     temporaries are inside a v5e's 16 GB."""
     from p2p_llm_tunnel_tpu.models import transformer as T
@@ -751,6 +770,11 @@ def test_the_state_is_updated_where_it_lies_and_the_share_fits(chip, program):
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
     assert aliased.count("alias") == len(cache)
     assert _grouped_products(hlo, kernel=True) >= 2
+    from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import SSM_STEP_KERNEL
+
+    calls = [ln for ln in hlo.splitlines()
+             if "custom-call(" in ln and f"%{SSM_STEP_KERNEL}" in ln]
+    assert len(calls) == 6 and all(f"f32[{dims}]" in ln for ln in calls)
     m = compiled.memory_analysis()
     weights = sum(math.prod(x.shape) * x.dtype.itemsize
                   for x in jax.tree.leaves(params))
