@@ -22,7 +22,7 @@ import os
 import threading
 import time
 from dataclasses import dataclass, replace as dc_replace
-from collections import deque
+from collections import Counter, deque
 from typing import AsyncIterator, Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -464,6 +464,27 @@ class _Dispatch:
 
 #: What a dispatch call is wrapped in while the span journal is off.
 _NO_ANNOTATION = contextlib.nullcontext()
+
+
+#: What the snapshots of recurrent state kept beside the prefix pool's pages
+#: may take in all.  Their count follows the pool's tokens (one every
+#: ``--prefill-chunk`` of them); that rule was written for a state of 12.8
+#: MB a slot (96 snapshots beside 3,072 blocks: 1.23 GB, under this cap).
+#: At 76 MB a slot a snapshot costs what 9,300 tokens of pages cost and the
+#: rule alone hands the snapshots 19 x the pages' bytes at ANY pool size: a
+#: pool that does not trip the thrash detector (2,048 blocks) would ask 4.9
+#: GB of them.  With the cap the pages are sized by the traffic and the
+#: snapshots by their bytes; pages beyond the newest snapshots' reach match
+#: no further than the last boundary that has one.
+#: **Provisional**: the one split both served state sizes fit in.  Nothing
+#: the engine knows gives it: the pages' bytes would leave the smaller
+#: state 7 snapshots of its 96, the device's free memory is not the
+#: snapshots' alone (the programs' temporaries come after), and no cell
+#: restores a snapshot yet, so none can judge a split.  The cell that shares
+#: prefixes sets it, or replaces it with one stated budget for pages and
+#: snapshots (ROADMAP Reach 9 h); no option until then (PR 44's review
+#: removed ``--state-snapshots``).
+STATE_SNAPSHOT_BYTES = 5 << 28
 
 
 class _CountsFirst:
@@ -1031,9 +1052,13 @@ class InferenceEngine(BlockDecodeMixin):
                 # scratch): pages match no further than a boundary that
                 # has one, and one is taken every ``prefill_chunk`` tokens
                 # of a segmented prompt (_state_snapshot), so the pool's
-                # tokens over that spacing; plain LRU.
-                n_snap = 1 + max(1, self.ecfg.prefix_pool_blocks * blk // (
-                    self.ecfg.prefill_chunk or self.ecfg.min_prefill_bucket))
+                # tokens over that spacing; never more than
+                # STATE_SNAPSHOT_BYTES of them; plain LRU.
+                n_snap = 1 + max(1, min(
+                    self.ecfg.prefix_pool_blocks * blk // (
+                        self.ecfg.prefill_chunk
+                        or self.ecfg.min_prefill_bucket),
+                    STATE_SNAPSHOT_BYTES // self._state_row_bytes))
                 self._snapshots = StateSnapshots(n_snap)
                 self._prefix.snapshots = self._snapshots
                 self._snap_pool = {
@@ -1734,6 +1759,7 @@ class InferenceEngine(BlockDecodeMixin):
                     # don't have to handle the crash a second time.
                     pass
                 self._task = None
+            self._drain_moe(all_of_it=True)
             # Persist warm prompt KV before the executor goes away (reads the
             # pool device arrays; must happen while XLA dispatch still works).
             self.save_prefix_snapshot()
@@ -1995,7 +2021,9 @@ class InferenceEngine(BlockDecodeMixin):
         options, the ragged prefill and the speculative verify read one
         plane of KV heads whose keys and values are equally wide."""
         if self.mcfg.mixer_pattern is not None:
-            what = "a recurrent state beside the KV planes, routed experts"
+            what = ("a recurrent state beside the KV planes, "
+                    + ("routed experts" if self.mcfg.n_experts
+                       else "a dense MLP a layer"))
         elif self.mcfg.kv_lora_rank:
             what = "latent attention, routed experts"
         elif self.mcfg.attn_pattern is not None:
@@ -2070,7 +2098,15 @@ class InferenceEngine(BlockDecodeMixin):
             # token and what a slot holds are two statements
             cache = cache_section(m, self.kv_cache)
             if self._state_keys:
-                cache["kinds"]["state"]["update"] = self._state_update
+                state = cache["kinds"]["state"]
+                state["update"] = self._state_update
+                if self._snapshots is not None:
+                    # (slot 0 of the snapshot arrays is padding's scratch)
+                    room = self._snapshots.capacity - 1
+                    state["snapshots"] = {
+                        "room": room, "held": len(self._snapshots),
+                        "bytes_each": self._state_row_bytes,
+                        "bytes": room * self._state_row_bytes}
         else:
             cache = {
                 "form": "latent" if m.kv_lora_rank else "kv_heads",
@@ -2090,9 +2126,24 @@ class InferenceEngine(BlockDecodeMixin):
                 "remasking": "sequential",
                 "mask_token_id": m.mask_token_id,
             }}
+        layer = {}
+        if m.mixer_pattern is not None:
+            # what a pattern layer is made of, and the published multipliers
+            # the programs apply (each 1 where the model states none)
+            layer = {
+                "layer": {"mixers": dict(Counter(m.mixer_kinds)),
+                          "mlp_width": m.ffn_dim if m.mixer_mlp else 0},
+                "multipliers": {
+                    "embedding": m.embed_multiplier,
+                    "residual": m.residual_multiplier,
+                    "attention_scores": m.query_scale or m.head_dim ** -0.5,
+                    "logits_divisor": m.logits_divisor},
+                "head": "the embedding" if m.tie_embeddings else "its own",
+            }
         return {
             "name": m.name,
             **generation,
+            **layer,
             "cache": cache,
             "layers": {"held": m.n_layers,
                        "of": m.published_layers or m.n_layers},
@@ -3089,11 +3140,29 @@ class InferenceEngine(BlockDecodeMixin):
         self._moe_pending.append(
             (self._last_dispatch if global_tracer.enabled else None, counts))
 
-    def _drain_moe(self) -> None:
+    def _drain_moe(self, upto: Optional[_Dispatch] = None,
+                   all_of_it: bool = False) -> None:
         """Publish the counts of every dispatch whose outputs are on the
-        host (called where a dispatch's tokens have just been fetched: its
-        counts came with them, so nothing here waits for the device)."""
-        while self._moe_pending and self._moe_pending[0][1].is_ready():
+        host.  ``upto``: the dispatch whose tokens have just been fetched
+        and whose record is about to close.  Its counts left the device
+        with them, and those of every dispatch queued before it earlier, so
+        they are taken whether or not each array's own ready flag is up
+        yet: an output's flag can trail its program's other outputs by a
+        moment, and a record that closed in that moment closed without its
+        counts, the counters a dispatch behind (under six test workers:
+        ISSUE 46).  ``all_of_it``: the engine is idle or stopping, nothing
+        is in flight, and /metrics must not lag the last dispatch."""
+        must = len(self._moe_pending) if all_of_it else 0
+        if upto is not None and not all_of_it:
+            # (a copy: the executor thread appends the next dispatch's
+            # while this one's record closes)
+            for n, (rec, _counts) in enumerate(list(self._moe_pending)):
+                if rec is upto:
+                    must = n + 1
+                    break
+        while self._moe_pending and (
+                must > 0 or self._moe_pending[0][1].is_ready()):
+            must -= 1
             rec, counts = self._moe_pending.popleft()
             made, held, fullest, touched = (int(n) for n in np.asarray(counts))
             global_metrics.inc("engine_moe_assignments_total", made)
@@ -3179,7 +3248,7 @@ class InferenceEngine(BlockDecodeMixin):
         """The sampled block of a prefill dispatch is on the host: its
         engine-scope record, and over the same interval one
         ``engine.prefill_part`` for each traced request with rows in it."""
-        self._drain_moe()
+        self._drain_moe(rec)
         if rec is None:
             return
         t1 = time.monotonic()
@@ -4793,7 +4862,7 @@ class InferenceEngine(BlockDecodeMixin):
         dispatches before burst n is fetched) — the Chrome view shows the
         pipelining directly.  ``rec`` is None when the burst was dispatched
         with tracing off."""
-        self._drain_moe()
+        self._drain_moe(rec)
         if rec is None:
             return
         if self._block:
@@ -5615,6 +5684,7 @@ class InferenceEngine(BlockDecodeMixin):
                     # iterations that did work, so its tail is dense with
                     # decisions when a postmortem reads it.
                     global_flight.set_phase("idle")
+                    self._drain_moe(all_of_it=True)
                     self._last_progress = time.monotonic()
                     self._wake.clear()
                     try:

@@ -182,8 +182,9 @@ class ModelConfig:
     # One mixer a layer by a pattern given as data (models/ssm_moe.py):
     # ``mixer_pattern[l]`` is ``M`` (a Mamba-2 state-space mixer), ``E``
     # (routed experts) or ``*`` (attention), each alone under one norm and
-    # one residual; ``n_layers`` counts them all and no position is
-    # encoded.  An ``M`` layer has ``ssm_heads`` heads of ``ssm_head_dim``
+    # one residual (or followed by an MLP: ``mixer_mlp``, below);
+    # ``n_layers`` counts them all and no position is encoded.  An ``M``
+    # layer has ``ssm_heads`` heads of ``ssm_head_dim``
     # (its inner width their product), ``ssm_groups`` groups that share B
     # and C of ``ssm_state`` values, a causal depthwise convolution over
     # ``ssm_conv`` positions, and a chunked scan of ``ssm_chunk`` positions
@@ -200,6 +201,19 @@ class ModelConfig:
     ssm_dt_min: float = 0.001
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
+    # A pattern layer that is a mixer AND a feed-forward (models/ssm_moe.py):
+    # under ``mixer_mlp`` every layer carries, after its mixer, a dense gated
+    # MLP of ``ffn_dim`` (``W_out(act(a) * b)``, ``[a | b] = u W_in``) under
+    # a norm and a residual of its own.  Four published multipliers, each
+    # the identity where it is left alone: the embedding's rows are scaled
+    # by ``embed_multiplier``, what a mixer or an MLP adds to the residual
+    # stream by ``residual_multiplier``, the logits divided by
+    # ``logits_divisor``; the attention scores' scale is ``query_scale``
+    # (above), which such a model states instead of ``head_dim ** -0.5``.
+    mixer_mlp: bool = False
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_divisor: float = 1.0
     # The routed layer's experts: gated (three products, ``act(gate) * up``
     # then down) or not (two: ``act(up)`` then down); the shared experts'
     # width where it is not the routed experts' (0: ``moe_ffn_dim``).
@@ -828,6 +842,84 @@ def tiny_ssm_moe(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+#: layer_types of granite-4.0-h-micro: attention at 5, 15, 25 and 35.
+_GRANITE_H_PATTERN = "MMMMM*" + "MMMMMMMMM*" * 3 + "MMMM"
+
+
+def granite_4_0_h_micro() -> ModelConfig:
+    """granite-4.0-h-micro as published (huggingface.co/ibm-granite/
+    granite-4.0-h-micro config.json, ``model_type`` ``granitemoehybrid``),
+    whole: 40 layers, each a mixer and then a dense gated MLP of 8192 under
+    its own norm and residual: 36 Mamba-2 (64 heads of 64, ONE group, state
+    128, conv 4, chunks of 256) and 4 of attention (32 query / 8 KV heads
+    of 64, no position encoded, scores scaled by 0.015625); the embedding
+    times 12, each residual branch times 0.22, the logits over 8, the head
+    the embedding.  3,191 M parameters, 6.38 GB in bfloat16: one chip holds
+    it."""
+    return ModelConfig(
+        name="granite-4.0-h-micro",
+        vocab_size=100352,
+        dim=2048,
+        n_layers=40,
+        n_heads=32,
+        n_kv_heads=8,
+        head_dim=64,
+        ffn_dim=8192,
+        norm_eps=1e-5,
+        act="silu",
+        tie_embeddings=True,
+        query_scale=0.015625,
+        v_head_dim=64,
+        mixer_pattern=_GRANITE_H_PATTERN,
+        ssm_heads=64,
+        ssm_head_dim=64,
+        ssm_groups=1,
+        ssm_state=128,
+        ssm_conv=4,
+        ssm_chunk=256,
+        mixer_mlp=True,
+        embed_multiplier=12.0,
+        residual_multiplier=0.22,
+        logits_divisor=8.0,
+        residual_f32=True,
+    )
+
+
+def tiny_ssm_mlp(vocab_size: int = 512) -> ModelConfig:
+    """CPU-testable granite-4.0-h-style config: ``MM*M`` (3 Mamba-2 of 4
+    heads of 8, ONE group, state 16, scan chunks of 8; 1 of attention, 4
+    query heads on 2 KV heads of 16 at a score scale that is not ``16 **
+    -0.5``), a gated MLP of 96 after every mixer, multipliers that are not
+    1, the head tied to the embedding."""
+    return ModelConfig(
+        name="tiny-ssm-mlp",
+        vocab_size=vocab_size,
+        dim=64,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=16,
+        ffn_dim=96,
+        norm_eps=1e-5,
+        act="silu",
+        tie_embeddings=True,
+        query_scale=0.125,
+        v_head_dim=16,
+        mixer_pattern="MM*M",
+        ssm_heads=4,
+        ssm_head_dim=8,
+        ssm_groups=1,
+        ssm_state=16,
+        ssm_conv=4,
+        ssm_chunk=8,
+        mixer_mlp=True,
+        embed_multiplier=6.0,
+        residual_multiplier=0.4,
+        logits_divisor=2.0,
+        residual_f32=True,
+    )
+
+
 def tiny_ssm_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
     """tiny-ssm-moe as one of 2 chips that share each layer: experts 0-3
     and ``vocab_size`` rows of a table twice as long."""
@@ -846,6 +938,8 @@ PRESETS = {
     "tiny": tiny,
     "tiny-ssm-moe": tiny_ssm_moe,
     "tiny-ssm-moe-ep2s": tiny_ssm_moe_ep2s,
+    "tiny-ssm-mlp": tiny_ssm_mlp,
+    "granite-4.0-h-micro": granite_4_0_h_micro,
     "nemotron-3-nano-30b-a3b": nemotron_3_nano_30b_a3b,
     "nemotron-3-nano-30b-a3b-ep2s": nemotron_3_nano_30b_a3b_ep2s,
     "tiny-sdar-moe": tiny_sdar_moe,

@@ -1,6 +1,8 @@
 """One mixer a layer by a pattern: Mamba-2 state-space layers, routed experts
 and attention, each alone under one norm and one residual
-(``NVIDIA-Nemotron-3-Nano-30B-A3B``, ``model_type`` ``nemotron_h``).
+(``NVIDIA-Nemotron-3-Nano-30B-A3B``, ``model_type`` ``nemotron_h``), or each
+followed by a dense gated MLP under a norm and a residual of its own
+(``granite-4.0-h-micro``, ``model_type`` ``granitemoehybrid``).
 
 ``models/transformer.py`` hands its entry points here when
 ``cfg.mixer_pattern`` is set, so the engine, the prefix pool and the tunnel
@@ -11,11 +13,22 @@ Layers.  ``x <- x + mixer(RMSNorm(x))``, the mixer by the layer's letter in
 experts of two products (``down(relu(up u)^2)``) with a shared expert of its
 own width (models/moe.py), ``*`` attention (``cfg.n_heads`` query heads on
 ``cfg.n_kv_heads`` KV heads, causal, no rotary: no position is encoded
-anywhere).  The kinds differ in shape, so each kind's weights are stacked by
+anywhere; scores scaled by ``cfg.query_scale`` where the model states one).
+The kinds differ in shape, so each kind's weights are stacked by
 themselves (``mamba``, ``attn``, ``blocks`` = the routed layers) and the
 layers are written out in order, each taking its static slice; where the
 grouped products are the kernel's, the routed layers read the stacked
 experts where they lie (``moe_mlp(stacked=...)``).
+
+Under ``cfg.mixer_mlp`` a layer is two steps, ``x <- x + r mixer(RMSNorm(
+x))`` and then ``x <- x + r W_out(act(a) * b)`` with ``[a | b] = RMSNorm'(x)
+W_in`` (``mlp``: one stack over ALL layers, ``cfg.ffn_dim`` wide, scope
+``ffn``), ``r = cfg.residual_multiplier`` on both; the embedding's rows are
+scaled by ``cfg.embed_multiplier``, the logits divided by
+``cfg.logits_divisor``, and under ``cfg.tie_embeddings`` the head is the
+embedding (no ``lm_head`` leaf).  Each is the identity at its default, and
+a model that sets none of them lowers to the program it lowered to before
+they existed.
 
 **The cache is KV planes and a state a slot**, one dict under one allocator:
 
@@ -78,12 +91,8 @@ import jax
 import jax.numpy as jnp
 
 from p2p_llm_tunnel_tpu.models.config import ModelConfig
-from p2p_llm_tunnel_tpu.models.mla import (
-    ROUTER_BIAS_STD,
-    _counted,
-    _embed,
-    _head,
-)
+from p2p_llm_tunnel_tpu.models import mla
+from p2p_llm_tunnel_tpu.models.mla import ROUTER_BIAS_STD, _counted
 from p2p_llm_tunnel_tpu.models.moe import (
     RAGGED,
     STATS,
@@ -191,22 +200,43 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
     ``dt_bias`` and ``D`` are drawn as the family's own initialiser draws
     them (``A`` uniform in [1, 16], the time step log-uniform between
     ``ssm_dt_min`` and ``ssm_dt_max`` through the inverse softplus, ``D``
-    ones), so that random weights decay as trained ones do."""
+    ones), so that random weights decay as trained ones do.  A
+    ``cfg.mixer_mlp`` model's MLPs are one stack over all layers, drawn from
+    part 2 of the key (``benchmarks/granite_hybrid_reference.py`` states that
+    family's draw again)."""
     dm, h, kv, hd, v = (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
                         cfg.vocab_size)
     n = kind_counts(cfg)
     lm, le, la = n["M"], n["E"], n["*"]
     keys = jax.random.split(key, 16)
+    # (a branch's last matrix is drawn 1 / residual_multiplier as wide: the
+    # branch then adds to the stream what it adds in a model that states no
+    # multiplier, and a token's own row does not drown what the layers
+    # compute: under a tied head that row alone would decide the logits)
+    out_x = 1.0 / cfg.residual_multiplier
 
-    def dense(k, shape, fan_in):
+    def dense(k, shape, fan_in, times=1.0):
         return (jax.random.truncated_normal(k, -2, 2, shape, jnp.float32)
-                * (fan_in ** -0.5)).astype(dtype)
+                * (times * fan_in ** -0.5)).astype(dtype)
 
     params = {
-        "embed": dense(keys[7], (v, dm), dm),
+        # (a table that is the head too is drawn ``logits_divisor`` times as
+        # wide: the logits of a random model are then spread as an untied
+        # head's are, not flat under the division)
+        "embed": dense(keys[7], (v, dm), dm,
+                       cfg.logits_divisor if cfg.tie_embeddings else 1.0),
         "final_norm": jnp.ones((dm,), dtype),
-        "lm_head": dense(jax.random.fold_in(key, 99), (dm, v), dm),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(jax.random.fold_in(key, 99), (dm, v), dm)
+    if cfg.mixer_mlp:
+        ks = jax.random.split(keys[2], 2)
+        layers, f = cfg.n_layers, cfg.ffn_dim
+        params["mlp"] = {
+            "norm": jnp.ones((layers, dm), dtype),
+            "w_in": dense(ks[0], (layers, dm, 2 * f), dm),
+            "w_out": dense(ks[1], (layers, f, dm), f, out_x),
+        }
     if la:
         ks = jax.random.split(keys[0], 4)
         params["attn"] = {
@@ -214,7 +244,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
             "wq": dense(ks[0], (la, dm, h * hd), dm),
             "wk": dense(ks[1], (la, dm, kv * hd), dm),
             "wv": dense(ks[2], (la, dm, kv * hd), dm),
-            "wo": dense(ks[3], (la, h * hd, dm), h * hd),
+            "wo": dense(ks[3], (la, h * hd, dm), h * hd, out_x),
         }
     if lm:
         ks = jax.random.split(keys[1], 6)
@@ -228,7 +258,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
             "w_in": dense(ks[0], (lm, dm, inner + conv_dim + heads), dm),
             "conv_w": dense(ks[1], (lm, cfg.ssm_conv, conv_dim), cfg.ssm_conv),
             "conv_b": dense(ks[2], (lm, conv_dim), cfg.ssm_conv),
-            "w_out": dense(ks[3], (lm, inner, dm), inner),
+            "w_out": dense(ks[3], (lm, inner, dm), inner, out_x),
             "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
             "a_log": jnp.log(jax.random.uniform(
                 ks[5], (lm, heads), jnp.float32, 1.0, 16.0)),
@@ -405,11 +435,56 @@ def _stacked_experts(cfg: ModelConfig, params):
         (-1,) + params["blocks"][k].shape[2:]) for k in expert_leaves(cfg)}
 
 
+def _embed(cfg: ModelConfig, params, tokens):
+    """The residual stream's first value, float32: the tokens' rows, times
+    ``cfg.embed_multiplier``."""
+    x = mla._embed(cfg, params, tokens)
+    return x if cfg.embed_multiplier == 1.0 else x * cfg.embed_multiplier
+
+
+def _head(cfg: ModelConfig, params, x):
+    """The final norm and the head (the embedding under
+    ``cfg.tie_embeddings``: ``transformer._logits``), over
+    ``cfg.logits_divisor``: the normed ``[rows, dim]`` is divided before the
+    product, not the ``[rows, vocabulary]`` logits after it in a pass of
+    their own (0.7 ms a step at 65 x 100,352: ISSUE 46).  No family here
+    states a divisor and a soft cap together."""
+    from p2p_llm_tunnel_tpu.models.transformer import _logits, _norm
+
+    if cfg.logits_divisor == 1.0:
+        return mla._head(cfg, params, x)
+    assert cfg.logit_softcap is None
+    with jax.named_scope("head_sample"):
+        h = _norm(cfg, x, params["final_norm"]) / cfg.logits_divisor
+        return _logits(cfg, params, h.astype(params["embed"].dtype))
+
+
+def _added(cfg: ModelConfig, out):
+    """What a branch adds to the float32 stream."""
+    out = out.astype(jnp.float32)
+    return out if cfg.residual_multiplier == 1.0 \
+        else out * cfg.residual_multiplier
+
+
+def _mlp(cfg: ModelConfig, blk, x, dtype):
+    """The dense gated MLP a ``cfg.mixer_mlp`` layer carries after its
+    mixer, from the stream ``x``: its own norm, ``[a | b] = u W_in``,
+    ``W_out(act(a) * b)``; the gate's product in float32 (``_mm32``)."""
+    from p2p_llm_tunnel_tpu.models.transformer import _act
+
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, blk["norm"], cfg.norm_eps).astype(dtype)
+        ab = _mm32(h, blk["w_in"], cfg.act_quant)
+        gated = _act(cfg, ab[..., :cfg.ffn_dim]) * ab[..., cfg.ffn_dim:]
+        return _mm32(gated.astype(dtype), blk["w_out"], cfg.act_quant)
+
+
 def _run_layers(cfg: ModelConfig, params, x, counted, mamba, attend):
     """The layers in order over the float32 stream ``x``: ``mamba(i, blk,
     h)`` and ``attend(i, blk, h)`` give a Mamba-2 and an attention layer's
     output from its normed input (``i``: the layer's index in its kind's
-    stack, and so in its kind's cache leaves).  Returns (x, stats)."""
+    stack, and so in its kind's cache leaves); under ``cfg.mixer_mlp`` the
+    layer's MLP follows.  Returns (x, stats)."""
     from p2p_llm_tunnel_tpu.models.transformer import _act
 
     dtype = params["embed"].dtype
@@ -422,7 +497,7 @@ def _run_layers(cfg: ModelConfig, params, x, counted, mamba, attend):
                               dtype) != RAGGED:
         stacked, leaves = _stacked_experts(cfg, params), expert_leaves(cfg)
     total = jnp.zeros((STATS,), jnp.int32)
-    for kind, i in _places(cfg):
+    for layer, (kind, i) in enumerate(_places(cfg)):
         blk = _layer(params[GROUP[kind]], i, leaves)
         h32 = rms_norm(x, blk["norm"], cfg.norm_eps)
         h = h32.astype(dtype)
@@ -436,7 +511,10 @@ def _run_layers(cfg: ModelConfig, params, x, counted, mamba, attend):
                     cfg, blk, h, lambda v: _act(cfg, v), counted,
                     stacked=stacked, layer=i, router_in=h32)
                 total = total + stats
-        x = x + out.astype(jnp.float32)
+        x = x + _added(cfg, out)
+        if cfg.mixer_mlp:
+            x = x + _added(
+                cfg, _mlp(cfg, _layer(params["mlp"], layer), x, dtype))
     return x, total
 
 
@@ -482,16 +560,22 @@ def prefill(cfg: ModelConfig, params, tokens, valid, counted=None):
     return _head(cfg, params, x), rows, stats
 
 
-def _rows_of(leaf, i: int, slots):
-    """Rows ``slots [Bp]`` of layer ``i`` of a state leaf ``[L, rows, ...]``
-    -> ``[Bp, ...]``, a row at a time by a slice at a traced index: the
-    layer's slice first and a gather of two rows out of it was a copy of the
-    layer's 270 MB in every Mamba-2 layer of every chunk-prefill dispatch (8
-    % of the device in the first traced runs)."""
+def _rows_of(leaf, i: int, slots, view: Optional[int] = None):
+    """Rows ``slots [Bp]`` of layer ``i`` of a cache leaf ``[L, rows, ...]``
+    -> ``[Bp, ...]`` (of a plane ``[L, rows, S, W]`` the first ``view``
+    positions: ``[Bp, view, W]``), a row at a time by a slice at a traced
+    index: the layer's slice first and a gather of two rows out of it was a
+    copy of the layer's 270 MB in every Mamba-2 layer of every chunk-prefill
+    dispatch (8 % of the device in the first traced runs), and of a plane's
+    ``[rows, view, W]`` in every attention layer, with a copy of each whole
+    plane before and after the tails' write (ISSUE 46: 3.8 GB of
+    temporaries at 64 slots of 2560 positions, more than the chip had
+    left)."""
     zeros = (0,) * (leaf.ndim - 2)
+    size = leaf.shape[2:] if view is None else (view,) + leaf.shape[3:]
     return jnp.stack([
         jax.lax.dynamic_slice(
-            leaf, (i, slots[r]) + zeros, (1, 1) + leaf.shape[2:])[0, 0]
+            leaf, (i, slots[r]) + zeros, (1, 1) + size)[0, 0]
         for r in range(slots.shape[0])])
 
 
@@ -500,8 +584,17 @@ def _write_state(kv_cache, states, slots):
     (``states``: [(ssm [Bp,H,P,N], conv [Bp,K-1,C])] in stack order)."""
     out = dict(kv_cache)
     with jax.named_scope("state_write"):
+        # The leaves and every layer's new state pass one barrier: each
+        # write then follows every read of the old state by the data's own
+        # order.  Without it the compiler has to find that order itself,
+        # and in a dispatch of ONE row (its writes are slice updates, not
+        # scatters) it did not: it copied the whole leaf first, 1.6 GB a
+        # dispatch where that fitted and more than the chip had at 4.6 GB
+        # (ISSUE 46).
+        held, states = jax.lax.optimization_barrier(
+            ({name: out[name] for name in STATE_KEYS}, states))
         for name, vals in zip(STATE_KEYS, zip(*states)):
-            leaf = out[name]
+            leaf = held[name]
             for i, v in enumerate(vals):
                 leaf = leaf.at[i, slots].set(v.astype(leaf.dtype))
             out[name] = leaf
@@ -548,11 +641,7 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
     ``starts`` is 0.  Neither the planes nor the state are a carry of the
     layers: all tails and states are written once after them.  Returns
     (logits, cache, stats)."""
-    from p2p_llm_tunnel_tpu.models.transformer import (
-        lay_tail,
-        read_cache_view,
-        tail_placement,
-    )
+    from p2p_llm_tunnel_tpu.models.transformer import lay_tail, tail_placement
 
     b, t = tokens.shape
     s = kv_cache["k"].shape[2]
@@ -570,11 +659,10 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
     kv, states = [], []
 
     def view_rows(name, i):
-        rows = read_cache_view(kv_cache[name], jnp.int32(i), kv_view, slots)
+        rows = _rows_of(kv_cache[name], i, slots, kv_view)
         if not quant:
             return rows
-        scale = read_cache_view(kv_cache[name + "_scale"], jnp.int32(i),
-                                kv_view, slots)
+        scale = _rows_of(kv_cache[name + "_scale"], i, slots, kv_view)
         return _unpack(rows, scale, dtype)
 
     def mamba(i, blk, h):

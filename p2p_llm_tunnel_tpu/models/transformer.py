@@ -419,9 +419,10 @@ def decode_kernel_decline(cfg: ModelConfig, mesh, kv_view: int):
       hazard prefill's flash_tp shard_map wrapper exists for — apply the
       same wrapper here before enabling);
     - shapes must tile (view and head_dim % 128) unless interpreting; in a
-      model with an ``attn_pattern`` the lane width is asked of a full
-      layer's ROWS (its KV heads side by side, models/swa.py), not of a
-      head."""
+      model with an ``attn_pattern`` or a ``mixer_pattern`` the lane width
+      is asked of a full layer's ROWS (its KV heads side by side,
+      models/swa.py and models/ssm_moe.py), not of a head: 8 heads of 64
+      are four lane tiles."""
     backend = jax.default_backend()
     if not (backend == "tpu" or cfg.flash_interpret or cfg.flash_force):
         return f"backend {backend!r} is not tpu"
@@ -433,10 +434,10 @@ def decode_kernel_decline(cfg: ModelConfig, mesh, kv_view: int):
         return f"kv view {kv_view} does not tile (% 128)"
     if cfg.flash_interpret:
         return None
-    if cfg.attn_pattern is not None:
+    if cfg.attn_pattern is not None or cfg.mixer_pattern is not None:
         # Planes whose rows are a position's KV heads side by side
-        # (models/swa.py): a row has to be whole lane tiles; a head's own
-        # width need not be.
+        # (models/swa.py, models/ssm_moe.py): a row has to be whole lane
+        # tiles; a head's own width need not be.
         kv = cfg.kv_heads_of("full")
         for what, width in (("key", kv * cfg.head_dim),
                             ("value", kv * cfg.v_head_dim)):

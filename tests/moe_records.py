@@ -32,7 +32,8 @@ async def dispatches_closed(eng, timeout: float = 10.0) -> None:
     out.  (A fixed ``sleep(0.3)`` stood here and lost that race under six
     test workers: ISSUE 45.)  With the journal on: until it holds a closed
     record for every dispatch opened since the first it holds, up to the
-    last one ``eng`` opened.  With it off: until the engine's counters have
+    last one ``eng`` opened, and no routed layer's counts are left queued
+    (ISSUE 46: the engine takes them with the record and when it idles).  With it off: until the engine's counters have
     stood still for three looks.  ``timeout`` seconds at most."""
     deadline = time.monotonic() + timeout
     before, still = None, 0
@@ -42,7 +43,8 @@ async def dispatches_closed(eng, timeout: float = 10.0) -> None:
             closed = {r.attrs.get("seq") for r in global_tracer.records()
                       if r.name in DISPATCHES}
             if closed and closed >= set(
-                    range(min(closed), last.attrs["seq"] + 1)):
+                    range(min(closed), last.attrs["seq"] + 1)) \
+                    and not eng._moe_pending:
                 return
         else:
             now = _counters()

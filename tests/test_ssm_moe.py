@@ -672,7 +672,17 @@ def test_healthz_names_the_planes_the_state_and_a_slots_bytes():
         "layers": 3, "heads": 4, "head_width": 8, "state_width": 16,
         "type": "float32", "conv_positions": 3, "conv_width": 96,
         "conv_type": "float32", "bytes_per_slot": per_slot,
-        "update": ELEMENTWISE}
+        "update": ELEMENTWISE,
+        # 8 blocks x 16 tokens / a chunk of 16 (ISSUE 46: said in bytes)
+        "snapshots": {"room": 8, "held": 0, "bytes_each": per_slot,
+                      "bytes": 8 * per_slot}}
+    # one mixer a layer, no multiplier, a head of its own (ISSUE 46)
+    assert section["layer"] == {"mixers": {"M": 3, "E": 2, "*": 2},
+                                "mlp_width": 0}
+    assert section["multipliers"] == {
+        "embedding": 1.0, "residual": 1.0, "attention_scores": 0.25,
+        "logits_divisor": 1.0}
+    assert section["head"] == "its own"
     # two statements: what the pool holds for a token, what a slot holds
     assert cache["bytes_per_token"] == 2 * 64 * 4
     assert cache["bytes_per_slot"] == 2 * 64 * 4 * 128 + per_slot

@@ -791,6 +791,51 @@ def test_the_state_is_updated_where_it_lies_and_the_share_fits(chip, program):
     assert held < 14.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
 
 
+@pytest.mark.slow  # 26 s alone: this file is tier-1's longest (ISSUE 46)
+def test_the_whole_hybrid_decodes_through_both_kernels_and_fits(chip):
+    """``granite-4.0-h-micro`` whole, at its cell's shapes (64 slots + the
+    scratch row x 2560), as a TPU backend runs a decode burst (ISSUE 46):
+    the state kernel at ONE group in all 36 Mamba-2 layers over the 4.9 GB
+    leaf, aliased in and out (no copy of it), the rows kernel over planes
+    whose rows are 8 KV heads of 64 side by side in the 4 attention layers
+    (a head of 64 is half a lane tile: Mosaic takes it), and 6.38 GB of
+    weights, the cache, 17 snapshots and the scratch one, the pool's 2,048
+    blocks and the step's temporaries inside a v5e's 16 GB."""
+    from p2p_llm_tunnel_tpu.models import transformer as T
+    from p2p_llm_tunnel_tpu.models.config import get_config
+    from p2p_llm_tunnel_tpu.models.ssm_moe import state_bytes_per_slot
+    from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import SSM_STEP_KERNEL
+
+    rows, seq = 65, 2560
+    cfg = get_config("granite-4.0-h-micro")
+    params, cache = _share_shapes(chip, cfg, rows, seq)
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (4, rows, seq, 512), "v": (4, rows, seq, 512),
+        "ssm": (36, rows, 64, 64, 128), "conv": (36, rows, 3, 4352)}
+    batch = _on(chip, {"rows": jax.ShapeDtypeStruct((rows,), jnp.int32)})
+    compiled = jax.jit(
+        lambda p, c, b: _ssm_burst(T, replace(cfg, flash_force=True), p, c,
+                                   b["rows"], b["rows"]),
+        donate_argnums=(1,)).lower(params, cache, batch).compile()
+    hlo = compiled.as_text()
+    dims = ",".join(str(d) for d in cache["ssm"].shape)
+    assert [ln for ln in hlo.splitlines()
+            if " copy(" in ln and f"[{dims}]" in ln] == []
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert aliased.count("alias") == len(cache)
+    calls = [ln for ln in hlo.splitlines() if "custom-call(" in ln]
+    assert sum(f"%{SSM_STEP_KERNEL}" in ln for ln in calls) == 36
+    assert sum(f"%{ROWS_KERNEL}" in ln for ln in calls) == 4
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert 6.38e9 < weights < 6.39e9  # 3,191 M parameters, the head tied
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + 18 * state_bytes_per_slot(cfg) + 2048 * 16 * 8192)
+    assert held < 14.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
+
+
 # ---------------------------------------------------------------------------
 # generation by blocks (ISSUE 38)
 # ---------------------------------------------------------------------------
