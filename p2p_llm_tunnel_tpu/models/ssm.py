@@ -17,6 +17,9 @@ groups that share ``B`` and ``C`` of ``N`` values, head ``h`` in group ``h //
 What a sequence carries from one dispatch to the next is ``S`` (float32) and
 the convolution's last ``K - 1`` inputs (the activations' type): a slot's
 recurrent state, held beside the KV planes (models/ssm_moe.py).
+:func:`causal_conv` is the convolution over a segment; :func:`conv_step` is
+one position of it over the tail as a slot holds it, the ``K - 1`` inputs
+side by side in one row.
 
 :func:`ssm_scan` is the recurrence over a segment in chunks of ``chunk``
 positions (the SSD form: inside a chunk the outputs are one masked product,
@@ -59,6 +62,23 @@ def causal_conv(conv_w, conv_b, tail, xbc, lengths):
         acc = acc + w[j] * full[:, j:j + t].astype(jnp.float32)
     at = lengths[:, None] + jnp.arange(k - 1)[None, :]
     new_tail = jnp.take_along_axis(full, at[:, :, None], axis=1)
+    return jax.nn.silu(acc).astype(xbc.dtype), new_tail
+
+
+def conv_step(conv_w, conv_b, tail, xbc, live):
+    """One position of :func:`causal_conv`, to its bits, over the tail as a
+    slot holds it: ``tail [B, (K-1) * C]``, the ``K - 1`` inputs before the
+    token side by side (the oldest first), ``xbc [B, C]`` the token's,
+    ``live [B]`` the rows that take a token.  Returns (``silu(conv) [B, C]``
+    in ``xbc``'s type, the new tail: a live row's shifted by one input, any
+    other row's as it was)."""
+    k, c = conv_w.shape
+    full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    w = conv_w.astype(jnp.float32)
+    acc = conv_b.astype(jnp.float32)[None, :]
+    for j in range(k):
+        acc = acc + w[j] * full[:, j * c:(j + 1) * c].astype(jnp.float32)
+    new_tail = jnp.where(live[:, None], full[:, c:], full[:, :(k - 1) * c])
     return jax.nn.silu(acc).astype(xbc.dtype), new_tail
 
 

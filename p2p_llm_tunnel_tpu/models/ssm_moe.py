@@ -36,10 +36,16 @@ they existed.
   row a position's KV heads side by side (models/swa.py's full planes:
   whole lane tiles, read by decode where they lie);
 - ``"ssm"`` ``[Lm, rows, H, P, N]`` (:data:`STATE_DTYPE`, float32): each
-  Mamba-2 layer's recurrent state, and ``"conv"`` ``[Lm, rows, K - 1, C]``
-  (the activations' type): the convolution's last ``K - 1`` inputs
-  (:data:`STATE_KEYS`; positions on the sublanes, the ``C`` channels on the
-  lanes: three values on the lanes would be padded to a tile).
+  Mamba-2 layer's recurrent state, and ``"conv"`` ``[Lm, rows, (K - 1) * C]``
+  (the activations' type): the convolution's last ``K - 1`` inputs, a
+  slot's tail one row, its positions side by side on the lanes, the oldest
+  first (:data:`STATE_KEYS`).  The slots are then the sublanes of the leaf
+  and of a decode step's write of a layer's part alike, so the write is a
+  slice update where the leaf lies; positions on an axis of their own are
+  the sublanes of that write and not of the leaf, and a program short of
+  memory then moves the whole leaf into the write's layout and back in
+  every layer (72 copies of 61 MB a step in granite-4.0-h-micro's cell:
+  ISSUE 47).
 
 A token caches rows in the attention layers only (the prefix pool's pages);
 the state is no function of one token, so the pool holds **snapshots** of it
@@ -103,6 +109,7 @@ from p2p_llm_tunnel_tpu.models.moe import (
 from p2p_llm_tunnel_tpu.models.quant import mm, round_act
 from p2p_llm_tunnel_tpu.models.ssm import (
     causal_conv,
+    conv_step,
     gated_group_norm,
     ssm_scan,
     ssm_step,
@@ -325,7 +332,7 @@ def init_kv_cache(cfg: ModelConfig, num_slots: int, max_seq: int,
         (n["M"], num_slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
         STATE_DTYPE)
     out["conv"] = jnp.zeros(
-        (n["M"], num_slots, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype)
+        (n["M"], num_slots, (cfg.ssm_conv - 1) * cfg.ssm_conv_dim), dtype)
     return out
 
 
@@ -336,7 +343,7 @@ def cache_section(cfg: ModelConfig, kv_cache) -> dict:
     per_token = sum(a.shape[3] * a.dtype.itemsize
                     for name, a in kv_cache.items() if name not in STATE_KEYS)
     ssm, conv = kv_cache["ssm"], kv_cache["conv"]
-    per_slot = sum(int(a[0, 0].size) * a.dtype.itemsize for a in (ssm, conv))
+    per_slot = state_bytes_per_slot(cfg, conv.dtype)
     return {
         "form": "kv_heads+state",
         "kinds": {
@@ -350,13 +357,14 @@ def cache_section(cfg: ModelConfig, kv_cache) -> dict:
                 "layers": ssm.shape[0], "heads": cfg.ssm_heads,
                 "head_width": cfg.ssm_head_dim, "state_width": cfg.ssm_state,
                 "type": str(ssm.dtype),
-                "conv_positions": conv.shape[2], "conv_width": conv.shape[3],
+                "conv_positions": cfg.ssm_conv - 1,
+                "conv_width": cfg.ssm_conv_dim,
                 "conv_type": str(conv.dtype),
-                "bytes_per_slot": ssm.shape[0] * per_slot,
+                "bytes_per_slot": per_slot,
             },
         },
         "bytes_per_token": la * per_token,
-        "bytes_per_slot": la * per_token * positions + ssm.shape[0] * per_slot,
+        "bytes_per_slot": la * per_token * positions + per_slot,
     }
 
 
@@ -382,7 +390,9 @@ def _mamba(cfg: ModelConfig, blk, h, tail, state, real, step=None):
     """One Mamba-2 mixer over ``h [B,T,Dm]`` (normed, the weights' type):
     ``tail [B,K-1,C]`` and ``state [B,H,P,N]`` before the segment, ``real
     [B,T]`` the positions that are no padding -> (out ``[B,T,Dm]``, new
-    tail, new state).  ``step``: decode's, ``T`` is 1 and the update is
+    tail, new state).  ``step``: decode's, ``T`` is 1, the tail in and out
+    is ``[B,(K-1)*C]`` as the ``"conv"`` leaf holds it (the convolution is
+    its one-token form over the slabs where they lie) and the update is
     ``step(x, dt, a, bm, cm) -> y`` of :func:`ssm.ssm_step`'s operands,
     which holds the state itself (``state`` is None, in and out)."""
     b, t, _ = h.shape
@@ -398,8 +408,13 @@ def _mamba(cfg: ModelConfig, blk, h, tail, state, real, step=None):
         dt = jax.nn.softplus(zxd[..., inner + conv_dim:] + blk["dt_bias"])
         dt = jnp.where(real[..., None], dt, 0.0)
     with jax.named_scope("ssm_conv"):
-        xbc, tail = causal_conv(blk["conv_w"], blk["conv_b"], tail, xbc,
-                                real.sum(axis=1).astype(jnp.int32))
+        if step is not None:
+            xbc, tail = conv_step(blk["conv_w"], blk["conv_b"], tail,
+                                  xbc[:, 0], real[:, 0])
+            xbc = xbc[:, None]
+        else:
+            xbc, tail = causal_conv(blk["conv_w"], blk["conv_b"], tail, xbc,
+                                    real.sum(axis=1).astype(jnp.int32))
     x = xbc[..., :inner].reshape(b, t, heads, p)
     bm = xbc[..., inner:inner + g * n].reshape(b, t, g, n)
     cm = xbc[..., inner + g * n:].reshape(b, t, g, n)
@@ -581,7 +596,8 @@ def _rows_of(leaf, i: int, slots, view: Optional[int] = None):
 
 def _write_state(kv_cache, states, slots):
     """The dispatch's rows' new state into their slots, a layer at a time
-    (``states``: [(ssm [Bp,H,P,N], conv [Bp,K-1,C])] in stack order)."""
+    (``states``: [(ssm [Bp,H,P,N], conv [Bp,K-1,C])] in stack order; a tail
+    is laid as the leaf holds it, its positions side by side)."""
     out = dict(kv_cache)
     with jax.named_scope("state_write"):
         # The leaves and every layer's new state pass one barrier: each
@@ -596,6 +612,7 @@ def _write_state(kv_cache, states, slots):
         for name, vals in zip(STATE_KEYS, zip(*states)):
             leaf = held[name]
             for i, v in enumerate(vals):
+                v = v.reshape(v.shape[:1] + leaf.shape[2:])
                 leaf = leaf.at[i, slots].set(v.astype(leaf.dtype))
             out[name] = leaf
     return out
@@ -669,8 +686,18 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
         with jax.named_scope("state_read"):
             state = jnp.where(carried[:, None, None, None],
                               _rows_of(kv_cache["ssm"], i, slots), 0)
-            tail = jnp.where(carried[:, None, None],
-                             _rows_of(kv_cache["conv"], i, slots), 0)
+            # (the rows' positions become an axis again behind a barrier,
+            # and the select reads what the barrier hands on, as it read
+            # the rows before ISSUE 47.  The TPU compiler's broadcast
+            # rewriter walks a program's elementwise groups by recursion on
+            # a small stack, and granite's t = 512 programs stand at its
+            # edge: with this reshape anywhere it could reach, after the
+            # select or before it, the compiler overflowed that stack in
+            # three cold starts of three)
+            tail = jax.lax.optimization_barrier(
+                _rows_of(kv_cache["conv"], i, slots).reshape(
+                    (b, cfg.ssm_conv - 1, cfg.ssm_conv_dim)))
+            tail = jnp.where(carried[:, None, None], tail, 0)
         out, tail, state = _mamba(cfg, blk, h, tail, state, valid)
         states.append((state, tail))
         return out

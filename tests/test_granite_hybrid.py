@@ -115,7 +115,7 @@ def test_the_preset_is_the_layer_the_issue_writes_down(model):
     cache = init_kv_cache(cfg, ROWS, MAX_SEQ, jnp.float32)
     assert {k: v.shape for k, v in cache.items()} == {
         "k": (1, ROWS, MAX_SEQ, 32), "v": (1, ROWS, MAX_SEQ, 32),
-        "ssm": (3, ROWS, 4, 8, 16), "conv": (3, ROWS, 3, 32 + 2 * 16)}
+        "ssm": (3, ROWS, 4, 8, 16), "conv": (3, ROWS, 3 * (32 + 2 * 16))}
     assert SHAPES["kinds"] == ("mamba", "mamba", "attention", "mamba")
 
 

@@ -201,7 +201,7 @@ def test_the_presets_cache_is_planes_and_a_state_a_slot(model):
     assert cfg.mixer_kinds == "MEM*EM*" and cfg.attn_kinds == ("full",) * 2
     assert {k: v.shape for k, v in cache.items()} == {
         "k": (2, ROWS, MAX_SEQ, 2 * 16), "v": (2, ROWS, MAX_SEQ, 2 * 16),
-        "ssm": (3, ROWS, 4, 8, 16), "conv": (3, ROWS, 3, 32 + 2 * 2 * 16)}
+        "ssm": (3, ROWS, 4, 8, 16), "conv": (3, ROWS, 3 * (32 + 2 * 2 * 16))}
     assert cache["ssm"].dtype == jnp.float32  # whatever the activations are
     assert init_kv_cache(cfg, ROWS, MAX_SEQ)["ssm"].dtype == jnp.float32
     assert ssm_moe.state_bytes_per_slot(cfg, jnp.float32) == 3 * (
