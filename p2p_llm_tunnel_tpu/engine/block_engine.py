@@ -1,18 +1,26 @@
 """The engine's part of generation by blocks (``ModelConfig.block_length``;
 models/block_decode.py): the device-side block carry, the decode program
-that mixes denoise and commit passes by row, and the host's accounting of a
-pass that yields 0 or ``k`` tokens a row.  A mixin of ``InferenceEngine``;
-every other model takes none of it.
+whose every pass decides a group of a row's current block and commits the
+block before it where that awaits it, and the host's accounting of a pass
+that yields up to ``k`` tokens a row.  A mixin of ``InferenceEngine``; every
+other model takes none of it.
 
 **The carry** stays on the device between dispatches, as the one-token
-carry does: a row's block of tokens ``[rows, block]``, the block's first
-position ``base``, how many offsets are ``decided`` (``== block``: the next
-pass commits), and the penalty counts.  A pass is ``block_decode_step``;
-after it a denoising row takes its ``k`` new tokens and ``decided += k``, a
-committing row moves to ``base + block`` with nothing decided.  Rows are out
-of phase with each other; the host learns each row's phase from the
-``decided`` and ``base`` the program returns beside the tokens, so it never
-has to model the device's lead on it.
+carry does: a row's two blocks of tokens ``[rows, 2 * block]`` (the block
+that awaits its commit, then the current one), the current block's first
+position ``base``, how many of its offsets are ``decided`` (always ``<
+block``), whether the block before it is ``pending`` (fully decided, not
+yet in the cache: its first position is ``base - block``), and the penalty
+counts.  A pass is ``block_decode_step``; after it a row takes its ``k`` new
+tokens, and where they were the block's last group it rolls at once: the
+block becomes the pending one, ``base += block``, nothing decided.  So the
+next pass on the row decides the next block's first group and writes the
+pending block's K/V on the way, and no pass is a commit alone.  A row's
+first block (after prefill, after a pool hit, under echo from position 0)
+has nothing pending; a row that finishes leaves its last block unwritten.
+Rows are out of phase with each other; the host learns each row's phase
+from the ``decided``, ``base`` and ``pending`` the program returns beside
+the tokens, so it never has to model the device's lead on it.
 
 **Forced outcomes.**  A prompt's whole blocks go through chunk prefill; its
 remainder (``n mod block`` tokens) enters the first decode block.  A prompt
@@ -27,7 +35,8 @@ function of the sequence's tokens alone, wherever the prompt ended.
 **Echo** is the same mechanism from position 0: an ``echo`` request is not
 prefilled at all; its prompt runs through the decode program as forced
 outcomes (every group kept, so every prompt token is scored in the pass that
-would have decided it) and the blocks are committed as generation's are.
+would have decided it) and the blocks are committed as generation's are:
+each by the first pass on the block after it.
 """
 
 from __future__ import annotations
@@ -75,7 +84,7 @@ class BlockDecodeMixin:
         # slot -> the echoed prompt's log-probabilities so far
         self._blk_echo: Dict[int, List[float]] = {}
         self._blk_burst_attrs: Dict[str, int] = {}
-        self._dev_decided = None
+        self._dev_decided = self._dev_pending = None
 
     def _prefill_ids(self, run) -> List[int]:
         """The prompt tokens that prefill computes: all of them, or the
@@ -106,18 +115,20 @@ class BlockDecodeMixin:
     # -- the program ------------------------------------------------------
 
     def _block_decode_fn(
-        self, params, kv_cache, tokens, base, decided, counts, bias, ov_mask,
-        ov_base, ov_decided, plane, forced_n, samp, key, kv_view, steps,
+        self, params, kv_cache, tokens, base, decided, pending, counts, bias,
+        ov_mask, ov_base, ov_decided, plane, forced_n, samp, key, kv_view,
+        steps,
     ):
-        """``steps`` chained passes over every row's block.  Returns
-        (tokens [B, steps, k], decided [B, steps] and base [B, steps] as
-        each pass found them, log-probability data, the carry, cache)."""
+        """``steps`` chained passes over every row's two blocks.  Returns
+        (tokens [B, steps, k], decided, base and pending [B, steps] as each
+        pass found them, log-probability data, the carry, cache)."""
         cfg = self.mcfg
         n, k = cfg.block_length, group_size(cfg)
         b = tokens.shape[0]
         s = plane.shape[1]
         base = jnp.where(ov_mask, ov_base, base)
         decided = jnp.where(ov_mask, ov_decided, decided)
+        pending = pending & ~ov_mask  # a row patched in has no block behind
         any_pen = jnp.any((samp.freq_pen != 0.0) | (samp.pres_pen != 0.0))
         counts = jax.lax.cond(
             any_pen, lambda: jnp.where(ov_mask[:, None], 0, counts),
@@ -127,20 +138,20 @@ class BlockDecodeMixin:
         row_ids = jnp.arange(b)
 
         def one(carry, _xs):
-            toks, base, dec, cnt, cache = carry
+            toks, base, dec, pend, cnt, cache = carry
             pos = base[:, None] + offs
             forced = pos < forced_n[:, None]
             prompt = jnp.take_along_axis(
                 plane, jnp.clip(pos, 0, s - 1), axis=1)
-            toks = jnp.where(forced, prompt, toks)
+            prev, cur = toks[:, :n], jnp.where(forced, prompt, toks[:, n:])
             logits, cache, *moe = block_decode_step(
-                cfg, params, cache, toks, base, dec, kv_view=kv_view,
+                cfg, params, cache, jnp.concatenate([prev, cur], axis=1),
+                base, dec, pend, kv_view=kv_view,
                 with_stats=self._moe_counts)
-            commit = dec >= n
             out_tok, out_lp = [], []
             with jax.named_scope("head_sample"):
                 for j in range(k):
-                    off = jnp.clip(dec + j, 0, n - 1)
+                    off = dec + j
                     sampled = sampling.sample(
                         logits[:, j], samp, None, counts=cnt,
                         pos=base + off, bias=bias)
@@ -150,13 +161,12 @@ class BlockDecodeMixin:
                         tok = jnp.where(
                             is_forced, jnp.sum(jnp.where(at, prompt, 0), 1),
                             sampled).astype(jnp.int32)
-                        toks = jnp.where(at & ~commit[:, None],
-                                         tok[:, None], toks)
+                        cur = jnp.where(at, tok[:, None], cur)
                     cnt = jax.lax.cond(
                         any_pen,
                         lambda cnt=cnt, tok=tok, is_forced=is_forced:
                         cnt.at[row_ids, tok].add(
-                            jnp.where(commit | is_forced, 0, 1)),
+                            jnp.where(is_forced, 0, 1)),
                         lambda cnt=cnt: cnt)
                     out_lp.append(jax.lax.cond(
                         any_lp,
@@ -165,20 +175,26 @@ class BlockDecodeMixin:
                         lambda: sampling.empty_logprob_data(
                             b, logits.shape[-1])))
                     out_tok.append(tok)
-            nxt = (toks, jnp.where(commit, base + n, base),
-                   jnp.where(commit, 0, dec + k), cnt, cache)
+            # the block's last group: it awaits its commit from here on, and
+            # the row's next pass is the first on the block after it
+            full = dec + k >= n
+            nxt = (jnp.concatenate(
+                       [jnp.where(full[:, None], cur, prev), cur], axis=1),
+                   jnp.where(full, base + n, base),
+                   jnp.where(full, 0, dec + k), full, cnt, cache)
             lp = jax.tree.map(lambda *a: jnp.stack(a, axis=1), *out_lp)
-            return nxt, (jnp.stack(out_tok, axis=1), dec, base, lp, moe)
+            return nxt, (jnp.stack(out_tok, axis=1), dec, base, pend, lp, moe)
 
-        (tokens, base, decided, counts, kv_cache), ys = jax.lax.scan(
-            one, (tokens, base, decided, counts, kv_cache), None,
+        (tokens, base, decided, pending, counts, kv_cache), ys = jax.lax.scan(
+            one, (tokens, base, decided, pending, counts, kv_cache), None,
             length=steps)
-        toks, decs, bases, lps, moe = ys
+        toks, decs, bases, pends, lps, moe = ys
         # [steps, B, ...] scan stacking -> [B, steps, ...] for the host
         lp_out = jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), lps)
         head = tuple(m.sum(axis=0) for m in moe)
-        return head + (jnp.swapaxes(toks, 0, 1), decs.T, bases.T, lp_out,
-                       tokens, base, decided, counts, kv_cache)
+        return head + (jnp.swapaxes(toks, 0, 1), decs.T, bases.T, pends.T,
+                       lp_out, tokens, base, decided, pending, counts,
+                       kv_cache)
 
     def _block_warm_args(self, view: int, steps: int):
         """Positional args of the block decode program, aval-identical to
@@ -187,8 +203,9 @@ class BlockDecodeMixin:
         zeros = jnp.zeros((rows,), jnp.int32)
         return (
             self.params, self.kv_cache, self._dev_tokens,
-            self._dev_positions, self._dev_decided, self._dev_counts,
-            self._bias, jnp.zeros((rows,), bool), zeros, zeros,
+            self._dev_positions, self._dev_decided, self._dev_pending,
+            self._dev_counts, self._bias, jnp.zeros((rows,), bool), zeros,
+            zeros,
             jnp.zeros((rows, self.ecfg.max_seq), jnp.int32), zeros,
             self._warm_samp(rows), self._key, view, steps,
         )
@@ -218,15 +235,15 @@ class BlockDecodeMixin:
         ) if global_tracer.enabled and not self._warming else None
         t_jit0 = time.monotonic()
         with rec.annotation() if rec else contextlib.nullcontext():
-            (toks, decs, bases, lp_out, self._dev_tokens,
-             self._dev_positions, self._dev_decided, self._dev_counts,
-             self.kv_cache) = self._jit_decode(
+            (toks, decs, bases, pends, lp_out, self._dev_tokens,
+             self._dev_positions, self._dev_decided, self._dev_pending,
+             self._dev_counts, self.kv_cache) = self._jit_decode(
                 self.params, self.kv_cache, self._dev_tokens,
-                self._dev_positions, self._dev_decided, self._dev_counts,
-                self._bias, jnp.array(ov_mask), jnp.array(ov_base),
-                jnp.array(ov_decided), jnp.array(self._blk_plane),
-                jnp.array(forced), self._burst_samp(), self._next_key(),
-                view, steps,
+                self._dev_positions, self._dev_decided, self._dev_pending,
+                self._dev_counts, self._bias, jnp.array(ov_mask),
+                jnp.array(ov_base), jnp.array(ov_decided),
+                jnp.array(self._blk_plane), jnp.array(forced),
+                self._burst_samp(), self._next_key(), view, steps,
             )
         self._note_program("decode", (view, steps),  # tunnelcheck: disable=TC17  the kind is planned where every decode program is: engine.py warmup_plan() enumerates ("decode", (view, steps)) and warms it through _dispatch_decode, which routes here
                            time.monotonic() - t_jit0)
@@ -236,10 +253,12 @@ class BlockDecodeMixin:
             global_metrics.inc("engine_decode_row_steps_total", live * steps)
             global_metrics.inc("engine_decode_slot_steps_total",
                                slots * steps)
-            self._note_moe("decode", (slots + 1) * self._block, rec)
-            # every pass's `block` queries see the prefix below the block
-            # and the block itself (the host's positions: the device's
-            # carry may lead them by the bursts in flight)
+            self._note_moe("decode", (slots + 1) * 2 * self._block, rec)
+            # every pass's `block` queries of the CURRENT block see the
+            # prefix below the block and the block itself (the host's
+            # positions: the device's carry may lead them by the bursts in
+            # flight); a pending block's queries ride the same read of the
+            # prefix and add nothing that has to be read
             n = self._block
             base = self._positions[:slots][active[:slots]].astype(
                 np.int64) // n * n
@@ -251,20 +270,20 @@ class BlockDecodeMixin:
         assign = self._burst_assign()
         if not np.any(np.where(active, self._logprobs, 0)):
             lp_out = None
-        outs = (toks, decs, bases, lp_out)
+        outs = (toks, decs, bases, pends, lp_out)
         self._start_host_copy(outs)
         return outs, assign
 
     async def _process_block_burst(self, outs, assign: List) -> None:
-        """Account one fetched burst: per pass and row, nothing (a commit
-        pass), or up to ``k`` tokens in position order.  A forced offset
-        (a prompt token) is no generated token: under ``echo`` its
-        log-probability is kept for the first event; a token past the
-        request's end (``max_tokens`` inside a group) is dropped with the
-        freed row."""
-        toks, decs, bases, lp_out = outs
-        n, k = self._block, group_size(self.mcfg)
-        denoise = commit = delivered = 0
+        """Account one fetched burst: per pass and row up to ``k`` tokens
+        in position order, and whether the pass wrote the block before
+        (``pending`` as the pass found it).  A forced offset (a prompt
+        token) is no generated token: under ``echo`` its log-probability is
+        kept for the first event; a token past the request's end
+        (``max_tokens`` inside a group) is dropped with the freed row."""
+        toks, decs, bases, pends, lp_out = outs
+        k = group_size(self.mcfg)
+        passes = fused = delivered = 0
         for col in range(toks.shape[1]):
             for i in np.nonzero(self._active_mask)[0]:
                 run = (self.scheduler.slots[i]
@@ -274,10 +293,8 @@ class BlockDecodeMixin:
                     continue
                 if run.request.request_id != assign[i]:
                     continue  # re-admitted: the next burst's
-                if decs[i, col] >= n:
-                    commit += 1
-                    continue
-                denoise += 1
+                passes += 1
+                fused += int(pends[i, col])
                 first = int(bases[i, col]) + int(decs[i, col])
                 plen = len(run.request.prompt_ids)
                 for j, pos in enumerate(range(first, first + k)):
@@ -301,10 +318,12 @@ class BlockDecodeMixin:
                     delivered += 1
             # this pass's tokens flush to consumers before the next's
             await asyncio.sleep(0)
-        global_metrics.inc("engine_block_row_passes_total", denoise + commit)
-        global_metrics.inc("engine_block_commit_row_passes_total", commit)
+        # every pass decides a group, so none is a commit alone: that
+        # counter and the records' key stay, at 0, for what reads them
+        global_metrics.inc("engine_block_row_passes_total", passes)
+        global_metrics.inc("engine_block_commit_row_passes_total", 0)
+        global_metrics.inc("engine_block_fused_commits_total", fused)
         global_metrics.inc("engine_block_tokens_decided_total", delivered)
         self._blk_burst_attrs = {
-            "row_passes_denoise": denoise, "row_passes_commit": commit,
-            "tokens_decided": delivered}
-
+            "row_passes_denoise": passes, "row_passes_commit": 0,
+            "row_commits_fused": fused, "tokens_decided": delivered}

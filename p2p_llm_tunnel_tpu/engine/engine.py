@@ -1262,7 +1262,7 @@ class InferenceEngine(BlockDecodeMixin):
         # (the decode program, the carry it donates, its static view and
         # steps: decode_launch_report lowers the same)
         self._decode_program = (
-            (self._block_decode_fn, (1, 2, 3, 4, 5), (14, 15))
+            (self._block_decode_fn, (1, 2, 3, 4, 5, 6), (15, 16))
             if self._block else (self._decode_fn, (1, 2, 3, 4), (11, 12)))
         self._jit_decode = jax.jit(
             self._decode_program[0], donate_argnums=self._decode_program[1],
@@ -2163,7 +2163,8 @@ class InferenceEngine(BlockDecodeMixin):
         e = self.ecfg
         return {"expert_products": {
             "decode": self._moe_branch(
-                "decode", (e.num_slots + 1) * (self.mcfg.block_length or 1)),
+                "decode",
+                (e.num_slots + 1) * (2 * self.mcfg.block_length or 1)),
             "chunk_prefill": self._moe_branch(
                 "chunk_prefill", e.prefill_rows * e.prefill_chunk)
             if e.prefill_chunk > 0 else None,
@@ -3853,9 +3854,10 @@ class InferenceEngine(BlockDecodeMixin):
         glob = (self._spmd.globalize if self._spmd is not None
                 else (lambda x: x))
         self._dev_tokens = glob(jnp.zeros(
-            (rows, self._block) if self._block else (rows,), jnp.int32))
-        if self._block:
+            (rows, 2 * self._block) if self._block else (rows,), jnp.int32))
+        if self._block:  # (engine/block_engine.py: the carry)
             self._dev_decided = jnp.zeros((rows,), jnp.int32)
+            self._dev_pending = jnp.zeros((rows,), bool)
         self._dev_positions = glob(jnp.zeros((rows,), jnp.int32))
         self._dev_counts = glob(
             jnp.zeros((rows, self.mcfg.vocab_size), jnp.int32)

@@ -160,10 +160,10 @@ class ModelConfig:
     # positions are filled ``block_length`` at a time (0: one token a
     # sequence and step).  A block takes ``denoise_steps`` passes that each
     # decide ``block_length / denoise_steps`` offsets in order (sequential
-    # remasking; the offsets not yet decided hold ``mask_token_id``), then a
-    # commit pass that writes the clean block's K/V.  Attention is
-    # block-causal: position ``i`` sees ``j`` where ``j // block_length <=
-    # i // block_length``.
+    # remasking; the offsets not yet decided hold ``mask_token_id``); the
+    # clean block's K/V are written by the first pass on the block after
+    # it, which forwards both.  Attention is block-causal: position ``i``
+    # sees ``j`` where ``j // block_length <= i // block_length``.
     block_length: int = 0
     denoise_steps: int = 0
     mask_token_id: int = 0

@@ -188,17 +188,22 @@ def block_attention(
     v_cache: jnp.ndarray,
     k_own: jnp.ndarray,
     v_own: jnp.ndarray,
-    base: jnp.ndarray,
+    bound: jnp.ndarray,
     *,
+    block: int,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """A block of ``T`` query positions a row against the cache prefix
-    ``[0, base)`` whole and the block's own ``T`` keys and values whole (no
-    causal mask inside the block), which need not be in the cache: the
-    decode pass of a model that fills positions a block at a time
-    (models/block_decode.py).  One softmax over both parts.
+    """``T`` query positions a row, whole blocks of ``block``: each against
+    the cache prefix below its own bound, ``[0, bound[b, t])`` whole, and
+    its own block's ``block`` keys and values whole (no causal mask inside
+    a block, nothing of another block of the call: the own part is
+    block-diagonal), which need not be in the cache: the decode pass of a
+    model that fills positions a block at a time (models/block_decode.py:
+    the block that awaits its commit beside the current one; the second's
+    queries reach the first's keys through the cache, where the pass has
+    just written them).  One softmax over both parts.
 
-    q [B,T,H,D]; k/v_cache [B,S,K,D]; k/v_own [B,T,K,D]; base [B] int32.
+    q [B,T,H,D]; k/v_cache [B,S,K,D]; k/v_own [B,T,K,D]; bound [B,T] int32.
     """
     b, t, h, d = q.shape
     kh = k_cache.shape[2]
@@ -206,10 +211,12 @@ def block_attention(
         scale = d**-0.5
     q5 = q.reshape(b, t, kh, h // kh, d)
     hist = _gqa_scores(q5, k_cache, scale)  # [B,K,G,T,S]
-    seen = jnp.arange(k_cache.shape[1])[None, :] < base[:, None]  # [B,S]
-    hist = jnp.where(seen[:, None, None, None, :], hist, _NEG_INF)
+    seen = jnp.arange(k_cache.shape[1])[None, None, :] < bound[:, :, None]
+    hist = jnp.where(seen[:, None, None, :, :], hist, _NEG_INF)  # [B,T,S]
     with jax.named_scope("attn_block"):
         own = _gqa_scores(q5, k_own, scale)  # [B,K,G,T,T]
+        of = jnp.arange(t) // block
+        own = jnp.where(of[:, None] == of[None, :], own, _NEG_INF)
         top = jnp.maximum(hist.max(axis=-1, keepdims=True),
                           own.max(axis=-1, keepdims=True))
     p_hist = jnp.exp(hist - top)

@@ -60,12 +60,18 @@ METRICS_CATALOG: Dict[str, str] = {
     ),
     "engine_block_row_passes_total": (
         "passes of real rows through the block decode program of a model "
-        "that generates by blocks, denoise and commit passes alike "
+        "that generates by blocks, each counted once whatever it carries "
         "(counter)"
     ),
     "engine_block_commit_row_passes_total": (
-        "of those, the commit passes: they write a block's K/V and decide "
-        "nothing (counter)"
+        "of those, the row-passes that decided nothing: none since a "
+        "block's commit rides the first pass on the block after it "
+        "(counter)"
+    ),
+    "engine_block_fused_commits_total": (
+        "of those, the row-passes that wrote the K/V of the block before "
+        "on their way (the first pass on every block of a row but its "
+        "first); the dispatch records' row_commits_fused (counter)"
     ),
     "engine_block_tokens_decided_total": (
         "tokens those passes decided that were delivered to a request "

@@ -1,6 +1,7 @@
 """Generation by blocks (``tiny-sdar-moe``: SDAR at a size the CPU runs:
-blocks of 4 filled in 2 denoise passes of 2 and a commit pass, QK norm, 8
-experts top-2) against its plain reference, tests/block_diffusion_plain.py:
+blocks of 4 filled in 2 denoise passes of 2, a block's commit riding the
+first pass on the block after it, QK norm, 8 experts top-2) against its
+plain reference, tests/block_diffusion_plain.py:
 the model's programs through the cache, the engine's block carry for every
 prompt remainder, ``max_tokens`` that ends inside a group, a chunk
 boundary, a prefix-pool hit, ``echo``, rows out of phase with each other,
@@ -102,8 +103,9 @@ def test_chunk_prefill_and_block_passes_against_the_reference(model, cut):
     """36 prompt tokens prefilled as two segments (the second starts at
     ``cut``: inside the first pool block's successor or past it), then the
     passes of three blocks given the reference's own tokens: every pass's
-    logits, both offsets, all columns; a denoise pass leaves the cache
-    bit-for-bit as it found it and a commit pass writes 4 rows a layer."""
+    logits, both offsets, all columns; a pass with nothing pending leaves
+    the cache bit-for-bit as it found it, and the first pass on a block
+    writes the 4 rows a layer of the block before it."""
     cfg, params = model
     ids = _prompt(2, 48)
     want = np.asarray(plain.denoise_logprobs(cfg, params, ids))
@@ -111,42 +113,126 @@ def test_chunk_prefill_and_block_passes_against_the_reference(model, cut):
     cache = _chunk(cfg, params, cache, ids[:cut], 0, 1, width=32)
     cache = _chunk(cfg, params, cache, ids[cut:36], cut, 1, width=32)
     for base in (36, 40, 44):
-        blk = _row(ids[base:base + BLOCK], 1, 0)
+        blk = _row(ids[base - BLOCK:base + BLOCK], 1, 0)  # [behind | block]
         at = _row(base, 1, MAX_SEQ)
         for decided in (0, 2):
-            logits, after = _block_step(cfg, params, cache, blk, at,
-                                        _row(decided, 1, 0), kv_view=MAX_SEQ)
+            behind = decided == 0 and base > 36
+            logits, after = _block_step(
+                cfg, params, cache, blk, at, _row(decided, 1, 0),
+                _row(behind, 1, 0).astype(bool), kv_view=MAX_SEQ)
             np.testing.assert_allclose(
                 _logprobs(logits[1]),
                 want[base + decided: base + decided + GROUP], atol=ATOL)
-            for key in cache:  # (b) nothing written
-                assert bool((after[key] == cache[key]).all()), key
-        _, after = _block_step(cfg, params, cache, blk, at,
-                               _row(BLOCK, 1, 0), kv_view=MAX_SEQ)
-        for key in cache:
-            changed = np.argwhere(np.asarray(after[key] != cache[key]))
-            assert set(changed[:, 1]) == {1}, key  # the row's own slot
-            assert set(changed[:, 2]) == set(range(base, base + BLOCK)), key
-        cache = after
+            for key in cache:
+                changed = np.argwhere(np.asarray(after[key] != cache[key]))
+                if not behind:  # (b) nothing written
+                    assert not len(changed), key
+                    continue
+                assert set(changed[:, 1]) == {1}, key  # the row's own slot
+                assert set(changed[:, 2]) == set(range(base - BLOCK, base)), \
+                    key
+            cache = after
+
+
+FUSED_CASES = {
+    # rows: (tokens held before the pending block, the pending block is
+    # there, offsets decided of the current block)
+    "a-block-behind": [(16, True, 0)],
+    "out-of-phase": [(24, True, 0), (12, False, 2), (8, False, 0)],
+    "the-mask-id-behind": [(16, True, 0)],
+    "int8-cache": [(16, True, 0), (20, False, 0)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_the_fused_pass_is_the_commit_and_then_the_first_denoise_pass(
+        model, case):
+    """One pass over ``[pending | current]`` against the two-pass form
+    computed here: the pending block forwarded clean by itself (chunk
+    prefill of its 4 tokens at its position: the same block-causal forward,
+    another program), then the pass on the current block with nothing
+    pending.  Equal logits at the deciding offsets and equal K/V written,
+    for a row with a block behind it beside rows without (one of them in
+    its second pass), a pending token equal to ``mask_token_id`` (a decided
+    token, read as itself) and the int8 cache control (the current block's
+    queries read the pending K/V quantised in both forms)."""
+    cfg, params = model
+    quant = case == "int8-cache"
+    cache = init_kv_cache(cfg, ROWS, MAX_SEQ, jnp.float32, quant=quant)
+    rows = FUSED_CASES[case]
+    toks = np.zeros((ROWS, 2 * BLOCK), np.int32)
+    base = np.full((ROWS,), MAX_SEQ, np.int32)
+    decided = np.zeros((ROWS,), np.int32)
+    behind = np.zeros((ROWS,), bool)
+    seqs = []
+    for slot, (held, pend, dec) in enumerate(rows):
+        ids = _prompt(90 + slot, held + 2 * BLOCK)
+        if case == "the-mask-id-behind":
+            ids[held + 1] = cfg.mask_token_id
+        cache = _chunk(cfg, params, cache, ids[:held], 0, slot, width=32)
+        if not pend:  # the block behind is in the cache already
+            cache = _chunk(cfg, params, cache, ids[held:held + BLOCK], held,
+                           slot, width=BLOCK)
+        toks[slot] = ids[held:]
+        base[slot], decided[slot], behind[slot] = held + BLOCK, dec, pend
+        seqs.append(ids)
+    args = (jnp.asarray(toks), jnp.asarray(base), jnp.asarray(decided))
+    fused, wrote = _block_step(cfg, params, cache, *args,
+                               jnp.asarray(behind), kv_view=MAX_SEQ)
+    two = cache
+    for slot, (held, pend, _dec) in enumerate(rows):
+        if pend:
+            two = _chunk(cfg, params, two, seqs[slot][held:held + BLOCK],
+                         held, slot, width=BLOCK)
+    alone, same = _block_step(cfg, params, two, *args,
+                              jnp.zeros((ROWS,), bool), kv_view=MAX_SEQ)
+    for key in two:
+        assert bool((same[key] == two[key]).all()), key
+    live = len(rows)
+    # (the int8 grid: a value that rounds the other way moves a key by a
+    # 127th of its row's largest, and the logits by what that is worth)
+    np.testing.assert_allclose(fused[:live], alone[:live],
+                               atol=2e-2 if quant else ATOL)
+    for key in two:
+        a, b = np.asarray(wrote[key]), np.asarray(two[key])
+        if a.dtype == np.int8:
+            assert np.abs(a.astype(np.int32) - b).max() <= 1, key
+        else:
+            np.testing.assert_allclose(a, b, atol=ATOL, err_msg=key)
+        changed = np.argwhere(a != np.asarray(cache[key]))
+        for slot, (held, pend, _dec) in enumerate(rows):
+            at = set(changed[changed[:, 1] == slot][:, 2])
+            assert at == (set(range(held, held + BLOCK)) if pend else set()), \
+                (key, slot)
+    # and the reference, which keeps its separate commit forward
+    for slot, (held, _pend, dec) in enumerate([] if quant else rows):
+        want = np.asarray(plain.denoise_logprobs(cfg, params, seqs[slot]))
+        first = held + BLOCK + dec
+        np.testing.assert_allclose(_logprobs(fused[slot]),
+                                   want[first:first + GROUP], atol=ATOL)
 
 
 def test_a_token_equal_to_the_mask_id_is_a_token(model):
     """Whether an offset is decided is kept by offset: a decided token that
     happens to be ``mask_token_id`` conditions the next group as itself
     (here it is the same embedding, so the check is that nothing else
-    changes), and an undecided offset's stale token is never read."""
+    changes), and an undecided offset's stale token is never read; nor is
+    the half before the block where nothing is pending."""
     cfg, params = model
     ids = _prompt(3, 16)
     cache = _chunk(cfg, params, init_kv_cache(cfg, ROWS, MAX_SEQ,
                                               jnp.float32), ids, 0, 0)
     at, dec = _row(16, 0, MAX_SEQ), _row(2, 0, 0)
-    a, _ = _block_step(cfg, params, cache, _row([7, 9, 11, 13], 0, 0), at,
-                       dec, kv_view=MAX_SEQ)
-    b, _ = _block_step(cfg, params, cache, _row([7, 9, 200, 201], 0, 0), at,
-                       dec, kv_view=MAX_SEQ)
+    none = jnp.zeros((ROWS,), bool)
+    stale = [1, 2, 3, 4]
+    a, _ = _block_step(cfg, params, cache, _row(stale + [7, 9, 11, 13], 0, 0),
+                       at, dec, none, kv_view=MAX_SEQ)
+    b, _ = _block_step(cfg, params, cache,
+                       _row([5, 6, 7, 8, 7, 9, 200, 201], 0, 0), at, dec,
+                       none, kv_view=MAX_SEQ)
     np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
-    c, _ = _block_step(cfg, params, cache, _row([7, 8, 11, 13], 0, 0), at,
-                       dec, kv_view=MAX_SEQ)
+    c, _ = _block_step(cfg, params, cache, _row(stale + [7, 8, 11, 13], 0, 0),
+                       at, dec, none, kv_view=MAX_SEQ)
     assert float(jnp.abs(a[0] - c[0]).max()) > 1e-3
 
 
@@ -284,8 +370,9 @@ def test_echo_runs_the_prompt_through_the_decode_passes():
 
 def test_rows_out_of_phase_get_what_they_get_alone():
     """(d) Rows admitted at different passes of each other's blocks, with
-    different remainders, echoed and not: one dispatch mixes denoise and
-    commit passes by row, and every row reads like the reference."""
+    different remainders, echoed and not: one dispatch mixes rows with a
+    block that awaits its commit and rows without, first and second passes,
+    and every row reads like the reference."""
     jobs = [(_prompt(60, 21), 12, False), (_prompt(61, 34), 9, False),
             (_prompt(62, 11), 8, True), (_prompt(63, 19), 11, False)]
     eng = _engine(decode_steps=3)
@@ -383,13 +470,16 @@ def test_counters_are_the_sums_of_the_records():
     """(f) ``engine_block_*_total``, ``engine_tokens_total`` and
     ``engine_kv_rows_full_total`` grow by what the ``engine.decode_burst``
     records of the same run add up to; a token is counted once it is
-    delivered, a pass whether it yields or not."""
+    delivered, a pass once whatever it carries.  The schedule: every pass
+    decides a group (none decides nothing), and about every other one
+    writes the block before on its way."""
     from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
 
     names = ("engine_block_row_passes_total",
              "engine_block_commit_row_passes_total",
              "engine_block_tokens_decided_total", "engine_tokens_total",
-             "engine_decode_row_steps_total")
+             "engine_decode_row_steps_total",
+             "engine_block_fused_commits_total")
     eng = _engine()
     jobs = [(_prompt(70, 18), 9, False), (_prompt(71, 35), 14, False),
             (_prompt(72, 9), 6, True)]
@@ -406,18 +496,27 @@ def test_counters_are_the_sums_of_the_records():
     passes = sum(a["row_passes_denoise"] + a["row_passes_commit"]
                  for a in bursts)
     assert grew[0] == passes
-    assert grew[1] == sum(a["row_passes_commit"] for a in bursts)
+    assert grew[1] == sum(a["row_passes_commit"] for a in bursts) == 0
     assert grew[2] == grew[3] == sum(a["tokens_decided"] for a in bursts)
     assert grew[2] == 9 + 14 + 6
+    assert grew[5] == sum(a["row_commits_fused"] for a in bursts)
+    # a block of 4 is written by the first of the 2 passes on the block
+    # after it, a row's first block has none behind it and its last is
+    # left unwritten: 18 + 9 tokens end in block 6 of blocks 4.., 35 + 14
+    # in block 12 of blocks 8.., the echoed 9 + 6 in block 3 of blocks 0..
+    assert grew[5] == 2 + 4 + 3
+    assert all(0 <= a["row_commits_fused"] <= a["row_passes_denoise"]
+               for a in bursts)
     # a real row's pass is a row-step of a dispatch; rows that ended inside
     # a burst stop being accounted, so the passes never exceed them
     assert 0 < passes <= grew[4] == sum(a["live_rows"] * a["steps"]
                                         for a in bursts)
-    # three passes fill four positions: a row's passes yield at most 4/3
-    assert grew[2] / passes <= BLOCK / 3
+    # two passes fill four positions: a row's passes yield at most 4/2
+    assert grew[2] / passes <= BLOCK / 2
     chunks = [r.attrs for r in records if r.name == "engine.prefill_segment"]
     assert kv == sum(a["kv_rows_full"] for a in bursts + chunks)
-    # every pass's 4 queries see base + 4 positions in each of 3 layers
+    # every pass's 4 queries of the current block see base + 4 positions in
+    # each of 3 layers (the block behind rides the same read)
     for a in bursts:
         assert a["kv_rows_full"] % (BLOCK * BLOCK * 3 * a["steps"]) == 0
         assert a["kv_rows_window"] == 0

@@ -862,17 +862,17 @@ def test_the_whole_hybrid_decodes_through_both_kernels_and_fits(chip):
 BD_ROWS, BD_SEQ = 49, 2048
 BD_PROGRAMS = {
     "block-decode-2048": lambda T, B, cfg, p, c, b: B.block_decode_step(
-        cfg, p, c, b["blk"], b["row49"], b["row49"], kv_view=2048,
-        with_stats=True),
+        cfg, p, c, b["blk"], b["row49"], b["row49"], b["flag49"],
+        kv_view=2048, with_stats=True),
     # (the branch a TPU backend takes: the grouped products as the repo's
     # kernel, ISSUE 39)
     "block-decode-on-the-chip": lambda T, B, cfg, p, c, b:
         B.block_decode_step(
             replace(cfg, flash_force=True), p, c, b["blk"], b["row49"],
-            b["row49"], kv_view=2048, with_stats=True),
+            b["row49"], b["flag49"], kv_view=2048, with_stats=True),
     "block-decode-256": lambda T, B, cfg, p, c, b: B.block_decode_step(
-        cfg, p, c, b["blk"], b["row49"], b["row49"], kv_view=256,
-        with_stats=True),
+        cfg, p, c, b["blk"], b["row49"], b["row49"], b["flag49"],
+        kv_view=256, with_stats=True),
     "chunk-512-at-2048": lambda T, B, cfg, p, c, b:
         T.chunk_prefill_into_cache(
             cfg, p, b["tok512"], b["row2"], b["row2"], c, b["row2"],
@@ -883,9 +883,10 @@ BD_PROGRAMS = {
 @pytest.mark.parametrize("program", sorted(BD_PROGRAMS))
 def test_the_block_programs_hold_the_cache_as_stated_and_fit_one_chip(
         chip, program):
-    """``sdar-30b-a3b-pp7s`` at the cell's size: the block decode pass and
-    chunk prefill (which returns no logits in this family: its head is dead
-    code).  The two planes are held at their stated bytes (49 x 2048 x
+    """``sdar-30b-a3b-pp7s`` at the cell's size: the block decode pass
+    (two blocks a row, the block that awaits its commit beside the current
+    one, and the ``pending`` flag: ISSUE 48) and chunk prefill (which
+    returns no logits in this family: its head is dead code).  The two planes are held at their stated bytes (49 x 2048 x
     14,336 B: 4 KV heads are not padded to a sublane tile), written where
     they lie (no plane-sized copy, both aliased to the donated buffers), and
     no layer's slice of the expert stacks is copied or converted before the
@@ -900,7 +901,8 @@ def test_the_block_programs_hold_the_cache_as_stated_and_fit_one_chip(
     params, cache = _share_shapes(chip, cfg, BD_ROWS, BD_SEQ)
     batch = _on(chip, {
         "row49": jax.ShapeDtypeStruct((BD_ROWS,), jnp.int32),
-        "blk": jax.ShapeDtypeStruct((BD_ROWS, 4), jnp.int32),
+        "flag49": jax.ShapeDtypeStruct((BD_ROWS,), jnp.bool_),
+        "blk": jax.ShapeDtypeStruct((BD_ROWS, 2 * 4), jnp.int32),
         "row2": jax.ShapeDtypeStruct((2,), jnp.int32),
         "tok512": jax.ShapeDtypeStruct((2, 512), jnp.int32)})
     compiled = jax.jit(
@@ -930,6 +932,44 @@ def test_the_block_programs_hold_the_cache_as_stated_and_fit_one_chip(
     held = (weights + planes + m.temp_size_in_bytes
             + m.output_size_in_bytes - m.alias_size_in_bytes + pool)
     assert held < 12.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
+
+
+def test_the_block_cell_plans_the_programs_it_planned_before_the_fusion():
+    """(ISSUE 48) A block's commit rides the first pass on the block after
+    it in the family's ONE decode program, at wider avals: the plan of the
+    cell ``sdar-30b-a3b.blockgen-closed`` keeps its 24 programs (5 views x
+    2 step counts of decode, 14 of chunk prefill; with the pool's two copy
+    programs the 26 that ``setup_programs`` reads there).  The plan follows
+    the engine's arguments and the block length, which ``tiny-sdar-moe``
+    shares with ``sdar-30b-a3b-pp7s``: the cell's arguments over the tiny
+    model's widths."""
+    import json
+
+    from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
+    from p2p_llm_tunnel_tpu.models.config import get_config
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                           "benchmarks", "configs",
+                           "sdar-30b-a3b.json")) as f:
+        serve = json.load(f)["serve"]
+    args = dict(zip(serve["args"][::2], serve["args"][1::2]))
+    tiny, cell = get_config("tiny-sdar-moe"), get_config(serve["model"])
+    assert (tiny.block_length, tiny.denoise_steps) == (
+        cell.block_length, cell.denoise_steps)
+    eng = InferenceEngine(engine_cfg=EngineConfig(
+        model="tiny-sdar-moe", max_seq=serve["max_seq"], mux=True,
+        prefix_cache=True, conv_cache=True,
+        num_slots=int(args["--slots"]),
+        prefix_pool_blocks=int(args["--prefix-pool-blocks"]),
+        prefill_chunk=int(args["--prefill-chunk"]),
+        prefill_rows=int(args["--prefill-rows"])))
+    plan = eng.warmup_plan()
+    assert len(plan) == len(set(plan)) == 24
+    decode = [shape for kind, shape in plan if kind == "decode"]
+    assert sorted(decode) == [(view, steps)
+                              for view in (128, 256, 512, 1024, 2048)
+                              for steps in (4, 8)]
+    assert {kind for kind, _ in plan} == {"decode", "chunk"}
 
 
 # ---------------------------------------------------------------------------
