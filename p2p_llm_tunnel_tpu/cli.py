@@ -228,7 +228,7 @@ def build_parser() -> argparse.ArgumentParser:
                             "quarters the long-context KV read term; int4 "
                             "composes with the prefix cache, chunked "
                             "prefill AND spec decode — byte-aligned pool "
-                            "pages + fused verify bursts leave /healthz "
+                            "pages + spliced verify bursts leave /healthz "
                             "config.fences empty)")
     serve.add_argument("--prefill-act-quant",
                        action=argparse.BooleanOptionalAction,
@@ -237,26 +237,6 @@ def build_parser() -> argparse.ArgumentParser:
                             "int8 too (2x MXU rate where prefill is "
                             "compute-bound); decode stays weight-only "
                             "(--no-prefill-act-quant overrides the env)")
-    serve.add_argument("--flash-decode",
-                       action=argparse.BooleanOptionalAction,
-                       default=_env("TUNNEL_FLASH_DECODE", "") == "1",
-                       help="use the Pallas decode-attention kernel on "
-                            "tileable shapes (--no-flash-decode overrides "
-                            "the env)")
-    serve.add_argument("--flash-sgrid",
-                       action=argparse.BooleanOptionalAction,
-                       default=_env("TUNNEL_FLASH_SGRID", "") == "1",
-                       help="with --flash-decode: the S-gridded kernel "
-                            "variant (per-block DMA, frontier-clamped "
-                            "fetches, no view cap)")
-    serve.add_argument("--fused-decode-layer",
-                       action=argparse.BooleanOptionalAction,
-                       default=_env("TUNNEL_FUSED_DECODE", "") == "1",
-                       help="fused decode-layer Pallas kernel: rope + "
-                            "new-row KV quant + in-place cache append + "
-                            "attention in ONE program per layer (collapses "
-                            "the per-step launch storm; composes with "
-                            "every --quant/--kv-quant)")
     serve.add_argument("--ragged-prefill",
                        action=argparse.BooleanOptionalAction,
                        default=_env("TUNNEL_RAGGED_PREFILL", "") == "1",
@@ -728,9 +708,6 @@ async def _engine_backend(args):
                     quant_group_size=args.quant_group_size,
                     kv_quant=args.kv_quant,
                     prefill_act_quant=args.prefill_act_quant,
-                    flash_decode=args.flash_decode,
-                    flash_sgrid=args.flash_sgrid,
-                    fused_decode_layer=args.fused_decode_layer,
                     prefix_cache=args.prefix_cache,
                     prefix_cache_dir=pfx_dir,
                     prefix_pool_blocks=args.prefix_pool_blocks,

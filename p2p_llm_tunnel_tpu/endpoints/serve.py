@@ -743,12 +743,7 @@ async def _send_healthz(
         "queue_depth": int(global_metrics.gauge("engine_queue_depth")),
         "slot_occupancy": global_metrics.gauge("engine_batch_occupancy"),
         "inflight_requests": inflight,
-        # ISSUE 4 observability: the decode program's launch profile and
-        # the warmup compile bill — fused-path regressions show up here
-        # on any host (0 = probe unavailable on this host).
-        "decode_kernels_per_step": int(
-            global_metrics.gauge("engine_decode_kernels_per_step")
-        ),
+        # ISSUE 4 observability: the warmup compile bill.
         "warmup_compile_s": round(
             global_metrics.gauge("engine_warmup_compile_s"), 1
         ),
@@ -775,7 +770,7 @@ async def _send_healthz(
         "prefix_dedup_hits": int(
             global_metrics.counter("engine_prefix_dedup_hits_total")
         ),
-        # ISSUE 17 observability: the fused speculative-decode ledger —
+        # ISSUE 17 observability: the speculative-decode ledger —
         # lifetime proposed/accepted verify tokens, the windowed (last-64
         # bursts) acceptance rate the adaptive-K controller steers on, and
         # the draft-history registry size (nonzero at rest is a leak;
@@ -814,7 +809,7 @@ async def _send_healthz(
         },
         # ISSUE 14 observability: the composition-fence registry — every
         # knob the engine auto-disabled at startup, with its reason.  The
-        # hero configuration (int4 + kv-int4 + fused + mux + prefix)
+        # hero configuration (int4 + kv-int4 + spec + mux + prefix)
         # reports an EMPTY list here; operators verify it fleet-wide via
         # the proxy's federated /healthz view.
         # ``attention``: which implementation (Pallas kernel or einsum)

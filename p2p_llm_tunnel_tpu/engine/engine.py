@@ -46,14 +46,12 @@ from p2p_llm_tunnel_tpu.models.moe import grouped_product_branch
 from p2p_llm_tunnel_tpu.models.transformer import (
     decode_attention_branch,
     decode_branch_coverage,
-    decode_kernel_decline,
     decode_step,
     init_kv_cache,
     init_params,
     prefill_attention_branch,
     prefill_into_cache,
     reads_expert_stack,
-    spec_attention_branch,
 )
 from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import ELEMENTWISE
 from p2p_llm_tunnel_tpu.utils.flight import (
@@ -216,33 +214,16 @@ class EngineConfig:
     # per-token-per-head scales — quarters the KV stream).  Halves (or
     # quarters) the KV read term that dominates long-context decode HBM
     # traffic; dequant fuses into the einsum operand read or runs in VMEM
-    # inside the Pallas kernels.  Since ISSUE 14 the prefix cache and
+    # inside the ragged prefill kernel.  Since ISSUE 14 the prefix cache and
     # chunked prefill COMPOSE with int4: every pool page and chunk start
     # is forced to an even (two-tokens-per-byte) boundary, so packed
     # writes cover whole bytes.  Since ISSUE 17 spec_ngram composes too —
-    # verify bursts splice covering bytes (quant.splice_packed_rows /
-    # the fused spec kernel's resident-byte append), so the
-    # ``config_fences`` registry is EMPTY.
+    # verify bursts splice covering bytes (quant.splice_packed_rows), so
+    # the ``config_fences`` registry is EMPTY.
     kv_quant: str = "none"
-    # Use the Pallas decode-attention kernel on TPU-tileable shapes
-    # (models/config.py flash_decode).  Off by default pending on-hardware
-    # measurement; correctness is oracle-pinned (tests/test_pallas_decode).
-    flash_decode: bool = False
-    # S-gridded flash decode (models/config.py flash_sgrid): per-block DMA
-    # with frontier-clamped fetches (VERDICT r4 item 2).  As of ISSUE 4,
-    # flash_decode and flash_sgrid both select the s-grid family.
-    flash_sgrid: bool = False
-    # Fused decode-layer Pallas kernel (ISSUE 4): one program per layer
-    # fuses rope + new-row KV quantization + the cache append + the
-    # frontier-clamped attention, collapsing the per-step launch storm
-    # (~4k launches per 32-layer × 16-step burst).  Composes with every
-    # kv_quant mode and weight quant in one program.  Off by default
-    # until chip-measured; oracle-pinned in tests/test_fused_decode_layer.
-    fused_decode_layer: bool = False
-    # Ragged grouped flash-prefill kernel (ISSUE 15): the prefill twin of
-    # fused_decode_layer.  Every chunk-prefill dispatch — mux segment
-    # sub-batches AND prefix-cache tails — packs the group's variable-
-    # length tail segments into ONE flat-token Pallas launch
+    # Ragged grouped flash-prefill kernel (ISSUE 15).  Every chunk-prefill
+    # dispatch — mux segment sub-batches AND prefix-cache tails — packs the
+    # group's variable-length tail segments into ONE flat-token Pallas launch
     # (ops/pallas_prefill_attention.py): per-block (slot, start, len)
     # descriptors ride scalar prefetch, rope + KV quantization run in
     # VMEM, the cache append is an aliased in-place write, and the
@@ -529,22 +510,11 @@ class InferenceEngine(BlockDecodeMixin):
             self.mcfg = dc_replace(
                 self.mcfg, mask_token_id=self.mcfg.vocab_size - 1)
         self._refuse_unsupported()
-        # flash_sgrid IMPLIES flash_decode (it selects the kernel variant):
-        # the bench applies the same implication, so the benched and served
-        # configs agree for a lone --flash-sgrid / TUNNEL_FLASH_SGRID=1.
-        if ((self.ecfg.flash_decode or self.ecfg.flash_sgrid)
-                and not self.mcfg.flash_decode):
-            self.mcfg = dc_replace(self.mcfg, flash_decode=True)
-        if self.ecfg.flash_sgrid and not self.mcfg.flash_sgrid:
-            self.mcfg = dc_replace(self.mcfg, flash_sgrid=True)
-        # Same one-directional promotion for the fused decode-layer kernel.
-        if self.ecfg.fused_decode_layer and not self.mcfg.fused_decode_layer:
-            self.mcfg = dc_replace(self.mcfg, fused_decode_layer=True)
         if self.ecfg.sp_mode not in ("ring", "ulysses"):
             raise ValueError(f"unknown sp_mode {self.ecfg.sp_mode!r}")
         if self.ecfg.sp_mode != "ring" and self.mcfg.sp_mode != self.ecfg.sp_mode:
-            # One-directional like flash_decode: a non-default EngineConfig
-            # choice promotes into the model config, but an explicitly
+            # One-directional: a non-default EngineConfig choice
+            # promotes into the model config, but an explicitly
             # ulysses model_cfg is never silently reverted to ring.
             self.mcfg = dc_replace(self.mcfg, sp_mode=self.ecfg.sp_mode)
         if jax.default_backend() == "tpu" and self.mcfg.flash_interpret:
@@ -780,8 +750,6 @@ class InferenceEngine(BlockDecodeMixin):
             fair=self.ecfg.fair_admission,
         )
 
-        self._fence_declined_decode_kernels()
-
         if self.ecfg.prefill_chunk > 0 and self.ecfg.sp > 1:
             # Same scope limit as the prefix cache below: the chunk-prefill
             # program has no sequence-parallel attention path, and silently
@@ -814,7 +782,7 @@ class InferenceEngine(BlockDecodeMixin):
             # Page-alignment pass (ISSUE 14), AFTER the mux default above
             # so the EFFECTIVE chunk width is what gets rounded: packed
             # int4 segment writes must cover whole bytes.
-            from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+            from p2p_llm_tunnel_tpu.models.quant import (
                 INT4_PACK_TOKENS,
                 page_alignment_violations,
             )
@@ -1260,13 +1228,12 @@ class InferenceEngine(BlockDecodeMixin):
         # of max_seq; the two burst sizes trade throughput (big) against
         # admission latency (small, used while requests wait).
         # (the decode program, the carry it donates, its static view and
-        # steps: decode_launch_report lowers the same)
-        self._decode_program = (
+        # steps)
+        decode_fn, donated, static = (
             (self._block_decode_fn, (1, 2, 3, 4, 5, 6), (15, 16))
             if self._block else (self._decode_fn, (1, 2, 3, 4), (11, 12)))
         self._jit_decode = jax.jit(
-            self._decode_program[0], donate_argnums=self._decode_program[1],
-            static_argnums=self._decode_program[2],
+            decode_fn, donate_argnums=donated, static_argnums=static,
         )
         self._jit_prefill = jax.jit(
             self._prefill_fn, donate_argnums=(1,), static_argnums=(8,)
@@ -1613,7 +1580,7 @@ class InferenceEngine(BlockDecodeMixin):
         k = t - 1
         logits, kv_cache = spec_verify_into_cache(
             self.mcfg, params, tokens, positions, kv_cache,
-            kv_view=kv_view, mesh=self.mesh,
+            kv_view=kv_view,
         )  # [B, t, V]
         if samp.bias_on is not None:
             logits = jax.lax.cond(
@@ -1858,96 +1825,8 @@ class InferenceEngine(BlockDecodeMixin):
             "engine_warmup_compile_s", time.monotonic() - t_warm0
         )
         self._warmup_done = True
-        with global_compile_watch.startup_phase(
-                "startup.launch_probe") as attrs:
-            report = await loop.run_in_executor(
-                self._executor, self._set_kernel_gauge)
-            if report is not None:
-                attrs["pallas_calls"] = report["layer_body_pallas"]
         global_compile_watch.listen(False)
         global_compile_watch.add_span("startup.warmup", t0=t_warm0)
-
-    def decode_launch_report(self, view: Optional[int] = None,
-                             steps: Optional[int] = None):
-        """Launch-proxy counts of the decode-burst program, counted on the
-        REAL TPU lowering (cross-lowered from any host — utils/hlo.py), or
-        None when this host cannot lower it.
-
-        Host-side lowering only, nothing executes.  The engine's mcfg is
-        momentarily swapped for a lowering-only variant (interpret off,
-        flash_force on) so the counted program is the one a TPU backend
-        would run even when this process serves the CPU/interpret path;
-        callers are single-threaded by construction (warmup before
-        serving).  The ONE home of the jit-signature + warm-args recipe:
-        a second hand-rolled copy is the TC02 stale-signature incident
-        class.
-        """
-        self._ensure_decode_carry()
-        old = self.mcfg
-        self.mcfg = dc_replace(
-            self.mcfg, flash_interpret=False, flash_force=True
-        )
-        try:
-            from p2p_llm_tunnel_tpu.utils.hlo import (
-                decode_launch_report as _report,
-            )
-
-            return _report(
-                jax.jit(self._decode_program[0],
-                        static_argnums=self._decode_program[2]),
-                *self._decode_warm_args(
-                    self._warmup_views()[0] if view is None else view,
-                    self.ecfg.decode_steps if steps is None else steps,
-                ),
-            )
-        finally:
-            self.mcfg = old
-
-    def spec_launch_report(self, view: Optional[int] = None,
-                           k: Optional[int] = None):
-        """Launch-proxy counts of the spec-verify program on the REAL TPU
-        lowering — :meth:`decode_launch_report`'s twin for the fused
-        K-token verify burst (ISSUE 17).  The layer-body claim the PERF.md
-        launch table and test_fused_spec_decode assert: ONE custom call
-        per layer for the whole K+1-position burst, vs K+1 separate
-        decode launches."""
-        old = self.mcfg
-        self.mcfg = dc_replace(
-            self.mcfg, flash_interpret=False, flash_force=True
-        )
-        try:
-            from p2p_llm_tunnel_tpu.utils.hlo import (
-                decode_launch_report as _report,
-            )
-
-            return _report(
-                jax.jit(self._spec_verify_fn, static_argnums=(6,)),
-                *self._spec_warm_args(
-                    self._warmup_views()[0] if view is None else view,
-                    self.ecfg.spec_k if k is None else k,
-                ),
-            )
-        finally:
-            self.mcfg = old
-
-    def _set_kernel_gauge(self) -> Optional[Dict[str, int]]:
-        """Publish ``engine_decode_kernels_per_step``: launch-proxy major
-        kernels in the layer-scan body of the decode burst
-        (:meth:`decode_launch_report`, which this returns)."""
-        report = self.decode_launch_report()
-        if report is None or not report["layer_body_major"]:
-            log.info("decode launch-count probe unavailable on this host")
-            return None
-        global_metrics.set_gauge(
-            "engine_decode_kernels_per_step", report["layer_body_major"]
-        )
-        log.info(
-            "decode burst launch profile: %d major kernels per layer-step "
-            "(%d ops; %d pallas calls)",
-            report["layer_body_major"], report["layer_body_ops"],
-            report["layer_body_pallas"],
-        )
-        return report
 
     def _note_program(self, kind: str, shape: Tuple[int, ...],
                       seconds: float) -> None:
@@ -2002,24 +1881,24 @@ class InferenceEngine(BlockDecodeMixin):
             return decode_attention_branch(
                 self.mcfg, self.mesh, shape[0], self._kv_quant_mode(),
                 self.ecfg.max_seq)
-        if kind == "spec":
-            return spec_attention_branch(self.mcfg, self.mesh, shape[0])
         if kind in ("prefill", "prefill_echo"):
             return prefill_attention_branch(
                 self._prefill_mcfg, self.mesh, shape[0]
             )
         if kind == "ragged":
             return "pallas-ragged"
-        return "einsum"  # chunk: ops.attention.history_attention
+        # chunk, and spec (a verify burst is a chunk prefill):
+        # ops.attention.history_attention
+        return "einsum"
 
     def _refuse_unsupported(self) -> None:
         """What a family served by a module of its own (latent attention:
         models/mla.py; window rings beside full planes: models/swa.py) does
         not have yet is refused at start-up, by name, instead of served
         wrongly: its weights have no quantiser (experts: models/quant.py),
-        its layers no mesh rules (parallel/), and the Pallas kernels behind
-        options, the ragged prefill and the speculative verify read one
-        plane of KV heads whose keys and values are equally wide."""
+        its layers no mesh rules (parallel/), and the ragged prefill and
+        the speculative verify read one plane of KV heads whose keys and
+        values are equally wide."""
         if self.mcfg.mixer_pattern is not None:
             what = ("a recurrent state beside the KV planes, "
                     + ("routed experts" if self.mcfg.n_experts
@@ -2038,10 +1917,6 @@ class InferenceEngine(BlockDecodeMixin):
             (e.kv_quant == "int4", "--kv-quant int4"),
             (e.tp > 1, f"--tp {e.tp}"), (e.sp > 1, f"--sp {e.sp}"),
             (e.ep > 1, f"--ep {e.ep}"),
-            (e.flash_decode or e.flash_sgrid or self.mcfg.flash_decode,
-             "--flash-decode"),
-            (e.fused_decode_layer or self.mcfg.fused_decode_layer,
-             "--fused-decode-layer"),
             (e.ragged_prefill, "--ragged-prefill"),
             (e.spec_ngram > 0, "--spec-ngram"),
             (bool(e.ckpt_path), "--ckpt (no converter for this family)"),
@@ -2061,8 +1936,8 @@ class InferenceEngine(BlockDecodeMixin):
             raise ValueError(
                 f"model {self.mcfg.name!r} ({what}) cannot be served with "
                 f"{', '.join(refused)}: "
-                "serve it with --quant none on one chip, without the Pallas "
-                "decode kernels, the ragged prefill or speculative decoding"
+                "serve it with --quant none on one chip, without the "
+                "ragged prefill or speculative decoding"
             )
 
     def _mux_default_chunk(self) -> int:
@@ -2170,38 +2045,6 @@ class InferenceEngine(BlockDecodeMixin):
             if e.prefill_chunk > 0 else None,
         }}
 
-    def _fence_declined_decode_kernels(self) -> None:
-        """An option that asked for a Pallas decode kernel (flash_decode /
-        flash_sgrid / fused_decode_layer — the fused spec verify rides the
-        last) must not give way to the einsum silently: where the model
-        layer's gate declines ANY view bucket this engine dispatches, the
-        option is fenced off whole, with the gate's reason, so one engine
-        never serves a mix.  Off the TPU the kernels run only in interpret
-        mode (CPU tests); a plain CPU rehearsal takes the einsum and the
-        log says so."""
-        asked = [
-            knob for knob in
-            ("flash_decode", "flash_sgrid", "fused_decode_layer")
-            if getattr(self.mcfg, knob)
-        ]
-        if not asked:
-            return
-        if not (jax.default_backend() == "tpu" or self.mcfg.flash_interpret):
-            log.info(
-                "%s asked for Pallas decode kernels; backend %r runs the "
-                "einsum path (kernels need the TPU or interpret mode)",
-                "/".join(asked), jax.default_backend(),
-            )
-            return
-        for view in self._view_buckets():
-            why = decode_kernel_decline(self.mcfg, self.mesh, view)
-            if why is None:
-                continue
-            for knob in asked:
-                self._fence(knob, False, why)
-            self.mcfg = dc_replace(self.mcfg, **{k: False for k in asked})
-            return
-
     def _blackbox_state(self) -> dict:
         """Engine section of a postmortem bundle (ISSUE 12): config +
         scheduler/slot/backlog state as plain JSON-able values.  Pure host
@@ -2293,7 +2136,7 @@ class InferenceEngine(BlockDecodeMixin):
             ("decode", (v, k)) for v in decode_views for k in sorted(steps)
         ]
         if self.ecfg.spec_ngram > 0:
-            # One fused verify program per (view, burst width): adaptive K
+            # One verify program per (view, burst width): adaptive K
             # walks the power-of-two ladder (_spec_k_buckets), so every
             # rung must be compiled up front or the first low-acceptance
             # slot cold-compiles mid-serve (pinned by test_warmup_aot's

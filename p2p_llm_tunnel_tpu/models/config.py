@@ -64,31 +64,15 @@ class ModelConfig:
     # Run the flash kernel in Pallas interpret mode even off-TPU — CPU-mesh
     # tests of the shard_map'd kernel path set this.
     flash_interpret: bool = False
-    # Assume the TPU backend when gating Pallas decode kernels, WITHOUT
-    # interpret mode: for cross-platform LOWERING only (the launch-count
-    # probe lowers the real TPU program from a CPU host — utils/hlo.py).
-    # A program traced with this set must never execute off-TPU.
+    # Assume the TPU backend when gating the Pallas decode kernels, WITHOUT
+    # interpret mode: for cross-platform LOWERING only (tests lower and
+    # compile the real TPU program from a CPU host).  A program traced with
+    # this set must never execute off-TPU.
     flash_force: bool = False
     # W8A8: quantize activations dynamically (per-token int8) so QTensor
     # matmuls run as native int8×int8 MXU dots — set by the engine when
     # EngineConfig.quant == "w8a8".  Inert for non-quantized params.
     act_quant: bool = False
-    # Use the Pallas decode-attention kernel (ops/pallas_decode_attention)
-    # for slot decode when the backend is TPU and shapes tile (view and
-    # head_dim % 128 == 0).  Off by default: the einsum path is the oracle;
-    # flip on once measured faster for the target config.
-    flash_decode: bool = False
-    # The S-gridded variant (per-block DMA, frontier skips the fetch too,
-    # no view-size cap).  As of ISSUE 4 both flags route to it.
-    flash_sgrid: bool = False
-    # Fused decode-layer Pallas kernel (ISSUE 4): one program per layer
-    # performs rope + new-row KV quantization + the cache append (in-place
-    # aliased row write) + frontier-clamped flash attention, collapsing
-    # the 6-8 XLA kernels the unfused path launches per layer per step.
-    # Composes with every kv_quant mode and every weight quant.  Off by
-    # default until chip-measured; correctness is oracle-pinned
-    # (tests/test_fused_decode_layer.py).
-    fused_decode_layer: bool = False
     # Sequence-parallel strategy when the mesh has sp > 1:
     # "ring"    — K/V blocks rotate via ppermute (bandwidth-optimal on the
     #             ICI ring; no sliding-window support)

@@ -30,7 +30,7 @@ BASELINE.md's "Llama-3 8B on v5e-1" config.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -130,6 +130,67 @@ def unpack_int4(packed: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
         packed.shape[:axis] + (2 * packed.shape[axis],) + packed.shape[axis + 1:]
     )
     return jnp.stack([lo, hi], axis=axis + 1).reshape(out_shape)
+
+
+#: Tokens per byte along the packed int4 sequence axis — THE packing
+#: constant the page-alignment contract (ISSUE 14) is a multiple of.
+#: A single decode token lands mid-byte through ``append_packed_token``'s
+#: covering-byte merge, but bulk writers — chunk-prefill segments, pool
+#: page copies — must land on whole bytes: the engine keeps pool pages and
+#: chunk widths multiples of this.
+INT4_PACK_TOKENS = 2
+
+
+def page_alignment_violations(kv_quant: Optional[str], page_tokens: int,
+                              chunk_tokens: int) -> list:
+    """The ONE spelling of the ISSUE 14 block-page alignment rule, kept
+    beside the packed-byte layout it protects: under
+    ``kv_quant="int4"`` the pool page size and the chunk-prefill segment
+    width must both be multiples of :data:`INT4_PACK_TOKENS`, so every
+    chunk start (a page or segment multiple) and every page copy covers
+    whole bytes — misalignment would silently corrupt the neighbouring
+    nibble's token.  Returns human-readable violation strings (empty =
+    aligned); the engine turns them into config fences at startup."""
+    if kv_quant != "int4":
+        return []
+    out = []
+    if page_tokens % INT4_PACK_TOKENS:
+        out.append(
+            f"pool page size {page_tokens} is not a multiple of the int4 "
+            f"packing ({INT4_PACK_TOKENS} tokens/byte)"
+        )
+    if chunk_tokens > 0 and chunk_tokens % INT4_PACK_TOKENS:
+        out.append(
+            f"chunk segment width {chunk_tokens} is not a multiple of the "
+            f"int4 packing ({INT4_PACK_TOKENS} tokens/byte)"
+        )
+    return out
+
+
+def _nibbles_i32(p):
+    """Packed int4 bytes -> (low, high) sign-extended nibbles as int32.
+    The shifts run in int32: Mosaic does not legalize ``arith.shli`` on
+    int8 vectors.  Values are identical to the int8 arithmetic shifts of
+    :func:`unpack_int4`."""
+    p32 = p.astype(jnp.int32)
+    return (jnp.right_shift(jnp.left_shift(p32, 28), 28),
+            jnp.right_shift(p32, 4))
+
+
+def unpack_seq(p):
+    """[N/2, ...] packed bytes -> [N, ...] int32 values in [-8, 7]: token
+    2i from the low nibble, 2i+1 from the high one.  The form a Pallas
+    kernel reads a packed block with (ops/pallas_prefill_attention.py)."""
+    lo, hi = _nibbles_i32(p)
+    return jnp.stack([lo, hi], axis=1).reshape(
+        (2 * p.shape[0],) + p.shape[1:]
+    )
+
+
+def pack_byte(lo, hi):
+    """int32 nibble values -> one int8 byte (:func:`pack_int4`'s layout:
+    low nibble = even token), a kernel's in-register packing."""
+    return (jnp.left_shift(hi, 4) | (lo & 0x0F)).astype(jnp.int8)
 
 
 # ---------------------------------------------------------------------------

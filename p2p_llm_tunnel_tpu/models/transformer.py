@@ -186,8 +186,8 @@ def _quant_kv(x: jnp.ndarray):
 def _quant_kv4(x: jnp.ndarray):
     """Symmetric int4 over the trailing head_dim axis → (q in [-7, 7] as
     int8 VALUES — caller packs — and per-(token, head) scale).  The same
-    formula the fused decode kernel applies in VMEM: any drift between
-    the two breaks fused/unfused token identity."""
+    formula the ragged prefill kernel applies in VMEM: any drift between
+    the two breaks ragged/chunk token identity."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, 1e-8) / 7.0
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -7, 7)
@@ -312,9 +312,9 @@ def _proj(cfg: ModelConfig, x, w):
 
 
 def _qkv_proj(cfg: ModelConfig, blk, h):
-    """QKV projections + bias + head split, NO rope — the fused decode
-    kernel applies rope in VMEM at each slot's position, so the decode
-    fused path consumes these directly."""
+    """QKV projections + bias + head split, NO rope — the ragged prefill
+    kernel applies rope in VMEM at each token's position, so
+    ``ragged_prefill_into_cache`` consumes these directly."""
     b, t, _ = h.shape
     q = _proj(cfg, h, blk["wq"])
     k = _proj(cfg, h, blk["wk"])
@@ -405,15 +405,12 @@ def prefill_attention_branch(cfg: ModelConfig, mesh, t: int) -> str:
 
 
 def decode_kernel_decline(cfg: ModelConfig, mesh, kv_view: int):
-    """Why the Pallas decode kernels (s-grid, fused decode layer, fused
-    spec verify) cannot serve this (config, mesh, view) — ``None`` when
-    they can.  The ONE gate ``decode_step`` and ``spec_verify_into_cache``
-    share; the engine turns a non-backend reason into a ``config_fences``
-    entry at startup, so an option that asked for a kernel never gives way
-    to the einsum silently.
+    """Why the Pallas decode kernel (``decode_attention_rows``) cannot serve
+    this (config, mesh, view) — ``None`` when it can.  The gate
+    ``decode_attention_branch`` asks:
 
-    - off the TPU backend the kernels run only in interpret mode (CPU
-      tests) or under ``flash_force`` (lowering-only probes);
+    - off the TPU backend the kernel runs only in interpret mode (CPU
+      tests) or under ``flash_force`` (lowering-only tests);
     - tp>1 declines: pallas_call is not GSPMD-partitioned, so under a tp
       mesh XLA would all-gather the sharded q/KV onto every chip (the
       hazard prefill's flash_tp shard_map wrapper exists for — apply the
@@ -452,28 +449,21 @@ def decode_kernel_decline(cfg: ModelConfig, mesh, kv_view: int):
 def decode_attention_branch(cfg: ModelConfig, mesh, kv_view: int,
                             kv_quant: Optional[str] = None,
                             max_seq: Optional[int] = None) -> str:
-    """Which attention implementation ``decode_step`` takes at this view
-    over a cache of precision ``kv_quant`` and ``max_seq`` positions (the
-    view's, where not given): ``"pallas-fused-decode-layer"`` or
-    ``"pallas-sgrid"`` where an option asks (flash_decode / flash_sgrid
-    both route to the s-grid family), else ``"pallas-rows"`` wherever it
-    can run — by what the code can observe, no option: the gate below
-    passes for the whole cache, which is what that kernel reads (the view
-    bounds nothing there), and the cache is plain planes of KV heads (the
-    int8 and int4 caches and the latent family keep the einsum) — else
-    ``"einsum"``.  ``cfg.flash`` off is the einsum everywhere, as in
-    prefill (the reference a kernel is held against).
+    """Which attention ``decode_step`` takes at this view over a cache of
+    precision ``kv_quant`` and ``max_seq`` positions (the view's, where not
+    given).  The one place that chooses, and the ``if`` below is the whole
+    rule: ``"pallas-rows"`` wherever that kernel can run — by what the code
+    can observe, no option: the gate passes for the whole cache, which is
+    what the kernel reads (the view bounds nothing there), and the cache is
+    plain planes of KV heads (the int8 and int4 caches, the latent family
+    and generation by blocks keep the einsum) — else ``"einsum"``.
+    ``cfg.flash`` off is the einsum everywhere, as in prefill (the
+    reference a kernel is held against).
 
     In a model with an ``attn_pattern`` (models/swa.py) the answer is the
     FULL layers': their planes are what follows a view.  A window layer
     reads its whole ring by einsum under either answer
     (:func:`decode_branch_coverage`)."""
-    if (cfg.attn_pattern is None
-            and decode_kernel_decline(cfg, mesh, kv_view) is None):
-        if cfg.fused_decode_layer:
-            return "pallas-fused-decode-layer"
-        if cfg.flash_decode or cfg.flash_sgrid:
-            return "pallas-sgrid"
     if (cfg.flash and kv_quant is None and not cfg.kv_lora_rank
             and not cfg.block_length  # models/block_decode.py: an einsum
             and decode_kernel_decline(cfg, mesh, max_seq or kv_view) is None):
@@ -488,15 +478,6 @@ def decode_branch_coverage(cfg: ModelConfig, branch: str) -> str:
     if cfg.attn_pattern is None or branch == "einsum":
         return branch
     return f"{branch} (full layers; window layers: einsum over the ring)"
-
-
-def spec_attention_branch(cfg: ModelConfig, mesh, kv_view: int) -> str:
-    """``spec_verify_into_cache``'s branch: the fused K-token verify kernel
-    or the chunk-prefill einsum."""
-    if (cfg.fused_decode_layer
-            and decode_kernel_decline(cfg, mesh, kv_view) is None):
-        return "pallas-fused-spec"
-    return "einsum"
 
 
 def _prefill_attention_fn(cfg: ModelConfig, mesh, t: int):
@@ -1071,92 +1052,32 @@ def spec_verify_into_cache(
     positions: jnp.ndarray,  # [B] global position of tokens[:, 0]
     kv_cache: KVCache,
     kv_view: Optional[int] = None,  # static: attend only to cache[:kv_view]
-    mesh=None,  # Mesh when params/cache are sharded (gates the fused path)
 ) -> Tuple[jnp.ndarray, KVCache]:
     """Speculative draft-verify burst: T = 1 + K positions per slot in ONE
-    forward pass (ISSUE 17).
+    forward pass (ISSUE 17), so a verify burst costs one weight-stream pass
+    instead of T decode steps.  Rejected-tail KV is junk PAST every accepted
+    position, rewritten by the row's next burst before any query can attend
+    it (all masks are strictly ``< pos``), so acceptance needs no cache
+    rollback.
 
-    The fused path runs ``ops.pallas_decode_attention.fused_spec_decode_layer``
-    — ONE Pallas launch per layer covering rope + KV quant + whole-byte
-    cache append + frontier-clamped flash over the cache prefix with the
-    burst's own rows substituted causally — so a verify burst costs one
-    weight-stream pass instead of T decode launches (the PR 4/15 launch
-    arithmetic, K-fold).  Its token streams are bitwise those of T
-    sequential ``fused_decode_layer`` steps (tests/test_fused_spec_decode);
-    rejected-tail KV is junk PAST every accepted position, rewritten by the
-    row's next burst before any query can attend it (all masks are strictly
-    ``< pos``), so acceptance needs no cache rollback.
-
-    The fallback (no TPU/interpret, tp>1, or fused disabled) is the chunk
-    prefill path with ``unaligned_int4=True``: spec starts are arbitrary
-    token positions, so packed int4 writes ride ``quant.splice_packed_rows``
-    (covering-byte gather → nibble merge → whole-byte scatter) instead of
-    the page-aligned scatter — the write discipline that lets spec_ngram
-    run under ``kv-int4`` with the ``config_fences`` registry EMPTY.
+    It is the chunk prefill path with ``unaligned_int4=True``: spec starts
+    are arbitrary token positions, so packed int4 writes ride
+    ``quant.splice_packed_rows`` (covering-byte gather → nibble merge →
+    whole-byte scatter) instead of the page-aligned scatter — the write
+    discipline that lets spec_ngram run under ``kv-int4`` with the
+    ``config_fences`` registry EMPTY.
 
     Inactive slots park at ``positions >= kv_view`` and compute junk
     (gathers clamp, scatters drop), masked by the engine.  Returns
     (logits [B, T, V], updated cache).
     """
     b, t = tokens.shape
-    quant_mode = kv_cache_quant_mode(kv_cache)
-    quant = quant_mode is not None
-    s = kv_cache["k"].shape[2] * (2 if quant_mode == "int4" else 1)
-    if kv_view is None or kv_view > s:
-        kv_view = s
-    if spec_attention_branch(cfg, mesh, kv_view) == "einsum":
-        lengths = jnp.full((b,), t, jnp.int32)
-        return chunk_prefill_into_cache(
-            cfg, params, tokens, lengths, positions, kv_cache,
-            jnp.arange(b), kv_view=kv_view, return_all_logits=True,
-            unaligned_int4=True,
-        )
-
-    from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
-        fused_spec_decode_layer,
+    lengths = jnp.full((b,), t, jnp.int32)
+    return chunk_prefill_into_cache(
+        cfg, params, tokens, lengths, positions, kv_cache,
+        jnp.arange(b), kv_view=kv_view, return_all_logits=True,
+        unaligned_int4=True,
     )
-
-    x = _embed(cfg, params, tokens)  # [B,T,Dm]
-    layer_idx = jnp.arange(cfg.n_layers)
-
-    def step(carry, xs):
-        x, cache = carry
-        blk, idx = xs
-        h = _norm(cfg, x, blk["attn_norm"])
-        q, k, v = _qkv_proj(cfg, blk, h)  # PRE-rope: kernel ropes the burst
-        attn, ck, cv, k_s, v_s = fused_spec_decode_layer(
-            q, k, v,
-            cache["k"], cache["v"],
-            cache.get("k_scale"), cache.get("v_scale"),
-            positions, idx,
-            kv_view=kv_view,
-            rope_theta=cfg.rope_theta,
-            kv_quant=quant_mode,
-            scale=cfg.query_scale,
-            softcap=cfg.attn_softcap,
-            window=_layer_window(cfg, idx, s),
-            interpret=cfg.flash_interpret,
-        )
-        cache = dict(cache)
-        cache["k"], cache["v"] = ck, cv
-        if quant:
-            cache["k_scale"], cache["v_scale"] = k_s, v_s
-        attn = mm(attn.reshape(b, t, -1), blk["wo"], cfg.act_quant)
-        if cfg.post_norms:
-            attn = _norm(cfg, attn, blk["post_attn_norm"])
-        x = x + attn
-        h = _norm(cfg, x, blk["mlp_norm"])
-        mlp, _ = _mlp(cfg, blk, h)
-        if cfg.post_norms:
-            mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
-        x = x + mlp
-        return (x, cache), None
-
-    (x, new_cache), _ = jax.lax.scan(
-        step, (x, dict(kv_cache)), (params["blocks"], layer_idx)
-    )
-    x = _norm(cfg, x, params["final_norm"])
-    return _logits(cfg, params, x), new_cache  # [B,T,V]
 
 
 def ragged_prefill_into_cache(
@@ -1277,8 +1198,7 @@ def decode_step(
 ):
     """One decode step over every slot. Returns (logits [B,V], new cache),
     and under ``with_stats`` what the routed layers counted (models/moe.py
-    STATS) of the rows that are not parked at ``positions >= S``; the Pallas
-    decode paths count nothing.
+    STATS) of the rows that are not parked at ``positions >= S``.
 
     Static shapes throughout: inactive slots still compute (masked out by the
     engine when sampling) — the XLA-friendly cost of continuous batching.
@@ -1319,63 +1239,8 @@ def decode_step(
     layer_idx = jnp.arange(cfg.n_layers)
     slot_ids = jnp.arange(b)
 
-    # Pallas gating beyond the config flags: decode_kernel_decline.
-    branch = decode_attention_branch(cfg, mesh, kv_view, quant_mode, s)
-    # The FUSED decode-layer kernel (ISSUE 4): rope + new-row quant +
-    # cache append + frontier-clamped attention in one program per layer.
-    # Supersedes the flash selection further below when enabled.
-    if branch == "pallas-fused-decode-layer":
-        from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
-            fused_decode_layer,
-        )
-
-        def step(carry, xs):
-            x, cache = carry
-            blk, idx = xs
-            h = _norm(cfg, x, blk["attn_norm"])
-            q, k, v = _qkv_proj(cfg, blk, h)  # PRE-rope: kernel ropes
-            attn, ck, cv, k_s, v_s = fused_decode_layer(
-                q[:, 0], k[:, 0], v[:, 0],
-                cache["k"], cache["v"],
-                cache.get("k_scale"), cache.get("v_scale"),
-                positions, idx,
-                kv_view=kv_view,
-                rope_theta=cfg.rope_theta,
-                kv_quant=quant_mode,
-                scale=cfg.query_scale,
-                softcap=cfg.attn_softcap,
-                window=_layer_window(cfg, idx, s),
-                interpret=cfg.flash_interpret,
-            )
-            cache = dict(cache)
-            cache["k"], cache["v"] = ck, cv
-            if quant:
-                cache["k_scale"], cache["v_scale"] = k_s, v_s
-            attn = mm(attn.reshape(b, 1, -1), blk["wo"], cfg.act_quant)
-            if cfg.post_norms:
-                attn = _norm(cfg, attn, blk["post_attn_norm"])
-            x = x + attn
-            h = _norm(cfg, x, blk["mlp_norm"])
-            mlp, _ = _mlp(cfg, blk, h)
-            if cfg.post_norms:
-                mlp = _norm(cfg, mlp, blk["post_mlp_norm"])
-            x = x + mlp
-            return (x, cache), None
-
-        (x, new_cache), _ = jax.lax.scan(
-            step,
-            (x, dict(kv_cache)),
-            (params["blocks"], layer_idx),
-        )
-        x = _norm(cfg, x, params["final_norm"])
-        logits = _logits(cfg, params, x)[:, 0]  # [B,V]
-        if with_stats:
-            return logits, new_cache, _moe_total(None)
-        return logits, new_cache
-
-    # flash_decode / flash_sgrid both route to the S-GRID family.
-    use_sgrid = branch == "pallas-sgrid"
-    use_rows = branch == "pallas-rows"
+    use_rows = decode_attention_branch(
+        cfg, mesh, kv_view, quant_mode, s) == "pallas-rows"
     if use_rows:
         from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
             decode_attention_rows,
@@ -1387,29 +1252,6 @@ def decode_step(
         # position: kv_view bounds nothing here, and nothing is sliced.
         block = rows_block(s, cfg.n_kv_heads)
         work = decode_rows_worklist(positions, s, block)
-    elif use_sgrid:
-        from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
-            flash_decode_attention_sgrid,
-        )
-
-        def attention(q, k_l, v_l, idx, k_s=None, v_s=None):
-            win = _layer_window(cfg, idx, s)
-            return flash_decode_attention_sgrid(
-                q, k_l, v_l, positions,
-                k_scale=k_s, v_scale=v_s, kv_quant=quant_mode,
-                scale=cfg.query_scale,
-                softcap=cfg.attn_softcap,
-                window=win,
-                interpret=cfg.flash_interpret,
-            )
-    else:
-        def attention(q, k_l, v_l, idx, k_s=None, v_s=None):
-            return cached_attention(
-                q, k_l, v_l, positions,
-                scale=cfg.query_scale,
-                softcap=cfg.attn_softcap,
-                window=_layer_window(cfg, idx, s),
-            )
 
     if quant_mode == "int4":
         from p2p_llm_tunnel_tpu.models.quant import (
@@ -1422,7 +1264,7 @@ def decode_step(
         with jax.named_scope("kv_read"):
             # ONE dynamic_slice for (layer, view-prefix): slicing the layer out
             # first and sub-slicing after makes XLA materialize the full-length
-            # layer before the view cut — the fused form reads only view bytes.
+            # layer before the view cut — the one slice reads only view bytes.
             view_rows = kv_view // 2 if quant_mode == "int4" else kv_view
             view_shape = (1, b, view_rows, cfg.n_kv_heads, cfg.head_dim)
             zero = jnp.zeros((), idx.dtype)
@@ -1438,21 +1280,20 @@ def decode_step(
                     cache["k_scale"], start[:4], sc_shape)[0]
                 v_s = jax.lax.dynamic_slice(
                     cache["v_scale"], start[:4], sc_shape)[0]
-                if not use_sgrid:
-                    if quant_mode == "int4":
-                        k_l = unpack_int4(k_l, axis=1)
-                        v_l = unpack_int4(v_l, axis=1)
-                    k_l = (k_l.astype(jnp.float32)
-                           * k_s[..., None]).astype(q.dtype)
-                    v_l = (v_l.astype(jnp.float32)
-                           * v_s[..., None]).astype(q.dtype)
+                if quant_mode == "int4":
+                    k_l = unpack_int4(k_l, axis=1)
+                    v_l = unpack_int4(v_l, axis=1)
+                k_l = (k_l.astype(jnp.float32)
+                       * k_s[..., None]).astype(q.dtype)
+                v_l = (v_l.astype(jnp.float32)
+                       * v_s[..., None]).astype(q.dtype)
         with jax.named_scope("attn"):
-            if quant and use_sgrid:
-                # Packed/int8 K/V + scales go straight into the kernel,
-                # which dequantizes in VMEM — the bf16 plane never
-                # materializes in HBM (that was the whole einsum-path cost).
-                return attention(q, k_l, v_l, idx, k_s, v_s)
-            return attention(q, k_l, v_l, idx)
+            return cached_attention(
+                q, k_l, v_l, positions,
+                scale=cfg.query_scale,
+                softcap=cfg.attn_softcap,
+                window=_layer_window(cfg, idx, s),
+            )
 
     def step(carry, xs):
         x, cache = carry

@@ -1,6 +1,5 @@
 """Ragged grouped Pallas flash-prefill kernel (ISSUE 15 tentpole).
 
-The prefill twin of ``ops/pallas_decode_attention.fused_decode_layer``:
 ONE program processes a whole admission group's variable-length tail
 segments — per-BLOCK ``(slot, start, qoff, base)`` descriptors ride
 scalar prefetch and drive every block index map, so the group needs no
@@ -54,8 +53,9 @@ program, all kv-heads:
 - ``sj == last``: normalize the online softmax and emit the block's
   attention output.
 
-Weight matmuls / norms stay in XLA exactly as in the fused decode layer
-(the docstring'd no-folding-left argument applies unchanged).  The
+Weight matmuls stay in XLA, where MXU fusion already works, and so does
+the pre-attention RMSNorm: it precedes the QKV projections and XLA fuses it
+into their operand reads.  The
 einsum path (``chunk_prefill_into_cache`` + ops/attention.py
 ``history_attention``) remains the numerics oracle —
 tests/test_ragged_prefill.py pins this kernel against it in interpret
@@ -73,10 +73,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+from p2p_llm_tunnel_tpu.models.quant import (
     INT4_PACK_TOKENS,
-    _pack_byte,
-    _unpack_seq,
+    pack_byte,
+    unpack_seq,
 )
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -279,8 +279,8 @@ def _ragged_prefill_kernel(
     @pl.when((sj < n_hist) & (sj * block_s < start))
     def _hist():
         if kv_quant == "int4":
-            k_blk = _unpack_seq(k_ref[:]).astype(jnp.float32)
-            v_blk = _unpack_seq(v_ref[:]).astype(jnp.float32)
+            k_blk = unpack_seq(k_ref[:]).astype(jnp.float32)
+            v_blk = unpack_seq(v_ref[:]).astype(jnp.float32)
         else:
             k_blk = k_ref[:].astype(jnp.float32)  # [BS, K, D]
             v_blk = v_ref[:].astype(jnp.float32)
@@ -362,8 +362,8 @@ def _ragged_prefill_kernel(
                 # Whole-byte pack (models.quant.pack_int4 layout): token
                 # 2i low nibble, 2i+1 high.  start/block_q evenness makes
                 # every write byte-aligned — no nibble RMW on this path.
-                ok_ref[:] = _pack_byte(kq_i[:, 0], kq_i[:, 1])
-                ov_ref[:] = _pack_byte(vq_i[:, 0], vq_i[:, 1])
+                ok_ref[:] = pack_byte(kq_i[:, 0], kq_i[:, 1])
+                ov_ref[:] = pack_byte(vq_i[:, 0], vq_i[:, 1])
             elif kv_quant == "int8":
                 ok_ref[:] = kq.astype(jnp.int8)
                 ov_ref[:] = vq.astype(jnp.int8)

@@ -126,7 +126,7 @@ FLIGHT_SCHEMA: Dict[str, str] = {
         "row (ISSUE 20; the decode role's disagg hit signal)"
     ),
     "spec_proposed": (
-        "draft tokens proposed to the fused verify burst this iteration "
+        "draft tokens proposed to the verify burst this iteration "
         "(ISSUE 17; greedy rows only, 0 when speculation is off/idle)"
     ),
     "spec_accepted": (
@@ -187,10 +187,6 @@ STARTUP_SCHEMA: Dict[str, str] = {
         "cache, a JAX without the events, or a program that compiles "
         "faster than JAX's threshold for caching and is compiled again at "
         "every start (a fact of the machine's disk, waived)"
-    ),
-    "pallas_calls": (
-        "custom calls in the decode layer body, on startup.launch_probe "
-        "(the one place utils/hlo.py's count is already paid for)"
     ),
     "clock": (
         "where startup.process got its start: proc (/proc/self/stat "

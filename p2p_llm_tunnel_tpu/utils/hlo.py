@@ -1,9 +1,7 @@
-"""Kernel/launch counting over lowered StableHLO (ISSUE 4 satellite).
+"""Kernel/launch counting over lowered StableHLO.
 
-The fused decode-layer kernel exists to collapse the per-step launch storm
-(32 layers × 16 steps ≈ 4k kernel launches per decode dispatch), and a
-regression that re-splits the layer body into many kernels should be
-visible to a CPU test run, at no chip time.  JAX can lower a
+A regression that re-splits a kernel's layer body into many kernels should
+be visible to a CPU test run, at no chip time.  JAX can lower a
 jitted program for the TPU platform from a CPU-only host
 (``jit(f).trace(*args).lower(lowering_platforms=("tpu",))``) — that module
 is the REAL serving program (Pallas kernels appear as single
@@ -17,13 +15,14 @@ counts bound what XLA can launch:
 - ``*_ops`` counts every non-structural op — the upper bound (all
   elementwise ops unfused).
 
-Both are reported; the decode scans appear ONCE in the module (lax.scan
+Both are reported; a layer scan appears ONCE in the module (lax.scan
 lowers to ``stablehlo.while``), so per-layer-step numbers come from the
 innermost while body that contains a dot — the layer scan.
 
-Used by the engine's ``engine_decode_kernels_per_step`` gauge and the
-ISSUE 4 acceptance test
-(fused path ≥40% fewer major kernels per decode layer-step).
+What uses it today: tests/test_ragged_prefill.py holds the ragged prefill
+kernel's claim (ONE custom call a layer) with ``decode_launch_report``.
+Nothing on the serving path does; the module goes or stays with the ragged
+kernel's contest on the chip (ROADMAP Design 6).
 """
 
 from __future__ import annotations

@@ -159,10 +159,6 @@ METRICS_CATALOG: Dict[str, str] = {
     "engine_queue_depth": "requests waiting for a slot (gauge)",
     "engine_batch_occupancy": "fraction of decode slots occupied (gauge)",
     "engine_degraded": "1 while the decode watchdog deems the engine stalled (gauge)",
-    "engine_decode_kernels_per_step": (
-        "launch-proxy major kernels per decode layer-step in the "
-        "TPU-lowered burst program (gauge; utils/hlo.py)"
-    ),
     "engine_warmup_compile_s": (
         "wall seconds warmup spent compiling the serving program set "
         "(gauge; the set-up a start pays before its first request; the "

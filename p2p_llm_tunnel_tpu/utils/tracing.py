@@ -199,7 +199,7 @@ SPAN_CATALOG: Dict[str, str] = {
         "the KV planes and the prefix pool allocated (bytes)"
     ),
     "startup.warmup": (
-        "engine.warmup(): parent of aot, execute, prefix_warm, launch_probe"
+        "engine.warmup(): parent of aot, execute, prefix_warm"
     ),
     "startup.aot": (
         "the threaded AOT phase: every planned program and the copy "
@@ -210,10 +210,6 @@ SPAN_CATALOG: Dict[str, str] = {
         "the serial pass: every planned program dispatched once"
     ),
     "startup.prefix_warm": "the prefix pool's copy programs run once",
-    "startup.launch_probe": (
-        "_set_kernel_gauge: the decode program lowered once more for "
-        "engine_decode_kernels_per_step (pallas_calls)"
-    ),
     "startup.program": (
         "one warmed program: a planned program's lower + compile in the "
         "AOT phase and its first dispatch in the serial pass, a copy "

@@ -556,8 +556,6 @@ def test_the_records_moe_and_the_kernel_counter_are_held_to_each_other(
     ({"kv_quant": "int4"}, "--kv-quant int4"),
     ({"tp": 2}, "--tp 2"), ({"sp": 2}, "--sp 2"), ({"ep": 2}, "--ep 2"),
     ({"ragged_prefill": True}, "--ragged-prefill"),
-    ({"fused_decode_layer": True}, "--fused-decode-layer"),
-    ({"flash_decode": True}, "--flash-decode"),
     ({"quant": "int8"}, "--quant int8"),
 ])
 def test_what_the_family_lacks_is_refused_by_name(option, named):

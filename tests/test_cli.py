@@ -105,17 +105,16 @@ def test_parser_engine_knobs():
     args = cli.build_parser().parse_args([
         "serve", "--room", "r", "--backend", "tpu",
         "--quant", "w8a8", "--kv-quant", "int8", "--prefill-act-quant",
-        "--flash-decode", "--sp", "2", "--sp-mode", "ulysses", "--ep", "4",
+        "--sp", "2", "--sp-mode", "ulysses", "--ep", "4",
     ])
     assert args.quant == "w8a8"
     assert args.kv_quant == "int8"
     assert args.prefill_act_quant is True
-    assert args.flash_decode is True
     assert args.sp == 2 and args.sp_mode == "ulysses" and args.ep == 4
     # defaults stay conservative
     d = cli.build_parser().parse_args(["serve", "--room", "r"])
     assert d.kv_quant == "none" and d.sp_mode == "ring" and d.ep == 1
-    assert d.prefill_act_quant is False and d.flash_decode is False
+    assert d.prefill_act_quant is False
 
 
 def test_cli_engine_knobs_reach_engine_config(monkeypatch):
@@ -151,7 +150,7 @@ def test_cli_engine_knobs_reach_engine_config(monkeypatch):
         args = cli_mod.build_parser().parse_args([
             "serve", "--room", "r", "--backend", "tpu",
             "--quant", "w8a8", "--kv-quant", "int8", "--prefill-act-quant",
-            "--flash-decode", "--sp", "2", "--sp-mode", "ulysses",
+            "--sp", "2", "--sp-mode", "ulysses",
             "--ep", "4", "--tp", "2",
         ])
         await cli_mod._engine_backend(args)
@@ -160,7 +159,7 @@ def test_cli_engine_knobs_reach_engine_config(monkeypatch):
     cfg = captured["cfg"]
     assert cfg.quant == "w8a8"
     assert cfg.kv_quant == "int8"
-    assert cfg.prefill_act_quant and cfg.flash_decode
+    assert cfg.prefill_act_quant
     assert cfg.sp == 2 and cfg.sp_mode == "ulysses"
     assert cfg.ep == 4 and cfg.tp == 2
 

@@ -216,14 +216,19 @@ def test_rows_on_planes_take_a_scale_a_window_and_a_traced_layer_in_a_scan():
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_a_row_of_the_planes_is_read_no_further_than_its_position():
+@pytest.mark.parametrize("layout", ["planes", "stacked"])
+def test_a_row_is_read_no_further_than_its_position(layout):
     """What lies past a row's own position is in no item: poison there (and
-    in every other layer) changes nothing, to the bit."""
-    q, k, v, pos = _planes(8, 2, 24, 16, jnp.float32, layers=2, seed=9)
+    in every other layer) changes nothing, to the bit, in either layout."""
+    if layout == "planes":
+        q, k, v, pos = _planes(8, 2, 24, 16, jnp.float32, layers=2, seed=9)
+    else:
+        q, k, v, pos = _mk(8, 2, 32, jnp.float32, layers=2, seed=9)
     block = rows_block(S, 2)
     work = decode_rows_worklist(pos, S, block)
-    past = (jnp.arange(S)[None, :] > pos[:, None])[None, :, :, None]
-    other = (jnp.arange(2) != 1)[:, None, None, None]
+    tail = (1,) * (k.ndim - 3)
+    past = (jnp.arange(S)[None, :] > pos[:, None]).reshape((1, -1, S) + tail)
+    other = (jnp.arange(2) != 1).reshape((2, 1, 1) + tail)
     bad = past | other
     got, poisoned = (
         decode_attention_rows(q, a, b_, jnp.int32(1), work, block=block,
@@ -231,6 +236,60 @@ def test_a_row_of_the_planes_is_read_no_further_than_its_position():
         for a, b_ in ((k, v), (jnp.where(bad, jnp.nan, k),
                                jnp.where(bad, 3e38, v))))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(poisoned))
+
+
+def _layout(name, seed):
+    """(q, k, v, oracle, block): the stacked cache or the planes, four KV
+    heads either way, so a work item is two ``ROWS_BLOCK``s long."""
+    if name == "stacked":
+        q, k, v, _ = _mk(8, 4, 32, jnp.float32, layers=2, seed=seed)
+
+        def oracle(pos):
+            return cached_attention(q[:, None], k[1], v[1], pos)[:, 0]
+    else:
+        q, k, v, _ = _planes(8, 4, 24, 16, jnp.float32, seed=seed)
+
+        def oracle(pos):
+            return _plane_oracle(q, k, v, pos, 1)
+    block = rows_block(S, 4)
+    assert block == 2 * ROWS_BLOCK
+
+    def rows(pos):
+        return decode_attention_rows(
+            q, k, v, jnp.int32(1), decode_rows_worklist(pos, S, block),
+            block=block, interpret=True)
+    return rows, oracle, block
+
+
+@pytest.mark.parametrize("edge", [-1, 0, 1])
+@pytest.mark.parametrize("layout", ["stacked", "planes"])
+def test_rows_match_the_einsum_where_a_work_list_breaks(layout, edge):
+    """A row whose position is an item's last, the next item's first and
+    the one after (the last block's partial fetch ends, begins, and holds
+    one position), beside rows that end elsewhere, at an item wider than
+    ``ROWS_BLOCK``."""
+    rows, oracle, block = _layout(layout, seed=11)
+    pos = jnp.asarray([block + edge, 0, S - 1, block + edge, 41,
+                       block - 1 - edge, 300, ROWS_BLOCK], jnp.int32)
+    np.testing.assert_allclose(np.asarray(rows(pos)), np.asarray(oracle(pos)),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layout", ["stacked", "planes"])
+def test_a_parked_row_leaves_the_live_rows_answers_to_the_bit(layout):
+    """Rows parked at ``S`` and beyond are in no item: the rows around them
+    answer what they answer when those rows are live, bit for bit (the
+    engine parks a slot between requests, beside slots that decode)."""
+    rows, _, block = _layout(layout, seed=13)
+    live = np.asarray([0, 1, 3, 5, 7])
+    base = np.asarray([block, 5, 0, S - 1, 0, 300, 0, block - 1], np.int32)
+    parked, busy = base.copy(), base.copy()
+    parked[[2, 4, 6]] = [S, S + 7, S]
+    busy[[2, 4, 6]] = [S - 1, 17, block + 1]
+    got = np.asarray(rows(jnp.asarray(parked)))
+    np.testing.assert_array_equal(
+        got[live], np.asarray(rows(jnp.asarray(busy)))[live])
+    assert not got[[2, 4, 6]].any() and got[live].any()
 
 
 def test_rows_refuse_a_sequence_that_does_not_tile():
@@ -258,14 +317,41 @@ INTERP_F32 = replace(get_config("tiny"), flash_interpret=True)
     (INTERP, 192, None, "einsum"),         # does not tile by 128
     (TINY, 256, None, "einsum"),           # a CPU backend, not interpreting
     (replace(INTERP, flash=False), 256, None, "einsum"),  # the reference
-    (replace(INTERP, flash_sgrid=True), 256, None, "pallas-sgrid"),
-    (replace(INTERP, fused_decode_layer=True), 256, None,
-     "pallas-fused-decode-layer"),
     (replace(get_config("tiny-mla-moe"), flash_interpret=True), 256, None,
      "einsum"),                            # the latent family
 ])
 def test_the_branch_is_decided_by_what_the_code_observes(cfg, view, kv, want):
     assert decode_attention_branch(cfg, None, view, kv) == want
+
+
+#: (serve preset, the cell's --max-seq) of the benchmark's seven
+#: configurations, and the decode read its cells were measured on over the
+#: plain cache (their ``engine.decode_burst`` records' ``attn``).
+CELL_PRESETS = {
+    "mistral-7b": (1024, "pallas-rows"),
+    "qwen2-7b": (1024, "pallas-rows"),
+    "sarvam-105b-ep4s": (4096, "einsum"),          # latent planes
+    "mimo-v2-flash-ep16s": (8192, "pallas-rows"),  # its full layers
+    "sdar-30b-a3b-pp7s": (2048, "einsum"),         # passes over blocks
+    "nemotron-3-nano-30b-a3b-ep2s": (4096, "pallas-rows"),  # attention layers
+    "granite-4.0-h-micro": (2560, "pallas-rows"),  # 8 heads of 64 a row
+}
+
+
+@pytest.mark.parametrize("kv", [None, "int8"])
+@pytest.mark.parametrize("preset", sorted(CELL_PRESETS))
+def test_every_cells_preset_takes_the_branch_its_cell_was_measured_on(
+        preset, kv):
+    """The whole rule as data, on the TPU's gate (``flash_force``): the
+    kernel over the plain cache where the family's planes are KV heads
+    whose rows tile, the einsum elsewhere and over every int8 cache
+    (``--kv-quant int8`` is the control a configuration's limits are read
+    with).  The view changes nothing: the kernel reads the cache."""
+    max_seq, plain = CELL_PRESETS[preset]
+    cfg = replace(get_config(preset), flash_force=True)
+    want = plain if kv is None else "einsum"
+    assert decode_attention_branch(cfg, None, max_seq, kv, max_seq) == want
+    assert decode_attention_branch(cfg, None, 128, kv, max_seq) == want
 
 
 @pytest.mark.parametrize("view,max_seq,want", [

@@ -381,9 +381,9 @@ def test_conversation_cache_turn2_prefills_tail_only():
 
 @pytest.mark.slow
 def test_fences_registry_and_published_info():
-    """The composition-fence registry: int4+spec records exactly the spec
-    fence; the hero config records NOTHING; the registry is published for
-    /healthz via the metrics info store."""
+    """The composition-fence registry: the hero config and int4+spec record
+    NOTHING (ISSUE 17: verify bursts splice whole bytes); what is fenced is
+    published for /healthz via the metrics info store."""
     from p2p_llm_tunnel_tpu.engine.engine import InferenceEngine
 
     def fences(**kw):
@@ -393,16 +393,32 @@ def test_fences_registry_and_published_info():
         return asyncio.run(main())
 
     hero = fences(kv_quant="int4", mux=True, prefix_cache=True,
-                  conv_cache=True, fused_decode_layer=True)
+                  conv_cache=True)
     assert hero == []
     assert global_metrics.info("config_fences") == []
 
-    spec = fences(kv_quant="int4", spec_ngram=2)
-    assert [f["knob"] for f in spec] == ["spec_ngram"]
-    assert global_metrics.info("config_fences") == spec
+    assert fences(kv_quant="int4", spec_ngram=2) == []
     # conv_cache without the pool is fenced with a reason, not silent.
     conv = fences(conv_cache=True, prefix_cache=False)
     assert [f["knob"] for f in conv] == ["conv_cache"]
+    assert global_metrics.info("config_fences") == conv
+
+
+def test_engine_rejects_unknown_kv_quant_and_gates_int4():
+    """A cache form the engine does not know is refused at start-up, by
+    name; the packed int4 cache composes with the prefix cache, chunked
+    prefill (ISSUE 14: page-aligned writes) and speculation (ISSUE 17)
+    as asked, with nothing fenced."""
+    from p2p_llm_tunnel_tpu.engine.engine import InferenceEngine
+
+    with pytest.raises(ValueError, match="kv_quant"):
+        InferenceEngine(engine_cfg=_cfg(kv_quant="int2"))
+    eng = InferenceEngine(engine_cfg=_cfg(
+        kv_quant="int4", prefix_cache=True, prefill_chunk=16, spec_ngram=2))
+    assert eng._prefix is not None
+    assert eng.ecfg.prefill_chunk == 16
+    assert eng.ecfg.spec_ngram == 2
+    assert eng.config_fences == []
 
 
 def test_int4_alignment_pass_covers_mux_defaulted_chunk():

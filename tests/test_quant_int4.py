@@ -14,15 +14,19 @@ import pytest
 
 from p2p_llm_tunnel_tpu.models.config import ModelConfig, get_config
 from p2p_llm_tunnel_tpu.models.quant import (
+    INT4_PACK_TOKENS,
     QTensor4,
     _dequant4,
     _quantize4,
     embed_lookup,
     head_matmul,
     mm,
+    pack_byte,
     pack_int4,
+    page_alignment_violations,
     quantize_params_int4,
     unpack_int4,
+    unpack_seq,
 )
 from p2p_llm_tunnel_tpu.models.transformer import init_params, prefill
 
@@ -163,85 +167,45 @@ def test_int4_decode_token_identical_to_dequant_reference():
     assert run(qparams) == run(ref_params)
 
 
-def test_sgrid_int4_kernel_matches_einsum_oracle():
-    """Interpret-mode oracle for the packed-int4-KV s-grid kernel: must
-    equal einsum attention over the dequantized cache, per-slot frontiers
-    included."""
-    from p2p_llm_tunnel_tpu.ops.attention import cached_attention
-    from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
-        flash_decode_attention_sgrid_int4,
-    )
-
-    rng = np.random.default_rng(0)
-    b, s, h, kh, d = 3, 256, 4, 2, 128
-    q = jnp.asarray(rng.standard_normal((b, 1, h, d)).astype(np.float32))
-    kf = rng.standard_normal((b, s, kh, d)).astype(np.float32)
-    vf = rng.standard_normal((b, s, kh, d)).astype(np.float32)
-    pos = jnp.asarray([5, 130, 255], jnp.int32)
-
-    def q4(x):
-        amax = np.abs(x).max(-1, keepdims=True)
-        scale = np.maximum(amax, 1e-8) / 7.0
-        qv = np.clip(np.round(x / scale), -7, 7)
-        return qv.astype(np.int8), scale
-
-    k4, ks = q4(kf)
-    v4, vs = q4(vf)
-    ref = cached_attention(
-        q, jnp.asarray(k4 * ks), jnp.asarray(v4 * vs), pos
-    )
-    got = flash_decode_attention_sgrid_int4(
-        q,
-        pack_int4(jnp.asarray(k4), axis=1),
-        pack_int4(jnp.asarray(v4), axis=1),
-        jnp.asarray(ks[..., 0]), jnp.asarray(vs[..., 0]),
-        pos, interpret=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-3
-    )
+@pytest.mark.parametrize("kv,page,chunk,named", [
+    (None, 15, 31, []),
+    ("int8", 15, 31, []),
+    ("int4", 16, 32, []),
+    ("int4", 15, 32, ["pool page size 15"]),
+    ("int4", 16, 31, ["chunk segment width 31"]),
+    ("int4", 15, 31, ["pool page size 15", "chunk segment width 31"]),
+    ("int4", 16, 0, []),      # no chunked prefill: nothing to align
+])
+def test_only_whole_bytes_of_a_packed_cache_are_written_in_bulk(
+        kv, page, chunk, named):
+    """The ISSUE 14 rule the engine's start-up fences are made from: under
+    the packed int4 cache, and there alone, a pool page and a chunk segment
+    hold whole bytes (``INT4_PACK_TOKENS`` tokens each)."""
+    got = page_alignment_violations(kv, page, chunk)
+    assert len(got) == len(named)
+    for why, what in zip(got, named):
+        assert what in why and f"{INT4_PACK_TOKENS} tokens/byte" in why
 
 
-def test_int4_weights_compose_with_sgrid_kv8_one_program():
-    """ISSUE 2 acceptance: int4 weights + flash_sgrid + int8 KV in ONE
-    decode program (interpret mode) match the einsum decode path on the
-    same quantized weights and cache."""
-    from dataclasses import replace
-
-    from p2p_llm_tunnel_tpu.models.transformer import (
-        decode_step, init_kv_cache, prefill_into_cache,
-    )
-
-    cfg = replace(
-        get_config("tiny"),
-        flash_decode=True, flash_sgrid=True, flash_interpret=True,
-    )
-    base = replace(cfg, flash_decode=False, flash_sgrid=False)
-    params = quantize_params_int4(
-        init_params(cfg, jax.random.PRNGKey(4), jnp.float32), group_size=32
-    )
-    prompt = jnp.asarray([[7, 2, 7, 1, 8, 2, 8, 1]])
-
-    def run(c):
-        cache = init_kv_cache(c, 2, 128, jnp.float32, quant=True)
-        last, cache = prefill_into_cache(
-            c, params, prompt, jnp.array([8]), cache, jnp.array([0])
-        )
-        logits, _ = decode_step(
-            c, params, cache,
-            jnp.array([int(np.asarray(last).argmax(-1)[0]), 0], jnp.int32),
-            jnp.array([8, 0], jnp.int32),
-            kv_view=128,
-        )
-        return np.asarray(logits)[0]
-
-    fused = run(cfg)
-    oracle = run(base)
-    # bf16 activations (the int4 serving dtype): the two attention
-    # implementations round differently at bf16 resolution (~0.8%); the
-    # bound is a few bf16 ulps at |logits| ~ 2, and argmax must hold.
-    np.testing.assert_allclose(fused, oracle, rtol=5e-2, atol=5e-2)
-    assert fused.argmax() == oracle.argmax()
+@pytest.mark.parametrize("rows", [16, 2, 6])
+def test_the_kernels_nibble_forms_are_the_formats_own(rows):
+    """``unpack_seq`` / ``pack_byte`` (int32 shifts, for Mosaic) against
+    ``unpack_int4`` / ``pack_int4`` (the int8 shifts that define the
+    format) on a random plane, every byte value possible: a token at an
+    even position is the low nibble, the next one the high nibble."""
+    rng = np.random.default_rng(rows)
+    plane = jnp.asarray(
+        rng.integers(-128, 128, (rows // 2, 3, 8)).astype(np.int8))
+    vals = unpack_seq(plane)
+    assert vals.dtype == jnp.int32 and vals.shape == (rows, 3, 8)
+    np.testing.assert_array_equal(
+        np.asarray(vals), np.asarray(unpack_int4(plane, axis=0)))
+    even, odd = vals[0::2], vals[1::2]
+    back = pack_byte(even, odd)
+    assert back.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(plane))
+    np.testing.assert_array_equal(
+        np.asarray(back), np.asarray(pack_int4(vals, axis=0)))
 
 
 def test_int4_params_shard_over_tp_mesh(cpu_devices):

@@ -387,8 +387,8 @@ def test_engine_stream_byte_identical_ragged_on_vs_off(kv_quant):
     """ISSUE 15 acceptance: under mux + prefix-grouped admission, the
     ragged path's token streams are identical to the chunked path's at
     every kv_quant — shared-prefix herd, multi-segment prompt, short
-    prompt, and a warm prefix-hit tail all covered (TIE_FREE_SEED family:
-    seed 7 keeps greedy argmax tie-free, see test_fused_decode_layer)."""
+    prompt, and a warm prefix-hit tail all covered (seed 7 keeps greedy
+    argmax tie-free)."""
     shared = list(range(1, 81))
     prompts = [shared + [100 + i] for i in range(3)]
     prompts.append(list(range(1, 150)))  # multi-segment (149 > chunk 128)

@@ -9,9 +9,20 @@ sequences, and token limits alike.
 
 import asyncio
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
+from p2p_llm_tunnel_tpu.models.config import get_config
+from p2p_llm_tunnel_tpu.models.transformer import (
+    decode_step,
+    init_kv_cache,
+    init_params,
+    prefill_into_cache,
+    spec_verify_into_cache,
+)
 from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
 
 # The full acceptance suite is compile-heavy (JAX jit of engine/model
@@ -44,7 +55,7 @@ REP = list(b"the cat sat on the mat. the cat sat on the mat. the cat")
 def test_greedy_spec_equivalence_tier1():
     """Tier-1 (ISSUE 17 satellite): greedy token streams are byte-identical
     spec-on vs spec-off at EVERY kv_quant mode — including int4, which was
-    fenced off speculation before the fused verify burst landed.  The
+    fenced off speculation before verify bursts spliced whole bytes.  The
     horizon is short (the verify path fires on every proposal whether or
     not anything is accepted), so this runs in `make test` and catches a
     spec regression without waiting for the slow tier."""
@@ -70,18 +81,62 @@ def test_greedy_spec_equivalence_tier1():
     assert global_metrics.gauge("engine_spec_hist_entries") == 0
 
 
+@pytest.mark.parametrize("kv_quant", [False, "int8", "int4"])
+def test_spec_verify_matches_sequential_decode_steps(kv_quant):
+    """The whole-model contract behind greedy spec/plain equivalence:
+    one spec_verify_into_cache call returns the same logits AND leaves
+    bitwise-identical cache planes as T sequential decode_steps.  Row 0
+    starts at an ODD position — the unaligned-int4 splice path must still
+    land whole-byte writes."""
+    cfg = get_config("tiny")
+    params = init_params(cfg, jax.random.PRNGKey(7), jnp.float32)
+    rng = np.random.RandomState(0)
+    b, s, t = 3, 256, 4
+    lens = [7, 12, 250]
+
+    cache = init_kv_cache(cfg, b, s, jnp.float32, quant=kv_quant)
+    toks = jnp.zeros((b, s), jnp.int32)
+    for i, n in enumerate(lens):
+        toks = toks.at[i, :n].set(
+            jnp.asarray(rng.randint(1, 200, size=n), jnp.int32))
+    _, cache = prefill_into_cache(
+        cfg, params, toks, jnp.array(lens), cache, jnp.arange(b))
+    positions = jnp.array(lens, jnp.int32)
+    burst = jnp.asarray(rng.randint(1, 200, size=(b, t)), jnp.int32)
+
+    sc = cache
+    seq_logits = []
+    for i in range(t):
+        lg, sc = decode_step(cfg, params, sc, burst[:, i],
+                             positions + i, kv_view=s)
+        seq_logits.append(lg)
+    seq_logits = jnp.stack(seq_logits, axis=1)
+
+    logits, oc = spec_verify_into_cache(
+        cfg, params, burst, positions, cache, kv_view=s)
+
+    l_err = np.abs(np.asarray(logits) - np.asarray(seq_logits)).max()
+    assert l_err < 2e-3, l_err
+    assert np.array_equal(np.argmax(np.asarray(logits), -1),
+                          np.argmax(np.asarray(seq_logits), -1))
+    for key in ("k", "v"):
+        assert np.array_equal(np.asarray(oc[key]), np.asarray(sc[key])), key
+    for key in oc:
+        np.testing.assert_allclose(np.asarray(oc[key]), np.asarray(sc[key]),
+                                   atol=2e-5)
+
+
 def test_spec_composes_with_hero_config_no_fences():
     """ISSUE 17 acceptance: spec_ngram under int4 weights + int4 KV +
-    fused decode layer + mux leaves the config_fences registry EMPTY —
+    mux + the prefix cache leaves the config_fences registry EMPTY —
     the last composition fence is gone.  Construction-time check: fences
     are registered at engine init."""
     engine = InferenceEngine(engine_cfg=_cfg(
         spec_ngram=3, spec_k=4, spec_k_max=8, quant="int4",
-        kv_quant="int4", fused_decode_layer=True, mux=True,
-        prefix_cache=True, max_seq=256))
+        kv_quant="int4", mux=True, prefix_cache=True, max_seq=256))
     assert engine.config_fences == [], engine.config_fences
     assert engine.ecfg.spec_ngram == 3
-    # The warmup plan carries the fused spec-verify ladder for the combo.
+    # The warmup plan carries the spec-verify ladder for the combo.
     assert [s for k, s in engine.warmup_plan() if k == "spec"]
 
 

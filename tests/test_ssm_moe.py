@@ -640,8 +640,6 @@ REFUSED = {
     "quant-w8a8": dict(quant="w8a8"),
     "kv-int4": dict(kv_quant="int4"),
     "tp": dict(tp=2), "sp": dict(sp=2), "ep": dict(ep=2),
-    "flash-decode": dict(flash_decode=True),
-    "fused-decode-layer": dict(fused_decode_layer=True),
     "ragged-prefill": dict(ragged_prefill=True),
     "spec-ngram": dict(spec_ngram=2),
     "ckpt": dict(ckpt_path="/nowhere"),

@@ -179,7 +179,7 @@ def test_the_phases_tile_the_process(starts, which):
 @pytest.mark.parametrize("parent,children", [
     ("startup.engine_build", ["startup.params", "startup.cache_alloc"]),
     ("startup.warmup", ["startup.aot", "startup.execute",
-                        "startup.prefix_warm", "startup.launch_probe"]),
+                        "startup.prefix_warm"]),
 ])
 def test_children_lie_inside_their_parent_in_order(starts, parent, children):
     start = starts["first"]
@@ -204,7 +204,6 @@ def test_the_phases_carry_their_attrs(starts):
     assert cache["bytes"] > 100_000
     assert one(start, "startup.aot")["attrs"] == {"threads": 2}
     assert one(start, "startup.execute")["attrs"] == {}
-    assert one(start, "startup.launch_probe")["attrs"]["pallas_calls"] == 0
     for rec in start["records"]:
         assert rec["name"] in SPAN_CATALOG
         assert set(rec["attrs"]) <= set(STARTUP_SCHEMA)
@@ -316,7 +315,7 @@ def test_healthz_has_the_startup_section_without_trace(starts):
     assert section["to_ready_s"] == section["phases_s"]["startup.process"]
     for name in ("startup.imports", "startup.backend",
                  "startup.engine_build", "startup.warmup", "startup.aot",
-                 "startup.execute", "startup.launch_probe"):
+                 "startup.execute"):
         assert section["phases_s"][name] >= 0.0
     assert set(section["slowest_program"]) == {"key", "seconds"}
     # the first session's handshake was timed, after ready

@@ -32,10 +32,7 @@ from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
     ROWS_KERNEL,
     decode_attention_rows,
     decode_rows_worklist,
-    flash_decode_attention_sgrid,
     rows_block,
-    fused_decode_layer,
-    fused_spec_decode_layer,
 )
 from p2p_llm_tunnel_tpu.ops.pallas_prefill_attention import (
     ragged_prefill_attention,
@@ -110,54 +107,6 @@ def test_flash_prefill_compiles_for_v5e(chip, t):
             q, k, v, valid, window=WINDOW),
         ((8, t, H, D), jnp.bfloat16), ((8, t, K, D), jnp.bfloat16),
         ((8, t, K, D), jnp.bfloat16), ((8, t), jnp.bool_),
-    )
-    assert n == 1
-
-
-@pytest.mark.parametrize("view", VIEWS)
-@pytest.mark.parametrize("kv", KV_FORMS)
-def test_sgrid_decode_compiles_for_v5e(chip, kv, view):
-    k, v, ks, vs = _cache_shapes(kv, (ROWS,), view)
-    n = _compile(
-        chip,
-        lambda q, k_, v_, pos, ks_, vs_: flash_decode_attention_sgrid(
-            q, k_, v_, pos, k_scale=ks_, v_scale=vs_, kv_quant=kv,
-            window=WINDOW),
-        ((ROWS, 1, H, D), jnp.bfloat16), k, v, ((ROWS,), jnp.int32), ks, vs,
-    )
-    assert n == 1
-
-
-@pytest.mark.parametrize("view", VIEWS)
-@pytest.mark.parametrize("kv", KV_FORMS)
-def test_fused_decode_layer_compiles_for_v5e(chip, kv, view):
-    k, v, ks, vs = _cache_shapes(kv, (L, ROWS), MAX_SEQ)
-    n = _compile(
-        chip,
-        lambda q, kn, vn, k_, v_, ks_, vs_, pos, layer: fused_decode_layer(
-            q, kn, vn, k_, v_, ks_, vs_, pos, layer, kv_view=view,
-            rope_theta=1e4, kv_quant=kv, window=WINDOW),
-        ((ROWS, H, D), jnp.bfloat16), ((ROWS, K, D), jnp.bfloat16),
-        ((ROWS, K, D), jnp.bfloat16), k, v, ks, vs,
-        ((ROWS,), jnp.int32), ((), jnp.int32),
-    )
-    assert n == 1
-
-
-@pytest.mark.parametrize("view", VIEWS)
-@pytest.mark.parametrize("kv", KV_FORMS)
-def test_fused_spec_decode_layer_compiles_for_v5e(chip, kv, view):
-    t = 5  # spec_k 4: the carry token + four proposals
-    k, v, ks, vs = _cache_shapes(kv, (L, ROWS), MAX_SEQ)
-    n = _compile(
-        chip,
-        lambda q, kn, vn, k_, v_, ks_, vs_, pos, layer:
-        fused_spec_decode_layer(
-            q, kn, vn, k_, v_, ks_, vs_, pos, layer, kv_view=view,
-            rope_theta=1e4, kv_quant=kv, window=WINDOW),
-        ((ROWS, t, H, D), jnp.bfloat16), ((ROWS, t, K, D), jnp.bfloat16),
-        ((ROWS, t, K, D), jnp.bfloat16), k, v, ks, vs,
-        ((ROWS,), jnp.int32), ((), jnp.int32),
     )
     assert n == 1
 
@@ -378,6 +327,21 @@ def test_rows_decode_compiles_for_planes_of_heads_side_by_side(chip):
         assert copies == [] and made == []
 
 
+def _dense_decode_hlo(chip, cfg, view, kv=None):
+    """(``decode_step`` at the 7B cells' shapes, 33 rows x 1024 of cache in
+    form ``kv``, donated, compiled for the described chip; the cache's
+    shapes)."""
+    from p2p_llm_tunnel_tpu.models.transformer import decode_step
+
+    params, cache = _share_shapes(chip, cfg, ROWS, MAX_SEQ, kv=kv)
+    row = _on(chip, jax.ShapeDtypeStruct((ROWS,), jnp.int32))
+    hlo = jax.jit(
+        lambda p, c, tok, pos: decode_step(cfg, p, c, tok, pos, kv_view=view),
+        donate_argnums=(1,),
+    ).lower(params, cache, row, row).compile().as_text()
+    return hlo, cache
+
+
 @pytest.mark.parametrize("model", ["mistral-7b", "qwen2-7b",
                                    "mimo-v2-flash-ep16s"])
 def test_the_shipped_decode_program_slices_no_plane(chip, model):
@@ -390,23 +354,14 @@ def test_the_shipped_decode_program_slices_no_plane(chip, model):
     one.  The mimo share (ISSUE 36) has two kinds of plane and sizes of its
     own: :func:`_mimo_decode_slices_no_full_plane`."""
     from p2p_llm_tunnel_tpu.models.config import get_config
-    from p2p_llm_tunnel_tpu.models.transformer import (
-        decode_attention_branch,
-        decode_step,
-    )
+    from p2p_llm_tunnel_tpu.models.transformer import decode_attention_branch
 
     if get_config(model).attn_pattern is not None:
         return _mimo_decode_slices_no_full_plane(chip)
     cfg = replace(get_config(model, ffn_dim=512, vocab_size=1024),
                   flash_force=True)  # the branch a TPU backend takes
     assert decode_attention_branch(cfg, None, MAX_SEQ) == "pallas-rows"
-    params, cache = _share_shapes(chip, cfg, ROWS, MAX_SEQ)
-    row = _on(chip, jax.ShapeDtypeStruct((ROWS,), jnp.int32))
-    hlo = jax.jit(
-        lambda p, c, tok, pos: decode_step(cfg, p, c, tok, pos,
-                                           kv_view=MAX_SEQ),
-        donate_argnums=(1,),
-    ).lower(params, cache, row, row).compile().as_text()
+    hlo, cache = _dense_decode_hlo(chip, cfg, MAX_SEQ)
 
     assert hlo.count("tpu_custom_call") == 1 and ROWS_KERNEL in hlo
     plane = f"[1,{ROWS},{MAX_SEQ},{cfg.n_kv_heads},{D}]"
@@ -414,6 +369,31 @@ def test_the_shipped_decode_program_slices_no_plane(chip, model):
     assert "dynamic-slice" not in "".join(
         line for line in hlo.splitlines()
         if f"{ROWS},{MAX_SEQ},{cfg.n_kv_heads},{D}]" in line)
+    copies, _ = _plane_work(hlo, math.prod(cache["k"].shape))
+    assert copies == []
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert aliased.count("alias") == len(cache)
+
+
+@pytest.mark.parametrize("view", VIEWS)
+@pytest.mark.parametrize("kv", KV_FORMS[1:])
+def test_the_quantised_caches_decode_compiles_for_v5e(chip, kv, view):
+    """``decode_step`` over an int8 and a packed int4 cache, which keep the
+    einsum on every backend (``--kv-quant`` is the control a configuration's
+    limits are read with), at mistral-7b's attention widths and depth and
+    each rung of the view ladder: the TPU's compiler takes the program,
+    there is no Mosaic kernel in it, no ``copy`` makes a plane (the
+    dequantised view is a layer's, never the stacked cache's) and the cache
+    written is the donated one."""
+    from p2p_llm_tunnel_tpu.models.config import get_config
+    from p2p_llm_tunnel_tpu.models.transformer import decode_attention_branch
+
+    cfg = replace(get_config("mistral-7b", ffn_dim=512, vocab_size=1024),
+                  flash_force=True)  # the branch a TPU backend takes
+    assert decode_attention_branch(cfg, None, view, kv, MAX_SEQ) == "einsum"
+    hlo, cache = _dense_decode_hlo(chip, cfg, view, kv)
+
+    assert "tpu_custom_call" not in hlo
     copies, _ = _plane_work(hlo, math.prod(cache["k"].shape))
     assert copies == []
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)

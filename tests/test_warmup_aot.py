@@ -129,57 +129,6 @@ def test_warmup_view_cap():
         del os.environ["TUNNEL_WARMUP_VIEW_CAP"]
 
 
-def test_fused_decode_variants_covered_by_warmup(persistent_cache,
-                                                 monkeypatch):
-    """ISSUE 4 acceptance: every fused decode-layer variant is covered by
-    warmup — after ``warmup()`` returns, live dispatch (prefill wave +
-    fused decode bursts + prefix insert, over the fused+int8-KV engine,
-    the richest fused program set) adds ZERO fresh compiles.
-
-    Unlike the base test above, the serial par=0 replay engine is not
-    re-warmed for a cross-engine hash comparison: JAX numbers outlined
-    StableHLO helpers (``@clip_N``) with a PROCESS-GLOBAL counter, so a
-    second engine's lowering text — and persistent-cache hash — can shift
-    with unrelated prior lowerings in the same process.  The operational
-    guarantee (no compile lands on the serving path) is per-engine and is
-    what this test pins; the cross-engine identity for the plain config
-    stays pinned above."""
-    from dataclasses import replace
-
-    from p2p_llm_tunnel_tpu.models.config import get_config
-
-    monkeypatch.setenv("TUNNEL_WARMUP_VIEW_CAP", "100")
-    monkeypatch.setenv("TUNNEL_WARMUP_PREFILL_TOKENS", str(len(PROMPT)))
-    monkeypatch.setenv("TUNNEL_WARMUP_PAR", "2")
-    tok = ByteTokenizer()
-    mcfg = replace(
-        get_config("tiny", vocab_size=tok.vocab_size), flash_interpret=True
-    )
-
-    async def run():
-        eng = InferenceEngine(
-            model_cfg=mcfg,
-            engine_cfg=EngineConfig(
-                **{**ECFG, "kv_quant": "int8", "fused_decode_layer": True}
-            ),
-            tokenizer=ByteTokenizer(),
-        )
-        await eng.start()
-        await eng.warmup()
-        warmed = _cache_files(persistent_cache)
-        toks = await _collect(eng, PROMPT)
-        await eng.stop()
-        return toks, warmed
-
-    toks, warmed = asyncio.run(run())
-    assert warmed, "warmup wrote nothing to the persistent cache"
-    assert len(toks) == 8
-    live_new = _cache_files(persistent_cache) - warmed
-    assert not live_new, (
-        f"live dispatch compiled {len(live_new)} fused programs warmup missed"
-    )
-
-
 def test_ragged_mux_herd_hits_zero_cold_compiles(persistent_cache,
                                                  monkeypatch):
     """ISSUE 15 acceptance: under the RAGGED prefill path the warmup
