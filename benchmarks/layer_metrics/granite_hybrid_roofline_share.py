@@ -23,14 +23,20 @@ state-space layers take: ``ssm_scope_share``'s six scopes and
 ``state_leaf``.
 
 **``state_leaf``.**  An operation on no scope's path whose result has the
-shape of the ``conv`` or the ``ssm`` leaf (``[Mamba-2 layers, rows, K - 1,
-channels]``, ``[Mamba-2 layers, rows, H, P, N]``; the rows are the
-engine's, so any count) is the state update's own work: the compiler's
-copies of a leaf around a layer's write carry the name it gave them and no
-scope (72 ``bitcast_dynamic-update-slice_fusion.N.remat_compressed`` /
-``.remat_uncompressed = bf16[36,65,3,4352]`` a step, 7.5 of 31.7 ms, in
-this family's first traced runs: ISSUE 46).  ``ssm_scope_share`` reads
+shape of the ``conv`` or the ``ssm`` leaf as the program lays them
+(``[Mamba-2 layers, rows, (K - 1) x channels]``: the convolution's tail as
+lanes of a slot's row, since PR 47; ``[Mamba-2 layers, rows, H, P, N]``;
+the rows are the engine's, so any count) is the state update's own work:
+the compiler's copies of a leaf around a layer's write carry the name it
+gave them and no scope (72 ``bitcast_dynamic-update-slice_fusion.N
+.remat_compressed`` / ``.remat_uncompressed = bf16[36,65,3,4352]`` a step,
+7.5 of 31.7 ms, in this family's first traced runs, when the tail lay as
+``[.., K - 1, channels]``: ISSUE 46; PR 47's layout left none, and the rule
+stays for the change that brings one back).  ``ssm_scope_share`` reads
 scopes alone and calls them unscoped.
+``tests/benchmarks/test_bm_granite_roofline.py`` holds ``leaf_shapes`` to
+the leaves ``models/ssm_moe.py`` ``init_kv_cache`` makes, so that the next
+change of layout fails a test and not, in silence, this rule.
 
 ``what="ssm_scan"``: the prefill scans alone: over the paired prefill runs,
 the least time for each dispatch's scans over the self-time under
@@ -72,17 +78,26 @@ def _paired(ctx, summary, span):
 STATE_LEAF = "state_leaf"
 
 
-def leaf_pattern(config):
-    """What the name of an operation whose result is a whole state leaf
-    holds: ``= bf16[36,<rows>,3,4352]`` or ``= f32[36,<rows>,64,64,128]``
-    (a loop's result is a tuple that holds the leaves: not this)."""
-    s = count.sizes(config)
+def leaf_shapes(config, rows):
+    """The shapes of the two state leaves at ``rows`` rows, from the
+    configuration's keys: ``conv`` ``[Lm, rows, (K - 1) x channels]`` and
+    ``ssm`` ``[Lm, rows, H, P, N]``."""
     n, k = int(config["mamba_d_state"]), int(config["mamba_d_conv"])
     heads, width = int(config["mamba_n_heads"]), int(config["mamba_d_head"])
     channels = heads * width + 2 * int(config["mamba_n_groups"]) * n
-    return re.compile(
-        r"= \w+\[%d,\d+,(%d,%d|%d,%d,%d)\]" % (
-            s["mamba_layers"], k - 1, channels, heads, width, n))
+    layers = count.sizes(config)["mamba_layers"]
+    return {"conv": (layers, rows, (k - 1) * channels),
+            "ssm": (layers, rows, heads, width, n)}
+
+
+def leaf_pattern(config):
+    """What the name of an operation whose result is a whole state leaf
+    holds: ``= bf16[36,<rows>,13056]`` or ``= f32[36,<rows>,64,64,128]``
+    (a loop's result is a tuple that holds the leaves: not this)."""
+    rows = r"\d+"
+    either = "|".join(",".join(str(d) for d in shape)
+                      for shape in leaf_shapes(config, rows).values())
+    return re.compile(r"= \w+\[(%s)\]" % either)
 
 
 def owner_of(config):

@@ -119,24 +119,31 @@ def test_the_reader_asks_its_records_for_state_and_rows_and_no_router(
                                       "ssm_share"}
 
 
-# names and paths as the chip's trace gave them (my chip run, PR 46)
+# names and paths as the chip's trace gave them (my chip run, PR 46), when
+# the convolution's tail lay as [.., K - 1, channels]: since PR 47 no
+# program has a leaf of that shape, and the name is no leaf's
+BEFORE_PR47 = ("%bitcast_dynamic-update-slice_fusion.106.remat_compressed = "
+               "bf16[36,65,3,4352]{3,2,1,0:T(8,128)(2,1)} fusion(...)")
+# the same copy of the leaf as the program lays it now: the tail as lanes of
+# a slot's row, bf16[36,65,13056] in the cell
 COMPRESSED = ("%bitcast_dynamic-update-slice_fusion.106.remat_compressed = "
-              "bf16[36,65,3,4352]{3,2,1,0:T(8,128)(2,1)} fusion(...)")
+              "bf16[36,65,13056]{2,1,0:T(8,128)(2,1)} fusion(...)")
 OPS = [
     # (name, tf_op, start, end)
     ("%while.52 = (s32[]{:T(128)}, s32[65]{0:T(128)}, f32[36,65,64,64,128], "
-     "bf16[36,65,3,4352]) while(...)", "", 0.0, 10.0),
+     "bf16[36,65,13056]) while(...)", "", 0.0, 10.0),
     ("%ssm_step_rows.7 = f32[36,65,64,64,128] custom-call(...)",
      "jit(_decode_fn)/closed_call/ssm_step/pallas_call", 1.0, 3.0),
     (COMPRESSED, "", 3.0, 4.0),
     (COMPRESSED.replace("remat_compressed", "remat_uncompressed"), "",
      4.0, 4.5),
-    ("%fusion.9 = bf16[65,3,4352] fusion(...)",
+    ("%fusion.9 = bf16[65,13056] fusion(...)",
      "jit(_decode_fn)/closed_call/ssm_conv/dynamic_slice", 4.5, 5.0),
     ("%fusion.11 = bf16[65,16384] fusion(...)",
      "jit(_decode_fn)/closed_call/ffn/dot_general", 5.0, 8.0),
     ("%copy.3 = f32[36,129,64,64,128] copy(...)", "", 8.0, 9.0),
     ("%fusion.12 = f32[65,2048] fusion(...)", "", 9.0, 9.5),
+    (BEFORE_PR47, "", 9.5, 9.75),
 ]
 
 
@@ -144,12 +151,13 @@ def test_a_state_leafs_unscoped_copy_is_the_state_updates_time(config):
     """The compiler's copies of a whole leaf around a layer's write carry
     no scope: the reader owns them by their result's shape (any count of
     rows), and owns neither the burst's loop, whose result is a tuple that
-    holds the leaves, nor what a scope already owns."""
+    holds the leaves, nor what a scope already owns, nor a shape that was a
+    leaf's before PR 47 and is none now."""
     reader = load_module(os.path.join(LM, "granite_hybrid_roofline_share.py"))
     owner = reader.owner_of(config)
     assert [owner(name, tf_op) for name, tf_op, _s, _e in OPS] == [
         "unscoped", "ssm_step", "state_leaf", "state_leaf", "ssm_conv",
-        "ffn", "state_leaf", "unscoped"]
+        "ffn", "state_leaf", "unscoped", "unscoped"]
     ops = [(owner(name, tf_op), s, e) for name, tf_op, s, e in OPS]
     own = reader.self_time_by_owner(ops, (0.0, 10.0))
     assert own == pytest.approx({
@@ -159,6 +167,38 @@ def test_a_state_leafs_unscoped_copy_is_the_state_updates_time(config):
     inside = reader.self_time_by_owner(ops, (0.0, 10.0), within=[(2.5, 4.2)])
     assert inside == pytest.approx({"state_leaf": 1.5})
     assert sum(own[s] for s in reader.STATE_SCOPES if s in own) == 5.0
+
+
+def test_the_leaf_pattern_names_the_leaves_the_program_makes(config):
+    """``state_leaf`` owns an operation by its result's shape, so the shape
+    has to be a leaf's as ``models/ssm_moe.py`` lays it: PR 47 moved the
+    convolution's tail into a row's lanes and the rule of PR 46 matched
+    nothing for three PRs.  Here the reader's shapes, from the published
+    keys alone, are held to the leaves ``init_kv_cache`` makes of the preset
+    that ``tinycell_granite.py`` serves; a change of layout fails this."""
+    import tinycell_granite
+    from p2p_llm_tunnel_tpu.models import ssm_moe
+    from p2p_llm_tunnel_tpu.models.config import get_config
+
+    reader = load_module(os.path.join(LM, "granite_hybrid_roofline_share.py"))
+    tiny = tinycell_granite.CONFIG
+    cache = ssm_moe.init_kv_cache(get_config(tiny["serve"]["model"]), 5, 32)
+    assert set(ssm_moe.STATE_KEYS) == {"ssm", "conv"}
+    shapes = reader.leaf_shapes(tiny, 5)
+    assert {k: cache[k].shape for k in ssm_moe.STATE_KEYS} == shapes
+    pattern = reader.leaf_pattern(tiny)
+    for key in ssm_moe.STATE_KEYS:
+        leaf = cache[key]
+        dims = ",".join(str(d) for d in leaf.shape)
+        kind = {"float32": "f32", "bfloat16": "bf16"}[str(leaf.dtype)]
+        assert pattern.search(f"%copy.1 = {kind}[{dims}]{{2,1,0}} copy(...)")
+        # a layer's rows of it, and the tuple a loop returns, are no leaf
+        rows = ",".join(str(d) for d in leaf.shape[1:])
+        assert not pattern.search(f"%fusion.1 = {kind}[{rows}] fusion(...)")
+        assert not pattern.search(f"%while.1 = (s32[], {kind}[{dims}]) while")
+    # the cell's own: 36 layers, 3 x 4,352 lanes and 64 x 64 x 128 a row
+    assert reader.leaf_shapes(config, 65) == {
+        "conv": (36, 65, 13056), "ssm": (36, 65, 64, 64, 128)}
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -183,19 +223,20 @@ def test_the_cells_metrics_are_the_ones_the_issue_names():
     # issue's to name: told by the entry's layer, not by their names
     mine = {m["name"] for m in bench["per_layer"]
             if CELL in m.get("workloads", []) and m["layer"] != "start-up"}
-    # The accepted metrics whose readers find something in the cell.  The
-    # family's own five are files here (the tests above read them) and
-    # entries of no list yet: another cell's test holds ITS five to be the
-    # last of ``per_layer``, so the ``benchmark`` PR that asks membership
-    # there appends these (ROADMAP Design 1 c); where they are entries,
-    # they read in this cell alone.
-    assert mine - set(NEW) == {
+    # the accepted metrics whose readers find something in the cell, and
+    # the family's own five: entries since ISSUE 50, wherever in the list
+    assert mine == set(NEW) | {
         "decode_fill_pct.closed", "decode_step_ctr_dev_ms.closed",
         "kv_move_dev_pct.closed"}
-    for m in bench["per_layer"]:
-        if m["name"] in NEW:
-            assert m["workloads"] == [CELL] and m["layer"] \
-                == "model + attention"
+    better = {"decode_roofline.assistants": "higher",
+              "ssm_step_roofline.assistants": "higher",
+              "ssm_scan_roofline.assistants": "higher",
+              "ssm_dev_pct.assistants": "lower",
+              "ffn_dev_pct.assistants": "lower"}
+    own = [m for m in bench["per_layer"] if m["name"] in NEW]
+    assert {m["name"]: m["better"] for m in own} == better
+    for m in own:
+        assert m["workloads"] == [CELL] and m["layer"] == "model + attention"
     for name in NEW:
         with open(os.path.join(LM, name + ".json")) as f:
             assert os.path.exists(

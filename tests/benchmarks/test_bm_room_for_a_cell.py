@@ -3,11 +3,15 @@
 may not touch ``benchmarks/`` or ``tests/benchmarks/`` as they stand: so a
 test there that pins how many cells, lists or places the benchmark has today
 refuses every such PR.  Here the benchmark and its tests are copied, the copy
-gets what such a PR brings, and the copy's own contract tests run on it.
+gets what such a PR brings, and the copy's own tests run on it: the contract
+whole, the start-up entries, and EVERY family's ``test_bm_*_roofline.py`` as
+it is written (ISSUE 50: one of them held its cell's metrics to be the last
+of ``per_layer``, which the selection of ISSUE 42 did not run, and the next
+cell's metrics went in as files of no list).
 
 Then the text of every test file is held to the rule that made the room: no
 count of cells, configurations or per-layer metrics, and no place in those
-lists, is compared with a literal."""
+lists, be it a literal or a slice counted from the end, is compared."""
 
 import glob
 import json
@@ -23,11 +27,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 CONFIG, MIX = "room-7b", "chat-burst"
 CELL = CONFIG + "." + MIX
 #: What runs on the copy: the contract whole, the nine start-up entries
-#: against every cell, and the two tests that name a cell's own metrics.
-SELECTED = ["test_bm_contract.py", "test_bm_startup_phase.py",
-            "test_bm_bd_roofline.py", "test_bm_swa_roofline.py"]
+#: against every cell, and every family's roofline file whole (found in the
+#: copy, so the next family's is run with no edit here): those name their
+#: cells' own metrics.
+SELECTED = ["test_bm_contract.py", "test_bm_startup_phase.py"]
+FAMILIES = "test_bm_*_roofline.py"
 KEYWORDS = ("test_bm_contract or declared_for_every_cell or moves_setup_s "
-            "or the_cells_metrics")
+            "or _roofline")
 
 
 def load(path):
@@ -99,23 +105,34 @@ def test_an_eighth_cell_is_taken_with_no_edit_to_a_file_that_is_there(
 
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("PYTEST_")}
-    env.update(PYTHONPATH=root, JAX_PLATFORMS="cpu")
-    files = [os.path.join("tests", "benchmarks", f) for f in SELECTED]
+    # the benchmark and its tests are the copy's, the program (which a
+    # family's test may hold a yardstick to) the repository's
+    env.update(PYTHONPATH=os.pathsep.join([root, REPO]), JAX_PLATFORMS="cpu")
+    families = sorted(os.path.basename(f) for f in glob.glob(
+        os.path.join(root, "tests", "benchmarks", FAMILIES)))
+    files = [os.path.join("tests", "benchmarks", f)
+             for f in SELECTED + families]
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
          "-p", "no:randomly", "-k", KEYWORDS] + files,
-        cwd=root, env=env, capture_output=True, text=True, timeout=120)
+        cwd=root, env=env, capture_output=True, text=True, timeout=180)
     out = proc.stdout
     assert proc.returncode == 0, out[-6000:] + proc.stderr[-2000:]
+    # each family's file ran, and in it the test that names its cell's own
+    for name in families:
+        assert re.search(rf"{re.escape(name)}::\S+ PASSED", out), name
+    named = re.findall(r"(test_bm_\w+_roofline)\.py::"
+                       r"test_the_cells_metrics_are_the_ones_the_issue_names "
+                       r"PASSED", out)
+    assert {"test_bm_bd_roofline", "test_bm_swa_roofline",
+            "test_bm_ssm_roofline", "test_bm_granite_roofline"} <= set(named)
     # the copy's tests ran on the copy, and on the new entries
     for case in (f"test_cells[{CELL}] PASSED",
                  f"test_configurations[{CONFIG}] PASSED",
                  "test_per_layer_metrics[setup_room_s] PASSED",
                  "test_per_layer_metrics[queue_wait_p90_ms.burst] PASSED",
                  "test_each_of_the_nine_is_declared_for_every_cell"
-                 "[setup_to_ready_s] PASSED",
-                 "test_the_cells_metrics_are_the_ones_the_issue_names "
-                 "PASSED"):
+                 "[setup_to_ready_s] PASSED"):
         assert case in out, case
 
     # the new mix needs no code: the one generator reads it
@@ -131,13 +148,16 @@ def test_an_eighth_cell_is_taken_with_no_edit_to_a_file_that_is_there(
 
 #: A count of the benchmark's cells, configurations or per-layer metrics,
 #: or of the cells a test derived from them, beside a literal, on either
-#: side; and a place in those lists given by a literal.
+#: side; a place in those lists given by a literal; and, anywhere on a
+#: line that names one of the lists, an index or a slice counted from the
+#: end (of the list itself or of what was gathered from it).
 LISTS = r"""(BENCH|bench)\[["'](workloads|configs|per_layer)["']\]"""
 COUNTED = rf"len\(\s*({LISTS}|\w*CELLS\w*)\s*\)"
 CMP = r"(==|!=|<=|>=|<|>)"
 PINS = [re.compile(rf"{COUNTED}\s*{CMP}\s*\d"),
         re.compile(rf"\d\s*{CMP}\s*{COUNTED}"),
-        re.compile(rf"{LISTS}\s*\[\s*-?\d*\s*:?\s*-?\d")]
+        re.compile(rf"{LISTS}\s*\[\s*-?\d*\s*:?\s*-?\d"),
+        re.compile(rf"{LISTS}.*\[\s*-\s*[\w(]")]
 
 
 def test_no_test_compares_a_count_or_a_place_in_the_lists_with_a_literal():
@@ -157,9 +177,19 @@ def test_no_test_compares_a_count_or_a_place_in_the_lists_with_a_literal():
                  "assert len(CELLS) == 5 and ok",
                  'assert 5 <= len(bench["configs"])',
                  'assert set(BENCH["per_layer"][-9:]) == nine',
-                 'first = BENCH["workloads"][0]'):
+                 'first = BENCH["workloads"][0]',
+                 # what PR 44's test held: written in two pieces, so that a
+                 # search of this directory for the form finds no test
+                 'assert [m["name"] for m in bench["per_layer"]][-'
+                 'len(NEW):] == NEW',
+                 'all(m["workloads"] == [C] for m in bench["per_layer"][-'
+                 'len(NEW):])',
+                 'last = bench["configs"][-n:]'):
         assert any(p.search(line) for p in PINS), line
     for line in ('assert four <= max(1, len(BENCH["workloads"]) // 4)',
                  'for cell in BENCH["workloads"]:',
-                 'assert len(names) == len(BENCH["per_layer"])'):
+                 'assert len(names) == len(BENCH["per_layer"])',
+                 'assert BENCH["command"][-1] == "benchmarks/run.py"',
+                 'assert [m["workloads"] for m in bench["per_layer"] '
+                 'if m["name"] in NEW] == [[CELL]] * len(NEW)'):
         assert not any(p.search(line) for p in PINS), line

@@ -180,9 +180,11 @@ def test_the_cells_metrics_are_the_ones_the_issue_names():
         "decode_fill_pct.closed", "decode_step_ctr_dev_ms.closed",
         "kv_move_dev_pct.closed", "moe_dev_pct.context",
         "moe_held_share_pct.context", "moe_imbalance.context"] + NEW)
-    # the new ones come after every accepted one and read in this cell alone
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == NEW
-    assert all(m["workloads"] == [CELL] for m in bench["per_layer"][-len(NEW):])
+    # each new one is an entry and reads in this cell alone; where it lies
+    # in the list is no test's to say: the next cell's metrics go after the
+    # last (test_bm_room_for_a_cell.py holds that against this file too)
+    assert [m["workloads"] for m in bench["per_layer"]
+            if m["name"] in NEW] == [[CELL]] * len(NEW)
     assert [w["chips"] for w in bench["workloads"] if w["name"] == CELL] \
         == [1]
     # the generic routed-layer reader finds the held experts under the key

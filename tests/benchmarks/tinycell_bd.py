@@ -1,7 +1,7 @@
 """``tinycell.build``'s root with one more cell, of the family that generates
 by masked denoising over blocks: the program's ``tiny-sdar-moe`` preset
-(blocks of 4 in 2 denoise passes and a commit pass, QK norm, 8 experts
-top-2) served in bfloat16, against
+(blocks of 4 in 2 denoise passes, the first of which writes the block
+before on its way, QK norm, 8 experts top-2) served in bfloat16, against
 ``benchmarks/block_diffusion_reference.py``; its per-layer metrics read the
 dispatch ledger's counts of passes, of tokens decided and of the routed
 layers."""
@@ -52,8 +52,7 @@ CONFIG = {
 CACHE_BYTES = 3 * 2 * 32 * 2
 #: The per-layer metrics a CPU run of the cell reports: the ledger's.
 LEDGER_METRICS = ("moe_held_share_pct.context", "moe_imbalance.context",
-                  "tokens_per_row_pass.blockgen",
-                  "commit_pass_share_pct.blockgen")
+                  "tokens_per_row_pass.blockgen")
 
 
 def build(root: str) -> str:

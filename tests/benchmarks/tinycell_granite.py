@@ -55,15 +55,6 @@ CONFIG = {
 }
 #: 1 attention layer x 2 KV heads x (16 + 16) values, in bfloat16
 CACHE_BYTES = 1 * 2 * 32 * 2
-#: The family's five per-layer metrics and which way each is better.  They
-#: are files under ``layer_metrics/`` and entries of no list yet:
-#: BENCHMARK.json ``per_layer`` takes them from a ``benchmark`` PR (ROADMAP
-#: Design 1 c); the copy declares them for its own cell, after the last.
-METRICS = {"decode_roofline.assistants": "higher",
-           "ssm_step_roofline.assistants": "higher",
-           "ssm_scan_roofline.assistants": "higher",
-           "ssm_dev_pct.assistants": "lower",
-           "ffn_dev_pct.assistants": "lower"}
 
 
 def build(root: str) -> str:
@@ -87,13 +78,10 @@ def build(root: str) -> str:
     for m in bench["end_to_end"]:
         if m["name"] == "out_tok_per_s":
             m["workloads"].append(CELL)
-    for name, better in METRICS.items():
-        with open(os.path.join(data, "layer_metrics", name + ".json")) as f:
-            spec = json.load(f)
-        bench["per_layer"].append({
-            "name": name, "unit": spec["unit"], "better": better,
-            "source": spec["source"], "layer": spec["layer"],
-            "moves": spec["moves"], "workloads": [CELL]})
+    # the family's five per-layer metrics, entries since ISSUE 50
+    for m in bench["per_layer"]:
+        if m["name"].endswith(".assistants"):
+            m["workloads"] = [CELL]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return root
