@@ -712,12 +712,12 @@ class InferenceEngine(BlockDecodeMixin):
         self._state_update: Optional[str] = None
         if self.mcfg.mixer_pattern is not None:
             from p2p_llm_tunnel_tpu.models.ssm_moe import (
-                STATE_KEYS,
                 state_bytes_per_slot,
+                state_keys,
                 state_update_branch,
             )
 
-            self._state_keys = STATE_KEYS
+            self._state_keys = state_keys(self.mcfg)
             self._state_row_bytes = state_bytes_per_slot(self.mcfg, dtype)
             self._state_update = state_update_branch(self.mcfg, self.mesh)
 

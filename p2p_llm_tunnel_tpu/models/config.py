@@ -118,7 +118,10 @@ class ModelConfig:
     v_head_dim: int = 0
     # RMSNorm(w) over each head's query before rope (latent attention:
     # models/mla.py); in the KV-head family over each query AND key head's
-    # columns, weights ``q_norm`` / ``k_norm`` [L, head_dim] (Qwen3's).
+    # columns, weights ``q_norm`` / ``k_norm`` [L, head_dim] (Qwen3's); in
+    # the pattern family (models/ssm_moe.py) over the WHOLE width of the
+    # query and of the key before the heads are split, weights [La, H * D]
+    # and [La, K * D] (OLMo's).
     qk_norm: bool = False
     yarn: Optional[YarnRope] = None
     # Window and full attention layers mixed by a pattern given as data
@@ -195,6 +198,25 @@ class ModelConfig:
     # ``logits_divisor``; the attention scores' scale is ``query_scale``
     # (above), which such a model states instead of ``head_dim ** -0.5``.
     mixer_mlp: bool = False
+    # A pattern layer whose branches are normed AFTER them and not before
+    # (OLMo's block): ``x <- x + RMSNorm(mixer(x))``, and the MLP's likewise.
+    # The identity at its default: a branch's ``norm`` weight then stands
+    # before it.
+    norm_after: bool = False
+    # ``L`` in ``mixer_pattern``: a gated delta-rule layer (models/delta.py),
+    # ``delta_heads`` heads whose state is a ``[delta_key_dim,
+    # delta_value_dim]`` matrix, float32, held a slot and layer beside the
+    # KV planes as the Mamba-2 state is; a causal depthwise convolution over
+    # ``delta_conv`` positions of ``q | k | v`` side by side, no bias;
+    # prefill in chunks of ``delta_chunk``; the write strength is ``2 *
+    # sigmoid`` under ``delta_neg_eigval`` (else ``sigmoid``).  The time
+    # step its bias is drawn for is ``ssm_dt_*``'s.
+    delta_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_conv: int = 4
+    delta_chunk: int = 64
+    delta_neg_eigval: bool = False
     embed_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_divisor: float = 1.0
@@ -274,6 +296,13 @@ class ModelConfig:
     def ssm_conv_dim(self) -> int:
         """What the convolution runs over: x, B and C side by side."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def delta_conv_dim(self) -> int:
+        """What a delta layer's convolution runs over: q, k and v side by
+        side."""
+        return self.delta_heads * (2 * self.delta_key_dim
+                                   + self.delta_value_dim)
 
     def kv_heads_of(self, kind: str) -> int:
         if kind == "window" and self.window_kv_heads:
@@ -904,6 +933,82 @@ def tiny_ssm_mlp(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+#: layer_types of Olmo-Hybrid-7B: three delta-rule layers, then attention.
+_OLMO_HYBRID_PATTERN = "LLL*" * 8
+
+
+def olmo_hybrid_7b() -> ModelConfig:
+    """Olmo-Hybrid-7B (huggingface.co/allenai/Olmo-Hybrid-7B config.json,
+    ``model_type`` ``olmo_hybrid``) as ONE OF TWO PIPELINE STAGES: 16 of the
+    32 layers, four whole periods of three gated delta-rule layers (30
+    heads, a ``[96, 192]`` float32 state a head, conv 4, chunks of 64, the
+    write strength in [0, 2]) and one of full attention (30 query and 30 KV
+    heads of 128, an RMSNorm over the whole 3840-wide query and key, no
+    position encoded), each followed by a dense gated MLP of 11008; OLMo's
+    block: no norm before a branch, one on its output; the head its own.
+    Every width, every head and the whole vocabulary: 12 x 215.6 M + 4 x
+    185.8 M + 770.7 M = 4,101 M parameters, 8.2 GB in bfloat16."""
+    return ModelConfig(
+        name="olmo-hybrid-7b",
+        vocab_size=100352,
+        dim=3840,
+        n_layers=16,
+        published_layers=32,
+        n_heads=30,
+        n_kv_heads=30,
+        head_dim=128,
+        ffn_dim=11008,
+        norm_eps=1e-6,
+        act="silu",
+        tie_embeddings=False,
+        qk_norm=True,
+        v_head_dim=128,
+        mixer_pattern=_OLMO_HYBRID_PATTERN,
+        mixer_mlp=True,
+        norm_after=True,
+        delta_heads=30,
+        delta_key_dim=96,
+        delta_value_dim=192,
+        delta_conv=4,
+        delta_chunk=64,
+        delta_neg_eigval=True,
+        residual_f32=True,
+    )
+
+
+def tiny_delta_mlp(vocab_size: int = 512) -> ModelConfig:
+    """CPU-testable Olmo-Hybrid-style config: ``LLL*`` twice (6 delta-rule
+    layers of 3 heads, keys 16 and values 24 wide: heads no power of two,
+    the two widths apart, neither a lane tile; chunks of 8; 2 of attention,
+    3 query heads on 3 KV heads of 16 under a whole-width QK norm), a gated
+    MLP of 96 after every mixer, every branch normed after it."""
+    return ModelConfig(
+        name="tiny-delta-mlp",
+        vocab_size=vocab_size,
+        dim=48,
+        n_layers=8,
+        n_heads=3,
+        n_kv_heads=3,
+        head_dim=16,
+        ffn_dim=96,
+        norm_eps=1e-6,
+        act="silu",
+        tie_embeddings=False,
+        qk_norm=True,
+        v_head_dim=16,
+        mixer_pattern="LLL*LLL*",
+        mixer_mlp=True,
+        norm_after=True,
+        delta_heads=3,
+        delta_key_dim=16,
+        delta_value_dim=24,
+        delta_conv=4,
+        delta_chunk=8,
+        delta_neg_eigval=True,
+        residual_f32=True,
+    )
+
+
 def tiny_ssm_moe_ep2s(vocab_size: int = 512) -> ModelConfig:
     """tiny-ssm-moe as one of 2 chips that share each layer: experts 0-3
     and ``vocab_size`` rows of a table twice as long."""
@@ -924,6 +1029,8 @@ PRESETS = {
     "tiny-ssm-moe-ep2s": tiny_ssm_moe_ep2s,
     "tiny-ssm-mlp": tiny_ssm_mlp,
     "granite-4.0-h-micro": granite_4_0_h_micro,
+    "tiny-delta-mlp": tiny_delta_mlp,
+    "olmo-hybrid-7b": olmo_hybrid_7b,
     "nemotron-3-nano-30b-a3b": nemotron_3_nano_30b_a3b,
     "nemotron-3-nano-30b-a3b-ep2s": nemotron_3_nano_30b_a3b_ep2s,
     "tiny-sdar-moe": tiny_sdar_moe,

@@ -2,20 +2,26 @@
 and attention, each alone under one norm and one residual
 (``NVIDIA-Nemotron-3-Nano-30B-A3B``, ``model_type`` ``nemotron_h``), or each
 followed by a dense gated MLP under a norm and a residual of its own
-(``granite-4.0-h-micro``, ``model_type`` ``granitemoehybrid``).
+(``granite-4.0-h-micro``, ``model_type`` ``granitemoehybrid``), or gated
+delta-rule layers and attention, each followed by such an MLP, every branch
+normed AFTER it (``Olmo-Hybrid-7B``, ``model_type`` ``olmo_hybrid``).
 
 ``models/transformer.py`` hands its entry points here when
 ``cfg.mixer_pattern`` is set, so the engine, the prefix pool and the tunnel
 run this family through the calls they make for every other.
 
 Layers.  ``x <- x + mixer(RMSNorm(x))``, the mixer by the layer's letter in
-``cfg.mixer_kinds``: ``M`` a Mamba-2 mixer (models/ssm.py), ``E`` routed
+``cfg.mixer_kinds``: ``M`` a Mamba-2 mixer (models/ssm.py), ``L`` a gated
+delta-rule mixer (models/delta.py: a matrix state ``[Dk, Dv]`` a head; a
+pattern holds ``M`` or ``L``, not both: :func:`state_kind`), ``E`` routed
 experts of two products (``down(relu(up u)^2)``) with a shared expert of its
 own width (models/moe.py), ``*`` attention (``cfg.n_heads`` query heads on
 ``cfg.n_kv_heads`` KV heads, causal, no rotary: no position is encoded
-anywhere; scores scaled by ``cfg.query_scale`` where the model states one).
+anywhere; scores scaled by ``cfg.query_scale`` where the model states one;
+under ``cfg.qk_norm`` an RMSNorm over the whole width of the query and of the
+key before the heads are split).
 The kinds differ in shape, so each kind's weights are stacked by
-themselves (``mamba``, ``attn``, ``blocks`` = the routed layers) and the
+themselves (``mamba``, ``delta``, ``attn``, ``blocks`` = the routed layers) and the
 layers are written out in order, each taking its static slice; where the
 grouped products are the kernel's, the routed layers read the stacked
 experts where they lie (``moe_mlp(stacked=...)``).
@@ -28,7 +34,10 @@ scaled by ``cfg.embed_multiplier``, the logits divided by
 ``cfg.logits_divisor``, and under ``cfg.tie_embeddings`` the head is the
 embedding (no ``lm_head`` leaf).  Each is the identity at its default, and
 a model that sets none of them lowers to the program it lowered to before
-they existed.
+they existed.  Under ``cfg.norm_after`` (OLMo's block) no norm stands before
+a branch and the branch's ``norm`` weight norms its OUTPUT: ``x <- x +
+RMSNorm(mixer(x))``, ``x <- x + RMSNorm(mlp(x))``; the identity at its
+default too.
 
 **The cache is KV planes and a state a slot**, one dict under one allocator:
 
@@ -45,7 +54,13 @@ they existed.
   the sublanes of that write and not of the leaf, and a program short of
   memory then moves the whole leaf into the write's layout and back in
   every layer (72 copies of 61 MB a step in granite-4.0-h-micro's cell:
-  ISSUE 47).
+  ISSUE 47);
+- of a delta-rule model instead ``"delta"`` ``[Ld, rows, H, Dk / f, f * Dv]``
+  (float32: a head's ``[Dk, Dv]`` with ``f`` of its rows side by side on the
+  lanes, ``delta.pack``: the same bytes, every row of the leaf whole ``(8,
+  128)`` tiles where ``Dv`` is 192) and ``"dconv"`` ``[Ld, rows, (K - 1) *
+  C]`` over ``q | k | v`` (:data:`KEYS_OF`: leaves of their own names, so
+  that a Mamba-2 model's leaves and programs stay what they were).
 
 A token caches rows in the attention layers only (the prefix pool's pages);
 the state is no function of one token, so the pool holds **snapshots** of it
@@ -82,15 +97,29 @@ the reference the kernel is held to.
 Int8 planes (``--kv-quant int8``) are models/swa.py's: the benchmark's cache
 control; the state has no quantised form.
 
+A delta-rule layer's state is updated by ``delta.delta_step`` in XLA over
+the layer's slice of the leaf as it lies (scope ``delta_step``; ``state_
+update_branch`` answers ``elementwise``: ``ops/pallas_ssm_step.py`` declines
+these shapes, and a kernel for the matrix-state step is a later change's);
+prefill is ``delta.delta_scan``, the rule in chunks of ``cfg.delta_chunk``.
+
 Scopes: ``ssm_proj`` (the two projections, the gate and its norm),
-``ssm_conv``, ``ssm_scan`` (prefill), ``ssm_step`` (decode), ``state_read``
-/ ``state_write`` (a slice or copy of a state leaf that is not the update
-itself), beside ``attn``, ``kv_read``, ``kv_write``, ``moe_route``,
-``moe_experts``, ``moe_shared``, ``head_sample``.
+``ssm_conv``, ``ssm_scan`` (prefill), ``ssm_step`` (decode); of a delta-rule
+layer ``delta_proj`` (the six projections, the L2 norms, the gate and its
+norm, the output's norm), ``delta_conv``, ``delta_scan`` (prefill),
+``delta_step`` (decode); ``state_read`` / ``state_write`` (a slice or copy of
+a state leaf that is not the update itself), beside ``attn``, ``ffn``,
+``kv_read``, ``kv_write``, ``moe_route``, ``moe_experts``, ``moe_shared``,
+``head_sample``.  The dispatch records' ``state_rows`` / ``state_bytes`` and
+the counters ``engine_state_snapshots_total`` / ``engine_state_restores_
+total`` read for either kind of state (the engine follows :func:`state_keys`,
+:func:`state_bytes_per_slot` and :func:`state_update_branch`, and names no
+kind).
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -105,6 +134,13 @@ from p2p_llm_tunnel_tpu.models.moe import (
     expert_leaves,
     grouped_product_branch,
     moe_mlp,
+)
+from p2p_llm_tunnel_tpu.models.delta import (
+    delta_scan,
+    delta_step,
+    gated_head_norm,
+    pack,
+    unit,
 )
 from p2p_llm_tunnel_tpu.models.quant import mm, round_act
 from p2p_llm_tunnel_tpu.models.ssm import (
@@ -126,20 +162,42 @@ from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import (
 )
 
 #: The cache leaves that are a state a slot, not rows a token: the prefix
-#: pool keeps snapshots of them, never pages.
+#: pool keeps snapshots of them, never pages.  A Mamba-2 model's.
 STATE_KEYS = ("ssm", "conv")
+#: The same by the letter of the kind of recurrent mixer that holds them
+#: (the state, then the convolution's tail): a delta-rule model's are leaves
+#: of their own names and shapes, a Mamba-2 model's stay what they were.  A
+#: pattern holds one such kind (:func:`state_kind`, :func:`state_keys`).
+KEYS_OF = {"M": STATE_KEYS, "L": ("delta", "dconv")}
 #: The recurrent state's type: float32, as the model's card asks of its
 #: servers.  A constant, not an option: ``correct`` cannot tell a bfloat16
 #: state apart (PERF.md section 2), so a narrower state has to come as a
 #: path of its own with a number that judges it.
 STATE_DTYPE = jnp.float32
 #: The weights' group of each letter.
-GROUP = {"M": "mamba", "E": "blocks", "*": "attn"}
+GROUP = {"M": "mamba", "L": "delta", "E": "blocks", "*": "attn"}
+#: The scope of a recurrent kind's convolution (decode reads and writes a
+#: layer's tail under it).
+CONV_SCOPE = {"M": "ssm_conv", "L": "delta_conv"}
 
 
 def kind_counts(cfg: ModelConfig) -> dict:
     kinds = cfg.mixer_kinds
     return {k: kinds.count(k) for k in GROUP}
+
+
+def state_kind(cfg: ModelConfig) -> Optional[str]:
+    """The letter of the pattern's recurrent mixers (None: it has none)."""
+    kinds = sorted(set(cfg.mixer_kinds) & set(KEYS_OF))
+    if len(kinds) > 1:
+        raise ValueError(f"the pattern {cfg.mixer_kinds!r} holds two kinds "
+                         f"of recurrent state ({kinds}): one cache, one kind")
+    return kinds[0] if kinds else None
+
+
+def state_keys(cfg: ModelConfig) -> tuple:
+    """The model's cache leaves that are a state a slot."""
+    return KEYS_OF.get(state_kind(cfg), ())
 
 
 def _places(cfg: ModelConfig):
@@ -174,6 +232,10 @@ def state_update_branch(cfg: ModelConfig, mesh) -> str:
       (``pallas_ssm_step.shapes_decline``), and whole lane tiles of 128
       (asked of the chip's compiler, not of the interpreter)."""
     backend = jax.default_backend()
+    if state_kind(cfg) != "M":
+        # (the delta rule's matrix state has no kernel yet: ``delta_step``
+        # in XLA over the layer's slice, where it lies)
+        return ELEMENTWISE
     if not (cfg.flash and (backend == "tpu" or cfg.flash_interpret
                            or cfg.flash_force)):
         return ELEMENTWISE
@@ -188,12 +250,38 @@ def state_update_branch(cfg: ModelConfig, mesh) -> str:
 
 
 def state_bytes_per_slot(cfg: ModelConfig, dtype=jnp.bfloat16) -> int:
-    """What a slot's recurrent state takes in all Mamba-2 layers."""
-    lm = kind_counts(cfg)["M"]
-    ssm = (cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
-           * jnp.dtype(STATE_DTYPE).itemsize)
-    conv = (cfg.ssm_conv - 1) * cfg.ssm_conv_dim * jnp.dtype(dtype).itemsize
-    return lm * (ssm + conv)
+    """What a slot's recurrent state takes in all the layers that hold
+    one, as stored."""
+    kind = state_kind(cfg)
+    if kind is None:
+        return 0
+    state, tail = _state_shapes(cfg, kind)
+    return kind_counts(cfg)[kind] * (
+        math.prod(state) * jnp.dtype(STATE_DTYPE).itemsize
+        + math.prod(tail) * jnp.dtype(dtype).itemsize)
+
+
+def _segment_shapes(cfg: ModelConfig, kind: Optional[str]):
+    """(a layer's state, its convolution's tail) of one row as a prefill
+    segment computes them: the state a head by itself, the tail's positions
+    an axis of their own."""
+    if kind == "L":
+        return ((cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim),
+                (cfg.delta_conv - 1, cfg.delta_conv_dim))
+    return ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+            (cfg.ssm_conv - 1, cfg.ssm_conv_dim))
+
+
+def _state_shapes(cfg: ModelConfig, kind: str):
+    """The same a slot as the leaves hold them, the same values in the same
+    order: the tail's positions side by side in one row, and a delta
+    layer's ``[Dk, Dv]`` ``pack`` rows side by side (models/delta.py: the
+    last axis whole lane tiles)."""
+    state, tail = _segment_shapes(cfg, kind)
+    if kind == "L":
+        f = pack(cfg.delta_key_dim, cfg.delta_value_dim)
+        state = (state[0], state[1] // f, f * state[2])
+    return state, (math.prod(tail),)
 
 
 # ---------------------------------------------------------------------------
@@ -210,11 +298,17 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
     ones), so that random weights decay as trained ones do.  A
     ``cfg.mixer_mlp`` model's MLPs are one stack over all layers, drawn from
     part 2 of the key (``benchmarks/granite_hybrid_reference.py`` states that
+    family's draw again).  The delta-rule layers are drawn from a ten-way
+    split of part 3, ``W_q``, ``W_k``, ``W_v``, ``W_z``, ``W_a``, ``W_b``
+    each by itself (held side by side: ``w_in``, ``w_ab``), ``A`` and the
+    time step as a Mamba-2 layer's; under ``cfg.norm_after`` the embedding's
+    rows are drawn at a unit RMS, the scale of what every branch's norm
+    adds to the stream (``benchmarks/olmo_hybrid_reference.py`` states that
     family's draw again)."""
     dm, h, kv, hd, v = (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
                         cfg.vocab_size)
     n = kind_counts(cfg)
-    lm, le, la = n["M"], n["E"], n["*"]
+    lm, ld, le, la = n["M"], n["L"], n["E"], n["*"]
     keys = jax.random.split(key, 16)
     # (a branch's last matrix is drawn 1 / residual_multiplier as wide: the
     # branch then adds to the stream what it adds in a model that states no
@@ -231,7 +325,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
         # wide: the logits of a random model are then spread as an untied
         # head's are, not flat under the division)
         "embed": dense(keys[7], (v, dm), dm,
-                       cfg.logits_divisor if cfg.tie_embeddings else 1.0),
+                       cfg.logits_divisor if cfg.tie_embeddings
+                       else dm ** 0.5 if cfg.norm_after else 1.0),
         "final_norm": jnp.ones((dm,), dtype),
     }
     if not cfg.tie_embeddings:
@@ -253,6 +348,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
             "wv": dense(ks[2], (la, dm, kv * hd), dm),
             "wo": dense(ks[3], (la, h * hd, dm), h * hd, out_x),
         }
+        if cfg.qk_norm:
+            params["attn"]["q_norm"] = jnp.ones((la, h * hd), dtype)
+            params["attn"]["k_norm"] = jnp.ones((la, kv * hd), dtype)
     if lm:
         ks = jax.random.split(keys[1], 6)
         inner, conv_dim, heads = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads
@@ -271,6 +369,30 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
                 ks[5], (lm, heads), jnp.float32, 1.0, 16.0)),
             "d_skip": jnp.ones((lm, heads), jnp.float32),
             "gate_norm": jnp.ones((lm, inner), dtype),
+        }
+    if ld:
+        ks = jax.random.split(keys[3], 10)
+        heads, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+        dt = jnp.exp(jax.random.uniform(
+            ks[8], (ld, heads), jnp.float32, jnp.log(cfg.ssm_dt_min),
+            jnp.log(cfg.ssm_dt_max)))
+        params["delta"] = {
+            "norm": jnp.ones((ld, dm), dtype),
+            # q | k | v (what the convolution runs over) | z
+            "w_in": jnp.concatenate(
+                [dense(k_, (ld, dm, heads * w), dm)
+                 for k_, w in zip(ks[:4], (dk, dk, dv, dv))], axis=-1),
+            # a | b: the decay's and the write strength's
+            "w_ab": jnp.concatenate(
+                [dense(ks[4], (ld, dm, heads), dm),
+                 dense(ks[5], (ld, dm, heads), dm)], axis=-1),
+            "conv_w": dense(ks[6], (ld, cfg.delta_conv, cfg.delta_conv_dim),
+                            cfg.delta_conv),
+            "w_out": dense(ks[7], (ld, heads * dv, dm), heads * dv, out_x),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "a_log": jnp.log(jax.random.uniform(
+                ks[9], (ld, heads), jnp.float32, 1.0, 16.0)),
+            "gate_norm": jnp.ones((ld, dv), dtype),
         }
     if le:
         e, fe = cfg.n_experts, cfg.expert_dim
@@ -312,8 +434,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
 
 def init_kv_cache(cfg: ModelConfig, num_slots: int, max_seq: int,
                   dtype=jnp.bfloat16, quant=False):
-    """The attention layers' planes ``"k"``, ``"v"`` and the Mamba-2 layers'
-    ``"ssm"`` and ``"conv"`` (zeros: a sequence's start)."""
+    """The attention layers' planes ``"k"``, ``"v"`` and the recurrent
+    layers' state and tail (:data:`KEYS_OF` the pattern's kind; zeros:
+    a sequence's start)."""
     plane = dtype
     if quant in (True, "int8"):
         plane = jnp.int8
@@ -328,11 +451,11 @@ def init_kv_cache(cfg: ModelConfig, num_slots: int, max_seq: int,
         if plane == jnp.int8:
             out[name + "_scale"] = jnp.zeros(
                 (n["*"], num_slots, max_seq, kv), jnp.float32)
-    out["ssm"] = jnp.zeros(
-        (n["M"], num_slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-        STATE_DTYPE)
-    out["conv"] = jnp.zeros(
-        (n["M"], num_slots, (cfg.ssm_conv - 1) * cfg.ssm_conv_dim), dtype)
+    kind = state_kind(cfg)
+    if kind is not None:
+        for name, shape, typ in zip(KEYS_OF[kind], _state_shapes(cfg, kind),
+                                    (STATE_DTYPE, dtype)):
+            out[name] = jnp.zeros((n[kind], num_slots) + shape, typ)
     return out
 
 
@@ -340,10 +463,26 @@ def cache_section(cfg: ModelConfig, kv_cache) -> dict:
     """What /healthz ``config.model.cache`` says: the attention layers'
     planes (what a pooled token takes) and the state a slot."""
     la, positions = kv_cache["k"].shape[0], kv_cache["k"].shape[2]
+    keys = state_keys(cfg)
     per_token = sum(a.shape[3] * a.dtype.itemsize
-                    for name, a in kv_cache.items() if name not in STATE_KEYS)
-    ssm, conv = kv_cache["ssm"], kv_cache["conv"]
+                    for name, a in kv_cache.items() if name not in keys)
+    ssm, conv = (kv_cache[name] for name in keys)
     per_slot = state_bytes_per_slot(cfg, conv.dtype)
+    if state_kind(cfg) == "M":
+        own = {"heads": cfg.ssm_heads, "head_width": cfg.ssm_head_dim,
+               "state_width": cfg.ssm_state,
+               "conv_positions": cfg.ssm_conv - 1,
+               "conv_width": cfg.ssm_conv_dim}
+    else:
+        own = {"rule": "gated delta", "heads": cfg.delta_heads,
+               "key_width": cfg.delta_key_dim,
+               "value_width": cfg.delta_value_dim,
+               # a head's [key_width, value_width] as the leaf holds it
+               "held_as": list(ssm.shape[3:]),
+               "conv_positions": cfg.delta_conv - 1,
+               "conv_width": cfg.delta_conv_dim}
+    state = {"layers": ssm.shape[0], **own, "type": str(ssm.dtype),
+             "conv_type": str(conv.dtype), "bytes_per_slot": per_slot}
     return {
         "form": "kv_heads+state",
         "kinds": {
@@ -353,15 +492,7 @@ def cache_section(cfg: ModelConfig, kv_cache) -> dict:
                 "positions_per_slot": positions,
                 "bytes_per_token_layer": per_token,
             },
-            "state": {
-                "layers": ssm.shape[0], "heads": cfg.ssm_heads,
-                "head_width": cfg.ssm_head_dim, "state_width": cfg.ssm_state,
-                "type": str(ssm.dtype),
-                "conv_positions": cfg.ssm_conv - 1,
-                "conv_width": cfg.ssm_conv_dim,
-                "conv_type": str(conv.dtype),
-                "bytes_per_slot": per_slot,
-            },
+            "state": state,
         },
         "bytes_per_token": la * per_token,
         "bytes_per_slot": la * per_token * positions + per_slot,
@@ -434,13 +565,77 @@ def _mamba(cfg: ModelConfig, blk, h, tail, state, real, step=None):
             tail, state
 
 
+def _delta(cfg: ModelConfig, blk, h, tail, state, real, step=None):
+    """One gated delta-rule mixer over ``h [B,T,Dm]`` (the weights' type),
+    as :func:`_mamba` is one Mamba-2 mixer: ``tail [B,K-1,C]`` and ``state
+    [B,H,Dk,Dv]`` before the segment, ``real [B,T]`` -> (out ``[B,T,Dm]``,
+    new tail, new state).  ``step``: decode's, ``T`` is 1, the tail in and
+    out is ``[B,(K-1)*C]`` as the leaf holds it and the update is ``step(q,
+    k, v, g, beta) -> o`` of :func:`delta.delta_step`'s operands, which
+    holds the state itself (``state`` is None, in and out)."""
+    b, t, _ = h.shape
+    heads, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    conv_dim = cfg.delta_conv_dim
+    with jax.named_scope("delta_proj"):
+        qkvz = _mm32(h, blk["w_in"], cfg.act_quant)
+        # (the convolution's carry is the activations' type, as the tail is)
+        qkv = qkvz[..., :conv_dim].astype(h.dtype)
+        z = qkvz[..., conv_dim:].reshape(b, t, heads, dv)
+        ab = _mm32(h, blk["w_ab"], cfg.act_quant)
+        g = -jnp.exp(blk["a_log"]) * jax.nn.softplus(
+            ab[..., :heads] + blk["dt_bias"])
+        beta = jax.nn.sigmoid(ab[..., heads:])
+        if cfg.delta_neg_eigval:
+            beta = 2.0 * beta
+        # a padded position leaves the state as it is
+        g = jnp.where(real[..., None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+    with jax.named_scope("delta_conv"):
+        no_bias = jnp.zeros((conv_dim,), jnp.float32)
+        if step is not None:
+            qkv, tail = conv_step(blk["conv_w"], no_bias, tail, qkv[:, 0],
+                                  real[:, 0])
+            qkv = qkv[:, None]
+        else:
+            qkv, tail = causal_conv(blk["conv_w"], no_bias, tail, qkv,
+                                    real.sum(axis=1).astype(jnp.int32))
+    with jax.named_scope("delta_proj"):
+        q, k = unit(qkv[..., :heads * dk].reshape(b, t, heads, dk),
+                    qkv[..., heads * dk:2 * heads * dk].reshape(
+                        b, t, heads, dk))
+        v = qkv[..., 2 * heads * dk:].reshape(b, t, heads, dv)
+    if step is not None:
+        with jax.named_scope("delta_step"):
+            o = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])[:, None]
+    else:
+        with jax.named_scope("delta_scan"):
+            o, new = delta_scan(q, k, v, g, beta, state, cfg.delta_chunk)
+            state = new.astype(state.dtype)
+    with jax.named_scope("delta_proj"):
+        y = gated_head_norm(o, z, blk["gate_norm"], cfg.norm_eps)
+        return _mm32(y.reshape(b, t, heads * dv).astype(h.dtype),
+                     blk["w_out"], cfg.act_quant), tail, state
+
+
+#: A recurrent kind's mixer, one signature.
+_RECURRENT = {"M": _mamba, "L": _delta}
+
+
 def _qkv(cfg: ModelConfig, blk, h):
     """h [B,T,Dm] -> q [B,T,H,D] and what the token caches: keys and values
-    ``[B,T,K*D]``, heads side by side.  No rotary."""
+    ``[B,T,K*D]``, heads side by side.  No rotary.  Under ``cfg.qk_norm`` an
+    RMSNorm over the whole width of the query and of the key, before the
+    heads are split."""
     b, t, _ = h.shape
     aq = cfg.act_quant
-    q = mm(h, blk["wq"], aq).reshape(b, t, cfg.n_heads, cfg.head_dim)
-    return q, mm(h, blk["wk"], aq), mm(h, blk["wv"], aq)
+    q = mm(h, blk["wq"], aq)
+    if cfg.qk_norm:
+        q = rms_norm(q, blk["q_norm"], cfg.norm_eps)
+    q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
+    k = mm(h, blk["wk"], aq)
+    if cfg.qk_norm:
+        k = rms_norm(k, blk["k_norm"], cfg.norm_eps)
+    return q, k, mm(h, blk["wv"], aq)
 
 
 def _stacked_experts(cfg: ModelConfig, params):
@@ -483,23 +678,33 @@ def _added(cfg: ModelConfig, out):
 
 def _mlp(cfg: ModelConfig, blk, x, dtype):
     """The dense gated MLP a ``cfg.mixer_mlp`` layer carries after its
-    mixer, from the stream ``x``: its own norm, ``[a | b] = u W_in``,
-    ``W_out(act(a) * b)``; the gate's product in float32 (``_mm32``)."""
+    mixer, from the stream ``x``: its own norm (on its output under
+    ``cfg.norm_after``), ``[a | b] = u W_in``, ``W_out(act(a) * b)``; the
+    gate's product in float32 (``_mm32``)."""
     from p2p_llm_tunnel_tpu.models.transformer import _act
 
     with jax.named_scope("ffn"):
-        h = rms_norm(x, blk["norm"], cfg.norm_eps).astype(dtype)
-        ab = _mm32(h, blk["w_in"], cfg.act_quant)
+        h = x if cfg.norm_after else rms_norm(x, blk["norm"], cfg.norm_eps)
+        ab = _mm32(h.astype(dtype), blk["w_in"], cfg.act_quant)
         gated = _act(cfg, ab[..., :cfg.ffn_dim]) * ab[..., cfg.ffn_dim:]
-        return _mm32(gated.astype(dtype), blk["w_out"], cfg.act_quant)
+        out = _mm32(gated.astype(dtype), blk["w_out"], cfg.act_quant)
+        return rms_norm(out, blk["norm"], cfg.norm_eps) if cfg.norm_after \
+            else out
+
+
+#: The scope of a kind's last product: its output's norm stands there too.
+_OUT_SCOPE = {"M": "ssm_proj", "L": "delta_proj", "E": "ffn", "*": "attn"}
 
 
 def _run_layers(cfg: ModelConfig, params, x, counted, mamba, attend):
     """The layers in order over the float32 stream ``x``: ``mamba(i, blk,
-    h)`` and ``attend(i, blk, h)`` give a Mamba-2 and an attention layer's
-    output from its normed input (``i``: the layer's index in its kind's
-    stack, and so in its kind's cache leaves); under ``cfg.mixer_mlp`` the
-    layer's MLP follows.  Returns (x, stats)."""
+    h)`` and ``attend(i, blk, h)`` give a recurrent (Mamba-2 or delta-rule)
+    and an attention layer's output from its normed input (``i``: the
+    layer's index in its kind's stack, and so in its kind's cache leaves);
+    under ``cfg.mixer_mlp`` the layer's MLP follows.  Under
+    ``cfg.norm_after`` a branch reads the stream as it is and its OUTPUT is
+    normed (under the scope of the branch's last product).  Returns (x,
+    stats)."""
     from p2p_llm_tunnel_tpu.models.transformer import _act
 
     dtype = params["embed"].dtype
@@ -514,9 +719,9 @@ def _run_layers(cfg: ModelConfig, params, x, counted, mamba, attend):
     total = jnp.zeros((STATS,), jnp.int32)
     for layer, (kind, i) in enumerate(_places(cfg)):
         blk = _layer(params[GROUP[kind]], i, leaves)
-        h32 = rms_norm(x, blk["norm"], cfg.norm_eps)
+        h32 = x if cfg.norm_after else rms_norm(x, blk["norm"], cfg.norm_eps)
         h = h32.astype(dtype)
-        if kind == "M":
+        if kind in KEYS_OF:
             out = mamba(i, blk, h)
         elif kind == "*":
             out = attend(i, blk, h)
@@ -526,6 +731,9 @@ def _run_layers(cfg: ModelConfig, params, x, counted, mamba, attend):
                     cfg, blk, h, lambda v: _act(cfg, v), counted,
                     stacked=stacked, layer=i, router_in=h32)
                 total = total + stats
+        if cfg.norm_after:
+            with jax.named_scope(_OUT_SCOPE[kind]):
+                out = rms_norm(out, blk["norm"], cfg.norm_eps)
         x = x + _added(cfg, out)
         if cfg.mixer_mlp:
             x = x + _added(
@@ -548,12 +756,14 @@ def prefill(cfg: ModelConfig, params, tokens, valid, counted=None):
     if counted is None:
         counted = valid
     kv, states = [], []
-    zero_tail = jnp.zeros((b, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype)
-    zero_state = jnp.zeros(
-        (b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+    kind = state_kind(cfg)
+    state_of, tail_of = _segment_shapes(cfg, kind)
+    zero_tail = jnp.zeros((b,) + tail_of, dtype)
+    zero_state = jnp.zeros((b,) + state_of, jnp.float32)
 
     def mamba(i, blk, h):
-        out, tail, state = _mamba(cfg, blk, h, zero_tail, zero_state, valid)
+        out, tail, state = _RECURRENT[kind](cfg, blk, h, zero_tail,
+                                            zero_state, valid)
         states.append((state, tail))
         return out
 
@@ -594,10 +804,11 @@ def _rows_of(leaf, i: int, slots, view: Optional[int] = None):
         for r in range(slots.shape[0])])
 
 
-def _write_state(kv_cache, states, slots):
+def _write_state(kv_cache, keys, states, slots):
     """The dispatch's rows' new state into their slots, a layer at a time
-    (``states``: [(ssm [Bp,H,P,N], conv [Bp,K-1,C])] in stack order; a tail
-    is laid as the leaf holds it, its positions side by side)."""
+    (``keys``: the model's state leaves; ``states``: [(state [Bp,H,P,N],
+    tail [Bp,K-1,C])] in stack order; each is laid as its leaf holds it, a
+    tail's positions side by side)."""
     out = dict(kv_cache)
     with jax.named_scope("state_write"):
         # The leaves and every layer's new state pass one barrier: each
@@ -608,8 +819,8 @@ def _write_state(kv_cache, states, slots):
         # dispatch where that fitted and more than the chip had at 4.6 GB
         # (ISSUE 46).
         held, states = jax.lax.optimization_barrier(
-            ({name: out[name] for name in STATE_KEYS}, states))
-        for name, vals in zip(STATE_KEYS, zip(*states)):
+            ({name: out[name] for name in keys}, states))
+        for name, vals in zip(keys, zip(*states)):
             leaf = held[name]
             for i, v in enumerate(vals):
                 v = v.reshape(v.shape[:1] + leaf.shape[2:])
@@ -637,7 +848,8 @@ def prefill_into_cache(cfg, params, tokens, lengths, kv_cache, slots,
         out = _write(cfg, out, "full", k[:, :, :s], v[:, :, :s], slots,
                      positions[:, :s], None)
     if "state" in rows:
-        out = _write_state(out, list(zip(*rows["state"])), slots)
+        out = _write_state(out, state_keys(cfg), list(zip(*rows["state"])),
+                           slots)
     if not return_prompt_logprobs:
         return last, out, stats
     lsm = jax.nn.log_softmax(logits[:, :-1], axis=-1)
@@ -674,6 +886,9 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
         pos, jnp.broadcast_to(jnp.arange(kv_view), (b, kv_view)))
     carried = starts > 0
     kv, states = [], []
+    kind = state_kind(cfg)
+    state_key, tail_key = state_keys(cfg) or (None, None)
+    state_of, tail_of = _segment_shapes(cfg, kind)
 
     def view_rows(name, i):
         rows = _rows_of(kv_cache[name], i, slots, kv_view)
@@ -684,8 +899,10 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
 
     def mamba(i, blk, h):
         with jax.named_scope("state_read"):
-            state = jnp.where(carried[:, None, None, None],
-                              _rows_of(kv_cache["ssm"], i, slots), 0)
+            state = jnp.where(
+                carried[:, None, None, None],
+                _rows_of(kv_cache[state_key], i, slots).reshape(
+                    (b,) + state_of), 0)
             # (the rows' positions become an axis again behind a barrier,
             # and the select reads what the barrier hands on, as it read
             # the rows before ISSUE 47.  The TPU compiler's broadcast
@@ -695,10 +912,10 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
             # select or before it, the compiler overflowed that stack in
             # three cold starts of three)
             tail = jax.lax.optimization_barrier(
-                _rows_of(kv_cache["conv"], i, slots).reshape(
-                    (b, cfg.ssm_conv - 1, cfg.ssm_conv_dim)))
+                _rows_of(kv_cache[tail_key], i, slots).reshape(
+                    (b,) + tail_of))
             tail = jnp.where(carried[:, None, None], tail, 0)
-        out, tail, state = _mamba(cfg, blk, h, tail, state, valid)
+        out, tail, state = _RECURRENT[kind](cfg, blk, h, tail, state, valid)
         states.append((state, tail))
         return out
 
@@ -722,7 +939,7 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
         k, v = (jnp.stack(a) for a in zip(*kv))
         new_cache = _write(cfg, new_cache, "full", k, v, slots, pos, None)
     if states:
-        new_cache = _write_state(new_cache, states, slots)
+        new_cache = _write_state(new_cache, state_keys(cfg), states, slots)
     logits = _head(cfg, params, x)
     if return_all_logits:
         return logits, new_cache, stats
@@ -775,6 +992,9 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
         with jax.named_scope("ssm_step"):
             state_rows = live_rows_worklist(positions, s)
     cache = dict(kv_cache)
+    kind = state_kind(cfg)
+    state_key, tail_key = state_keys(cfg) or (None, None)
+    one_step = delta_step if kind == "L" else ssm_step
 
     def mamba(i, blk, h):
         # (the layer's part of each leaf in and out is the update itself:
@@ -785,15 +1005,16 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
                     cache["ssm"], i, state_rows, *operands,
                     interpret=cfg.flash_interpret)
                 return y
-            y, state = ssm_step(*operands, cache["ssm"][i])
-            cache["ssm"] = cache["ssm"].at[i].set(state)
+            y, state = one_step(*operands, cache[state_key][i])
+            cache[state_key] = cache[state_key].at[i].set(state)
             return y
 
-        with jax.named_scope("ssm_conv"):
-            tail = cache["conv"][i]
-        out, tail, _ = _mamba(cfg, blk, h, tail, None, live[:, None], step)
-        with jax.named_scope("ssm_conv"):
-            cache["conv"] = cache["conv"].at[i].set(tail)
+        with jax.named_scope(CONV_SCOPE[kind]):
+            tail = cache[tail_key][i]
+        out, tail, _ = _RECURRENT[kind](cfg, blk, h, tail, None,
+                                        live[:, None], step)
+        with jax.named_scope(CONV_SCOPE[kind]):
+            cache[tail_key] = cache[tail_key].at[i].set(tail)
         return out
 
     def attend(i, blk, h):

@@ -707,11 +707,12 @@ SSM_PROGRAMS = {
 }
 
 
-def _ssm_burst(T, cfg, params, cache, tokens, positions, steps=4):
+def _ssm_burst(T, cfg, params, cache, tokens, positions, steps=4, seq=None):
     def one(carry, _):
         tok, pos, cache = carry
         logits, cache, stats = T.decode_step(
-            cfg, params, cache, tok, pos, kv_view=SSM_SEQ, with_stats=True)
+            cfg, params, cache, tok, pos, kv_view=seq or SSM_SEQ,
+            with_stats=True)
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (tok, pos + 1, cache), (tok, stats)
 
@@ -831,6 +832,54 @@ def test_the_whole_hybrid_decodes_through_both_kernels_and_fits(chip):
     held = (m.argument_size_in_bytes + m.temp_size_in_bytes
             + m.output_size_in_bytes - m.alias_size_in_bytes
             + 18 * state_bytes_per_slot(cfg) + 2048 * 16 * 8192)
+    assert held < 14.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
+
+
+@pytest.mark.slow  # 20 s alone: this file is tier-1's longest (ISSUE 46)
+def test_the_delta_hybrid_decodes_where_its_state_lies_and_fits(chip):
+    """``olmo-hybrid-7b`` at its cell's shapes (64 slots + the scratch row x
+    1024), as a TPU backend runs a decode burst (ISSUE 51): the delta
+    state's leaf ``[12, 65, 30, 48, 384]`` (a head's ``[96, 192]`` two rows
+    side by side: whole ``(8, 128)`` tiles, no padding) and the tail's
+    ``[12, 65, 34560]`` are the donated ones, updated where they lie (no
+    copy of either, and the step's temporaries stay far under a layer's
+    slice of the state: ``k`` spread over the lanes fuses into the pass that
+    reads it), the rows kernel over planes whose rows are 30 KV heads of 128
+    side by side in the 4 attention layers, and 8.2 GB of weights, the
+    cache, 17 snapshots, the pool's 512 blocks and the step's temporaries
+    inside a v5e's 16 GB."""
+    from p2p_llm_tunnel_tpu.models import transformer as T
+    from p2p_llm_tunnel_tpu.models.config import get_config
+    from p2p_llm_tunnel_tpu.models.ssm_moe import state_bytes_per_slot
+
+    rows, seq = 65, 1024
+    cfg = get_config("olmo-hybrid-7b")
+    params, cache = _share_shapes(chip, cfg, rows, seq)
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (4, rows, seq, 3840), "v": (4, rows, seq, 3840),
+        "delta": (12, rows, 30, 48, 384), "dconv": (12, rows, 3 * 11520)}
+    batch = _on(chip, {"rows": jax.ShapeDtypeStruct((rows,), jnp.int32)})
+    compiled = jax.jit(
+        lambda p, c, b: _ssm_burst(T, replace(cfg, flash_force=True), p, c,
+                                   b["rows"], b["rows"], seq=seq),
+        donate_argnums=(1,)).lower(params, cache, batch).compile()
+    hlo = compiled.as_text()
+    for leaf in ("delta", "dconv"):
+        assert _leaf_moves(hlo, cache[leaf].shape) == [], leaf
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert aliased.count("alias") == len(cache)
+    calls = [ln for ln in hlo.splitlines() if "custom-call(" in ln]
+    assert sum(f"%{ROWS_KERNEL}" in ln for ln in calls) == 4
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert 8.20e9 < weights < 8.21e9  # 4,101 M parameters
+    m = compiled.memory_analysis()
+    # a layer's slice of the state is 144 MB: nothing of that size stands
+    # beside it
+    assert m.temp_size_in_bytes < 64 * 2 ** 20, m.temp_size_in_bytes
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+            + 17 * state_bytes_per_slot(cfg) + 512 * 16 * 61440)
     assert held < 14.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
 
 
