@@ -27,7 +27,10 @@ it equals the token-by-token recurrence at any chunking
 (tests/test_olmo_hybrid.py).  Its products are asked in full float32, as
 ``ssm.ssm_scan``'s are.
 
-:func:`delta_step` is one position over the state AS THE LEAF HOLDS IT,
+:func:`delta_step` is one position over the state AS THE LEAF HOLDS IT
+(decode's update on a CPU backend, under a mesh and with ``cfg.flash`` off,
+and the reference that the TPU's kernel over the step's live rows,
+``ops/pallas_delta_step.py``, is held to: ``ssm_moe.state_update_branch``),
 ``[B, H, Dk / f, f * Dv]``: ``f`` (:func:`pack`) rows of a head's ``[Dk,
 Dv]`` side by side on the lanes, the same bytes in the same order, so that
 the leaf's last axis is whole lane tiles of 128 where ``Dv`` is not (192: two
@@ -38,7 +41,8 @@ elementwise over that layout: ``k`` is spread over the lanes of its rows
 ``Dk`` (``S^T k``, ``S^T q``) are sums over the sublanes whose ``f`` parts
 are added after.  The output is ``e^g S^T q + (k . q) d``, which is ``(e^g S
 + k (x) d)^T q`` read from the OLD state: one pass reads ``S`` for both sums,
-a second writes the new one.
+a second writes the new one (in XLA, over every row of the slice; the kernel
+keeps a live row's block in VMEM between the two: one read, one write).
 """
 
 from __future__ import annotations

@@ -85,23 +85,21 @@ layers counted (``moe.STATS``).
 Decode reads an attention layer by what
 ``transformer.decode_attention_branch`` answers: on the TPU, over plain
 bf16 planes, ``decode_attention_rows`` over the stacked planes where they
-lie; elsewhere the einsum over the layer's ``kv_view`` positions.  A Mamba-2
-layer's state is updated where it lies by what :func:`state_update_branch`
-answers (scope ``ssm_step`` either way): on the TPU one kernel a layer
-(``ops/pallas_ssm_step.py``) over the step's LIVE rows of the donated leaf,
-each row's state read once and written once with the sum with ``C`` in the
-same pass, parked rows and the other layers never named; elsewhere (a CPU
-backend, a mesh, ``cfg.flash`` off, a state that is no whole tiles)
-``ssm.ssm_step`` elementwise over the layer's ``[rows, H, P, N]`` slice,
-the reference the kernel is held to.
+lie; elsewhere the einsum over the layer's ``kv_view`` positions.  A
+recurrent layer's state is updated where it lies by what
+:func:`state_update_branch` answers (scope ``ssm_step`` or ``delta_step``
+either way): on the TPU one kernel a layer (``ops/pallas_ssm_step.py`` for a
+Mamba-2 state, ``ops/pallas_delta_step.py`` for the delta rule's matrix
+state) over the step's LIVE rows of the donated leaf, each row's state read
+once and written once with the sums (with ``C``; ``S^T k`` and ``S^T q``) in
+the same pass, parked rows and the other layers never named; elsewhere (a
+CPU backend, a mesh, ``cfg.flash`` off, a state that is no whole tiles)
+``ssm.ssm_step`` / ``delta.delta_step`` elementwise over the layer's slice
+of the leaf as it lies, the reference the kernel is held to.  Prefill of a
+delta-rule layer is ``delta.delta_scan``, the rule in chunks of
+``cfg.delta_chunk``.
 Int8 planes (``--kv-quant int8``) are models/swa.py's: the benchmark's cache
 control; the state has no quantised form.
-
-A delta-rule layer's state is updated by ``delta.delta_step`` in XLA over
-the layer's slice of the leaf as it lies (scope ``delta_step``; ``state_
-update_branch`` answers ``elementwise``: ``ops/pallas_ssm_step.py`` declines
-these shapes, and a kernel for the matrix-state step is a later change's);
-prefill is ``delta.delta_scan``, the rule in chunks of ``cfg.delta_chunk``.
 
 Scopes: ``ssm_proj`` (the two projections, the gate and its norm),
 ``ssm_conv``, ``ssm_scan`` (prefill), ``ssm_step`` (decode); of a delta-rule
@@ -153,9 +151,15 @@ from p2p_llm_tunnel_tpu.models.ssm import (
 from p2p_llm_tunnel_tpu.models.swa import _as_held, _attend, _pack, _unpack, _write
 from p2p_llm_tunnel_tpu.ops.attention import window_mask
 from p2p_llm_tunnel_tpu.ops.norms import rms_norm
+from p2p_llm_tunnel_tpu.ops.pallas_delta_step import (
+    DELTA_STEP_KERNEL,
+    delta_step_rows,
+    shapes_decline as delta_shapes_decline,
+)
 from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import (
     ELEMENTWISE,
     SSM_STEP_KERNEL,
+    SUBLANES,
     live_rows_worklist,
     shapes_decline,
     ssm_step_rows,
@@ -179,6 +183,12 @@ GROUP = {"M": "mamba", "L": "delta", "E": "blocks", "*": "attn"}
 #: The scope of a recurrent kind's convolution (decode reads and writes a
 #: layer's tail under it).
 CONV_SCOPE = {"M": "ssm_conv", "L": "delta_conv"}
+#: The scope of a recurrent kind's one-token update of its state.
+STEP_SCOPE = {"M": "ssm_step", "L": "delta_step"}
+#: A recurrent kind's one-token update: in XLA over a layer's slice of the
+#: state, and the kernel over the live rows of the leaf
+#: (:func:`state_update_branch` says which a decode program takes).
+_STEP = {"M": (ssm_step, ssm_step_rows), "L": (delta_step, delta_step_rows)}
 
 
 def kind_counts(cfg: ModelConfig) -> dict:
@@ -212,12 +222,14 @@ def _places(cfg: ModelConfig):
 
 def state_update_branch(cfg: ModelConfig, mesh) -> str:
     """Which implementation decode's one-token update of the recurrent
-    state takes: ``ops/pallas_ssm_step.py``'s kernel by its name
-    (``SSM_STEP_KERNEL``: the step's live rows of the leaf where they lie,
-    read once and written once, the sum with ``C`` in the same pass) or
-    ``"elementwise"`` (:func:`ssm.ssm_step` in XLA over the layer's slice,
-    every row of it: the reference the kernel is held to).  Answered from
-    what the code observes, no option (ISSUE 45), as
+    state takes: the kernel of the state's kind by its name
+    (``SSM_STEP_KERNEL``, ``ops/pallas_ssm_step.py``, a Mamba-2 state;
+    ``DELTA_STEP_KERNEL``, ``ops/pallas_delta_step.py``, the delta rule's
+    matrix state: the step's live rows of the leaf where they lie, read
+    once and written once, the sums in the same pass) or ``"elementwise"``
+    (:func:`ssm.ssm_step` / :func:`delta.delta_step` in XLA over the
+    layer's slice, every row of it: the reference the kernel is held to).
+    Answered from what the code observes, no option (ISSUE 45, 52), as
     ``transformer.decode_attention_branch`` and
     ``moe.grouped_product_branch`` answer:
 
@@ -227,26 +239,33 @@ def state_update_branch(cfg: ModelConfig, mesh) -> str:
       keeps the reference everywhere;
     - the mesh: a ``pallas_call`` is not GSPMD-partitioned (and state
       under ``--tp`` is refused at start-up);
-    - the static shapes: a head's ``[P, N]`` is whole registers of 8
-      sublanes, in the powers of two the kernel's butterflies take
-      (``pallas_ssm_step.shapes_decline``), and whole lane tiles of 128
-      (asked of the chip's compiler, not of the interpreter)."""
+    - the state's kind and static shapes: a Mamba-2 head's ``[P, N]`` is
+      whole registers of 8 sublanes, in the powers of two the kernel's
+      butterflies take (``pallas_ssm_step.shapes_decline``); a delta
+      head's ``[Dk / f, f * Dv]`` as the leaf holds it is whole lane tiles
+      whose columns of ``k`` and ``q`` lie in one register
+      (``pallas_delta_step.shapes_decline``); and whole tiles of ``(8,
+      128)`` (asked of the chip's compiler, not of the interpreter)."""
     backend = jax.default_backend()
-    if state_kind(cfg) != "M":
-        # (the delta rule's matrix state has no kernel yet: ``delta_step``
-        # in XLA over the layer's slice, where it lies)
-        return ELEMENTWISE
-    if not (cfg.flash and (backend == "tpu" or cfg.flash_interpret
-                           or cfg.flash_force)):
+    kind = state_kind(cfg)
+    if kind is None or not (cfg.flash and (
+            backend == "tpu" or cfg.flash_interpret or cfg.flash_force)):
         return ELEMENTWISE
     if mesh is not None and any(n > 1 for n in dict(mesh.shape).values()):
         return ELEMENTWISE
-    h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-    if shapes_decline(h, p, n, cfg.ssm_groups) is not None:
+    if kind == "L":
+        (_, r, w), _ = _state_shapes(cfg, kind)
+        declined = delta_shapes_decline(r, w, cfg.delta_value_dim)
+        tiles = r % SUBLANES == 0
+        name = DELTA_STEP_KERNEL
+    else:
+        h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+        declined = shapes_decline(h, p, n, cfg.ssm_groups)
+        tiles = n % 128 == 0
+        name = SSM_STEP_KERNEL
+    if declined is not None or not (tiles or cfg.flash_interpret):
         return ELEMENTWISE
-    if not cfg.flash_interpret and n % 128:
-        return ELEMENTWISE
-    return SSM_STEP_KERNEL
+    return name
 
 
 def state_bytes_per_slot(cfg: ModelConfig, dtype=jnp.bfloat16) -> int:
@@ -953,9 +972,10 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
     """``transformer.decode_step`` for this family: one token a row.  The
     cache is threaded through the layers; an attention layer takes one
     in-place row write and reads by what ``decode_attention_branch``
-    answers, a Mamba-2 layer updates its part of the state leaf where it
-    lies by what :func:`state_update_branch` answers (the kernel over the
-    live rows, or ``ssm.ssm_step`` over the layer's slice).  Rows parked at
+    answers, a recurrent layer updates its part of the state leaf where it
+    lies by what :func:`state_update_branch` answers (its kind's kernel
+    over the live rows, or ``ssm.ssm_step`` / ``delta.delta_step`` over the
+    layer's slice).  Rows parked at
     ``positions >= S`` write nothing, leave their state as it is (to the
     bit: ``dt`` 0, or never visited) and count for nothing.  Returns
     (logits [B,V], cache, stats)."""
@@ -986,23 +1006,23 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
     else:
         mask = window_mask(
             pos2d, jnp.broadcast_to(jnp.arange(kv_view), (b, kv_view)))
-    state_rows = None
-    if state_update_branch(cfg, mesh) == SSM_STEP_KERNEL:
-        # One list of the step's live rows, shared by the Mamba-2 layers.
-        with jax.named_scope("ssm_step"):
-            state_rows = live_rows_worklist(positions, s)
-    cache = dict(kv_cache)
     kind = state_kind(cfg)
     state_key, tail_key = state_keys(cfg) or (None, None)
-    one_step = delta_step if kind == "L" else ssm_step
+    one_step, rows_step = _STEP.get(kind, (None, None))
+    state_rows = None
+    if state_update_branch(cfg, mesh) != ELEMENTWISE:
+        # One list of the step's live rows, shared by the recurrent layers.
+        with jax.named_scope(STEP_SCOPE[kind]):
+            state_rows = live_rows_worklist(positions, s)
+    cache = dict(kv_cache)
 
     def mamba(i, blk, h):
         # (the layer's part of each leaf in and out is the update itself:
         # under its scopes, so that its time is the update's)
         def step(*operands):
             if state_rows is not None:
-                y, cache["ssm"] = ssm_step_rows(
-                    cache["ssm"], i, state_rows, *operands,
+                y, cache[state_key] = rows_step(
+                    cache[state_key], i, state_rows, *operands,
                     interpret=cfg.flash_interpret)
                 return y
             y, state = one_step(*operands, cache[state_key][i])

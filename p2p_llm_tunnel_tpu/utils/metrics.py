@@ -40,8 +40,9 @@ METRICS_CATALOG: Dict[str, str] = {
     ),
     "engine_decode_state_kernel_steps_total": (
         "of the decode steps of a model with a recurrent state a slot, "
-        "those whose state updates ran as the Pallas kernel over the "
-        "step's live rows (the record's state_update is not elementwise); "
+        "those whose state updates ran as the Pallas kernel of the "
+        "state's kind (ssm_step_rows, delta_step_rows) over the step's "
+        "live rows (the record's state_update is not elementwise); "
         "over engine_decode_steps_total it is the share of decode that "
         "kernel engages in (counter)"
     ),

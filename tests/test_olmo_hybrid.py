@@ -27,6 +27,8 @@ import pytest
 from benchmarks import olmo_hybrid_reference as bench
 from p2p_llm_tunnel_tpu.models import delta, ssm_moe
 from p2p_llm_tunnel_tpu.models.config import get_config
+from p2p_llm_tunnel_tpu.ops.pallas_delta_step import DELTA_STEP_KERNEL
+from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import ELEMENTWISE
 from p2p_llm_tunnel_tpu.models.transformer import (
     chunk_prefill_into_cache,
     decode_attention_branch,
@@ -127,8 +129,11 @@ def test_the_preset_is_the_layer_the_issue_writes_down(model):
         "k": (2, ROWS, MAX_SEQ, 48), "v": (2, ROWS, MAX_SEQ, 48),
         "delta": (6, ROWS, 3, 1, 384), "dconv": (6, ROWS, 3 * 168)}
     assert ssm_moe.state_keys(cfg) == ("delta", "dconv")
+    # one row a head: the chip's compiler is not asked, the interpreter is
     assert ssm_moe.state_update_branch(
-        replace(cfg, flash_force=True), None) == "elementwise"
+        replace(cfg, flash_force=True), None) == ELEMENTWISE
+    assert ssm_moe.state_update_branch(
+        _decoding(cfg, "kernel"), None) == DELTA_STEP_KERNEL
     assert SHAPES["kinds"] == ("linear_attention",) * 3 + (
         "full_attention",) + ("linear_attention",) * 3 + ("full_attention",)
 
@@ -194,6 +199,18 @@ def test_each_piece_left_out_fails_the_tolerance(model, piece, monkeypatch):
     assert apart > least, apart
 
 
+#: Decode's state update as ``delta.delta_step`` in XLA, and as the kernel
+#: over the live rows (interpreted): ISSUE 52.
+UPDATES = {"elementwise": {}, "kernel": {"flash_interpret": True}}
+
+
+def _decoding(cfg, update):
+    cfg = replace(cfg, **UPDATES[update])
+    assert ssm_moe.state_update_branch(cfg, None) == (
+        DELTA_STEP_KERNEL if update == "kernel" else ELEMENTWISE)
+    return cfg
+
+
 def _chunk(cfg, params, cache, prompt, start, end, slot, width=32):
     """One segment beside a padding row on the scratch slot."""
     tok = jnp.zeros((2, width), jnp.int32).at[0, :end - start].set(
@@ -204,13 +221,16 @@ def _chunk(cfg, params, cache, prompt, start, end, slot, width=32):
         return_all_logits=True)[:2]
 
 
-def test_chunk_prefill_in_segments_then_decode_through_the_cache(model):
+@pytest.mark.parametrize("update", sorted(UPDATES))
+def test_chunk_prefill_in_segments_then_decode_through_the_cache(model,
+                                                                 update):
     """The prompt as chunk-prefill segments of uneven lengths that split
     the rule's chunks of 8 (0-21, 21-27, 27-43), then 40 decode steps (the
-    state by ``delta_step`` over the leaf as it lies), against ONE full
-    forward of the reference: logits, at a tolerance a bfloat16 state
-    fails."""
+    state by ``delta_step`` over the leaf as it lies, or by the kernel over
+    the live rows of the leaf), against ONE full forward of the reference:
+    logits, at a tolerance a bfloat16 state fails."""
     cfg, params = model
+    cfg = _decoding(cfg, update)
     full = _prompt(3, 43) + _prompt(4, 40)
     want = _want(params, full)
     cache = init_kv_cache(cfg, ROWS, MAX_SEQ, jnp.float32)
@@ -322,8 +342,11 @@ def test_padding_leaves_the_state_to_the_bit():
     np.testing.assert_array_equal(np.asarray(after), np.asarray(held))
 
 
-def test_padded_and_parked_rows_leave_state_and_tail_unchanged(model):
+@pytest.mark.parametrize("update", sorted(UPDATES))
+def test_padded_and_parked_rows_leave_state_and_tail_unchanged(model,
+                                                               update):
     cfg, params = model
+    cfg = _decoding(cfg, update)
     prompt = _prompt(5, 30)
     cache = init_kv_cache(cfg, ROWS, MAX_SEQ, jnp.float32)
     _, cache = _chunk(cfg, params, cache, prompt, 0, 30, 2)
@@ -441,7 +464,7 @@ def test_thirty_kv_heads_of_128_are_whole_lane_tiles():
     assert decode_attention_branch(cfg, None, 1024) == "einsum"
     forced = replace(cfg, flash_force=True)
     assert decode_attention_branch(forced, None, 1024) == "pallas-rows"
-    assert ssm_moe.state_update_branch(forced, None) == "elementwise"
+    assert ssm_moe.state_update_branch(forced, None) == DELTA_STEP_KERNEL
 
 
 # ---- the published preset -------------------------------------------------------
@@ -522,10 +545,10 @@ def test_the_benchmarks_reference_draws_the_programs_weights():
 
 # ---- through the engine ------------------------------------------------------------
 
-def _engine(**kw):
+def _engine(model_cfg=None, **kw):
     from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
 
-    return InferenceEngine(engine_cfg=EngineConfig(
+    return InferenceEngine(model_cfg=model_cfg, engine_cfg=EngineConfig(
         model="tiny-delta-mlp", num_slots=2, max_seq=128, dtype="float32",
         decode_steps=2, **kw))
 
@@ -601,15 +624,25 @@ def test_a_prefix_hit_restores_a_snapshot_and_decodes_as_the_unshared_run():
     assert eng._snap_pool["delta"].shape[:2] == (6, 17)
 
 
-def test_the_dispatch_records_carry_state_rows():
+@pytest.mark.parametrize("update", sorted(UPDATES))
+def test_the_dispatch_records_carry_state_rows(update):
     """``engine.decode_burst`` and ``engine.prefill_segment`` records name
     the rows whose state the dispatch read and wrote and their bytes, as a
-    Mamba-2 model's do."""
+    Mamba-2 model's do; a burst's ``state_update`` names the branch's answer
+    (as /healthz does) and ``engine_decode_state_kernel_steps_total`` counts
+    the steps that took the kernel."""
+    from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
     from tests.moe_records import tracing
 
+    name = "engine_decode_state_kernel_steps_total"
     with tracing() as tracer:
-        eng = _engine(mux=True, prefill_chunk=16)
+        eng = _engine(_decoding(get_config("tiny-delta-mlp"), update),
+                      mux=True, prefill_chunk=16)
+        before = global_metrics.counter(name)
+        steps = global_metrics.counter("engine_decode_steps_total")
         _generate(eng, [_prompt(12, 20)], new=4)
+        grew = global_metrics.counter(name) - before
+        steps = global_metrics.counter("engine_decode_steps_total") - steps
         records = tracer.records()
     row = ssm_moe.state_bytes_per_slot(eng.mcfg, jnp.float32)
     segs = [r for r in records if r.name == "engine.prefill_segment"]
@@ -621,6 +654,10 @@ def test_the_dispatch_records_carry_state_rows():
         a = r.attrs
         assert a["state_rows"] == a["live_rows"] * a["steps"]
         assert a["state_bytes"] == 2 * row * a["state_rows"]
+    want = DELTA_STEP_KERNEL if update == "kernel" else ELEMENTWISE
+    assert {r.attrs["state_update"] for r in bursts} == {want}
+    assert eng._model_section()["cache"]["kinds"]["state"]["update"] == want
+    assert steps > 0 and grew == (steps if update == "kernel" else 0)
 
 
 @pytest.mark.parametrize("case", [

@@ -2,8 +2,9 @@
 (ops/pallas_ssm_step.py, ISSUE 45), in interpret mode on the CPU: against
 ``ssm.ssm_step`` on a stacked leaf (live, parked and scratch rows, first and
 last layer, one group and eight), the rows it never names to the bit, the
-list of live rows, the branch by what the code observes, and what the decode
-program lowers to for a TPU.
+list of live rows, the branch by what the code observes (both kinds of
+state: the delta rule's kernel is tests/test_delta_step_kernel.py's), and
+what the decode program lowers to for a TPU.
 """
 
 from __future__ import annotations
@@ -16,6 +17,7 @@ import pytest
 from p2p_llm_tunnel_tpu.models import ssm, ssm_moe
 from p2p_llm_tunnel_tpu.models.config import get_config
 from p2p_llm_tunnel_tpu.ops import pallas_ssm_step as kernel
+from p2p_llm_tunnel_tpu.ops.pallas_delta_step import DELTA_STEP_KERNEL
 
 SEQ = 128
 #: rows 0-3 are slots, row 4 the scratch row (parked at every decode step).
@@ -120,6 +122,9 @@ def _two_chips(cpu_devices):
 
 
 CELL = "nemotron-3-nano-30b-a3b-ep2s"
+#: The delta rule's cell (a head's ``[96, 192]`` held as ``[48, 384]``) and
+#: the tiny preset of its kind (``[16, 24]`` held as one row of 384 lanes).
+DELTA_CELL, DELTA_TINY = "olmo-hybrid-7b", "tiny-delta-mlp"
 #: (what the code can observe) -> the state update's branch: the backend
 #: (None: this one, a CPU), the model and its fields, a mesh of two chips?
 BRANCHES = {
@@ -150,6 +155,40 @@ BRANCHES = {
          False, kernel.ELEMENTWISE),
     "two-lane-tiles-of-state":
         ("tpu", CELL, dict(ssm_state=256), False, kernel.SSM_STEP_KERNEL),
+    # the delta rule's matrix state: the same rule, its own kernel (ISSUE 52)
+    "delta-interpreting-one-row-a-head":
+        (None, DELTA_TINY, dict(flash_interpret=True), False,
+         DELTA_STEP_KERNEL),
+    "delta-the-cells-state-on-a-tpu-backend":
+        ("tpu", DELTA_CELL, {}, False, DELTA_STEP_KERNEL),
+    "delta-the-cells-state-lowered-for-a-tpu":
+        (None, DELTA_CELL, dict(flash_force=True), False, DELTA_STEP_KERNEL),
+    "delta-a-cpu-backend":
+        (None, DELTA_CELL, {}, False, kernel.ELEMENTWISE),
+    "delta-the-reference":
+        ("tpu", DELTA_CELL, dict(flash=False), False, kernel.ELEMENTWISE),
+    "delta-a-mesh-of-two-chips":
+        ("tpu", DELTA_CELL, {}, True, kernel.ELEMENTWISE),
+    "delta-a-mesh-of-two-chips-interpreting":
+        (None, DELTA_TINY, dict(flash_interpret=True), True,
+         kernel.ELEMENTWISE),
+    "delta-one-row-a-head-on-a-tpu-backend":  # no group of 8 sublanes
+        ("tpu", DELTA_TINY, {}, False, kernel.ELEMENTWISE),
+    "delta-a-key-width-of-100":  # [50, 384]: six groups and two sublanes
+        ("tpu", DELTA_CELL, dict(delta_key_dim=100), False,
+         kernel.ELEMENTWISE),
+    "delta-a-key-width-of-97":  # packs no rows: [97, 192], half a lane tile
+        ("tpu", DELTA_CELL, dict(delta_key_dim=97), False,
+         kernel.ELEMENTWISE),
+    "delta-a-key-width-of-97-interpreting":
+        (None, DELTA_CELL, dict(delta_key_dim=97, flash_interpret=True),
+         False, kernel.ELEMENTWISE),
+    "delta-a-key-width-of-1024":  # 256 columns of k and q a head
+        ("tpu", DELTA_CELL, dict(delta_key_dim=1024), False,
+         kernel.ELEMENTWISE),
+    "delta-a-value-width-of-128":  # packs no rows: [96, 128]
+        ("tpu", DELTA_CELL, dict(delta_value_dim=128), False,
+         DELTA_STEP_KERNEL),
 }
 
 
