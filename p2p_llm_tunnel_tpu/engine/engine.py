@@ -2001,7 +2001,7 @@ class InferenceEngine(BlockDecodeMixin):
                 "remasking": "sequential",
                 "mask_token_id": m.mask_token_id,
             }}
-        layer = {}
+        layer = _attention_section(m)
         if m.mixer_pattern is not None:
             # what a pattern layer is made of, and the published multipliers
             # the programs apply (each 1 where the model states none)
@@ -5727,3 +5727,19 @@ class InferenceEngine(BlockDecodeMixin):
                 state.queue.put_nowait(_CRASHED)
             raise
         log.info("engine loop stopped")
+
+
+def _attention_section(m) -> Dict[str, object]:
+    """/healthz ``config.model.attention`` of a window-ring model
+    (models/swa.py): what its two kinds of attention layer differ in beside
+    their planes; nothing for any other model.  (At the file's end: the
+    lines above it are the parent's, ROADMAP Speed 6.)"""
+    if m.attn_pattern is None:
+        return {}
+    kinds = ("full", "window")
+    return {"attention": {
+        "query_heads": {k: m.heads_of(k) for k in kinds},
+        "rotary_columns": {k: m.rotary_of(k) or m.head_dim for k in kinds},
+        "gate": m.attn_gate or None,
+        "qk_norm": m.qk_norm,
+    }}

@@ -24,6 +24,10 @@ class YarnRope:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    # ``rope_type`` ``yarn`` on a plain (not latent) rope states the number
+    # that multiplies sin and cos outright (models/swa.py's full layers); 0:
+    # none stated.
+    attention_factor: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -143,6 +147,18 @@ class ModelConfig:
     value_scale: float = 1.0
     window_sink: bool = False
     ring_positions: int = 0
+    # What else the two kinds may differ in (Laguna's; each 0 or empty where
+    # a model has one answer for both kinds): a window layer's query heads
+    # (``heads_of``) and rotary columns (``rotary_of``); under ``yarn`` the
+    # FULL layers' rotary columns turn at yarn's frequencies and their sin
+    # and cos are multiplied by its ``attention_factor``, the window layers
+    # rope plainly; ``attn_gate`` ``"per-head"``: ``sigmoid(W_g h)``, one
+    # number a token and query head, multiplies that head's weighted sum
+    # before ``W_o``; ``qk_norm`` (above) is in this family an RMSNorm over
+    # each query and key head's columns before the rope.
+    window_heads: int = 0
+    window_rotary_dim: int = 0
+    attn_gate: str = ""
     # Generation by masked denoising over blocks (models/block_decode.py):
     # positions are filled ``block_length`` at a time (0: one token a
     # sequence and step).  A block takes ``denoise_steps`` passes that each
@@ -308,6 +324,19 @@ class ModelConfig:
         if kind == "window" and self.window_kv_heads:
             return self.window_kv_heads
         return self.n_kv_heads
+
+    def heads_of(self, kind: str) -> int:
+        """Query heads of an attention layer of ``kind``."""
+        if kind == "window" and self.window_heads:
+            return self.window_heads
+        return self.n_heads
+
+    def rotary_of(self, kind: str) -> int:
+        """Leading columns of a head that a layer of ``kind`` ropes (0: all
+        of them)."""
+        if kind == "window" and self.window_rotary_dim:
+            return self.window_rotary_dim
+        return self.rotary_dim
 
     def ring_default(self, max_seq: int, chunk: int = 0) -> int:
         """Positions a window layer's ring holds a slot: the window and the
@@ -699,6 +728,115 @@ def tiny_swa_moe(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+def laguna_s_2_1() -> ModelConfig:
+    """Laguna-S-2.1 as published (huggingface.co/poolside/Laguna-S-2.1
+    config.json, ``model_type`` ``laguna``): 48 layers, a full-attention
+    layer (48 query heads, yarn on the leading 64 of 128 columns) then three
+    window layers (72 query heads, window 512, a plain rope on all 128), 8
+    KV heads of 128 in both, a sigmoid gate a query head on attention's
+    output; one dense layer of 12288, then 256 routed experts of 1024
+    top-10 (over their sum, times 2.5) beside one shared expert of 1024.
+    What the config leaves open (sigmoid scores with a selection bias, an
+    RMSNorm a head on queries and keys, the shared expert ungated) is the
+    family's convention: benchmarks/LAGUNA_MOE.md.  For shapes and tests of
+    the config: no chip holds it."""
+    return ModelConfig(
+        name="laguna-s-2.1",
+        vocab_size=100352,
+        dim=3072,
+        n_layers=48,
+        n_heads=48,
+        n_kv_heads=8,
+        head_dim=128,
+        ffn_dim=12288,
+        rope_theta=500000.0,
+        norm_eps=1e-6,
+        sliding_window=512,
+        n_experts=256,
+        n_experts_per_tok=10,
+        moe_ffn_dim=1024,
+        n_shared_experts=1,
+        shared_expert_dim=1024,
+        first_dense_layers=1,
+        router_score="sigmoid",
+        router_bias=True,
+        routed_scale=2.5,
+        v_head_dim=128,
+        qk_norm=True,
+        attn_pattern=(0, 1, 1, 1) * 12,
+        window_heads=72,
+        window_rope_theta=10000.0,
+        rotary_dim=64,
+        window_rotary_dim=128,
+        attn_gate="per-head",
+        yarn=YarnRope(factor=128.0, original_max=8192, beta_fast=32.0,
+                      beta_slow=1.0,
+                      attention_factor=1.4852030263919618),
+    )
+
+
+def laguna_s_2_1_ep8s() -> ModelConfig:
+    """One chip's share of Laguna-S-2.1: one of 8 chips that share each
+    layer (experts 0-31, vocabulary rows 0-12,543; attention, the router
+    and the shared expert whole on each) and the first pipeline stage: the
+    dense layer and seven routed layers, kinds F WWW F WWW (two whole
+    periods).  Every width, both head counts, the router's 256 outputs,
+    top-10 and 2.5 are as published."""
+    return replace(laguna_s_2_1(), name="laguna-s-2.1-ep8s", n_layers=8,
+                   published_layers=48, vocab_size=12544, layer_chips=8,
+                   chip_index=0)
+
+
+def tiny_laguna(vocab_size: int = 512) -> ModelConfig:
+    """CPU-testable Laguna-style config: eight layers F WWW F WWW, 6 (full)
+    and 9 (window) query heads on 3 KV heads of 16 (groups of 2 and 3, head
+    counts no power of two), window 8, yarn on a full layer's leading 8
+    columns and a plain rope on all 16 of a window layer's, a gate a head,
+    QK norm, one dense layer then 16 experts top-3 beside a shared one;
+    rings of 16 positions, so a prompt of some tens of tokens wraps
+    them."""
+    return ModelConfig(
+        name="tiny-laguna",
+        vocab_size=vocab_size,
+        dim=64,
+        n_layers=8,
+        n_heads=6,
+        n_kv_heads=3,
+        head_dim=16,
+        ffn_dim=128,
+        rope_theta=500000.0,
+        norm_eps=1e-6,
+        sliding_window=8,
+        n_experts=16,
+        n_experts_per_tok=3,
+        moe_ffn_dim=32,
+        n_shared_experts=1,
+        shared_expert_dim=32,
+        first_dense_layers=1,
+        router_score="sigmoid",
+        router_bias=True,
+        routed_scale=2.5,
+        v_head_dim=16,
+        qk_norm=True,
+        attn_pattern=(0, 1, 1, 1) * 2,
+        window_heads=9,
+        window_rope_theta=10000.0,
+        rotary_dim=8,
+        window_rotary_dim=16,
+        attn_gate="per-head",
+        yarn=YarnRope(factor=8.0, original_max=16, beta_fast=32.0,
+                      beta_slow=1.0, attention_factor=1.2079441541679836),
+        ring_positions=16,
+    )
+
+
+def tiny_laguna_ep2s(vocab_size: int = 512) -> ModelConfig:
+    """tiny-laguna as one of 2 chips that share each layer: experts 0-7 and
+    ``vocab_size`` rows of a table twice as long."""
+    return replace(tiny_laguna(vocab_size), name="tiny-laguna-ep2s",
+                   layer_chips=2, chip_index=0)
+
+
 def sdar_30b_a3b() -> ModelConfig:
     """SDAR-30B-A3B-Chat (JetLM; ``model_type`` ``sdar_moe``): a Qwen3-MoE
     body (QK norm, 128 routed experts of width 768, top-8 of a softmax,
@@ -1036,6 +1174,10 @@ PRESETS = {
     "tiny-sdar-moe": tiny_sdar_moe,
     "sdar-30b-a3b": sdar_30b_a3b,
     "sdar-30b-a3b-pp7s": sdar_30b_a3b_pp7s,
+    "tiny-laguna": tiny_laguna,
+    "tiny-laguna-ep2s": tiny_laguna_ep2s,
+    "laguna-s-2.1": laguna_s_2_1,
+    "laguna-s-2.1-ep8s": laguna_s_2_1_ep8s,
     "tiny-swa-moe": tiny_swa_moe,
     "tiny-swa-moe-ep2s": tiny_swa_moe_ep2s,
     "mimo-v2-flash": mimo_v2_flash,

@@ -1,5 +1,5 @@
 """Window and full attention layers mixed by a pattern, over routed experts:
-``MiMo-V2-Flash``.
+``MiMo-V2-Flash``, ``Laguna-S-2.1``.
 
 ``models/transformer.py`` hands its entry points here when
 ``cfg.attn_pattern`` is set, so the engine, the prefix pool and the tunnel
@@ -89,7 +89,7 @@ from p2p_llm_tunnel_tpu.ops.attention import (
     window_mask,
 )
 from p2p_llm_tunnel_tpu.ops.norms import rms_norm
-from p2p_llm_tunnel_tpu.ops.rope import apply_rope
+from p2p_llm_tunnel_tpu.ops.rope import apply_rope, yarn_inv_freq
 
 # (the selection bias's spread as drawn, the expert leaves kept out of a
 # scan's sliced operands, the float32 stream's first value and the head are
@@ -143,7 +143,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
     """Random init; an expert is drawn from its own key by its PUBLISHED
     index, one at a time (``models/mla.init_params``'s scheme: a share's
     experts are the whole model's of the same seed)."""
-    dm, h, v = cfg.dim, cfg.n_heads, cfg.vocab_size
+    dm, v = cfg.dim, cfg.vocab_size
     dk, dv = cfg.head_dim, cfg.v_head_dim
     lf, lw = kind_counts(cfg)
     ld = cfg.layer_kinds.count("dense")
@@ -154,15 +154,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
         return (jax.random.truncated_normal(k, -2, 2, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dtype)
 
-    def attn(k, n, kv):
-        ks = jax.random.split(k, 4)
+    def attn(k, n, kind):
+        ks, h, kv = jax.random.split(k, 4), *_heads(cfg, kind)
         return {
             "attn_norm": jnp.ones((n, dm), dtype),
             "wq": dense(ks[0], (n, dm, h * dk), dm),
             "wk": dense(ks[1], (n, dm, kv * dk), dm),
             "wv": dense(ks[2], (n, dm, kv * dv), dm),
             "wo": dense(ks[3], (n, h * dv, dm), h * dv),
-        }
+            **_attn_extras(cfg, k, n, h, dense, dtype)}
 
     params = {
         "embed": dense(keys[7], (v, dm), dm),
@@ -170,14 +170,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
         "lm_head": dense(jax.random.fold_in(key, 99), (dm, v), dm),
     }
     if lf:
-        params["attn_full"] = attn(keys[0], lf, cfg.kv_heads_of("full"))
+        params["attn_full"] = attn(keys[0], lf, "full")
     if lw:
-        params["attn_window"] = attn(keys[1], lw, cfg.kv_heads_of("window"))
+        params["attn_window"] = attn(keys[1], lw, "window")
         if cfg.window_sink:
             # a logit among scores of about unit spread: drawn so that it
             # takes a real share of a head's weight
             params["attn_window"]["sink"] = jax.random.normal(
-                keys[2], (lw, h), jnp.float32)
+                keys[2], (lw, cfg.heads_of("window")), jnp.float32)
     if ld:
         f = cfg.ffn_dim
         params["dense_ffn"] = {
@@ -209,7 +209,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
         if cfg.router_bias:
             blocks["router_bias"] = ROUTER_BIAS_STD * jax.random.normal(
                 keys[12], (lm, e), jnp.float32)
-        params["blocks"] = blocks
+        params["blocks"] = dict(blocks, **_shared_leaves(cfg, keys, lm, dense))
     return params
 
 
@@ -264,33 +264,33 @@ def cache_section(cfg: ModelConfig, kv_cache) -> dict:
 # shared layer pieces
 # ---------------------------------------------------------------------------
 
-def _rope(cfg: ModelConfig, kind: str, x, positions):
-    """The leading ``rotary_dim`` columns of each head roped; the rest
-    pass."""
-    theta = cfg.window_rope_theta if kind == "window" else cfg.rope_theta
-    r = cfg.rotary_dim or x.shape[-1]
-    if r == x.shape[-1]:
-        return apply_rope(x, positions, theta)
-    return jnp.concatenate(
-        [apply_rope(x[..., :r], positions, theta), x[..., r:]], axis=-1)
-
-
-def _attn_inputs(cfg: ModelConfig, kind: str, blk, h, positions):
-    """h [B,T,Dm] -> q [B,T,H,Dk] (roped) and what the token caches: its
-    roped keys [B,T,K*Dk] and scaled values [B,T,K*Dv], heads side by
-    side."""
-    b, t, _ = h.shape
-    kv, dk = cfg.kv_heads_of(kind), cfg.head_dim
-    aq = cfg.act_quant
-    q = _rope(cfg, kind, mm(h, blk["wq"], aq).reshape(b, t, cfg.n_heads, dk),
-              positions)
-    k = _rope(cfg, kind, mm(h, blk["wk"], aq).reshape(b, t, kv, dk),
-              positions)
-    v = mm(h, blk["wv"], aq)
-    if cfg.value_scale != 1.0:
-        v = (v.astype(jnp.float32) * cfg.value_scale).astype(v.dtype)
-    return q, k.reshape(b, t, kv * dk), v
-
+# What Laguna-S-2.1 adds to the family (``_rope``, ``_attn_inputs``,
+# ``_attn_out`` and the draws' helpers stand further down, after the pieces
+# that ``models/ssm_moe.py`` shares with this module: those keep their lines,
+# see the note there):
+#
+# - query heads by layer kind, ``H_k = cfg.heads_of(kind)``: ``W_q``, ``W_o``,
+#   ``W_g`` and the scores' reshape go by kind;
+# - under ``cfg.qk_norm`` every query and key head is RMS-normed over its
+#   columns before the rope;
+# - the leading ``cfg.rotary_of(kind)`` columns of a head are roped; under
+#   ``cfg.yarn`` a FULL layer's turn at yarn's frequencies over that width,
+#   sin and cos times its ``attention_factor``; a window layer ropes
+#   plainly at ``window_rope_theta``;
+# - under ``cfg.attn_gate`` ``g = sigmoid(W_g h)`` [H_k], one number a token
+#   and query head, multiplies that head's weighted sum before ``W_o``
+#   (scope ``attn_gate`` inside the kind's);
+# - a routed layer is ``models/moe.py``'s whole: the shared expert and the
+#   scaling factor where the configuration has them.
+#
+# ``W_g`` is drawn ``GATE_STD`` times the standard draw: gate logits of
+# spread about 1.8 over the normed stream, so that a fifth of the gates lie
+# under 0.2 or over 0.8 and a head's gate is no constant near one half.
+#
+# THE LINES OF ``_pack`` ... ``_as_held`` BELOW ARE THE PARENT'S (PR 55): the
+# chip compiler's broadcast rewriter stands at the edge of its stack in
+# granite-4.0-h-micro's cold start (ROADMAP Speed 6), and that model's
+# programs trace through these functions.
 
 def _pack(rows, heads: int, quant: bool):
     """Rows ``[..., heads * D]`` as the planes hold them -> (rows, scales
@@ -429,6 +429,100 @@ def _as_held(cfg, kind, rows, quant: bool):
 
 
 # ---------------------------------------------------------------------------
+# what goes by layer kind (Laguna-S-2.1): the draws, the rope, the gate
+# ---------------------------------------------------------------------------
+
+#: What multiplies the standard draw of ``W_g`` (the note above).
+GATE_STD = 2.0
+
+
+def _heads(cfg: ModelConfig, kind: str) -> Tuple[int, int]:
+    """(query heads, KV heads) of a layer of ``kind``."""
+    return cfg.heads_of(kind), cfg.kv_heads_of(kind)
+
+
+def _attn_extras(cfg: ModelConfig, k, n, h, dense, dtype) -> dict:
+    """The leaves of ``n`` attention layers of ``h`` query heads that only
+    some configurations have: the QK norm's weights (ones) and ``W_g``."""
+    out = {}
+    if cfg.qk_norm:
+        out["q_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+        out["k_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+    if cfg.attn_gate:
+        out["wg"] = GATE_STD * dense(
+            jax.random.fold_in(k, 4), (n, cfg.dim, h), cfg.dim)
+    return out
+
+
+def _shared_leaves(cfg: ModelConfig, keys, lm, dense) -> dict:
+    """The shared experts of ``lm`` routed layers (``models/mla.py``'s key
+    parts), or nothing."""
+    if not cfg.n_shared_experts:
+        return {}
+    dm = cfg.dim
+    fs = cfg.n_shared_experts * (cfg.shared_expert_dim or cfg.expert_dim)
+    return {"shared_gate": dense(keys[13], (lm, dm, fs), dm),
+            "shared_up": dense(keys[14], (lm, dm, fs), dm),
+            "shared_down": dense(keys[15], (lm, fs, dm), fs)}
+
+
+def _rope(cfg: ModelConfig, kind: str, x, positions):
+    """The leading ``cfg.rotary_of(kind)`` columns of each head roped; the
+    rest pass.  Under ``cfg.yarn`` a full layer's columns turn at yarn's
+    frequencies over that width, sin and cos times its stated factor."""
+    theta = cfg.window_rope_theta if kind == "window" else cfg.rope_theta
+    r = cfg.rotary_of(kind) or x.shape[-1]
+    how = {}
+    if cfg.yarn is not None and kind == "full":
+        how = {"inv_freq": yarn_inv_freq(r, theta, cfg.yarn),
+               "mscale": cfg.yarn.attention_factor or 1.0}
+    if r == x.shape[-1]:
+        return apply_rope(x, positions, theta, **how)
+    return jnp.concatenate(
+        [apply_rope(x[..., :r], positions, theta, **how), x[..., r:]],
+        axis=-1)
+
+
+def _attn_inputs(cfg: ModelConfig, kind: str, blk, h, positions):
+    """h [B,T,Dm] -> q [B,T,H_k,Dk] (roped), what the token caches: its
+    roped keys [B,T,K*Dk] and scaled values [B,T,K*Dv], heads side by
+    side, and the heads' gates [B,T,H_k] float32 (None without
+    ``cfg.attn_gate``)."""
+    b, t, _ = h.shape
+    kv, dk = cfg.kv_heads_of(kind), cfg.head_dim
+    aq = cfg.act_quant
+
+    def heads(w, n, norm):
+        x = mm(h, blk[w], aq).reshape(b, t, n, dk)
+        if cfg.qk_norm:  # over each head's columns, before the rope
+            x = rms_norm(x, blk[norm], cfg.norm_eps)
+        return _rope(cfg, kind, x, positions)
+
+    q = heads("wq", cfg.heads_of(kind), "q_norm")
+    k = heads("wk", kv, "k_norm")
+    v = mm(h, blk["wv"], aq)
+    if cfg.value_scale != 1.0:
+        v = (v.astype(jnp.float32) * cfg.value_scale).astype(v.dtype)
+    gate = None
+    if cfg.attn_gate:
+        with jax.named_scope(ATTN_GROUP[kind]), jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(mm(h, blk["wg"], aq).astype(jnp.float32))
+    return q, k.reshape(b, t, kv * dk), v, gate
+
+
+def _attn_out(cfg: ModelConfig, kind: str, blk, a, gate):
+    """The heads' weighted sums ``a [B,T,H_k*Dv]`` -> what the layer adds to
+    the stream: under a gate each head's sum times its gate, then ``W_o``."""
+    with jax.named_scope("attn"):
+        if gate is not None:
+            with jax.named_scope(ATTN_GROUP[kind]), jax.named_scope(
+                    "attn_gate"):
+                heads = a.reshape(gate.shape + (-1,)).astype(jnp.float32)
+                a = (heads * gate[..., None]).astype(a.dtype).reshape(a.shape)
+        return mm(a, blk["wo"], cfg.act_quant)
+
+
+# ---------------------------------------------------------------------------
 # the three serving programs (+ the whole-prompt forward)
 # ---------------------------------------------------------------------------
 
@@ -444,14 +538,13 @@ def prefill(cfg: ModelConfig, params, tokens, valid, counted=None):
 
     def layer(run, x, blk, ai, ffn):
         with jax.named_scope("attn"):
-            q, k, v = _attn_inputs(cfg, run.attn, blk,
-                                   _normed(cfg, x, blk, dtype), positions)
+            q, k, v, gate = _attn_inputs(
+                cfg, run.attn, blk, _normed(cfg, x, blk, dtype), positions)
         mask = window_mask(
             positions, key_pos,
             cfg.sliding_window if run.attn == "window" else None)
         a = _attend(cfg, run.attn, blk, q, k, v, mask)
-        with jax.named_scope("attn"):
-            x = x + mm(a, blk["wo"], cfg.act_quant)
+        x = x + _attn_out(cfg, run.attn, blk, a, gate)
         with jax.named_scope("ffn"):
             out, stats = ffn(x, counted)
             return x + out, (k, v), stats
@@ -550,8 +643,8 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
 
     def layer(run, x, blk, ai, ffn):
         with jax.named_scope("attn"):
-            q, k, v = _attn_inputs(cfg, run.attn, blk,
-                                   _normed(cfg, x, blk, dtype), pos)
+            q, k, v, gate = _attn_inputs(
+                cfg, run.attn, blk, _normed(cfg, x, blk, dtype), pos)
         kp, vp = PLANES[run.attn]
         with jax.named_scope("kv_read"):
             k_new = _as_held(cfg, run.attn, k, quant)
@@ -565,8 +658,7 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
                 v_all = jnp.concatenate([ring_rows(vp, ai), v_new], axis=1)
                 mask = win_mask
         a = _attend(cfg, run.attn, blk, q, k_all, v_all, mask)
-        with jax.named_scope("attn"):
-            x = x + mm(a, blk["wo"], cfg.act_quant)
+        x = x + _attn_out(cfg, run.attn, blk, a, gate)
         with jax.named_scope("ffn"):
             out, stats = ffn(x, counted)
             return x + out, (k, v), stats
@@ -636,8 +728,8 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
         kind = run.attn
         on_rows = use_rows and kind == "full"
         with jax.named_scope("attn"):
-            q, k, v = _attn_inputs(cfg, kind, blk,
-                                   _normed(cfg, x, blk, dtype), pos2d)
+            q, k, v, gate = _attn_inputs(
+                cfg, kind, blk, _normed(cfg, x, blk, dtype), pos2d)
         cache = dict(cache)
         zero = jnp.zeros((), ai.dtype)
 
@@ -668,8 +760,7 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
                     interpret=cfg.flash_interpret).reshape(b, 1, -1)
         else:
             a = _attend(cfg, kind, blk, q, rows[0], rows[1], masks[kind])
-        with jax.named_scope("attn"):
-            x = x + mm(a, blk["wo"], cfg.act_quant)
+        x = x + _attn_out(cfg, kind, blk, a, gate)
         with jax.named_scope("ffn"):
             out, stats = ffn(x, counted)
             return (x + out, cache), None, stats

@@ -57,7 +57,7 @@ def _missing_targets(text, makefile):
 @pytest.mark.parametrize(
     "doc", ["README.md", "Makefile", ".claude/skills/verify/SKILL.md",
             "benchmarks/BLOCK_DIFFUSION.md", "benchmarks/SSM_MOE.md",
-            "benchmarks/OLMO_HYBRID.md"])
+            "benchmarks/OLMO_HYBRID.md", "benchmarks/LAGUNA_MOE.md"])
 def test_document_names_only_what_exists(doc):
     with open(os.path.join(REPO, "Makefile")) as f:
         makefile = f.read()

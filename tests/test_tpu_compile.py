@@ -685,6 +685,89 @@ def test_the_mimo_share_fits_one_chip_at_its_stated_bytes(chip, program):
 
 
 # ---------------------------------------------------------------------------
+# head counts by layer kind and a gate a head (ISSUE 55)
+# ---------------------------------------------------------------------------
+
+#: laguna-s-2.1-ep8s at the cell's size: 64 slots + the scratch row x 6144,
+#: rings of 1024 (window 512 + segments of 512); a row of 8 x 128 = 1,024
+#: values in both kinds.
+LAG_ROWS, LAG_SEQ, LAG_RING = 65, 6144, 1024
+LAG_PLANES = {"k": (2, LAG_SEQ, 1024), "v": (2, LAG_SEQ, 1024),
+              "wk": (6, LAG_RING, 1024), "wv": (6, LAG_RING, 1024)}
+LAG_PROGRAMS = {
+    # as a TPU backend runs it: the full layers (48 heads, 6 a KV head) on
+    # the rows kernel, the grouped products on the grouped kernel
+    "decode-on-the-chip": lambda T, cfg, p, c, b: T.decode_step(
+        replace(cfg, flash_force=True), p, c, b["rows"], b["rows"],
+        kv_view=LAG_SEQ, with_stats=True),
+    "chunk-512-at-6144": lambda T, cfg, p, c, b: T.chunk_prefill_into_cache(
+        replace(cfg, flash_force=True), p, b["tok512"], b["row2"], b["row2"],
+        c, b["row2"], kv_view=LAG_SEQ, stat_rows=b["row2"] != 64),
+}
+
+
+def _laguna_compiled(chip, program, **small):
+    from p2p_llm_tunnel_tpu.models import transformer as T
+    from p2p_llm_tunnel_tpu.models.config import get_config
+
+    cfg = get_config("laguna-s-2.1-ep8s", ring_positions=LAG_RING, **small)
+    params, cache = _share_shapes(chip, cfg, LAG_ROWS, LAG_SEQ)
+    assert {k: (v.shape[0],) + v.shape[2:] for k, v in cache.items()} \
+        == LAG_PLANES
+    batch = _on(chip, {
+        "rows": jax.ShapeDtypeStruct((LAG_ROWS,), jnp.int32),
+        "row2": jax.ShapeDtypeStruct((2,), jnp.int32),
+        "tok512": jax.ShapeDtypeStruct((2, 512), jnp.int32)})
+    return cfg, cache, jax.jit(
+        lambda p, c, b: LAG_PROGRAMS[program](T, cfg, p, c, b),
+        donate_argnums=(1,)).lower(params, cache, batch).compile()
+
+
+@pytest.mark.parametrize("program", sorted(LAG_PROGRAMS))
+def test_the_laguna_share_holds_its_planes_as_stated_and_fits(chip, program):
+    """``laguna-s-2.1-ep8s`` at the cell's size, as a TPU backend runs it:
+    the four planes at their stated bytes to the byte (65 x (2 x 6,144 + 6 x
+    1,024) x 4,096 B = 4.91 GB), no plane-sized ``copy`` around a row write
+    of any of them, every plane written is the donated one and in chunk
+    prefill no loop body makes one; weights (2,843 M parameters), planes,
+    the prefix pool of 2,048 blocks and the program's own temporaries are
+    inside a v5e's 16 GB.  Decode holds the rows kernel in each of the two
+    runs with a full layer (48 query heads on 8 KV heads: a group of 6) and
+    the routed products are Mosaic kernels."""
+    cfg, cache, compiled = _laguna_compiled(chip, program)
+    hlo = compiled.as_text()
+    m = compiled.memory_analysis()
+    planes = sum(math.prod(v.shape) * 2 for v in cache.values())
+    assert planes == LAG_ROWS * (2 * LAG_SEQ + 6 * LAG_RING) * 4096
+    for name, plane in cache.items():
+        copies, made = _plane_work(hlo, math.prod(plane.shape))
+        assert copies == [], name
+        if program.startswith("chunk"):
+            assert made == [], name
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    assert aliased.count("alias") == len(cache)
+    from p2p_llm_tunnel_tpu.models.transformer import init_params
+
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize for x in
+                  jax.tree.leaves(jax.eval_shape(
+                      lambda: init_params(cfg, jax.random.PRNGKey(0)))))
+    assert 5.68e9 < weights < 5.70e9
+    assert 0 <= m.argument_size_in_bytes - weights - planes < 2 ** 20
+    tiled = set(re.findall(
+        r"bf16\[[26],65,(?:6144|1024),1024\]\{3,2,1,0:T\(8,128\)\(2,1\)\}",
+        hlo))
+    assert len(tiled) == 2, tiled  # keys and values are equally wide here
+    pool = 2048 * 16 * 32768
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes + pool)
+    assert held < 14.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
+    assert _grouped_products(hlo, kernel=True) >= 3
+    if program.startswith("decode"):
+        assert hlo.count(ROWS_KERNEL) >= 2
+        assert f"[1,{LAG_ROWS},{LAG_SEQ},1024]" not in hlo
+
+
+# ---------------------------------------------------------------------------
 # a recurrent state a slot beside the KV planes (ISSUE 44)
 # ---------------------------------------------------------------------------
 
