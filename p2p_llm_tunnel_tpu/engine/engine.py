@@ -1849,7 +1849,7 @@ class InferenceEngine(BlockDecodeMixin):
         self._programs_ready.add(key)
         branch = self._attention_branch(kind, shape)
         if kind == "decode":
-            branch = decode_branch_coverage(self.mcfg, branch)
+            branch = decode_branch_coverage(self.mcfg, branch, self._ring)
         if branch not in self.attention_branches.setdefault(kind, []):
             self.attention_branches[kind].append(branch)
             global_metrics.set_info(
@@ -2937,28 +2937,28 @@ class InferenceEngine(BlockDecodeMixin):
             b *= 2
         return min(b, self.ecfg.max_seq)
 
-    def _count_kv_rows(self, first, n, rec: Optional[_Dispatch]) -> None:
-        """What a dispatch's attention has to read of the cache, by layer
-        kind: for each row, queries at positions ``first[i] .. first[i] +
-        n[i] - 1`` (as the host accounts them when it dispatches: no fetch)
-        see ``p + 1`` positions in a full layer and ``min(p + 1, window)``
-        in a window layer; summed over rows and layers.  Counted in
-        ``engine_kv_rows_{full,window}_total`` and, under tracing, on the
-        dispatch's record, so the two always agree."""
+    def _count_kv_rows(self, first, n, rec, decode: bool = False) -> None:
+        """What a dispatch's attention has to read of the cache by layer kind,
+        on the host's accounting (no fetch): a row's queries at ``first[i] ..
+        first[i] + n[i] - 1`` see ``p + 1`` positions in a full layer and
+        ``min(p + 1, window)`` in a window layer, whose read fetches
+        ``window_read``; summed, on the counters and the record alike."""
         first = np.asarray(first, np.int64)
         n = np.broadcast_to(np.asarray(n, np.int64), first.shape)
         lw = sum(self._attn_kinds)
         lf = len(self._attn_kinds) - lw
-        full = int((n * first + n * (n + 1) // 2).sum()) * lf
-        window = 0
+        rows = dict(full=int((n * first + n * (n + 1) // 2).sum()) * lf,
+                    window=0, window_read=0)
         if lw:
             w = int(self.mcfg.sliding_window)
             m = np.clip(w - 1 - first, 0, n)  # queries that see < w positions
             window = int((m * first + m * (m + 1) // 2 + (n - m) * w).sum()) * lw
-        global_metrics.inc("engine_kv_rows_full_total", full)
-        global_metrics.inc("engine_kv_rows_window_total", window)
+            rows.update(window=window, window_read=lw * _window_rows_fetched(
+                self, first, n) if decode else window)
+        for kind, count in rows.items():
+            global_metrics.inc(f"engine_kv_rows_{kind}_total", count)
         if rec is not None:
-            rec.attrs.update(kv_rows_full=full, kv_rows_window=window)
+            rec.attrs.update({f"kv_rows_{k}": v for k, v in rows.items()})
 
     def _count_state(self, rows: int, rec: Optional[_Dispatch]) -> None:
         """What a dispatch reads and writes of the recurrent state (a
@@ -3626,7 +3626,7 @@ class InferenceEngine(BlockDecodeMixin):
             # (the device's carry may lead these positions by the bursts
             # in flight)
             self._count_kv_rows(
-                self._positions[:slots][active[:slots]], steps, rec)
+                self._positions[:slots][active[:slots]], steps, rec, True)
             self._count_state(live * steps, rec)
             if self._state_update is not None:
                 if self._state_update != ELEMENTWISE:
@@ -5743,3 +5743,30 @@ def _attention_section(m) -> Dict[str, object]:
         "gate": m.attn_gate or None,
         "qk_norm": m.qk_norm,
     }}
+
+
+def _window_rows_fetched(eng, first, steps) -> int:
+    """Positions ONE window layer's decode read fetches over a burst, from
+    the rows' positions on the host: each live row at ``first[i]`` takes
+    ``steps[i]`` steps, and a step fetches the whole ring under the einsum,
+    the blocks of the ring's work list under the rows kernel
+    (``ops.pallas_decode_attention.ring_run``; a whole block counts, also
+    where the row's last is fetched in part).  A prefill dispatch gathers a
+    window by position and fetches what it needs.  (At the file's end, as
+    ``_attention_section`` is.)"""
+    from p2p_llm_tunnel_tpu.models.swa import ring_kernel_decline
+
+    ring = eng._ring
+    if not (eng._decode_reads_rows()
+            and ring_kernel_decline(eng.mcfg, ring) is None):
+        return int(steps.sum()) * ring
+    from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+        ring_run,
+        rows_block,
+    )
+
+    block = rows_block(ring, eng.mcfg.kv_heads_of("window"))
+    step = np.arange(int(steps.max(initial=0)))[None, :]
+    _, blocks = ring_run(first[:, None] + step, ring, block,
+                         int(eng.mcfg.sliding_window))
+    return int((blocks * (step < steps[:, None])).sum()) * block

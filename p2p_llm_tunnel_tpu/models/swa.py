@@ -53,13 +53,13 @@ parameters' type; the router scores the normed stream before that rounding
 (``models/mla.py``'s reasons hold here: a routed layer compares scores).
 Every program also returns what its routed layers counted (``moe.STATS``).
 
-Decode reads a full layer by what ``transformer.decode_attention_branch``
-answers (ISSUE 36): on the TPU, over plain bf16 planes whose rows are whole
-lane tiles, ``ops.pallas_decode_attention.decode_attention_rows`` takes the
-stacked planes where they lie and each row's blocks up to its own position
-(no layer sliced out, no view); elsewhere the einsum over the layer's
-``kv_view`` positions.  A window layer reads its whole ring by einsum under
-either, and chunk prefill keeps the einsum and its views.
+Decode reads by what ``transformer.decode_attention_branch`` answers: on the
+TPU, over plain bf16 planes whose rows are whole lane tiles,
+``decode_attention_rows`` takes the stacked planes where they lie, a full
+layer's row up to its own position (ISSUE 36), a window layer's ring by the
+blocks that hold its window (ISSUE 56, ``ring_kernel_decline``): no layer
+sliced out, no view.  Elsewhere the einsum over a full layer's ``kv_view``
+positions and a ring whole; chunk prefill keeps the einsum and its views.
 
 Int8 planes (``--kv-quant int8``) keep int8 values with one float32 scale a
 token, layer and KV head beside each plane (``"k_scale" [Lf, rows, S, Kf]``
@@ -677,18 +677,49 @@ def chunk_prefill_into_cache(cfg, params, tokens, lengths, starts, kv_cache,
     return last, new_cache, stats
 
 
+def ring_kernel_decline(cfg: ModelConfig, ring: int) -> Optional[str]:
+    """Why a window layer's decode keeps the einsum over its ring of ``ring``
+    positions where the full layers take the rows kernel; ``None`` where it
+    takes the kernel too (ISSUE 56).  What a ring adds to
+    ``transformer.decode_kernel_decline``: its slots in whole blocks of 128
+    and, unless interpreting, a window layer's key and value rows in whole
+    lane tiles."""
+    if ring % 128:
+        return f"a ring of {ring} positions does not tile (% 128)"
+    if cfg.flash_interpret:
+        return None
+    kv = cfg.kv_heads_of("window")
+    for what, width in (("key", kv * cfg.head_dim),
+                        ("value", kv * cfg.v_head_dim)):
+        if width % 128:
+            return (f"a window layer's {what} row of {width} does not tile "
+                    "(% 128)")
+    return None
+
+
+def ring_read(cfg: ModelConfig, ring: int) -> str:
+    """How a window layer's decode reads its ring where the full layers take
+    the rows kernel, as /healthz prints it
+    (``transformer.decode_branch_coverage``)."""
+    if ring_kernel_decline(cfg, ring) is None:
+        return "rows of the ring"
+    return "einsum over the ring"
+
+
 def decode_step(cfg, params, kv_cache, tokens, positions,
                 kv_view: Optional[int] = None, mesh=None):
     """``transformer.decode_step`` for the two kinds of plane; the planes
     are the carry of the runs' scans and take one in-place row write a layer
-    each.  A window layer reads its whole ring under the mask by position.
-    A full layer reads by what ``decode_attention_branch`` answers:
+    each.  Both kinds read by what ``decode_attention_branch`` answers:
     ``"pallas-rows"`` (the TPU, plain bf16 planes) is one kernel over the
-    stacked planes where they lie that stops at each row's own position, so
-    no layer is sliced out and ``kv_view`` bounds nothing; the einsum reads
-    the layer's ``kv_view`` positions under the causal mask.  Rows parked at
-    ``positions >= S`` write nothing and count for nothing.  Returns (logits
-    [B,V], cache, stats)."""
+    stacked planes where they lie, so no layer is sliced out and ``kv_view``
+    bounds nothing: a full layer's row stops at its own position, a window
+    layer's takes the blocks of its ring that hold its window and masks a
+    slot by the position it holds (a ring ``ring_kernel_decline`` refuses
+    keeps the einsum).  The einsum reads a full layer's ``kv_view``
+    positions under the causal mask and a window layer's whole ring under
+    the mask by position.  Rows parked at ``positions >= S`` write nothing
+    and count for nothing.  Returns (logits [B,V], cache, stats)."""
     from p2p_llm_tunnel_tpu.models.transformer import decode_attention_branch
 
     b = tokens.shape[0]
@@ -702,23 +733,29 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
     slot_ids = jnp.arange(b)
     live = positions < s
     counted = live[:, None]
-    use_rows = decode_attention_branch(
-        cfg, mesh, kv_view, "int8" if quant else None, s) == "pallas-rows"
-    masks = {"window": window_mask(pos2d, ring_positions(positions, ring),
-                                   cfg.sliding_window)}
-    if use_rows:
+    masks, work = {}, {}  # a kind reads by its work list or under its mask
+    if decode_attention_branch(
+            cfg, mesh, kv_view, "int8" if quant else None, s) == "pallas-rows":
         from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
             decode_attention_rows,
+            decode_ring_worklist,
             decode_rows_worklist,
             rows_block,
         )
 
-        # One work list a step, shared by the full layers.
-        block = rows_block(s, cfg.kv_heads_of("full"))
-        work = decode_rows_worklist(positions, s, block)
-    else:
+        # One work list a step and kind, shared by the kind's layers.
+        block = {"full": rows_block(s, cfg.kv_heads_of("full"))}
+        work["full"] = decode_rows_worklist(positions, s, block["full"])
+        if ring_kernel_decline(cfg, ring) is None:
+            block["window"] = rows_block(ring, cfg.kv_heads_of("window"))
+            work["window"] = decode_ring_worklist(
+                positions, s, ring, block["window"], cfg.sliding_window)
+    if "full" not in work:
         masks["full"] = window_mask(
             pos2d, jnp.broadcast_to(jnp.arange(kv_view), (b, kv_view)))
+    if "window" not in work:
+        masks["window"] = window_mask(
+            pos2d, ring_positions(positions, ring), cfg.sliding_window)
     at = {"full": positions,
           "window": _ring_slots(positions, live, ring)}
     extent = {"full": kv_view, "window": ring}
@@ -726,7 +763,6 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
     def layer(run, carry, blk, ai, ffn):
         x, cache = carry
         kind = run.attn
-        on_rows = use_rows and kind == "full"
         with jax.named_scope("attn"):
             q, k, v, gate = _attn_inputs(
                 cfg, kind, blk, _normed(cfg, x, blk, dtype), pos2d)
@@ -747,16 +783,20 @@ def decode_step(cfg, params, kv_cache, tokens, positions,
                 if quant:
                     cache[name + "_scale"] = cache[
                         name + "_scale"].at[where].set(scale)
-            if not on_rows:
+            if kind not in work:
                 with jax.named_scope("kv_read"):
                     rows.append(
                         _unpack(seen(name), seen(name + "_scale"), dtype)
                         if quant else seen(name))
-        if on_rows:
-            with jax.named_scope("attn"), jax.named_scope("attn_full"):
+        if kind in work:
+            window = kind == "window"
+            with jax.named_scope("attn"), jax.named_scope(ATTN_GROUP[kind]):
                 a = decode_attention_rows(
-                    q[:, 0], cache["k"], cache["v"], ai, work, block=block,
+                    q[:, 0], *(cache[name] for name in PLANES[kind]), ai,
+                    work[kind], block=block[kind],
                     scale=cfg.query_scale or cfg.head_dim ** -0.5,
+                    window=cfg.sliding_window if window else None,
+                    ring=window, sink=blk.get("sink") if window else None,
                     interpret=cfg.flash_interpret).reshape(b, 1, -1)
         else:
             a = _attend(cfg, kind, blk, q, rows[0], rows[1], masks[kind])

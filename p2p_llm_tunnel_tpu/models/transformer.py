@@ -462,8 +462,8 @@ def decode_attention_branch(cfg: ModelConfig, mesh, kv_view: int,
 
     In a model with an ``attn_pattern`` (models/swa.py) the answer is the
     FULL layers': their planes are what follows a view.  A window layer
-    reads its whole ring by einsum under either answer
-    (:func:`decode_branch_coverage`)."""
+    follows it where ``swa.ring_kernel_decline`` lets its ring, else reads
+    its whole ring by einsum (:func:`decode_branch_coverage`)."""
     if (cfg.flash and kv_quant is None and not cfg.kv_lora_rank
             and not cfg.block_length  # models/block_decode.py: an einsum
             and decode_kernel_decline(cfg, mesh, max_seq or kv_view) is None):
@@ -471,13 +471,13 @@ def decode_attention_branch(cfg: ModelConfig, mesh, kv_view: int,
     return "einsum"
 
 
-def decode_branch_coverage(cfg: ModelConfig, branch: str) -> str:
-    """``branch`` as /healthz ``config.attention.decode`` prints it: with
-    the layers it covers where it does not cover all (a model with window
-    layers: the branch is its full layers')."""
+def decode_branch_coverage(cfg: ModelConfig, branch: str, ring: int) -> str:
+    """``branch`` as /healthz ``config.attention.decode`` prints it: with the
+    layers it covers, where a model's full layers and its rings may differ."""
     if cfg.attn_pattern is None or branch == "einsum":
         return branch
-    return f"{branch} (full layers; window layers: einsum over the ring)"
+    from p2p_llm_tunnel_tpu.models.swa import ring_read
+    return f"{branch} (full layers; window layers: {ring_read(cfg, ring)})"
 
 
 def _prefill_attention_fn(cfg: ModelConfig, mesh, t: int):

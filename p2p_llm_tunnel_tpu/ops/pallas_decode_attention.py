@@ -4,10 +4,13 @@
 the TPU: one invocation a layer over the stacked cache where it lies, a
 software pipeline over each live row's blocks, no view; since ISSUE 36 also
 over planes whose rows hold a position's KV heads side by side, keys and
-values not equally wide (models/swa.py's full layers).  Score, mask, softmax
-and value product are one kernel where the einsum
-(ops/attention.py ``cached_attention``) lowers to several, and a row's blocks
-past its position are neither fetched nor computed.
+values not equally wide (models/swa.py's full layers), and since ISSUE 56
+over rings of such planes (its window layers: slot ``p % R`` holds position
+``p``), by a work list that stops at the window from below as well and a
+mask by the position a slot holds.  Score, mask, softmax and value product
+are one kernel where the einsum (ops/attention.py ``cached_attention``)
+lowers to several, and a row's blocks past its position are neither fetched
+nor computed.
 
 ``models/transformer.py`` ``decode_attention_branch`` is the one place that
 chooses between this kernel and the einsum, from what the code can observe
@@ -85,6 +88,67 @@ def decode_rows_worklist(positions: jnp.ndarray, seq: int,
     return jnp.concatenate([ends[-1:], row << 16 | blk, pos])
 
 
+#: A ring item's marks beside its block's index (the low bits): the row's
+#: first item (the softmax starts over), its last (the row is emitted), and
+#: whether that last block may be fetched only as far as the row's slot.
+RING_FIRST, RING_LAST, RING_PART = 1 << 15, 1 << 14, 1 << 13
+
+
+def ring_run(positions, ring: int, block: int, window: int):
+    """Which blocks of a ring of ``ring`` slots hold a position in ``(p -
+    window, p]`` once ``p`` lies at ``p % ring``: (the oldest such
+    position's slot, blocks) of the cyclic run that starts at that slot's
+    block.  Before the ring wraps the run is ``max(0, p - window + 1) //
+    block .. p // block``; after, the blocks over slots ``(p - window + 1) %
+    ring .. p % ring``, which come round to their own first block where the
+    window is the ring (counted once: never more than the ring's blocks).
+    Whole numbers of numpy or of jax alike (the engine counts what a step
+    fetches from the host's positions)."""
+    oldest = (positions - min(window, ring) + 1).clip(0)
+    at = oldest % ring
+    blocks = (at % block + (positions - oldest)) // block + 1
+    return at, blocks.clip(None, ring // block)
+
+
+def ring_row_items(ring: int, block: int, window: int) -> int:
+    """The most blocks :func:`ring_run` names for one row."""
+    return min(ring // block, (window + block - 2) // block + 1)
+
+
+def decode_ring_worklist(positions: jnp.ndarray, limit: int, ring: int,
+                         block: int, window: int) -> jnp.ndarray:
+    """:func:`decode_rows_worklist` for rings: for every row at a position
+    ``< limit`` (the full planes' length: a row parked there has no item)
+    only the ring blocks of :func:`ring_run`, oldest first, so the newest
+    block, which holds the row's own slot, is a row's last item.  ``[1 + N
+    + B] int32`` with ``N = B * ring_row_items(...)``: the count, ``row <<
+    16 | marks | block index`` an item (``RING_FIRST``, ``RING_LAST``;
+    ``RING_PART`` on a last item whose slots past the row's own hold nothing
+    the window admits), the positions.  Made once a step and shared by the
+    window layers."""
+    b = positions.shape[0]
+    n_sb = ring // block
+    pos = positions.astype(jnp.int32)
+    at, run = ring_run(pos, ring, block, window)
+    nblk = jnp.where(pos < limit, run, 0)
+    ends = jnp.cumsum(nblk)
+    w = jnp.arange(b * ring_row_items(ring, block, window), dtype=jnp.int32)
+    row = jnp.minimum(
+        jnp.searchsorted(ends, w, side="right", method="compare_all")
+        .astype(jnp.int32), b - 1)
+    nth = w - (ends - nblk)[row]
+    blk = (at[row] // block + nth) % n_sb
+    last = nth == nblk[row] - 1
+    # (a run that came round to its first block holds the window's oldest
+    # positions past the row's own slot: that block is read whole)
+    slot = pos % ring
+    came_round = (at // block == slot // block) & (at > slot)
+    part = last & ~came_round[row]
+    marks = (jnp.where(nth == 0, RING_FIRST, 0) | jnp.where(last, RING_LAST, 0)
+             | jnp.where(part, RING_PART, 0))
+    return jnp.concatenate([ends[-1:], row << 16 | marks | blk, pos])
+
+
 def _decode_rows_kernel(
     layer_sref,  # scalar-prefetch [2] int32: layer index into the [L,...]
     #              cache, sliding window (S+1 = disabled)
@@ -92,11 +156,10 @@ def _decode_rows_kernel(
     q_ref,      # [B, H, D] every row's query heads (head = kv_head * G + g)
     k_hbm,      # [L, B, S*K, D] the stacked cache where it lies (HBM)
     v_hbm,
-    o_ref,      # [B, H, D]
-    kbuf,       # [DEPTH, BS*K, D] ring of key blocks
-    vbuf,
-    sem,        # DMA semaphores [2, DEPTH]
-    *,
+    *rest,      # [sink_ref [H, 1] float32 under ``sink``,] then
+    #             o_ref [B, H, D],
+    #             kbuf, vbuf [DEPTH, BS*K, D] the key and value blocks in
+    #             flight, sem [2, DEPTH] their DMA semaphores
     scale: float,
     softcap: Optional[float],
     block_s: int,
@@ -104,6 +167,8 @@ def _decode_rows_kernel(
     parts: int,
     kv_heads: int,
     side_by_side: bool = False,
+    ring: int = 0,
+    sink: bool = False,
 ):
     """One invocation a layer: a software pipeline over the step's work
     list.  Each item is one ``[BS*K, D]`` block of one row — positions
@@ -123,7 +188,18 @@ def _decode_rows_kernel(
     zeros elsewhere, so ONE product scores every head against the block
     with no head mask (the other heads' keys meet zeros), and a KV head's
     value columns — a static slice of whole lane tiles — take only its own
-    query heads' weights.  ``o_ref`` is ``[B, H, Dv]``."""
+    query heads' weights.  ``o_ref`` is ``[B, H, Dv]``.
+
+    ``ring`` (ISSUE 56: the planes' ``S`` is a ring of that many slots, slot
+    ``p % ring`` holding position ``p``) changes two things, both static: a
+    block column's position is the newest ``<=`` the row's own that lies at
+    its slot (``ops.attention.ring_positions``; one the ring has not come
+    round to is negative and masked), and a row's first and last item are
+    what ``decode_ring_worklist`` marked, not block 0 and the row's own
+    block.  Under ``sink`` a row's denominator takes ``exp(sink_h - m)``
+    once, when the row is emitted (``ops.attention.masked_attention``)."""
+    sink_ref = rest[0] if sink else None
+    o_ref, kbuf, vbuf, sem = rest[-4:]
     layer = layer_sref[0]
     window = layer_sref[1]
     n_work = work_sref[0]
@@ -151,7 +227,8 @@ def _decode_rows_kernel(
             jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0), h // kv_heads)
 
     def item(w):
-        """Work item ``w``: its row, its block's index, the row's position."""
+        """Work item ``w``: its row, its block's index (under ``ring`` with
+        the item's marks above it), the row's position."""
         packed = work_sref[1 + w]
         row = packed >> 16
         return row, packed & 0xFFFF, work_sref[pos_at + row]
@@ -163,9 +240,12 @@ def _decode_rows_kernel(
         earlier item left there, which the mask takes out (the value ring
         is zeroed once below, so what it takes out is finite)."""
         row, blk, pos = item(w)
+        if ring:
+            in_part = (blk & RING_PART) != 0
+            blk, pos = blk & (RING_PART - 1), pos % ring
         start = pl.multiple_of(blk * rows_per_blk, rows_per_blk)
         part = rows_per_blk // parts
-        needed = jnp.where(blk == pos // block_s,
+        needed = jnp.where(in_part if ring else blk == pos // block_s,
                            (pos % block_s) * per_pos // part + 1, parts)
         for n in range(1, parts + 1):
             @pl.when(needed == n)
@@ -214,7 +294,11 @@ def _decode_rows_kernel(
         # ahead.
         start(w + depth - 1)
 
-        first = blk == 0
+        if ring:
+            first, last = (blk & RING_FIRST) != 0, (blk & RING_LAST) != 0
+            blk = blk & (RING_PART - 1)
+        else:
+            first = blk == 0
         m_prev = jnp.where(first, _NEG_INF, m_prev)
         l_prev = jnp.where(first, 0.0, l_prev)
         acc = jnp.where(first, 0.0, acc)
@@ -228,8 +312,14 @@ def _decode_rows_kernel(
         ) * scale  # [H, BS*K]
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        k_pos = blk * block_s + col_off  # [1, BS*K]
-        live = (k_pos <= pos) & ((pos - k_pos) < window)
+        if ring:
+            # How far behind the row's own position a slot's is.
+            at = pos % ring - (blk * block_s + col_off)
+            behind = jnp.where(at < 0, at + ring, at)  # [1, BS*K]
+            live = (behind <= pos) & (behind < window)
+        else:
+            k_pos = blk * block_s + col_off  # [1, BS*K]
+            live = (k_pos <= pos) & ((pos - k_pos) < window)
         if not side_by_side:
             live = live & (col_head == row_head)
         s = jnp.where(live, s, _NEG_INF)
@@ -248,9 +338,16 @@ def _decode_rows_kernel(
             pv = weighted(p, v)
         acc = acc * corr + pv
 
-        @pl.when(blk == pos // block_s)
+        @pl.when(last if ring else blk == pos // block_s)
         def _emit():
-            o_ref[row] = (acc / jnp.maximum(l_new, 1e-30)).astype(o_ref.dtype)
+            if sink:
+                top = jnp.maximum(m_new, sink_ref[...])
+                keys = jnp.exp(m_new - top)
+                total = l_new * keys + jnp.exp(sink_ref[...] - top)
+                o_ref[row] = (acc * keys / total).astype(o_ref.dtype)
+            else:
+                o_ref[row] = (acc / jnp.maximum(l_new, 1e-30)).astype(
+                    o_ref.dtype)
 
         return m_new, l_new, acc
 
@@ -272,6 +369,8 @@ def decode_attention_rows(
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
     window=None,  # None | int | traced int scalar
+    ring: bool = False,  # static: ``S`` is a ring, ``work`` its list
+    sink: Optional[jnp.ndarray] = None,  # [H] float32 logits
     interpret: bool = False,
 ) -> jnp.ndarray:
     """``cached_attention`` over layer ``layer_idx`` of the stacked cache,
@@ -281,7 +380,14 @@ def decode_attention_rows(
     already holds this step's own row at the positions the work list was
     made from.  Same mathematics as the einsum: the cache's own operands
     into float32 scores, float32 softmax and accumulation.  A window masks;
-    it does not yet bound the blocks fetched from below.
+    over the planes it does not bound the blocks fetched from below.
+
+    Under ``ring`` the planes' ``S`` slots are a ring (slot ``p % S`` holds
+    position ``p``), ``work`` is ``decode_ring_worklist``'s and names only
+    the blocks that hold a position of the row's window, and a slot is
+    masked by the position it holds.  ``sink``: one logit a head that joins
+    the softmax's denominator and carries no value
+    (``ops.attention.masked_attention``).
 
     The layout is the cache's own and read off its shape: five axes are
     ``[L, B, S, K, D]``; four are planes whose rows hold a position's KV
@@ -316,7 +422,10 @@ def decode_attention_rows(
         _decode_rows_kernel,
         scale=scale, softcap=softcap, block_s=block, depth=ROWS_DEPTH,
         parts=ROWS_PARTS, kv_heads=kh, side_by_side=side_by_side,
+        ring=s if ring else 0, sink=sink is not None,
     )
+    more = [] if sink is None else [
+        sink.astype(jnp.float32).reshape(h, 1)]
     if not side_by_side:
         # (a bitcast: positions outermost, KV heads inside, as they lie)
         k_cache = k_cache.reshape(l, b, s * kh, d)
@@ -329,7 +438,7 @@ def decode_attention_rows(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
-            in_specs=[vmem, hbm, hbm],
+            in_specs=[vmem, hbm, hbm] + [vmem] * len(more),
             out_specs=vmem,
             scratch_shapes=[
                 pltpu.VMEM((ROWS_DEPTH, rows, k_cache.shape[-1]),
@@ -341,4 +450,4 @@ def decode_attention_rows(
         ),
         interpret=interpret,
         name=ROWS_KERNEL,
-    )(layer, work, q, k_cache, v_cache)
+    )(layer, work, q, k_cache, v_cache, *more)

@@ -59,6 +59,14 @@ METRICS_CATALOG: Dict[str, str] = {
         "over the sum of the two it is the share of attention's reads that "
         "the windows bound (counter)"
     ),
+    "engine_kv_rows_window_read_total": (
+        "cache positions x window layers that the window layers' read "
+        "fetched, from the rows' positions on the host: a decode step takes "
+        "the whole ring a live row under the einsum and the ring blocks that "
+        "hold its window under the rows kernel, a prefill dispatch what it "
+        "needs; engine_kv_rows_window_total over it is how tight the read "
+        "is (counter)"
+    ),
     "engine_block_row_passes_total": (
         "passes of real rows through the block decode program of a model "
         "that generates by blocks, each counted once whatever it carries "

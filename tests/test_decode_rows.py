@@ -32,15 +32,23 @@ from p2p_llm_tunnel_tpu.models.transformer import (
 from p2p_llm_tunnel_tpu.ops.attention import (
     cached_attention,
     masked_attention,
+    ring_positions,
     window_mask,
 )
 from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
+    RING_FIRST,
+    RING_LAST,
+    RING_PART,
     ROWS_BLOCK,
     decode_attention_rows,
+    decode_ring_worklist,
     decode_rows_worklist,
+    ring_row_items,
+    ring_run,
     rows_block,
 )
 from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
+from tests.ring_rows import blocks_by_hand
 
 S = 512
 #: position 0, a block's last and the next block's first, S - 1, a row
@@ -290,6 +298,220 @@ def test_a_parked_row_leaves_the_live_rows_answers_to_the_bit(layout):
     np.testing.assert_array_equal(
         got[live], np.asarray(rows(jnp.asarray(busy)))[live])
     assert not got[[2, 4, 6]].any() and got[live].any()
+
+
+# ---------------------------------------------------------------------------
+# rings (ISSUE 56; models/swa.py's window layers): slot ``p % R`` holds
+# position ``p``, the work list stops at the window from below as well
+# ---------------------------------------------------------------------------
+
+#: The full planes' length beside the rings: a row at or past it is parked.
+LIMIT = 4096
+#: (ring, window) -> positions: a ring part full (some rows short of one
+#: block, of the window), a row at position 0, at a block's and the ring's
+#: last slot, just wrapped, wrapped many times, parked.
+RING_CASES = {
+    "window-half-the-ring": (512, 256),       # laguna's proportions
+    "window-a-fifth-of-the-ring": (640, 128),  # mimo's
+    "window-is-the-ring": (256, 256),
+    "window-no-multiple-of-a-block": (384, 200),
+    "window-of-one": (256, 1),
+    "ring-of-one-block": (128, 128),
+    "window-short-of-the-ring-by-one": (384, 383),
+}
+
+
+def _ring_rows(ring):
+    return [0, 5, ROWS_BLOCK - 1, ROWS_BLOCK, ring - 1, ring, ring + 1,
+            ring + ROWS_BLOCK - 1, 3 * ring + 41, 7 * ring - 1, LIMIT - 1,
+            LIMIT, LIMIT + 9, 2 * ring, 300, 1]
+
+
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+def test_ring_worklist_is_each_live_rows_window_blocks_oldest_first(case):
+    ring, window = RING_CASES[case]
+    rows = _ring_rows(ring)
+    work = np.asarray(decode_ring_worklist(
+        jnp.asarray(rows, jnp.int32), LIMIT, ring, ROWS_BLOCK, window))
+    per_row = ring_row_items(ring, ROWS_BLOCK, window)
+    assert per_row == min(ring // ROWS_BLOCK,
+                          -(-(window - 1) // ROWS_BLOCK) + 1)
+    assert work.shape == (1 + len(rows) * per_row + len(rows),)
+    assert list(work[-len(rows):]) == rows
+    items = [(int(w) >> 16, int(w) & (RING_PART - 1),
+              bool(w & RING_FIRST), bool(w & RING_LAST), bool(w & RING_PART))
+             for w in work[1:1 + work[0]]]
+    want, most = [], 0
+    for row, p in enumerate(rows):
+        if p >= LIMIT:
+            continue  # parked: no work
+        blocks = blocks_by_hand(p, ring, window, ROWS_BLOCK)
+        most = max(most, len(blocks))
+        newest = p % ring // ROWS_BLOCK
+        # the newest block may be cut at the row's slot unless the window's
+        # oldest positions lie past it in the same block
+        cut = all(held % ring <= p % ring
+                  for held in range(max(0, p - window + 1), p + 1)
+                  if held % ring // ROWS_BLOCK == newest)
+        for n, blk in enumerate(blocks):
+            last = n == len(blocks) - 1
+            want.append((row, blk, n == 0, last, last and cut))
+            assert not (last and cut) or blk == newest
+    assert items == want
+    assert most <= per_row
+    # the engine's count of what a step fetches is the list's
+    _, counted = ring_run(np.asarray(rows), ring, ROWS_BLOCK, window)
+    assert int(counted[np.asarray(rows) < LIMIT].sum()) == work[0]
+
+
+def _ring_planes(h, kh, dk, dv, dtype, ring, layers=2, seed=0, sink=False):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    rows = _ring_rows(ring)
+    b = len(rows)
+    q = jax.random.normal(ks[0], (b, h, dk), dtype)
+    k = jax.random.normal(ks[1], (layers, b, ring, kh * dk), dtype)
+    v = jax.random.normal(ks[2], (layers, b, ring, kh * dv), dtype)
+    logits = jax.random.normal(ks[3], (h,), jnp.float32) if sink else None
+    return q, k, v, jnp.asarray(rows, jnp.int32), logits
+
+
+def _ring_oracle(q, k, v, pos, layer, window, logits):
+    """``masked_attention`` over the layer's ring, each slot named by the
+    position it holds: what the einsum path computes."""
+    b, h, dk = q.shape
+    ring, kh = k.shape[2], k.shape[-1] // dk
+    mask = window_mask(pos[:, None], ring_positions(pos, ring), window)
+    return masked_attention(
+        q[:, None], k[layer].reshape(b, ring, kh, dk),
+        v[layer].reshape(b, ring, kh, -1), mask, dk ** -0.5,
+        sink=logits)[:, 0]
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["no-sink", "sink"])
+@pytest.mark.parametrize("h,kh,dk,dv,dtype,tol", [
+    (72, 8, 128, 128, jnp.float32, 2e-5),   # laguna's window layers: 9 a
+    (72, 8, 128, 128, jnp.bfloat16, 2e-2),  # KV head, and its precision
+    (64, 8, 192, 128, jnp.float32, 2e-5),   # mimo's: 8 a KV head, keys
+    (64, 8, 192, 128, jnp.bfloat16, 2e-2),  # wider than values
+    (9, 3, 16, 16, jnp.float32, 2e-5),      # tiny-laguna's
+    (4, 2, 24, 16, jnp.float32, 2e-5),      # tiny-swa-moe's
+])
+def test_rows_match_the_einsum_on_rings(h, kh, dk, dv, dtype, tol, sink):
+    """The ring form against ``masked_attention`` over ``ring_positions``,
+    planes side by side at both cells' head counts and widths, with and
+    without a sink: rings part full, at every edge, wrapped and parked."""
+    ring, window = 384, 200
+    q, k, v, pos, logits = _ring_planes(h, kh, dk, dv, dtype, ring, sink=sink)
+    live = np.asarray(pos) < LIMIT
+    work = decode_ring_worklist(pos, LIMIT, ring, ROWS_BLOCK, window)
+    got = decode_attention_rows(
+        q, k, v, jnp.int32(1), work, block=ROWS_BLOCK, window=window,
+        ring=True, sink=logits, interpret=True)
+    assert got.shape == (len(live), h, dv) and got.dtype == dtype
+    got = np.asarray(got, np.float32)
+    want = np.asarray(_ring_oracle(q, k, v, pos, 1, window, logits),
+                      np.float32)
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+def test_rows_match_the_einsum_on_every_ring_and_window(case):
+    """Every proportion of window and ring, a sink, the layer index traced
+    inside a scan (the runs' scans hand it so)."""
+    ring, window = RING_CASES[case]
+    q, k, v, pos, logits = _ring_planes(8, 2, 24, 16, jnp.float32, ring,
+                                        layers=3, seed=3, sink=True)
+    work = decode_ring_worklist(pos, LIMIT, ring, ROWS_BLOCK, window)
+
+    def body(_, idx):
+        return None, decode_attention_rows(
+            q, k, v, idx, work, block=ROWS_BLOCK, window=window, ring=True,
+            sink=logits, interpret=True)
+
+    _, got = jax.lax.scan(body, None, jnp.arange(3))
+    live = np.asarray(pos) < LIMIT
+    for layer in range(3):
+        want = _ring_oracle(q, k, v, pos, layer, window, logits)
+        np.testing.assert_allclose(np.asarray(got[layer])[live],
+                                   np.asarray(want)[live],
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_a_ring_on_the_stacked_layout_reads_like_the_planes():
+    """The position map is the layout's neighbour, not its part: a ring of
+    ``[L, B, R, K, D]`` reads like the same values side by side."""
+    ring, window = 256, 100
+    q, k, v, pos, _ = _ring_planes(8, 2, 32, 32, jnp.float32, ring, seed=7)
+    work = decode_ring_worklist(pos, LIMIT, ring, ROWS_BLOCK, window)
+    got = [decode_attention_rows(
+        q, a, b_, jnp.int32(1), work, block=ROWS_BLOCK, window=window,
+        ring=True, interpret=True)
+        for a, b_ in ((k, v), (k.reshape(k.shape[:3] + (2, 32)),
+                               v.reshape(v.shape[:3] + (2, 32))))]
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(got[1]),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["window-half-the-ring",
+                                  "window-a-fifth-of-the-ring"])
+def test_a_ring_is_fetched_no_further_than_its_window(case):
+    """What the list does not name is in no item: NaN keys and huge values
+    in every block outside a row's run (and in every other layer) change
+    nothing, to the bit."""
+    ring, window = RING_CASES[case]
+    q, k, v, pos, logits = _ring_planes(8, 2, 24, 16, jnp.float32, ring,
+                                        seed=9, sink=True)
+    work = decode_ring_worklist(pos, LIMIT, ring, ROWS_BLOCK, window)
+    named = np.zeros((len(pos), ring // ROWS_BLOCK), bool)
+    for w in np.asarray(work[1:1 + int(work[0])]):
+        named[int(w) >> 16, int(w) & (RING_PART - 1)] = True
+    assert not named.all(axis=1).any()  # every row leaves blocks out
+    bad = ~np.repeat(named, ROWS_BLOCK, axis=1)[None, :, :, None]
+    bad = jnp.asarray(bad | (np.arange(2) != 1)[:, None, None, None])
+    got, poisoned = (
+        decode_attention_rows(q, a, b_, jnp.int32(1), work, block=ROWS_BLOCK,
+                              window=window, ring=True, sink=logits,
+                              interpret=True)
+        for a, b_ in ((k, v), (jnp.where(bad, jnp.nan, k),
+                               jnp.where(bad, 3e38, v))))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(poisoned))
+
+
+#: sha256 of the jaxpr text of ``decode_attention_rows`` with the ring's
+#: switches off, as the parent (PR 55) traced it: the dense family, the
+#: pattern family's attention layers and the window-and-full family's full
+#: layers run this specialisation in nine accepted cells (ISSUE 56: with
+#: the switches off the kernel traces to the parent's text).
+PARENT_JAXPRS = {
+    ("stacked", None): "5cb3a5d07f1a5ea8",
+    ("stacked", 64): "6cb2ea901d548d80",
+    ("planes", None): "2d49f24746bfcaff",
+}
+
+
+@pytest.mark.parametrize("layout,window", sorted(
+    PARENT_JAXPRS, key=lambda k: (k[0], k[1] or 0)))
+def test_the_switches_off_trace_to_the_parents_kernel(layout, window):
+    import hashlib
+
+    b, h, kh, d = 8, 8, 2, 128
+    shape = (3, b, S, kh * d) if layout == "planes" else (3, b, S, kh, d)
+    block = rows_block(S, kh)
+
+    def fn(q, k, v, pos, idx):
+        return decode_attention_rows(
+            q, k, v, idx, decode_rows_worklist(pos, S, block), block=block,
+            window=window)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    text = str(jax.make_jaxpr(fn)(
+        arg((b, h, d)), arg(shape), arg(shape), arg((b,), jnp.int32),
+        arg((), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_JAXPRS[layout, window]
 
 
 def test_rows_refuse_a_sequence_that_does_not_tile():

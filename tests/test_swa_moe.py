@@ -479,7 +479,8 @@ def test_the_records_and_the_counters_carry_the_rows_read_by_kind():
     from p2p_llm_tunnel_tpu.utils.metrics import METRICS_CATALOG, global_metrics
     from p2p_llm_tunnel_tpu.utils.tracing import global_tracer
 
-    names = ("engine_kv_rows_full_total", "engine_kv_rows_window_total")
+    names = ("engine_kv_rows_full_total", "engine_kv_rows_window_total",
+             "engine_kv_rows_window_read_total")
     assert all(n in METRICS_CATALOG for n in names)
     prompt = _prompt(9, 37)
     global_tracer.clear()
@@ -504,12 +505,18 @@ def test_the_records_and_the_counters_carry_the_rows_read_by_kind():
         assert r.attrs["kv_rows_full"] == 2 * sum(p + 1 for p in seen)
         assert r.attrs["kv_rows_window"] == 5 * sum(
             min(p + 1, WINDOW) for p in seen)
+        # a prefill dispatch gathers its window by position: read = need
+        assert r.attrs["kv_rows_window_read"] == r.attrs["kv_rows_window"]
     for r in bursts:
         a = r.attrs
         assert a["kv_rows_window"] == 5 * WINDOW * a["live_rows"] * a["steps"]
         assert a["kv_rows_full"] >= 2 * 37 * a["live_rows"] * a["steps"]
+        # the einsum reads the whole ring a live row, step and layer
+        assert a["kv_rows_window_read"] == \
+            5 * RING * a["live_rows"] * a["steps"]
     assert [sum(r.attrs[k] for r in segs + bursts)
-            for k in ("kv_rows_full", "kv_rows_window")] == grew
+            for k in ("kv_rows_full", "kv_rows_window",
+                      "kv_rows_window_read")] == grew
     # a model with one kind of layer counts it all as that kind
     dense = _engine("tiny")
     assert dense._attn_kinds == (False, False) and dense._ring == 0
@@ -614,9 +621,17 @@ def test_the_branch_is_decided_by_what_the_code_observes(case, cpu_devices):
     seq = 8192 if name.startswith("mimo") else 512
     assert decode_attention_branch(cfg, mesh, 128, kv, seq) == want
     assert prefill_attention_branch(cfg, None, 512) == "einsum"
-    covers = decode_branch_coverage(cfg, want)
+    ring = cfg.ring_default(seq, 512 if name.startswith("mimo") else 0)
+    covers = decode_branch_coverage(cfg, want, ring)
     assert covers.startswith(want) and ("window layers" in covers) == (
         want != "einsum")
+    # the rings follow the full layers where they tile (ISSUE 56): mimo's
+    # 640 slots of 1,536 and 1,024 values do, the tiny preset's 16 do not
+    assert ring == (640 if name.startswith("mimo") else RING)
+    assert ("rows of the ring" in covers) == (
+        want != "einsum" and name.startswith("mimo"))
+    assert ("einsum over the ring" in covers) == (
+        want != "einsum" and not name.startswith("mimo"))
     if name.startswith("mimo"):
         return  # the share at its size is tests/test_tpu_compile.py's
     # what decode_step traces

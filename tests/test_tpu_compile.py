@@ -31,6 +31,7 @@ from p2p_llm_tunnel_tpu.ops.pallas_attention import flash_causal_attention
 from p2p_llm_tunnel_tpu.ops.pallas_decode_attention import (
     ROWS_KERNEL,
     decode_attention_rows,
+    decode_ring_worklist,
     decode_rows_worklist,
     rows_block,
 )
@@ -327,6 +328,48 @@ def test_rows_decode_compiles_for_planes_of_heads_side_by_side(chip):
         assert copies == [] and made == []
 
 
+#: (layers, rows, ring, KV heads, key and value width a head, query heads,
+#: window, the full planes' length, a sink?) of the two cells' window layers.
+RING_CELLS = {
+    "laguna-s-2.1": (6, 65, 1024, 8, 128, 128, 72, 512, 6144, False),
+    "mimo-v2-flash": (5, 49, 640, 8, 192, 128, 64, 128, 8192, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(RING_CELLS))
+def test_rows_decode_compiles_for_the_cells_rings(chip, cell):
+    """The ring form (ISSUE 56) at both cells' window shapes: laguna's
+    ``[6,65,1024,1024]`` with 72 query heads (9 a KV head), mimo's
+    ``[5,49,640,1536]`` / ``[..,1024]`` with 64 and a sink: one Mosaic
+    kernel that takes the rings as they lie (every row's queries, ``[B, H,
+    K * Dk]`` = 9.6 MB at either, fit VMEM beside the blocks in flight),
+    the list of ``B x (window / 128 + 1)`` items."""
+    layers, rows, ring, kv, dk, dv, heads, window, seq, sink = RING_CELLS[cell]
+    k = ((layers, rows, ring, kv * dk), jnp.bfloat16)
+    v = ((layers, rows, ring, kv * dv), jnp.bfloat16)
+    block = rows_block(ring, kv)
+    assert block == 128
+
+    def fn(q, k, v, pos, layer, logits):
+        work = decode_ring_worklist(pos, seq, ring, block, window)
+        assert work.shape == (1 + rows * (window // 128 + 1) + rows,)
+        return decode_attention_rows(
+            q, k, v, layer, work, block=block, window=window, ring=True,
+            sink=logits if sink else None)
+
+    args = [
+        jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in (
+            ((rows, heads, dk), jnp.bfloat16), k, v,
+            ((rows,), jnp.int32), ((), jnp.int32), ((heads,), jnp.float32))
+    ]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    assert f"bf16[{rows},{heads},{dv}]" in hlo
+    for plane in (k, v):
+        copies, made = _plane_work(hlo, math.prod(plane[0]))
+        assert copies == [] and made == []
+
+
 def _dense_decode_hlo(chip, cfg, view, kv=None):
     """(``decode_step`` at the 7B cells' shapes, 33 rows x 1024 of cache in
     form ``kv``, donated, compiled for the described chip; the cache's
@@ -618,13 +661,26 @@ def test_the_four_planes_are_written_where_they_lie(chip, program):
     assert aliased.count("alias") == len(cache)
 
 
+def _no_layer_of_a_plane(hlo, rows, seq, widths):
+    """No value of one layer's ``[rows, seq, width]`` in a compiled program:
+    no slice of it out of the stacked plane, no copy of one."""
+    for width in widths:
+        assert f"[1,{rows},{seq},{width}]" not in hlo
+        assert f"[{rows},{seq},{width}]" not in hlo
+        assert "dynamic-slice" not in "".join(
+            line for line in hlo.splitlines()
+            if f"{rows},{seq},{width}]" in line)
+
+
 def _mimo_decode_slices_no_full_plane(chip):
     """``decode_step`` as a TPU backend runs it at the cell's shapes (ISSUE
     36; the feed-forwards narrowed): the rows kernel once in each of the two
     runs that hold a full layer, and no ``[1,49,8192,768]`` or ``[..,512]``
     left, which as a ``dynamic-slice`` and a ``copy`` of each were the eight
     largest operations of the cell's window (PERF.md section 6, PR 36).
-    The window layers' ring is still sliced: ``[1,49,640,*]``."""
+    Since ISSUE 56 the window layers' rings are read where they lie too: the
+    kernel in all four runs, no ``[1,49,640,1536]`` or ``[..,1024]`` (the
+    einsum path still slices them)."""
     from p2p_llm_tunnel_tpu.models.transformer import decode_attention_branch
 
     cfg, cache, compiled = _swa_compiled(
@@ -634,19 +690,15 @@ def _mimo_decode_slices_no_full_plane(chip):
         replace(cfg, flash_force=True), None, 1024, None, SWA_SEQ) \
         == "pallas-rows"
     hlo = compiled.as_text()
-    assert hlo.count(ROWS_KERNEL) >= 2
-    for width in (768, 512):
-        assert f"[1,{SWA_ROWS},{SWA_SEQ},{width}]" not in hlo
-        assert f"[{SWA_ROWS},{SWA_SEQ},{width}]" not in hlo
-        assert "dynamic-slice" not in "".join(
-            line for line in hlo.splitlines()
-            if f"{SWA_ROWS},{SWA_SEQ},{width}]" in line)
-    assert f"[1,{SWA_ROWS},{SWA_RING}," in hlo
+    assert hlo.count(ROWS_KERNEL) >= 4
+    for seq, widths in ((SWA_SEQ, (768, 512)), (SWA_RING, (1536, 1024))):
+        _no_layer_of_a_plane(hlo, SWA_ROWS, seq, widths)
     # and the einsum path, which a CPU backend and the int8 planes keep,
     # still has them
     _, _, einsum = _swa_compiled(
         chip, "decode-8192", ffn_dim=512, moe_ffn_dim=128, vocab_size=1024)
     assert f"[1,{SWA_ROWS},{SWA_SEQ},768]" in einsum.as_text()
+    assert f"[1,{SWA_ROWS},{SWA_RING},1536]" in einsum.as_text()
     assert ROWS_KERNEL not in einsum.as_text()
 
 
@@ -763,8 +815,13 @@ def test_the_laguna_share_holds_its_planes_as_stated_and_fits(chip, program):
     assert held < 14.5 * 2 ** 30, f"{held / 2 ** 30:.2f} GiB"
     assert _grouped_products(hlo, kernel=True) >= 3
     if program.startswith("decode"):
-        assert hlo.count(ROWS_KERNEL) >= 2
-        assert f"[1,{LAG_ROWS},{LAG_SEQ},1024]" not in hlo
+        # the kernel in all four runs (ISSUE 56: the rings too, 72 query
+        # heads on 8 KV heads: a group of 9) and no layer of a plane or of a
+        # ring sliced out: ``bf16[1,65,1024,1024]`` as a slice and a copy
+        # were eight of the ten largest operations of the cell's first line
+        assert hlo.count(ROWS_KERNEL) >= 4
+        for seq in (LAG_SEQ, LAG_RING):
+            _no_layer_of_a_plane(hlo, LAG_ROWS, seq, (1024,))
 
 
 # ---------------------------------------------------------------------------
