@@ -884,6 +884,13 @@ async def _amain(args) -> None:
             "request tracing on: buffer=%d sample=%.3f (export: GET "
             "/healthz?trace=1)", args.trace_buffer, args.trace_sample,
         )
+        # The flight ring rides the same export: give it the journal's
+        # room, so a traced run's records reach back as far as its spans
+        # (1,024 records are under a minute of a busy loop, ISSUE 57).
+        from p2p_llm_tunnel_tpu.utils.flight import global_flight
+
+        global_flight.configure(
+            capacity=max(global_flight.capacity, args.trace_buffer))
     if args.command == "serve":
         if args.backend == "tpu":
             # Before the first compile: a second start of the same

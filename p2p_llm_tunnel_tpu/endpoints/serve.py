@@ -53,6 +53,7 @@ from p2p_llm_tunnel_tpu.utils.flight import (
     global_blackbox,
     global_compile_watch,
     global_flight,
+    global_gc,
 )
 from p2p_llm_tunnel_tpu.utils.logging import get_logger
 from p2p_llm_tunnel_tpu.utils.metrics import (
@@ -1240,6 +1241,7 @@ async def _serve_dispatch(
                     # peer its own engine-flight lane for free.  So does
                     # the start-up journal (ISSUE 40), whatever the span
                     # rings have turned over since.
+                    global_gc.publish()  # the collector's kept pauses
                     trace = global_tracer.chrome_trace()
                     trace["traceEvents"] = (
                         list(trace["traceEvents"])
@@ -1284,6 +1286,7 @@ async def _serve_dispatch(
                 # are refreshed first so the slo_* labeled series a fleet
                 # scrape relabels are current at every scrape.
                 global_slo.publish()
+                global_gc.publish()
                 await _send_simple(
                     channel, req.stream_id, 200,
                     global_metrics.prometheus_text().encode(),

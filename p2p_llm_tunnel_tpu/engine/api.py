@@ -938,9 +938,11 @@ class EngineAPI:
                 Metrics,
                 global_metrics,
             )
+            from p2p_llm_tunnel_tpu.utils.flight import global_gc
             from p2p_llm_tunnel_tpu.utils.slo import global_slo
 
             global_slo.publish()  # slo_* series current at every scrape
+            global_gc.publish()  # and the collector's counters
             return (
                 200,
                 {"content-type": Metrics.PROM_CONTENT_TYPE},

@@ -55,9 +55,11 @@ from p2p_llm_tunnel_tpu.models.transformer import (
 )
 from p2p_llm_tunnel_tpu.ops.pallas_ssm_step import ELEMENTWISE
 from p2p_llm_tunnel_tpu.utils.flight import (
+    IterationSplit,
     global_blackbox,
     global_compile_watch,
     global_flight,
+    global_gc,
 )
 from p2p_llm_tunnel_tpu.utils.logging import get_logger
 from p2p_llm_tunnel_tpu.utils.metrics import (
@@ -1198,6 +1200,10 @@ class InferenceEngine(BlockDecodeMixin):
         # Non-idle loop iterations so far (engine.prefill_exec's
         # ``iterations`` is a difference of two readings).
         self._loop_iter = 0
+        # Where the running iteration's wall goes (ISSUE 57): the loop makes
+        # one an iteration; what it awaits feeds it (_offload, _fetch,
+        # _reserve_pages).
+        self._split = IterationSplit(global_flight)
         self._flight_admitted = 0
         self._flight_conv = 0
         self._flight_pageouts = 0
@@ -1624,6 +1630,7 @@ class InferenceEngine(BlockDecodeMixin):
         if self._task is None:
             self._running = True
             self._stopped = False
+            global_gc.install()
             self._task = asyncio.create_task(self._loop())
             if self.ecfg.watchdog_budget_s > 0:
                 self._watchdog_task = asyncio.create_task(self._watchdog())
@@ -4312,9 +4319,7 @@ class InferenceEngine(BlockDecodeMixin):
                     self._segmented[run.slot] = (run, hist)
                     admitted.remove(run)
             if seg_hits:
-                await loop.run_in_executor(
-                    self._executor, self._prefix_copy_in, seg_hits
-                )
+                await self._offload(loop, self._prefix_copy_in, seg_hits)
         # Group by (tail bucket, cached?): cached runs use the chunk-prefill
         # program, whose bucket is the tail length.  A matched prefix whose
         # tail exceeds every compiled chunk bucket is dropped back to the
@@ -4341,23 +4346,19 @@ class InferenceEngine(BlockDecodeMixin):
         for t, cached, echo, runs in chunked:
             t0 = time.monotonic()
             if cached:
-                await loop.run_in_executor(  # tunnelcheck: disable=TC07  one copy call per prefill_rows-wide chunk, dispatched before that chunk's prefill (same executor, same device order)
-                    self._executor, self._prefix_copy_in,
+                await self._offload(  # tunnelcheck: disable=TC07  one copy call per prefill_rows-wide chunk, dispatched before that chunk's prefill (same executor, same device order)
+                    loop, self._prefix_copy_in,
                     [(run.slot, pool_ids_of[run.slot]) for run in runs],
                 )
             hists = [hist_of[r.slot] for r in runs] if cached else None
-            first_dev = await loop.run_in_executor(  # tunnelcheck: disable=TC07  one dispatch per prefill_rows-wide bucket chunk, back-to-back so chunk n+1 computes under chunk n's RTT
-                self._executor, self._dispatch_prefill_batch, runs, t, hists,
-                echo,
+            first_dev = await self._offload(  # tunnelcheck: disable=TC07  one dispatch per prefill_rows-wide bucket chunk, back-to-back so chunk n+1 computes under chunk n's RTT
+                loop, self._dispatch_prefill_batch, runs, t, hists, echo,
             )
             dispatched.append((runs, first_dev, t0, self._last_dispatch))
         inserts: List[RunningSlot] = []
         for runs, first_dev, t0, rec in dispatched:
-            firsts, lp, plp = await loop.run_in_executor(
-                self._executor,
-                lambda fd=first_dev: jax.tree.map(np.asarray,
-                                                  jax.device_get(fd)),  # tunnelcheck: disable=TC07  one FETCH per already-dispatched chunk, in dispatch order: the pipelining that overlaps the RTT with compute
-            )
+            firsts, lp, plp = await self._offload(  # tunnelcheck: disable=TC07  one FETCH per already-dispatched chunk, in dispatch order: the pipelining that overlaps the RTT with compute
+                loop, self._fetch, first_dev)
             self._close_prefill_dispatch(rec)
             # Wall time of this chunk's dispatch → result-on-host span, the
             # per-phase timing SURVEY §5 asks for (overlaps siblings').
@@ -4387,9 +4388,7 @@ class InferenceEngine(BlockDecodeMixin):
         # a chunk's fetch and the next chunk's (the TTFT-critical path).
         live = [r for r in inserts if self.scheduler.slots[r.slot] is r]
         if live:
-            await loop.run_in_executor(
-                self._executor, self._prefix_insert, live
-            )
+            await self._offload(loop, self._prefix_insert, live)
             self._release_pages_for(live)
 
     # -- multiplexed admission (ISSUE 5) ----------------------------------
@@ -4494,9 +4493,7 @@ class InferenceEngine(BlockDecodeMixin):
             # Dispatched before any of the wave's segments (same executor,
             # same device order), so reused history KV is in place when the
             # first tail segment reads it.
-            await loop.run_in_executor(
-                self._executor, self._prefix_copy_in, hits
-            )
+            await self._offload(loop, self._prefix_copy_in, hits)
 
     async def _mux_wake(self, loop) -> None:
         """Release dead owners' in-flight prefix claims and RE-PLAN waiters
@@ -4667,13 +4664,26 @@ class InferenceEngine(BlockDecodeMixin):
                                     [n for _run, n in mid])
         return rows, first_lp, t_dispatch, n_tokens, self._last_dispatch
 
+    async def _offload(self, loop, fn, *args):
+        """The loop's ``run_in_executor``: one call on the XLA executor
+        thread, its own wall and the loop's wait beyond it summed into the
+        running iteration's split (``exec_ms`` / ``lag_ms``).  For what the
+        loop task awaits inside an iteration only: a call from another task
+        would book its time to an iteration it is no part of."""
+        return await self._split.call(loop, self._executor, fn, *args)
+
+    def _fetch(self, dev):
+        """Executor thread: a dispatch's results to the host, blocking
+        until the device has them: the iteration's ``wait_ms``."""
+        began = time.monotonic()
+        out = jax.tree.map(np.asarray, jax.device_get(dev))
+        self._split.fetched(began)
+        return out
+
     async def _finish_segments(self, loop, seg) -> None:
         """Fetch a segment dispatch's sampled block; activate final rows."""
         rows, first_dev, t_dispatch, n_tokens, rec = seg
-        firsts, lp, _plp = await loop.run_in_executor(
-            self._executor,
-            lambda: jax.tree.map(np.asarray, jax.device_get(first_dev)),
-        )
+        firsts, lp, _plp = await self._offload(loop, self._fetch, first_dev)
         # REAL segment tokens (pad rows and a final short segment's pad
         # positions excluded): inflating the denominator would deflate
         # the per-token estimate and underprice every page for the
@@ -4696,9 +4706,7 @@ class InferenceEngine(BlockDecodeMixin):
             if self._prefix is not None:
                 inserts.append(run)
         if inserts:
-            await loop.run_in_executor(
-                self._executor, self._prefix_insert, inserts
-            )
+            await self._offload(loop, self._prefix_insert, inserts)
             self._release_pages_for(inserts)
 
     def _trace_burst(self, rec: Optional[_Dispatch]) -> None:
@@ -4739,7 +4747,10 @@ class InferenceEngine(BlockDecodeMixin):
         need = len(self._prefix.missing(req.prompt_ids))
         if need <= 0:
             return
+        began, before = time.monotonic(), self._prefix.evictions
         granted = self._prefix.reserve(need)
+        if self._prefix.evictions != before:
+            self._split.evicted(began, self._prefix.evictions - before)
         if granted:
             self._page_reserved[req.request_id] = granted
 
@@ -4821,7 +4832,7 @@ class InferenceEngine(BlockDecodeMixin):
             return
         pending, self._conv_pending = self._conv_pending, []
         self._flight_conv = len(pending)
-        await loop.run_in_executor(self._executor, self._conv_insert, pending)
+        await self._offload(loop, self._conv_insert, pending)
 
     def _memory_exhausted(self) -> bool:
         """The ISSUE 16 degradation verdict: BOTH KV tiers exhausted — the
@@ -4869,9 +4880,7 @@ class InferenceEngine(BlockDecodeMixin):
             return
         self._spill_inflight += len(plan)
         try:
-            results = await loop.run_in_executor(
-                self._executor, self._spill_copy_out, plan
-            )
+            results = await self._offload(loop, self._spill_copy_out, plan)
         finally:
             self._spill_inflight -= len(plan)
         committed = 0
@@ -4981,9 +4990,7 @@ class InferenceEngine(BlockDecodeMixin):
             return
         self._spill_inflight += len(items)
         try:
-            results = await loop.run_in_executor(
-                self._executor, self._spill_copy_in, items
-            )
+            results = await self._offload(loop, self._spill_copy_in, items)
         finally:
             self._spill_inflight -= len(items)
         ok_n = 0
@@ -5432,16 +5439,15 @@ class InferenceEngine(BlockDecodeMixin):
             # next (keeps SSE pacing smooth within a burst).
             await asyncio.sleep(0)
 
-    def _flight_record(self, it_t0: float, t_admit: float, t_prefill: float,
-                       t_dispatch: float, t_fetch: float, plain_rows: int,
-                       seg_rows: int, cold0: int) -> None:
+    def _flight_record(self, plain_rows: int, seg_rows: int,
+                       cold0: int) -> None:
         """One flight-recorder row per non-idle loop iteration (ISSUE 12).
 
         Pure host bookkeeping: reads the scratch the iteration's own
         methods stashed (_last_mux/_last_burst/_flight_admitted) plus
         cheap scheduler state — no device traffic, no allocation beyond
-        the record dict, so the ring can stay always-on."""
-        now = time.monotonic()
+        the record dict, so the ring can stay always-on.  Where the wall
+        went (ISSUE 57) is the iteration's split's to say."""
         slots = self.scheduler.slots
         mux = self._last_mux
         # Disagg transfers run off the iteration rhythm (API/serve-driven
@@ -5455,8 +5461,6 @@ class InferenceEngine(BlockDecodeMixin):
             backlog = (len(self._segmented) + len(self._pending_plain)
                        + len(self._prefix_waiters))
         global_flight.record_iteration(
-            t=it_t0,
-            dur_ms=round((now - it_t0) * 1000.0, 3),
             queue_depth=self.scheduler.queue_depth,
             backlog_rows=int(backlog),
             min_slack_s=mux.get("min_slack_s"),
@@ -5500,12 +5504,9 @@ class InferenceEngine(BlockDecodeMixin):
             streams_detached=int(
                 global_metrics.gauge("serve_streams_detached")
             ),
-            admit_ms=round((t_admit - it_t0) * 1000.0, 3),
-            prefill_ms=round((t_prefill - t_admit) * 1000.0, 3),
-            dispatch_ms=round((t_dispatch - t_prefill) * 1000.0, 3),
-            fetch_ms=round((t_fetch - t_dispatch) * 1000.0, 3),
-            process_ms=round((now - t_fetch) * 1000.0, 3),
+            **self._split.fields(),
         )
+        global_gc.publish()
 
     async def _loop(self) -> None:
         loop = asyncio.get_running_loop()
@@ -5514,6 +5515,12 @@ class InferenceEngine(BlockDecodeMixin):
             self.mcfg.name, self.ecfg.num_slots, self.ecfg.max_seq,
             self.ecfg.decode_steps,
         )
+        sums = ("engine_flight_iterations_total",
+                "engine_loop_host_seconds_total",
+                "engine_loop_wait_seconds_total",
+                "engine_loop_lag_seconds_total",
+                "process_gc_pause_seconds_total")
+        sums0 = [global_metrics.counter(name) for name in sums]
         # Crash containment: a dispatch exception must surface loudly
         # and unblock every consumer — without this, one bad program
         # (found the hard way: a shape bug in a new sampler input)
@@ -5529,6 +5536,7 @@ class InferenceEngine(BlockDecodeMixin):
                     # iterations that did work, so its tail is dense with
                     # decisions when a postmortem reads it.
                     global_flight.set_phase("idle")
+                    global_gc.publish()
                     self._drain_moe(all_of_it=True)
                     self._last_progress = time.monotonic()
                     self._wake.clear()
@@ -5544,8 +5552,9 @@ class InferenceEngine(BlockDecodeMixin):
 
                 # Flight recorder (ISSUE 12): per-iteration scratch reset +
                 # phase markers.  A wedged dispatch leaves the phase at the
-                # stalled step — the watchdog's attribution.
-                it_t0 = time.monotonic()
+                # stalled step — the watchdog's attribution.  The split
+                # (ISSUE 57) times the parts between the markers.
+                split = self._split = IterationSplit(global_flight)
                 self._loop_iter += 1
                 self._flight_admitted = 0  # tunnelcheck: disable=TC13  single-writer contract: only THIS loop task and the admission helpers it awaits touch the per-iteration flight scratch; the reset-here/accumulate-in-_note_admission/read-at-record sequence cannot interleave with another writer
                 self._flight_conv = 0
@@ -5556,7 +5565,7 @@ class InferenceEngine(BlockDecodeMixin):
                 self._flight_spec = (0, 0, 0)
                 cold0 = global_compile_watch.cold_total
                 plain_rows = 0
-                global_flight.set_phase("admit")
+                split.enter("admit")
                 self._expire_deadlines()
                 # The page-in splice (ISSUE 16) runs INSIDE admission —
                 # between scheduler.admit() and the wave's matches — for
@@ -5570,12 +5579,11 @@ class InferenceEngine(BlockDecodeMixin):
                     # prefill work.
                     await self._admit_pending(loop)
                     plain_rows += self._flight_admitted
-                t_admit = time.monotonic()
 
                 global_metrics.set_gauge("engine_batch_occupancy", self.scheduler.occupancy)
                 global_metrics.set_gauge("engine_queue_depth", self.scheduler.queue_depth)
                 self._publish_prefix_gauges()
-                global_flight.set_phase("prefill_dispatch")
+                split.enter("prefill_dispatch")
 
                 # Prefill work for this iteration, dispatched before the
                 # decode burst.  Non-mux: one prefill_rows-wide segment
@@ -5603,21 +5611,17 @@ class InferenceEngine(BlockDecodeMixin):
                     # so a drain budget costs ONE iteration, not one
                     # iteration per sub-batch.
                     while self._segmented and rows_budget > 0:
-                        seg = await loop.run_in_executor(  # tunnelcheck: disable=TC07  one dispatch per prefill_rows-wide sub-batch of the iteration budget, back-to-back
-                            self._executor, self._dispatch_segments,
-                            rows_budget,
+                        seg = await self._offload(  # tunnelcheck: disable=TC07  one dispatch per prefill_rows-wide sub-batch of the iteration budget, back-to-back
+                            loop, self._dispatch_segments, rows_budget,
                         )
                         if seg is None:
                             break
                         segs.append(seg)
                         rows_budget -= len(seg[0])
                 elif self._segmented:
-                    seg = await loop.run_in_executor(
-                        self._executor, self._dispatch_segments
-                    )
+                    seg = await self._offload(loop, self._dispatch_segments)
                     if seg is not None:
                         segs.append(seg)
-                t_prefill = time.monotonic()
                 seg_rows = sum(len(s[0]) for s in segs)
 
                 if self._spec_usable() and any(self._active_mask):
@@ -5625,33 +5629,27 @@ class InferenceEngine(BlockDecodeMixin):
                     # — counts must be read before consumers can be fed, so
                     # there is no carry to pipeline.  Drain the pipelined
                     # plain burst first (mode switch mid-stream).
-                    global_flight.set_phase("decode_fetch")
                     if in_flight is not None:
+                        split.enter("decode_fetch")
                         outs_dev, assign, burst_rec = in_flight
-                        outs = await loop.run_in_executor(
-                            self._executor,
-                            lambda: jax.tree.map(
-                                np.asarray, jax.device_get(outs_dev)),
-                        )
+                        outs = await self._offload(
+                            loop, self._fetch, outs_dev)
+                        split.enter("process")
                         await self._process_burst(outs, assign)
                         self._trace_burst(burst_rec)
                         in_flight = None
-                    global_flight.set_phase("decode_dispatch")
-                    spec_out, spec_assign = await loop.run_in_executor(
-                        self._executor, self._dispatch_spec
-                    )
-                    t_spec = time.monotonic()
-                    global_flight.set_phase("process")
+                    split.enter("decode_dispatch")
+                    spec_out, spec_assign = await self._offload(
+                        loop, self._dispatch_spec)
+                    split.enter("process")
                     await self._process_spec(spec_out, spec_assign)
-                    global_flight.set_phase("segments")
+                    split.enter("segments")
                     for seg in segs:
                         await self._finish_segments(loop, seg)
+                    split.enter("drain")
                     await self._drain_conv_inserts(loop)
                     await self._drain_spill_outs(loop)
-                    self._flight_record(
-                        it_t0, t_admit, t_prefill, t_spec, t_spec,
-                        plain_rows, seg_rows, cold0,
-                    )
+                    self._flight_record(plain_rows, seg_rows, cold0)
                     continue
 
                 # Pipeline: dispatch burst n (returns immediately; carry stays
@@ -5663,34 +5661,22 @@ class InferenceEngine(BlockDecodeMixin):
                 # dead-peer timeout.  warmup() precompiles every variant; this
                 # is the belt to that suspender for consumers that skip it.
                 current = None
-                global_flight.set_phase("decode_dispatch")
+                split.enter("decode_dispatch")
                 if any(self._active_mask):
-                    outs_dev0, assign0 = await loop.run_in_executor(
-                        self._executor, self._dispatch_decode
-                    )
+                    outs_dev0, assign0 = await self._offload(
+                        loop, self._dispatch_decode)
                     current = (outs_dev0, assign0, self._last_dispatch)
-                t_dispatch = time.monotonic()
-                global_flight.set_phase("decode_fetch")
+                split.enter("decode_fetch")
                 if in_flight is not None:
                     outs_dev, assign, burst_rec = in_flight
-                    t0 = time.monotonic()
-                    outs = await loop.run_in_executor(
-                        self._executor,
-                        lambda: jax.tree.map(np.asarray, jax.device_get(outs_dev)),
-                    )
-                    # Decode-phase stall: how long the host waited for the
-                    # previous burst after dispatching the next one (0 ≈ the
-                    # RTT is fully hidden by pipelining).
-                    global_metrics.observe(
-                        "engine_decode_fetch_ms", (time.monotonic() - t0) * 1000.0
-                    )
-                    t_fetch = time.monotonic()
-                    global_flight.set_phase("process")
+                    # How long the host waited for the previous burst after
+                    # dispatching the next one is the record's wait_ms (0 ≈
+                    # the RTT is fully hidden by pipelining).
+                    outs = await self._offload(loop, self._fetch, outs_dev)
+                    split.enter("process")
                     await self._process_burst(outs, assign)
                     self._trace_burst(burst_rec)
-                else:
-                    t_fetch = t_dispatch
-                global_flight.set_phase("segments")
+                split.enter("segments")
                 for seg in segs:
                     # Fetched after the decode work above, so each segment
                     # sub-batch's device→host RTT rides under real compute
@@ -5699,6 +5685,7 @@ class InferenceEngine(BlockDecodeMixin):
                 # Conversation-cache inserts for slots that finished this
                 # iteration — BEFORE the next admission can re-prefill
                 # them (ISSUE 14; off the TTFT-critical path by position).
+                split.enter("drain")
                 await self._drain_conv_inserts(loop)
                 # Spill page-outs LAST (ISSUE 16): cold pages copied to
                 # the host tier after all of this iteration's serving
@@ -5706,10 +5693,7 @@ class InferenceEngine(BlockDecodeMixin):
                 # position as the conversation drain.
                 await self._drain_spill_outs(loop)
                 in_flight = current
-                self._flight_record(
-                    it_t0, t_admit, t_prefill, t_dispatch, t_fetch,
-                    plain_rows, seg_rows, cold0,
-                )
+                self._flight_record(plain_rows, seg_rows, cold0)
         except Exception:
             log.exception(
                 "engine loop crashed; failing %d in-flight requests",
@@ -5726,7 +5710,19 @@ class InferenceEngine(BlockDecodeMixin):
             for state in list(self._requests.values()):
                 state.queue.put_nowait(_CRASHED)
             raise
-        log.info("engine loop stopped")
+        finally:
+            # However it stops (stop(), a crash, or a serve process whose
+            # asyncio.run cancels the task on its way out): the run's log
+            # keeps the loop's totals (ISSUE 57).
+            global_gc.publish()
+            grown = [global_metrics.counter(name) - was
+                     for name, was in zip(sums, sums0)]
+            log.info(
+                "engine loop stopped: %d iterations recorded, host %.3f s, "
+                "waiting for the chip %.3f s, event-loop lag %.3f s, the "
+                "collector's pauses %.3f s (of the process, parks included)",
+                *grown,
+            )
 
 
 def _attention_section(m) -> Dict[str, object]:

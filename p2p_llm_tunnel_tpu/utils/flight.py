@@ -7,9 +7,10 @@ decode-stall watchdog trips.  This module is that third leg (ISSUE 12):
 
 - :class:`FlightRecorder` — a bounded, host-only, ALWAYS-ON ring holding
   one record per engine-loop iteration (mux budget inputs/outputs, decode
-  burst width, prefill rows dispatched, slot/tenant occupancy, the host
-  wall split).  Cheap enough to never be off: one dict + deque append per
-  iteration, no device traffic, no syscalls.  Exported as Chrome-trace
+  burst width, prefill rows dispatched, slot/tenant occupancy, and where
+  the iteration's wall went: :class:`IterationSplit`).  Cheap enough to
+  never be off: one dict + deque append per iteration, no device traffic,
+  no syscalls.  Exported as Chrome-trace
   slices (the whole record in each slice's args) through the existing
   ``/healthz?trace=1`` journal
   (so PR 9's fleet stitching yields per-peer engine lanes for free) and
@@ -27,6 +28,9 @@ decode-stall watchdog trips.  This module is that third leg (ISSUE 12):
   process, in a bounded list of its own that no request traffic can evict;
   exported on a ``startup`` lane of ``/healthz?trace=1`` and summed in the
   ``/healthz`` ``startup`` section.
+- :class:`GcWatch` — the collector's pauses, timed where they happen (one
+  ``gc.callbacks`` hook a process, ISSUE 57): what the loop's records, the
+  ``process_gc_*`` counters and the ``process.gc_pause`` spans are fed from.
 - :class:`BlackBox` — postmortem capture: on a watchdog trip, SLO breach,
   drain timeout, or fatal engine error, atomically snapshot {flight tail,
   scheduler/slot/tenant state, recent spans, metrics, EngineConfig} into
@@ -51,6 +55,7 @@ the explicitly-waived wall-clock fields (``WALLCLOCK_WAIVED`` + the
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -143,11 +148,56 @@ FLIGHT_SCHEMA: Dict[str, str] = {
         "at iteration end (ISSUE 13; nonzero while the engine is "
         "generating into replay journals with no channel attached)"
     ),
-    "admit_ms": "expire + admission host wall (waived)",
-    "prefill_ms": "prefill dispatch host wall (waived)",
-    "dispatch_ms": "decode-burst dispatch host wall (waived)",
-    "fetch_ms": "previous-burst device->host fetch wall (waived)",
-    "process_ms": "token accounting + segment finish wall (waived)",
+    # -- where the iteration's wall went (ISSUE 57; all waived) ------------
+    # Seven parts tile the iteration: their sum is dur_ms to the rounding.
+    "admit_ms": (
+        "part: expiry, admission, the pool's reservation and eviction, "
+        "page-ins, the mux wave's plan"
+    ),
+    "prefill_ms": "part: prefill dispatch (whole-prompt waves, segments)",
+    "dispatch_ms": "part: decode-burst dispatch",
+    "fetch_ms": "part: the previous burst's device->host fetch",
+    "process_ms": (
+        "part: token accounting of the fetched block (_process_burst, "
+        "_trace_burst)"
+    ),
+    "segments_ms": (
+        "part: _finish_segments over the iteration's prefill segments "
+        "(their fetch, first tokens, pool inserts)"
+    ),
+    "drain_ms": (
+        "part: the end-of-iteration drains (_drain_conv_inserts, "
+        "_drain_spill_outs)"
+    ),
+    "at_ms": (
+        "loop phase (the phase marker's names) -> offset of its FIRST start "
+        "from t, ms: lays the parts on the clock whatever their order"
+    ),
+    "wait_ms": (
+        "wall inside the iteration's blocking device->host fetches (the "
+        "burst's, each segment's, each whole-prompt wave's), measured "
+        "around the calls in the executor thread: the host waited for the "
+        "chip.  dur_ms - wait_ms is the iteration's HOST time"
+    ),
+    "waits_ms": "[offset from t, length] of each such fetch, ms",
+    "exec_ms": (
+        "the iteration's executor calls' own wall, in the executor thread"
+    ),
+    "lag_ms": (
+        "sum over those calls of (the await's wall - the call's own): how "
+        "long the loop's coroutine waited for the event loop (or a busy "
+        "executor) around work that was done"
+    ),
+    "evict_ms": (
+        "wall of the pool's reservations that evicted, inside admission "
+        "(_reserve_pages -> PrefixIndex.reserve); 0 where none did"
+    ),
+    "evicted_pages": "pages those reservations evicted",
+    "gc_ms": "the collector's pause time that fell inside the iteration",
+    "gc_full": (
+        "how many of those collections were of the oldest generation "
+        "(follows the allocator, waived)"
+    ),
 }
 
 #: The one catalogue of legal start-up journal field names (tunnelcheck
@@ -382,6 +432,12 @@ WALLCLOCK_WAIVED = frozenset({
     # a compile event's thread name and whether the machine's disk held
     # the executable (STARTUP_SCHEMA): facts of the process, not the run
     "thread", "persistent_hit",
+    # what the collector did inside an iteration (FLIGHT_SCHEMA), and the
+    # counters that sum the clock's and the collector's doings (ISSUE 57)
+    "gc_full",
+    "engine_loop_host_seconds_total", "engine_loop_wait_seconds_total",
+    "engine_loop_lag_seconds_total", "process_gc_pause_seconds_total",
+    "process_gc_collections_total", "process_gc_full_collections_total",
 })
 #: Field-name suffixes waived as wall-clock derived (``engine_ttft_ms``,
 #: ``engine_warmup_compile_s``, ``tenant_tokens_per_s``, ...); the
@@ -412,6 +468,190 @@ def postmortem_canonical(obj: object) -> object:
     return obj
 
 
+#: The loop's phases (its phase marker's names) and the record's part each
+#: one's wall is summed into.  The parts tile the iteration.
+LOOP_PARTS: Dict[str, str] = {
+    "admit": "admit_ms",
+    "prefill_dispatch": "prefill_ms",
+    "decode_dispatch": "dispatch_ms",
+    "decode_fetch": "fetch_ms",
+    "process": "process_ms",
+    "segments": "segments_ms",
+    "drain": "drain_ms",
+}
+#: An iteration whose HOST time (dur_ms - wait_ms) reaches this logs its
+#: split, so an untraced run's serve.log names its own holes ...
+LONG_HOLD_MS = 50.0
+#: ... at most one line in this many seconds.
+LONG_HOLD_EVERY_S = 1.0
+#: With the span journal on, a collection of the oldest generation or one
+#: this long writes a ``process.gc_pause`` span.
+GC_SPAN_MIN_S = 0.001
+
+
+def _ms(seconds: float) -> float:
+    return round(seconds * 1000.0, 3)
+
+
+class GcWatch:
+    """The collector's pauses, timed where they happen (ISSUE 57).
+
+    One ``gc.callbacks`` hook a process, installed when the first engine
+    starts.  The hook runs INSIDE a collection, which any allocation may
+    start, also one made under the metrics registry's or the span journal's
+    lock: so it takes no lock and calls nothing that does.  It adds to plain
+    totals (collections never overlap: the interpreter runs one at a time,
+    under its lock) and keeps the pauses worth a span; :meth:`publish`,
+    called from outside the collector (the engine loop at every record and
+    park, the ``/metrics`` and ``/healthz?trace=1`` handlers), moves the
+    growth into the ``process_gc_*`` counters and the kept pauses into the
+    journal.  It changes no threshold and freezes nothing."""
+
+    def __init__(self) -> None:
+        self.pause_s = 0.0
+        self.collections = 0
+        self.full = 0
+        self._began = 0.0
+        self._published = (0.0, 0, 0)
+        self._pauses: Deque[Tuple[float, float, int, int]] = deque(maxlen=4096)
+        self._tracer = None
+        self._lock = threading.Lock()  # publish()'s alone
+
+    def install(self) -> None:
+        """Hook the collector (once a process; later calls do nothing)."""
+        from p2p_llm_tunnel_tpu.utils.tracing import global_tracer
+
+        with self._lock:
+            if self._tracer is None:
+                self._tracer = global_tracer
+                gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        now = time.monotonic()
+        if phase == "start":
+            self._began = now
+            return
+        pause = now - self._began
+        full = info.get("generation") == 2
+        self.pause_s += pause
+        self.collections += 1
+        self.full += full
+        if self._tracer.enabled and (full or pause >= GC_SPAN_MIN_S):
+            self._pauses.append((self._began, pause, info.get("generation"),
+                                 info.get("collected")))
+
+    def totals(self) -> Tuple[float, int]:
+        """(pause seconds, collections of the oldest generation) so far:
+        the loop reads it at an iteration's two ends."""
+        return self.pause_s, self.full
+
+    def publish(self) -> None:
+        with self._lock:
+            now = (self.pause_s, self.collections, self.full)
+            grown = [a - b for a, b in zip(now, self._published)]
+            self._published = now
+            for name, amount in zip(
+                    ("process_gc_pause_seconds_total",
+                     "process_gc_collections_total",
+                     "process_gc_full_collections_total"), grown):
+                if amount:
+                    global_metrics.inc(name, amount)
+            while self._pauses:
+                began, pause, generation, collected = self._pauses.popleft()
+                self._tracer.add_span(
+                    "process.gc_pause", trace_id=None, track="process",
+                    t0=began, t1=began + pause,
+                    attrs={"generation": generation, "collected": collected},
+                )
+
+
+class IterationSplit:
+    """Where one engine-loop iteration's wall went (ISSUE 57): made at the
+    iteration's start, fed by the loop as it goes, read once into the
+    iteration's flight record.  A dozen ``time.monotonic()`` calls and a
+    few small containers an iteration; always on, as the ring is.
+
+    - :meth:`enter` moves the loop's phase marker and closes the part that
+      ran: the parts (``LOOP_PARTS``) tile the iteration;
+    - :meth:`call` is the loop's ``run_in_executor``: the call's own wall
+      in the executor thread, and how much longer the loop's coroutine
+      waited for it (the event loop's lag);
+    - :meth:`fetched` (executor thread) notes a blocking device->host
+      fetch: the host waited for the chip;
+    - :meth:`evicted` notes a reservation that evicted pool pages."""
+
+    def __init__(self, recorder: "FlightRecorder") -> None:
+        self.t0 = self._since = time.monotonic()
+        self._recorder = recorder
+        self._phase: Optional[str] = None
+        self._parts = dict.fromkeys(LOOP_PARTS, 0.0)
+        self._at: Dict[str, float] = {}
+        self._waits: List[Tuple[float, float]] = []
+        self._exec_s = self._lag_s = self._evict_s = 0.0
+        self._evicted = 0
+        self._gc0 = global_gc.totals()
+
+    def enter(self, phase: str) -> None:
+        if self._phase is not None:  # (the first phase opens at t0)
+            now = time.monotonic()
+            self._parts[self._phase] += now - self._since
+            self._since = now
+        self._phase = phase
+        self._at.setdefault(phase, self._since - self.t0)
+        self._recorder.set_phase(phase)
+
+    async def call(self, loop, executor, fn: Callable, *args: object):
+        """``await loop.run_in_executor(executor, fn, *args)``, timed."""
+        asked = time.monotonic()
+        own = [0.0]
+
+        def run():
+            began = time.monotonic()
+            try:
+                return fn(*args)
+            finally:
+                own[0] = time.monotonic() - began
+
+        try:
+            return await loop.run_in_executor(executor, run)
+        finally:
+            self._exec_s += own[0]
+            self._lag_s += max(0.0, time.monotonic() - asked - own[0])
+
+    def fetched(self, began: float) -> None:
+        self._waits.append((began, time.monotonic() - began))
+
+    def evicted(self, began: float, pages: int) -> None:
+        self._evict_s += time.monotonic() - began
+        self._evicted += pages
+
+    def fields(self) -> Dict[str, object]:
+        """The record's FLIGHT_SCHEMA fields of the split, the iteration
+        ending now."""
+        now = time.monotonic()
+        if self._phase is not None:
+            self._parts[self._phase] += now - self._since
+            self._since = now
+        gc_s, gc_full = global_gc.totals()
+        out: Dict[str, object] = {
+            "t": self.t0,
+            "dur_ms": _ms(now - self.t0),
+            "at_ms": {phase: _ms(at) for phase, at in self._at.items()},
+            "wait_ms": _ms(sum(length for _t, length in self._waits)),
+            "waits_ms": [[_ms(began - self.t0), _ms(length)]
+                         for began, length in self._waits],
+            "exec_ms": _ms(self._exec_s),
+            "lag_ms": _ms(self._lag_s),
+            "evict_ms": _ms(self._evict_s),
+            "evicted_pages": self._evicted,
+            "gc_ms": _ms(gc_s - self._gc0[0]),
+            "gc_full": gc_full - self._gc0[1],
+        }
+        for phase, field in LOOP_PARTS.items():
+            out[field] = _ms(self._parts[phase])
+        return out
+
+
 class FlightRecorder:
     """Bounded, thread-safe, always-on ring of engine-loop iteration
     records, plus the loop's current-phase marker (what the watchdog
@@ -427,6 +667,8 @@ class FlightRecorder:
         self._records: Deque[Dict[str, object]] = deque(maxlen=self.capacity)
         self._iter = 0
         self._phase = "idle"
+        self._hold_said = float("-inf")
+        self._holds_unsaid = 0
 
     def configure(self, *, capacity: Optional[int] = None) -> None:
         with self._lock:
@@ -439,6 +681,8 @@ class FlightRecorder:
             self._records.clear()
             self._iter = 0
             self._phase = "idle"
+            self._hold_said = float("-inf")
+            self._holds_unsaid = 0
 
     # -- phase marker ------------------------------------------------------
 
@@ -469,6 +713,39 @@ class FlightRecorder:
             rec.update(fields)
             self._records.append(rec)
         global_metrics.inc("engine_flight_iterations_total")
+        if "wait_ms" in fields:
+            self._note_host_time(rec)
+
+    def _note_host_time(self, rec: Dict[str, object]) -> None:
+        """What an UNTRACED run leaves behind of the split (ISSUE 57): the
+        three sums on ``/metrics``, and one INFO line for an iteration that
+        held the loop for LONG_HOLD_MS of host time, at most one in
+        LONG_HOLD_EVERY_S on the records' own clock."""
+        dur, wait = float(rec["dur_ms"]), float(rec["wait_ms"])
+        host = max(0.0, dur - wait)
+        global_metrics.inc("engine_loop_host_seconds_total", host / 1000.0)
+        global_metrics.inc("engine_loop_wait_seconds_total", wait / 1000.0)
+        global_metrics.inc("engine_loop_lag_seconds_total",
+                           float(rec.get("lag_ms", 0.0)) / 1000.0)
+        if host < LONG_HOLD_MS:
+            return
+        end = float(rec["t"]) + dur / 1000.0
+        if end - self._hold_said < LONG_HOLD_EVERY_S:
+            self._holds_unsaid += 1
+            return
+        log.info(
+            "engine loop: iteration %d held the loop %.1f ms of host time "
+            "(dur %.1f, wait %.1f; %s; exec %.1f lag %.1f evict %.1f/%d "
+            "pages gc %.1f/%d full; %d more such since the last line)",
+            rec["iter"], host, dur, wait,
+            " ".join(f"{phase} {rec.get(part, 0.0):.1f}"
+                     for phase, part in LOOP_PARTS.items()),
+            rec.get("exec_ms", 0.0), rec.get("lag_ms", 0.0),
+            rec.get("evict_ms", 0.0), rec.get("evicted_pages", 0),
+            rec.get("gc_ms", 0.0), rec.get("gc_full", 0),
+            self._holds_unsaid,
+        )
+        self._hold_said, self._holds_unsaid = end, 0
 
     # -- reading -----------------------------------------------------------
 
@@ -486,7 +763,8 @@ class FlightRecorder:
     def chrome_events(self) -> List[Dict[str, object]]:
         """The ring as Chrome trace events: one ``ph:"X"`` slice per
         iteration on an ``engine-flight`` lane (args = the full record:
-        what ``scripts/traceview.py --flight`` and the benchmark read).
+        what ``scripts/traceview.py --flight`` and the benchmark's readers
+        ``layer_metrics/loop_host.py`` and ``idle_by_phase.py`` read).
         Merged into the ``/healthz?trace=1`` export by the serve loop, so
         the fleet stitcher gives every peer its own engine-flight lane."""
         recs = self.records()
@@ -959,6 +1237,7 @@ class BlackBox:
 
 
 #: Process-wide singletons (the global_metrics/global_tracer convention).
+global_gc = GcWatch()
 global_flight = FlightRecorder()
 global_compile_watch = CompileWatch()
 global_blackbox = BlackBox()

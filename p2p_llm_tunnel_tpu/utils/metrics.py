@@ -189,6 +189,30 @@ METRICS_CATALOG: Dict[str, str] = {
         "postmortem black-box bundles captured (counter; triggers: "
         "watchdog trip, SLO breach, drain timeout, engine crash)"
     ),
+    # -- the loop's host time (ISSUE 57): sums over the flight records ----
+    "engine_loop_host_seconds_total": (
+        "seconds of the recorded iterations the host needed for itself: "
+        "dur_ms - wait_ms summed (counter; over the wall between two "
+        "scrapes: the share of the time the loop held the host)"
+    ),
+    "engine_loop_wait_seconds_total": (
+        "seconds the recorded iterations spent inside blocking "
+        "device->host fetches: the host waited for the chip (counter)"
+    ),
+    "engine_loop_lag_seconds_total": (
+        "seconds the loop's coroutine waited for its event loop after an "
+        "executor call's work was done, summed over the recorded "
+        "iterations (counter; grows with the stream writers sharing the "
+        "loop)"
+    ),
+    "process_gc_pause_seconds_total": (
+        "seconds Python's collector held this process, from its own "
+        "callbacks (counter; utils/flight.py GcWatch)"
+    ),
+    "process_gc_collections_total": "collections of any generation (counter)",
+    "process_gc_full_collections_total": (
+        "collections of the oldest generation (counter)"
+    ),
     "engine_ttft_ms": "time to first token per request (histogram, ms)",
     "engine_queue_wait_ms": (
         "submit -> decode-slot admission wait per request (histogram, ms; "
@@ -200,7 +224,6 @@ METRICS_CATALOG: Dict[str, str] = {
         "park time)"
     ),
     "engine_prefill_ms": "prefill step latency (histogram, ms)",
-    "engine_decode_fetch_ms": "device->host fetch of a sampled block (histogram, ms)",
     # -- serve endpoint --------------------------------------------------
     "serve_requests_total": "tunneled requests dispatched to the backend (counter)",
     "serve_timeouts_total": "requests cut by x-tunnel-deadline-ms (counter)",

@@ -159,6 +159,13 @@ SPAN_CATALOG: Dict[str, str] = {
         "a hole in the warmup bucket grid; attrs carry the program key "
         "(instant; ISSUE 12 cold-start profiler)"
     ),
+    # -- the process (ISSUE 57) ------------------------------------------
+    "process.gc_pause": (
+        "one pause of Python's collector, timed by its own callbacks "
+        "(utils/flight.py GcWatch): written for a collection of the oldest "
+        "generation or one of 1 ms or more; attrs generation, collected; "
+        "track process"
+    ),
     # -- start-up (ISSUE 40) ---------------------------------------------
     # Written to the start-up journal (utils/flight.py CompileWatch), not
     # to this recorder's rings: always on, never evicted, exported on the

@@ -224,10 +224,13 @@ def summarize_flight(trace: dict, tail: int = 12) -> dict:
 
     Returns aggregates over every iteration in the capture — totals of
     admitted/prefill/decode work, budget and queue-depth distribution,
-    cold-compile count — plus the last ``tail`` raw records (the part a
-    postmortem reader scans first)."""
+    cold-compile count, where the iterations' wall went (``split``, ISSUE
+    57) — plus the last ``tail`` raw records (the part a postmortem reader
+    scans first)."""
+    from p2p_llm_tunnel_tpu.utils.flight import LOOP_PARTS
     from p2p_llm_tunnel_tpu.utils.tracing import validate_chrome_trace
 
+    parts = tuple(LOOP_PARTS.values())
     validate_chrome_trace(trace)
     rows = sorted(
         (
@@ -243,7 +246,34 @@ def summarize_flight(trace: dict, tail: int = 12) -> dict:
 
     budgets = col("budget_tokens")
     queue = col("queue_depth")
+    # Where the iterations' wall went (ISSUE 57): the seven parts that
+    # tile an iteration, and what lies across them.  Records from before
+    # the split (no wait_ms) give no such section.
+    split = None
+    timed = [a for a in args if "wait_ms" in a]
+    if timed:
+        def total(key):
+            return round(sum(float(a.get(key) or 0.0) for a in timed), 3)
+
+        dur, wait = total("dur_ms"), total("wait_ms")
+        longest = max(timed, key=lambda a: (float(a["dur_ms"])
+                                            - float(a["wait_ms"])))
+        split = {
+            "iterations": len(timed),
+            "dur_ms": dur,
+            "host_ms": round(dur - wait, 3),
+            "host_share_pct": 100.0 * (dur - wait) / dur if dur else None,
+            "parts_ms": {part: total(part) for part in parts},
+            **{key: total(key) for key in
+               ("wait_ms", "exec_ms", "lag_ms", "evict_ms", "gc_ms")},
+            "evicted_pages": int(total("evicted_pages")),
+            "gc_full": int(total("gc_full")),
+            "longest_hold": {k: longest.get(k) for k in
+                             ("iter", "dur_ms", "wait_ms", "lag_ms",
+                              "evict_ms", "gc_ms", *parts)},
+        }
     return {
+        "split": split,
         "iterations": len(rows),
         "admitted_total": sum(col("admitted")),
         "prefill_rows_total": sum(col("prefill_rows")),
@@ -267,6 +297,29 @@ def _print_flight(out: dict) -> None:
         f"{out['budget_tokens_p50']}, active slots max "
         f"{out['active_slots_max']}"
     )
+    split = out.get("split")
+    if split:
+        share = split["host_share_pct"]
+        print(
+            f"  wall {split['dur_ms']:.1f} ms over {split['iterations']} "
+            f"iteration(s): host {split['host_ms']:.1f} ms"
+            + (f" ({share:.1f} %)" if share is not None else "")
+            + f", waiting for the chip {split['wait_ms']:.1f} ms")
+        print("  parts: " + ", ".join(
+            f"{part[:-3]} {ms:.1f}" for part, ms in split["parts_ms"].items()))
+        print(
+            f"  executor calls {split['exec_ms']:.1f} ms, event-loop lag "
+            f"{split['lag_ms']:.1f} ms, eviction {split['evict_ms']:.1f} ms "
+            f"({split['evicted_pages']} pages), collector "
+            f"{split['gc_ms']:.1f} ms ({split['gc_full']} full)")
+        hold = split["longest_hold"]
+        print(
+            f"  longest hold: iteration {hold['iter']}, "
+            f"{hold['dur_ms'] - hold['wait_ms']:.1f} ms of host time ("
+            + ", ".join(f"{part[:-3]} {hold[part]}"
+                        for part in split["parts_ms"])
+            + f"; lag {hold['lag_ms']}, evict {hold['evict_ms']}, gc "
+            f"{hold['gc_ms']})")
     if not out["tail"]:
         return
     cols = ("iter", "queue_depth", "backlog_rows", "budget_tokens",

@@ -9,10 +9,17 @@ widths.
 
 import asyncio
 import contextlib
+import gc
 
 import pytest
 
 from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
+from p2p_llm_tunnel_tpu.utils.flight import (
+    FLIGHT_SCHEMA,
+    LOOP_PARTS,
+    global_flight,
+    global_gc,
+)
 from p2p_llm_tunnel_tpu.utils.metrics import METRICS_CATALOG, global_metrics
 from p2p_llm_tunnel_tpu.utils.tracing import (
     SPAN_CATALOG,
@@ -28,6 +35,15 @@ COUNTERS = (
     "engine_decode_kernel_steps_total",
     "engine_decode_state_kernel_steps_total",
 )
+#: the sums an untraced run leaves of its loop's host time and of the
+#: collector (ISSUE 57)
+HOST_COUNTERS = (
+    "engine_loop_host_seconds_total", "engine_loop_wait_seconds_total",
+    "engine_loop_lag_seconds_total", "process_gc_pause_seconds_total",
+    "process_gc_collections_total", "process_gc_full_collections_total",
+)
+#: flight records and the growth of HOST_COUNTERS of each run_engine(trace)
+FLIGHT = {}
 CHUNK = 16
 SLOTS = 4
 ROWS = 2
@@ -72,8 +88,24 @@ async def _drive(engine, trace: bool, delay_shared: bool = True):
     return await asyncio.gather(*jobs)
 
 
+def collect_once(engine):
+    """Force ONE collection of every generation, inside the loop's third
+    decode dispatch (the executor thread, the middle of an iteration)."""
+    dispatch, calls = engine._dispatch_decode, [0]
+
+    def dispatching():
+        calls[0] += 1
+        if calls[0] == 3:
+            gc.collect()
+        return dispatch()
+
+    engine._dispatch_decode = dispatching
+
+
 def run_engine(trace: bool, patch=None):
-    """Counter growth, journal records and emitted-token counts of one run."""
+    """Counter growth, journal records and emitted-token counts of one run.
+    The collector runs once, where ``collect_once`` says (its own schedule
+    is off meanwhile), and the run's flight records are kept in FLIGHT."""
     async def main():
         engine = InferenceEngine(engine_cfg=EngineConfig(
             model="tiny", num_slots=SLOTS, max_seq=256, dtype="float32",
@@ -82,22 +114,31 @@ def run_engine(trace: bool, patch=None):
         ))
         if patch:
             patch(engine)
+        collect_once(engine)
         await engine.start()
         try:
-            before = {c: global_metrics.counter(c) for c in COUNTERS}
+            global_gc.publish()  # (collections from before this run)
+            before = {c: global_metrics.counter(c)
+                      for c in COUNTERS + HOST_COUNTERS}
             emitted = await _drive(engine, trace)
             # the burst dispatched under the last tokens is fetched, and its
             # record closed, by the loop's next pass
             await asyncio.sleep(0.3)
             grown = {c: global_metrics.counter(c) - before[c]
-                     for c in COUNTERS}
+                     for c in before}
         finally:
             await engine.stop()
         return grown, emitted
 
-    with tracing(trace):
-        grown, emitted = asyncio.run(main())
-        return grown, global_tracer.records(), emitted
+    global_flight.reset()
+    gc.disable()
+    try:
+        with tracing(trace):
+            grown, emitted = asyncio.run(main())
+            FLIGHT[trace] = (global_flight.records(), grown)
+            return grown, global_tracer.records(), emitted
+    finally:
+        gc.enable()
 
 
 @pytest.fixture(scope="module")
@@ -111,10 +152,13 @@ def named(records, name):
 
 def test_new_names_are_catalogued():
     for name in ("engine.prefill_part", "engine.pool_copy",
-                 "engine.prefill_segment", "engine.decode_burst"):
+                 "engine.prefill_segment", "engine.decode_burst",
+                 "process.gc_pause"):
         assert name in SPAN_CATALOG
-    for name in COUNTERS:
+    for name in COUNTERS + HOST_COUNTERS:
         assert name in METRICS_CATALOG
+    # (ISSUE 57: the flight record's fetch_ms / wait_ms say it, and more)
+    assert "engine_decode_fetch_ms" not in METRICS_CATALOG
 
 
 def test_tc09_passes_on_the_engine():
@@ -246,6 +290,72 @@ def test_with_the_recorder_off_nothing_is_built_and_counters_still_count():
         SLOTS * grown["engine_decode_steps_total"] > 0
     assert 0 < grown["engine_decode_row_steps_total"] <= \
         grown["engine_decode_slot_steps_total"]
+
+
+@pytest.mark.parametrize("trace", [True, False], ids=["traced", "untraced"])
+def test_a_flight_record_says_where_its_iterations_wall_went(traced, trace):
+    """ISSUE 57, on the runs this file makes anyway: the parts tile the
+    iteration, the phases' starts are in the loop's order, the waits lie in
+    the fetches, and what the executor and the event loop took is inside
+    the wall.  Always on: the journal's switch changes nothing here.  (The
+    untraced run is the one the test above has made.)"""
+    if trace not in FLIGHT:
+        run_engine(trace=False)
+    records, grown = FLIGHT[trace]
+    assert len(records) > 10
+    phases = list(LOOP_PARTS)
+    for rec in records:
+        assert set(rec) <= set(FLIGHT_SCHEMA)
+        parts = [rec[part] for part in LOOP_PARTS.values()]
+        assert abs(sum(parts) - rec["dur_ms"]) <= 0.001 * len(parts), rec
+        at = rec["at_ms"]
+        assert at["admit"] == 0.0 and set(at) <= set(phases)
+        starts = [at[p] for p in phases if p in at]
+        assert starts == sorted(starts), at
+        assert abs(sum(w[1] for w in rec["waits_ms"]) - rec["wait_ms"]) < 0.01
+        # no whole-prompt wave in this engine (mux, chunked): a burst's
+        # fetch and each segment's are all its blocking fetches
+        assert rec["wait_ms"] <= rec["fetch_ms"] + rec["segments_ms"] + 0.01
+        assert all(0 <= at_ms and at_ms + length <= rec["dur_ms"] + 0.01
+                   for at_ms, length in rec["waits_ms"])
+        assert 0 <= rec["lag_ms"] and 0 <= rec["exec_ms"]
+        assert rec["exec_ms"] + rec["lag_ms"] <= rec["dur_ms"] + 0.01
+        assert rec["evict_ms"] == 0 and rec["evicted_pages"] == 0
+    assert any(rec["wait_ms"] > 0 for rec in records)
+    assert sum(rec["exec_ms"] > 0 for rec in records) > len(records) // 2
+    assert any(rec["segments_ms"] > 0 for rec in records)
+    # the sums an untraced run leaves: the records', to the rounding
+    dur = sum(r["dur_ms"] for r in records) / 1e3
+    wait = sum(r["wait_ms"] for r in records) / 1e3
+    assert grown["engine_loop_wait_seconds_total"] == pytest.approx(wait)
+    assert grown["engine_loop_host_seconds_total"] == pytest.approx(
+        dur - wait, abs=1e-3)
+    assert grown["engine_loop_lag_seconds_total"] == pytest.approx(
+        sum(r["lag_ms"] for r in records) / 1e3)
+
+
+@pytest.mark.parametrize("trace", [True, False], ids=["traced", "untraced"])
+def test_a_collection_lands_in_one_record_three_counters_and_one_span(
+        traced, trace):
+    if trace not in FLIGHT:
+        run_engine(trace=False)
+    records, grown = FLIGHT[trace]
+    hit = [r for r in records if r["gc_ms"] > 0]
+    assert len(hit) == 1 and hit[0]["gc_full"] == 1
+    assert hit[0]["gc_ms"] <= hit[0]["dispatch_ms"] + 0.01
+    assert sum(r["gc_full"] for r in records) == 1
+    assert grown["process_gc_collections_total"] == 1
+    assert grown["process_gc_full_collections_total"] == 1
+    assert grown["process_gc_pause_seconds_total"] == pytest.approx(
+        hit[0]["gc_ms"] / 1e3, abs=1e-5)
+    if trace:
+        pauses = named(traced[1], "process.gc_pause")
+        assert len(pauses) == 1 and pauses[0].track == "process"
+        assert pauses[0].attrs["generation"] == 2
+        assert "collected" in pauses[0].attrs
+        assert pauses[0].dur == pytest.approx(hit[0]["gc_ms"] / 1e3, abs=1e-5)
+        start = pauses[0].ts - hit[0]["t"]
+        assert 0 <= start <= hit[0]["dur_ms"] / 1e3
 
 
 def test_the_annotation_is_named_as_the_span_and_carries_the_join_keys():

@@ -16,10 +16,14 @@ Three layers, matching where the machinery lives:
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
+import gc
 import json
+import logging
 import os
 import random
 import threading
+import time
 
 import pytest
 
@@ -28,12 +32,15 @@ from p2p_llm_tunnel_tpu.testing.frame_client import FrameClient
 from p2p_llm_tunnel_tpu.transport import loopback_pair
 from p2p_llm_tunnel_tpu.utils.flight import (
     FLIGHT_SCHEMA,
+    LOOP_PARTS,
     POSTMORTEM_SCHEMA,
     STARTUP_PHASES,
     STARTUP_SCHEMA,
     BlackBox,
     CompileWatch,
     FlightRecorder,
+    GcWatch,
+    IterationSplit,
     global_blackbox,
     global_compile_watch,
     global_flight,
@@ -209,21 +216,199 @@ def test_startup_journal_chrome_events_ride_a_lane_of_their_own():
             assert set(e["args"]) <= set(STARTUP_SCHEMA)
 
 
-def test_startup_records_survive_300000_request_ring_events():
-    """The request ring of a traced benchmark run (262,144 records) turns
-    over; the journal's list is its own and is never evicted."""
+def test_startup_records_survive_a_request_ring_that_turns_over():
+    """The request ring of a traced run turns over (1,200 events through a
+    ring of 1,024; a benchmark run's holds 262,144); the journal's list is
+    its own and is never evicted."""
     _a_start(global_compile_watch)
     before = global_compile_watch.chrome_events()
-    global_tracer.configure(enabled=True, capacity=262144)
+    global_tracer.configure(enabled=True, capacity=1024)
     try:
-        for i in range(300_000):
+        for i in range(1200):
             global_tracer.add_event("engine.first_token", trace_id="ab" * 8)
-        assert len(global_tracer.records()) == 262144
+        assert len(global_tracer.records()) == 1024
         assert global_compile_watch.chrome_events() == before
         assert global_compile_watch.startup_section()["programs"] == 2
     finally:
         global_tracer.configure(enabled=False, capacity=4096)
         global_tracer.clear()
+
+
+# -- where an iteration's wall went (ISSUE 57) -------------------------------
+
+
+class _SlowHandback(concurrent.futures.Executor):
+    """A stubbed executor: the call runs at once, on a thread of its own,
+    and its result is handed back 50 ms after the work was done: what an
+    event loop busy with its stream writers does to the engine loop."""
+
+    def submit(self, fn, *args):
+        future = concurrent.futures.Future()
+
+        def work():
+            result = fn(*args)
+            time.sleep(0.05)
+            future.set_result(result)
+
+        threading.Thread(target=work).start()
+        return future
+
+
+def test_iteration_split_tiles_the_wall_and_times_a_stubbed_executor():
+    rec = FlightRecorder(capacity=4)
+
+    def slow(x):
+        time.sleep(0.02)
+        return x
+
+    def fetch(split):  # what the executor thread does around a device_get
+        began = time.monotonic()
+        time.sleep(0.01)
+        split.fetched(began)
+
+    async def main():
+        loop = asyncio.get_running_loop()
+        split = IterationSplit(rec)
+        split.enter("admit")
+        assert rec.current_phase() == "admit"
+        split.evicted(time.monotonic() - 0.003, 5)
+        await asyncio.sleep(0.01)
+        split.enter("decode_dispatch")
+        assert await split.call(loop, _SlowHandback(), slow, 7) == 7
+        split.enter("decode_fetch")
+        fetch(split)
+        split.enter("process")
+        split.enter("admit")  # a phase entered again adds to its part
+        await asyncio.sleep(0.002)
+        return split.fields()
+
+    fields = asyncio.run(main())
+    assert set(fields) <= set(FLIGHT_SCHEMA)
+    assert set(LOOP_PARTS.values()) <= set(fields)
+    parts = [fields[part] for part in LOOP_PARTS.values()]
+    assert abs(sum(parts) - fields["dur_ms"]) <= 0.01 * len(parts)
+    assert fields["admit_ms"] >= 10 + 2 and fields["prefill_ms"] == 0
+    assert fields["exec_ms"] >= 20
+    assert fields["lag_ms"] >= 45  # the await's wall less the call's own
+    assert fields["dispatch_ms"] >= fields["exec_ms"] + fields["lag_ms"] - 0.01
+    at = fields["at_ms"]
+    assert list(at) == ["admit", "decode_dispatch", "decode_fetch", "process"]
+    assert at["admit"] < at["decode_dispatch"] < at["decode_fetch"]
+    (wait_at, wait_len), = fields["waits_ms"]
+    assert wait_len == fields["wait_ms"] >= 10
+    assert at["decode_fetch"] <= wait_at
+    assert wait_at + wait_len <= at["process"] + 0.01
+    assert fields["evict_ms"] >= 3 and fields["evicted_pages"] == 5
+    assert fields["gc_ms"] >= 0 and fields["gc_full"] >= 0
+    rec.record_iteration(**fields)  # every field is the schema's
+    assert rec.records()[0]["wait_ms"] == fields["wait_ms"]
+
+
+def test_a_long_hold_is_said_once_a_second_on_the_records_own_clock(caplog):
+    from p2p_llm_tunnel_tpu.utils import flight
+
+    rec = FlightRecorder(capacity=8)
+    sums = ("engine_loop_host_seconds_total",
+            "engine_loop_wait_seconds_total", "engine_loop_lag_seconds_total")
+    before = [global_metrics.counter(name) for name in sums]
+    with caplog.at_level(logging.INFO, logger=flight.log.name):
+        for t, dur, wait in (
+                (100.0, 49.0, 0.0),    # under LONG_HOLD_MS of host time
+                (100.1, 80.0, 40.0),   # 40 ms of it: the rest was the chip
+                (100.2, 80.0, 10.0),   # 70 ms: said
+                (100.5, 300.0, 0.0),   # inside the second: counted, unsaid
+                (101.0, 60.0, 0.0),    # ends at 101.06 < 100.28 + 1
+                (101.3, 60.0, 0.0)):   # a second on: said, with the count
+            rec.record_iteration(t=t, dur_ms=dur, wait_ms=wait, lag_ms=1.5)
+    said = [r.getMessage() for r in caplog.records
+            if "held the loop" in r.getMessage()]
+    assert len(said) == 2
+    assert "iteration 3 held the loop 70.0 ms" in said[0]
+    assert "0 more such" in said[0]
+    assert "iteration 6" in said[1] and "2 more such" in said[1]
+    grown = [global_metrics.counter(name) - was
+             for name, was in zip(sums, before)]
+    assert grown == pytest.approx([0.579, 0.050, 0.009])
+    assert flight.LONG_HOLD_MS == 50.0 and flight.LONG_HOLD_EVERY_S == 1.0
+    # a record from before the split (no wait_ms) adds nothing
+    rec.record_iteration(t=200.0, dur_ms=500.0)
+    assert global_metrics.counter(sums[0]) - before[0] == pytest.approx(0.579)
+
+
+def test_gc_watch_counts_each_collection_and_writes_a_span_with_the_journal_on():
+    names = ("process_gc_pause_seconds_total", "process_gc_collections_total",
+             "process_gc_full_collections_total")
+    watch = GcWatch()
+    watch.install()
+    watch.install()  # once a process
+    assert gc.callbacks.count(watch._on_gc) == 1
+    was_on = gc.isenabled()
+    gc.disable()  # only the collections this test forces
+    try:
+        before = [global_metrics.counter(name) for name in names]
+        gc.collect(0)
+        gc.collect(1)  # (a collection of every generation takes seconds in
+        #                a test worker's heap: this test makes one, below)
+        assert watch.totals()[1] == 0 and watch.collections == 2
+        # the hook itself publishes nothing: it may run under any lock
+        assert [global_metrics.counter(n) for n in names] == before
+        watch.publish()
+        grown = [global_metrics.counter(n) - b for n, b in zip(names, before)]
+        assert grown[0] == pytest.approx(watch.pause_s) and grown[0] > 0
+        assert grown[1:] == [2, 0]
+        assert global_tracer.records() == []  # the journal is off
+        global_tracer.configure(enabled=True)
+        try:
+            t0 = time.monotonic()
+            gc.collect(0)  # young: counted; a span only if it took 1 ms
+            gc.collect()
+            watch.publish()
+            (span,) = [r for r in global_tracer.records()
+                       if r.name == "process.gc_pause"
+                       and r.attrs["generation"] == 2]
+            assert span.track == "process" and span.trace_id is None
+            assert span.attrs["generation"] == 2
+            assert span.attrs["collected"] >= 0
+            assert t0 <= span.ts and span.ts + span.dur <= time.monotonic()
+            written = len(global_tracer.records())
+            watch.publish()  # nothing is written twice
+            assert len(global_tracer.records()) == written <= 2
+        finally:
+            global_tracer.configure(enabled=False)
+            global_tracer.clear()
+        assert global_metrics.counter(names[1]) - before[1] == 4
+        assert global_metrics.counter(names[2]) - before[2] == 1
+        assert watch.totals()[1] == 1
+    finally:
+        gc.callbacks.remove(watch._on_gc)
+        if was_on:
+            gc.enable()
+
+
+def test_the_split_and_the_collectors_names_are_catalogued():
+    from p2p_llm_tunnel_tpu.utils.flight import WALLCLOCK_WAIVED, _waived
+    from p2p_llm_tunnel_tpu.utils.metrics import METRICS_CATALOG
+    from p2p_llm_tunnel_tpu.utils.tracing import SPAN_CATALOG
+
+    new = ("segments_ms", "drain_ms", "at_ms", "wait_ms", "waits_ms",
+           "exec_ms", "lag_ms", "evict_ms", "evicted_pages", "gc_ms",
+           "gc_full")
+    assert set(new) <= set(FLIGHT_SCHEMA)
+    assert set(LOOP_PARTS.values()) <= set(FLIGHT_SCHEMA)
+    # what follows the clock or the collector is waived from the bundles'
+    # identity; a count of pages is not
+    assert all(_waived(f) for f in new if f != "evicted_pages")
+    assert not _waived("evicted_pages")
+    counters = ("engine_loop_host_seconds_total",
+                "engine_loop_wait_seconds_total",
+                "engine_loop_lag_seconds_total",
+                "process_gc_pause_seconds_total",
+                "process_gc_collections_total",
+                "process_gc_full_collections_total")
+    assert set(counters) <= set(METRICS_CATALOG)
+    assert set(counters) <= WALLCLOCK_WAIVED
+    assert "engine_decode_fetch_ms" not in METRICS_CATALOG
+    assert "process.gc_pause" in SPAN_CATALOG
 
 
 def test_startup_journal_is_bounded_by_dropping_the_latest():
@@ -602,6 +787,7 @@ def test_traceview_flight_summary(tmp_path, capsys):
     assert out["cold_compiles"] == 1
     assert out["queue_depth_max"] == 4
     assert len(out["tail"]) == 3
+    assert out["split"] is None  # records from before the split say nothing
 
     path = tmp_path / "trace.json"
     path.write_text(json.dumps(trace))
@@ -612,6 +798,27 @@ def test_traceview_flight_summary(tmp_path, capsys):
     # --json twin stays machine-readable.
     assert traceview.main([str(path), "--flight", "--json"]) == 0
     assert json.loads(capsys.readouterr().out)["iterations"] == 3
+
+    # ISSUE 57: where the iterations' wall went
+    parts = dict.fromkeys(LOOP_PARTS.values(), 0.0)
+    for i, (dur, wait) in enumerate(((100.0, 60.0), (200.0, 20.0))):
+        global_flight.record_iteration(
+            t=10.0 + i, dur_ms=dur, wait_ms=wait, lag_ms=2.0, exec_ms=90.0,
+            evict_ms=1.5, evicted_pages=3, gc_ms=4.0, gc_full=1,
+            **dict(parts, dispatch_ms=dur))
+    trace["traceEvents"] = (list(global_tracer.chrome_trace()["traceEvents"])
+                            + global_flight.chrome_events())
+    split = traceview.summarize_flight(trace)["split"]
+    assert split["iterations"] == 2 and split["host_ms"] == 220.0
+    assert split["host_share_pct"] == pytest.approx(100.0 * 220.0 / 300.0)
+    assert split["parts_ms"]["dispatch_ms"] == 300.0
+    assert split["evicted_pages"] == 6 and split["gc_full"] == 2
+    assert split["longest_hold"]["iter"] == 5
+    path.write_text(json.dumps(trace))
+    assert traceview.main([str(path), "--flight"]) == 0
+    printed = capsys.readouterr().out
+    assert "host 220.0 ms (73.3 %)" in printed
+    assert "longest hold: iteration 5, 180.0 ms of host time" in printed
 
 
 def test_traceview_startup_phase_table_and_slowest_programs(tmp_path,

@@ -176,8 +176,10 @@ def test_validator_rejects_malformed_traces():
 def test_span_catalog_names_are_layer_dotted():
     for name in SPAN_CATALOG:
         layer, _, what = name.partition(".")
-        # (startup: the start-up journal's spans, ISSUE 40)
-        assert layer in ("proxy", "serve", "engine", "startup") and what, name
+        # (startup: the start-up journal's spans, ISSUE 40; process: the
+        # collector's pauses, ISSUE 57)
+        assert layer in ("proxy", "serve", "engine", "startup",
+                         "process") and what, name
 
 
 # ---------------------------------------------------------------------------

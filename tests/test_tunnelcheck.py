@@ -1022,7 +1022,12 @@ def test_tc07_flags_factory_returned_callable_per_slot(tmp_path):
     assert "_copy_in" in active[0].message
 
 
-def test_tc07_flags_dispatching_helper_via_executor(tmp_path):
+@pytest.mark.parametrize("handoff", [
+    "loop.run_in_executor(None, self._dispatch_one, run)",
+    # the engine loop's timed run_in_executor (ISSUE 57): same place
+    "self._offload(loop, self._dispatch_one, run)",
+])
+def test_tc07_flags_dispatching_helper_via_executor(tmp_path, handoff):
     """A method that transitively dispatches, handed to run_in_executor
     once per request, is still one dispatch per iteration."""
     active, _ = check(
@@ -1039,8 +1044,8 @@ def test_tc07_flags_dispatching_helper_via_executor(tmp_path):
 
             async def admit(self, loop, admitted):
                 for run in admitted:
-                    await loop.run_in_executor(None, self._dispatch_one, run)
-        """,
+                    await HANDOFF
+        """.replace("HANDOFF", handoff),
         filename=ENGINE_FIXTURE,
         rules=["TC07"],
     )

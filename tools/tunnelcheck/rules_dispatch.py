@@ -76,7 +76,9 @@ DEVICE_CALLS = {
     "jax.block_until_ready": "jax.block_until_ready",
 }
 
-_EXECUTOR_METHODS = {"run_in_executor", "submit"}
+#: ``_offload(loop, fn, ...)`` is the engine loop's timed
+#: ``run_in_executor`` (ISSUE 57): the callable is its second argument too.
+_EXECUTOR_METHODS = {"run_in_executor", "_offload", "submit"}
 
 
 def _in_scope(sf: SourceFile) -> bool:
@@ -230,11 +232,12 @@ def check_tc07(sf: SourceFile, ctx: ProjectContext) -> Iterator[Violation]:
                     report(sub, f"{callee}(...)", loop)
                     continue
                 if callee in _EXECUTOR_METHODS:
-                    # run_in_executor(executor, fn, ...) / submit(fn, ...):
+                    # run_in_executor(executor, fn, ...) / _offload(loop, fn,
+                    # ...) / submit(fn, ...):
                     # the handed-off callable dispatches on another thread,
                     # still once per iteration.
-                    cands = sub.args[1:] if callee == "run_in_executor" \
-                        else sub.args[:1]
+                    cands = sub.args[:1] if callee == "submit" \
+                        else sub.args[1:]
                     for a in cands[:1]:
                         an = _callee_name(a)
                         if an in names or an in dispatching:
