@@ -41,7 +41,11 @@ test: lint test-unit test-local
 test-unit:         ## full pytest suite on the virtual CPU mesh
 	python -m pytest tests/ -q
 
-test-fast:         ## <3 min iteration loop: everything not marked slow
+# Everything not marked slow, in one process: the better part of an hour since
+# the model families came (it was 3 min at ISSUE 31).  The iteration loop is a
+# file or a -k; the whole of it is what the driver runs, six workers a file
+# each, about 17 min: add `-p xdist -n 6 --dist loadfile`.
+test-fast:         ## everything not marked slow
 	python -m pytest tests/ -q -m "not slow"
 
 test-local:        ## hermetic 4-process end-to-end over real sockets

@@ -308,6 +308,7 @@ def test_the_configuration_file_keeps_every_published_key():
             args[args.index("--slots") + 1])
 
 
+@pytest.mark.usefixtures("full_optimiser")  # weights held to the bit
 def test_the_benchmarks_reference_draws_the_programs_weights():
     cfg = get_config("tiny-ssm-mlp")
     weights = bench.make_weights(SHAPES, 5)
@@ -478,7 +479,7 @@ def test_the_tiny_cell_is_correct_as_stated_and_not_under_a_control(mode):
     the weights' precision lowered.  The ladder's prefixes reach the chunk
     program through the pool and the snapshots.  (Through signal + serve +
     proxy: tests/benchmarks/test_bm_granite_rehearsal.py, ``slow``.)"""
-    from test_mla_moe import _ask_in_process
+    from tests.tiny_cell import _ask_in_process
 
     from benchmarks import correctness, traffic
     from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine

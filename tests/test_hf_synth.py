@@ -14,6 +14,7 @@ AutoTokenizer offline load, apply_chat_template expansion, int8 load
 quantization, serve → tunnel → /v1/chat/completions.
 """
 
+import importlib.util
 import os
 import subprocess
 import sys
@@ -21,12 +22,16 @@ import sys
 import pytest
 
 # The generator + e2e need the HF tooling stack; skip (not fail) where a
-# minimal install lacks it — these are not declared project deps.
-pytest.importorskip("tokenizers")
-pytest.importorskip("safetensors")
-pytest.importorskip("transformers")
+# minimal install lacks it — these are not declared project deps.  Asked
+# without importing them: a run that deselects this all-`slow` file still
+# collects it, and the three imports were 12 s of every worker's collection.
+_MISSING = [m for m in ("tokenizers", "safetensors", "transformers")
+            if importlib.util.find_spec(m) is None]
 
-pytestmark = pytest.mark.slow
+pytestmark = [
+    pytest.mark.slow,
+    pytest.mark.skipif(bool(_MISSING), reason=f"not installed: {_MISSING}"),
+]
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

@@ -1,16 +1,14 @@
 """The latent-attention, routed-expert family (``tiny-mla-moe``: sarvam-105b
 at a size the CPU runs) against its plain reference, tests/mla_moe_plain.py:
 the three serving programs, the two attention forms, the shares of a layer,
-routing under imbalance, latent pages in the prefix pool, the routed
-layers' counters, the benchmark's own copy of the reference and its
-configuration file.
+routing under imbalance and latent pages in the prefix pool.  The family
+through the engine is tests/test_mla_moe_engine.py; its preset, configuration
+file, the benchmark's reference and the tiny cell are
+tests/test_mla_moe_cell.py.
 """
 
 from __future__ import annotations
 
-import asyncio
-import json
-import os
 from dataclasses import replace
 
 import jax
@@ -22,50 +20,31 @@ from p2p_llm_tunnel_tpu.models import mla, moe
 from p2p_llm_tunnel_tpu.models.config import get_config
 from p2p_llm_tunnel_tpu.models.transformer import (
     _act,
-    chunk_prefill_into_cache,
-    decode_step,
     init_kv_cache,
     init_params,
-    prefill,
-    prefill_into_cache,
 )
 from tests import mla_moe_plain as plain
-from tests.moe_records import dispatches_closed
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ROWS, MAX_SEQ = 4, 64
-# float32 program against the float32 reference at `highest`: sums taken in
-# another order (a grouped product over sorted rows, the absorbed form's
-# folded query) differ in the last places of a float32.
-ATOL = 2e-4
-
-
-@pytest.fixture(scope="module", params=["tiny-mla-moe", "tiny-mla-moe-ep2s"])
-def model(request):
-    cfg = get_config(request.param)
-    return cfg, init_params(cfg, jax.random.PRNGKey(11), jnp.float32)
-
-
-def _prompt(seed, n):
-    return list(np.random.RandomState(seed).randint(1, 500, size=n))
+from tests.mla_moe_tiny import (
+    ATOL,
+    MAX_SEQ,
+    ROWS,
+    _chunk_prefill,
+    _decode_step,
+    _prefill,
+    _prompt,
+    _whole,
+    model,
+)
 
 
 def _logprobs(logits):
     return np.asarray(jax.nn.log_softmax(logits, axis=-1))
 
 
-def _whole(cfg, params, cache, prompt, slot, **kw):
-    width = 16 * -(-len(prompt) // 16)
-    tok = jnp.zeros((1, width), jnp.int32).at[0, :len(prompt)].set(
-        jnp.array(prompt))
-    return prefill_into_cache(cfg, params, tok, jnp.array([len(prompt)]),
-                              cache, jnp.array([slot]), **kw)
-
-
 def _chunk(cfg, params, cache, prompt, start, slot, view=MAX_SEQ, **kw):
     tail = prompt[start:]
     tok = jnp.zeros((1, 16), jnp.int32).at[0, :len(tail)].set(jnp.array(tail))
-    return chunk_prefill_into_cache(
+    return _chunk_prefill(
         cfg, params, tok, jnp.array([len(tail)]), jnp.array([start]), cache,
         jnp.array([slot]), kv_view=view, **kw)
 
@@ -77,8 +56,8 @@ def test_whole_prompt_prefill_matches_the_reference(model):
     prompt = _prompt(1, 23)
     want = np.asarray(plain.forward_logprobs(cfg, params, prompt))
     tok = jnp.array([prompt + [0] * 9])
-    logits, rows, none = prefill(cfg, params, tok,
-                                 jnp.arange(32)[None] < len(prompt))
+    logits, rows, none = _prefill(cfg, params, tok,
+                                  jnp.arange(32)[None] < len(prompt))
     assert none is None and rows.shape == (cfg.n_layers, 1, 32, cfg.head_dim)
     np.testing.assert_allclose(_logprobs(logits[0, :23]), want, atol=ATOL)
     # ... and the log-probabilities the echo path returns
@@ -110,7 +89,7 @@ def test_chunked_prefill_and_decode_match_the_reference(model, segments):
     for step in range(4):
         seq.append(int(np.argmax(got[-1])))
         tokens[1], positions[1] = seq[-1], len(seq) - 1
-        logits, cache = decode_step(cfg, params, cache, jnp.array(tokens),
+        logits, cache = _decode_step(cfg, params, cache, jnp.array(tokens),
                                     jnp.array(positions), kv_view=MAX_SEQ)
         got.append(_logprobs(logits[1]))
     want = np.asarray(plain.forward_logprobs(cfg, params, seq))
@@ -263,433 +242,3 @@ def test_a_latent_page_survives_pool_slot_pool(model):
     a, _ = _chunk(cfg, params, cache, prompt, 48, 0)
     b, _ = _chunk(cfg, params, cache, prompt, 48, 2)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# ---- the engine -----------------------------------------------------------------
-
-def _engine(model_name="tiny-mla-moe-ep2s", model_cfg=None, **kw):
-    from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
-
-    return InferenceEngine(model_cfg=model_cfg, engine_cfg=EngineConfig(
-        model=model_name, num_slots=2, max_seq=128, dtype="float32",
-        decode_steps=2, **kw))
-
-
-def _routing(cfg, params, seq):
-    """For each expert layer, the experts [T, k] the plain reference's own
-    forward over ``seq`` routes each position to."""
-    lo, _ = cfg.experts_held
-    f32 = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), params)
-    chosen = []
-    with jax.default_matmul_precision("highest"):
-        x = f32["embed"][jnp.asarray(seq)]
-        for group in ("dense_blocks", "blocks"):
-            for i in range(f32[group]["wq"].shape[0]):
-                w = jax.tree.map(lambda a: a[i], f32[group])
-                x = plain.attention(cfg, w, x)
-                h = plain._norm(x, w["mlp_norm"], cfg.norm_eps)
-                if group == "dense_blocks":
-                    x = x + plain._swiglu(h, w["w_gate"], w["w_up"],
-                                          w["w_down"])
-                    continue
-                chosen.append(np.asarray(moe.route(cfg, w, h)[0]))
-                x = x + plain.routed_layer(cfg, w, h, first_held=lo)
-    return chosen
-
-
-def _recount(cfg, chosen, positions):
-    """What the routed layers count of ``positions`` in one call."""
-    lo, n = cfg.experts_held
-    made = held = fullest = touched = 0
-    for top_i in chosen:
-        here = top_i[positions]
-        local = here[(here >= lo) & (here < lo + n)] - lo
-        per = np.bincount(local, minlength=n)
-        made, held = made + here.size, held + local.size
-        fullest, touched = fullest + per.max(), touched + int((per > 0).sum())
-    return [made, held, fullest, touched]
-
-
-def test_each_program_counts_what_a_host_side_recount_does(model):
-    """Padding rows and parked rows count for nothing."""
-    cfg, params = model
-    prompt = _prompt(9, 27)
-    chosen = _routing(cfg, params, prompt)
-    cache = init_kv_cache(cfg, ROWS, MAX_SEQ, jnp.float32)
-    tok = jnp.zeros((2, 32), jnp.int32).at[0, :27].set(jnp.array(prompt))
-    park = ROWS - 1
-    *_, stats = prefill_into_cache(
-        cfg, params, tok, jnp.array([27, 1]), cache, jnp.array([1, park]),
-        stat_rows=jnp.array([True, False]))
-    assert list(stats) == _recount(cfg, chosen, np.arange(27))
-    _, cache = _whole(cfg, params, cache, prompt[:16], 1)
-    tail = jnp.zeros((2, 16), jnp.int32).at[0, :10].set(
-        jnp.array(prompt[16:26]))
-    _, cache, stats = chunk_prefill_into_cache(
-        cfg, params, tail, jnp.array([10, 1]), jnp.array([16, 0]), cache,
-        jnp.array([1, park]), kv_view=32, stat_rows=jnp.array([True, False]))
-    assert list(stats) == _recount(cfg, chosen, np.arange(16, 26))
-    tokens = jnp.zeros((ROWS,), jnp.int32).at[1].set(prompt[26])
-    positions = jnp.full((ROWS,), MAX_SEQ).at[1].set(26)
-    _, _, stats = decode_step(cfg, params, cache, tokens, positions,
-                              kv_view=32, with_stats=True)
-    assert list(stats) == _recount(cfg, chosen, np.array([26]))
-
-
-def test_the_counters_and_the_ledger_carry_the_counts():
-    """One request through the engine (chunked prefill, then decode bursts):
-    the counters grow by what the dispatch records carry, the prefill
-    record by a host-side recount, the decode records by their live rows
-    and steps."""
-    from p2p_llm_tunnel_tpu.utils.metrics import METRICS_CATALOG, global_metrics
-    from p2p_llm_tunnel_tpu.utils.tracing import global_tracer
-
-    names = ("engine_moe_assignments_total",
-             "engine_moe_assignments_held_total",
-             "engine_moe_expert_tokens_max_total",
-             "engine_moe_experts_touched_total")
-    keys = ("moe_assignments", "moe_held", "moe_expert_tokens_max",
-            "moe_experts_touched")
-    assert all(n in METRICS_CATALOG for n in names)
-    prompt = _prompt(9, 37)
-
-    async def main():
-        eng = _engine(mux=True, prefix_cache=True, prefix_pool_blocks=16)
-        assert eng._moe_counts
-        await eng.start()
-        try:
-            before = [global_metrics.counter(n) for n in names]
-            toks = [ev.token_id async for ev in eng.generate(
-                prompt, max_new_tokens=5, stop_ids=())]
-            await dispatches_closed(eng)
-            grew = [global_metrics.counter(n) - b
-                    for n, b in zip(names, before)]
-        finally:
-            await eng.stop()
-        return eng, toks, grew
-
-    global_tracer.clear()
-    global_tracer.configure(enabled=True, sample=1.0, capacity=65536)
-    try:
-        eng, toks, grew = asyncio.run(asyncio.wait_for(main(), 300))
-        records = global_tracer.records()
-    finally:
-        global_tracer.configure(enabled=False)
-        global_tracer.clear()
-    cfg = eng.mcfg
-    assert len(toks) == 5
-    segs = [r for r in records if r.name == "engine.prefill_segment"]
-    bursts = [r for r in records if r.name == "engine.decode_burst"]
-    assert len(segs) == 1 and bursts
-    chosen = _routing(cfg, eng.params, prompt)
-    assert [segs[0].attrs[k] for k in keys] == _recount(
-        cfg, chosen, np.arange(len(prompt)))
-    per_position = cfg.n_experts_per_tok * len(chosen)
-    for r in bursts:
-        a = r.attrs
-        assert a["moe_assignments"] == a["live_rows"] * a["steps"] * per_position
-        assert 0 < a["moe_expert_tokens_max"] <= a["moe_held"] <= \
-            a["moe_assignments"]
-    assert [sum(r.attrs[k] for r in segs + bursts) for k in keys] == grew
-    assert 0 < grew[1] < grew[0]  # a share holds some of them, not all
-
-
-@pytest.mark.parametrize("kernel", [False, True],
-                         ids=["ragged-dot", "kernel"])
-def test_the_records_moe_and_the_kernel_counter_are_held_to_each_other(
-        kernel):
-    """(ISSUE 39) Every decode and prefill record of a share says which
-    grouped product its program ran; the counter grows by the records that
-    say the kernel; the kernel (interpreted here) emits ``ragged_dot``'s
-    tokens."""
-    from tests import moe_records
-
-    def run(interpret):
-        eng = _engine(
-            model_cfg=get_config("tiny-mla-moe-ep2s", flash_interpret=interpret,
-                                 vocab_size=259),
-            mux=True, prefix_cache=True, prefix_pool_blocks=16)
-        return (eng,) + moe_records.run_traced(eng, _prompt(9, 37), 5)
-
-    eng, toks, grew, records = run(kernel)
-    moe_records.check(eng, grew, records, kernel)
-    if kernel:
-        assert toks == run(False)[1]
-
-
-REFUSED = {
-    "quant-int8": dict(quant="int8"),
-    "quant-int4": dict(quant="int4"),
-    "quant-w8a8": dict(quant="w8a8"),
-    "kv-int4": dict(kv_quant="int4"),
-    "tp": dict(tp=2), "sp": dict(sp=2), "ep": dict(ep=2),
-    "ragged-prefill": dict(ragged_prefill=True),
-    "spec-ngram": dict(spec_ngram=2),
-}
-
-
-@pytest.mark.parametrize("case", sorted(REFUSED))
-def test_what_the_family_lacks_is_refused_at_start_up(case):
-    with pytest.raises(ValueError, match="cannot be served with --"):
-        _engine("tiny-mla-moe", **REFUSED[case])
-
-
-def test_healthz_names_the_cache_form_and_the_share():
-    eng = _engine(prefix_cache=True, prefix_pool_blocks=8)
-    section = eng._model_section()
-    cfg = eng.mcfg
-    assert section["cache"] == {
-        "form": "latent", "values_per_token_layer": 40,
-        "bytes_per_token": cfg.n_layers * 40 * 4}
-    assert section["layers"] == {"held": 4, "of": 4}
-    assert section["experts"] == {"held": 4, "first": 0, "of": 8}
-    # (a CPU backend: the grouped products are ragged_dot's)
-    assert set(section["expert_products"]) == {"decode", "chunk_prefill"}
-    assert section["expert_products"]["decode"] == moe.RAGGED
-    assert section["vocab_rows"] == {"held": cfg.vocab_size,
-                                     "of": 2 * cfg.vocab_size}
-    assert eng._prefix_block_bytes == 16 * cfg.n_layers * 40 * 4
-    assert eng._prefix_snapshot_meta()["page"] == [
-        ["c", [32], "float32"], ["kr", [16], "float32"]]
-    dense = _engine("tiny")._model_section()
-    assert dense["cache"]["form"] == "kv_heads"
-    assert dense["experts"] == {"held": 0, "first": 0, "of": 0}
-
-
-def test_the_published_preset_and_its_share():
-    whole, share = get_config("sarvam-105b"), get_config("sarvam-105b-ep4s")
-    assert (whole.n_layers, whole.n_experts, whole.vocab_size) == (
-        32, 128, 262144)
-    assert whole.experts_held == (0, 128)
-    assert share.experts_held == (0, 32) and share.n_layers == 6
-    assert share.vocab_size * share.layer_chips == whole.vocab_size
-    assert share.layer_kinds == ("dense",) + ("moe",) * 5
-    same = {f: getattr(whole, f) for f in (
-        "dim", "n_heads", "head_dim", "ffn_dim", "moe_ffn_dim",
-        "n_experts", "n_experts_per_tok", "n_shared_experts",
-        "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
-        "router_bias", "routed_scale", "yarn")}
-    assert same == {f: getattr(share, f) for f in same}
-    assert whole.head_dim == whole.kv_lora_rank + whole.qk_rope_head_dim
-
-
-# ---- the benchmark's copy and its configuration ---------------------------------
-
-TINY_FILE = {
-    "hidden_size": 64, "num_hidden_layers": 4, "num_attention_heads": 4,
-    "kv_lora_rank": 32, "qk_rope_head_dim": 8, "qk_nope_head_dim": 16,
-    "v_head_dim": 16, "intermediate_size": 128, "moe_intermediate_size": 32,
-    "num_experts": 8, "num_experts_per_tok": 2, "num_shared_experts": 1,
-    "first_k_dense_replace": 1, "moe_router_enable_expert_bias": True,
-    "routed_scaling_factor": 2.5, "use_qk_norm": True, "rope_theta": 10000.0,
-    "rms_norm_eps": 1e-6, "vocab_size": 512,
-    "rope_scaling": {"type": "deepseek_yarn", "factor": 40,
-                     "original_max_position_embeddings": 16, "beta_fast": 32,
-                     "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1},
-    "precision": {"kv_cache": "bfloat16"},
-}
-
-
-@pytest.mark.parametrize("share", [False, True], ids=["whole", "share"])
-def test_the_benchmarks_reference_is_the_same_model(share):
-    """benchmarks/mla_moe_reference.py draws the program's weights from the
-    seed and computes the plain reference's forward, whole and as a share."""
-    from benchmarks import mla_moe_reference as bench
-
-    config = dict(TINY_FILE)
-    if share:
-        config.update(num_experts=4, layer_chips=2, chip_index=0,
-                      published_counts={"num_experts": 8})
-    cfg = get_config("tiny-mla-moe-ep2s" if share else "tiny-mla-moe")
-    shapes = bench.shapes_of(config)
-    weights = bench.make_weights(shapes, 5)
-    params = init_params(cfg, jax.random.PRNGKey(5), jnp.bfloat16)
-    for mine, theirs in (("wq", "wq"), ("gate", "moe_gate"),
-                         ("down", "moe_down"), ("router", "router"),
-                         ("bias", "router_bias"),
-                         ("shared_up", "shared_up")):
-        np.testing.assert_array_equal(
-            np.asarray(weights["moe"][mine], np.float32),
-            np.asarray(params["blocks"][theirs], np.float32))
-    np.testing.assert_array_equal(
-        np.asarray(weights["dense"]["gate"], np.float32),
-        np.asarray(params["dense_blocks"]["w_gate"], np.float32))
-    np.testing.assert_array_equal(np.asarray(weights["embed"], np.float32),
-                                  np.asarray(params["embed"], np.float32))
-    tokens = _prompt(3, 21)
-    got = np.asarray(bench.forward_logprobs(shapes, weights, tokens))
-    want = np.asarray(plain.forward_logprobs(cfg, params, tokens))
-    np.testing.assert_allclose(got, want, atol=ATOL)
-    rounded = np.asarray(bench.forward_logprobs(shapes, weights, tokens,
-                                                weight_bits=8))
-    assert 1e-3 < np.abs(rounded - want).mean() < 0.5
-    assert bench.cache_bytes_per_token(config) == 4 * 40 * 2
-
-
-def test_the_configuration_file_keeps_the_published_keys():
-    with open(os.path.join(REPO, "benchmarks", "configs",
-                           "sarvam-105b.json")) as f:
-        body = json.load(f)
-    with open(os.path.join(REPO, "tests", "benchmarks", "data",
-                           "sarvam-105b.published.json")) as f:
-        published = json.load(f)
-    reduced = {"num_hidden_layers": 6, "num_experts": 32, "vocab_size": 65536}
-    assert set(body["reduced"]) == set(reduced)
-    for key, value in published.items():
-        assert body[key] == reduced.get(key, value), key
-    assert body["published_counts"] == {k: published[k] for k in reduced}
-    assert body["layer_chips"] * body["num_experts"] == published["num_experts"]
-    assert body["layer_chips"] * body["vocab_size"] == published["vocab_size"]
-    from benchmarks import mla_moe_reference as bench
-
-    assert bench.cache_bytes_per_token(body) == 6912
-    shapes = bench.shapes_of(body)
-    assert (shapes["held"], shapes["experts"], shapes["layers"]) == (32, 128, 6)
-    share = get_config(body["serve"]["model"])
-    assert share.experts_held == (shapes["first_held"], shapes["held"])
-    assert share.vocab_size == shapes["vocab"]
-
-
-# ---- the tiny cell, in one process ----------------------------------------------
-
-def _ask_in_process(eng, seqs):
-    """``benchmarks.correctness.ask_engine`` without the tunnel: the same
-    requests through ``engine.generate``, the sequences filled alike."""
-    from benchmarks import correctness
-
-    async def one(prompt, new, echo):
-        events = [ev async for ev in eng.generate(
-            prompt, max_new_tokens=new, logprobs=1, echo_logprobs=echo,
-            stop_ids=())]
-        return ([ev.token_id for ev in events], [ev.logprob for ev in events],
-                events[0].prompt_logprobs)
-
-    async def main():
-        await eng.start()
-        try:
-            jobs = []
-            for i, seq in enumerate(seqs):
-                seq.update(tokens=list(seq["prompt"]), probes=[], system=[],
-                           parts=[])
-                if seq["group"] == "ladder":
-                    jobs += [(i, n, 1, False) for n in correctness._rungs(seq)]
-                else:
-                    jobs.append((i, len(seq["prompt"]), correctness.NEW_TOKENS,
-                                 seq["group"] == "echo"))
-            gate = asyncio.Semaphore(correctness.ASK_AT_ONCE)
-
-            async def gated(job):
-                async with gate:
-                    return await one(seqs[job[0]]["prompt"][:job[1]], job[2],
-                                     job[3])
-
-            return jobs, await asyncio.gather(*(gated(j) for j in jobs))
-        finally:
-            await eng.stop()
-
-    jobs, answers = asyncio.run(asyncio.wait_for(main(), 900))
-    for (i, n, asked, echo), (tokens, values, plps) in zip(jobs, answers):
-        seq = seqs[i]
-        assert len(tokens) == asked
-        if echo:
-            for t in range(1, n):
-                seq["probes"].append((t - 1, seq["prompt"][t]))
-                seq["system"].append(plps[t])
-                seq["parts"].append("echo_prompt")
-        if seq["group"] == "ladder":
-            seq["probes"].append((n - 1, tokens[0]))
-            seq["system"].append(values[0])
-            seq["parts"].append("traffic_prefill")
-            continue
-        seq["tokens"] = seq["prompt"] + tokens
-        for j, (tok, value) in enumerate(zip(tokens, values)):
-            seq["probes"].append((n - 1 + j, tok))
-            seq["system"].append(value)
-            seq["parts"].append(seq["group"] + "_decode")
-
-
-TINY_CELL_MODES = {
-    "stated": ({}, None),
-    "weights": ({}, 8),
-    "activations": ({"quant": "a8"}, None),
-    "kv_cache": ({"kv_quant": "int8"}, None),
-}
-# echo_prompt has the most positions (about 770) and is the steady one: as
-# stated it reads 0.038 here, with 8-bit weights in the reference's place
-# 0.064, with int8 activations 0.074 (a model this narrow routes a token
-# elsewhere on a rounding, which is most of every number); the other three
-# have a few hundred positions, read 0.014-0.058 as stated or with int8
-# planes, and only have to hold.
-TINY_CELL_LIMITS = {"echo_prompt": 0.05, "echo_decode": 0.1,
-                    "traffic_decode": 0.1, "traffic_prefill": 0.1}
-
-
-@pytest.mark.parametrize("mode", sorted(TINY_CELL_MODES))
-def test_the_tiny_cell_is_correct_as_stated_and_not_under_a_control(mode):
-    """tests/benchmarks/tinycell_mla.py's cell (the ``tiny-mla-moe-ep2s``
-    share in bfloat16 against benchmarks/mla_moe_reference.py given the same
-    share) through the engine in this process: what ``correct`` compares,
-    as stated and with each stated precision lowered.  (Through signal +
-    serve + proxy: tests/benchmarks/test_bm_mla_rehearsal.py, ``slow``.)"""
-    import sys
-
-    sys.path.insert(0, os.path.join(REPO, "tests", "benchmarks"))
-    import tinycell_mla
-    from benchmarks import correctness, mla_moe_reference as bench, traffic
-    from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
-    from p2p_llm_tunnel_tpu.engine.tokenizer import ByteTokenizer
-
-    engine_args, weight_bits = TINY_CELL_MODES[mode]
-    config, seed = tinycell_mla.CONFIG, 11
-    vocab = config["vocab_size"]
-    plan = traffic.make_plan(
-        {"name": "t", "loop": "closed", "clients": 3,
-         "requests_per_client": 4, "lead_s": 0.5, "tail_s": 0.0,
-         "request_timeout_s": 30.0,
-         "prompt_tokens": {"dist": "uniform", "min": 8, "max": 24},
-         "output_tokens": {"dist": "uniform", "min": 8, "max": 16}},
-        seed, 3, vocab)
-    seqs = correctness.sequences(plan, seed, vocab, 256)
-    shapes = bench.shapes_of(config)
-    weights = bench.make_weights(shapes, seed)
-    stated = bench.cache_bytes_per_token(config)
-    if weight_bits is None:
-        class Words(ByteTokenizer):
-            vocab_size = vocab
-
-        eng = InferenceEngine(
-            engine_cfg=EngineConfig(
-                model=config["serve"]["model"], num_slots=4, max_seq=256,
-                seed=seed, mux=True, prefix_cache=True, **engine_args),
-            tokenizer=Words())
-        _ask_in_process(eng, seqs)
-        counted = eng._prefix_block_bytes / eng._prefix_block
-    else:  # the reference in the program's place, its weights rounded
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "control", os.path.join(REPO, "benchmarks", "control.py"))
-        control = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(control)
-        counted = stated
-        for seq in seqs:
-            control.pretend(seq)
-            lp = np.asarray(bench.forward_logprobs(
-                shapes, weights, seq["tokens"], weight_bits=weight_bits))
-            seq["system"] = [float(lp[p, t]) for p, t in seq["probes"]]
-    reference = []
-    for seq in seqs:
-        lp = np.asarray(bench.forward_logprobs(shapes, weights, seq["tokens"]))
-        reference.append([float(lp[p, t]) for p, t in seq["probes"]])
-    numbers = correctness.compare(seqs, reference)
-    said = []
-    held = correctness.judge(numbers, TINY_CELL_LIMITS, counted, stated,
-                             said.append)
-    assert held is (mode == "stated"), "\n".join(said)
-    if mode == "kv_cache":  # by its width alone
-        assert counted == 4 * (40 + 8) and stated == 4 * 40 * 2
-        assert all(numbers[n]["mean_abs"] <= TINY_CELL_LIMITS[n]
-                   for n in correctness.NUMBERS), said
-    elif mode != "stated":
-        assert numbers["echo_prompt"]["mean_abs"] > \
-            TINY_CELL_LIMITS["echo_prompt"], said

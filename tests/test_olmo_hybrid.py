@@ -5,18 +5,15 @@ the benchmark's plain reference, benchmarks/olmo_hybrid_reference.py, on the
 program's own seeded random weights: whole-prompt prefill, chunk prefill in
 segments that split a chunk then decode through state and cache, each piece
 of the layer left out, the chunked form against the token-by-token one,
-padding and parked rows, a snapshot restored, a Mamba-2 model's programs
-unchanged, the engine end to end, /healthz, the published preset's count of
-parameters, the configuration file, and the tiny cell in one process.
+padding and parked rows, a snapshot restored, and a Mamba-2 model's programs
+unchanged.  The family through the engine is
+tests/test_olmo_hybrid_engine.py; the published preset, the configuration
+file and the tiny cell are tests/test_olmo_hybrid_cell.py.
 """
 
 from __future__ import annotations
 
-import asyncio
 import hashlib
-import json
-import os
-import sys
 from dataclasses import replace
 
 import jax
@@ -24,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import olmo_hybrid_reference as bench
 from p2p_llm_tunnel_tpu.models import delta, ssm_moe
 from p2p_llm_tunnel_tpu.models.config import get_config
 from p2p_llm_tunnel_tpu.ops.pallas_delta_step import DELTA_STEP_KERNEL
@@ -37,40 +33,17 @@ from p2p_llm_tunnel_tpu.models.transformer import (
     init_params,
     prefill,
 )
+from tests.olmo_hybrid_tiny import (
+    ATOL,
+    SHAPES,
+    UPDATES,
+    _decoding,
+    _prompt,
+    _want,
+)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "tests", "benchmarks"))
-import tinycell_olmo as tiny  # noqa: E402
 
 ROWS, MAX_SEQ = 4, 128
-# float32 program against the float32 reference at `highest`: sums taken in
-# another order (a chunked solve against the recurrence, the output read
-# from the old state) differ in the last places of a float32; sixteen
-# branches' norms carry them on.  A bfloat16 state reads 40 x this
-# (test_a_narrower_state_fails_the_tolerance).
-ATOL = 3e-4
-SHAPES = bench.shapes_of(tiny.CONFIG)
-
-
-def _as_reference(params):
-    """The program's parameter tree in the reference's layout: its norms are
-    ones and not stored there, and the six projections of a delta layer are
-    matrices of their own."""
-    assert all(float(jnp.abs(params[g]["norm"] - 1).max()) == 0
-               for g in ("delta", "attn", "mlp"))
-    h, dk, dv = SHAPES["d_heads"], SHAPES["dk"], SHAPES["dv"]
-    d = params["delta"]
-    cuts = np.cumsum([h * dk, h * dk, h * dv])
-    wq, wk, wv, wz = jnp.split(d["w_in"], cuts, axis=-1)
-    wa, wb = jnp.split(d["w_ab"], 2, axis=-1)
-    return {
-        "embed": params["embed"], "lm_head": params["lm_head"],
-        "mlp": {k: params["mlp"][k] for k in ("w_in", "w_out")},
-        "attn": {k: params["attn"][k] for k in ("wq", "wk", "wv", "wo")},
-        "delta": {"wq": wq, "wk": wk, "wv": wv, "wz": wz, "wa": wa,
-                  "wb": wb, "conv_w": d["conv_w"], "wo": d["w_out"],
-                  "dt_bias": d["dt_bias"], "a_log": d["a_log"]},
-    }
 
 
 @pytest.fixture(scope="module")
@@ -78,15 +51,6 @@ def model():
     cfg = get_config("tiny-delta-mlp")
     params = init_params(cfg, jax.random.PRNGKey(11), jnp.float32)
     return cfg, params
-
-
-def _want(params, tokens):
-    return np.asarray(bench.forward_logprobs(
-        SHAPES, _as_reference(params), tokens))
-
-
-def _prompt(seed, n):
-    return list(np.random.RandomState(seed).randint(1, 250, size=n))
 
 
 def _logprobs(logits):
@@ -147,26 +111,35 @@ def test_whole_prompt_prefill_matches_the_reference(model):
     assert rows["state"][0].shape == (6, 1, 3, 16, 24)
 
 
+def _by_token(q, k, v, g, beta):
+    """The operands of a token-by-token ``lax.scan``, time first (a Python
+    loop over 48 positions inside one jit is one huge program: 155 s to
+    compile where this is 2)."""
+    return tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+
+
 def _scan_without_correction(q, k, v, g, beta, state, chunk):
-    s, outs = state, []
-    for t in range(q.shape[1]):
-        s = jnp.exp(g[:, t])[..., None, None] * s
-        s = s + k[:, t][..., None] * (beta[:, t][..., None]
-                                      * v[:, t].astype(jnp.float32)
-                                      )[..., None, :]
-        outs.append(jnp.einsum("bhkv,bhk->bhv", s, q[:, t]))
-    return jnp.stack(outs, 1), s
+    def token(s, x):
+        q, k, v, g, beta = x
+        s = jnp.exp(g)[..., None, None] * s
+        s = s + k[..., None] * (beta[..., None] * v.astype(jnp.float32)
+                                )[..., None, :]
+        return s, jnp.einsum("bhkv,bhk->bhv", s, q)
+
+    s, outs = jax.lax.scan(token, state, _by_token(q, k, v, g, beta))
+    return jnp.moveaxis(outs, 0, 1), s
 
 
 def _bf16_state_scan(q, k, v, g, beta, state, chunk):
     """The recurrence with the state rounded to bfloat16 after every
     token."""
-    s, outs = state, []
-    for t in range(q.shape[1]):
-        o, s = delta.delta_step(q[:, t], k[:, t], v[:, t], g[:, t],
-                                beta[:, t], s.astype(jnp.bfloat16))
-        outs.append(o)
-    return jnp.stack(outs, 1), s.astype(jnp.float32)
+    def token(s, x):
+        o, s = delta.delta_step(*x, s)
+        return s, o
+
+    s, outs = jax.lax.scan(token, state.astype(jnp.bfloat16),
+                           _by_token(q, k, v, g, beta))
+    return jnp.moveaxis(outs, 0, 1), s.astype(jnp.float32)
 
 
 LEFT_OUT = {
@@ -197,18 +170,6 @@ def test_each_piece_left_out_fails_the_tolerance(model, piece, monkeypatch):
         lambda c, *rest: prefill(c, *rest), static_argnums=(0,)))
     apart = np.abs(got - _want(params, prompt)).max()
     assert apart > least, apart
-
-
-#: Decode's state update as ``delta.delta_step`` in XLA, and as the kernel
-#: over the live rows (interpreted): ISSUE 52.
-UPDATES = {"elementwise": {}, "kernel": {"flash_interpret": True}}
-
-
-def _decoding(cfg, update):
-    cfg = replace(cfg, **UPDATES[update])
-    assert ssm_moe.state_update_branch(cfg, None) == (
-        DELTA_STEP_KERNEL if update == "kernel" else ELEMENTWISE)
-    return cfg
 
 
 def _chunk(cfg, params, cache, prompt, start, end, slot, width=32):
@@ -465,275 +426,3 @@ def test_thirty_kv_heads_of_128_are_whole_lane_tiles():
     forced = replace(cfg, flash_force=True)
     assert decode_attention_branch(forced, None, 1024) == "pallas-rows"
     assert ssm_moe.state_update_branch(forced, None) == DELTA_STEP_KERNEL
-
-
-# ---- the published preset -------------------------------------------------------
-
-def test_the_published_preset_counts_4101_m_parameters():
-    cfg = get_config("olmo-hybrid-7b")
-    shapes = jax.eval_shape(
-        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
-    count = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
-    # 12 x (88.75 M a delta mixer + 126.81 M an MLP) + 4 x (58.98 M + 126.81
-    # M) + 770.7 M of embedding and head (the issue's 4,097.5 M counts a
-    # delta mixer at 88.5 M)
-    assert abs(count - 4100.8e6) < 1e6, count
-    assert cfg.mixer_kinds == "LLL*" * 4 and cfg.published_layers == 32
-    # a slot's state: 12 x (30 x 96 x 192 float32 + 3 x 11,520 bfloat16)
-    assert ssm_moe.state_bytes_per_slot(cfg) == 12 * (2211840 + 69120) \
-        == 27_371_520
-    cache = jax.eval_shape(lambda: init_kv_cache(cfg, 65, 1024))
-    # whole (8, 128) tiles a row: 48 sublanes x 384 lanes a head
-    assert {k: v.shape for k, v in cache.items()} == {
-        "k": (4, 65, 1024, 3840), "v": (4, 65, 1024, 3840),
-        "delta": (12, 65, 30, 48, 384), "dconv": (12, 65, 3 * 11520)}
-
-
-def test_the_configuration_file_keeps_every_published_key():
-    with open(os.path.join(REPO, "benchmarks", "configs",
-                           "olmo-hybrid-7b.json")) as f:
-        body = json.load(f)
-    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
-        published = next(row for row in map(json.loads, f)
-                         if row["name"] == "Olmo-Hybrid-7B")
-    assert body["source"] == published["source_url"]
-    assert body["reduced"] == ["num_hidden_layers", "layer_types"]
-    for key, value in published["config"].items():
-        if key not in body["reduced"]:
-            assert body[key] == value, key
-    # four whole periods of the published list
-    assert body["num_hidden_layers"] == 16
-    assert body["layer_types"] == published["config"]["layer_types"][:16]
-    shapes = bench.shapes_of(body)
-    cfg = get_config(body["serve"]["model"])
-    assert "".join("L" if k == "linear_attention" else "*"
-                   for k in shapes["kinds"]) == cfg.mixer_kinds
-    assert (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.ffn_dim,
-            cfg.vocab_size, cfg.norm_eps) == (
-        shapes["dim"], shapes["heads"], shapes["kv"], shapes["hd"],
-        shapes["ffn"], shapes["vocab"], shapes["eps"])
-    assert (cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim,
-            cfg.delta_conv, 2.0 if cfg.delta_neg_eigval else 1.0) == (
-        shapes["d_heads"], shapes["dk"], shapes["dv"], shapes["conv"],
-        shapes["beta_x"])
-    assert (cfg.ssm_dt_min, cfg.ssm_dt_max) == (bench.DT_MIN, bench.DT_MAX)
-    assert delta.UNIT_EPS == bench.UNIT_EPS
-    assert not cfg.tie_embeddings and cfg.mixer_mlp and cfg.norm_after
-    assert jnp.dtype(ssm_moe.STATE_DTYPE).name == body["state_type"]
-    # 4 attention layers x 2 x 30 KV heads of 128 in bfloat16
-    assert bench.cache_bytes_per_token(body) == 61440
-    # the cell's clients are the file's slots
-    args = body["serve"]["args"]
-    with open(os.path.join(REPO, "benchmarks", "traffic",
-                           "chatturns-closed.json")) as f:
-        assert json.load(f)["clients"] == int(
-            args[args.index("--slots") + 1])
-
-
-def test_the_benchmarks_reference_draws_the_programs_weights():
-    cfg = get_config("tiny-delta-mlp")
-    weights = bench.make_weights(SHAPES, 5)
-    mine = _as_reference(init_params(cfg, jax.random.PRNGKey(5),
-                                     jnp.bfloat16))
-    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
-        np.asarray(a, np.float32), np.asarray(b, np.float32)), weights, mine)
-    # the embedding's rows are drawn at a unit RMS (0.88: the truncation)
-    assert float(jnp.std(weights["embed"].astype(jnp.float32))) \
-        == pytest.approx(0.88, rel=0.05)
-    assert bench.cache_bytes_per_token(tiny.CONFIG) == tiny.CACHE_BYTES
-
-
-# ---- through the engine ------------------------------------------------------------
-
-def _engine(model_cfg=None, **kw):
-    from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
-
-    return InferenceEngine(model_cfg=model_cfg, engine_cfg=EngineConfig(
-        model="tiny-delta-mlp", num_slots=2, max_seq=128, dtype="float32",
-        decode_steps=2, **kw))
-
-
-def _generate(eng, prompts, new=8):
-    async def main():
-        await eng.start()
-        try:
-            out = []
-            for prompt in prompts:
-                events = [ev async for ev in eng.generate(
-                    prompt, max_new_tokens=new, logprobs=1, stop_ids=())]
-                out.append(([ev.token_id for ev in events],
-                            [ev.logprob for ev in events]))
-            return out
-        finally:
-            await eng.stop()
-
-    return asyncio.run(asyncio.wait_for(main(), 300))
-
-
-def test_a_prefix_hit_restores_a_snapshot_and_decodes_as_the_unshared_run():
-    """Two prompts that share their first 48 tokens, one after the other
-    (chunk prefill in segments of 16, the pool, decode bursts): the second
-    restores the snapshot of state at 48 and its generated tokens and their
-    log-probabilities are those of an engine with no pool, and the
-    reference's; the state's counters count for this state as for
-    Mamba-2's."""
-    from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
-
-    base = _prompt(9, 60)
-    prompts = [base, base[:48] + _prompt(10, 11)]
-    eng = _engine(mux=True, prefix_cache=True, prefix_pool_blocks=16,
-                  prefill_chunk=16)
-    assert not eng.config_fences
-    hit = global_metrics.counter("engine_prefix_hit_tokens_total")
-    restores = global_metrics.counter("engine_state_restores_total")
-    saves = global_metrics.counter("engine_state_snapshots_total")
-    moved = global_metrics.counter("engine_state_bytes_total")
-    shared = _generate(eng, prompts)
-    assert global_metrics.counter("engine_prefix_hit_tokens_total") - hit == 48
-    assert global_metrics.counter("engine_state_restores_total") - restores \
-        == 1
-    assert global_metrics.counter("engine_state_snapshots_total") > saves
-    assert global_metrics.counter("engine_state_bytes_total") > moved
-    alone = _generate(_engine(mux=True, prefix_cache=False,
-                              prefill_chunk=16), prompts[1:])
-    assert shared[1][0] == alone[0][0]
-    np.testing.assert_allclose(shared[1][1], alone[0][1], atol=ATOL)
-    tokens, values = shared[1]
-    want = _want(eng.params, prompts[1] + tokens)
-    n = len(prompts[1])
-    np.testing.assert_allclose(
-        values, [want[n - 1 + j, t] for j, t in enumerate(tokens)], atol=ATOL)
-    # /healthz names the layer, the head, the delta state a slot (heads, key
-    # and value widths, type, bytes) and the snapshots' room in bytes
-    said = eng._model_section()
-    assert said["layer"] == {"mixers": {"L": 6, "*": 2}, "mlp_width": 96}
-    assert said["head"] == "its own"
-    state = said["cache"]["kinds"]["state"]
-    per_slot = ssm_moe.state_bytes_per_slot(eng.mcfg, jnp.float32)
-    assert per_slot == 6 * (3 * 16 * 24 * 4 + 3 * 168 * 4)
-    assert {k: state[k] for k in (
-        "rule", "layers", "heads", "key_width", "value_width", "type",
-        "held_as", "bytes_per_slot", "update")} == {
-        "rule": "gated delta", "layers": 6, "heads": 3, "key_width": 16,
-        "value_width": 24, "type": "float32", "held_as": [1, 384],
-        "bytes_per_slot": per_slot, "update": "elementwise"}
-    assert state["snapshots"] == {
-        "room": 16, "held": len(eng._snapshots), "bytes_each": per_slot,
-        "bytes": 16 * per_slot}
-    assert said["cache"]["kinds"]["attention"]["kv_heads"] == 3
-    assert eng._snap_pool["delta"].shape[:2] == (6, 17)
-
-
-@pytest.mark.parametrize("update", sorted(UPDATES))
-def test_the_dispatch_records_carry_state_rows(update):
-    """``engine.decode_burst`` and ``engine.prefill_segment`` records name
-    the rows whose state the dispatch read and wrote and their bytes, as a
-    Mamba-2 model's do; a burst's ``state_update`` names the branch's answer
-    (as /healthz does) and ``engine_decode_state_kernel_steps_total`` counts
-    the steps that took the kernel."""
-    from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
-    from tests.moe_records import tracing
-
-    name = "engine_decode_state_kernel_steps_total"
-    with tracing() as tracer:
-        eng = _engine(_decoding(get_config("tiny-delta-mlp"), update),
-                      mux=True, prefill_chunk=16)
-        before = global_metrics.counter(name)
-        steps = global_metrics.counter("engine_decode_steps_total")
-        _generate(eng, [_prompt(12, 20)], new=4)
-        grew = global_metrics.counter(name) - before
-        steps = global_metrics.counter("engine_decode_steps_total") - steps
-        records = tracer.records()
-    row = ssm_moe.state_bytes_per_slot(eng.mcfg, jnp.float32)
-    segs = [r for r in records if r.name == "engine.prefill_segment"]
-    bursts = [r for r in records if r.name == "engine.decode_burst"]
-    assert [r.attrs["tokens"] for r in segs] == [16, 4] and bursts
-    for r in segs:
-        assert (r.attrs["state_rows"], r.attrs["state_bytes"]) == (1, 2 * row)
-    for r in bursts:
-        a = r.attrs
-        assert a["state_rows"] == a["live_rows"] * a["steps"]
-        assert a["state_bytes"] == 2 * row * a["state_rows"]
-    want = DELTA_STEP_KERNEL if update == "kernel" else ELEMENTWISE
-    assert {r.attrs["state_update"] for r in bursts} == {want}
-    assert eng._model_section()["cache"]["kinds"]["state"]["update"] == want
-    assert steps > 0 and grew == (steps if update == "kernel" else 0)
-
-
-@pytest.mark.parametrize("case", [
-    dict(quant="int8"), dict(kv_quant="int4"), dict(spec_ngram=2),
-    dict(ragged_prefill=True), dict(tp=2)], ids=lambda c: next(iter(c)))
-def test_what_the_family_lacks_is_refused_for_this_model_too(case):
-    with pytest.raises(ValueError, match="a dense MLP a layer"):
-        _engine(**case)
-
-
-# ---- the tiny cell, in one process ------------------------------------------------
-
-@pytest.mark.parametrize("mode", ["stated", "weights"])
-def test_the_tiny_cell_is_correct_as_stated_and_not_under_a_control(mode):
-    """tests/benchmarks/tinycell_olmo.py's cell (``tiny-delta-mlp`` in
-    bfloat16 against benchmarks/olmo_hybrid_reference.py) through the
-    engine in this process: what ``correct`` compares, as stated and with
-    the weights' precision lowered.  The ladder's prefixes reach the chunk
-    program through the pool and the snapshots.  (Through signal + serve +
-    proxy: tests/benchmarks/test_bm_olmo_rehearsal.py, ``slow``.)"""
-    from test_mla_moe import _ask_in_process
-
-    from benchmarks import correctness, traffic
-    from p2p_llm_tunnel_tpu.engine.engine import EngineConfig, InferenceEngine
-    from p2p_llm_tunnel_tpu.engine.tokenizer import ByteTokenizer
-    from p2p_llm_tunnel_tpu.utils.metrics import global_metrics
-
-    config, seed = tiny.CONFIG, 11
-    limits = config["correct"]["limits"]
-    vocab = config["vocab_size"]
-    plan = traffic.make_plan(
-        {"name": "t", "loop": "closed", "clients": 3,
-         "requests_per_client": 4, "lead_s": 0.5, "tail_s": 0.0,
-         "request_timeout_s": 30.0,
-         "prompt_tokens": {"dist": "uniform", "min": 8, "max": 24},
-         "output_tokens": {"dist": "uniform", "min": 8, "max": 16}},
-        seed, 3, vocab)
-    seqs = correctness.sequences(plan, seed, vocab, 256)
-    weights = bench.make_weights(SHAPES, seed)
-    stated = bench.cache_bytes_per_token(config)
-    if mode == "stated":
-        class Words(ByteTokenizer):
-            vocab_size = vocab
-
-        restores = global_metrics.counter("engine_state_restores_total")
-        eng = InferenceEngine(
-            engine_cfg=EngineConfig(
-                model=config["serve"]["model"], num_slots=4, max_seq=256,
-                seed=seed, mux=True, prefix_cache=True, prefill_chunk=16),
-            tokenizer=Words())
-        _ask_in_process(eng, seqs)
-        counted = eng._prefix_block_bytes / eng._prefix_block
-        assert global_metrics.counter("engine_state_restores_total") \
-            > restores
-    else:  # the reference in the program's place, its weights rounded
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "control", os.path.join(REPO, "benchmarks", "control.py"))
-        control = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(control)
-        counted = stated
-        for seq in seqs:
-            control.pretend(seq)
-            lp = np.asarray(bench.forward_logprobs(
-                SHAPES, weights, seq["tokens"], weight_bits=8))
-            seq["system"] = [float(lp[p, t]) for p, t in seq["probes"]]
-    reference = []
-    for seq in seqs:
-        lp = np.asarray(bench.forward_logprobs(SHAPES, weights, seq["tokens"]))
-        reference.append([float(lp[p, t]) for p, t in seq["probes"]])
-    numbers = correctness.compare(seqs, reference)
-    said = []
-    held = correctness.judge(numbers, limits, counted, stated, said.append)
-    print("\n".join(said))
-    assert held is (mode == "stated"), "\n".join(said)
-    assert stated == tiny.CACHE_BYTES
-    if mode != "stated":
-        assert numbers["echo_prompt"]["mean_abs"] > limits["echo_prompt"], said
